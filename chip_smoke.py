@@ -1,0 +1,723 @@
+#!/usr/bin/env python
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py                 # one chip: kernels, server, trainer
+    python chip_smoke.py --four-chips    # one four-chip host: the sharded legs
+
+ONE process. It refuses to start unless JAX's first device is a TPU, and
+any failed check raises: the exit code is non-zero and no result line is
+printed. On success the LAST line of stdout is one JSON object,
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
+
+What the default run does, at 24 layers / hidden 1024 / 16 heads of 64 /
+FFN 4096 (BERT-Large read as an encoder, GPT-2-medium read as a causal
+decoder), weights random from a seed:
+
+* kernels — the paged decode/append kernel (W = 1, 5, 32; kv_splits 1
+  and 4) and the flash kernel (seq 512, forward and both backward
+  kernels) against their plain-XLA references, compiled by Mosaic;
+* server — ``GenerationEngine`` (8 slots, block 16, 1,024 positions,
+  vocabulary 50,257, float32) behind ``InferenceServer``: the program
+  family is warmed through ``engine.generate`` BEFORE the scheduler
+  thread starts (a cold compile on that thread would trip the 30 s step
+  watchdog), then concurrent HTTP requests (JSON and SSE, four prompt
+  buckets up to 1,024, greedy and seeded sampling, one prompt that
+  reuses a cached prefix) must return exactly their ``max_new_tokens``
+  in-vocabulary tokens, equal to the direct ``engine.generate`` streams,
+  reproduce under teacher forcing through ``forward_full`` within
+  ``LOGIT_MARGIN``, add zero jit traces, and leave every self-healing
+  counter in ``/v2/stats`` at zero;
+* trainer — ``build_transformer`` in bf16 at sequence 512,
+  ``FFModel.compile`` then ``executor.train_batch`` on one fixed batch,
+  once data-parallel and once searched: loss finite and falling;
+* HLO — the decode, suffix-prefill and train-step programs must hold the
+  Mosaic custom call. Serving through the XLA reference is a FAILURE.
+
+Compile seconds are reported apart from run seconds, and the persistent
+compile cache (``flexflow_tpu.device.enable_compile_cache``) is reported
+cold or warm by its entry count. ``flexflow_tpu/_native/libffcore.so`` is
+removed first so the native library is rebuilt from ``native/src``.
+"""
+from __future__ import annotations
+
+import argparse
+import concurrent.futures
+import functools
+import gc
+import importlib.metadata
+import json
+import os
+import pathlib
+import sys
+import time
+import urllib.request
+
+REPO = pathlib.Path(__file__).resolve().parent
+sys.path.insert(0, str(REPO))
+
+SEED = 0
+SLOTS = 8
+# four prefill buckets (16, 128, 512, 1024) shared by six prompts: every
+# extra bucket is another 24-layer program to compile cold
+PROMPT_LENS = (12, 100, 300, 600, 9, 120)
+NEW_TOKENS = 32
+# Greedy tokens must be forward_full's argmax or within this many logits
+# of it. The two paths differ in attention arithmetic only (the paged
+# kernel accumulates scores in float32 on the VPU, forward_full's einsum
+# runs XLA's default single bf16 pass). On the v5e 187 of 192 greedy
+# tokens were the argmax and the worst gap was 0.0006, on logits whose
+# spread is ~0.2 and whose top-two gap averages ~0.04 at this vocabulary;
+# the margin is ten times that worst gap.
+LOGIT_MARGIN = 0.005
+MOSAIC_CALL = "tpu_custom_call"
+
+_T0 = time.monotonic()
+
+
+def log(msg: str) -> None:
+    print(f"[smoke +{time.monotonic() - _T0:7.1f}s] {msg}", flush=True)
+
+
+def check(ok: bool, what: str) -> None:
+    """A failed check ends the run: raise, never print-and-continue."""
+    if not ok:
+        raise RuntimeError(f"chip_smoke check failed: {what}")
+
+
+# ----------------------------------------------------------------- set-up
+
+
+def rebuild_native() -> str:
+    """Remove the (gitignored, possibly stale) native library so it is
+    rebuilt from native/src, and say which host path the run uses."""
+    lib = REPO / "flexflow_tpu" / "_native" / "libffcore.so"
+    existed = lib.exists()
+    if existed:
+        lib.unlink()
+    try:
+        import flexflow_tpu._native as native
+    except ImportError:
+        return f"pure-Python fallback (native build failed; stale .so removed: {existed})"
+    return f"{native.version()} rebuilt from native/src (stale .so removed: {existed})"
+
+
+def cache_entries(cache_dir: str) -> int:
+    p = pathlib.Path(cache_dir)
+    return sum(1 for f in p.iterdir() if f.is_file()) if p.is_dir() else 0
+
+
+def mosaic_calls(fn, *args) -> int:
+    """Mosaic custom calls in the program ``fn`` lowers to for ``args``."""
+    import jax
+
+    return jax.jit(fn).lower(*args).as_text().count(MOSAIC_CALL)
+
+
+# ---------------------------------------------------------------- kernels
+
+
+def kernels_phase(rs) -> dict:
+    """Both Pallas kernels against their XLA references at the shapes
+    the server and the trainer run, compiled (never interpreted)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ops.attention import reference_attention
+    from flexflow_tpu.ops.kernels.decode_attention import (
+        paged_append_attention,
+        paged_kernel_refusal,
+        reference_paged_append_attention,
+    )
+    from flexflow_tpu.ops.kernels.flash_attention import flash_attention
+
+    out = {}
+    b, h, d, bs, max_blocks = 8, 16, 64, 16, 64
+    nb = b * max_blocks + 1
+    k_cache = jnp.asarray(rs.randn(nb, bs, h, d), jnp.float32)
+    v_cache = jnp.asarray(rs.randn(nb, bs, h, d), jnp.float32)
+    tables = jnp.asarray(1 + rs.permutation(nb - 1).reshape(b, max_blocks), jnp.int32)
+    for w in (1, 5, 32):
+        check(paged_kernel_refusal(h, d, bs, w) is None, f"gate refuses W={w}")
+        q = jnp.asarray(rs.randn(b, w, h, d), jnp.float32)
+        base = rs.randint(0, max_blocks * bs - w, size=b)
+        qpos = base[:, None] + np.arange(w)[None, :]
+        qpos[-1, w // 2 + 1:] = -1  # padding queries on one row
+        if w > 1:
+            qpos[0, :] = -1  # one wholly inactive row
+        qpos = jnp.asarray(qpos, jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            ref = reference_paged_append_attention(q, k_cache, v_cache, tables, qpos)
+        for splits in (1, 4):
+            got = jax.jit(
+                functools.partial(paged_append_attention, kv_splits=splits)
+            )(q, k_cache, v_cache, tables, qpos)
+            err = float(jnp.max(jnp.abs(got - ref)))
+            check(np.isfinite(err) and err <= 1e-3, f"paged W={w} splits={splits}: err {err}")
+            check(
+                bool(jnp.all(jnp.where(qpos[:, :, None, None] < 0, got == 0.0, True))),
+                f"paged W={w} splits={splits}: padding queries must emit zeros",
+            )
+            out[f"paged_w{w}_s{splits}_max_abs_err"] = err
+    log(f"paged kernel matches the reference: {out}")
+
+    q, k, v = (jnp.asarray(rs.randn(2, 512, 16, 64), jnp.bfloat16) for _ in range(3))
+    wgt = jnp.asarray(rs.randn(2, 512, 16, 64), jnp.float32)
+    for causal in (False, True):
+        def loss(attn, q, k, v):
+            return jnp.sum(attn(q, k, v, causal=causal).astype(jnp.float32) * wgt)
+
+        def ref_attn(q, k, v, causal):
+            with jax.default_matmul_precision("highest"):
+                return reference_attention(
+                    *(x.astype(jnp.float32) for x in (q, k, v)), causal=causal
+                )
+
+        got = jax.jit(jax.value_and_grad(functools.partial(loss, flash_attention), (0, 1, 2)))(q, k, v)
+        ref = jax.jit(jax.value_and_grad(functools.partial(loss, ref_attn), (0, 1, 2)))(q, k, v)
+        for name, g, r in zip(("dq", "dk", "dv"), got[1], ref[1]):
+            r = r.astype(jnp.float32)
+            rel = float(jnp.max(jnp.abs(g.astype(jnp.float32) - r)) / jnp.max(jnp.abs(r)))
+            check(np.isfinite(rel) and rel <= 0.05, f"flash causal={causal} {name}: rel err {rel}")
+            out[f"flash_causal{int(causal)}_{name}_rel_err"] = rel
+        o = flash_attention(q, k, v, causal=causal).astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(o - ref_attn(q, k, v, causal))))
+        check(np.isfinite(err) and err <= 0.05, f"flash causal={causal} fwd: err {err}")
+        out[f"flash_causal{int(causal)}_fwd_max_abs_err"] = err
+    log("flash kernel (seq 512, fwd + dq + dkv) matches the reference")
+    return out
+
+
+# ----------------------------------------------------------------- server
+
+
+def decoder_config(num_layers: int):
+    from flexflow_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        num_layers=num_layers, hidden_size=1024, num_heads=16, ff_size=4096,
+        seq_length=1024, vocab_size=50257, causal=True,
+    )
+
+
+def paged_program_mosaic_calls(engine, window: int = 0) -> int:
+    """Mosaic custom calls in the engine's decode program (``window`` 0)
+    or in its one-sequence append program at ``window`` (suffix prefill),
+    lowered through the dispatch the engine's own jits take."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.generation.decoder import decode_step, verify_step
+
+    zi = lambda *s: jnp.zeros(s, jnp.int32)
+    cache = (engine.cache.k, engine.cache.v)
+    kw = dict(backend=engine.backend, mesh=engine._kernel_mesh)
+    b, mb = engine.max_batch_slots, engine.max_blocks_per_seq
+    if window:
+        return mosaic_calls(
+            functools.partial(verify_step, **kw),
+            engine.params, zi(1, window), zi(1, window), *cache, zi(1, mb),
+        )
+    return mosaic_calls(
+        functools.partial(decode_step, **kw),
+        engine.params, zi(b), zi(b), *cache, zi(b, mb), zi(b),
+    )
+
+
+def make_requests(rs, vocab: int, prompt_lens) -> list:
+    """Greedy requests over ``prompt_lens`` (JSON and SSE alternating),
+    the last one sampled from a seed."""
+    reqs = [
+        {
+            "prompt": [int(t) for t in rs.randint(0, vocab, size=n)],
+            "max_new_tokens": NEW_TOKENS,
+            "stream": bool(i % 2),
+        }
+        for i, n in enumerate(prompt_lens)
+    ]
+    reqs[-1].update(temperature=0.8, top_k=50, seed=7)
+    return reqs
+
+
+def direct_generate(engine, bodies: list) -> list:
+    """The synchronous path (``engine.generate``: a private scheduler
+    stepped on this thread), one call per sampling configuration."""
+    from flexflow_tpu.serving.generation import GenerationModel
+
+    out = [None] * len(bodies)
+    groups: dict = {}
+    for i, body in enumerate(bodies):
+        groups.setdefault(GenerationModel.sampling_from(body), []).append(i)
+    for sampling, idx in groups.items():
+        for i, toks in zip(idx, engine.generate([bodies[i]["prompt"] for i in idx], sampling)):
+            out[i] = [int(t) for t in toks]
+    return out
+
+
+def http_generate(base: str, name: str, body: dict) -> list:
+    req = urllib.request.Request(
+        f"{base}/v2/models/{name}/generate", data=json.dumps(body).encode()
+    )
+    with urllib.request.urlopen(req, timeout=600) as resp:
+        raw = resp.read().decode()
+    if not body["stream"]:
+        return [int(t) for t in json.loads(raw)["tokens"]]
+    events = [
+        json.loads(line[len("data: "):])
+        for chunk in raw.strip().split("\n\n")
+        for line in chunk.split("\n")
+        if line.startswith("data: ")
+    ]
+    check(events and events[-1].get("done") is True, f"SSE stream did not finish: {events[-1:]}")
+    check("error" not in events[-1], f"SSE stream failed: {events[-1]}")
+    streamed = [int(e["token"]) for e in events[:-1]]
+    check(streamed == events[-1]["tokens"], "SSE token events disagree with the done event")
+    return streamed
+
+
+def teacher_force(params, bodies: list, streams: list):
+    """Per request: forward_full's argmax at every generated position,
+    and how far below the maximum the produced token's logit is."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.generation.decoder import forward_full
+
+    n_new = len(streams[0])
+    lens = [len(b["prompt"]) for b in bodies]
+    pad = -(-(max(lens) + n_new) // 128) * 128
+    tokens = np.zeros((len(bodies), pad), np.int32)
+    for i, (b, s) in enumerate(zip(bodies, streams)):
+        tokens[i, : lens[i]] = b["prompt"]
+        tokens[i, lens[i] : lens[i] + n_new] = s
+
+    @jax.jit
+    def run(params, tokens, lengths, prompt_lens, produced):
+        logits = forward_full(params, tokens, lengths)  # [N, S, V]
+        # the logits at position p predict the token at p + 1
+        at = prompt_lens[:, None] - 1 + jnp.arange(n_new)[None, :]
+        sel = jnp.take_along_axis(logits, at[:, :, None], axis=1)  # [N, n_new, V]
+        got = jnp.take_along_axis(sel, produced[:, :, None], axis=2)[..., 0]
+        return jnp.argmax(sel, axis=-1), jnp.max(sel, axis=-1) - got, jnp.all(jnp.isfinite(sel))
+
+    argmax, gap, finite = run(
+        params, jnp.asarray(tokens), jnp.asarray(np.asarray(lens) + n_new, jnp.int32),
+        jnp.asarray(lens, jnp.int32), jnp.asarray(np.asarray(streams, np.int32)),
+    )
+    check(bool(finite), "forward_full produced non-finite logits")
+    return np.asarray(argmax), np.asarray(gap)
+
+
+def check_streams(bodies, streams, vocab, what):
+    for body, toks in zip(bodies, streams):
+        check(
+            len(toks) == body["max_new_tokens"],
+            f"{what}: prompt[{len(body['prompt'])}] returned {len(toks)} tokens, "
+            f"asked {body['max_new_tokens']}",
+        )
+        check(all(0 <= t < vocab for t in toks), f"{what}: token outside the vocabulary")
+
+
+def check_teacher_forcing(params, bodies, streams, what) -> dict:
+    import numpy as np
+
+    greedy = [i for i, b in enumerate(bodies) if b.get("temperature", 0.0) <= 0.0]
+    argmax, gap = teacher_force(
+        params, [bodies[i] for i in greedy], [streams[i] for i in greedy]
+    )
+    produced = np.asarray([streams[i] for i in greedy])
+    off = int(np.sum(argmax != produced))
+    worst = float(gap.max())
+    log(
+        f"{what}: teacher forcing through forward_full: {produced.size - off}/"
+        f"{produced.size} greedy tokens are the argmax, worst logit gap {worst:.5f} "
+        f"(margin {LOGIT_MARGIN})"
+    )
+    check(worst <= LOGIT_MARGIN, f"{what}: a greedy token is {worst} logits below forward_full's best")
+    return {"greedy_tokens": int(produced.size), "not_argmax": off, "worst_logit_gap": worst}
+
+
+ZERO_COUNTERS = (
+    "rejected", "expired", "failed", "cancelled", "preemptions", "recompiles",
+    "retraces_blamed", "recoveries", "step_retries", "replayed_tokens",
+    "quarantined", "watchdog_trips", "engine_failures", "degrade_level",
+    "overload_sheds_total", "overload_throttled_total",
+)
+
+
+def server_phase(rs, num_layers: int = 24) -> dict:
+    import jax
+
+    from flexflow_tpu.generation import GenerationEngine, init_decoder_params
+    from flexflow_tpu.ops.kernels.decode_attention import paged_kernel_refusal
+    from flexflow_tpu.serving import InferenceServer
+    from flexflow_tpu.serving.generation import GenerationModel
+
+    out: dict = {}
+    cfg = decoder_config(num_layers)
+    t0 = time.monotonic()
+    params = init_decoder_params(jax.random.key(SEED), cfg)
+    engine = GenerationEngine(params, cfg, max_batch_slots=SLOTS, block_size=16)
+    jax.block_until_ready(engine.cache.k)
+    cc = engine.cache_config
+    log(
+        f"engine: {num_layers} L / {cfg.hidden_size} / {cfg.num_heads} x "
+        f"{cfg.hidden_size // cfg.num_heads} / {cfg.ff_size}, vocab {cfg.vocab_size}, "
+        f"{SLOTS} slots, buckets {engine.buckets}, cache {cc.num_blocks} x {cc.block_size} "
+        f"= {cc.total_bytes / 2**30:.2f} GiB {cc.dtype.name}, donate={engine.donate}, "
+        f"built in {time.monotonic() - t0:.1f}s"
+    )
+    check(engine.donate, "cache donation must be on for a TPU backend")
+    check(engine.cache.k is not engine.cache.v, "K and V must not share one buffer")
+
+    bodies = make_requests(rs, cfg.vocab_size, PROMPT_LENS)
+    # a follow-up that shares request 2's prompt: its full blocks are in
+    # the prefix cache by then, so only a <=32-token suffix is computed —
+    # the append kernel's W > 1 window inside a serving program
+    shared = len(bodies[2]["prompt"]) // 16 * 16
+    suffix = [int(t) for t in rs.randint(0, cfg.vocab_size, size=22)]
+    follow = {"prompt": bodies[2]["prompt"][:shared] + suffix,
+              "max_new_tokens": NEW_TOKENS, "stream": True}
+    buckets = sorted({engine.bucket_for(len(b["prompt"])) for b in bodies})
+    check(len(buckets) >= 3 and buckets[-1] >= 512, f"prompts must span buckets: {buckets}")
+
+    # ---- warm: compile the whole family on THIS thread, before start()
+    t0 = time.monotonic()
+    direct = direct_generate(engine, bodies)
+    direct_follow = direct_generate(engine, [follow])
+    warm_s = time.monotonic() - t0
+    compile_s = {p["name"]: round(p["compile_s"], 2) for p in engine.programs.snapshot()
+                 if p.get("compile_s")}
+    log(f"warm-up through engine.generate: {warm_s:.1f}s; compile+first-run seconds by program: {compile_s}")
+    out.update(warm_s=round(warm_s, 1), compile_s=compile_s)
+    check(engine.prefix_cache.hits >= 1, "the follow-up prompt must reuse a cached prefix")
+    check(
+        f"prefix_prefill[{engine.bucket_for(len(suffix))}]" in engine.trace_counts,
+        f"suffix prefill did not run: {engine.trace_counts}",
+    )
+    check_streams(bodies + [follow], direct + direct_follow, cfg.vocab_size, "engine.generate")
+
+    # a clean cache and prefix index, so the served run takes the same
+    # programs in the same order as the direct one (no recompiles)
+    engine.reset()
+    traces_before = dict(engine.trace_counts)
+
+    # ---- serve: real HTTP, concurrent, JSON and SSE
+    server = InferenceServer(port=0)
+    model = GenerationModel(engine, name="lm")
+    server.register_generation(model)
+    t0 = time.monotonic()
+    with server:
+        base = f"http://127.0.0.1:{server.port}"
+        with concurrent.futures.ThreadPoolExecutor(len(bodies)) as pool:
+            futures = [pool.submit(http_generate, base, "lm", b) for b in bodies]
+            served = [f.result(timeout=900) for f in futures]
+        served_follow = [http_generate(base, "lm", follow)]
+        serve_s = time.monotonic() - t0
+        stats = json.load(urllib.request.urlopen(f"{base}/v2/stats"))["generation"]["lm"]
+        ready = json.load(urllib.request.urlopen(f"{base}/v2/health/ready"))
+    n_tok = sum(len(s) for s in served + served_follow)
+    log(
+        f"served {len(bodies) + 1} HTTP requests ({n_tok} tokens) in {serve_s:.1f}s; "
+        f"steps {engine.step_counts}; decode execute {engine.phase_time_s['decode']['execute']:.2f}s"
+    )
+    out.update(serve_s=round(serve_s, 2), tokens=n_tok, step_counts=dict(engine.step_counts))
+
+    check_streams(bodies + [follow], served + served_follow, cfg.vocab_size, "HTTP")
+    check(served == direct, "HTTP streams differ from engine.generate of the same prompts")
+    check(served_follow == direct_follow, "HTTP prefix-reuse stream differs from engine.generate")
+    new_traces = {
+        k: v - traces_before.get(k, 0) for k, v in engine.trace_counts.items()
+        if v != traces_before.get(k, 0)
+    }
+    check(not new_traces, f"jit traces while serving (steady state must have none): {new_traces}")
+    nonzero = {k: stats[k] for k in ZERO_COUNTERS if stats[k]}
+    check(not nonzero, f"self-healing ran while serving: {nonzero}")
+    check(stats["completed"] == len(bodies) + 1, f"completed {stats['completed']}")
+    check(model.breaker.state == "closed", f"breaker {model.breaker.state}")
+    check(stats["prefix_cache_tokens_reused_total"] >= shared, "served follow-up missed the prefix cache")
+    log(f"zero new traces, zero recoveries/retries/quarantines/watchdog trips; readiness {ready.get('ready')}")
+
+    out["teacher_forcing"] = check_teacher_forcing(
+        params, bodies + [follow], served + served_follow, "server"
+    )
+
+    # ---- which attention ran: the programs' own lowering
+    head_dim = cfg.hidden_size // cfg.num_heads
+    for window in (1, engine.bucket_for(len(suffix))):
+        check(
+            paged_kernel_refusal(cfg.num_heads, head_dim, cc.block_size, window) is None,
+            f"the kernel gate refuses the served shape (window {window})",
+        )
+    w = engine.bucket_for(len(suffix))
+    n_decode = paged_program_mosaic_calls(engine)
+    n_suffix = paged_program_mosaic_calls(engine, window=w)
+    log(f"Mosaic custom calls: decode {n_decode}, suffix prefill[{w}] {n_suffix} (one per layer)")
+    check(n_decode == num_layers, f"decode program holds {n_decode} Mosaic calls, want {num_layers}")
+    check(n_suffix == num_layers, f"suffix-prefill program holds {n_suffix} Mosaic calls")
+    out.update(mosaic_decode=n_decode, mosaic_suffix_prefill=n_suffix)
+    mem = jax.devices()[0].memory_stats() or {}
+    out["peak_bytes_in_use"] = mem.get("peak_bytes_in_use")
+    log(f"device memory: peak {mem.get('peak_bytes_in_use', 0) / 2**30:.2f} GiB "
+        f"of {mem.get('bytes_limit', 0) / 2**30:.2f} GiB")
+    return out
+
+
+# ---------------------------------------------------------------- trainer
+
+
+def train_phase(rs, label: str, config_kwargs: dict, strategy_fn=None,
+                num_layers: int = 24):
+    """Compile and take five steps on one fixed batch; returns the
+    facts and the model (the four-chip legs look at where its arrays
+    live)."""
+    batch, seq, steps = 8, 512, 5
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu import AdamOptimizer, DataType, FFConfig, LossType
+    from flexflow_tpu.models import TransformerConfig, build_transformer
+
+    cfg = TransformerConfig(
+        num_layers=num_layers, hidden_size=1024, num_heads=16, ff_size=4096,
+        seq_length=seq, dtype=DataType.BFLOAT16,
+    )
+    config = FFConfig(batch_size=batch, num_nodes=1, **config_kwargs)
+    model = build_transformer(config, cfg)
+    t0 = time.monotonic()
+    # Adam: its step does not shrink with the mean-squared loss's 1/N
+    # gradient, so five steps move the loss well clear of bf16 noise
+    model.compile(
+        optimizer=AdamOptimizer(alpha=1e-4), loss_type=LossType.MEAN_SQUARED_ERROR,
+        strategy=strategy_fn(model.graph) if strategy_fn else None,
+    )
+    compile_s = time.monotonic() - t0
+    ex = model.executor
+    mesh = dict(zip(model.mesh.axis_names, model.mesh.devices.shape))
+    x = jnp.asarray(rs.randn(batch, seq, cfg.hidden_size), cfg.dtype.jnp)
+    y = 0.5 * x
+    rng = jax.random.key(SEED)
+    n_mosaic = mosaic_calls(
+        ex._train_step_fn, ex.params, ex.opt_state, ex.state, (x,), y, rng
+    )
+    check(n_mosaic >= 3 * num_layers,
+          f"{label}: train step holds {n_mosaic} Mosaic calls, want fwd + dq + dkv per layer")
+    losses, times = [], []
+    for _ in range(steps):
+        t0 = time.monotonic()
+        losses.append(float(ex.train_batch([x], y, rng)["loss"]))
+        times.append(time.monotonic() - t0)
+    log(
+        f"trainer[{label}]: mesh {mesh}, FFModel.compile {compile_s:.1f}s, first step "
+        f"(XLA compile + run) {times[0]:.1f}s, later steps {min(times[1:]):.3f}s, "
+        f"{n_mosaic} Mosaic calls, loss {losses[0]:.5f} -> {losses[-1]:.5f}"
+    )
+    check(all(np.isfinite(l) for l in losses), f"{label}: non-finite loss {losses}")
+    check(losses[-1] < losses[0], f"{label}: loss did not fall over {steps} steps: {losses}")
+    out = {"mesh": mesh, "losses": losses, "first_step_s": round(times[0], 1),
+           "step_s": round(min(times[1:]), 4), "mosaic_calls": n_mosaic}
+    if model._search_result is not None:
+        out["search_cost_ms"] = model._search_result.best_cost * 1e3
+    return out, model
+
+
+def calibration_hit(kind: str) -> str:
+    """The search's op-cost lookup for this chip must hit a table inside
+    the checkout: nothing under $HOME decides a strategy."""
+    from flexflow_tpu.search.calibration import load_calibration
+
+    cal = load_calibration(kind)
+    check(cal is not None, f"no committed calibration table for device kind {kind!r}")
+    inside = pathlib.Path(cal.source).resolve().is_relative_to(REPO)
+    check(inside or "FLEXFLOW_TPU_CACHE" in os.environ,
+          f"calibration table {cal.source} is outside the checkout")
+    return f"{cal.source} ({len(cal.entries)} entries, derates {cal.derates})"
+
+
+# -------------------------------------------------------------- four chips
+
+
+def shard_report(arr) -> str:
+    shards = arr.addressable_shards
+    return (f"{tuple(arr.shape)} as {len(shards)} x {tuple(shards[0].data.shape)} on devices "
+            f"{sorted(s.device.id for s in shards)}")
+
+
+def compiled_flash_call(ex) -> str:
+    """The flash forward custom call as the COMPILED (partitioned)
+    train step holds it: per-chip batch/heads, or the global tensor
+    gathered onto every chip?"""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.zeros((8, 512, 1024), jnp.bfloat16)
+    hlo = ex._train_step.lower(
+        ex.params, ex.opt_state, ex.state, tuple(ex._shard_inputs([x])), x,
+        jax.random.key(SEED),
+    ).compile().as_text()
+    calls = [l for l in hlo.splitlines() if MOSAIC_CALL in l and "flash_attention_fwd" in l]
+    check(bool(calls), "compiled train step holds no flash forward custom call")
+    return calls[0].split("custom-call(")[0].strip()[:200]
+
+
+def four_chip_phase(rs, num_layers: int = 4) -> dict:
+    """The sharded legs on one four-chip host, in this one process.
+    Widths as in the one-chip run; depth cut to ``num_layers`` so every
+    program family compiles inside the chip budget."""
+    import jax
+    import numpy as np
+
+    from flexflow_tpu.generation import GenerationEngine, init_decoder_params
+    from flexflow_tpu.parallel.mesh import serving_mesh
+    from flexflow_tpu.parallel.strategy import megatron_strategy
+
+    devs = jax.devices()
+    check(len(devs) == 4, f"--four-chips needs 4 devices, have {len(devs)}")
+    out: dict = {}
+
+    def used(d):
+        return (d.memory_stats() or {}).get("bytes_in_use", 0)
+
+    # ---- trainer: one chip, {data: 4}, and megatron dp=2 x tp=2
+    runs = {}
+    for label, kwargs, strat in (
+        ("1chip", dict(workers_per_node=1, only_data_parallel=True), None),
+        ("data4", dict(workers_per_node=4, only_data_parallel=True), None),
+        ("dp2xtp2", dict(workers_per_node=4, only_data_parallel=True),
+         lambda g: megatron_strategy(g, dp=2, tp=2)),
+    ):
+        r, model = train_phase(np.random.RandomState(SEED), label, kwargs, strat,
+                               num_layers=num_layers)
+        leaves = jax.tree.leaves(model.executor.params)
+        big = max(leaves, key=lambda a: a.size)
+        per_dev = [round(used(d) / 2**20) for d in devs]
+        log(f"trainer[{label}]: largest weight {shard_report(big)}; MiB in use per device {per_dev}")
+        r.update(largest_weight=shard_report(big), mib_in_use=per_dev)
+        if label != "1chip":
+            check(int(np.prod(list(r["mesh"].values()))) == 4, f"{label}: mesh {r['mesh']}")
+            check(all(m > 0 for m in per_dev), f"{label}: a device holds nothing: {per_dev}")
+            r["flash_fwd_hlo"] = compiled_flash_call(model.executor)
+            log(f"trainer[{label}]: flash_attention_fwd in the compiled HLO: {r['flash_fwd_hlo']}")
+        runs[label] = r
+        del model
+        gc.collect()
+    for label in ("data4", "dp2xtp2"):
+        a, b = runs["1chip"]["losses"], runs[label]["losses"]
+        rel = max(abs(p - q) / abs(p) for p, q in zip(a, b))
+        log(f"trainer[{label}] loss vs one chip: max rel diff {rel:.4f} over {len(a)} steps")
+        check(rel <= 0.02, f"{label}: loss departs from the one-chip run: {a} vs {b}")
+        runs[label]["loss_rel_diff_vs_1chip"] = rel
+    out["trainer"] = runs
+
+    # ---- server: tp_degree=4 against tp=1, same prompts
+    cfg = decoder_config(num_layers)
+    params = init_decoder_params(jax.random.key(SEED), cfg)
+    bodies = make_requests(rs, cfg.vocab_size, (12, 100, 300, 9))
+    streams = {}
+    for tp in (1, 4):
+        engine = GenerationEngine(params, cfg, max_batch_slots=SLOTS, block_size=16, tp_degree=tp)
+        jax.block_until_ready(engine.cache.k)
+        log(f"server[tp={tp}]: cache {shard_report(engine.cache.k)}; "
+            f"wq {shard_report(engine.params['layers'][0]['wq'])}")
+        if tp == 4:
+            shards = engine.cache.k.addressable_shards
+            check(len({s.device.id for s in shards}) == 4, "tp=4 cache is not on four devices")
+            check(shards[0].data.shape[3] == cfg.num_heads // 4, "tp=4 cache is not head-sharded")
+            n = paged_program_mosaic_calls(engine)
+            check(n == num_layers, f"tp=4 decode holds {n} Mosaic calls, want {num_layers}")
+        streams[tp] = direct_generate(engine, bodies)
+        check_streams(bodies, streams[tp], cfg.vocab_size, f"tp={tp}")
+        out[f"server_tp{tp}"] = check_teacher_forcing(params, bodies, streams[tp], f"server[tp={tp}]")
+        del engine
+        gc.collect()
+    same = sum(a == b for a, b in zip(streams[1], streams[4]))
+    log(f"server: {same}/{len(bodies)} tp=4 streams are token-identical to tp=1 "
+        f"(all within the teacher-forcing margin)")
+    out["tp4_streams_identical_to_tp1"] = f"{same}/{len(bodies)}"
+
+    # ---- four one-chip engines in one process, one device each
+    before = [used(d) for d in devs]
+    engines = [
+        GenerationEngine(params, cfg, max_batch_slots=SLOTS, block_size=16,
+                         mesh=serving_mesh(1, devices=[d]))
+        for d in devs
+    ]
+    placed = []
+    for d, e in zip(devs, engines):
+        jax.block_until_ready(e.cache.k)
+        on = {s.device.id for s in e.cache.k.addressable_shards}
+        on |= {s.device.id for leaf in jax.tree.leaves(e.params) for s in leaf.addressable_shards}
+        check(on == {d.id}, f"engine asked onto device {d.id} has arrays on {sorted(on)}")
+        placed.append(sorted(on))
+    grew = [round((used(d) - b) / 2**20) for d, b in zip(devs, before)]
+    check(all(g > 0 for g in grew), f"a device did not grow: {grew}")
+    short = [{"prompt": bodies[0]["prompt"], "max_new_tokens": 8, "stream": False}]
+    outs = [direct_generate(e, short)[0] for e in engines]
+    check(all(o == outs[0] for o in outs), f"the four replicas disagree: {outs}")
+    log(f"four engines via mesh=: arrays on devices {placed}, MiB grown per device {grew}, "
+        f"same 8 tokens from each")
+    out["four_engines"] = {"devices": placed, "mib_grown": grew}
+    return out
+
+
+# ------------------------------------------------------------------- main
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run the sharded legs on a four-chip host instead")
+    args = ap.parse_args(argv)
+
+    import jax
+    import numpy as np
+
+    from flexflow_tpu.device import COMPILE_CACHE_ENV, enable_compile_cache, require_tpu
+
+    dev = require_tpu()
+    native = rebuild_native()  # nothing has imported flexflow_tpu._native yet
+    device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices())}
+    cache_dir = enable_compile_cache()
+    entries_before = cache_entries(cache_dir)
+    log(
+        f"device {device}; jax {jax.__version__}, jaxlib "
+        f"{importlib.metadata.version('jaxlib')}, libtpu {importlib.metadata.version('libtpu')}; "
+        f"native: {native}"
+    )
+    log(
+        f"compile cache: {cache_dir} ({'from ' + COMPILE_CACHE_ENV if os.environ.get(COMPILE_CACHE_ENV) else 'in-checkout default'}), "
+        f"{entries_before} entries -> this run is {'WARM' if entries_before else 'COLD'}"
+    )
+    summary = {"device": device, "jax": jax.__version__, "native": native,
+               "compile_cache": {"dir": cache_dir, "entries_before": entries_before}}
+    rs = np.random.RandomState(SEED)
+    if args.four_chips:
+        summary["four_chips"] = four_chip_phase(rs)
+    else:
+        summary["calibration"] = calibration_hit(dev.device_kind)
+        log(f"calibration lookup for {dev.device_kind!r}: {summary['calibration']}")
+        summary["kernels"] = kernels_phase(rs)
+        summary["server"] = server_phase(rs)
+        gc.collect()
+        for label, kwargs in (
+            ("dp", dict(workers_per_node=1, only_data_parallel=True)),
+            ("searched", dict(workers_per_node=1, only_data_parallel=False, search_budget=5)),
+        ):
+            summary[f"trainer_{label}"], model = train_phase(rs, label, kwargs)
+            del model
+            gc.collect()
+    entries_after = cache_entries(cache_dir)
+    summary["compile_cache"]["entries_after"] = entries_after
+    summary["wall_s"] = round(time.monotonic() - _T0, 1)
+    log(f"compile cache: {entries_before} -> {entries_after} entries; wall {summary['wall_s']}s")
+    out_dir = REPO / "chiprun_out"
+    out_dir.mkdir(exist_ok=True)
+    name = "chip_smoke_four_chips.json" if args.four_chips else "chip_smoke.json"
+    (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -16,6 +16,9 @@ from flexflow_tpu.models import TransformerConfig, build_transformer
 
 
 def main():
+    from flexflow_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     config = FFConfig.from_args()
     cfg = TransformerConfig(
         num_layers=4, hidden_size=512, num_heads=8, ff_size=2048, seq_length=128,

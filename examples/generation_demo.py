@@ -30,6 +30,9 @@ from flexflow_tpu.serving.generation import GenerationModel
 
 
 def main():
+    from flexflow_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     cfg = TransformerConfig(
         num_layers=2, hidden_size=64, num_heads=4, ff_size=256,
         seq_length=128, vocab_size=256, causal=True,

@@ -15,6 +15,9 @@ from flexflow_tpu.serving import InferenceModel, InferenceServer
 
 
 def main():
+    from flexflow_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--port", type=int, default=8000)
     ap.add_argument("--max-batch", type=int, default=32)

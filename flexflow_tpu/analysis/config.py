@@ -19,7 +19,7 @@ from typing import Dict, FrozenSet, Union
 CLOCK_WHITELIST: Dict[str, Union[str, FrozenSet[str]]] = {
     # Offline bench/diagnostic harnesses: measuring physical wall time
     # is their job (genbench/perfwatch/chaoscheck/obsreport/calib_debug
-    # / mfu_profile / tpu_evidence), and their watchdog waits bound
+    # / mfu_profile), and their watchdog waits bound
     # real blocking calls.
     "tools/": "*",
     # Kernel calibration measures device wall time by definition.

@@ -61,8 +61,8 @@ class FFConfig:
     # inside ONE XLA program (the reference amortizes per-iteration
     # runtime analysis with Legion traces, begin_trace/end_trace
     # flexflow_cffi.py:2079-2086; here the trace is a lax.scan over
-    # stacked batches, which also removes per-step host dispatch —
-    # dominant over tunneled/remote device transports). 1 = eager.
+    # stacked batches, which also removes per-step host dispatch).
+    # 1 = eager.
     trace_window: int = 1
     # ZeRO-1 optimizer-state sharding over the data axis (beyond-parity:
     # the reference replicates optimizer state everywhere; PS/NCCL only

@@ -180,8 +180,13 @@ class KVCache:
         self.v = v
         self.sharding = sharding
 
-    @classmethod
-    def create(cls, config: CacheConfig, sharding=None) -> "KVCache":
+    @staticmethod
+    def _zeros(config: CacheConfig, sharding) -> jax.Array:
+        """One zeroed cache array, allocated where it will live: with a
+        sharding each device materializes only its own head shard (never
+        the whole array on device 0, then moved). K and V each get their
+        own call — the decode/verify jits donate both, and XLA refuses
+        one buffer donated twice."""
         shape = (
             config.num_layers,
             config.num_blocks,
@@ -189,10 +194,16 @@ class KVCache:
             config.num_heads,
             config.head_dim,
         )
-        zeros = jnp.zeros(shape, config.dtype.jnp)
-        if sharding is not None:
-            zeros = jax.device_put(zeros, sharding)
-        return cls(config, zeros, zeros, sharding=sharding)
+        return jnp.zeros(shape, config.dtype.jnp, device=sharding)
+
+    @classmethod
+    def create(cls, config: CacheConfig, sharding=None) -> "KVCache":
+        return cls(
+            config,
+            cls._zeros(config, sharding),
+            cls._zeros(config, sharding),
+            sharding=sharding,
+        )
 
     def update(self, k: jax.Array, v: jax.Array) -> None:
         self.k = k
@@ -202,11 +213,8 @@ class KVCache:
         """Drop all cached K/V (engine crash recovery): every position is
         rewritten by recompute-replay prefills, and rezeroing also clears
         any NaN a poisoned batch may have written."""
-        zeros = jnp.zeros(self.k.shape, self.config.dtype.jnp)
-        if self.sharding is not None:
-            zeros = jax.device_put(zeros, self.sharding)
-        self.k = zeros
-        self.v = zeros
+        self.k = self._zeros(self.config, self.sharding)
+        self.v = self._zeros(self.config, self.sharding)
 
 
 class BlockAllocator:

@@ -39,7 +39,7 @@ Three cooperating pieces:
   supervisor discards its late result and restarts when (if) the device
   call finally returns.
 
-Failure taxonomy (the README's failure-semantics table):
+Failure classes (the README's failure-semantics table):
 
   transient device error   -> RetryPolicy retry, invisible
   hard step crash, once    -> supervisor step retry, invisible

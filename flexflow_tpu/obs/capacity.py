@@ -21,7 +21,7 @@ this module answers the *resource* dimension with three pieces:
   peaks. Convention follows MFU literature: only model-shaped work
   counts — true prompt lengths and live context positions, never bucket
   padding or inactive slots — so serving MFU is comparable to the
-  training MFU in MFU_PROFILE.json. Work the device executed but
+  training MFU tools/mfu_profile.py reports. Work the device executed but
   clients never benefited from (recovery replay, bisection probes, step
   retries) DOES count, in both the FLOPs numerator and the device-time
   denominator: MFU measures hardware utilization, not client benefit —

@@ -4,8 +4,10 @@ Reference: src/ops/attention.cc (926 LoC) lowering to a monolithic
 ``cudnnMultiHeadAttnForward`` (src/ops/attention.cu:35) with qkv+output
 projection weights woven into one tensor. TPU-native: explicit q/k/v/o
 projections (MXU matmuls) around a fused attention core — a Pallas
-flash-attention kernel on TPU (ops/kernels/flash_attention.py), falling
-back to the einsum/softmax composition under jit elsewhere. Unlike the
+flash-attention kernel on the TPU backend (ops/kernels/flash_attention.py)
+and the einsum/softmax composition on the CPU backend. On a TPU a shape
+a kernel's gate refuses also takes the composition, and says so once in
+the log. Unlike the
 reference (no causal masking, no long-context support at all — SURVEY
 §2.2), this op supports causal masks and, via the strategy layer,
 sequence-parallel ring attention.
@@ -13,6 +15,8 @@ sequence-parallel ring attention.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import logging
 from typing import List, Optional
 
 import jax
@@ -20,7 +24,31 @@ import jax.numpy as jnp
 
 from ..core.tensor import TensorSpec
 from ..core.types import DataType, OpType
+from ..device import on_tpu
 from .base import LowerCtx, OpCost, OpDef, WeightSpec, io_cost, register_op
+from .kernels.decode_attention import (
+    paged_append_attention,
+    paged_decode_attention,
+    paged_kernel_refusal,
+    reference_paged_append_attention,
+    reference_paged_attention,
+    sharded_paged_append_attention,
+    sharded_paged_decode_attention,
+)
+from .kernels.flash_attention import (
+    flash_attention,
+    flash_attention_sharded,
+    supports_shapes,
+)
+
+_log = logging.getLogger(__name__)
+
+
+@functools.lru_cache(maxsize=None)
+def _note_refusal(kernel: str, reason: str) -> None:
+    """One log line per distinct refusal: which kernel a TPU program is
+    NOT running, and why (traced once per program, so this is quiet)."""
+    _log.warning("%s refused on the TPU backend, using the XLA composition: %s", kernel, reason)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,7 +169,9 @@ class MultiHeadAttentionOp(OpDef):
                 head_axis=head_axis,
             )
         else:
-            ctx_out = attention_core(qh, kh, vh, causal=params.causal, backend=ctx.backend)
+            ctx_out = attention_core(
+                qh, kh, vh, causal=params.causal, backend=ctx.backend, mesh=mesh
+            )
         out = jnp.einsum("bshd,hde->bse", ctx_out, weights["wo"])
         # manual tensor parallelism (inside shard_map — GPipe stages):
         # head-sharded wq/wk/wv make ctx_out carry H/tp local heads and
@@ -183,22 +213,44 @@ def attention_core(
     causal: bool = False,
     backend: str = "tpu",
     scale: Optional[float] = None,
+    mesh=None,
 ) -> jax.Array:
     """Scaled dot-product attention over [B, S, H, D] tensors.
 
-    Dispatches to the Pallas flash-attention kernel on TPU backends and to
-    the XLA einsum composition elsewhere (CPU test meshes, interpret mode).
+    The Pallas flash-attention kernel on the TPU backend, the XLA einsum
+    composition on the CPU backend (and for shapes the kernel refuses).
+    ``mesh`` is the GSPMD mesh the caller's operands are sharded over
+    (None inside a manual shard_map region): with more than one device
+    the kernel runs per shard (:func:`flash_attention_sharded`).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if backend == "tpu":
-        try:
-            from .kernels.flash_attention import flash_attention, on_tpu, supports_shapes
-        except ImportError:
-            flash_attention = None
-        if flash_attention is not None and on_tpu() and supports_shapes(q.shape, k.shape):
+    if backend == "tpu" and on_tpu():
+        if supports_shapes(q.shape, k.shape):
+            if mesh is not None and mesh.size > 1:
+                return flash_attention_sharded(q, k, v, mesh, causal=causal, scale=scale)
             return flash_attention(q, k, v, causal=causal, scale=scale)
+        _note_refusal("flash_attention", f"q{tuple(q.shape)} k{tuple(k.shape)}")
     return reference_attention(q, k, v, causal=causal, scale=scale)
+
+
+def _paged_kernel_tp(kernel, backend, mesh, head_axis, num_heads, head_dim, cache, window):
+    """Head shards to run the paged kernel over (1 = unsharded), or 0
+    for the XLA reference: the CPU backend, or a shape the kernel's gate
+    refuses — which on a TPU is logged."""
+    if backend != "tpu" or not on_tpu():
+        return 0
+    tp = 1 if mesh is None else int(dict(mesh.shape).get(head_axis, 1))
+    if num_heads % tp:
+        reason = f"{num_heads} heads do not divide over {tp} shards"
+    else:
+        reason = paged_kernel_refusal(
+            num_heads // tp, head_dim, cache.shape[1], window, cache.dtype.itemsize
+        )
+    if reason is not None:
+        _note_refusal(kernel, reason)
+        return 0
+    return tp
 
 
 def decode_attention_core(
@@ -216,36 +268,23 @@ def decode_attention_core(
     over a block-structured KV cache with position masking, so
     incremental decode reproduces full-context causal logits.
 
-    Dispatches to the Pallas paged-attention kernel on TPU backends
-    (kernels/decode_attention.py) and to the XLA gather + masked softmax
-    composition elsewhere (paged_decode_attention itself falls back on
-    pallas-less jax builds). ``mesh`` with a >1 ``head_axis`` selects
-    the HEAD-SHARDED kernel path (ISSUE 15): each shard's kernel runs
-    over its local KV heads via shard_map; the reference path needs no
-    mesh plumb — GSPMD partitions the plain-XLA composition itself.
+    The Pallas paged-attention kernel on the TPU backend
+    (kernels/decode_attention.py), the XLA gather + masked softmax
+    composition on the CPU backend. ``mesh`` with a >1 ``head_axis``
+    selects the HEAD-SHARDED kernel path (ISSUE 15): each shard's kernel
+    runs over its local KV heads via shard_map; the reference path needs
+    no mesh plumb — GSPMD partitions the plain-XLA composition itself.
     """
-    from .kernels.decode_attention import (
-        on_tpu,
-        paged_decode_attention,
-        reference_paged_attention,
-        sharded_paged_decode_attention,
-        supports_decode_shapes,
+    tp = _paged_kernel_tp(
+        "paged_decode_attention", backend, mesh, head_axis,
+        q.shape[1], q.shape[2], k_cache, 1,
     )
-
-    tp = 1 if mesh is None else int(dict(mesh.shape).get(head_axis, 1))
-    if (
-        backend == "tpu"
-        and on_tpu()
-        and q.shape[1] % max(1, tp) == 0
-        and supports_decode_shapes(
-            q.shape[1] // max(1, tp), q.shape[2], k_cache.shape[1]
+    if tp > 1:
+        return sharded_paged_decode_attention(
+            q, k_cache, v_cache, block_tables, context_lens,
+            mesh, axis=head_axis, scale=scale,
         )
-    ):
-        if tp > 1:
-            return sharded_paged_decode_attention(
-                q, k_cache, v_cache, block_tables, context_lens,
-                mesh, axis=head_axis, scale=scale,
-            )
+    if tp == 1:
         return paged_decode_attention(
             q, k_cache, v_cache, block_tables, context_lens, scale=scale
         )
@@ -274,34 +313,19 @@ def append_attention_core(
     fixed-shape padding queries (they emit zeros). Decode-mode attention
     is the W = 1 special case.
 
-    Dispatches to the generalized Pallas paged kernel on TPU backends
-    (kernels/decode_attention.py) and to the XLA gather + masked softmax
-    composition elsewhere. ``mesh`` with a >1 ``head_axis`` selects the
-    head-sharded shard_map kernel path (see
-    :func:`decode_attention_core`).
+    Dispatch as in :func:`decode_attention_core`; windows past the
+    kernel's bound (suffix-prefill buckets) take the XLA composition.
     """
-    from .kernels.decode_attention import (
-        on_tpu,
-        paged_append_attention,
-        reference_paged_append_attention,
-        sharded_paged_append_attention,
-        supports_append_shapes,
+    tp = _paged_kernel_tp(
+        "paged_append_attention", backend, mesh, head_axis,
+        q.shape[2], q.shape[3], k_cache, q.shape[1],
     )
-
-    tp = 1 if mesh is None else int(dict(mesh.shape).get(head_axis, 1))
-    if (
-        backend == "tpu"
-        and on_tpu()
-        and q.shape[2] % max(1, tp) == 0
-        and supports_append_shapes(
-            q.shape[2] // max(1, tp), q.shape[3], k_cache.shape[1], q.shape[1]
+    if tp > 1:
+        return sharded_paged_append_attention(
+            q, k_cache, v_cache, block_tables, q_positions,
+            mesh, axis=head_axis, scale=scale,
         )
-    ):
-        if tp > 1:
-            return sharded_paged_append_attention(
-                q, k_cache, v_cache, block_tables, q_positions,
-                mesh, axis=head_axis, scale=scale,
-            )
+    if tp == 1:
         return paged_append_attention(
             q, k_cache, v_cache, block_tables, q_positions, scale=scale
         )

@@ -24,15 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax>=0.8 promotes shard_map out of experimental (check_rep -> check_vma)
-    from jax import shard_map as _shard_map  # type: ignore
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_rep)
-
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 NEG_INF = -1e30
 
 
@@ -137,12 +128,12 @@ def ring_attention_sharded(
     ba = batch_axis if batch_axis in mesh.axis_names else None
     ha = head_axis if head_axis in mesh.axis_names else None
     spec = P(ba, seq_axis, ha, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v)
 
@@ -193,11 +184,11 @@ def ulysses_attention_sharded(
 ) -> jax.Array:
     ba = batch_axis if batch_axis in mesh.axis_names else None
     spec = P(ba, seq_axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=seq_axis, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k, v)

@@ -194,10 +194,6 @@ class ExpertsOp(OpDef):
                     axis = cand
                     break
         if axis is not None:
-            try:
-                from jax import shard_map
-            except ImportError:
-                from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             def local(x, w1, b1, w2, b2):
@@ -207,7 +203,7 @@ class ExpertsOp(OpDef):
 
             ep = P(axis, None, None)
             e2 = P(axis, None)
-            y = shard_map(
+            y = jax.shard_map(
                 local,
                 mesh=mesh,
                 in_specs=(ep, ep, e2, ep, e2),

@@ -36,7 +36,8 @@ def build_mesh(
 
     Uses mesh_utils.create_device_mesh when the product covers all
     devices so the mesh layout follows the physical ICI torus (collectives
-    ride neighbor links); falls back to a simple reshape otherwise.
+    ride neighbor links) — a mesh it cannot lay out raises; a mesh over a
+    subset of the devices is a plain reshape.
     """
     sizes = {k: v for k, v in axis_sizes.items() if v > 1}
     if not sizes:
@@ -57,13 +58,9 @@ def build_mesh(
     shape = tuple(sizes[n] for n in names)
     use = list(devices)[:total]
     if total == len(devices):
-        try:
-            from jax.experimental import mesh_utils
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(shape, devices=use)
-            return Mesh(dev_array, names)
-        except Exception:
-            pass
+        return Mesh(mesh_utils.create_device_mesh(shape, devices=use), names)
     return Mesh(np.asarray(use).reshape(shape), names)
 
 

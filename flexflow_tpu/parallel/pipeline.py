@@ -102,11 +102,6 @@ def gpipe(
     The returned function must be called under jit with ``mesh`` active
     (shard_map handles the collectives).
     """
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     n_stages = mesh.shape[axis]
 
     def pipelined(stacked_params, x, shared=None):
@@ -179,24 +174,22 @@ def gpipe(
             outs0 = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), xs_local)
             # rank-1, not scalar: a 0-d aux residual crossing the
             # shard_map fwd/bwd partial-eval split trips _check_names
-            # (jax 0.4.x promotes scalar residuals on only some paths —
-            # residual out_names {0: axes} is invalid for ndim-0), which
+            # (residual out_names {0: axes} is invalid for ndim-0), which
             # surfaced as a _SpecError under jax.grad of pipelined MoE
             aux0 = jnp.zeros((1,), jnp.float32)
-            if hasattr(jax.lax, "pcast"):
-                # newer shard_map tracks varying manual axes: each carry
-                # leaf must enter the scan with the variance it will have
-                # after a tick — {pipe} ∪ the axes ITS spec shards over
-                # (data for the batch dim; seq in pp x cp). The banked
-                # outs pick up the same per-leaf axes (they hold copies
-                # of the rotating values) plus pipe.
-                vary_leaf = lambda a, sp: jax.lax.pcast(
-                    a, (axis,) + _spec_axes(sp), to="varying"
-                )
-                act0 = jax.tree.map(vary_leaf, act0, xs_spec)
-                shr0 = jax.tree.map(vary_leaf, shr0, ss_spec)
-                outs0 = jax.tree.map(vary_leaf, outs0, xs_spec)
-                aux0 = jax.lax.pcast(aux0, (axis,) + all_axes, to="varying")
+            # shard_map tracks varying manual axes: each carry leaf must
+            # enter the scan with the variance it will have after a tick
+            # — {pipe} ∪ the axes ITS spec shards over (data for the
+            # batch dim; seq in pp x cp). The banked outs pick up the
+            # same per-leaf axes (they hold copies of the rotating
+            # values) plus pipe.
+            vary_leaf = lambda a, sp: jax.lax.pcast(
+                a, (axis,) + _spec_axes(sp), to="varying"
+            )
+            act0 = jax.tree.map(vary_leaf, act0, xs_spec)
+            shr0 = jax.tree.map(vary_leaf, shr0, ss_spec)
+            outs0 = jax.tree.map(vary_leaf, outs0, xs_spec)
+            aux0 = jax.lax.pcast(aux0, (axis,) + all_axes, to="varying")
 
             def tick(carry, t):
                 act, shr, outs, aux_acc = carry
@@ -269,7 +262,7 @@ def gpipe(
             else jax.tree.map(lambda _: PartitionSpec(axis), stacked_params)
         )
         out_specs = (xs_spec, PartitionSpec()) if with_aux else xs_spec
-        result = shard_map(
+        result = jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(specs_params, xs_spec, ss_spec),

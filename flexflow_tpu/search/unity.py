@@ -820,19 +820,16 @@ def _detected_chip(honest_cpu: bool = False):
     validation must never compare a TPU roofline against a CPU wall
     clock — VERDICT r2 weak #2); the default keeps the v5p-ish preset so
     searches in CPU test runs still optimize for TPU-shaped costs."""
+    import jax
+
     from ..parallel.machine import TPUChipSpec
-    from .calibration import chip_spec_for
+    from .calibration import chip_spec_for, detected_device_kind
 
-    try:
-        import jax
-
-        if jax.default_backend() != "cpu":
-            return chip_spec_for(getattr(jax.devices()[0], "device_kind", ""))
-        if honest_cpu:
-            return chip_spec_for("cpu")
-    except Exception:
-        pass
-    return TPUChipSpec()
+    if jax.default_backend() != "cpu":
+        # a device kind with no preset raises: no search runs against
+        # another chip's peaks
+        return chip_spec_for(detected_device_kind())
+    return chip_spec_for("cpu") if honest_cpu else TPUChipSpec()
 
 
 def predict_step_time(

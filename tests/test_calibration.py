@@ -54,7 +54,9 @@ def test_measure_lowered_op_returns_time():
 
 def test_calibrate_and_roundtrip(tmp_path, monkeypatch):
     monkeypatch.setenv("FLEXFLOW_TPU_CACHE", str(tmp_path))
-    cal = calibrate(device_kind="test-chip", suite=tiny_suite(), save=True)
+    cal = calibrate(
+        MachineSpec(), device_kind="test-chip", suite=tiny_suite(), save=True
+    )
     assert cal.entries, "calibration produced no measurements"
     assert set(cal.derates) <= {"matmul", "memory"}
     assert all(r > 0 for r in cal.derates.values())
@@ -99,7 +101,9 @@ def test_chip_spec_detection():
     assert chip_spec_for("TPU v5p").name == "v5p"
     assert chip_spec_for("TPU v4").name == "v4"
     assert chip_spec_for("TPU v6e").name == "v6e"
-    assert chip_spec_for("weird future chip").name == "v5p"  # conservative default
+    # an unknown device is an error, not another chip's peaks
+    with pytest.raises(ValueError, match="weird future chip"):
+        chip_spec_for("weird future chip")
 
 
 def test_predict_step_time_ranks_strategies():
@@ -302,12 +306,12 @@ def test_unresolved_suite_op_recorded_loudly(monkeypatch, tmp_path):
 def test_v5e_table_predicts_measured_bert_step_times(monkeypatch, tmp_path):
     """Non-circular cost-model validation (VERDICT r4 weak #3): the
     committed v5e slope-capture table must predict the five measured
-    round-5 on-chip BERT step times within the demanded [0.3, 3] band —
-    actual agreement is 0.87-0.97 (BENCH_TPU_evidence_r5.json). Guards
+    on-chip BERT step times (July capture, pinned below) within the
+    demanded [0.3, 3] band — actual agreement is 0.87-0.97. Guards
     the cost model, the simulator, AND the table against regressions
     that would silently break the search's premise."""
-    # pin to the COMMITTED factory table: load_calibration prefers the
-    # user cache, where a stale capture would shadow what this test pins
+    # pin to the COMMITTED factory table: a FLEXFLOW_TPU_CACHE leaked in
+    # from the environment would shadow what this test pins
     monkeypatch.setenv("FLEXFLOW_TPU_CACHE", str(tmp_path))
     from flexflow_tpu import DataType, FFConfig
     from flexflow_tpu.models import TransformerConfig, build_transformer

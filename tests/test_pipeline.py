@@ -832,10 +832,7 @@ def test_dropout_mask_decorrelated_across_manual_shards():
     shards. shard_rng folds the axis indices in."""
     from functools import partial
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from flexflow_tpu.ops.base import LowerCtx

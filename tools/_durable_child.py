@@ -21,6 +21,8 @@ import os
 import sys
 import time
 
+# always a CPU process (chaoscheck also passes this): it is started by a
+# parent that may hold the chip
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)

@@ -30,6 +30,8 @@ def force_host_devices(n: int) -> None:
     parts = [p for p in parts if not p.startswith(f"--{_FLAG}=")]
     parts.append(f"--{_FLAG}={n}")
     os.environ["XLA_FLAGS"] = " ".join(parts)
+    # replaces this process before JAX has initialized a backend, so no
+    # chip is held across the exec
     os.execv(sys.executable, [sys.executable] + sys.argv)
 
 

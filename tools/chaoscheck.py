@@ -856,6 +856,8 @@ def run_durable_sweep() -> bool:
         [sys.executable, os.path.join(REPO, "tools", "_durable_child.py"),
          sigkill_dir],
         stdout=subprocess.PIPE, text=True,
+        # the victim is pinned to the CPU: a child that asked for the
+        # chip while this process holds it would fail or hang
         env=dict(os.environ, JAX_PLATFORMS="cpu"),
     )
     killed, child_done, deadline = False, False, time.monotonic() + 300
@@ -1832,6 +1834,8 @@ def main() -> int:
             "-p", "no:cacheprovider",
             *pytest_args,
         ]
+        # the pytest legs are CPU tests (tests/conftest.py pins them
+        # too): the child never needs a chip this process may hold
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         rc = subprocess.call(cmd, cwd=REPO, env=env)
     if not args.no_sweep and rc == 0:

@@ -1412,6 +1412,9 @@ def trace_overhead_bench(args, cfg, params) -> tuple:
 
 
 def main() -> int:
+    from flexflow_tpu.device import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="")
     ap.add_argument("--requests", type=int, default=12)

@@ -744,6 +744,10 @@ def main() -> int:
             return 2
         resolve_schedule(args)
         return 0
+    if not args.url:  # --url drives someone else's server and compiles nothing
+        from flexflow_tpu.device import enable_compile_cache
+
+        enable_compile_cache()
     if args.disagg_ab:
         report = run_disagg_ab(args)
     elif args.url:
