@@ -40,7 +40,7 @@ _PEAK_BF16 = [
 def train_flops_per_token(n_params: int, num_layers: int, seq_length: int, hidden_size: int) -> float:
     """6N (fwd+bwd matmul FLOPs per token) + attention score/value
     matmuls 12*L*S*H — the PaLM-appendix-style accounting; 6N alone
-    undercounts the work. Shared with tools/mfu_profile.py."""
+    undercounts the work (``benchmark/stats.py`` holds the same)."""
     return 6.0 * n_params + 12.0 * num_layers * seq_length * hidden_size
 
 

@@ -18,9 +18,8 @@ from typing import Dict, FrozenSet, Union
 # injectable clock so virtual-clock tests control time.
 CLOCK_WHITELIST: Dict[str, Union[str, FrozenSet[str]]] = {
     # Offline bench/diagnostic harnesses: measuring physical wall time
-    # is their job (genbench/perfwatch/chaoscheck/obsreport/calib_debug
-    # / mfu_profile), and their watchdog waits bound
-    # real blocking calls.
+    # is their job (genbench/perfwatch/chaoscheck/obsreport/calib_debug),
+    # and their watchdog waits bound real blocking calls.
     "tools/": "*",
     # Kernel calibration measures device wall time by definition.
     "flexflow_tpu/search/calibration.py": "*",
@@ -29,18 +28,21 @@ CLOCK_WHITELIST: Dict[str, Union[str, FrozenSet[str]]] = {
     # PR 6 dual-stamp decision: device-step phase DURATIONS are
     # physical profiling data (perf_counter) even in virtual-clock
     # tests; scheduler-plane timestamps still ride the injectable
-    # clock. Only perf_counter is exempt — time.time/monotonic in these
-    # files is still a violation.
-    "flexflow_tpu/generation/engine.py": frozenset({"perf_counter"}),
+    # clock. Host spans are opened through obs/steptrace.phase (below),
+    # so the engine, the executor, the prefix cache, the HTTP handler
+    # and the data loader read no clock of their own; what is left here
+    # is the scheduler's iteration wall and the device-lane "execute"
+    # stamp, which are not host spans. Only perf_counter is exempt —
+    # time.time/monotonic in this file is still a violation.
     "flexflow_tpu/generation/scheduler.py": frozenset({"perf_counter"}),
-    "flexflow_tpu/runtime/executor.py": frozenset({"perf_counter"}),
     # Grammar-compile telemetry (ISSUE 18): compile_seconds is physical
     # profiling data like the engine's phase spans — perf_counter only.
     "flexflow_tpu/generation/constrained/tokens.py": frozenset({"perf_counter"}),
-    # Step-anatomy profiler (ISSUE 12): perf_counter-only physical
-    # profiling per the PR 6 dual-clock decision — it aggregates the
-    # engine/scheduler perf_counter span stamps and must never mix in
-    # the scheduler's injectable (possibly virtual) clock.
+    # Step-anatomy profiler (ISSUE 12) and phase(), the one way a span
+    # is opened (ISSUE 23): perf_counter-only physical profiling per
+    # the PR 6 dual-clock decision — phase() stamps every host span,
+    # StepAnatomy aggregates the stamps, and neither may mix in the
+    # scheduler's injectable (possibly virtual) clock.
     "flexflow_tpu/obs/steptrace.py": frozenset({"perf_counter"}),
     # Durable WAL (ISSUE 19): fsync DURATION is physical profiling data
     # (perf_counter only). Journal-record wall stamps ride the

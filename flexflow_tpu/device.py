@@ -2,7 +2,7 @@
 
 The one place that answers both. Kernel dispatch asks :func:`on_tpu`;
 anything that reports a device number (``chip_smoke.py``, ``bench.py``,
-``tools/mfu_profile.py``) calls :func:`require_tpu` and fails without a
+``benchmark/run.py``) calls :func:`require_tpu` and fails without a
 chip instead of measuring the CPU; every launcher calls
 :func:`enable_compile_cache` before its first jit.
 """

@@ -142,20 +142,25 @@ def prefill(
     """Prefill forward: logits [B, S, V] plus every layer's K/V
     ([L, B, S, H, D] each) for the engine to write into the cache."""
     b, s = tokens.shape
-    x = _embed(params, tokens, jnp.arange(s)[None, :])
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, jnp.arange(s)[None, :])
     ks, vs = [], []
-    for layer in params["layers"]:
-        h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-        q = jnp.einsum("bse,ehd->bshd", h, layer["wq"])
-        k = jnp.einsum("bse,ehd->bshd", h, layer["wk"])
-        v = jnp.einsum("bse,ehd->bshd", h, layer["wv"])
-        ks.append(k)
-        vs.append(v)
-        ctx = masked_attention(q, k, v, lengths, causal=True)
-        x = x + jnp.einsum("bshd,hde->bse", ctx, layer["wo"])
-        x = _ffn(layer, x)
-    x = _ln(x, params["final_ln_g"], params["final_ln_b"])
-    return x @ params["lm_head"], jnp.stack(ks), jnp.stack(vs)
+    for li, layer in enumerate(params["layers"]):
+        with jax.named_scope(f"layer{li}"):
+            with jax.named_scope("attention"):
+                h = _ln(x, layer["ln1_g"], layer["ln1_b"])
+                q = jnp.einsum("bse,ehd->bshd", h, layer["wq"])
+                k = jnp.einsum("bse,ehd->bshd", h, layer["wk"])
+                v = jnp.einsum("bse,ehd->bshd", h, layer["wv"])
+                ks.append(k)
+                vs.append(v)
+                ctx = masked_attention(q, k, v, lengths, causal=True)
+                x = x + jnp.einsum("bshd,hde->bse", ctx, layer["wo"])
+            with jax.named_scope("mlp"):
+                x = _ffn(layer, x)
+    with jax.named_scope("head"):
+        x = _ln(x, params["final_ln_g"], params["final_ln_b"])
+        return x @ params["lm_head"], jnp.stack(ks), jnp.stack(vs)
 
 
 def decode_step(
@@ -179,29 +184,38 @@ def decode_step(
     Returns (logits [B, V], cache_k, cache_v) with the K/V written.
     """
     nb, bs = cache_k.shape[1], cache_k.shape[2]
-    x = _embed(params, tokens, positions)  # [B, E]
-    slots = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, positions)
+    with jax.named_scope("embed"):
+        x = _embed(params, tokens, positions)  # [B, E]
+        slots = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, positions)
     for li, layer in enumerate(params["layers"]):
-        h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-        q = jnp.einsum("be,ehd->bhd", h, layer["wq"])
-        k = jnp.einsum("be,ehd->bhd", h, layer["wk"])
-        v = jnp.einsum("be,ehd->bhd", h, layer["wv"])
-        # write this token's K/V, then attend over the updated cache so
-        # the token sees itself (context_lens includes it)
-        flat_k = cache_k[li].reshape(nb * bs, *cache_k.shape[3:])
-        flat_v = cache_v[li].reshape(nb * bs, *cache_v.shape[3:])
-        flat_k = flat_k.at[slots].set(k.astype(flat_k.dtype))
-        flat_v = flat_v.at[slots].set(v.astype(flat_v.dtype))
-        cache_k = cache_k.at[li].set(flat_k.reshape(cache_k.shape[1:]))
-        cache_v = cache_v.at[li].set(flat_v.reshape(cache_v.shape[1:]))
-        ctx = decode_attention_core(
-            q, cache_k[li], cache_v[li], block_tables, context_lens,
-            backend=backend, mesh=mesh,
-        )
-        x = x + jnp.einsum("bhd,hde->be", ctx, layer["wo"])
-        x = _ffn(layer, x)
-    x = _ln(x, params["final_ln_g"], params["final_ln_b"])
-    return x @ params["lm_head"], cache_k, cache_v
+        # scope names land in the instructions' op_name: a device trace
+        # can be grouped by them (layer<i>/attention | cache_write | mlp)
+        with jax.named_scope(f"layer{li}"):
+            with jax.named_scope("attention"):
+                h = _ln(x, layer["ln1_g"], layer["ln1_b"])
+                q = jnp.einsum("be,ehd->bhd", h, layer["wq"])
+                k = jnp.einsum("be,ehd->bhd", h, layer["wk"])
+                v = jnp.einsum("be,ehd->bhd", h, layer["wv"])
+            # write this token's K/V, then attend over the updated cache
+            # so the token sees itself (context_lens includes it)
+            with jax.named_scope("cache_write"):
+                flat_k = cache_k[li].reshape(nb * bs, *cache_k.shape[3:])
+                flat_v = cache_v[li].reshape(nb * bs, *cache_v.shape[3:])
+                flat_k = flat_k.at[slots].set(k.astype(flat_k.dtype))
+                flat_v = flat_v.at[slots].set(v.astype(flat_v.dtype))
+                cache_k = cache_k.at[li].set(flat_k.reshape(cache_k.shape[1:]))
+                cache_v = cache_v.at[li].set(flat_v.reshape(cache_v.shape[1:]))
+            with jax.named_scope("attention"):
+                ctx = decode_attention_core(
+                    q, cache_k[li], cache_v[li], block_tables, context_lens,
+                    backend=backend, mesh=mesh,
+                )
+                x = x + jnp.einsum("bhd,hde->be", ctx, layer["wo"])
+            with jax.named_scope("mlp"):
+                x = _ffn(layer, x)
+    with jax.named_scope("head"):
+        x = _ln(x, params["final_ln_g"], params["final_ln_b"])
+        return x @ params["lm_head"], cache_k, cache_v
 
 
 def verify_step(
@@ -232,30 +246,37 @@ def verify_step(
     overwrites before any masked read can see it.
     """
     nb, bs = cache_k.shape[1], cache_k.shape[2]
-    safe_pos = jnp.maximum(positions, 0)
-    x = _embed(params, tokens, safe_pos)  # [B, W, E]
-    slots = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, safe_pos)
-    slots = jnp.where(positions >= 0, slots, 0)  # padding -> scratch
-    flat_slots = slots.reshape(-1)
+    with jax.named_scope("embed"):
+        safe_pos = jnp.maximum(positions, 0)
+        x = _embed(params, tokens, safe_pos)  # [B, W, E]
+        slots = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, safe_pos)
+        slots = jnp.where(positions >= 0, slots, 0)  # padding -> scratch
+        flat_slots = slots.reshape(-1)
     for li, layer in enumerate(params["layers"]):
-        h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-        q = jnp.einsum("bwe,ehd->bwhd", h, layer["wq"])
-        k = jnp.einsum("bwe,ehd->bwhd", h, layer["wk"])
-        v = jnp.einsum("bwe,ehd->bwhd", h, layer["wv"])
-        # write the whole window's K/V, then attend over the updated
-        # cache with per-query position masks (each token sees itself
-        # and everything before it, nothing after)
-        flat_k = cache_k[li].reshape(nb * bs, *cache_k.shape[3:])
-        flat_v = cache_v[li].reshape(nb * bs, *cache_v.shape[3:])
-        flat_k = flat_k.at[flat_slots].set(k.reshape(-1, *k.shape[2:]).astype(flat_k.dtype))
-        flat_v = flat_v.at[flat_slots].set(v.reshape(-1, *v.shape[2:]).astype(flat_v.dtype))
-        cache_k = cache_k.at[li].set(flat_k.reshape(cache_k.shape[1:]))
-        cache_v = cache_v.at[li].set(flat_v.reshape(cache_v.shape[1:]))
-        ctx = append_attention_core(
-            q, cache_k[li], cache_v[li], block_tables, positions,
-            backend=backend, mesh=mesh,
-        )
-        x = x + jnp.einsum("bwhd,hde->bwe", ctx, layer["wo"])
-        x = _ffn(layer, x)
-    x = _ln(x, params["final_ln_g"], params["final_ln_b"])
-    return x @ params["lm_head"], cache_k, cache_v
+        with jax.named_scope(f"layer{li}"):
+            with jax.named_scope("attention"):
+                h = _ln(x, layer["ln1_g"], layer["ln1_b"])
+                q = jnp.einsum("bwe,ehd->bwhd", h, layer["wq"])
+                k = jnp.einsum("bwe,ehd->bwhd", h, layer["wk"])
+                v = jnp.einsum("bwe,ehd->bwhd", h, layer["wv"])
+            # write the whole window's K/V, then attend over the updated
+            # cache with per-query position masks (each token sees itself
+            # and everything before it, nothing after)
+            with jax.named_scope("cache_write"):
+                flat_k = cache_k[li].reshape(nb * bs, *cache_k.shape[3:])
+                flat_v = cache_v[li].reshape(nb * bs, *cache_v.shape[3:])
+                flat_k = flat_k.at[flat_slots].set(k.reshape(-1, *k.shape[2:]).astype(flat_k.dtype))
+                flat_v = flat_v.at[flat_slots].set(v.reshape(-1, *v.shape[2:]).astype(flat_v.dtype))
+                cache_k = cache_k.at[li].set(flat_k.reshape(cache_k.shape[1:]))
+                cache_v = cache_v.at[li].set(flat_v.reshape(cache_v.shape[1:]))
+            with jax.named_scope("attention"):
+                ctx = append_attention_core(
+                    q, cache_k[li], cache_v[li], block_tables, positions,
+                    backend=backend, mesh=mesh,
+                )
+                x = x + jnp.einsum("bwhd,hde->bwe", ctx, layer["wo"])
+            with jax.named_scope("mlp"):
+                x = _ffn(layer, x)
+    with jax.named_scope("head"):
+        x = _ln(x, params["final_ln_g"], params["final_ln_b"])
+        return x @ params["lm_head"], cache_k, cache_v

@@ -46,7 +46,6 @@ legacy engine.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -56,6 +55,7 @@ import numpy as np
 from ..device import on_tpu
 from ..models.transformer import TransformerConfig
 from ..obs.capacity import ProgramRegistry, ServingFlops
+from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
 from ..runtime import faults
 from .cache import BlockAllocator, CacheConfig, KVCache, slot_mapping
@@ -590,14 +590,16 @@ class GenerationEngine:
             flat = cache.reshape(nb * bs, *cache.shape[2:])
             return flat.at[slots].set(layer_kv.astype(flat.dtype)).reshape(cache.shape)
 
-        cache_k = jax.vmap(write)(cache_k, ks[:, 0])
-        cache_v = jax.vmap(write)(cache_v, vs[:, 0])
-        last = logits[0, length - 1]
-        ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
-        # grammar mask: additive [V] bias, 0 / NEG (finite — the ok gate
-        # above still sees model NaN, never the mask)
-        last = last + mask
-        token = _sample(last[None], temp[None], top_k[None], key[None])[0]
+        with jax.named_scope("cache_write"):
+            cache_k = jax.vmap(write)(cache_k, ks[:, 0])
+            cache_v = jax.vmap(write)(cache_v, vs[:, 0])
+        with jax.named_scope("sample"):
+            last = logits[0, length - 1]
+            ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
+            # grammar mask: additive [V] bias, 0 / NEG (finite — the ok
+            # gate above still sees model NaN, never the mask)
+            last = last + mask
+            token = _sample(last[None], temp[None], top_k[None], key[None])[0]
         return token, ok, cache_k, cache_v
 
     def _decode_impl(
@@ -620,12 +622,14 @@ class GenerationEngine:
         # mask is the grammar constraint: [B, V] additive rows of 0 / NEG
         # (finite, so it commutes with the poison semantics — the ok gate
         # trips on model/injected NaN, never on a banned token)
-        logits = logits + bias[:, None] + mask
-        ok = jnp.all(jnp.isfinite(logits), axis=-1)
-        # sampling keys derive in-jit from (seed, token count): no host
-        # fold_in/stack on the critical path, same key bits as before
-        keys = derive_keys(seeds, counts)
-        return _sample(logits, temps, top_ks, keys), ok, cache_k, cache_v
+        with jax.named_scope("sample"):
+            logits = logits + bias[:, None] + mask
+            ok = jnp.all(jnp.isfinite(logits), axis=-1)
+            # sampling keys derive in-jit from (seed, token count): no
+            # host fold_in/stack on the critical path, same key bits as
+            # before
+            keys = derive_keys(seeds, counts)
+            return _sample(logits, temps, top_ks, keys), ok, cache_k, cache_v
 
     def _verify_impl(
         self, params, tokens, start, n_draft, cache_k, cache_v, block_tables, temps, top_ks, bias, seeds, counts, mask
@@ -781,28 +785,28 @@ class GenerationEngine:
 
     # ----------------------------------------------------------- host API
     def _record_step_phases(
-        self, kind: str, t0: float, t_disp: float, t_exec: float
+        self, kind: str, disp: phase, block: phase, read: phase
     ) -> Tuple[float, float]:
-        """Stamp one step's dispatch/execute/readback split (called
-        after the result readback; stamps t_read itself) and publish
-        the spans for the scheduler's step-anatomy profiler. "block"
-        (host parked in block_until_ready) and "execute" (device
-        computing) cover the same interval today; they separate once
-        the overlap refactor dispatches ahead of the bookkeeping.
+        """Account one blocking step from its three host spans
+        (``ff.engine.<kind>.dispatch | block | readback``, opened
+        through obs/steptrace.phase) and publish them for the
+        scheduler's step-anatomy profiler. The device-lane "execute"
+        span is not host work and opens no annotation: in a blocking
+        step the device computes while the host sits in "block", so it
+        takes that span's stamps (the two diverge only under the
+        overlap pipeline). ``phase_time_s`` stays contiguous — each
+        phase runs to the start of the next — so its sum is the whole
+        call, as it has always been.
         Returns (total_elapsed_s, execute_s) — the old conflated total
-        and the device-only seconds the truth ledger now pairs."""
-        t_read = time.perf_counter()
+        and the device-only seconds the truth ledger pairs."""
         ph = self.phase_time_s[kind]
-        ph["dispatch"] += t_disp - t0
-        ph["execute"] += t_exec - t_disp
-        ph["readback"] += t_read - t_exec
+        ph["dispatch"] += block.t0 - disp.t0
+        ph["execute"] += block.seconds
+        ph["readback"] += read.t1 - block.t1
         self.last_step_spans = [
-            ("dispatch", t0, t_disp),
-            ("block", t_disp, t_exec),
-            ("execute", t_disp, t_exec),
-            ("readback", t_exec, t_read),
+            disp.span, block.span, ("execute", block.t0, block.t1), read.span,
         ]
-        return t_read - t0, t_exec - t_disp
+        return read.t1 - disp.t0, block.seconds
 
     def prefill_one(
         self,
@@ -826,33 +830,33 @@ class GenerationEngine:
         if prefix_len > 0:
             return self._prefill_suffix(prompt, block_table, sampling, key, prefix_len, mask)
         self.step_counts["prefill"] += 1
-        t0 = time.perf_counter()
-        n = len(prompt)
-        bucket = self.bucket_for(n)
-        traces_before = self.trace_counts.get(f"prefill[{bucket}]", 0)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :n] = prompt
-        table = np.zeros((self.max_blocks_per_seq,), np.int32)
-        table[: len(block_table)] = block_table
-        token, ok, ck, cv = self._prefill_jit(
-            self.params,
-            self._dev(tokens),
-            jnp.int32(n),
-            self.cache.k,
-            self.cache.v,
-            self._dev(table),
-            jnp.float32(sampling.temperature),
-            jnp.int32(sampling.top_k),
-            self._dev(key),
-            self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
-        )
-        t_disp = time.perf_counter()
-        jax.block_until_ready((token, ok, ck, cv))  # device execution done
-        t_exec = time.perf_counter()
-        self.cache.update(ck, cv)
-        self.last_finite = np.asarray(ok).reshape(1)
-        out = int(token)  # result sync lands inside the readback span
-        elapsed, execute_s = self._record_step_phases("prefill", t0, t_disp, t_exec)
+        with phase("engine.prefill.dispatch") as disp:
+            n = len(prompt)
+            bucket = self.bucket_for(n)
+            traces_before = self.trace_counts.get(f"prefill[{bucket}]", 0)
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :n] = prompt
+            table = np.zeros((self.max_blocks_per_seq,), np.int32)
+            table[: len(block_table)] = block_table
+            token, ok, ck, cv = self._prefill_jit(
+                self.params,
+                self._dev(tokens),
+                jnp.int32(n),
+                self.cache.k,
+                self.cache.v,
+                self._dev(table),
+                jnp.float32(sampling.temperature),
+                jnp.int32(sampling.top_k),
+                self._dev(key),
+                self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
+            )
+        with phase("engine.prefill.block") as block:
+            jax.block_until_ready((token, ok, ck, cv))  # device execution done
+        with phase("engine.prefill.readback") as read:
+            self.cache.update(ck, cv)
+            self.last_finite = np.asarray(ok).reshape(1)
+            out = int(token)  # result sync lands inside the readback span
+        elapsed, execute_s = self._record_step_phases("prefill", disp, block, read)
         # FLOPs accrue only on SUCCESS, next to the time they pair with:
         # a step that raises (and is retried by the supervisor) must not
         # count its FLOPs without its time, or MFU inflates under faults
@@ -898,36 +902,36 @@ class GenerationEngine:
         prefill(): step/FLOPs/time under the "prefill" kind, compile
         calls registry-stamped, steady calls ledger-paired."""
         self.step_counts["prefill"] += 1
-        t0 = time.perf_counter()
-        n = len(prompt)
-        suffix = list(prompt[prefix_len:])
-        w = self.bucket_for(len(suffix))
-        name = f"prefix_prefill[{w}]"
-        traces_before = self.trace_counts.get(name, 0)
-        tokens = np.zeros((1, w), np.int32)
-        tokens[0, : len(suffix)] = suffix
-        table = np.zeros((self.max_blocks_per_seq,), np.int32)
-        table[: len(block_table)] = block_table
-        token, ok, ck, cv = self._prefix_prefill_jit(
-            self.params,
-            self._dev(tokens),
-            jnp.int32(prefix_len),
-            jnp.int32(len(suffix)),
-            self.cache.k,
-            self.cache.v,
-            self._dev(table),
-            jnp.float32(sampling.temperature),
-            jnp.int32(sampling.top_k),
-            self._dev(key),
-            self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
-        )
-        t_disp = time.perf_counter()
-        jax.block_until_ready((token, ok, ck, cv))  # device execution done
-        t_exec = time.perf_counter()
-        self.cache.update(ck, cv)
-        self.last_finite = np.asarray(ok).reshape(1)
-        out = int(token)  # result sync lands inside the readback span
-        elapsed, execute_s = self._record_step_phases("prefill", t0, t_disp, t_exec)
+        with phase("engine.prefill.dispatch") as disp:
+            n = len(prompt)
+            suffix = list(prompt[prefix_len:])
+            w = self.bucket_for(len(suffix))
+            name = f"prefix_prefill[{w}]"
+            traces_before = self.trace_counts.get(name, 0)
+            tokens = np.zeros((1, w), np.int32)
+            tokens[0, : len(suffix)] = suffix
+            table = np.zeros((self.max_blocks_per_seq,), np.int32)
+            table[: len(block_table)] = block_table
+            token, ok, ck, cv = self._prefix_prefill_jit(
+                self.params,
+                self._dev(tokens),
+                jnp.int32(prefix_len),
+                jnp.int32(len(suffix)),
+                self.cache.k,
+                self.cache.v,
+                self._dev(table),
+                jnp.float32(sampling.temperature),
+                jnp.int32(sampling.top_k),
+                self._dev(key),
+                self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
+            )
+        with phase("engine.prefill.block") as block:
+            jax.block_until_ready((token, ok, ck, cv))  # device execution done
+        with phase("engine.prefill.readback") as read:
+            self.cache.update(ck, cv)
+            self.last_finite = np.asarray(ok).reshape(1)
+            out = int(token)  # result sync lands inside the readback span
+        elapsed, execute_s = self._record_step_phases("prefill", disp, block, read)
         # useful work = suffix tokens only, each attending its full live
         # context (causal): ctx = sum_{p=prefix_len}^{n-1} (p + 1)
         ctx = (n * (n + 1) - prefix_len * (prefix_len + 1)) // 2
@@ -1097,27 +1101,27 @@ class GenerationEngine:
         pc = self.prefix_cache
         predicted = pc.swap_in_cost_s(1)
         traces_before = self.trace_counts.get("kv_block_write", 0)
-        t0 = time.perf_counter()
-        try:
-            faults.inject(faults.GENERATION_KV_OFFLOAD, ("in", 1))
-            buf = pc.take_host_copy(entry)
-            if buf is None:  # corrupted or already dropped
-                raise ValueError("host-tier block failed CRC verification")
-            hk, hv = buf
-            ck, cv = self._write_block_jit(
-                self.cache.k, self.cache.v, jnp.int32(dst),
-                self._dev(hk), self._dev(hv),
-            )
-            self.cache.update(ck, cv)
-        except Exception:
-            pc.swap_in_failures += 1
-            pc.recompute_fallbacks += 1
-            return False
-        pc.note_swapped_in(entry, dst)
-        elapsed = time.perf_counter() - t0
+        with phase("cache.restore") as restore:
+            try:
+                faults.inject(faults.GENERATION_KV_OFFLOAD, ("in", 1))
+                buf = pc.take_host_copy(entry)
+                if buf is None:  # corrupted or already dropped
+                    raise ValueError("host-tier block failed CRC verification")
+                hk, hv = buf
+                ck, cv = self._write_block_jit(
+                    self.cache.k, self.cache.v, jnp.int32(dst),
+                    self._dev(hk), self._dev(hv),
+                )
+                self.cache.update(ck, cv)
+            except Exception:
+                pc.swap_in_failures += 1
+                pc.recompute_fallbacks += 1
+                return False
+            pc.note_swapped_in(entry, dst)
+        pc.observe("cache_restore", restore.seconds)
         if self.trace_counts.get("kv_block_write", 0) == traces_before:
             self.ledger.observe(
-                "kv_swap_in", predicted, elapsed,
+                "kv_swap_in", predicted, restore.seconds,
                 label="kv_swap_in (host tier)",
                 provenance="host-tier transfer model (link bytes/s)",
                 alarm=self._roofline_alarm,
@@ -1221,7 +1225,13 @@ class GenerationEngine:
             )
             return np.asarray(k), np.asarray(v)
 
-        return self.prefix_cache.reclaim(max(1, n_blocks), read)
+        # one span per call: victim selection, the device reads and the
+        # CRCs are all what evicting to the host tier costs an admission
+        with phase("cache.offload", blocks=n_blocks) as offload:
+            freed = self.prefix_cache.reclaim(max(1, n_blocks), read)
+        if freed:
+            self.prefix_cache.observe("cache_offload", offload.seconds)
+        return freed
 
     def pack_kv_blocks(
         self, table: List[int], n_positions: int
@@ -1352,20 +1362,20 @@ class GenerationEngine:
             # wedge like any device work — chaos plans target it here
             faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
         self.step_counts["decode"] += 1
-        t0 = time.perf_counter()
-        traces_before = self.trace_counts.get("decode", 0)
-        args, context_lens = self._decode_args(
-            positions, block_tables, active, temps, top_ks, seeds,
-            counts, bias, mask,
-        )
-        out, ok, ck, cv = self._decode_jit(self.params, self._dev(masked), *args)
-        t_disp = time.perf_counter()
-        jax.block_until_ready((out, ok, ck, cv))  # device execution done
-        t_exec = time.perf_counter()
-        self.cache.update(ck, cv)
-        self.last_finite = np.asarray(ok)
-        result = np.asarray(out)  # result sync lands in the readback span
-        elapsed, execute_s = self._record_step_phases("decode", t0, t_disp, t_exec)
+        with phase("engine.decode.dispatch") as disp:
+            traces_before = self.trace_counts.get("decode", 0)
+            args, context_lens = self._decode_args(
+                positions, block_tables, active, temps, top_ks, seeds,
+                counts, bias, mask,
+            )
+            out, ok, ck, cv = self._decode_jit(self.params, self._dev(masked), *args)
+        with phase("engine.decode.block") as block:
+            jax.block_until_ready((out, ok, ck, cv))  # device execution done
+        with phase("engine.decode.readback") as read:
+            self.cache.update(ck, cv)
+            self.last_finite = np.asarray(ok)
+            result = np.asarray(out)  # result sync lands in the readback span
+        elapsed, execute_s = self._record_step_phases("decode", disp, block, read)
         # success-only, paired with the time below (see prefill())
         n_active, ctx_sum = int(active.sum()), int(context_lens.sum())
         self._account_decode(
@@ -1445,24 +1455,23 @@ class GenerationEngine:
         if self.tp_degree > 1:
             faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
         self.step_counts["decode"] += 1
-        t0 = time.perf_counter()
-        traces_before = self.trace_counts.get("decode", 0)
-        args, context_lens = self._decode_args(
-            positions, block_tables, active, temps, top_ks, seeds,
-            counts, bias, mask,
-        )
-        tok_arg = tokens_dev if tokens_dev is not None else self._dev(masked)
-        prev_k, prev_v = (None, None) if self.donate else (self.cache.k, self.cache.v)
-        out, ok, ck, cv = self._decode_jit(self.params, tok_arg, *args)
-        t_disp = time.perf_counter()
+        with phase("engine.decode.dispatch") as disp:
+            traces_before = self.trace_counts.get("decode", 0)
+            args, context_lens = self._decode_args(
+                positions, block_tables, active, temps, top_ks, seeds,
+                counts, bias, mask,
+            )
+            tok_arg = tokens_dev if tokens_dev is not None else self._dev(masked)
+            prev_k, prev_v = (None, None) if self.donate else (self.cache.k, self.cache.v)
+            out, ok, ck, cv = self._decode_jit(self.params, tok_arg, *args)
         # start the device->host copies NOW; consume_decode's numpy
         # conversion then finds the bytes already resident
         out.copy_to_host_async()
         ok.copy_to_host_async()
         self.cache.update(ck, cv)
-        self.phase_time_s["decode"]["dispatch"] += t_disp - t0
+        self.phase_time_s["decode"]["dispatch"] += disp.seconds
         return InFlightDecode(
-            out, ok, prev_k, prev_v, ck, cv, t0, t_disp,
+            out, ok, prev_k, prev_v, ck, cv, disp.t0, disp.t1,
             traced=self.trace_counts.get("decode", 0) > traces_before,
             n_active=int(active.sum()), ctx_sum=int(context_lens.sum()),
         )
@@ -1477,37 +1486,36 @@ class GenerationEngine:
         if step.consumed:
             raise RuntimeError("InFlightDecode consumed twice")
         step.consumed = True
-        t_block = time.perf_counter()
-        try:
-            jax.block_until_ready((step.out, step.ok))
-        except Exception:
-            if step.prev_k is not None:
-                # roll the cache back to the pre-step refs: the failed
-                # program's outputs (and any successor chained on them)
-                # are poisoned, while the inputs are still intact. A
-                # successor's own discard must NOT restore forward over
-                # this (it checks its outputs are still current).
-                self.cache.update(step.prev_k, step.prev_v)
-            raise
-        t_exec = time.perf_counter()
-        self.last_finite = np.asarray(step.ok)
-        result = np.asarray(step.out)  # async copy already landed
-        t_read = time.perf_counter()
+        with phase("engine.decode.block") as block:
+            try:
+                jax.block_until_ready((step.out, step.ok))
+            except Exception:
+                if step.prev_k is not None:
+                    # roll the cache back to the pre-step refs: the
+                    # failed program's outputs (and any successor
+                    # chained on them) are poisoned, while the inputs
+                    # are still intact. A successor's own discard must
+                    # NOT restore forward over this (it checks its
+                    # outputs are still current).
+                    self.cache.update(step.prev_k, step.prev_v)
+                raise
+        with phase("engine.decode.readback") as read:
+            self.last_finite = np.asarray(step.ok)
+            result = np.asarray(step.out)  # async copy already landed
+        t_exec = block.t1
         ph = self.phase_time_s["decode"]
         ph["execute"] += t_exec - step.t_started
-        ph["readback"] += t_read - t_exec
+        ph["readback"] += read.t1 - t_exec
         # two-lane spans: "execute" starts at t_started (when the device
         # actually began this step — restamped by the scheduler at the
         # previous step's completion), "block" is only the host's park
         # inside THIS call. The lanes genuinely diverge under overlap.
         self.last_step_spans = [
-            ("block", t_block, t_exec),
-            ("execute", step.t_started, t_exec),
-            ("readback", t_exec, t_read),
+            block.span, ("execute", step.t_started, t_exec), read.span,
         ]
         self._account_decode(
             step.n_active, step.ctx_sum, step.traced,
-            elapsed=t_read - step.t0,
+            elapsed=read.t1 - step.t0,
             execute_s=t_exec - step.t_started,
         )
         return result
@@ -1575,33 +1583,33 @@ class GenerationEngine:
         live = n_draft >= 0
         w_tok = np.where(live, nd + 1, 0)
         ctx = np.where(live, w_tok * (start.astype(np.int64) + 1) + nd * (nd + 1) // 2, 0)
-        t0 = time.perf_counter()
-        traces_before = self.trace_counts.get("verify", 0)
-        out, n_emitted, ok, ck, cv = self._verify_jit(
-            self.params,
-            self._dev(window),
-            self._dev(start.astype(np.int32)),
-            self._dev(n_draft.astype(np.int32)),
-            self.cache.k,
-            self.cache.v,
-            self._stage("verify.tables", block_tables.astype(np.int32)),
-            self._stage("verify.temps", temps.astype(np.float32)),
-            self._stage("verify.top_ks", top_ks.astype(np.int32)),
-            self._bias_arg(bias),
-            self._stage("verify.seeds", seeds.astype(np.uint32)),
-            self._dev(counts.astype(np.int32)),
-            self._mask_arg(
-                mask, "verify_mask",
-                (self.max_batch_slots, self.spec_window, self.cfg.vocab_size),
-            ),
-        )
-        t_disp = time.perf_counter()
-        jax.block_until_ready((out, n_emitted, ok, ck, cv))  # execution done
-        t_exec = time.perf_counter()
-        self.cache.update(ck, cv)
-        self.last_finite = np.asarray(ok)
-        result = (np.asarray(out), np.asarray(n_emitted))
-        elapsed, execute_s = self._record_step_phases("verify", t0, t_disp, t_exec)
+        with phase("engine.verify.dispatch") as disp:
+            traces_before = self.trace_counts.get("verify", 0)
+            out, n_emitted, ok, ck, cv = self._verify_jit(
+                self.params,
+                self._dev(window),
+                self._dev(start.astype(np.int32)),
+                self._dev(n_draft.astype(np.int32)),
+                self.cache.k,
+                self.cache.v,
+                self._stage("verify.tables", block_tables.astype(np.int32)),
+                self._stage("verify.temps", temps.astype(np.float32)),
+                self._stage("verify.top_ks", top_ks.astype(np.int32)),
+                self._bias_arg(bias),
+                self._stage("verify.seeds", seeds.astype(np.uint32)),
+                self._dev(counts.astype(np.int32)),
+                self._mask_arg(
+                    mask, "verify_mask",
+                    (self.max_batch_slots, self.spec_window, self.cfg.vocab_size),
+                ),
+            )
+        with phase("engine.verify.block") as block:
+            jax.block_until_ready((out, n_emitted, ok, ck, cv))  # execution done
+        with phase("engine.verify.readback") as read:
+            self.cache.update(ck, cv)
+            self.last_finite = np.asarray(ok)
+            result = (np.asarray(out), np.asarray(n_emitted))
+        elapsed, execute_s = self._record_step_phases("verify", disp, block, read)
         # success-only, paired with the time below (see prefill())
         n_tok, ctx_sum = int(w_tok.sum()), int(ctx.sum())
         flops = self.flops_model.verify_flops(n_tok, ctx_sum)

@@ -227,6 +227,11 @@ class PrefixCache:
         self.evicted_total = 0
         self.dropped_total = 0
         self.host_bytes = 0
+        # where the seconds of the host tier's two spans go (the engine
+        # opens ff.cache.offload around reclaim() and ff.cache.restore
+        # around a swap-in): the scheduler points this at its model's
+        # ServingStats.observe; standalone, nothing listens
+        self.observe: Callable[[str, float], None] = lambda name, seconds: None
 
     # ------------------------------------------------------------- queries
     @property

@@ -106,6 +106,7 @@ from ..obs import (
     TraceRing,
     next_request_id,
 )
+from ..obs.steptrace import DEVICE_PHASES, phase
 from ..runtime import faults
 from ..serving.overload import OverloadConfig, OverloadController, Priority
 from ..serving.resilience import (
@@ -139,6 +140,25 @@ from .recovery import (
 from .speculative.drafter import SpeculationConfig, build_drafter
 
 _END = object()  # token-stream sentinel
+
+# the engine's host spans: one "device" total in a flight record
+_ENGINE_PHASES = frozenset({"dispatch", "block", "readback"})
+
+
+def _flight_phases(spans, walls: Dict[str, float]) -> Dict[str, float]:
+    """A flight record's phase durations: the iteration's host spans
+    summed under their names, and ``walls``, what the ring carries that
+    is no anatomy span — ``device``, the wall of the supervised device
+    step (failed attempts, retries and bisection included, which is
+    what an incident needs), and the pipeline's ``dispatch``. The
+    engine's own dispatch / block / readback sit inside ``device``; the
+    device-lane execute span is the record's ``execute_s``."""
+    out = dict(walls)
+    for name, t0, t1 in spans:
+        if name in DEVICE_PHASES or name in _ENGINE_PHASES:
+            continue
+        out[name] = out.get(name, 0.0) + (t1 - t0)
+    return out
 
 
 class GenerationHandle:
@@ -600,7 +620,6 @@ class ContinuousBatchingScheduler:
         self.flight = FlightRecorder(
             capacity=flight_capacity, enabled=observability, sched_clock=self.clock
         )
-        self._step_phases: Dict[str, float] = {}
         self._step_info: Dict = {}
         self._step_recorded = False
         # step-anatomy profiler (obs/steptrace.py): first-class host
@@ -608,11 +627,27 @@ class ContinuousBatchingScheduler:
         # flexflow_serving_step_phase_seconds histograms, the
         # device-bubble/overlap-headroom gauges, and the on-demand
         # two-lane capture on GET /v2/debug/anatomy. _step_spans holds
-        # THIS iteration's (phase, t0, t1) perf_counter stamps; loop
-        # thread only.
+        # THIS iteration's (phase, t0, t1) perf_counter stamps, as
+        # obs/steptrace.phase leaves them — the one account of a step:
+        # the flight record's phase durations are summed from it too.
+        # Loop thread only.
         self.anatomy = StepAnatomy(enabled=observability)
         self.anatomy.register_gauges(self.stats)
         self._step_spans: List = []
+        # the flight record's walls that are no anatomy span ("device",
+        # the pipeline's "dispatch"), for THIS iteration; loop thread only
+        self._step_walls: Dict[str, float] = {}
+        # admit_stall: how long running streams were held by an
+        # admission. _result_t is the readback stamp of the last decode
+        # result consumed (None while nothing decodes), _stall_from the
+        # one before the admissions now waiting for the next result
+        self._result_t: Optional[float] = None
+        self._stall_from = 0.0
+        self._stalled_admits = 0
+        if observability:
+            # the cache's own spans (ff.cache.offload / .restore) land
+            # in this model's windows: timed where the work happens
+            engine.prefix_cache.observe = self.stats.observe
         self.spec_stats = SpeculationStats()
         self.spec_stats.register_gauges(self.stats)
         # capacity & compute observability (obs/capacity.py, obs/slo.py):
@@ -1292,6 +1327,7 @@ class ContinuousBatchingScheduler:
         would double-free — must never follow this)."""
         self._running.clear()
         self._free_slots = list(range(self.engine.max_batch_slots - 1, -1, -1))
+        self._result_t, self._stalled_admits = None, 0
 
     def _quarantine(self, state: _Running, err: BaseException) -> None:
         """Fail ONE poisoned request and keep the batch: blocks freed,
@@ -1643,91 +1679,93 @@ class ContinuousBatchingScheduler:
         # Radix planning is a first-class anatomy phase (prefix_plan):
         # PR 11 made it a real admission cost the waterfall must not
         # hide inside "admit".
-        t_p0 = time.perf_counter()
-        plan = self.engine.prefix_plan(req.prompt)
-        t_p1 = time.perf_counter()
-        self._span("prefix_plan", t_p0, t_p1)
-        need = (
-            self.engine.cache_config.blocks_for(len(req.prompt) + 1)
-            - plan.n_resident
-        )
-        blocks = self.engine.allocator.allocate(need)
-        if blocks is None:
-            # unreferenced cached prefixes are the reclaim of last
-            # resort BEFORE making the head wait (or preempt): LRU
-            # entries offload to host and their device blocks free
-            with self._stamped():
-                reclaimed = self.engine.reclaim_cached(
-                    need - self.engine.allocator.num_free
-                )
-            if reclaimed:
-                blocks = self.engine.allocator.allocate(need)
-        if blocks is None:
-            # admission-rejection blame: remember when the FCFS head
-            # first stalled on blocks and how many it is short — the
-            # eventual admit stamps "queued Nms waiting for K
-            # block(s)" on the request's trace
-            if self.obs_enabled and req.cache_wait_start is None:
-                req.cache_wait_start = self.clock()
-            req.cache_wait_short = need - self.engine.allocator.num_free
-            return False
-        with self._lock:
-            if not self._queue or self._queue[0] is not req or not self._free_slots:
-                # the head changed while blocks were gathered (fleet
-                # steal_queue / adopt mutate the queue from other
-                # threads): hand the blocks back, retry next iteration
-                self.engine.allocator.free(blocks)
-                return False
-            self._queue.popleft()
-            slot = self._free_slots.pop()
-        if req.cache_wait_start is not None:
-            wait_s = max(0.0, self.clock() - req.cache_wait_start)
-            blame = self.capacity.note_admission_wait(wait_s, req.cache_wait_short)
-            req.trace.event(
-                "cache_wait", wait_s=wait_s,
-                blocks_short=req.cache_wait_short, blame=blame,
+        with self._phase("sched.prefix_plan", request=req.id) as p_plan:
+            plan = self.engine.prefix_plan(req.prompt)
+        with self._phase("sched.admit", request=req.id):
+            need = (
+                self.engine.cache_config.blocks_for(len(req.prompt) + 1)
+                - plan.n_resident
             )
-            req.cache_wait_start = None
-        self._span("admit", t_p1, time.perf_counter())
+            blocks = self.engine.allocator.allocate(need)
+            if blocks is None:
+                # unreferenced cached prefixes are the reclaim of last
+                # resort BEFORE making the head wait (or preempt): LRU
+                # entries offload to host and their device blocks free
+                with self._stamped():
+                    reclaimed = self.engine.reclaim_cached(
+                        need - self.engine.allocator.num_free
+                    )
+                if reclaimed:
+                    blocks = self.engine.allocator.allocate(need)
+            if blocks is None:
+                # admission-rejection blame: remember when the FCFS head
+                # first stalled on blocks and how many it is short — the
+                # eventual admit stamps "queued Nms waiting for K
+                # block(s)" on the request's trace
+                if self.obs_enabled and req.cache_wait_start is None:
+                    req.cache_wait_start = self.clock()
+                req.cache_wait_short = need - self.engine.allocator.num_free
+                return False
+            with self._lock:
+                if not self._queue or self._queue[0] is not req or not self._free_slots:
+                    # the head changed while blocks were gathered (fleet
+                    # steal_queue / adopt mutate the queue from other
+                    # threads): hand the blocks back, retry next iteration
+                    self.engine.allocator.free(blocks)
+                    return False
+                self._queue.popleft()
+                slot = self._free_slots.pop()
+            # the queue wait ends here, with a slot and blocks in hand:
+            # what follows (prefix assembly, the prefill) is service
+            popped_at = self.clock()
+            if req.cache_wait_start is not None:
+                wait_s = max(0.0, popped_at - req.cache_wait_start)
+                blame = self.capacity.note_admission_wait(wait_s, req.cache_wait_short)
+                req.trace.event(
+                    "cache_wait", wait_s=wait_s,
+                    blocks_short=req.cache_wait_short, blame=blame,
+                )
+                req.cache_wait_start = None
         # assemble the block table from the prefix plan: swap-ins + the
         # COW boundary copy are device work, so the watchdog's stall
         # heartbeat covers them like any other step
-        t_q0 = time.perf_counter()
-        with self._stamped():
-            prep = self.engine.prepare_prefix(req.prompt, plan, blocks)
-        t_q1 = time.perf_counter()
-        self._span("prefix_plan", t_q0, t_q1)
-        if prep is None:
-            # a mid-assembly swap-in fallback could not replace the
-            # lost shared blocks: everything was handed back — requeue
-            # the head and retry next iteration
-            self._free_slots.append(slot)
-            with self._lock:
-                self._queue.appendleft(req)
-            return False
-        table, shared_idx, entries, prefix_len = prep
-        # blocks first, then the request: cache_report treats a set
-        # _admitting as implying its blocks are readable (private
-        # blocks only — shared ones are the prefix index's to report)
-        self._admitting_blocks = [
-            b for i, b in enumerate(table) if i not in shared_idx
-        ]
-        self._admitting = req
-        t_dev = time.perf_counter()
-        self._span("admit", t_q1, t_dev)
+        with self._phase("sched.prefix_plan", request=req.id) as p_prep:
+            with self._stamped():
+                prep = self.engine.prepare_prefix(req.prompt, plan, blocks)
+        with self._phase("sched.admit", request=req.id):
+            if prep is None:
+                # a mid-assembly swap-in fallback could not replace the
+                # lost shared blocks: everything was handed back — requeue
+                # the head and retry next iteration
+                self._free_slots.append(slot)
+                with self._lock:
+                    self._queue.appendleft(req)
+                return False
+            table, shared_idx, entries, prefix_len = prep
+            # blocks first, then the request: cache_report treats a set
+            # _admitting as implying its blocks are readable (private
+            # blocks only — shared ones are the prefix index's to report)
+            self._admitting_blocks = [
+                b for i, b in enumerate(table) if i not in shared_idx
+            ]
+            self._admitting = req
         try:
-            pf_mask = None
-            if req.mask_state is not None:
-                # the prefill samples this stream's next token in-jit:
-                # mask it exactly like a decode step would
-                pf_mask = req.mask_state.mask_row(req.sampling.eos_id)
-                self.constrained_stats.incr("masked_steps")
-            token = self._device(
-                lambda: self.engine.prefill_one(
-                    req.prompt, table, req.sampling, req.sample_key(),
-                    prefix_len=prefix_len, mask=pf_mask,
+            with self._phase("sched.admit", request=req.id):
+                pf_mask = None
+                if req.mask_state is not None:
+                    # the prefill samples this stream's next token in-jit:
+                    # mask it exactly like a decode step would
+                    pf_mask = req.mask_state.mask_row(req.sampling.eos_id)
+                    self.constrained_stats.incr("masked_steps")
+                # a dispatched fold_in; the same key on every retry
+                key = req.sample_key()
+            with phase("sched.device_step", request=req.id) as p_dev:
+                token = self._device(
+                    lambda: self.engine.prefill_one(
+                        req.prompt, table, req.sampling, key,
+                        prefix_len=prefix_len, mask=pf_mask,
+                    )
                 )
-            )
         except Exception as e:
             self._admitting = None
             self._admitting_blocks = None
@@ -1755,130 +1793,130 @@ class ContinuousBatchingScheduler:
             if req.handle._fail(e):
                 self.stats.incr("failed")
             return True  # did work (and must not spin on the same head)
-        t_dev_end = time.perf_counter()
-        dev_s = t_dev_end - t_dev
         # the prefill's dispatch/block/execute/readback spans join the
         # iteration's anatomy timeline with their real offsets
         execute_s = self._engine_spans()
-        if not bool(self.engine.last_finite[0]):
-            # poisoned prompt: the prefill's logits went non-finite, and
-            # a single-sequence step needs no bisection to assign blame
+        with self._phase("sched.admit", request=req.id):
+            if not bool(self.engine.last_finite[0]):
+                # poisoned prompt: the prefill's logits went non-finite, and
+                # a single-sequence step needs no bisection to assign blame
+                self._admitting = None
+                self._admitting_blocks = None
+                self.engine.release_admission(table, shared_idx, entries)
+                self._free_slots.append(slot)
+                err = PoisonedRequestError(
+                    f"request {req.id} produced non-finite logits at prefill",
+                    request_id=req.id, step="prefill", reason="nan_logits",
+                )
+                req.trace.event("quarantine", step="prefill", reason="nan_logits")
+                err.flight_snapshot = self.flight.incident(
+                    "quarantine", request_id=req.id, step="prefill",
+                    reason="nan_logits",
+                )
+                if req.handle._fail(err):
+                    self.stats.incr("failed")
+                    self.recovery_stats.incr("quarantined")
+                return True
+            # the prompt's freshly written full blocks join the radix index
+            # AFTER the finiteness gate — poisoned K/V must never become
+            # shared content another request could reuse (reuse telemetry
+            # also counts here, so failed admissions never inflate it)
+            self.engine.register_prefix(
+                req.prompt, table, shared_idx, entries, prefix_len=prefix_len
+            )
+            state = _Running(
+                req, slot, table, cached_len=len(req.prompt),
+                admitted_seq=next(self._admitted_seq),
+                shared_idx=shared_idx, shared_entries=entries,
+            )
+            self._note_admission()
+            self._running[slot] = state
+            # clear only AFTER slot registration: cache_report reads
+            # _running first and dedupes by request id, so the blocks are
+            # visible (as a provisional or real row, never both) for the
+            # whole admission — residency keeps summing to used under
+            # concurrent scrapes
             self._admitting = None
             self._admitting_blocks = None
-            self.engine.release_admission(table, shared_idx, entries)
-            self._free_slots.append(slot)
-            err = PoisonedRequestError(
-                f"request {req.id} produced non-finite logits at prefill",
-                request_id=req.id, step="prefill", reason="nan_logits",
-            )
-            req.trace.event("quarantine", step="prefill", reason="nan_logits")
-            err.flight_snapshot = self.flight.incident(
-                "quarantine", request_id=req.id, step="prefill",
-                reason="nan_logits",
-            )
-            if req.handle._fail(err):
-                self.stats.incr("failed")
-                self.recovery_stats.incr("quarantined")
-            return True
-        # the prompt's freshly written full blocks join the radix index
-        # AFTER the finiteness gate — poisoned K/V must never become
-        # shared content another request could reuse (reuse telemetry
-        # also counts here, so failed admissions never inflate it)
-        self.engine.register_prefix(
-            req.prompt, table, shared_idx, entries, prefix_len=prefix_len
-        )
-        state = _Running(
-            req, slot, table, cached_len=len(req.prompt),
-            admitted_seq=next(self._admitted_seq),
-            shared_idx=shared_idx, shared_entries=entries,
-        )
-        self._running[slot] = state
-        # clear only AFTER slot registration: cache_report reads
-        # _running first and dedupes by request id, so the blocks are
-        # visible (as a provisional or real row, never both) for the
-        # whole admission — residency keeps summing to used under
-        # concurrent scrapes
-        self._admitting = None
-        self._admitting_blocks = None
-        if self.supervisor.failed:  # a dead engine just served a prefill
-            self.supervisor.note_engine_recovered()
-        self.journal.record(req, state.admitted_seq)
-        if req.handle.done():  # watchdog reaped it while the prefill ran
-            self._release(state)
-            return True
-        was_first = req.n_generated == 0
-        now = self.clock()
-        req.trace.mark_admit(
-            slot=slot, prompt_len=len(req.prompt),
-            preemptions=req.preemptions, replays=req.replays,
-        )
-        req.journey.hop(
-            "admit", slot=slot, prompt_len=len(req.prompt),
-            replica=self.fault_scope, preemptions=req.preemptions,
-            replays=req.replays,
-        )
-        if self.obs_enabled and was_first and req.preemptions == 0 and req.replays == 0:
-            # first-life admission only: a recompute re-admission is a
-            # scheduling event, not client-visible queueing
-            self.stats.observe(
-                "queue_time", max(0.0, now - req.submitted_at),
-                exemplar=req.journey.journey_id,
-            )
-        self._emit_token(state, token)
-        req.trace.note_tokens(1, "prefill")
-        req.journey.hop(
-            "prefill", prompt_len=len(req.prompt),
-            prefix_reused=prefix_len, replica=self.fault_scope,
-        )
-        if self.obs_enabled and was_first:
-            # gated like tpot (trace-derived in _finish) so disabling
-            # observability drops all three SLO windows together, not
-            # a confusing two of three
-            self.stats.observe(
-                "ttft", max(0.0, now - req.submitted_at),
-                exemplar=req.journey.journey_id,
-            )
-        self.flight.record_step(
-            "prefill",
-            phases={"prefix_plan": (t_p1 - t_p0) + (t_q1 - t_q0),
-                    "device": dev_s},
-            execute_s=execute_s, request_id=req.id,
-            prompt_len=len(req.prompt), occupancy=len(self._running),
-            queue_depth=len(self._queue),
-            blocks_free=self.engine.allocator.num_free,
-            prefix_reused=prefix_len,
-        )
-        self.token_rate.record(1)
-        if req.finished():
-            self._finish(state)
-        elif self.handoff_sink is not None:
-            # disaggregated prefill pool: this replica's job ends at the
-            # first token. Pack the prompt's KV into the CRC-stamped
-            # wire format while the blocks are still resident, hand the
-            # slot back, and ship (request, payload) to the handoff
-            # supervisor — the stream continues on the decode pool.
-            with self._stamped():
-                payload = self.engine.pack_kv_blocks(
-                    state.blocks, state.cached_len
-                )
-            self._release(state)
-            req.trace.event(
-                "kv_handoff_pack", n_blocks=len(payload.blocks),
-                payload_bytes=payload.nbytes,
+            if self.supervisor.failed:  # a dead engine just served a prefill
+                self.supervisor.note_engine_recovered()
+            self.journal.record(req, state.admitted_seq)
+            if req.handle.done():  # watchdog reaped it while the prefill ran
+                self._release(state)
+                return True
+            was_first = req.n_generated == 0
+            now = self.clock()
+            req.trace.mark_admit(
+                slot=slot, prompt_len=len(req.prompt),
+                preemptions=req.preemptions, replays=req.replays,
             )
             req.journey.hop(
-                "kv_handoff_pack", n_blocks=len(payload.blocks),
-                payload_bytes=payload.nbytes, replica=self.fault_scope,
+                "admit", slot=slot, prompt_len=len(req.prompt),
+                replica=self.fault_scope, preemptions=req.preemptions,
+                replays=req.replays,
             )
-            sink = self.handoff_sink
-            try:
-                sink(req, payload)
-            except Exception as e:
-                # the sink must never kill the loop; a sink crash fails
-                # the stream typed instead of losing it silently
-                if req.handle._fail(e):
-                    self.stats.incr("failed")
-        self._span("admit", t_dev_end, time.perf_counter())
+            if self.obs_enabled and was_first and req.preemptions == 0 and req.replays == 0:
+                # first-life admission only: a recompute re-admission is a
+                # scheduling event, not client-visible queueing
+                self.stats.observe(
+                    "queue_time", max(0.0, popped_at - req.submitted_at),
+                    exemplar=req.journey.journey_id,
+                )
+            self._emit_token(state, token)
+            req.trace.note_tokens(1, "prefill")
+            req.journey.hop(
+                "prefill", prompt_len=len(req.prompt),
+                prefix_reused=prefix_len, replica=self.fault_scope,
+            )
+            if self.obs_enabled and was_first:
+                # gated like tpot (trace-derived in _finish) so disabling
+                # observability drops all three SLO windows together, not
+                # a confusing two of three
+                self.stats.observe(
+                    "ttft", max(0.0, now - req.submitted_at),
+                    exemplar=req.journey.journey_id,
+                )
+            self.flight.record_step(
+                "prefill",
+                phases=_flight_phases(
+                    [p_plan.span, p_prep.span], {"device": p_dev.seconds}
+                ),
+                execute_s=execute_s, request_id=req.id,
+                prompt_len=len(req.prompt), occupancy=len(self._running),
+                queue_depth=len(self._queue),
+                blocks_free=self.engine.allocator.num_free,
+                prefix_reused=prefix_len,
+            )
+            self.token_rate.record(1)
+            if req.finished():
+                self._finish(state)
+            elif self.handoff_sink is not None:
+                # disaggregated prefill pool: this replica's job ends at the
+                # first token. Pack the prompt's KV into the CRC-stamped
+                # wire format while the blocks are still resident, hand the
+                # slot back, and ship (request, payload) to the handoff
+                # supervisor — the stream continues on the decode pool.
+                with self._stamped():
+                    payload = self.engine.pack_kv_blocks(
+                        state.blocks, state.cached_len
+                    )
+                self._release(state)
+                req.trace.event(
+                    "kv_handoff_pack", n_blocks=len(payload.blocks),
+                    payload_bytes=payload.nbytes,
+                )
+                req.journey.hop(
+                    "kv_handoff_pack", n_blocks=len(payload.blocks),
+                    payload_bytes=payload.nbytes, replica=self.fault_scope,
+                )
+                sink = self.handoff_sink
+                try:
+                    sink(req, payload)
+                except Exception as e:
+                    # the sink must never kill the loop; a sink crash fails
+                    # the stream typed instead of losing it silently
+                    if req.handle._fail(e):
+                        self.stats.incr("failed")
         return True
 
     def _admit_imported(self, req: Request) -> bool:
@@ -1891,102 +1929,102 @@ class ContinuousBatchingScheduler:
         back to the recompute-prefill path, which replays the stream
         byte-exactly from the request object."""
         payload = req.imported_kv
-        t0 = time.perf_counter()
-        need = self.engine.cache_config.blocks_for(payload.n_positions + 1)
-        blocks = self.engine.allocator.allocate(need)
-        if blocks is None:
-            with self._stamped():
-                reclaimed = self.engine.reclaim_cached(
-                    need - self.engine.allocator.num_free
-                )
-            if reclaimed:
-                blocks = self.engine.allocator.allocate(need)
-        if blocks is None:
-            if self.obs_enabled and req.cache_wait_start is None:
-                req.cache_wait_start = self.clock()
-            req.cache_wait_short = need - self.engine.allocator.num_free
-            return False
-        with self._lock:
-            if not self._queue or self._queue[0] is not req or not self._free_slots:
-                self.engine.allocator.free(blocks)
-                return False
-            self._queue.popleft()
-            slot = self._free_slots.pop()
-        try:
-            faults.inject(
-                faults.GENERATION_KV_IMPORT, (req.id, len(payload.blocks))
-            )
-            if payload.block_size != self.engine.cache_config.block_size:
-                raise ValueError(
-                    f"handoff block size {payload.block_size} != this "
-                    f"engine's {self.engine.cache_config.block_size}"
-                )
-            if len(payload.blocks) < self.engine.cache_config.blocks_for(
-                payload.n_positions
-            ):
-                raise ValueError("handoff payload is missing blocks")
-            n_import = self.engine.cache_config.blocks_for(payload.n_positions)
-            wire = payload.blocks[:n_import]
-            for pb in wire:
-                if not pb.verify():
-                    raise ValueError(
-                        "imported KV block failed CRC verification"
+        with self._phase("sched.admit", request=req.id) as p_admit:
+            need = self.engine.cache_config.blocks_for(payload.n_positions + 1)
+            blocks = self.engine.allocator.allocate(need)
+            if blocks is None:
+                with self._stamped():
+                    reclaimed = self.engine.reclaim_cached(
+                        need - self.engine.allocator.num_free
                     )
-            # every block CRC-verified BEFORE any device write, then one
-            # batched program commits the whole payload — a decode-pool
-            # replica pays one dispatch per adopted stream between steps
-            with self._stamped():
-                self.engine.import_kv_blocks(blocks[:n_import], wire)
-        except Exception as e:
-            # reject the import: hand everything back and requeue for
-            # the recompute path (this is the replay the clean-handoff
-            # adopt() deliberately did not count)
-            req.imported_kv = None
-            self.engine.allocator.free(blocks)
+                if reclaimed:
+                    blocks = self.engine.allocator.allocate(need)
+            if blocks is None:
+                if self.obs_enabled and req.cache_wait_start is None:
+                    req.cache_wait_start = self.clock()
+                req.cache_wait_short = need - self.engine.allocator.num_free
+                return False
             with self._lock:
-                self._free_slots.append(slot)
-                self._queue.appendleft(req)
-            self.recovery_stats.incr("kv_imports_rejected")
-            if req.n_generated > 0:
-                req.replays += 1
-                req.trace.note_replay()
-                self.recovery_stats.incr("replayed_tokens", req.n_generated)
+                if not self._queue or self._queue[0] is not req or not self._free_slots:
+                    self.engine.allocator.free(blocks)
+                    return False
+                self._queue.popleft()
+                slot = self._free_slots.pop()
+            try:
+                faults.inject(
+                    faults.GENERATION_KV_IMPORT, (req.id, len(payload.blocks))
+                )
+                if payload.block_size != self.engine.cache_config.block_size:
+                    raise ValueError(
+                        f"handoff block size {payload.block_size} != this "
+                        f"engine's {self.engine.cache_config.block_size}"
+                    )
+                if len(payload.blocks) < self.engine.cache_config.blocks_for(
+                    payload.n_positions
+                ):
+                    raise ValueError("handoff payload is missing blocks")
+                n_import = self.engine.cache_config.blocks_for(payload.n_positions)
+                wire = payload.blocks[:n_import]
+                for pb in wire:
+                    if not pb.verify():
+                        raise ValueError(
+                            "imported KV block failed CRC verification"
+                        )
+                # every block CRC-verified BEFORE any device write, then one
+                # batched program commits the whole payload — a decode-pool
+                # replica pays one dispatch per adopted stream between steps
+                with self._stamped():
+                    self.engine.import_kv_blocks(blocks[:n_import], wire)
+            except Exception as e:
+                # reject the import: hand everything back and requeue for
+                # the recompute path (this is the replay the clean-handoff
+                # adopt() deliberately did not count)
+                req.imported_kv = None
+                self.engine.allocator.free(blocks)
+                with self._lock:
+                    self._free_slots.append(slot)
+                    self._queue.appendleft(req)
+                self.recovery_stats.incr("kv_imports_rejected")
+                if req.n_generated > 0:
+                    req.replays += 1
+                    req.trace.note_replay()
+                    self.recovery_stats.incr("replayed_tokens", req.n_generated)
+                req.trace.event(
+                    "kv_import_rejected", reason=type(e).__name__,
+                    n_blocks=len(payload.blocks),
+                )
+                return True
+            req.imported_kv = None
+            self.recovery_stats.incr("kv_imports")
+            state = _Running(
+                req, slot, blocks, cached_len=payload.n_positions,
+                admitted_seq=next(self._admitted_seq),
+            )
+            self._note_admission()
+            self._running[slot] = state
+            self.journal.record(req, state.admitted_seq)
+            if req.handle.done():  # reaped while blocks were in flight
+                self._release(state)
+                return True
+            req.trace.mark_admit(
+                slot=slot, prompt_len=len(req.prompt),
+                preemptions=req.preemptions, replays=req.replays,
+            )
             req.trace.event(
-                "kv_import_rejected", reason=type(e).__name__,
+                "kv_import", n_blocks=len(payload.blocks),
+                n_positions=payload.n_positions, payload_bytes=payload.nbytes,
+            )
+            req.journey.hop(
+                "admit", slot=slot, prompt_len=len(req.prompt),
+                replica=self.fault_scope, imported=True,
                 n_blocks=len(payload.blocks),
             )
-            return True
-        req.imported_kv = None
-        self.recovery_stats.incr("kv_imports")
-        state = _Running(
-            req, slot, blocks, cached_len=payload.n_positions,
-            admitted_seq=next(self._admitted_seq),
-        )
-        self._running[slot] = state
-        self.journal.record(req, state.admitted_seq)
-        if req.handle.done():  # reaped while blocks were in flight
-            self._release(state)
-            return True
-        req.trace.mark_admit(
-            slot=slot, prompt_len=len(req.prompt),
-            preemptions=req.preemptions, replays=req.replays,
-        )
-        req.trace.event(
-            "kv_import", n_blocks=len(payload.blocks),
-            n_positions=payload.n_positions, payload_bytes=payload.nbytes,
-        )
-        req.journey.hop(
-            "admit", slot=slot, prompt_len=len(req.prompt),
-            replica=self.fault_scope, imported=True,
-            n_blocks=len(payload.blocks),
-        )
         self.flight.record_step(
-            "kv_import", phases={"admit": time.perf_counter() - t0},
+            "kv_import", phases={"admit": p_admit.seconds},
             request_id=req.id, prompt_len=len(req.prompt),
             occupancy=len(self._running), queue_depth=len(self._queue),
             blocks_free=self.engine.allocator.num_free,
         )
-        self._span("admit", t0, time.perf_counter())
         return True
 
     def _emit_token(self, state: _Running, token: int) -> None:
@@ -2223,26 +2261,23 @@ class ContinuousBatchingScheduler:
     def _decode_once(self) -> bool:
         if not self._running:
             return False
-        t_c0 = time.perf_counter()
-        order = sorted(self._running.values(), key=lambda s: s.slot)
-        step, probe = self._decode_step_fns(order)
-        t_c1 = time.perf_counter()
-        self._span("schedule", t_c0, t_c1)
-        ph, info = self._step_phases, self._step_info
+        with self._phase("sched.schedule"):
+            order = sorted(self._running.values(), key=lambda s: s.slot)
+            step, probe = self._decode_step_fns(order)
+        info = self._step_info
         info["kind"] = "decode"
-        t_dev = time.perf_counter()
-        out = self.supervisor.run_step("decode", step, order, probe)
-        ph["device"] = time.perf_counter() - t_dev
+        with phase("sched.device_step") as p_dev:
+            out = self.supervisor.run_step("decode", step, order, probe)
+        self._step_walls["device"] = p_dev.seconds
         if out is None:
             info["handled_failure"] = True
             return True  # failure handled: quarantined or journal-replayed
-        info["execute_s"] = self._engine_spans()
+        info["execute_s"] = self._engine_spans(decode_result=True)
         if self._quarantine_nan("decode", order):
             info["handled_failure"] = True
             return True
-        t_book = time.perf_counter()
-        n_live, _ = self._scatter_decode(order, out)
-        self._span("bookkeep", t_book, time.perf_counter())
+        with self._phase("sched.bookkeep"):
+            n_live, _ = self._scatter_decode(order, out)
         info["emitted"] = n_live
         self.token_rate.record(n_live)
         return True
@@ -2370,8 +2405,9 @@ class ContinuousBatchingScheduler:
         blame). Device errors propagate to the caller's
         pipeline-failure handling."""
         faults.inject(faults.GENERATION_ASYNC_READBACK, ("decode", len(f.states)))
-        t_b0 = time.perf_counter()
-        out = self.engine.consume_decode(f.handle)
+        with phase("sched.device_step") as p_dev:
+            out = self.engine.consume_decode(f.handle)
+        self._add_wall("device", p_dev.seconds)
         # completion stamp (satellite: dispatch AND completion): the
         # successor — if any — only starts device work now, so its
         # heartbeat age and execute span are measured from here; a
@@ -2384,10 +2420,9 @@ class ContinuousBatchingScheduler:
             nf.handle.t_started = time.perf_counter()
         else:
             self._heartbeat = None
-        ph = self._step_phases
-        ph["device"] = ph.get("device", 0.0) + (time.perf_counter() - t_b0)
         self._step_info["execute_s"] = (
-            self._step_info.get("execute_s", 0.0) + self._engine_spans()
+            self._step_info.get("execute_s", 0.0)
+            + self._engine_spans(decode_result=True)
         )
         if self.supervisor._consume_stall(f.seq0):
             # the watchdog tripped while this chain was in flight: the
@@ -2413,20 +2448,21 @@ class ContinuousBatchingScheduler:
             if self._quarantine_nan("decode", f.states):
                 self._step_info["handled_failure"] = True
                 return None
-        t_book = time.perf_counter()
-        n_live, finish = self._scatter_decode(f.states, out, defer_finish=True)
-        self.token_rate.record(n_live)
+        with self._phase("sched.bookkeep"):
+            n_live, finish = self._scatter_decode(f.states, out, defer_finish=True)
+            self.token_rate.record(n_live)
         if finish:
             # finish/EOS is a non-steady event: the successor step may
             # still be writing into the finishing streams' blocks —
-            # drain it (bookkept; its tokens for finished slots are
-            # skipped by the scatter) before any release
+            # drain it (bookkept under spans of its own; its tokens for
+            # finished slots are skipped by the scatter) before any
+            # release
             if self._pipe is not None:
                 self._drain_frontier("finish")
-            for st in finish:
-                if self._running.get(st.slot) is st and not st.req.handle.done():
-                    self._finish(st)
-        self._span("bookkeep", t_book, time.perf_counter())
+            with self._phase("sched.bookkeep"):
+                for st in finish:
+                    if self._running.get(st.slot) is st and not st.req.handle.done():
+                        self._finish(st)
         return n_live
 
     def _dispatch_pipeline(self, live, prev: Optional["_Frontier"]) -> "_Frontier":
@@ -2488,8 +2524,7 @@ class ContinuousBatchingScheduler:
             self._hb_seq = seq  # seq stays burned; stall flags on it are void
             raise
         self._step_spans.append(("dispatch", handle.t0, handle.t_disp))
-        ph = self._step_phases
-        ph["dispatch"] = ph.get("dispatch", 0.0) + (handle.t_disp - handle.t0)
+        self._add_wall("dispatch", handle.t_disp - handle.t0)
         return _Frontier(
             handle, list(live), positions, active, temps, top_ks, seeds,
             counts, tables, sig, seq, seq0,
@@ -2520,7 +2555,7 @@ class ContinuousBatchingScheduler:
         out = self.supervisor.resume_step("decode", e, step, order, probe, since_seq)
         if out is None:
             return
-        self._step_info["execute_s"] = self._engine_spans()
+        self._step_info["execute_s"] = self._engine_spans(decode_result=True)
         if self._quarantine_nan("decode", order):
             return
         n_live, _ = self._scatter_decode(order, out)
@@ -2551,25 +2586,39 @@ class ContinuousBatchingScheduler:
                 self._drain_frontier("idle")
             return None
         info = self._step_info
-        t_s0 = time.perf_counter()
         f = self._pipe
-        covered = {s.slot for s in f.states} if f is not None else set()
-        # slots live at the NEXT dispatch: budget-predicted finishes are
-        # excluded (sequential would have freed them before this step);
-        # EOS cannot be predicted and is handled at consume
-        live = []
-        for s in order:
-            pend = 1 if s.slot in covered else 0
-            if s.req.n_generated + pend >= s.req.max_new:
-                continue
-            live.append(s)
+        with self._phase("sched.schedule"):
+            covered = {s.slot for s in f.states} if f is not None else set()
+            # slots live at the NEXT dispatch: budget-predicted finishes
+            # are excluded (sequential would have freed them before this
+            # step); EOS cannot be predicted and is handled at consume
+            live = []
+            for s in order:
+                pend = 1 if s.slot in covered else 0
+                if s.req.n_generated + pend >= s.req.max_new:
+                    continue
+                live.append(s)
+            # grow block tables for the dispatch positions (plain
+            # allocation only: reclaim/preempt pressure is handled
+            # sequentially)
+            short = False
+            for s in live:
+                pend = 1 if s.slot in covered else 0
+                need = self.engine.cache_config.blocks_for(s.cached_len + pend + 1)
+                while len(s.blocks) < need:
+                    got = self.engine.allocator.allocate(1)
+                    if got is None:
+                        short = True
+                        break
+                    s.blocks.extend(got)
+                if short:
+                    break
         if not live:
             if f is None:
                 return None
             # stream tail: nothing left to dispatch — consume only
             info["kind"] = "decode"
             self._pipe = None
-            self._span("schedule", t_s0, time.perf_counter())
             try:
                 n = self._consume_and_finish(f)
             except Exception as e:
@@ -2578,26 +2627,12 @@ class ContinuousBatchingScheduler:
             if n is not None:
                 info["emitted"] = n
             return True
-        # grow block tables for the dispatch positions (plain allocation
-        # only: reclaim/preempt pressure is handled sequentially)
-        for s in live:
-            pend = 1 if s.slot in covered else 0
-            need = self.engine.cache_config.blocks_for(s.cached_len + pend + 1)
-            short = False
-            while len(s.blocks) < need:
-                got = self.engine.allocator.allocate(1)
-                if got is None:
-                    short = True
-                    break
-                s.blocks.extend(got)
-            if short:
-                self._span("schedule", t_s0, time.perf_counter())
-                if f is not None:
-                    info["kind"] = "decode"
-                    self._drain_frontier("pressure")
-                    return True
-                return None
-        self._span("schedule", t_s0, time.perf_counter())
+        if short:
+            if f is not None:
+                info["kind"] = "decode"
+                self._drain_frontier("pressure")
+                return True
+            return None
         info["kind"] = "decode"
         try:
             new_f = self._dispatch_pipeline(live, f)
@@ -2689,44 +2724,41 @@ class ContinuousBatchingScheduler:
             return False
         b = self.engine.max_batch_slots
         w = self.engine.spec_window
-        ph, info = self._step_phases, self._step_info
+        info = self._step_info
         info["kind"] = "verify"
-        t_c0 = time.perf_counter()
-        order = sorted(self._running.values(), key=lambda s: s.slot)
-        (last, start, tables, _active, temps, top_ks, seeds,
-         counts) = self._collect_slots(order)
-        t_draft = time.perf_counter()
-        self._span("schedule", t_c0, t_draft)
+        with self._phase("sched.schedule"):
+            order = sorted(self._running.values(), key=lambda s: s.slot)
+            (last, start, tables, _active, temps, top_ks, seeds,
+             counts) = self._collect_slots(order)
         window = np.zeros((b, w), np.int32)
         window[:, 0] = last
         n_draft = np.full((b,), -1, np.int32)  # -1 = inactive slot
-        for state in order:
-            i = state.slot
-            req = state.req
-            draft: List[int] = []
-            if state.step_k > 0 and req.drafter is not None:
-                try:
-                    # original_prompt, NOT prompt: after a preemption the
-                    # recompute prompt already folds in generated tokens
-                    draft = list(
-                        req.drafter.propose(
-                            req.original_prompt + req.generated, state.step_k
-                        )
-                    )[: state.step_k]
-                except Exception:
-                    # a dying drafter must not kill the scheduler loop:
-                    # verification is exact with ANY draft, so a failed
-                    # proposal degrades to a plain (zero-draft) step
-                    self.stats.incr("drafter_errors")
-            if req.mask_state is not None and draft:
-                # grammar-banned draft tokens would be rejected by the
-                # masked target anyway; trimming to the longest legal
-                # prefix just stops them wasting verify positions
-                draft = req.mask_state.filter_draft(draft, req.sampling.eos_id)
-            window[i, 1 : 1 + len(draft)] = draft
-            n_draft[i] = len(draft)
-        t_d1 = time.perf_counter()
-        self._span("draft", t_draft, t_d1)
+        with self._phase("sched.draft"):
+            for state in order:
+                i = state.slot
+                req = state.req
+                draft: List[int] = []
+                if state.step_k > 0 and req.drafter is not None:
+                    try:
+                        # original_prompt, NOT prompt: after a preemption the
+                        # recompute prompt already folds in generated tokens
+                        draft = list(
+                            req.drafter.propose(
+                                req.original_prompt + req.generated, state.step_k
+                            )
+                        )[: state.step_k]
+                    except Exception:
+                        # a dying drafter must not kill the scheduler loop:
+                        # verification is exact with ANY draft, so a failed
+                        # proposal degrades to a plain (zero-draft) step
+                        self.stats.incr("drafter_errors")
+                if req.mask_state is not None and draft:
+                    # grammar-banned draft tokens would be rejected by the
+                    # masked target anyway; trimming to the longest legal
+                    # prefix just stops them wasting verify positions
+                    draft = req.mask_state.filter_draft(draft, req.sampling.eos_id)
+                window[i, 1 : 1 + len(draft)] = draft
+                n_draft[i] = len(draft)
         # the per-window key matrix derives in-jit from (seed, count) —
         # the old host "sample" phase (vmapped fold_in + stack per
         # request) no longer exists
@@ -2750,84 +2782,104 @@ class ContinuousBatchingScheduler:
                 )
             )
 
-        t_dev = time.perf_counter()
-        result = self.supervisor.run_step("verify", step, order, probe)
-        ph["device"] = time.perf_counter() - t_dev
+        with phase("sched.device_step") as p_dev:
+            result = self.supervisor.run_step("verify", step, order, probe)
+        self._step_walls["device"] = p_dev.seconds
         if result is None:
             info["handled_failure"] = True
             return True  # failure handled: quarantined or journal-replayed
-        info["execute_s"] = self._engine_spans()
+        info["execute_s"] = self._engine_spans(decode_result=True)
         out, n_emitted = result
         if self._quarantine_nan("verify", order):
             info["handled_failure"] = True
             return True
-        t_book = time.perf_counter()
         n_accepted = 0
         n_live_tokens = 0
-        for state in order:
-            if self._running.get(state.slot) is not state:
-                continue  # preempted/expired between collect and scatter
-            if state.req.handle.done():
-                continue  # watchdog-reaped mid-step; _expire releases it
-            req = state.req
-            i = state.slot
-            m = int(n_emitted[i])
-            toks = [int(t) for t in out[i, :m]]
-            # budget truncation: never emit past max_new
-            toks = toks[: req.max_new - req.n_generated]
-            # mid-window EOS: keep through the FIRST eos, drop the rest
-            eos = req.sampling.eos_id
-            if eos is not None and eos in toks:
-                toks = toks[: toks.index(eos) + 1]
-            accepted = max(0, m - 1)  # drafts the target agreed with
-            n_accepted += accepted
-            req.update_speculation(proposed=int(max(0, n_draft[i])), accepted=accepted)
-            req.trace.note_speculation(int(max(0, n_draft[i])), accepted)
-            emitted = 0
-            for t in toks:
-                self._emit_token(state, t)
-                emitted += 1
-                if req.mask_state is not None and (
-                    req.mask_error is not None or req.finished()
-                ):
-                    # constrained stream ended mid-window — a parked
-                    # advance error or the exhaustion clamp. The rest of
-                    # the accepted run was sampled at states past the
-                    # grammar's end: drop it, never surface or cache it.
-                    break
-            self.spec_stats.record_window(
-                proposed=int(max(0, n_draft[i])), accepted=accepted, emitted=emitted
-            )
-            req.trace.note_tokens(emitted, "verify")
-            state.cached_len += emitted
-            self._trim_blocks(state)
-            n_live_tokens += emitted
-            if req.finished():
-                self._finish(state)
-        self._span("bookkeep", t_book, time.perf_counter())
+        with self._phase("sched.bookkeep"):
+            for state in order:
+                if self._running.get(state.slot) is not state:
+                    continue  # preempted/expired between collect and scatter
+                if state.req.handle.done():
+                    continue  # watchdog-reaped mid-step; _expire releases it
+                req = state.req
+                i = state.slot
+                m = int(n_emitted[i])
+                toks = [int(t) for t in out[i, :m]]
+                # budget truncation: never emit past max_new
+                toks = toks[: req.max_new - req.n_generated]
+                # mid-window EOS: keep through the FIRST eos, drop the rest
+                eos = req.sampling.eos_id
+                if eos is not None and eos in toks:
+                    toks = toks[: toks.index(eos) + 1]
+                accepted = max(0, m - 1)  # drafts the target agreed with
+                n_accepted += accepted
+                req.update_speculation(proposed=int(max(0, n_draft[i])), accepted=accepted)
+                req.trace.note_speculation(int(max(0, n_draft[i])), accepted)
+                emitted = 0
+                for t in toks:
+                    self._emit_token(state, t)
+                    emitted += 1
+                    if req.mask_state is not None and (
+                        req.mask_error is not None or req.finished()
+                    ):
+                        # constrained stream ended mid-window — a parked
+                        # advance error or the exhaustion clamp. The rest of
+                        # the accepted run was sampled at states past the
+                        # grammar's end: drop it, never surface or cache it.
+                        break
+                self.spec_stats.record_window(
+                    proposed=int(max(0, n_draft[i])), accepted=accepted, emitted=emitted
+                )
+                req.trace.note_tokens(emitted, "verify")
+                state.cached_len += emitted
+                self._trim_blocks(state)
+                n_live_tokens += emitted
+                if req.finished():
+                    self._finish(state)
         info["accepted"] = n_accepted
         info["emitted"] = n_live_tokens
         self.token_rate.record(n_live_tokens)
         return True
 
-    def _span(self, name: str, t0: float, t1: float) -> None:
-        """Record one host span of THIS iteration: real perf_counter
-        stamps for the anatomy profiler, duration accumulated into the
-        flight record's phase dict. Loop thread only."""
-        self._step_spans.append((name, t0, t1))
-        ph = self._step_phases
-        ph[name] = ph.get(name, 0.0) + (t1 - t0)
+    def _phase(self, name: str, **args) -> phase:
+        """Open one host span of THIS iteration (obs/steptrace.phase:
+        an ``ff.<name>`` event on the profiler's clock and perf_counter
+        stamps for the anatomy profiler, from which the flight record's
+        phase durations are summed). Loop thread only."""
+        return phase(name, into=self._step_spans, **args)
 
-    def _engine_spans(self) -> float:
+    def _add_wall(self, key: str, seconds: float) -> None:
+        """Add to one of THIS iteration's flight-record walls (a
+        pipelined iteration may consume, and dispatch, more than once)."""
+        self._step_walls[key] = self._step_walls.get(key, 0.0) + seconds
+
+    def _engine_spans(self, decode_result: bool = False) -> float:
         """Adopt the engine's last step's dispatch/block/execute/
-        readback spans into this iteration's anatomy span list (NOT
-        into the flight phases — those keep the conflated "device"
-        total so the ring's series stays continuous). Returns the
-        device-execute seconds for the flight record's new
-        ``execute_s`` field."""
+        readback spans into this iteration's anatomy span list.
+        ``decode_result``: the step was a decode (or verify) whose
+        result the loop has just consumed, which ends the stall of
+        every admission made since the result before it. Returns the
+        device-execute seconds for the flight record's ``execute_s``
+        field."""
         spans = self.engine.last_step_spans
         self._step_spans.extend(spans)
+        if decode_result:
+            t = spans[-1][2]  # the readback's end
+            for _ in range(self._stalled_admits):
+                self.stats.observe("admit_stall", max(0.0, t - self._stall_from))
+            self._stalled_admits = 0
+            self._result_t = t
         return sum(s1 - s0 for name, s0, s1 in spans if name == "execute")
+
+    def _note_admission(self) -> None:
+        """An admission is about to take a slot. If streams are
+        decoding, they are held from the last decode result consumed
+        until the next one: ``admit_stall``, observed once per such
+        admission when that next result arrives."""
+        if self.obs_enabled and self._running and self._result_t is not None:
+            if not self._stalled_admits:
+                self._stall_from = self._result_t
+            self._stalled_admits += 1
 
     def _flight_step(self) -> None:
         """Write THIS iteration's step record (idempotent per step):
@@ -2840,7 +2892,7 @@ class ContinuousBatchingScheduler:
         info = dict(self._step_info)
         self.flight.record_step(
             info.pop("kind", "admit"),
-            phases=dict(self._step_phases),
+            phases=_flight_phases(self._step_spans, self._step_walls),
             occupancy=len(self._running),
             queue_depth=len(self._queue),
             blocks_free=self.engine.allocator.num_free,
@@ -2864,54 +2916,56 @@ class ContinuousBatchingScheduler:
             return self._step_impl()
 
     def _step_impl(self) -> bool:
-        self._step_phases = {}
         info = self._step_info = {}
         self._step_spans = []
+        self._step_walls = {}
         self._step_recorded = False
+        if not self._running:
+            self._result_t = None  # nothing decodes: no stream to hold
         t0 = time.perf_counter()
-        if self.overlap:
-            # overlapped decode: steady-state iterations pipeline
-            # dispatch/consume; any non-steady event drains the
-            # frontier and falls through to the sequential body below
-            r = self._try_pipeline()
-            if r is not None:
-                if r:
-                    self._flight_step()
-                    self.anatomy.observe_step(
-                        info.get("kind", "decode"), self._step_spans, t0,
-                        time.perf_counter(),
-                        tokens=int(info.get("emitted", 0)),
-                        hot=not info.get("handled_failure", False),
-                    )
-                # durable group commit rides the pipeline's execute
-                # window like the other host bookkeeping (no-op on the
-                # base journal)
-                self.journal.flush_step()
-                self.capacity.tick()
-                self._overload_tick()
-                return r
-        self._expire()
-        self._sweep_mask_errors()
-        t1 = time.perf_counter()
-        self._span("schedule", t0, t1)
         admitted = 0
-        # admit as many as fit THIS iteration — they decode together
-        # below. Admission spans (admit / prefix_plan / the prefill's
-        # dispatch-execute-readback) are recorded inside _admit.
-        while self._admit():
-            admitted += 1
-        t2 = time.perf_counter()
-        self._plan_speculation()
-        self._grow()
-        t3 = time.perf_counter()
-        self._span("schedule", t2, t3)
-        if admitted:
-            info["admitted"] = admitted
-        speculating = any(s.step_k > 0 for s in self._running.values())
-        stepped = self._verify_once() if speculating else self._decode_once()
-        did = stepped or admitted > 0
+        # overlapped decode: steady-state iterations pipeline
+        # dispatch/consume; any non-steady event drains the frontier
+        # and falls through to the sequential body
+        r = self._try_pipeline() if self.overlap else None
+        if r is not None:
+            did, kind = r, "decode"
+        else:
+            with self._phase("sched.schedule"):
+                self._expire()
+                self._sweep_mask_errors()
+            # admit as many as fit THIS iteration — they decode together
+            # below. Admission spans (admit / prefix_plan / the prefill's
+            # dispatch-execute-readback) are recorded inside _admit.
+            while self._admit():
+                admitted += 1
+            with self._phase("sched.schedule"):
+                self._plan_speculation()
+                self._grow()
+            if admitted:
+                info["admitted"] = admitted
+            speculating = any(s.step_k > 0 for s in self._running.values())
+            stepped = self._verify_once() if speculating else self._decode_once()
+            did, kind = stepped or admitted > 0, "admit"
         if did:
+            # before housekeep, whose overload tick may shed requests:
+            # the record's occupancy and queue depth are the step's own
             self._flight_step()
+        with self._phase("sched.housekeep"):
+            # durable group commit: one write+fsync for every journal
+            # record this iteration buffered (admits, token deltas,
+            # ends) — off the device dispatch path (under the pipeline
+            # it rides the execute window like the other host
+            # bookkeeping), a no-op on the base journal
+            self.journal.flush_step()
+            # integrate time-at-pressure AFTER the step's allocations,
+            # so the pressure flag reflects the state the next interval
+            # runs in (injectable clock: virtual-clock tests integrate
+            # exactly); the overload control plane ticks on the fresh
+            # pressure flag
+            self.capacity.tick()
+            self._overload_tick()
+        if did:
             # one anatomy observation per working iteration: host spans
             # + the device execute lane, under the iteration's step kind
             # (admission work inside a decode iteration charges the
@@ -2920,19 +2974,9 @@ class ContinuousBatchingScheduler:
             # they have no execute span and a retry-inflated wall that
             # would skew the bubble/headroom math for a whole window.
             self.anatomy.observe_step(
-                info.get("kind", "admit"), self._step_spans, t0,
+                info.get("kind", kind), self._step_spans, t0,
                 time.perf_counter(),
                 tokens=int(info.get("emitted", 0)) + admitted,
                 hot=not info.get("handled_failure", False),
             )
-        # durable group commit: one write+fsync for every journal
-        # record this iteration buffered (admits, token deltas, ends) —
-        # off the device dispatch path, a no-op on the base journal
-        self.journal.flush_step()
-        # integrate time-at-pressure AFTER the step's allocations, so
-        # the pressure flag reflects the state the next interval runs in
-        # (injectable clock: virtual-clock tests integrate exactly);
-        # the overload control plane ticks on the fresh pressure flag
-        self.capacity.tick()
-        self._overload_tick()
         return did

@@ -697,9 +697,16 @@ class FFModel:
             # a whole extra XLA compile for a once-per-epoch window size
             for step, k, batch_x, batch_y in self._iter_windows(xs, y, bs, steps, tw):
                 rng, sub = jax.random.split(rng)
+                # one profiler step per dispatch (a window of k steps is
+                # one program): xprof's step analysis then lines up with
+                # the executor's ff.train.* spans inside it
+                with jax.profiler.StepTraceAnnotation(
+                    "ff.train.step", step_num=epoch * steps + step
+                ):
+                    run = self.executor.train_window if k > 1 else self.executor.train_batch
+                    mets = run(batch_x, batch_y, sub)
                 if k > 1:
-                    wmets = self.executor.train_window(batch_x, batch_y, sub)
-                    host = {kk: np.asarray(v) for kk, v in wmets.items()}
+                    host = {kk: np.asarray(v) for kk, v in mets.items()}
                     for i in range(k):
                         perf.update({kk: float(v[i]) for kk, v in host.items() if kk != "loss"})
                         if verbose and (step + i) % interval == 0:
@@ -708,7 +715,6 @@ class FFModel:
                                 f"loss {float(host.get('loss', np.zeros(k))[i]):.4f} acc {perf.accuracy:.4f}"
                             )
                 else:
-                    mets = self.executor.train_batch(batch_x, batch_y, sub)
                     perf.update({kk: float(v) for kk, v in mets.items() if kk != "loss"})
                     if verbose and step % interval == 0:
                         loss = float(mets.get("loss", 0.0))

@@ -21,7 +21,7 @@ this module answers the *resource* dimension with three pieces:
   peaks. Convention follows MFU literature: only model-shaped work
   counts — true prompt lengths and live context positions, never bucket
   padding or inactive slots — so serving MFU is comparable to the
-  training MFU tools/mfu_profile.py reports. Work the device executed but
+  training MFU the benchmark's ``mfu`` metric reports. Work the device executed but
   clients never benefited from (recovery replay, bisection probes, step
   retries) DOES count, in both the FLOPs numerator and the device-time
   denominator: MFU measures hardware utilization, not client benefit —
@@ -46,6 +46,7 @@ capacity telemetry enabled).
 from __future__ import annotations
 
 import dataclasses
+import re
 import threading
 import time
 from collections import deque
@@ -508,7 +509,11 @@ class ProgramRegistry:
         """Wrap ``fn`` for ``jax.jit`` so every trace self-registers
         (the wrapper body runs at trace time only — zero steady-state
         cost). Used for the executor's train/eval programs, where
-        arguments are anonymous pytrees."""
+        arguments are anonymous pytrees. The wrapper takes the
+        program's own name (``executor[3].train_window[16]`` ->
+        ``train_window_16``), which is what jit names the XLA module
+        by: a device trace then shows ``jit_train_step``, not one
+        ``jit_traced`` for every program."""
 
         def traced(*args, **kwargs):
             sig = {f"arg{i}": a for i, a in enumerate(args)}
@@ -516,6 +521,9 @@ class ProgramRegistry:
             self.note_trace(name, sig)
             return fn(*args, **kwargs)
 
+        traced.__name__ = traced.__qualname__ = (
+            re.sub(r"\W+", "_", name.rpartition(".")[2]).strip("_") or "traced"
+        )
         return traced
 
     def remove_namespace(self, prefix: str) -> None:

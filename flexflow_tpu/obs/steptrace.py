@@ -46,11 +46,21 @@ layout for the captured window. Served fleet-aware on
 ``GET /v2/debug/anatomy?capture=K`` (per-replica units, like the other
 debug endpoints) and summarized by ``tools/obsreport.py anatomy``.
 
+One way to open a span: :class:`phase`. ``with phase("sched.admit")``
+enters ``jax.profiler.TraceAnnotation("ff.sched.admit")`` — so the span
+is an event on the profiler's clock, on the timeline of the device ops,
+whenever a trace is running (and an atomic flag test when none is) —
+and hands back the ``perf_counter`` stamps of entry and exit, which is
+what StepAnatomy, ``engine.phase_time_s`` and the flight record are fed
+from. The scheduler, the engine, the prefix cache, the HTTP handler,
+the executor and the data loader all open their spans here; the span
+catalogue is in README "Step anatomy".
+
 Clock discipline (the PR 6 dual-clock decision): span stamps are
-``time.perf_counter`` values produced by the scheduler/engine —
-physical profiling data even in virtual-clock tests. This module never
-reads a clock itself; it only aggregates the stamps it is handed
-(whitelisted in analysis/config.py alongside the engine's timers).
+``time.perf_counter`` values — physical profiling data even in
+virtual-clock tests. :class:`phase` is the one place they are read
+(this module is whitelisted in analysis/config.py for perf_counter
+only); StepAnatomy itself only aggregates the stamps it is handed.
 
 CPU-backend caveat: XLA:CPU completes small programs *inside* the
 dispatch call, so the measured ``execute`` span can be near zero and
@@ -69,7 +79,10 @@ from __future__ import annotations
 import math
 import threading
 from collections import deque
+from time import perf_counter
 from typing import Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # span names on the DEVICE lane of the two-lane timeline; everything
 # else is host work. With overlap OFF, "block" (host parked in
@@ -93,6 +106,46 @@ PHASE_BUCKETS: Tuple[float, ...] = (
 )
 
 Span = Tuple[str, float, float]  # (phase, t0, t1) — perf_counter stamps
+
+
+class phase:
+    """One span on two clocks: ``with phase("<layer>.<phase>") as p``
+    is a ``TraceAnnotation("ff.<layer>.<phase>", **args)`` on the
+    profiler's timeline and leaves ``p.t0`` / ``p.t1``, the
+    ``perf_counter`` stamps taken just inside it, so the annotation
+    encloses the stamped interval by well under a microsecond. ``into``,
+    a list, receives ``p.span`` on exit, early returns and exceptions
+    included. Request-scoped spans pass ``request=<id>``: the spans of
+    one request then share an argument in the trace."""
+
+    __slots__ = ("name", "t0", "t1", "_ann", "_into")
+
+    def __init__(self, name: str, into: Optional[List[Span]] = None, **args):
+        self.name = name
+        self._into = into
+        self._ann = TraceAnnotation("ff." + name, **args)
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        self.t0 = perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.t1 = perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        if self._into is not None:
+            self._into.append(self.span)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    @property
+    def span(self) -> Span:
+        """``(phase, t0, t1)`` under the name's last component, the
+        key of StepAnatomy's per-``{kind, phase}`` histograms."""
+        return (self.name.rpartition(".")[2], self.t0, self.t1)
 
 
 class _PhaseHist:
@@ -224,10 +277,10 @@ class StepAnatomy:
                 dispatch += d
         with self._lock:
             self.steps_total += 1
-            for phase, d in per_phase.items():
-                h = self._hists.get((kind, phase))
+            for name, d in per_phase.items():
+                h = self._hists.get((kind, name))
                 if h is None:
-                    h = self._hists[(kind, phase)] = _PhaseHist()
+                    h = self._hists[(kind, name)] = _PhaseHist()
                 h.observe(d)
             if hot and kind in HOT_KINDS:
                 self._window.append(
@@ -289,12 +342,12 @@ class StepAnatomy:
             return {"traceEvents": events, "displayTimeUnit": "ms"}
         t0 = captures[0]["t_start"]
         for i, cap in enumerate(captures):
-            for phase, s0, s1 in cap["spans"]:
+            for span, s0, s1 in cap["spans"]:
                 events.append({
-                    "name": phase,
+                    "name": span,
                     "ph": "X",
                     "pid": pid,
-                    "tid": 2 if phase in DEVICE_PHASES else 1,
+                    "tid": 2 if span in DEVICE_PHASES else 1,
                     "ts": (s0 - t0) * 1e6,
                     "dur": max(0.0, s1 - s0) * 1e6,
                     "args": {"step": i, "kind": cap["kind"]},
@@ -386,14 +439,24 @@ class StepAnatomy:
             items = [(k, h.count, h.sum, h.quantile(0.5))
                      for k, h in sorted(self._hists.items())]
         out: Dict[str, Dict[str, Dict]] = {}
-        for (kind, phase), count, total, p50 in items:
-            out.setdefault(kind, {})[phase] = {
+        for (kind, name), count, total, p50 in items:
+            out.setdefault(kind, {})[name] = {
                 "count": count,
                 "total_s": total,
                 "mean_s": total / count if count else 0.0,
                 "p50_s": p50,
             }
         return out
+
+    def cumulative(self) -> Dict[str, Dict]:
+        """``{"<kind>.<phase>": {"count", "total_s"}}`` since start: the
+        ``step_phases`` entry of ``/v2/stats``. Monotone, so the delta
+        between two snapshots is what happened between them."""
+        with self._lock:
+            return {
+                f"{kind}.{name}": {"count": h.count, "total_s": h.sum}
+                for (kind, name), h in sorted(self._hists.items())
+            }
 
     def report(self) -> Dict:
         """The ``GET /v2/debug/anatomy`` payload for one unit."""
@@ -418,20 +481,23 @@ class StepAnatomy:
         buckets, sorted for deterministic rendering."""
         with self._lock:
             items = [
-                (kind, phase, h.buckets(), h.sum, h.count)
-                for (kind, phase), h in sorted(self._hists.items())
+                (kind, name, h.buckets(), h.sum, h.count)
+                for (kind, name), h in sorted(self._hists.items())
             ]
         return [
-            {"kind": kind, "phase": phase, "buckets": buckets,
+            {"kind": kind, "phase": name, "buckets": buckets,
              "sum": total, "count": count}
-            for kind, phase, buckets, total, count in items
+            for kind, name, buckets, total, count in items
         ]
 
     def register_gauges(self, stats) -> None:
         """Surface the window-derived signals as ServingStats gauges
         (``flexflow_serving_step_*`` on /metrics). A gauge returning
         None is skipped by the exposition — a disabled or not-yet-warm
-        anatomy emits nothing rather than zeros that look like data."""
+        anatomy emits nothing rather than zeros that look like data.
+        The cumulative phase sums join ``/v2/stats`` as ``step_phases``."""
+        if self.enabled:
+            stats.add_section("step_phases", self.cumulative)
         stats.add_gauge("step_device_bubble_ratio", self.device_bubble_ratio)
         stats.add_gauge(
             "step_host_bound",

@@ -19,6 +19,8 @@ from typing import Iterator, List, Optional, Sequence
 import jax
 import numpy as np
 
+from ..obs.steptrace import phase
+
 _native_gather = None  # cached: function, or False after a failed import
 
 
@@ -137,7 +139,9 @@ class DataLoader:
                 for _ in range(self.num_batches):
                     if stop.is_set():
                         return
-                    vals = [next(it) for it in iters]
+                    # numpy gather -> device_put, on the prefetch thread
+                    with phase("data.produce"):
+                        vals = [next(it) for it in iters]
                     if not put((vals[:-1], vals[-1])):
                         return
                 put(None)
@@ -148,7 +152,10 @@ class DataLoader:
         t.start()
         try:
             while True:
-                item = q.get()
+                # the consumer blocked on the prefetch queue: device idle
+                # under this span is the loader's, not the step's
+                with phase("data.wait"):
+                    item = q.get()
                 if item is None:
                     break
                 if isinstance(item, Exception):

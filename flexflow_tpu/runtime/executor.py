@@ -18,7 +18,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
-import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -28,6 +27,7 @@ import jax.numpy as jnp
 from ..core.graph import PCGraph, Node
 from ..core.types import CompMode, LossType, MetricsType, OpType
 from ..obs.capacity import GLOBAL_PROGRAMS
+from ..obs.steptrace import phase
 from ..obs.truth import GLOBAL_LEDGER
 from ..ops.base import LowerCtx, get_op_def
 from ..parallel.propagation import infer_all_specs
@@ -369,7 +369,10 @@ class CompiledExecutor:
             weights.update(params.get(nkey, {}))
             weights.update(state.get(nkey, {}))
             ctx.node_guid = node.guid
-            outs = op_def.lower(node.params, node_inputs, weights, ctx)
+            # the layer's name lands in its instructions' op_name, so a
+            # device trace can be grouped by layer
+            with jax.named_scope(node.name or nkey):
+                outs = op_def.lower(node.params, node_inputs, weights, ctx)
             for i, o in enumerate(outs):
                 values[(node.guid, i)] = self._constrain_output(node.guid, i, o)
         new_state = _apply_state_updates(state, ctx.state_updates, self.graph)
@@ -393,7 +396,8 @@ class CompiledExecutor:
             weights.update(params.get(nkey, {}))
             weights.update(state.get(nkey, {}))
             ctx.node_guid = node.guid
-            outs = op_def.lower(node.params, node_inputs, weights, ctx)
+            with jax.named_scope(node.name or nkey):
+                outs = op_def.lower(node.params, node_inputs, weights, ctx)
             for i, o in enumerate(outs):
                 values[(node.guid, i)] = (
                     self._constrain_output(node.guid, i, o) if constrain else o
@@ -732,7 +736,8 @@ class CompiledExecutor:
             def objective(p, st, ins, lab, r):
                 outs, new_state, aux = self._forward_impl(p, st, ins, r, training=True)
                 final = outs[-1]
-                loss = loss_fn(final, lab)
+                with jax.named_scope("loss"):
+                    loss = loss_fn(final, lab)
                 # aux is a Python LIST of scalar aux losses — pytree
                 # structure iteration at trace time, not a traced array
                 for a in aux:  # flexlint: disable=jit-discipline
@@ -796,7 +801,8 @@ class CompiledExecutor:
                     return jnp.sum(v)
 
                 mets = {k: merge(k, v) for k, v in mets_all.items()}
-            new_params, new_opt_state = self.optimizer.apply(params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = self.optimizer.apply(params, grads, opt_state)
             if self._zero_specs is not None:
                 # ZeRO-1: pin the updated moments back onto their
                 # data-axis shards so GSPMD keeps them distributed
@@ -926,18 +932,13 @@ class CompiledExecutor:
         if self.opt_state is not None and "lr" in self.opt_state:
             self.opt_state["lr"] = jnp.asarray(lr, jnp.float32)
 
-    def train_batch(self, inputs: Sequence[jax.Array], label: jax.Array, rng: jax.Array) -> Dict[str, Any]:
-        # chaos hook (no-op unless a FaultPlan is installed): rules can
-        # raise a device error, stall, or NaN-poison the batch
-        inputs = faults.inject(faults.EXECUTOR_TRAIN_BATCH, inputs)
-        inputs = self._shard_inputs(inputs)
-        if jax.process_count() > 1:
-            label = self.shard_label(label)
-        # truth-ledger measurement (sampled — see _truth_sample): the
-        # default fit loop (trace_window=1) runs THIS program, so the
-        # simulator's step prediction must pair here too, not only on
-        # the traced multi-step windows below
-        program = f"{self._prog_ns}.train_step"
+    def _run_train_program(self, program: str, jitted, inputs, label, rng,
+                           num_steps: int) -> Dict[str, Any]:
+        """Dispatch one train program (``ff.train.dispatch``) and, on
+        the sampled calls (see _truth_sample), measure it for the truth
+        ledger: the timing includes a metrics sync — telemetry, not
+        billing. Both drains of a measured call are ``ff.train.truth_sync``
+        spans, so a trace shows which device idle the ledger bought."""
         measure = self._truth_sample(program)
         traces_before = GLOBAL_PROGRAMS.trace_count(program) if measure else 0
         if measure:
@@ -945,17 +946,34 @@ class CompiledExecutor:
             # unmeasured calls between samples never sync, so the device
             # may still be running earlier steps — timing them into this
             # window would over-report step time and false-alarm drift
-            jax.block_until_ready(self.params)
-        t0 = time.perf_counter() if measure else 0.0
-        self.params, self.opt_state, self.state, mets = self._train_step(
-            self.params, self.opt_state, self.state, tuple(inputs), label, rng
-        )
+            with phase("train.truth_sync"):
+                jax.block_until_ready(self.params)
+        with phase("train.dispatch") as dispatch:
+            self.params, self.opt_state, self.state, mets = jitted(
+                self.params, self.opt_state, self.state, tuple(inputs), label, rng
+            )
         if measure:
-            jax.block_until_ready(mets)
+            with phase("train.truth_sync") as sync:
+                jax.block_until_ready(mets)
             self._measure_window_step(
-                program, traces_before, time.perf_counter() - t0, 1
+                program, traces_before, sync.t1 - dispatch.t0, num_steps
             )
         return mets
+
+    def train_batch(self, inputs: Sequence[jax.Array], label: jax.Array, rng: jax.Array) -> Dict[str, Any]:
+        # chaos hook (no-op unless a FaultPlan is installed): rules can
+        # raise a device error, stall, or NaN-poison the batch
+        inputs = faults.inject(faults.EXECUTOR_TRAIN_BATCH, inputs)
+        with phase("train.shard_inputs"):
+            inputs = self._shard_inputs(inputs)
+            if jax.process_count() > 1:
+                label = self.shard_label(label)
+        # the default fit loop (trace_window=1) runs THIS program, so
+        # the simulator's step prediction must pair here too, not only
+        # on the traced multi-step windows below
+        return self._run_train_program(
+            f"{self._prog_ns}.train_step", self._train_step, inputs, label, rng, 1
+        )
 
     def _scan_train_steps(self, w: int, per_step_xs: bool):
         """Get-or-build the jitted program running ``w`` train steps as
@@ -1011,29 +1029,14 @@ class CompiledExecutor:
         if self.optimizer is None:
             raise RuntimeError("train_batch_repeated requires a compiled optimizer")
         jitted = self._scan_train_steps(num_steps, per_step_xs=False)
-        inputs = self._shard_inputs(inputs)
-        if jax.process_count() > 1:
-            label = self.shard_label(label)
-        # truth-ledger measurement (sampled — see _truth_sample): the
-        # timing includes a metrics sync — telemetry, not billing
-        program = f"{self._prog_ns}.train_repeat[{num_steps}]"
-        measure = self._truth_sample(program)
-        traces_before = GLOBAL_PROGRAMS.trace_count(program) if measure else 0
-        if measure:
-            # drain async dispatch backlog BEFORE the timer starts: the
-            # unmeasured calls between samples never sync, so the device
-            # may still be running earlier steps — timing them into this
-            # window would over-report step time and false-alarm drift
-            jax.block_until_ready(self.params)
-        t0 = time.perf_counter() if measure else 0.0
-        self.params, self.opt_state, self.state, mets = jitted(
-            self.params, self.opt_state, self.state, tuple(inputs), label, rng
+        with phase("train.shard_inputs"):
+            inputs = self._shard_inputs(inputs)
+            if jax.process_count() > 1:
+                label = self.shard_label(label)
+        mets = self._run_train_program(
+            f"{self._prog_ns}.train_repeat[{num_steps}]", jitted, inputs, label,
+            rng, num_steps,
         )
-        if measure:
-            jax.block_until_ready(mets)
-            self._measure_window_step(
-                program, traces_before, time.perf_counter() - t0, num_steps
-            )
         return jax.tree.map(lambda m: m[-1], mets)
 
     def train_window(
@@ -1047,27 +1050,12 @@ class CompiledExecutor:
             raise RuntimeError("train_window requires a compiled optimizer")
         w = int(inputs[0].shape[0])
         jitted = self._scan_train_steps(w, per_step_xs=True)
-        inputs = self._shard_inputs(inputs, leading_axis=True)
-        labels = self.shard_label(labels, leading_axis=True)
-        program = f"{self._prog_ns}.train_window[{w}]"
-        measure = self._truth_sample(program)
-        traces_before = GLOBAL_PROGRAMS.trace_count(program) if measure else 0
-        if measure:
-            # drain async dispatch backlog BEFORE the timer starts: the
-            # unmeasured calls between samples never sync, so the device
-            # may still be running earlier steps — timing them into this
-            # window would over-report step time and false-alarm drift
-            jax.block_until_ready(self.params)
-        t0 = time.perf_counter() if measure else 0.0
-        self.params, self.opt_state, self.state, mets = jitted(
-            self.params, self.opt_state, self.state, tuple(inputs), labels, rng
+        with phase("train.shard_inputs"):
+            inputs = self._shard_inputs(inputs, leading_axis=True)
+            labels = self.shard_label(labels, leading_axis=True)
+        return self._run_train_program(
+            f"{self._prog_ns}.train_window[{w}]", jitted, inputs, labels, rng, w
         )
-        if measure:
-            jax.block_until_ready(mets)
-            self._measure_window_step(
-                program, traces_before, time.perf_counter() - t0, w
-            )
-        return mets
 
     def eval_window(
         self, inputs: Sequence[jax.Array], labels: jax.Array, rng: Optional[jax.Array] = None

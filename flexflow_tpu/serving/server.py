@@ -127,6 +127,7 @@ from ..obs import (
     parse_traceparent,
     render_prometheus,
 )
+from ..obs.steptrace import phase
 from ..runtime import faults
 from .batcher import DynamicBatcher, make_batcher
 from .model import InferenceModel
@@ -799,43 +800,50 @@ class InferenceServer:
                 gen = server.generators.get(name)
                 if gen is None:
                     return self._json(404, {"error": f"unknown generation model {name}"})
+                # the front end's two spans, timed from inside: body read
+                # -> submit returned, and (streaming) first token taken
+                # off the handle -> its SSE event flushed. A fleet's
+                # aggregate stats view takes no observations.
+                observe = getattr(gen.stats, "observe", None) or (lambda n, s: None)
                 try:
-                    length = int(self.headers.get("Content-Length", 0))
-                    req = json.loads(self.rfile.read(length))
-                    prompt = [int(t) for t in req["prompt"]]
-                    sampling = gen.sampling_from(req)
-                    stream = bool(req.get("stream", False))
-                    timeout_ms = (req.get("parameters") or {}).get(
-                        "timeout_ms", self.headers.get("X-Request-Timeout-Ms")
-                    )
-                    deadline_s = None if timeout_ms is None else float(timeout_ms) / 1000.0
-                    speculation = gen.speculation_from(req)
-                    # priority class: body field first, then the
-                    # X-Request-Priority header (absent -> standard)
-                    priority = req.get(
-                        "priority", self.headers.get("X-Request-Priority")
-                    )
-                    response_format = gen.response_format_from(req)
-                    # journey ingress: mint (or join the client's W3C
-                    # traceparent) only when the target unit records
-                    # journeys — journeys-off deployments stay inert
-                    journey = None
-                    if getattr(gen, "journeys", None) is not None:
-                        journey = server.journeys.mint(
-                            parent=parse_traceparent(
-                                self.headers.get("traceparent")
+                    with phase("http.ingress") as ingress:
+                        length = int(self.headers.get("Content-Length", 0))
+                        req = json.loads(self.rfile.read(length))
+                        prompt = [int(t) for t in req["prompt"]]
+                        sampling = gen.sampling_from(req)
+                        stream = bool(req.get("stream", False))
+                        timeout_ms = (req.get("parameters") or {}).get(
+                            "timeout_ms", self.headers.get("X-Request-Timeout-Ms")
+                        )
+                        deadline_s = None if timeout_ms is None else float(timeout_ms) / 1000.0
+                        speculation = gen.speculation_from(req)
+                        # priority class: body field first, then the
+                        # X-Request-Priority header (absent -> standard)
+                        priority = req.get(
+                            "priority", self.headers.get("X-Request-Priority")
+                        )
+                        response_format = gen.response_format_from(req)
+                        # journey ingress: mint (or join the client's W3C
+                        # traceparent) only when the target unit records
+                        # journeys — journeys-off deployments stay inert
+                        journey = None
+                        if getattr(gen, "journeys", None) is not None:
+                            journey = server.journeys.mint(
+                                parent=parse_traceparent(
+                                    self.headers.get("traceparent")
+                                )
                             )
+                            journey.hop(
+                                "ingress", transport="http", model=name,
+                                stream=stream, prompt_len=len(prompt),
+                            )
+                        handle = gen.submit(
+                            prompt, sampling, deadline_s=deadline_s,
+                            speculation=speculation, transport="http",
+                            priority=priority, response_format=response_format,
+                            journey=journey,
                         )
-                        journey.hop(
-                            "ingress", transport="http", model=name,
-                            stream=stream, prompt_len=len(prompt),
-                        )
-                    handle = gen.submit(
-                        prompt, sampling, deadline_s=deadline_s,
-                        speculation=speculation, transport="http",
-                        priority=priority, response_format=response_format,
-                        journey=journey,
-                    )
+                    observe("http_ingress", ingress.seconds)
                 except ResilienceError as e:
                     return self._json(
                         http_status(e), _reject_payload(e),
@@ -907,7 +915,12 @@ class InferenceServer:
                 count = 0
                 try:
                     for tok in handle.tokens(timeout=wait):
-                        event({"token": int(tok), "index": count}, eid=count)
+                        if count == 0:
+                            with phase("http.first_write", request=handle._request.id) as first:
+                                event({"token": int(tok), "index": 0}, eid=0)
+                            observe("http_first_write", first.seconds)
+                        else:
+                            event({"token": int(tok), "index": count}, eid=count)
                         count += 1
                     done = {"done": True, "tokens": handle.result(timeout=wait)}
                     if durable_id is not None:

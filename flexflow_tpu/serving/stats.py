@@ -133,7 +133,16 @@ class ServingStats:
     """Counters + latency windows + histograms + live gauges for one
     served model. ``observe(name, s)`` feeds a named window (rolling
     percentiles on /v2/stats) AND a Prometheus histogram (/metrics)
-    under the same name — queue_time / ttft / tpot in generation."""
+    under the same name — queue_time / ttft / tpot in generation, and
+    the spans timed where the work happens (http_ingress,
+    http_first_write, cache_offload, cache_restore, admit_stall).
+    On /v2/stats every window's entry also carries its histogram's
+    ``count_total`` / ``sum_total_s``: monotone since start, so a
+    reader takes the delta between two snapshots (what
+    ``rate(sum) / rate(count)`` computes from /metrics) instead of a
+    percentile over whichever ``latency_window`` observations came
+    last. The rolling percentiles stay for the limiter and the SLO
+    monitor, which want exactly that."""
 
     COUNTERS = ("admitted", "rejected", "expired", "completed", "failed", "cancelled")
 
@@ -149,6 +158,10 @@ class ServingStats:
         self.gauges: Dict[str, Callable[[], float]] = {}  # guarded-by: _lock
         self._windows: Dict[str, LatencyWindow] = {}  # guarded-by: _lock
         self._histograms: Dict[str, Histogram] = {}  # guarded-by: _lock
+        # name -> zero-arg callable returning a JSON-able dict, for
+        # structured entries of /v2/stats that are not one number (the
+        # step anatomy's cumulative phase sums); not on /metrics
+        self._sections: Dict[str, Callable[[], Dict]] = {}  # guarded-by: _lock
 
     def incr(self, counter: str, n: int = 1) -> None:
         with self._lock:
@@ -165,6 +178,10 @@ class ServingStats:
     def add_gauge(self, name: str, fn: Callable[[], float]) -> None:
         with self._lock:
             self.gauges[name] = fn
+
+    def add_section(self, name: str, fn: Callable[[], Dict]) -> None:
+        with self._lock:
+            self._sections[name] = fn
 
     def observe(self, name: str, seconds: float,
                 exemplar: Optional[str] = None) -> None:
@@ -227,9 +244,19 @@ class ServingStats:
     def snapshot(self) -> Dict:
         out: Dict = dict(self.counters())
         out["latency"] = self.latency.snapshot()
-        for name, snap in self.window_snapshots().items():
-            out[name] = snap
+        with self._lock:
+            pairs = [(n, w, self._histograms[n]) for n, w in self._windows.items()]
+            sections = list(self._sections.items())
+        for name, w, h in pairs:
+            # histogram first: the window is then never behind it
+            total = h.snapshot()
+            out[name] = dict(w.snapshot(), count_total=total["count"], sum_total_s=total["sum"])
         out.update(self.gauge_values())
+        for name, fn in sections:
+            try:
+                out[name] = fn()
+            except Exception:  # like a dying gauge: never kill a scrape
+                out[name] = None
         return out
 
 

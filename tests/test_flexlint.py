@@ -57,14 +57,20 @@ class TestClockRule:
     def test_whitelist_file(self):
         src = "import time\n\ndef f():\n    return time.perf_counter()\n"
         assert findings(src, "clock-discipline", relpath="tools/genbench.py") == []
-        # the engine whitelist covers perf_counter ONLY (PR 6 dual-stamp)
+        # the scheduler whitelist covers perf_counter ONLY (PR 6 dual-stamp)
         assert findings(
             src, "clock-discipline",
-            relpath="flexflow_tpu/generation/engine.py",
+            relpath="flexflow_tpu/generation/scheduler.py",
         ) == []
         wall = "import time\n\ndef f():\n    return time.time()\n"
         assert len(findings(
             wall, "clock-discipline",
+            relpath="flexflow_tpu/generation/scheduler.py",
+        )) == 1
+        # the engine opens its spans through obs/steptrace.phase and
+        # reads no clock of its own any more (ISSUE 23)
+        assert len(findings(
+            src, "clock-discipline",
             relpath="flexflow_tpu/generation/engine.py",
         )) == 1
 
