@@ -125,6 +125,7 @@ def kernels_phase(rs) -> dict:
 
     from flexflow_tpu.ops.attention import reference_attention
     from flexflow_tpu.ops.kernels.decode_attention import (
+        cache_row_shape,
         paged_append_attention,
         paged_kernel_refusal,
         reference_paged_append_attention,
@@ -134,10 +135,15 @@ def kernels_phase(rs) -> dict:
     out = {}
     b, h, d, bs, max_blocks = 8, 16, 64, 16, 64
     nb = b * max_blocks + 1
-    k_cache = jnp.asarray(rs.randn(nb, bs, h, d), jnp.float32)
-    v_cache = jnp.asarray(rs.randn(nb, bs, h, d), jnp.float32)
+    # a whole cache of three layers, stored as the engine stores it (two
+    # heads of 64 to a 128-lane row): the kernel takes all of it and a
+    # static layer index
+    layers, rows = 3, cache_row_shape(h, d)
+    check(rows == (8, 128), f"16 heads of 64 should be stored as 8 x 128, not {rows}")
+    k_cache = jnp.asarray(rs.randn(layers, nb, bs, *rows), jnp.float32)
+    v_cache = jnp.asarray(rs.randn(layers, nb, bs, *rows), jnp.float32)
     tables = jnp.asarray(1 + rs.permutation(nb - 1).reshape(b, max_blocks), jnp.int32)
-    for w in (1, 5, 32):
+    for layer, w in enumerate((1, 5, 32)):
         check(paged_kernel_refusal(h, d, bs, w) is None, f"gate refuses W={w}")
         q = jnp.asarray(rs.randn(b, w, h, d), jnp.float32)
         base = rs.randint(0, max_blocks * bs - w, size=b)
@@ -147,10 +153,10 @@ def kernels_phase(rs) -> dict:
             qpos[0, :] = -1  # one wholly inactive row
         qpos = jnp.asarray(qpos, jnp.int32)
         with jax.default_matmul_precision("highest"):
-            ref = reference_paged_append_attention(q, k_cache, v_cache, tables, qpos)
+            ref = reference_paged_append_attention(q, k_cache, v_cache, layer, tables, qpos)
         for splits in (1, 4):
             got = jax.jit(
-                functools.partial(paged_append_attention, kv_splits=splits)
+                lambda q, k, v, t, p: paged_append_attention(q, k, v, layer, t, p, kv_splits=splits)
             )(q, k_cache, v_cache, tables, qpos)
             err = float(jnp.max(jnp.abs(got - ref)))
             check(np.isfinite(err) and err <= 1e-3, f"paged W={w} splits={splits}: err {err}")
@@ -159,7 +165,7 @@ def kernels_phase(rs) -> dict:
                 f"paged W={w} splits={splits}: padding queries must emit zeros",
             )
             out[f"paged_w{w}_s{splits}_max_abs_err"] = err
-    log(f"paged kernel matches the reference: {out}")
+    log(f"paged kernel on layers 0-2 of a 5-D cache of 8 x 128 rows matches the reference: {out}")
 
     q, k, v = (jnp.asarray(rs.randn(2, 512, 16, 64), jnp.bfloat16) for _ in range(3))
     wgt = jnp.asarray(rs.randn(2, 512, 16, 64), jnp.float32)
@@ -200,27 +206,49 @@ def decoder_config(num_layers: int):
     )
 
 
-def paged_program_mosaic_calls(engine, window: int = 0) -> int:
-    """Mosaic custom calls in the engine's decode program (``window`` 0)
-    or in its one-sequence append program at ``window`` (suffix prefill),
-    lowered through the dispatch the engine's own jits take."""
+def suffix_prefill_mosaic_calls(engine, window: int) -> int:
+    """Mosaic custom calls in the one-sequence append program at
+    ``window`` (suffix prefill), lowered through the dispatch the
+    engine's own jits take."""
     import jax.numpy as jnp
 
-    from flexflow_tpu.generation.decoder import decode_step, verify_step
+    from flexflow_tpu.generation.decoder import verify_step
 
     zi = lambda *s: jnp.zeros(s, jnp.int32)
-    cache = (engine.cache.k, engine.cache.v)
-    kw = dict(backend=engine.backend, mesh=engine._kernel_mesh)
-    b, mb = engine.max_batch_slots, engine.max_blocks_per_seq
-    if window:
-        return mosaic_calls(
-            functools.partial(verify_step, **kw),
-            engine.params, zi(1, window), zi(1, window), *cache, zi(1, mb),
-        )
     return mosaic_calls(
-        functools.partial(decode_step, **kw),
-        engine.params, zi(b), zi(b), *cache, zi(b, mb), zi(b),
+        functools.partial(verify_step, backend=engine.backend, mesh=engine._kernel_mesh),
+        engine.params, zi(1, window), zi(1, window), engine.cache.k, engine.cache.v,
+        zi(1, engine.max_blocks_per_seq),
     )
+
+
+def decode_program_facts(engine) -> dict:
+    """The engine's OWN decode jit (both cache arrays donated), compiled
+    for the shapes it serves: Mosaic calls, and what
+    ``memory_analysis()`` says it holds beside its arguments. The
+    program is in the compile cache by now."""
+    import numpy as np
+
+    b, mb, v = engine.max_batch_slots, engine.max_blocks_per_seq, engine.cfg.vocab_size
+    z = lambda dtype, *s: engine._dev(np.zeros(s, dtype))
+    i32, f32 = np.int32, np.float32
+    compiled = engine._decode_jit.lower(
+        engine.params, z(i32, b), z(i32, b), engine.cache.k, engine.cache.v, z(i32, b, mb),
+        z(i32, b), z(f32, b), z(i32, b), z(f32, b), z(np.uint32, b), z(i32, b), z(f32, b, v),
+    ).compile()
+    m = compiled.memory_analysis()
+    return {
+        "mosaic_calls": compiled.as_text().count(MOSAIC_CALL),
+        "argument_bytes": int(m.argument_size_in_bytes),
+        "temp_bytes": int(m.temp_size_in_bytes),
+        "alias_bytes": int(m.alias_size_in_bytes),
+    }
+
+
+def cache_device_bytes(engine) -> int:
+    """What one shard of K plus V holds on its device, padding and all."""
+    return sum(a.addressable_shards[0].data.on_device_size_in_bytes()
+               for a in (engine.cache.k, engine.cache.v))
 
 
 def make_requests(rs, vocab: int, prompt_lens) -> list:
@@ -347,6 +375,7 @@ ZERO_COUNTERS = (
 
 def server_phase(rs, num_layers: int = 24) -> dict:
     import jax
+    import numpy as np
 
     from flexflow_tpu.generation import GenerationEngine, init_decoder_params
     from flexflow_tpu.ops.kernels.decode_attention import paged_kernel_refusal
@@ -369,6 +398,15 @@ def server_phase(rs, num_layers: int = 24) -> dict:
     )
     check(engine.donate, "cache donation must be on for a TPU backend")
     check(engine.cache.k is not engine.cache.v, "K and V must not share one buffer")
+    # stored as [L, nb, bs, 8, 128], the cache's default layout on the
+    # chip is the row-major one the paged kernel reads, and pads nothing
+    cache_dev = cache_device_bytes(engine)
+    log(f"cache on the device: {engine.cache.k.shape} x 2 = {cache_dev / 2**30:.2f} GiB, "
+        f"layout {engine.cache.k.format.layout}")
+    check(cache_dev == cc.total_bytes, f"the cache holds {cache_dev} B on the device, nominal {cc.total_bytes}")
+    check(engine.cache.k.format.layout.major_to_minor == (0, 1, 2, 3, 4),
+          f"the cache's default layout is not row-major: {engine.cache.k.format.layout}")
+    out["cache_device_bytes"] = cache_dev
 
     bodies = make_requests(rs, cfg.vocab_size, PROMPT_LENS)
     # a follow-up that shares request 2's prompt: its full blocks are in
@@ -450,12 +488,35 @@ def server_phase(rs, num_layers: int = 24) -> dict:
             f"the kernel gate refuses the served shape (window {window})",
         )
     w = engine.bucket_for(len(suffix))
-    n_decode = paged_program_mosaic_calls(engine)
-    n_suffix = paged_program_mosaic_calls(engine, window=w)
+    facts = decode_program_facts(engine)
+    n_decode = facts["mosaic_calls"]
+    n_suffix = suffix_prefill_mosaic_calls(engine, w)
     log(f"Mosaic custom calls: decode {n_decode}, suffix prefill[{w}] {n_suffix} (one per layer)")
     check(n_decode == num_layers, f"decode program holds {n_decode} Mosaic calls, want {num_layers}")
     check(n_suffix == num_layers, f"suffix-prefill program holds {n_suffix} Mosaic calls")
-    out.update(mosaic_decode=n_decode, mosaic_suffix_prefill=n_suffix)
+    out.update(mosaic_decode=n_decode, mosaic_suffix_prefill=n_suffix, decode_program=facts)
+    # no cache-sized copy in the token loop: the program aliases both
+    # cache arrays to its results and holds no cache-sized temporary
+    log(f"decode program memory_analysis: arguments {facts['argument_bytes'] / 2**30:.2f} GiB, "
+        f"temporaries {facts['temp_bytes'] / 2**30:.3f} GiB, aliased {facts['alias_bytes'] / 2**30:.2f} GiB")
+    check(facts["alias_bytes"] >= cache_dev, f"decode aliases {facts['alias_bytes']} B, the cache is {cache_dev} B")
+    check(facts["temp_bytes"] < cache_dev // 4,
+          f"decode holds {facts['temp_bytes']} B of temporaries beside a {cache_dev} B cache")
+    # the block programs the served traffic did not reach: a swap-in
+    # write, a host-tier read and the batched wire pair; off the device a
+    # block has its logical [L, bs, H, D] shape and comes back bit for bit
+    blk = engine.allocator.allocate(2)
+    hk, hv = (rs.randn(num_layers, cc.block_size, cfg.num_heads, head_dim).astype(np.float32)
+              for _ in range(2))
+    engine.import_kv_block(blk[0], hk, hv)
+    rk, rv = engine._read_block_jit(engine.cache.k, engine.cache.v, np.int32(blk[0]))
+    check(np.array_equal(np.asarray(rk), hk) and np.array_equal(np.asarray(rv), hv),
+          "kv_block_write then kv_block_read does not return the block")
+    payload = engine.pack_kv_blocks(blk[:1], cc.block_size)
+    engine.import_kv_blocks(blk[1:], payload.blocks)
+    rk, _ = engine._read_block_jit(engine.cache.k, engine.cache.v, np.int32(blk[1]))
+    check(np.array_equal(np.asarray(rk), hk), "kv_blocks_read then kv_blocks_write does not return the block")
+    engine.allocator.free(blk)
     mem = jax.devices()[0].memory_stats() or {}
     out["peak_bytes_in_use"] = mem.get("peak_bytes_in_use")
     log(f"device memory: peak {mem.get('peak_bytes_in_use', 0) / 2**30:.2f} GiB "
@@ -623,9 +684,14 @@ def four_chip_phase(rs, num_layers: int = 4) -> dict:
         if tp == 4:
             shards = engine.cache.k.addressable_shards
             check(len({s.device.id for s in shards}) == 4, "tp=4 cache is not on four devices")
-            check(shards[0].data.shape[3] == cfg.num_heads // 4, "tp=4 cache is not head-sharded")
-            n = paged_program_mosaic_calls(engine)
+            check(shards[0].data.shape[3] == engine.cache.k.shape[3] // 4,
+                  "tp=4 cache is not sharded on its rows (heads)")
+            facts = decode_program_facts(engine)
+            n = facts["mosaic_calls"]
             check(n == num_layers, f"tp=4 decode holds {n} Mosaic calls, want {num_layers}")
+            check(facts["alias_bytes"] >= cache_device_bytes(engine),
+                  f"tp=4 decode does not alias its cache shards: {facts}")
+            out["server_tp4_decode_program"] = facts
         streams[tp] = direct_generate(engine, bodies)
         check_streams(bodies, streams[tp], cfg.vocab_size, f"tp={tp}")
         out[f"server_tp{tp}"] = check_teacher_forcing(params, bodies, streams[tp], f"server[tp={tp}]")

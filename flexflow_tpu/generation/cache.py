@@ -10,6 +10,32 @@ the same cache shape regardless of how many sequences are live or how
 long they've grown. The reference has no KV cache at all (its attention
 is a one-shot cuDNN call, SURVEY §2.2).
 
+"Nothing is moved" holds on the device because of three things that only
+work together (ISSUE 24; with any one missing the compiler puts
+cache-sized copies back into every decode step):
+
+* the arrays are **stored in a shape whose default device layout is the
+  one the paged kernel reads**: ``[L, num_blocks, block_size, R, LW]``,
+  a position's ``H x D`` values laid head after head over ``R`` rows of
+  ``LW`` lanes (``CacheConfig.row_shape``; two heads of 64 share a
+  128-lane row, so 16 x 64 is stored as 8 x 128). Stored as ``[..., 16,
+  64]`` the TPU compiler lays the array out with ``num_blocks`` on the
+  lanes, because a ``head_dim`` of 64 half-fills them; a Mosaic kernel
+  reads its operands row-major, so every program that called the paged
+  kernel converted both arrays on entry and back on exit. The default
+  layout of ``[..., 8, 128]`` is row-major and unpadded, and the default
+  is what every program agrees on without being told — including one
+  loaded from the persistent compile cache, which drops a layout
+  declared through ``jax.experimental.layout`` (measured, PR 24). A
+  row-major reshape gives the logical ``[..., H, D]`` view wherever one
+  is wanted (the XLA composition, the block programs' host side);
+* the decode/verify programs **donate** both arrays and write a step's
+  rows with one scatter on the whole 5-D operand
+  (``decoder.py::write_rows``) — no layer is ever sliced out, rebuilt
+  and written back;
+* the paged kernel takes the **whole cache and a static layer index**
+  (``ops/kernels/decode_attention.py``) and DMAs single blocks out of it.
+
 Block 0 is reserved as a **scratch block**: padded prompt positions and
 inactive decode slots scatter their (meaningless) K/V there, so the
 jitted steps never need dynamic shapes or masked scatters to avoid
@@ -26,6 +52,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.types import DataType
+from ..ops.kernels.decode_attention import cache_row_shape
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +61,9 @@ class CacheConfig:
 
     ``num_blocks`` INCLUDES the reserved scratch block 0, so the usable
     capacity is ``(num_blocks - 1) * block_size`` token positions.
+    ``kv_shards`` is the serving mesh's tensor-parallel degree: the
+    arrays shard their row axis over it, so heads are packed into rows
+    shard by shard (:attr:`row_shape`).
     """
 
     num_layers: int
@@ -42,12 +72,22 @@ class CacheConfig:
     num_blocks: int
     block_size: int = 16
     dtype: DataType = DataType.FLOAT
+    kv_shards: int = 1
 
     def __post_init__(self):
         if self.num_blocks < 2:
             raise ValueError("num_blocks must be >= 2 (block 0 is scratch)")
         if self.block_size < 1:
             raise ValueError("block_size must be >= 1")
+
+    @property
+    def row_shape(self) -> Tuple[int, int]:
+        """``(R, LW)``: one position's ``H x D`` values as the arrays
+        store them (ops/kernels/decode_attention.py::cache_row_shape,
+        applied to one shard's heads so that sharding the row axis is
+        sharding the heads)."""
+        rows, lanes = cache_row_shape(self.num_heads // self.kv_shards, self.head_dim)
+        return rows * self.kv_shards, lanes
 
     @property
     def bytes_per_block(self) -> int:
@@ -116,6 +156,7 @@ class CacheConfig:
             num_blocks=int(num_blocks),
             block_size=block_size,
             dtype=dtype,
+            kv_shards=kv_shards,
         )
 
     @classmethod
@@ -164,14 +205,16 @@ class CacheConfig:
 
 class KVCache:
     """Device storage: ``k``/``v`` of shape [L, num_blocks, block_size,
-    H, D]. Functional updates — jitted steps take the arrays and return
-    replacements; this object just holds the current ones.
+    R, LW] (``CacheConfig.row_shape``; [..., H, D] after a row-major
+    reshape). Functional updates — jitted steps take the arrays and
+    return replacements; this object just holds the current ones.
 
-    ``sharding`` (a NamedSharding over the serving mesh, heads sharded —
-    generation/sharding.py) commits the arrays across the mesh at
-    creation AND at every :meth:`reset`: crash recovery must hand the
-    jits a cache with the exact sharding they were compiled for, or the
-    first replay step would silently recompile every program."""
+    ``sharding`` (a NamedSharding over the serving mesh, rows — that is,
+    heads — sharded: generation/sharding.py) commits the arrays across
+    the mesh at creation AND at every :meth:`reset`: crash recovery must
+    hand the jits a cache with the exact sharding they were compiled
+    for, or the first replay step would silently recompile every
+    program."""
 
     def __init__(self, config: CacheConfig, k: jax.Array, v: jax.Array,
                  sharding=None):
@@ -191,8 +234,7 @@ class KVCache:
             config.num_layers,
             config.num_blocks,
             config.block_size,
-            config.num_heads,
-            config.head_dim,
+            *config.row_shape,
         )
         return jnp.zeros(shape, config.dtype.jnp, device=sharding)
 
@@ -292,16 +334,18 @@ class BlockAllocator:
 
 def slot_mapping(
     block_table: jnp.ndarray, positions: jnp.ndarray, block_size: int
-) -> jnp.ndarray:
-    """Flat cache slot (block * block_size + offset) for each position.
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Cache slot ``(block, offset)`` of each position: the two index
+    arrays a step's rows are scattered at (``cache.at[layer, block,
+    offset]``), so a write never needs the cache reshaped or a layer
+    taken out.
 
     ``block_table``: [max_blocks] int32; ``positions``: [...] int32 of
     cache positions. Positions past the table's coverage land in the
-    scratch block (block 0) instead of indexing out of bounds — callers
-    mask those positions out of attention anyway.
+    scratch block (block 0, offset 0) instead of indexing out of bounds
+    — callers mask those positions out of attention anyway.
     """
     block_idx = positions // block_size
-    offset = positions % block_size
     in_range = block_idx < block_table.shape[0]
     block = jnp.where(in_range, block_table[jnp.clip(block_idx, 0, block_table.shape[0] - 1)], 0)
-    return block * block_size + jnp.where(in_range, offset, 0)
+    return block, jnp.where(in_range, positions % block_size, 0)

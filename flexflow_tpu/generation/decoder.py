@@ -163,6 +163,18 @@ def prefill(
         return x @ params["lm_head"], jnp.stack(ks), jnp.stack(vs)
 
 
+def write_rows(cache, layer: int, block, offset, rows):
+    """Write a step's rows ([n, H, D]) of static ``layer`` at
+    ``(block[n], offset[n])`` with ONE scatter on the whole [L,
+    num_blocks, block_size, R, LW] operand (a position's H x D values
+    stored row-major as R x LW: generation/cache.py). With the operand
+    donated the scatter runs in place: the step touches n rows, not a
+    layer. Taking ``cache[layer]`` out, or writing a rebuilt layer back,
+    would make every step copy layer-sized values (ISSUE 24)."""
+    rows = rows.reshape(rows.shape[0], *cache.shape[3:]).astype(cache.dtype)
+    return cache.at[layer, block, offset].set(rows)
+
+
 def decode_step(
     params: DecoderParams,
     tokens: jax.Array,
@@ -177,16 +189,19 @@ def decode_step(
     """One decode step for every batch slot.
 
     tokens/positions: [B] int32 (the token being decoded and its cache
-    position); cache_k/cache_v: [L, num_blocks, block_size, H, D];
-    block_tables: [B, max_blocks]; context_lens: [B] — valid cache
-    positions INCLUDING this token (``positions + 1`` for live slots,
-    0 for inactive ones, whose writes land in scratch block 0).
-    Returns (logits [B, V], cache_k, cache_v) with the K/V written.
+    position); cache_k/cache_v: [L, num_blocks, block_size, R, LW] (the
+    stored form of [..., H, D]); block_tables: [B, max_blocks];
+    context_lens: [B] — valid cache positions INCLUDING this token
+    (``positions + 1`` for live slots, 0 for inactive ones, whose writes
+    land in scratch block 0).
+    Returns (logits [B, V], cache_k, cache_v) with the K/V written:
+    the two arrays pass through whole (rows scattered in place when the
+    caller donates them, the kernel reading blocks of the same arrays).
     """
-    nb, bs = cache_k.shape[1], cache_k.shape[2]
+    bs = cache_k.shape[2]
     with jax.named_scope("embed"):
         x = _embed(params, tokens, positions)  # [B, E]
-        slots = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, positions)
+        block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, positions)
     for li, layer in enumerate(params["layers"]):
         # scope names land in the instructions' op_name: a device trace
         # can be grouped by them (layer<i>/attention | cache_write | mlp)
@@ -199,15 +214,11 @@ def decode_step(
             # write this token's K/V, then attend over the updated cache
             # so the token sees itself (context_lens includes it)
             with jax.named_scope("cache_write"):
-                flat_k = cache_k[li].reshape(nb * bs, *cache_k.shape[3:])
-                flat_v = cache_v[li].reshape(nb * bs, *cache_v.shape[3:])
-                flat_k = flat_k.at[slots].set(k.astype(flat_k.dtype))
-                flat_v = flat_v.at[slots].set(v.astype(flat_v.dtype))
-                cache_k = cache_k.at[li].set(flat_k.reshape(cache_k.shape[1:]))
-                cache_v = cache_v.at[li].set(flat_v.reshape(cache_v.shape[1:]))
+                cache_k = write_rows(cache_k, li, block, offset, k)
+                cache_v = write_rows(cache_v, li, block, offset, v)
             with jax.named_scope("attention"):
                 ctx = decode_attention_core(
-                    q, cache_k[li], cache_v[li], block_tables, context_lens,
+                    q, cache_k, cache_v, li, block_tables, context_lens,
                     backend=backend, mesh=mesh,
                 )
                 x = x + jnp.einsum("bhd,hde->be", ctx, layer["wo"])
@@ -237,7 +248,7 @@ def verify_step(
     window slots (fixed-shape windows with fewer real drafts): their
     K/V scatter to scratch block 0 and their attention/logits rows are
     meaningless (the caller's acceptance logic never reads them).
-    cache_k/cache_v: [L, num_blocks, block_size, H, D]; block_tables:
+    cache_k/cache_v: [L, num_blocks, block_size, R, LW]; block_tables:
     [B, max_blocks]. Returns (logits [B, W, V], cache_k, cache_v) with
     all W tokens' K/V written — accepted positions hold exactly the K/V
     sequential decode would have written (a window token's K/V depends
@@ -245,13 +256,14 @@ def verify_step(
     rejected/later positions hold garbage that the next window
     overwrites before any masked read can see it.
     """
-    nb, bs = cache_k.shape[1], cache_k.shape[2]
+    bs = cache_k.shape[2]
     with jax.named_scope("embed"):
         safe_pos = jnp.maximum(positions, 0)
         x = _embed(params, tokens, safe_pos)  # [B, W, E]
-        slots = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, safe_pos)
-        slots = jnp.where(positions >= 0, slots, 0)  # padding -> scratch
-        flat_slots = slots.reshape(-1)
+        block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, safe_pos)
+        # padding -> scratch block 0, offset 0
+        block = jnp.where(positions >= 0, block, 0).reshape(-1)
+        offset = jnp.where(positions >= 0, offset, 0).reshape(-1)
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
             with jax.named_scope("attention"):
@@ -263,15 +275,11 @@ def verify_step(
             # cache with per-query position masks (each token sees itself
             # and everything before it, nothing after)
             with jax.named_scope("cache_write"):
-                flat_k = cache_k[li].reshape(nb * bs, *cache_k.shape[3:])
-                flat_v = cache_v[li].reshape(nb * bs, *cache_v.shape[3:])
-                flat_k = flat_k.at[flat_slots].set(k.reshape(-1, *k.shape[2:]).astype(flat_k.dtype))
-                flat_v = flat_v.at[flat_slots].set(v.reshape(-1, *v.shape[2:]).astype(flat_v.dtype))
-                cache_k = cache_k.at[li].set(flat_k.reshape(cache_k.shape[1:]))
-                cache_v = cache_v.at[li].set(flat_v.reshape(cache_v.shape[1:]))
+                cache_k = write_rows(cache_k, li, block, offset, k.reshape(-1, *k.shape[2:]))
+                cache_v = write_rows(cache_v, li, block, offset, v.reshape(-1, *v.shape[2:]))
             with jax.named_scope("attention"):
                 ctx = append_attention_core(
-                    q, cache_k[li], cache_v[li], block_tables, positions,
+                    q, cache_k, cache_v, li, block_tables, positions,
                     backend=backend, mesh=mesh,
                 )
                 x = x + jnp.einsum("bwhd,hde->bwe", ctx, layer["wo"])

@@ -59,7 +59,7 @@ from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
 from ..runtime import faults
 from .cache import BlockAllocator, CacheConfig, KVCache, slot_mapping
-from .decoder import DecoderParams, decode_step, prefill, verify_step
+from .decoder import DecoderParams, decode_step, prefill, verify_step, write_rows
 from .prefix import KVHandoffPayload, PackedBlock, PrefixCache, PrefixEntry
 from .sharding import ServingLayout
 
@@ -307,6 +307,9 @@ class GenerationEngine:
                     block_size=block_size,
                     expected_prefix_sharing=expected_prefix_sharing,
                 )
+        if cache_config.kv_shards != self.tp_degree:
+            # rows are packed shard by shard (CacheConfig.row_shape)
+            cache_config = dataclasses.replace(cache_config, kv_shards=self.tp_degree)
         self.cache_config = cache_config
         self.cache = KVCache.create(
             cache_config,
@@ -425,7 +428,14 @@ class GenerationEngine:
         # KV-cache buffer donation on the hot fixed-shape programs: the
         # decode/verify jits alias their cache inputs to their cache
         # outputs, so XLA updates the (large) cache in place instead of
-        # copying it every step. Auto: on for accelerator backends, off
+        # copying it every step. Aliasing alone moves nothing out of the
+        # step: it is the in-place row scatter and the whole-cache kernel
+        # signature (decoder.py::write_rows, paged_append_attention(q,
+        # k_cache, v_cache, layer, ...)) over arrays stored in the shape
+        # whose default layout the kernel reads (cache.py) that leave the
+        # step no layer- or cache-sized value to copy (ISSUE 24: the
+        # compiled 24-layer program's temporaries fell from 4.67 GiB to
+        # 0.04). Auto: on for accelerator backends, off
         # on CPU — donation consumes the input buffers, which makes a
         # FAILED step unrecoverable by retry/bisection (the supervisor
         # then goes straight to reset + journal replay, which is
@@ -580,19 +590,18 @@ class GenerationEngine:
             "cache_k": cache_k, "block_table": block_table,
             "temp": temp, "top_k": top_k, "key": key, "mask": mask,
         })
-        nb, bs = cache_k.shape[1], cache_k.shape[2]
         logits, ks, vs = prefill(params, tokens, jnp.full((1,), length, jnp.int32))
         positions = jnp.arange(s, dtype=jnp.int32)
-        slots = slot_mapping(block_table, positions, bs)
-        slots = jnp.where(positions < length, slots, 0)  # padding -> scratch
-
-        def write(cache, layer_kv):
-            flat = cache.reshape(nb * bs, *cache.shape[2:])
-            return flat.at[slots].set(layer_kv.astype(flat.dtype)).reshape(cache.shape)
-
+        block, offset = slot_mapping(block_table, positions, cache_k.shape[2])
+        block = jnp.where(positions < length, block, 0)  # padding -> scratch
+        offset = jnp.where(positions < length, offset, 0)
         with jax.named_scope("cache_write"):
-            cache_k = jax.vmap(write)(cache_k, ks[:, 0])
-            cache_v = jax.vmap(write)(cache_v, vs[:, 0])
+            # layer by layer, as a decode step writes: one scatter over
+            # all layers would have the compiler transpose the whole
+            # cache to bring the scattered axes to the front, and back
+            for li in range(ks.shape[0]):
+                cache_k = write_rows(cache_k, li, block, offset, ks[li, 0])
+                cache_v = write_rows(cache_v, li, block, offset, vs[li, 0])
         with jax.named_scope("sample"):
             last = logits[0, length - 1]
             ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
@@ -721,14 +730,25 @@ class GenerationEngine:
 
     def _read_block_impl(self, cache_k, cache_v, src):
         """Host-tier swap-out read: one block's K/V ([L, bs, H, D]
-        each), fetched with a traced index so every block id shares ONE
-        program."""
+        each: off the device a block has its logical shape, whatever
+        rows the cache stores it in), fetched with a traced index so
+        every block id shares ONE program."""
         self.trace_counts["kv_block_read"] = self.trace_counts.get("kv_block_read", 0) + 1
         self.programs.note_trace("kv_block_read", {"cache_k": cache_k, "src": src})
         return (
-            jax.lax.dynamic_index_in_dim(cache_k, src, axis=1, keepdims=False),
-            jax.lax.dynamic_index_in_dim(cache_v, src, axis=1, keepdims=False),
+            self._logical(jax.lax.dynamic_index_in_dim(cache_k, src, axis=1, keepdims=False)),
+            self._logical(jax.lax.dynamic_index_in_dim(cache_v, src, axis=1, keepdims=False)),
         )
+
+    def _logical(self, blocks):
+        """Stored [L, ..., bs, R, LW] -> logical [L, ..., bs, H, D]."""
+        cc = self.cache_config
+        return blocks.reshape(*blocks.shape[:-2], cc.num_heads, cc.head_dim)
+
+    def _stored(self, blocks, like):
+        """Logical [L, ..., bs, H, D] -> as ``like`` (a cache array)
+        stores it: [L, ..., bs, R, LW], in its dtype."""
+        return blocks.reshape(*blocks.shape[:-2], *like.shape[3:]).astype(like.dtype)
 
     def _write_block_impl(self, cache_k, cache_v, dst, host_k, host_v):
         """Host-tier swap-in write: place one block's K/V back into the
@@ -739,10 +759,10 @@ class GenerationEngine:
         })
         return (
             jax.lax.dynamic_update_slice_in_dim(
-                cache_k, host_k[:, None].astype(cache_k.dtype), dst, axis=1
+                cache_k, self._stored(host_k[:, None], cache_k), dst, axis=1
             ),
             jax.lax.dynamic_update_slice_in_dim(
-                cache_v, host_v[:, None].astype(cache_v.dtype), dst, axis=1
+                cache_v, self._stored(host_v[:, None], cache_v), dst, axis=1
             ),
         )
 
@@ -754,8 +774,8 @@ class GenerationEngine:
         self.trace_counts["kv_blocks_read"] = self.trace_counts.get("kv_blocks_read", 0) + 1
         self.programs.note_trace("kv_blocks_read", {"cache_k": cache_k, "srcs": srcs})
         return (
-            jnp.take(cache_k, srcs, axis=1),
-            jnp.take(cache_v, srcs, axis=1),
+            self._logical(jnp.take(cache_k, srcs, axis=1)),
+            self._logical(jnp.take(cache_v, srcs, axis=1)),
         )
 
     def _write_blocks_impl(self, cache_k, cache_v, dsts, host_ks, host_vs):
@@ -771,10 +791,10 @@ class GenerationEngine:
             ck, cv = carry
             dst, hk, hv = x
             ck = jax.lax.dynamic_update_slice_in_dim(
-                ck, hk[:, None].astype(ck.dtype), dst, axis=1
+                ck, self._stored(hk[:, None], ck), dst, axis=1
             )
             cv = jax.lax.dynamic_update_slice_in_dim(
-                cv, hv[:, None].astype(cv.dtype), dst, axis=1
+                cv, self._stored(hv[:, None], cv), dst, axis=1
             )
             return (ck, cv), None
 

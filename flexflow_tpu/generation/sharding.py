@@ -25,8 +25,11 @@ Per-leaf placement of the decoder pytree (decoder.py):
 
 and of the engine's runtime state:
 
-  KV cache k/v [L, num_blocks, block_size, H, D]  P(None, None, None,
+  KV cache k/v [L, num_blocks, block_size, R, LW]  P(None, None, None,
                                                     "model", None)
+                  (a position's H x D values stored as R rows, packed
+                  shard by shard — cache.py — so the row axis shards
+                  exactly as the head axis does)
   block tables / positions / sampling params / tokens   replicated
 
 Block tables and the host-side allocator are therefore device-count-
@@ -96,7 +99,8 @@ class ServingLayout:
 
     @property
     def cache_sharding(self) -> NamedSharding:
-        """KV cache [L, num_blocks, block_size, H, D]: heads sharded."""
+        """KV cache [L, num_blocks, block_size, R, LW]: rows, that is
+        heads, sharded."""
         return self.sharding(None, None, None, MODEL_AXIS, None)
 
     def param_shardings(self, params: Dict[str, Any]) -> Dict[str, Any]:

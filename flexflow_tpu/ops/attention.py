@@ -245,7 +245,7 @@ def _paged_kernel_tp(kernel, backend, mesh, head_axis, num_heads, head_dim, cach
         reason = f"{num_heads} heads do not divide over {tp} shards"
     else:
         reason = paged_kernel_refusal(
-            num_heads // tp, head_dim, cache.shape[1], window, cache.dtype.itemsize
+            num_heads // tp, head_dim, cache.shape[2], window, cache.dtype.itemsize
         )
     if reason is not None:
         _note_refusal(kernel, reason)
@@ -257,6 +257,7 @@ def decode_attention_core(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     context_lens: jax.Array,
     backend: str = "tpu",
@@ -265,8 +266,11 @@ def decode_attention_core(
     head_axis: str = "model",
 ) -> jax.Array:
     """Decode-mode attention: one query token per sequence ([B, H, D])
-    over a block-structured KV cache with position masking, so
-    incremental decode reproduces full-context causal logits.
+    over static ``layer`` of the whole block-structured KV cache
+    ([L, num_blocks, block_size, R, LW], the stored form of [..., H, D]:
+    never a sliced-out layer) with
+    position masking, so incremental decode reproduces full-context
+    causal logits.
 
     The Pallas paged-attention kernel on the TPU backend
     (kernels/decode_attention.py), the XLA gather + masked softmax
@@ -281,15 +285,15 @@ def decode_attention_core(
     )
     if tp > 1:
         return sharded_paged_decode_attention(
-            q, k_cache, v_cache, block_tables, context_lens,
+            q, k_cache, v_cache, layer, block_tables, context_lens,
             mesh, axis=head_axis, scale=scale,
         )
     if tp == 1:
         return paged_decode_attention(
-            q, k_cache, v_cache, block_tables, context_lens, scale=scale
+            q, k_cache, v_cache, layer, block_tables, context_lens, scale=scale
         )
     return reference_paged_attention(
-        q, k_cache, v_cache, block_tables, context_lens, scale=scale
+        q, k_cache, v_cache, layer, block_tables, context_lens, scale=scale
     )
 
 
@@ -297,6 +301,7 @@ def append_attention_core(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     q_positions: jax.Array,
     backend: str = "tpu",
@@ -305,8 +310,8 @@ def append_attention_core(
     head_axis: str = "model",
 ) -> jax.Array:
     """Chunked-append attention: a W-token window per sequence
-    ([B, W, H, D], K/V already written) over the block-structured KV
-    cache. Query (b, w) attends cache positions ``<= q_positions[b, w]``
+    ([B, W, H, D], K/V already written) over static ``layer`` of the
+    whole block-structured KV cache. Query (b, w) attends cache positions ``<= q_positions[b, w]``
     — causal within the window, full history before it — so verifying a
     k+1-token speculative window in one forward reproduces the k+1
     sequential decode steps' logits exactly. ``q_positions < 0`` marks
@@ -322,15 +327,15 @@ def append_attention_core(
     )
     if tp > 1:
         return sharded_paged_append_attention(
-            q, k_cache, v_cache, block_tables, q_positions,
+            q, k_cache, v_cache, layer, block_tables, q_positions,
             mesh, axis=head_axis, scale=scale,
         )
     if tp == 1:
         return paged_append_attention(
-            q, k_cache, v_cache, block_tables, q_positions, scale=scale
+            q, k_cache, v_cache, layer, block_tables, q_positions, scale=scale
         )
     return reference_paged_append_attention(
-        q, k_cache, v_cache, block_tables, q_positions, scale=scale
+        q, k_cache, v_cache, layer, block_tables, q_positions, scale=scale
     )
 
 

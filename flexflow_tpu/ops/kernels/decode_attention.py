@@ -1,6 +1,32 @@
 """Paged decode/append attention: a window of query tokens per sequence
 attending over a block-structured KV cache.
 
+Every entry point takes the WHOLE cache — ``k_cache`` / ``v_cache`` as
+generation/cache.py holds them — and a **static layer index**: the
+caller never slices a layer out, so a decode program carries the two
+donated arrays from its parameters to its results untouched but for the
+rows it writes (ISSUE 24; a sliced-out layer handed to a Mosaic call is
+a layer-sized copy, 48 of them a step).
+
+**Stored shape.** The cache is ``[L, num_blocks, block_size, R, LW]``:
+one position's ``H x D`` values laid out, head after head, over ``R``
+rows of ``LW`` lanes (:func:`cache_row_shape`). Where ``D`` divides the
+128 lanes of a TPU vector register, ``128 // D`` heads share a row
+(``R = H * D / 128``, ``LW`` = 128: two heads of 64 per row); otherwise
+a row is one head (``R, LW = H, D``: the plain layout). A row-major
+reshape turns either into ``[..., H, D]``, so nothing but the kernel's
+body knows which it is. The reason is the device's default layout, which
+is the only one a program loaded from JAX's persistent compile cache
+keeps (a layout declared through ``jax.experimental.layout`` is honoured
+by a fresh compile and dropped by a cached one — measured on the v5e,
+jax 0.9.0, PR 24): for ``[..., 16, 64]`` the TPU compiler puts
+``num_blocks`` on the lanes, since 64 would half-fill them, and every
+program calling this kernel then converted the whole cache to row-major
+on entry and back on exit; for ``[..., 8, 128]`` the default IS row-major
+and unpadded, the kernel DMAs blocks straight out of the arrays the
+engine carries, and it reads half the bytes it read when each 64-wide
+head was padded to 128 lanes.
+
 The generation engine's decode step calls this once per layer with a
 one-token window (``q`` [B, H, D]); the speculative-verification step
 calls the generalized *chunked-append* form with a W = k+1 token window
@@ -16,14 +42,16 @@ nothing and emits zeros.
 Two lowerings:
 
 * :func:`reference_paged_append_attention` — gather the table'd blocks
-  and run a masked softmax in plain XLA. This is the CPU/test path and
+  of the layer (ONE gather, ``cache[layer, block_tables]``) and run a
+  masked softmax in plain XLA. This is the CPU/test path and
   the parity oracle. :func:`reference_paged_attention` is its W = 1
   wrapper (the original decode form).
 * :func:`paged_append_attention` — a Pallas TPU kernel gridded over
   (batch, cache blocks) with the block tables AND per-query positions
   scalar-prefetched (``pltpu.PrefetchScalarGridSpec``), so each grid
-  step DMAs exactly one cache block into VMEM (the PagedAttention
-  access pattern) and accumulates per-query online-softmax state in
+  step DMAs exactly one cache block — ``(layer, table[b, j])`` of the
+  5-D array — into VMEM (the PagedAttention access pattern) and
+  accumulates per-query online-softmax state in
   scratch across the sequential grid. Out-of-range table entries point
   at the scratch block 0 and are masked, never read out of bounds.
   :func:`paged_decode_attention` is its W = 1 wrapper.
@@ -31,7 +59,7 @@ Two lowerings:
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,12 +67,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+LANES = 128  # of a TPU vector register: the width a cache row fills
+
+
+def cache_row_shape(num_heads: int, head_dim: int) -> Tuple[int, int]:
+    """``(R, LW)``: how one cache position's ``num_heads x head_dim``
+    values are stored (see the module docstring). ``num_heads`` is what
+    ONE device holds: a head-sharded cache packs each shard's heads."""
+    per_row = LANES // head_dim if LANES % head_dim == 0 else 1
+    if per_row > 1 and num_heads % per_row == 0:
+        return num_heads // per_row, LANES
+    return num_heads, head_dim
 
 
 def reference_paged_append_attention(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     q_positions: jax.Array,
     scale: Optional[float] = None,
@@ -52,20 +92,22 @@ def reference_paged_append_attention(
     """Masked window attention over gathered cache blocks, in plain XLA.
 
     q: [B, W, H, D] (a W-token append window per sequence, K/V already
-    written into the cache); k_cache/v_cache: [num_blocks, block_size,
-    H, D]; block_tables: [B, max_blocks] int32; q_positions: [B, W]
-    int32 — each window query's cache position. Query (b, w) attends to
+    written into the cache); k_cache/v_cache: [L, num_blocks,
+    block_size, R, LW] (R x LW = H x D, row-major), of which static
+    ``layer`` is read; block_tables: [B, max_blocks] int32; q_positions:
+    [B, W] int32 — each window query's cache position. Query (b, w)
+    attends to
     cache positions ``<= q_positions[b, w]`` (its own history including
     itself); ``q_positions[b, w] < 0`` marks a padding query, which
     produces zeros, not NaN. Returns [B, W, H, D].
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    bs = k_cache.shape[1]
+    bs = k_cache.shape[2]
     b, max_blocks = block_tables.shape
-    # [B, max_blocks, bs, H, D] -> [B, S_max, H, D]
-    k = k_cache[block_tables].reshape(b, max_blocks * bs, *k_cache.shape[2:])
-    v = v_cache[block_tables].reshape(b, max_blocks * bs, *v_cache.shape[2:])
+    # one gather out of the whole cache: [B, max_blocks, bs, R, LW] -> [B, S_max, H, D]
+    k = k_cache[layer, block_tables].reshape(b, max_blocks * bs, *q.shape[2:])
+    v = v_cache[layer, block_tables].reshape(b, max_blocks * bs, *q.shape[2:])
     s = jnp.einsum("bwhd,bkhd->bhwk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     pos = jnp.arange(max_blocks * bs)[None, None, None, :]  # key positions
     valid = pos <= q_positions[:, None, :, None]  # [B, 1, W, S_max]
@@ -84,6 +126,7 @@ def reference_paged_attention(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     context_lens: jax.Array,
     scale: Optional[float] = None,
@@ -93,7 +136,7 @@ def reference_paged_attention(
     already-written K/V; 0 marks an inactive slot). The W = 1 special
     case of :func:`reference_paged_append_attention`."""
     out = reference_paged_append_attention(
-        q[:, None], k_cache, v_cache, block_tables, context_lens[:, None] - 1, scale
+        q[:, None], k_cache, v_cache, layer, block_tables, context_lens[:, None] - 1, scale
     )
     return out[:, 0]
 
@@ -102,24 +145,42 @@ def reference_paged_attention(
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
 #
-# Everything inside the kernel keeps the cache's own [block_size, H, D]
-# arrangement: heads on sublanes, head_dim on lanes. Scores are a
-# lane reduction of k * q (VPU + XLU) and the value sum is a reduction
-# over the block's leading axis, so the body needs no transposes, no
-# batched matmul and no vector loads from SMEM — the three things
-# Mosaic refused in the first version of this kernel, which computed
-# [H, W, block_size] scores with a dot_general batched over a
-# non-leading K axis. Per-query state is [W, H, 1] / [W, H, D] and the
-# window is walked one query at a time; each query's cache position is
-# a scalar SMEM read.
+# Everything inside the kernel keeps the cache's own [block_size, R, LW]
+# arrangement: rows on sublanes, a row's heads side by side on lanes.
+# Scores are a lane reduction of k * q (VPU + XLU) — one reduction per
+# head of the row, each over its own lanes, the result left on those
+# lanes — and the value sum is a reduction over the block's leading
+# axis, so the body needs no transposes, no batched matmul and no vector
+# loads from SMEM — the three things Mosaic refused in the first version
+# of this kernel, which computed [H, W, block_size] scores with a
+# dot_general batched over a non-leading K axis. Per-query state is
+# [W, R, LW] (a head's running max and denominator repeated over its
+# lanes: a [.., 1] column occupies whole vector registers anyway) and
+# the window is walked one query at a time; each query's cache position
+# is a scalar SMEM read.
+
+
+def _head_scores(prod, head_dim):
+    """Per-head sums of ``prod`` ([bs, R, LW]) over each head's own
+    ``head_dim`` lanes, left on those lanes. One head per row: a plain
+    lane reduction, kept as a [bs, R, 1] column."""
+    lanes = prod.shape[-1]
+    if lanes == head_dim:
+        return jnp.sum(prod, axis=-1, keepdims=True)
+    head = jax.lax.broadcasted_iota(jnp.int32, prod.shape, 2) // head_dim
+    s = jnp.zeros_like(prod)
+    for g in range(lanes // head_dim):
+        mine = head == g
+        s = jnp.where(mine, jnp.sum(jnp.where(mine, prod, 0.0), axis=-1, keepdims=True), s)
+    return s
 
 
 def _accumulate_block(
-    qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, b, block_start, *, scale
+    qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, b, block_start, *, scale, head_dim
 ):
     """Fold one cache block into every window query's online-softmax
-    state (m/l [W, H, 1], acc [W, H, D])."""
-    k = k_ref[:].astype(jnp.float32)  # [bs, H, D]
+    state (m/l [W, R, LW or 1], acc [W, R, LW])."""
+    k = k_ref[:].astype(jnp.float32)  # [bs, R, LW]
     v = v_ref[:].astype(jnp.float32)
     pos = block_start + jax.lax.broadcasted_iota(
         jnp.int32, (k.shape[0], k.shape[1], 1), 0
@@ -127,17 +188,17 @@ def _accumulate_block(
 
     def one_query(w, carry):
         qp = qpos_ref[b, w]  # scalar: this query's cache position
-        q = q_ref[w].astype(jnp.float32) * scale  # [H, D]
-        s = jnp.sum(k * q[None], axis=-1, keepdims=True)  # [bs, H, 1]
+        q = q_ref[w].astype(jnp.float32) * scale  # [R, LW]
+        s = _head_scores(k * q[None], head_dim)  # [bs, R, LW or 1]
         valid = pos <= qp  # causal-within-window + history
         s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[w]  # [H, 1]
+        m_prev = m_ref[w]  # [R, LW or 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
         p = jnp.where(valid, jnp.exp(s - m_new[None]), 0.0)
         corr = jnp.exp(m_prev - m_new)
         m_ref[w] = m_new
         l_ref[w] = l_ref[w] * corr + jnp.sum(p, axis=0)
-        acc_ref[w] = acc_ref[w] * corr + jnp.sum(p * v, axis=0)  # [H, D]
+        acc_ref[w] = acc_ref[w] * corr + jnp.sum(p * v, axis=0)  # [R, LW]
         return carry
 
     jax.lax.fori_loop(0, q_ref.shape[0], one_query, 0)
@@ -153,16 +214,17 @@ def _append_kernel(
     bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
     qpos_ref,  # scalar-prefetch: [B, W] per-query cache positions (-1 = pad)
     maxpos_ref,  # scalar-prefetch: [B] max over the window's positions
-    q_ref,  # [W, H, D] this sequence's query window
-    k_ref,  # [block_size, H, D] the grid step's cache block
-    v_ref,  # [block_size, H, D]
-    o_ref,  # [W, H, D]
-    m_ref,  # scratch [W, H, 1] running max per query
-    l_ref,  # scratch [W, H, 1] running denominator per query
-    acc_ref,  # scratch [W, H, D] running numerator per query
+    q_ref,  # [W, R, LW] this sequence's query window, laid out as a cache row
+    k_ref,  # [block_size, R, LW] the grid step's cache block
+    v_ref,  # [block_size, R, LW]
+    o_ref,  # [W, R, LW]
+    m_ref,  # scratch [W, R, LW or 1] running max per query
+    l_ref,  # scratch [W, R, LW or 1] running denominator per query
+    acc_ref,  # scratch [W, R, LW] running numerator per query
     *,
     scale,
     block_size,
+    head_dim,
 ):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -177,7 +239,7 @@ def _append_kernel(
     def _accum():
         _accumulate_block(
             qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-            b, j * block_size, scale=scale,
+            b, j * block_size, scale=scale, head_dim=head_dim,
         )
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -192,18 +254,19 @@ def _append_kernel_split(
     bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
     qpos_ref,  # scalar-prefetch: [B, W] per-query cache positions (-1 = pad)
     maxpos_ref,  # scalar-prefetch: [B] max over the window's positions
-    q_ref,  # [W, H, D] this sequence's query window
-    k_ref,  # [block_size, H, D] the grid step's cache block
-    v_ref,  # [block_size, H, D]
-    acc_out_ref,  # [W, H, D] this split's UNNORMALIZED numerator
-    m_out_ref,  # [W, H, 1] this split's running max
-    l_out_ref,  # [W, H, 1] this split's denominator
-    m_ref,  # scratch [W, H, 1]
-    l_ref,  # scratch [W, H, 1]
-    acc_ref,  # scratch [W, H, D]
+    q_ref,  # [W, R, LW] this sequence's query window
+    k_ref,  # [block_size, R, LW] the grid step's cache block
+    v_ref,  # [block_size, R, LW]
+    acc_out_ref,  # [W, R, LW] this split's UNNORMALIZED numerator
+    m_out_ref,  # [W, R, LW or 1] this split's running max
+    l_out_ref,  # [W, R, LW or 1] this split's denominator
+    m_ref,  # scratch [W, R, LW or 1]
+    l_ref,  # scratch [W, R, LW or 1]
+    acc_ref,  # scratch [W, R, LW]
     *,
     scale,
     block_size,
+    head_dim,
     blocks_per_split,
     max_blocks,
 ):
@@ -229,7 +292,7 @@ def _append_kernel_split(
     def _accum():
         _accumulate_block(
             qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-            b, jj * block_size, scale=scale,
+            b, jj * block_size, scale=scale, head_dim=head_dim,
         )
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -244,18 +307,19 @@ def _append_kernel_split(
 def _combine_splits(acc, m, l, q_positions, out_dtype):
     """Exact partial-softmax recombination across the KV-split axis.
 
-    acc: [B, S, W, H, D] unnormalized numerators; m/l: [B, S, W, H, 1]
-    per-split running max / denominator. An empty split carries
+    acc: [B, S, W, R, LW] unnormalized numerators; m/l: [B, S, W, R,
+    LW or 1] per-split running max / denominator (cache-row layout).
+    An empty split carries
     (m=NEG_INF, l=0, acc=0) and contributes nothing; a padding query
     (q_position < 0) has EVERY split empty and emits zeros, matching
     the single-pass kernel."""
-    m_max = jnp.max(m, axis=1, keepdims=True)  # [B, 1, W, H, 1]
+    m_max = jnp.max(m, axis=1, keepdims=True)  # [B, 1, W, R, LW or 1]
     # all-empty guard: exp(NEG_INF - NEG_INF) is NaN; rescale against 0
     # instead (every alpha then underflows to exp(NEG_INF) = 0)
     safe_max = jnp.where(m_max > NEG_INF / 2, m_max, 0.0)
-    alpha = jnp.exp(m - safe_max)  # [B, S, W, H, 1]
-    denom = jnp.sum(l * alpha, axis=1)  # [B, W, H, 1]
-    numer = jnp.sum(acc * alpha, axis=1)  # [B, W, H, D]
+    alpha = jnp.exp(m - safe_max)  # [B, S, W, R, LW or 1]
+    denom = jnp.sum(l * alpha, axis=1)  # [B, W, R, LW or 1]
+    numer = jnp.sum(acc * alpha, axis=1)  # [B, W, R, LW]
     out = numer / jnp.maximum(denom, 1e-30)
     out = jnp.where(q_positions[:, :, None, None] >= 0, out, 0.0)
     return out.astype(out_dtype)
@@ -265,6 +329,7 @@ def paged_append_attention(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     q_positions: jax.Array,
     scale: Optional[float] = None,
@@ -283,22 +348,28 @@ def paged_append_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     b, w, h, d = q.shape
-    _, block_size, _, _ = k_cache.shape
+    block_size, r, lw = k_cache.shape[2:]
+    if r * lw != h * d or (lw != d and lw % d):
+        raise ValueError(f"cache rows {r} x {lw} do not hold {h} heads of {d}")
+    sw = lw if lw != d else 1  # a head's max / denominator: on its lanes, or a column
+    out_dtype = q.dtype
+    q = q.reshape(b, w, r, lw)  # the window, laid out as the cache lays a position out
+    layer = int(layer)  # static: part of the index map, not an operand
     max_blocks = block_tables.shape[1]
     kv_splits = max(1, min(int(kv_splits), max_blocks))
     block_tables = block_tables.astype(jnp.int32)
     q_positions = q_positions.astype(jnp.int32)
     prefetch = (block_tables, q_positions, jnp.max(q_positions, axis=1))
     scratch_shapes = [
-        pltpu.VMEM((w, h, 1), jnp.float32),
-        pltpu.VMEM((w, h, 1), jnp.float32),
-        pltpu.VMEM((w, h, d), jnp.float32),
+        pltpu.VMEM((w, r, sw), jnp.float32),
+        pltpu.VMEM((w, r, sw), jnp.float32),
+        pltpu.VMEM((w, r, lw), jnp.float32),
     ]
     if kv_splits > 1:
         bps = -(-max_blocks // kv_splits)  # blocks per split (ceil)
 
         def kv_map(i, s, j, bt, qp, mp):
-            return (bt[i, jnp.minimum(s * bps + j, max_blocks - 1)], 0, 0, 0)
+            return (layer, bt[i, jnp.minimum(s * bps + j, max_blocks - 1)], 0, 0, 0)
 
         def out_map(i, s, j, bt, qp, mp):
             return (i, s, 0, 0, 0)
@@ -307,56 +378,58 @@ def paged_append_attention(
             num_scalar_prefetch=3,
             grid=(b, kv_splits, bps),
             in_specs=[
-                pl.BlockSpec((None, w, h, d), lambda i, s, j, bt, qp, mp: (i, 0, 0, 0)),
-                pl.BlockSpec((None, block_size, h, d), kv_map),
-                pl.BlockSpec((None, block_size, h, d), kv_map),
+                pl.BlockSpec((None, w, r, lw), lambda i, s, j, bt, qp, mp: (i, 0, 0, 0)),
+                pl.BlockSpec((None, None, block_size, r, lw), kv_map),
+                pl.BlockSpec((None, None, block_size, r, lw), kv_map),
             ],
             out_specs=[
-                pl.BlockSpec((None, None, w, h, d), out_map),
-                pl.BlockSpec((None, None, w, h, 1), out_map),
-                pl.BlockSpec((None, None, w, h, 1), out_map),
+                pl.BlockSpec((None, None, w, r, lw), out_map),
+                pl.BlockSpec((None, None, w, r, sw), out_map),
+                pl.BlockSpec((None, None, w, r, sw), out_map),
             ],
             scratch_shapes=scratch_shapes,
         )
         kernel = functools.partial(
             _append_kernel_split, scale=float(scale), block_size=block_size,
-            blocks_per_split=bps, max_blocks=max_blocks,
+            head_dim=d, blocks_per_split=bps, max_blocks=max_blocks,
         )
         acc, m, l = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((b, kv_splits, w, h, d), jnp.float32),
-                jax.ShapeDtypeStruct((b, kv_splits, w, h, 1), jnp.float32),
-                jax.ShapeDtypeStruct((b, kv_splits, w, h, 1), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv_splits, w, r, lw), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv_splits, w, r, sw), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv_splits, w, r, sw), jnp.float32),
             ],
             interpret=interpret,
             name="paged_append_attention_split",
         )(*prefetch, q, k_cache, v_cache)
-        return _combine_splits(acc, m, l, q_positions, q.dtype)
+        return _combine_splits(acc, m, l, q_positions, out_dtype).reshape(b, w, h, d)
 
     def kv_map(i, j, bt, qp, mp):
-        return (bt[i, j], 0, 0, 0)
+        return (layer, bt[i, j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, max_blocks),
         in_specs=[
-            pl.BlockSpec((None, w, h, d), lambda i, j, bt, qp, mp: (i, 0, 0, 0)),
-            pl.BlockSpec((None, block_size, h, d), kv_map),
-            pl.BlockSpec((None, block_size, h, d), kv_map),
+            pl.BlockSpec((None, w, r, lw), lambda i, j, bt, qp, mp: (i, 0, 0, 0)),
+            pl.BlockSpec((None, None, block_size, r, lw), kv_map),
+            pl.BlockSpec((None, None, block_size, r, lw), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, w, h, d), lambda i, j, bt, qp, mp: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, w, r, lw), lambda i, j, bt, qp, mp: (i, 0, 0, 0)),
         scratch_shapes=scratch_shapes,
     )
-    kernel = functools.partial(_append_kernel, scale=float(scale), block_size=block_size)
+    kernel = functools.partial(
+        _append_kernel, scale=float(scale), block_size=block_size, head_dim=d
+    )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, w, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, w, r, lw), out_dtype),
         interpret=interpret,
         name="paged_append_attention",
-    )(*prefetch, q, k_cache, v_cache)
+    )(*prefetch, q, k_cache, v_cache).reshape(b, w, h, d)
 
 
 def default_kv_splits(batch: int, max_blocks: int) -> int:
@@ -382,6 +455,7 @@ def paged_decode_attention(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     context_lens: jax.Array,
     scale: Optional[float] = None,
@@ -398,6 +472,7 @@ def paged_decode_attention(
         q[:, None],
         k_cache,
         v_cache,
+        layer,
         block_tables,
         context_lens[:, None] - 1,
         scale=scale,
@@ -411,6 +486,7 @@ def sharded_paged_append_attention(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     q_positions: jax.Array,
     mesh,
@@ -429,8 +505,9 @@ def sharded_paged_append_attention(
     ops/parallel_ops.py's ``ReductionOp`` annotates in the training
     path). Shapes as in :func:`paged_append_attention`; ``q`` is
     [B, W, H, D] with H sharded on ``axis``, the caches shard their head
-    dim, tables/positions are replicated, and the output keeps H
-    sharded.
+    dim (the layer axis is whole on every shard, so the static ``layer``
+    passes straight through), tables/positions are replicated, and the
+    output keeps H sharded.
 
     ``scale`` must be passed explicitly when H is sharded — the default
     would be computed from a LOCAL shape inside shard_map; head_dim is
@@ -442,7 +519,7 @@ def sharded_paged_append_attention(
 
     def local(q_, k_, v_, bt_, qp_):
         return paged_append_attention(
-            q_, k_, v_, bt_, qp_, scale=scale, interpret=interpret,
+            q_, k_, v_, layer, bt_, qp_, scale=scale, interpret=interpret,
             kv_splits=kv_splits,
         )
 
@@ -451,8 +528,8 @@ def sharded_paged_append_attention(
         mesh=mesh,
         in_specs=(
             P(None, None, axis, None),  # q [B, W, H, D]
-            P(None, None, axis, None),  # k_cache [nb, bs, H, D]
-            P(None, None, axis, None),  # v_cache
+            P(None, None, None, axis, None),  # k_cache [L, nb, bs, R, LW], rows sharded
+            P(None, None, None, axis, None),  # v_cache
             P(None, None),  # block_tables (replicated)
             P(None, None),  # q_positions (replicated)
         ),
@@ -465,6 +542,7 @@ def sharded_paged_decode_attention(
     q: jax.Array,
     k_cache: jax.Array,
     v_cache: jax.Array,
+    layer: int,
     block_tables: jax.Array,
     context_lens: jax.Array,
     mesh,
@@ -481,6 +559,7 @@ def sharded_paged_decode_attention(
         q[:, None],
         k_cache,
         v_cache,
+        layer,
         block_tables,
         context_lens[:, None] - 1,
         mesh,
@@ -509,12 +588,14 @@ _VMEM_BUDGET_BYTES = 12 << 20
 def _vmem_bytes(num_heads: int, head_dim: int, block_size: int, window: int, itemsize: int) -> int:
     """Upper estimate of the kernel's VMEM footprint: double-buffered
     K/V and Q/O blocks, the online-softmax scratch, and the block-sized
-    float32 temporaries, with (H, D) padded to the (8, 128) tile."""
-    heads = -(-num_heads // 8) * 8
-    row = heads * -(-head_dim // 128) * 128  # one [H, D] slab, in elements
+    float32 temporaries, with a position's (R, LW) rows padded to the
+    (8, 128) tile."""
+    rows, lanes = cache_row_shape(num_heads, head_dim)
+    rows = -(-rows // 8) * 8
+    row = rows * -(-lanes // LANES) * LANES  # one position's slab, in elements
     kv = 2 * 2 * block_size * row * itemsize
     qo = 2 * 2 * window * row * itemsize
-    scratch = window * (row + 2 * heads * 128) * 4  # acc + m + l
+    scratch = window * (row + 2 * rows * LANES) * 4  # acc + m + l
     temporaries = 3 * block_size * row * 4
     return kv + qo + scratch + temporaries
 

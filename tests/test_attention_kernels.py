@@ -204,10 +204,14 @@ def test_flash_adaptive_block_policy(monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def _paged_fixtures(seed, b, w, max_blocks, nb=33, bs=8, h=4, d=64):
+def _paged_fixtures(seed, b, w, max_blocks, nb=33, bs=8, h=4, d=64, layers=2, rows=None):
+    """A whole cache ([L, nb, bs, R, LW], as the kernels take it since
+    ISSUE 24: ``rows`` = (R, LW), by default one head a row), queries,
+    tables and per-query positions."""
     rs = np.random.RandomState(seed)
-    k_cache = jnp.asarray(rs.randn(nb, bs, h, d).astype(np.float32))
-    v_cache = jnp.asarray(rs.randn(nb, bs, h, d).astype(np.float32))
+    rows = rows or (h, d)
+    k_cache = jnp.asarray(rs.randn(layers, nb, bs, *rows).astype(np.float32))
+    v_cache = jnp.asarray(rs.randn(layers, nb, bs, *rows).astype(np.float32))
     q = jnp.asarray(rs.randn(b, w, h, d).astype(np.float32))
     tables = jnp.asarray(rs.randint(1, nb, (b, max_blocks)).astype(np.int32))
     qpos = []
@@ -241,9 +245,9 @@ def test_split_kv_append_matches_reference(b, w, max_blocks, splits):
     q, k_cache, v_cache, tables, qpos = _paged_fixtures(
         100 + b + w + splits, b, w, max_blocks
     )
-    ref = reference_paged_append_attention(q, k_cache, v_cache, tables, qpos)
+    ref = reference_paged_append_attention(q, k_cache, v_cache, 1, tables, qpos)
     out = paged_append_attention(
-        q, k_cache, v_cache, tables, qpos, interpret=True, kv_splits=splits
+        q, k_cache, v_cache, 1, tables, qpos, interpret=True, kv_splits=splits
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
     # padding queries emit exact zeros, like the single-pass kernel
@@ -266,9 +270,9 @@ def test_split_kv_decode_wrapper_and_heuristic():
     assert default_kv_splits(1, 8) == 1        # short table: not worth it
     q, k_cache, v_cache, tables, _ = _paged_fixtures(7, 2, 1, 24)
     ctx = jnp.asarray(np.asarray([150, 40], np.int32))
-    ref = reference_paged_attention(q[:, 0], k_cache, v_cache, tables, ctx)
+    ref = reference_paged_attention(q[:, 0], k_cache, v_cache, 0, tables, ctx)
     out = paged_decode_attention(
-        q[:, 0], k_cache, v_cache, tables, ctx, interpret=True, kv_splits=4
+        q[:, 0], k_cache, v_cache, 0, tables, ctx, interpret=True, kv_splits=4
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
@@ -280,9 +284,52 @@ def test_split_kv_single_split_is_the_sequential_kernel():
 
     q, k_cache, v_cache, tables, qpos = _paged_fixtures(3, 2, 3, 9)
     base = paged_append_attention(
-        q, k_cache, v_cache, tables, qpos, interpret=True, kv_splits=1
+        q, k_cache, v_cache, 1, tables, qpos, interpret=True, kv_splits=1
     )
     clamped = paged_append_attention(
-        q, k_cache, v_cache, tables, qpos, interpret=True, kv_splits=0
+        q, k_cache, v_cache, 1, tables, qpos, interpret=True, kv_splits=0
     )
     assert np.array_equal(np.asarray(base), np.asarray(clamped))
+
+
+@pytest.mark.parametrize("rows", [(4, 64), (2, 128)], ids=["head_per_row", "two_heads_per_row"])
+@pytest.mark.parametrize("splits", [1, 4])
+@pytest.mark.parametrize("layer", [0, 1, 4])
+def test_paged_kernel_reads_its_layer_of_the_whole_cache(layer, splits, rows):
+    """The kernel takes the 5-D cache and a static layer index (its
+    index map is ``(layer, table[b, j], 0, 0, 0)``): for the first, a
+    middle and the last layer it equals the XLA composition on that
+    layer, whether a cache row holds one head or two side by side, and
+    the composition equals itself on the layer sliced out as a one-layer
+    cache in the plain [.., H, D] view — so neither reads a neighbouring
+    layer, and the packed rows are a row-major reshape and nothing more."""
+    from flexflow_tpu.ops.kernels.decode_attention import (
+        cache_row_shape,
+        paged_append_attention,
+        reference_paged_append_attention,
+    )
+
+    assert cache_row_shape(4, 64) == (2, 128)
+    q, k_cache, v_cache, tables, qpos = _paged_fixtures(
+        11, 2, 3, 8, nb=12, layers=5, rows=rows
+    )
+    ref = reference_paged_append_attention(q, k_cache, v_cache, layer, tables, qpos)
+    plain = lambda c: c[layer].reshape(1, 12, 8, 4, 64)
+    alone = reference_paged_append_attention(q, plain(k_cache), plain(v_cache), 0, tables, qpos)
+    assert np.array_equal(np.asarray(ref), np.asarray(alone))
+    out = paged_append_attention(
+        q, k_cache, v_cache, layer, tables, qpos, interpret=True, kv_splits=splits
+    )
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    assert np.all(np.asarray(out)[np.asarray(qpos) < 0] == 0.0)  # padding queries: zeros
+    others = [l for l in range(5) if l != layer]
+    far = reference_paged_append_attention(q, k_cache, v_cache, others[0], tables, qpos)
+    assert not np.allclose(np.asarray(out), np.asarray(far), atol=1e-3)
+
+
+def test_paged_kernel_refuses_rows_that_do_not_hold_the_heads():
+    from flexflow_tpu.ops.kernels.decode_attention import paged_append_attention
+
+    q, k_cache, v_cache, tables, qpos = _paged_fixtures(2, 2, 1, 4, rows=(3, 128))
+    with pytest.raises(ValueError, match="do not hold 4 heads of 64"):
+        paged_append_attention(q, k_cache, v_cache, 0, tables, qpos, interpret=True)

@@ -92,10 +92,10 @@ def test_calibration_tables_come_from_the_checkout_only(monkeypatch, tmp_path):
 def _paged_args(w=1):
     rs = np.random.RandomState(0)
     q = jnp.asarray(rs.randn(2, w, 4, 64), jnp.float32)
-    kc = jnp.asarray(rs.randn(5, 8, 4, 64), jnp.float32)
+    kc = jnp.asarray(rs.randn(2, 5, 8, 4, 64), jnp.float32)  # [L, nb, bs, H, D]
     bt = jnp.asarray(rs.randint(1, 5, (2, 3)), jnp.int32)
     qp = jnp.asarray(np.tile(np.arange(w), (2, 1)) + 3, jnp.int32)
-    return q, kc, kc, bt, qp
+    return q, kc, kc, 1, bt, qp
 
 
 def test_kernels_are_not_interpreted_unless_a_test_asks():
@@ -168,6 +168,16 @@ def test_kv_cache_holds_two_buffers_created_where_they_live():
     assert sharded.k.addressable_shards[0].data.shape == (2, 5, 4, 1, 8)
     sharded.reset()
     assert sharded.k.sharding == sh and sharded.k is not sharded.v
+    # stored rows: heads that divide the 128 lanes share a row, packed
+    # shard by shard, so sharding the row axis is sharding the heads
+    wide = dict(num_layers=2, num_heads=16, head_dim=64, num_blocks=5, block_size=4)
+    assert CacheConfig(**wide).row_shape == (8, 128)
+    assert CacheConfig(**wide, kv_shards=4).row_shape == (8, 128)  # 4 heads = 2 rows a shard
+    assert CacheConfig(**wide, kv_shards=16).row_shape == (16, 64)  # one head a shard: no pair
+    assert cfg.row_shape == (4, 8)  # 4 heads of 8 do not fill a row: stored as they are
+    packed = KVCache.create(CacheConfig(**wide, kv_shards=4), sharding=sh)
+    assert packed.k.shape == (2, 5, 4, 8, 128)
+    assert packed.k.addressable_shards[0].data.shape == (2, 5, 4, 2, 128)
 
 
 def test_chip_smoke_fails_without_a_tpu():

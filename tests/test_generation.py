@@ -129,8 +129,9 @@ def test_cache_budget_sizing():
 
 def test_slot_mapping_out_of_table_hits_scratch():
     table = jnp.asarray([3, 7], jnp.int32)
-    slots = slot_mapping(table, jnp.asarray([0, 5, 9, 100], jnp.int32), 4)
-    np.testing.assert_array_equal(np.asarray(slots), [12, 29, 0, 0])
+    block, offset = slot_mapping(table, jnp.asarray([0, 5, 9, 100], jnp.int32), 4)
+    np.testing.assert_array_equal(np.asarray(block), [3, 7, 0, 0])
+    np.testing.assert_array_equal(np.asarray(offset), [0, 1, 0, 0])
 
 
 # ---------------------------------------------------------------------------
@@ -163,16 +164,11 @@ def test_decode_logits_match_full_forward(decoder_params, prompt_len):
         decoder_params, jnp.asarray(padded), jnp.asarray([prompt_len], jnp.int32)
     )
     positions = jnp.arange(bucket, dtype=jnp.int32)
-    slots = slot_mapping(table, positions, BLOCK)
-    slots = jnp.where(positions < prompt_len, slots, 0)
-    nb, bs = cc.num_blocks, cc.block_size
-
-    def write(cache_arr, layer_kv):
-        flat = cache_arr.reshape(nb * bs, *cache_arr.shape[2:])
-        return flat.at[slots].set(layer_kv).reshape(cache_arr.shape)
-
-    ck = jax.vmap(write)(cache.k, ks[:, 0])
-    cv = jax.vmap(write)(cache.v, vs[:, 0])
+    block, offset = slot_mapping(table, positions, BLOCK)
+    block = jnp.where(positions < prompt_len, block, 0)  # padding -> scratch
+    offset = jnp.where(positions < prompt_len, offset, 0)
+    ck = cache.k.at[:, block, offset].set(ks[:, 0])
+    cv = cache.v.at[:, block, offset].set(vs[:, 0])
 
     seq = list(prompt)
     full = forward_full(decoder_params, jnp.asarray([seq], jnp.int32))
@@ -230,14 +226,269 @@ def test_pallas_decode_kernel_matches_reference():
     rs = np.random.RandomState(0)
     b, h, d, nb, bs, mb = 3, 4, 64, 10, 8, 4
     q = jnp.asarray(rs.randn(b, h, d).astype(np.float32))
-    kc = jnp.asarray(rs.randn(nb, bs, h, d).astype(np.float32))
-    vc = jnp.asarray(rs.randn(nb, bs, h, d).astype(np.float32))
+    kc = jnp.asarray(rs.randn(3, nb, bs, h, d).astype(np.float32))  # [L, nb, bs, H, D]
+    vc = jnp.asarray(rs.randn(3, nb, bs, h, d).astype(np.float32))
     bt = jnp.asarray(rs.randint(0, nb, (b, mb)).astype(np.int32))
     cl = jnp.asarray(np.array([5, 17, 0], np.int32))  # incl. inactive slot
-    ref = reference_paged_attention(q, kc, vc, bt, cl)
-    ker = paged_decode_attention(q, kc, vc, bt, cl, interpret=True)
+    ref = reference_paged_attention(q, kc, vc, 2, bt, cl)
+    ker = paged_decode_attention(q, kc, vc, 2, bt, cl, interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
     assert float(jnp.max(jnp.abs(ref[2]))) == 0.0  # inactive -> zeros, not NaN
+
+
+# ---------------------------------------------------------------------------
+# the cache stays where it is (ISSUE 24): rows written in place, the whole
+# 5-D array handed to the kernel, stored in the shape whose default device
+# layout the kernel reads
+# ---------------------------------------------------------------------------
+
+
+def _cfg3(hidden):
+    """Three layers, four heads: of 8 (stored as they are: 4 x 8) or of
+    32 (four heads fill one 128-lane row: stored 1 x 128)."""
+    return TransformerConfig(
+        num_layers=3, hidden_size=hidden, num_heads=4, ff_size=64,
+        seq_length=64, vocab_size=50, causal=True,
+    )
+
+
+@pytest.mark.parametrize("hidden", [32, 128], ids=["rows4x8", "rows1x128"])
+@pytest.mark.parametrize("step,window", [("decode", 1), ("verify", 1), ("verify", 4)])
+def test_step_writes_only_its_rows(step, window, hidden):
+    """decode_step / verify_step hand back the cache they were given
+    with ONLY the step's (layer, block, offset) rows and scratch block 0
+    changed — every other element bit-identical, whatever noise sat
+    there — and their logits are forward_full's."""
+    from flexflow_tpu.generation.decoder import verify_step
+
+    cfg = _cfg3(hidden)
+    params = init_decoder_params(jax.random.key(1), cfg)
+    rs = np.random.RandomState(40 + window)
+    n, nb = 11, 12  # prompt length (mid-block), cache blocks
+    seq = rs.randint(0, cfg.vocab_size, n + window).tolist()
+    cc = CacheConfig(num_layers=3, num_heads=4, head_dim=hidden // 4, num_blocks=nb, block_size=BLOCK)
+    assert cc.row_shape == ((4, 8) if hidden == 32 else (1, 128))
+    shape = (3, nb, BLOCK, *cc.row_shape)
+    ck = jnp.asarray(rs.randn(*shape), jnp.float32)  # noise everywhere
+    cv = jnp.asarray(rs.randn(*shape), jnp.float32)
+    blocks = [5, 2, 9]  # covers positions 0..23
+    table = jnp.asarray(blocks, jnp.int32)
+    _, ks, vs = prefill(params, jnp.asarray([seq[:n]], jnp.int32), jnp.asarray([n], jnp.int32))
+    block, offset = slot_mapping(table, jnp.arange(n, dtype=jnp.int32), BLOCK)
+    ck = ck.at[:, block, offset].set(ks[:, 0].reshape(3, n, *cc.row_shape))
+    cv = cv.at[:, block, offset].set(vs[:, 0].reshape(3, n, *cc.row_shape))
+
+    # slot 0 runs the window at positions n..n+window-1; slot 1 is inactive
+    tables = jnp.asarray([blocks, [0, 0, 0]], jnp.int32)
+    if step == "decode":
+        logits, nk, nv = decode_step(
+            params, jnp.asarray([seq[n], 0], jnp.int32), jnp.asarray([n, 0], jnp.int32),
+            ck, cv, tables, jnp.asarray([n + 1, 0], jnp.int32), backend="cpu",
+        )
+        logits = logits[:, None]
+    else:
+        positions = np.full((2, window), -1, np.int32)
+        positions[0] = np.arange(n, n + window)
+        tokens = np.zeros((2, window), np.int32)
+        tokens[0] = seq[n:]
+        logits, nk, nv = verify_step(
+            params, jnp.asarray(tokens), jnp.asarray(positions), ck, cv, tables, backend="cpu",
+        )
+    full = forward_full(params, jnp.asarray([seq], jnp.int32))
+    np.testing.assert_allclose(
+        np.asarray(logits[0]), np.asarray(full[0, n:n + window]), atol=1e-5,
+        err_msg=f"{step} W={window} logits != forward_full",
+    )
+    step_rows = {
+        (li, blocks[p // BLOCK], p % BLOCK)
+        for li in range(3) for p in range(n, n + window)
+    }
+    for before, after in ((ck, nk), (cv, nv)):
+        diff = np.any(np.asarray(before) != np.asarray(after), axis=(-1, -2))  # [L, nb, bs]
+        changed = {tuple(int(i) for i in idx) for idx in np.argwhere(diff)}
+        assert step_rows <= changed, "a step row was not written"
+        stray = {c for c in changed - step_rows if c[1] != 0}
+        assert not stray, f"{step} W={window} wrote outside its rows and scratch: {sorted(stray)}"
+
+
+_MOVES = ("copy", "slice", "dynamic-slice", "dynamic-update-slice")
+
+
+def _layer_sized_moves(hlo_text, layer_elems):
+    """(op, shape) of every instruction of the optimized HLO, fused
+    computations included, that copies or cuts a layer's worth of
+    elements or more."""
+    import re
+
+    found = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\](?:\{[^}]*\})? ([\w\-]+)\(", hlo_text):
+        elems = int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+        if m.group(2) in _MOVES and elems >= layer_elems:
+            found.append((m.group(2), m.group(1)))
+    return found
+
+
+def _step_arguments(eng, kind, cache, put):
+    """Arguments of the engine's decode / verify jit: ``cache`` for both
+    cache arrays, zeros through ``put(dtype, *shape)`` for the rest."""
+    b, mb, v, w = eng.max_batch_slots, eng.max_blocks_per_seq, eng.cfg.vocab_size, eng.spec_window
+    i32, f32, u32 = np.int32, np.float32, np.uint32
+    tail = (put(f32, b), put(i32, b), put(f32, b), put(u32, b), put(i32, b))
+    if kind == "decode":
+        return (put(i32, b), put(i32, b), cache, cache, put(i32, b, mb), put(i32, b),
+                *tail, put(f32, b, v))
+    return (put(i32, b, w), put(i32, b), put(i32, b), cache, cache, put(i32, b, mb),
+            *tail, put(f32, b, w, v))
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_engine_step_programs_hold_no_layer_sized_copy(kind):
+    """The engine's OWN decode and verify jits (both cache arrays
+    donated), as the CPU compiler optimizes them: no copy, slice,
+    dynamic-slice or dynamic-update-slice as large as one layer of the
+    cache — the rows are scattered into the aliased arrays and nothing
+    else touches them."""
+    cfg = _cfg3(128)
+    params = init_decoder_params(jax.random.key(1), cfg)
+    eng = GenerationEngine(
+        params, cfg, max_batch_slots=3, block_size=BLOCK, prompt_buckets=BUCKETS,
+        donate_cache=True,
+    )
+    jit = eng._decode_jit if kind == "decode" else eng._verify_jit
+    args = _step_arguments(eng, kind, eng.cache.k, lambda dt, *s: np.zeros(s, dt))
+    compiled = jit.lower(eng.params, *args).compile()
+    layer = int(np.prod(eng.cache.k.shape[1:]))
+    assert _layer_sized_moves(compiled.as_text(), layer) == []
+    cache_bytes = 2 * eng.cache.k.size * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described (not attached) v5e chip, for compiles only."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or its lock is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_uncached(jit, *args):
+    """A deviceless compile cannot be read back from the persistent
+    compile cache: keep it out of one."""
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return jit.lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+def test_engine_step_programs_on_the_v5e_hold_no_cache_sized_temporary(
+    kind, one_v5e_chip, monkeypatch
+):
+    """The same two jits compiled by the TPU's own compiler at the
+    serving cells' shapes (24 layers, 8 slots, 514 blocks of 16, 16 x 64
+    heads, float32), with the layouts the compiler picks by default: the
+    Mosaic kernel is in every layer, both cache arrays are aliased at
+    their nominal, unpadded size, no layer-sized copy or slice is left
+    and the program's temporaries stay under 0.5 GiB (4.67 GiB before
+    ISSUE 24, beside four relayouts of the cache a step). Nothing runs:
+    shapes in, a compiled program out."""
+    import flexflow_tpu.ops.attention as attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)  # the chip's dispatch
+    cfg = TransformerConfig(
+        num_layers=24, hidden_size=1024, num_heads=16, ff_size=4096,
+        seq_length=1024, vocab_size=50257, causal=True,
+    )
+    sds = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_v5e_chip)
+    shapes = jax.eval_shape(lambda k: init_decoder_params(k, cfg), jax.random.key(0))
+    params = jax.tree.map(lambda a: sds(a.dtype, *a.shape), shapes)
+    # the engine's own cache is a stand-in of two blocks: the programs
+    # take their shapes from their arguments
+    cc = CacheConfig(num_layers=24, num_heads=16, head_dim=64, num_blocks=2)
+    eng = GenerationEngine(
+        shapes, cfg, max_batch_slots=8, block_size=16, prompt_buckets=[1024], max_seq_len=1024,
+        cache_config=cc, donate_cache=True,
+    )
+    eng.backend = "tpu"
+    cache = sds(np.float32, 24, 514, 16, *cc.row_shape)
+    jit = eng._decode_jit if kind == "decode" else eng._verify_jit
+    compiled = _compile_uncached(jit, params, *_step_arguments(eng, kind, cache, sds))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 24
+    assert _layer_sized_moves(text, 514 * 16 * 16 * 64) == []
+    m = compiled.memory_analysis()
+    nominal = 2 * 24 * 514 * 16 * 16 * 64 * 4
+    assert m.alias_size_in_bytes == nominal, m
+    assert m.temp_size_in_bytes < 0.5 * 2**30, m
+
+
+@pytest.mark.parametrize("kv_shards", [1, 4])
+def test_stored_cache_shape_is_row_major_and_unpadded_on_the_v5e(kv_shards, one_v5e_chip):
+    """What the whole arrangement rests on: the layout the TPU compiler
+    gives the stored shape BY DEFAULT is row-major (the paged kernel
+    DMAs blocks out of it as it is) and holds no padding — for the
+    cells' cache and for one shard of a four-way head-sharded one."""
+    cc = CacheConfig(num_layers=24, num_heads=16, head_dim=64, num_blocks=514, kv_shards=kv_shards)
+    rows, lanes = cc.row_shape
+    shard = (24, 514, 16, rows // kv_shards, lanes)
+    x = jax.ShapeDtypeStruct(shard, np.float32, sharding=one_v5e_chip)
+    compiled = _compile_uncached(jax.jit(lambda a: a[0, 0, 0]), x)
+    (fmt,), _ = compiled.input_formats
+    assert fmt.layout.major_to_minor == (0, 1, 2, 3, 4), fmt
+    assert compiled.memory_analysis().argument_size_in_bytes == int(np.prod(shard)) * 4
+
+
+def test_packed_cache_through_every_engine_program():
+    """An engine whose heads share cache rows (four heads of 32 in one
+    128-lane row) against the stateless forward: prefill, decode, a
+    suffix prefill on cached blocks, a COW copy, speculative verify —
+    and the block programs, which speak the LOGICAL [L, bs, H, D] shape
+    off the device (host tier, wire) and round-trip bit for bit."""
+    from flexflow_tpu.generation.speculative import SpeculationConfig
+
+    cfg = TransformerConfig(
+        num_layers=2, hidden_size=128, num_heads=4, ff_size=64,
+        seq_length=64, vocab_size=50, causal=True,
+    )
+    params = init_decoder_params(jax.random.key(0), cfg)
+    eng = GenerationEngine(
+        params, cfg, max_batch_slots=3, block_size=BLOCK, prompt_buckets=BUCKETS,
+    )
+    assert eng.cache.k.shape[3:] == (1, 128)
+    prompt = list(range(1, 21))  # 20 tokens: two full blocks and a half
+    out = eng.generate([prompt], SamplingParams(max_new_tokens=4))  # prefill, decode
+    assert out == [naive_greedy(params, prompt, 4)]
+    for other in (prompt[:16] + [7, 8, 9], prompt[:16]):  # suffix prefill; COW copy
+        got = eng.generate([other], SamplingParams(max_new_tokens=3))
+        assert got == [naive_greedy(params, other, 3)]
+    assert eng.prefix_cache.hits >= 2 and eng.prefix_cache.cow_copies_total >= 1
+    rep = [3, 4] * 6
+    got = eng.generate([rep], SamplingParams(max_new_tokens=6), speculation=SpeculationConfig(k=4))
+    assert got == [naive_greedy(params, rep, 6)] and eng.step_counts["verify"] >= 1
+
+    blk = eng.allocator.allocate(3)
+    rs = np.random.RandomState(5)
+    hk, hv = (rs.randn(2, BLOCK, 4, 32).astype(np.float32) for _ in range(2))
+    eng.import_kv_block(blk[0], hk, hv)
+    assert eng.cache.k.shape[3:] == (1, 128)
+    rk, rv = eng._read_block_jit(eng.cache.k, eng.cache.v, jnp.int32(blk[0]))
+    assert rk.shape == (2, BLOCK, 4, 32)
+    assert np.array_equal(np.asarray(rk), hk) and np.array_equal(np.asarray(rv), hv)
+    # head h of the block sits on lanes [32 h, 32 h + 32) of its row
+    assert np.array_equal(np.asarray(eng.cache.k[1, blk[0], 3, 0, 64:96]), hk[1, 3, 2])
+    payload = eng.pack_kv_blocks([blk[0]], BLOCK)  # the wire: logical too
+    assert np.array_equal(payload.blocks[0].host_k, hk)
+    eng.import_kv_blocks(blk[1:2], payload.blocks)
+    rk2, _ = eng._read_block_jit(eng.cache.k, eng.cache.v, jnp.int32(blk[1]))
+    assert np.array_equal(np.asarray(rk2), hk)
+    eng.allocator.free(blk)
 
 
 # ---------------------------------------------------------------------------

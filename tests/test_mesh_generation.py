@@ -320,13 +320,13 @@ from flexflow_tpu.ops.kernels.decode_attention import (
 mesh = Mesh(np.asarray(jax.devices()), ("model",))
 rs = np.random.RandomState(0)
 q = rs.randn(3, 4, 64).astype(np.float32)
-kc = rs.randn(6, 8, 4, 64).astype(np.float32)
-vc = rs.randn(6, 8, 4, 64).astype(np.float32)
+kc = rs.randn(2, 6, 8, 4, 64).astype(np.float32)  # [L, nb, bs, H, D]
+vc = rs.randn(2, 6, 8, 4, 64).astype(np.float32)
 bt = rs.randint(0, 6, (3, 4)).astype(np.int32)
 cl = np.array([5, 17, 30], np.int32)
-ref_o = reference_paged_attention(*map(jax.numpy.asarray, (q, kc, vc, bt, cl)))
-shd_o = sharded_paged_decode_attention(
-    *map(jax.numpy.asarray, (q, kc, vc, bt, cl)), mesh, interpret=True)
+q, kc, vc, bt, cl = map(jax.numpy.asarray, (q, kc, vc, bt, cl))
+ref_o = reference_paged_attention(q, kc, vc, 1, bt, cl)
+shd_o = sharded_paged_decode_attention(q, kc, vc, 1, bt, cl, mesh, interpret=True)
 res["kernel_parity"] = bool(np.allclose(np.asarray(ref_o), np.asarray(shd_o),
                                         atol=2e-5))
 

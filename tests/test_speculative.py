@@ -164,14 +164,14 @@ def test_pallas_append_kernel_matches_reference():
     rs = np.random.RandomState(3)
     b, w, h, d, nb, bs, mb = 3, 5, 4, 64, 9, 8, 4
     q = jnp.asarray(rs.randn(b, w, h, d), jnp.float32)
-    kc = jnp.asarray(rs.randn(nb, bs, h, d), jnp.float32)
-    vc = jnp.asarray(rs.randn(nb, bs, h, d), jnp.float32)
+    kc = jnp.asarray(rs.randn(2, nb, bs, h, d), jnp.float32)  # [L, nb, bs, H, D]
+    vc = jnp.asarray(rs.randn(2, nb, bs, h, d), jnp.float32)
     bt = jnp.asarray(rs.randint(1, nb, (b, mb)), jnp.int32)
     qp = jnp.asarray(
         [[10, 11, 12, 13, 14], [3, 4, -1, -1, -1], [-1, -1, -1, -1, -1]], jnp.int32
     )
-    ref = reference_paged_append_attention(q, kc, vc, bt, qp)
-    ker = paged_append_attention(q, kc, vc, bt, qp, interpret=True)
+    ref = reference_paged_append_attention(q, kc, vc, 1, bt, qp)
+    ker = paged_append_attention(q, kc, vc, 1, bt, qp, interpret=True)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(ker), atol=2e-5)
     # padding queries emit zeros, not NaN
     assert float(jnp.max(jnp.abs(ref[2]))) == 0.0
