@@ -36,6 +36,8 @@ COLLECTIVE = re.compile(
     r"^%?(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all|"
     r"collective-broadcast|ragged-all-to-all)(-start|-done)?(\.|$)"
 )
+# the program's own spans (``obs/steptrace.phase``): ``ff.<layer>.<phase>``
+PROGRAM_SPAN = "ff."
 # runtime bookkeeping that is on every host thread all the time and says
 # nothing about what the program was doing
 _HOST_NOISE = ("MemoryAllocation", "MemoryDeallocation", "PythonRefManager", "AllocateRawBuffer")
@@ -123,10 +125,16 @@ def exposed_collective(events: Sequence[Event]) -> Tuple[float, float]:
 
 
 def label_gap(gap: Interval, host_events: Sequence[Event]) -> str:
-    """What the host was doing in an idle gap: the SHORTEST host event
-    that covers at least half of it (the innermost frame that explains
-    it), else the one overlapping it most, else ``unattributed``."""
+    """What the host was doing in an idle gap: the innermost of the
+    program's own spans (``ff.<layer>.<phase>``, the SHORTEST that
+    covers at least half of the gap), else the shortest host event of
+    any kind that does (a runtime TraceMe, the benchmark's own span),
+    else the one overlapping it most, else ``unattributed``. The
+    program's spans come first because they name a layer, and a
+    runtime event inside one (``PjitFunction``, a transfer) names only
+    the call that layer happened to be in."""
     lo, hi = gap
+    best_ff: Optional[Event] = None
     best_cover: Optional[Event] = None
     best_overlap, best_any = 0.0, None
     for ev in host_events:
@@ -134,11 +142,14 @@ def label_gap(gap: Interval, host_events: Sequence[Event]) -> str:
         ov = min(b, hi) - max(a, lo)
         if ov <= 0:
             continue
-        if ov >= 0.5 * (hi - lo) and (best_cover is None or b - a < best_cover[2] - best_cover[1]):
-            best_cover = ev
+        if ov >= 0.5 * (hi - lo):
+            if best_cover is None or b - a < best_cover[2] - best_cover[1]:
+                best_cover = ev
+            if n.startswith(PROGRAM_SPAN) and (best_ff is None or b - a < best_ff[2] - best_ff[1]):
+                best_ff = ev
         if ov > best_overlap:
             best_overlap, best_any = ov, ev
-    chosen = best_cover or best_any
+    chosen = best_ff or best_cover or best_any
     return chosen[0] if chosen else "unattributed"
 
 

@@ -106,7 +106,7 @@ INSIDE = {
     # (whether one of a few seconds' open-loop admissions finds a stream
     # decoding is the seed's and the machine's to say: admit_stall is
     # asked of the closed loop, where every admission does)
-    "gpt2-medium.chat-open": {
+    "gpt2-medium.chat-steady": {
         "queue_wait_mean_ms", "frontend_inside_mean_ms", "host_dispatch_share.itl", "host_readback_share.itl",
         "host_sched_share.itl",
     },
