@@ -36,6 +36,27 @@ cache-sized copies back into every decode step):
 * the paged kernel takes the **whole cache and a static layer index**
   (``ops/kernels/decode_attention.py``) and DMAs single blocks out of it.
 
+**Two kinds of state in one manager.** The K/V arrays hold the
+ATTENTION layers only (``CacheConfig.num_layers`` counts those, and
+``num_heads`` the K/V heads: grouped queries store the heads they read).
+A layer whose operator is a gated short convolution
+(generation/decoder.py) keeps instead the last ``K - 1`` rows of its
+gated input per sequence, which is not paged: :class:`StateConfig`
+describes it, and :class:`KVCache` holds it beside K/V as
+
+* ``conv`` ``[n_conv, slots, K - 1, E]`` — every batch slot's state: a
+  prefill writes its slot's at the sequence's own length, a decode step
+  reads, shifts and writes all of it in place (donated, like K/V);
+* ``snap`` ``[n_conv, num_blocks, K - 1, E]`` — the state at the END of
+  a block, indexed by block id exactly as K/V is, written by the prefill
+  programs for every full block they write. A block can be resumed from
+  only together with it, so it lives, moves to the host tier and comes
+  back with the block (generation/prefix.py); a decode step never
+  touches it.
+
+A configuration without such layers has neither array, and its programs
+are what they were.
+
 Block 0 is reserved as a **scratch block**: padded prompt positions and
 inactive decode slots scatter their (meaningless) K/V there, so the
 jitted steps never need dynamic shapes or masked scatters to avoid
@@ -46,7 +67,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -203,10 +224,36 @@ class CacheConfig:
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class StateConfig:
+    """Geometry of the per-sequence state of the layers that are not
+    attention (module docstring): ``num_layers`` such layers, each
+    keeping ``rows`` rows of ``width`` values per sequence, for ``slots``
+    batch slots."""
+
+    num_layers: int
+    rows: int
+    width: int
+    slots: int
+    dtype: DataType = DataType.FLOAT
+
+    @property
+    def bytes_per_sequence(self) -> int:
+        """One slot's state (and one block's snapshot) over all layers."""
+        return self.num_layers * self.rows * self.width * self.dtype.size_bytes
+
+    def total_bytes(self, num_blocks: int) -> int:
+        """``conv`` + ``snap`` on the device."""
+        return (self.slots + num_blocks) * self.bytes_per_sequence
+
+
 class KVCache:
     """Device storage: ``k``/``v`` of shape [L, num_blocks, block_size,
     R, LW] (``CacheConfig.row_shape``; [..., H, D] after a row-major
-    reshape). Functional updates — jitted steps take the arrays and
+    reshape) and, with a :class:`StateConfig`, ``state``: the dict
+    ``{"conv": [n, slots, rows, width], "snap": [n, num_blocks, rows,
+    width]}`` (empty without one: an empty pytree adds nothing to a
+    program). Functional updates — jitted steps take the arrays and
     return replacements; this object just holds the current ones.
 
     ``sharding`` (a NamedSharding over the serving mesh, rows — that is,
@@ -217,11 +264,22 @@ class KVCache:
     program."""
 
     def __init__(self, config: CacheConfig, k: jax.Array, v: jax.Array,
-                 sharding=None):
+                 sharding=None, state_config: Optional[StateConfig] = None):
         self.config = config
         self.k = k
         self.v = v
         self.sharding = sharding
+        self.state_config = state_config
+        self.state: Dict[str, jax.Array] = self._state_zeros()
+
+    def _state_zeros(self) -> Dict[str, jax.Array]:
+        sc = self.state_config
+        if sc is None:
+            return {}
+        return {
+            "conv": jnp.zeros((sc.num_layers, sc.slots, sc.rows, sc.width), sc.dtype.jnp),
+            "snap": jnp.zeros((sc.num_layers, self.config.num_blocks, sc.rows, sc.width), sc.dtype.jnp),
+        }
 
     @staticmethod
     def _zeros(config: CacheConfig, sharding) -> jax.Array:
@@ -239,24 +297,31 @@ class KVCache:
         return jnp.zeros(shape, config.dtype.jnp, device=sharding)
 
     @classmethod
-    def create(cls, config: CacheConfig, sharding=None) -> "KVCache":
+    def create(cls, config: CacheConfig, sharding=None,
+               state_config: Optional[StateConfig] = None) -> "KVCache":
         return cls(
             config,
             cls._zeros(config, sharding),
             cls._zeros(config, sharding),
             sharding=sharding,
+            state_config=state_config,
         )
 
-    def update(self, k: jax.Array, v: jax.Array) -> None:
+    def update(self, k: jax.Array, v: jax.Array, **state: jax.Array) -> None:
+        """Take a program's replacements: K, V and whichever of the
+        ``state`` arrays it carried (``conv=``, ``snap=``)."""
         self.k = k
         self.v = v
+        self.state.update(state)
 
     def reset(self) -> None:
-        """Drop all cached K/V (engine crash recovery): every position is
-        rewritten by recompute-replay prefills, and rezeroing also clears
-        any NaN a poisoned batch may have written."""
+        """Drop all cached K/V and every sequence's state (engine crash
+        recovery): every position is rewritten by recompute-replay
+        prefills, and rezeroing also clears any NaN a poisoned batch may
+        have written."""
         self.k = self._zeros(self.config, self.sharding)
         self.v = self._zeros(self.config, self.sharding)
+        self.state = self._state_zeros()
 
 
 class BlockAllocator:

@@ -1,45 +1,69 @@
-"""Decoder-only transformer for the generation engine: a pure-JAX
-params pytree + three forward modes that provably agree.
+"""Decoder-only language model for the generation engine: a pure-JAX
+params pytree, ONE block definition driven by data, and four forward
+modes over it that provably agree.
 
-The graph-built transformers (models/transformer.py) lower to one-shot
-jitted programs with no state; generation needs a forward that can split
-into prefill (write the prompt's K/V into the cache) and decode (one
-token against cached K/V). This module keeps the same layer recipe as
-``attention_encoder_layer`` with ``causal=True`` — pre-LN residual
-blocks, GELU FFN, the ops/attention.py weight layouts ([E, H, D]
-projections, [H, D, E] output) — plus a learned absolute position
-embedding (cache positions index it directly) and a token-embedding
-front end with an LM head.
+**What a layer is comes from the configuration** (:class:`DecoderConfig`,
+a :class:`~flexflow_tpu.models.transformer.TransformerConfig` with the
+block's choices added; a plain ``TransformerConfig`` is the GPT-2
+setting of every one of them):
 
-Three forwards over one params pytree:
+* norm: ``layernorm`` (weight and bias) or ``rmsnorm`` (weight only);
+* positions: ``learned`` (an absolute table added at the embedding) or
+  ``rotary`` (rotate-half on q and k inside attention, nothing at the
+  embedding);
+* operator per layer (``layer_types``): ``attention`` — causal softmax
+  attention, optionally grouped (``num_kv_heads`` K/V heads, query head
+  ``i`` reading K/V head ``i // group``) and with a per-head RMSNorm on
+  q and k (``qk_norm``) — or ``conv``, a gated short convolution:
+  ``[B, C, X] = split3(W_in u)``, ``z_t = B_t * X_t``, ``c_t = sum_j
+  w[:, j] * z_{t-K+1+j}`` (depthwise, causal, kernel ``K``, zeros before
+  the sequence), ``out = W_out (C_t * c_t)``. Its state after position
+  ``t`` is the last ``K - 1`` rows of ``z``;
+* feed-forward per layer: ``gelu`` (two matrices with biases), ``swiglu``
+  (``W2 (silu(W1 v) * W3 v)``) for the first ``num_dense_layers``, and
+  routed experts after them (:func:`expert_ffn`: sigmoid router, a
+  selection bias used for the choice only, top-k, renormalised gates,
+  SwiGLU experts, no capacity and no dropped token);
+* dtype: the weights' own. Every matmul accumulates in float32 and
+  hands its result on in the activations' type; norms, softmax, the
+  rotary angles and the router are computed in float32.
+
+Every layer is ``h = x + Op(norm(x))``, ``y = h + FFN(norm(h))``.
+
+Four forwards over one params pytree, all through :func:`_layers`:
 
 * :func:`forward_full` — full-context causal forward, [B, S] -> logits
   [B, S, V]. The parity oracle.
-* :func:`prefill` — forward_full that also returns every layer's K/V
-  ([L, B, S, H, D]) for the engine to scatter into the block cache,
+* :func:`prefill` — forward_full that also returns every ATTENTION
+  layer's K/V ([n_attn, B, S, Hkv, D]) for the engine to scatter into
+  the block cache and every CONVOLUTION layer's padded ``z`` rows
+  ([n_conv, B, S + K - 1, E]: the engine takes each sequence's state at
+  its own length, and each block's at the block's end, out of them),
   with per-sequence length masking so padded prompt buckets match the
   unpadded forward.
 * :func:`decode_step` — one token per sequence against the cache
-  (writes the token's K/V, then decode-mode attention), [B] -> logits
-  [B, V].
+  (writes the token's K/V, then decode-mode attention; reads, shifts
+  and writes the convolution state in place), [B] -> logits [B, V].
 * :func:`verify_step` — a W-token append window per sequence against
-  the cache (writes all W tokens' K/V, then chunked-append attention
-  with causal-within-window masking), [B, W] -> logits [B, W, V]. The
-  speculative-decoding verification forward: W sequential decode_steps
-  in ONE call, with identical logits.
+  the cache, [B, W] -> logits [B, W, V]: the speculative-verification
+  forward (attention-only configurations) and the suffix prefill behind
+  a prefix hit (any configuration: the window continues from the
+  convolution state its slots hold).
 
 ``forward_full(tokens)[b, i] == decode logits after caching tokens[:i]``
-within fp32 tolerance — asserted by tests/test_generation.py;
-``verify_step`` agrees with ``decode_step`` token-for-token — asserted
-by tests/test_speculative.py.
+within fp32 tolerance — asserted by tests/test_generation.py and
+tests/test_lfm2.py; ``verify_step`` agrees with ``decode_step``
+token-for-token — asserted by tests/test_speculative.py.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import dataclasses
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..core.types import DataType
 from ..models.transformer import TransformerConfig
 from ..ops.attention import append_attention_core, decode_attention_core, masked_attention
 from .cache import slot_mapping
@@ -48,52 +72,169 @@ from .cache import slot_mapping
 DecoderParams = Dict[str, Any]
 
 
-def _glorot(rng, shape):
+@dataclasses.dataclass
+class DecoderConfig(TransformerConfig):
+    """The block's choices, as data (module docstring). The defaults are
+    GPT-2's, so ``DecoderConfig(**asdict(TransformerConfig(...)))`` is
+    the decoder the engine has always served."""
+
+    norm: str = "layernorm"  # | "rmsnorm"
+    norm_eps: float = 1e-5
+    positions: str = "learned"  # | "rotary"
+    rope_theta: float = 10000.0
+    qk_norm: bool = False
+    num_kv_heads: int = 0  # 0: as many as query heads
+    head_dim: int = 0  # 0: hidden_size // num_heads
+    layer_types: Tuple[str, ...] = ()  # per layer "attention" | "conv"; (): all attention
+    conv_kernel: int = 3
+    ffn: str = "gelu"  # the dense feed-forward: "gelu" | "swiglu"
+    num_dense_layers: int = -1  # layers from here on are routed experts; -1: none is
+    num_experts: int = 0
+    experts_per_token: int = 0
+    moe_ff_size: int = 0
+    routed_scaling_factor: float = 1.0
+    tied_head: bool = False  # logits = x E^T, no output matrix of its own
+
+    def __post_init__(self):
+        if self.layer_types and len(self.layer_types) != self.num_layers:
+            raise ValueError(f"{len(self.layer_types)} layer_types for {self.num_layers} layers")
+        for kind in self.layer_types:
+            if kind not in ("attention", "conv"):
+                raise ValueError(f"layer type {kind!r}: 'attention' or 'conv'")
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def dim_per_head(self) -> int:
+        return self.head_dim or self.hidden_size // self.num_heads
+
+    def operator(self, layer: int) -> str:
+        return self.layer_types[layer] if self.layer_types else "attention"
+
+    def ffn_kind(self, layer: int) -> str:
+        return "experts" if 0 <= self.num_dense_layers <= layer else self.ffn
+
+    @property
+    def attention_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "attention")
+
+    @property
+    def conv_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "conv")
+
+    @property
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.ffn_kind(l) == "experts")
+
+    @property
+    def stateful(self) -> bool:
+        """Some layer keeps a state that is not paged K/V."""
+        return bool(self.conv_layers)
+
+
+def decoder_config(cfg: TransformerConfig) -> DecoderConfig:
+    """``cfg`` as a :class:`DecoderConfig` (a plain TransformerConfig is
+    the GPT-2 setting)."""
+    if isinstance(cfg, DecoderConfig):
+        return cfg
+    return DecoderConfig(**dataclasses.asdict(cfg))
+
+
+def _config_of(params: DecoderParams) -> DecoderConfig:
+    """The GPT-2 setting a bare pytree implies (the forwards' ``cfg``
+    argument is optional for it: nothing in that setting is read from
+    the configuration but what the weights' shapes already say)."""
+    wq = params["layers"][0]["wq"]
+    return DecoderConfig(
+        num_layers=len(params["layers"]), hidden_size=wq.shape[0], num_heads=wq.shape[1],
+        ff_size=params["layers"][0]["ff1"].shape[1], vocab_size=params["tok_embed"].shape[0],
+        causal=True,
+    )
+
+
+def _glorot(rng, shape, dtype=jnp.float32):
     fan_in, fan_out = shape[0], shape[-1]
     if len(shape) == 3:  # [E, H, D] / [H, D, E] projections
         fan_in = shape[0] if shape[0] > shape[2] else shape[0] * shape[1]
         fan_out = shape[1] * shape[2] if shape[0] > shape[2] else shape[2]
     lim = (6.0 / (fan_in + fan_out)) ** 0.5
-    return jax.random.uniform(rng, shape, jnp.float32, -lim, lim)
+    return jax.random.uniform(rng, shape, jnp.float32, -lim, lim).astype(dtype)
 
 
 def init_decoder_params(
     rng: jax.Array, cfg: TransformerConfig, max_positions: Optional[int] = None
 ) -> DecoderParams:
-    """Initialize the decoder pytree for ``cfg`` (``vocab_size`` > 0)."""
+    """Initialize the decoder pytree for ``cfg`` (``vocab_size`` > 0),
+    in ``cfg.dtype`` (router weights and bias stay float32)."""
     if cfg.vocab_size <= 0:
         raise ValueError("generation decoder needs cfg.vocab_size > 0")
-    e, h = cfg.hidden_size, cfg.num_heads
-    d = e // h
+    cfg = decoder_config(cfg)
+    dt = cfg.dtype.jnp
+    e, h, hk, d = cfg.hidden_size, cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
     f, v = cfg.ff_size, cfg.vocab_size
     p = max_positions or cfg.seq_length
-    keys = iter(jax.random.split(rng, 4 + 6 * cfg.num_layers))
-    params: DecoderParams = {
-        "tok_embed": _glorot(next(keys), (v, e)),
-        "pos_embed": 0.02 * jax.random.normal(next(keys), (p, e), jnp.float32),
-        "final_ln_g": jnp.ones((e,), jnp.float32),
-        "final_ln_b": jnp.zeros((e,), jnp.float32),
-        "lm_head": _glorot(next(keys), (e, v)),
-        "layers": [],
-    }
-    for _ in range(cfg.num_layers):
-        params["layers"].append(
-            {
-                "ln1_g": jnp.ones((e,), jnp.float32),
-                "ln1_b": jnp.zeros((e,), jnp.float32),
-                "wq": _glorot(next(keys), (e, h, d)),
-                "wk": _glorot(next(keys), (e, h, d)),
-                "wv": _glorot(next(keys), (e, h, d)),
-                "wo": _glorot(next(keys), (h, d, e)),
-                "ln2_g": jnp.ones((e,), jnp.float32),
-                "ln2_b": jnp.zeros((e,), jnp.float32),
-                "ff1": _glorot(next(keys), (e, f)),
-                "ff1_b": jnp.zeros((f,), jnp.float32),
-                "ff2": _glorot(next(keys), (f, e)),
-                "ff2_b": jnp.zeros((e,), jnp.float32),
-            }
-        )
+    keys = iter(jax.random.split(rng, 4 + 10 * cfg.num_layers))
+    ones, zeros = jnp.ones((e,), dt), jnp.zeros((e,), dt)
+    params: DecoderParams = {"tok_embed": _glorot(next(keys), (v, e), dt)}
+    pos_key, head_key = next(keys), next(keys)
+    if cfg.positions == "learned":
+        params["pos_embed"] = (0.02 * jax.random.normal(pos_key, (p, e), jnp.float32)).astype(dt)
+    params["final_ln_g"] = ones
+    if cfg.norm == "layernorm":
+        params["final_ln_b"] = zeros
+    if not cfg.tied_head:
+        params["lm_head"] = _glorot(head_key, (e, v), dt)
+    params["layers"] = []
+    for li in range(cfg.num_layers):
+        layer: Dict[str, Any] = {"ln1_g": ones}
+        if cfg.norm == "layernorm":
+            layer["ln1_b"] = zeros
+        if cfg.operator(li) == "attention":
+            layer.update(
+                wq=_glorot(next(keys), (e, h, d), dt), wk=_glorot(next(keys), (e, hk, d), dt),
+                wv=_glorot(next(keys), (e, hk, d), dt), wo=_glorot(next(keys), (h, d, e), dt),
+            )
+            if cfg.qk_norm:
+                layer.update(q_norm_g=jnp.ones((d,), dt), k_norm_g=jnp.ones((d,), dt))
+        else:
+            layer.update(
+                conv_in=_glorot(next(keys), (e, 3 * e), dt),
+                conv_w=_glorot(next(keys), (e, cfg.conv_kernel), dt),
+                conv_out=_glorot(next(keys), (e, e), dt),
+            )
+        layer["ln2_g"] = ones
+        if cfg.norm == "layernorm":
+            layer["ln2_b"] = zeros
+        kind = cfg.ffn_kind(li)
+        if kind == "gelu":
+            layer.update(
+                ff1=_glorot(next(keys), (e, f), dt), ff1_b=jnp.zeros((f,), dt),
+                ff2=_glorot(next(keys), (f, e), dt), ff2_b=zeros,
+            )
+        elif kind == "swiglu":
+            layer.update(
+                w1=_glorot(next(keys), (e, f), dt), w3=_glorot(next(keys), (e, f), dt),
+                w2=_glorot(next(keys), (f, e), dt),
+            )
+        else:
+            n, fe = cfg.num_experts, cfg.moe_ff_size
+            layer.update(
+                router=_glorot(next(keys), (e, n)),
+                router_bias=0.02 * jax.random.normal(next(keys), (n,), jnp.float32),
+                ew1=_glorot(next(keys), (n, e, fe), dt), ew3=_glorot(next(keys), (n, e, fe), dt),
+                ew2=_glorot(next(keys), (n, fe, e), dt),
+            )
+        params["layers"].append(layer)
     return params
+
+
+# ------------------------------------------------------------------ pieces
+def _mm(eq: str, x, w):
+    """A matmul that accumulates in float32 and hands its result on in
+    the activations' type (for float32 operands: the plain einsum)."""
+    return jnp.einsum(eq, x, w, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _ln(x, g, b, eps=1e-5):
@@ -102,65 +243,258 @@ def _ln(x, g, b, eps=1e-5):
     return (x - mu) * jax.lax.rsqrt(var + eps) * g + b
 
 
-def _embed(params, tokens, positions):
-    return params["tok_embed"][tokens] + params["pos_embed"][positions]
+def _rms(x, g, eps):
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * jax.lax.rsqrt(ms + eps) * g
 
 
-def _ffn(layer, x):
-    h = _ln(x, layer["ln2_g"], layer["ln2_b"])
-    h = jax.nn.gelu(h @ layer["ff1"] + layer["ff1_b"])
-    return x + h @ layer["ff2"] + layer["ff2_b"]
+def _norm(cfg: DecoderConfig, x, where: Dict, name: str):
+    """``where[name_g]`` (and ``name_b``) applied over the last axis, in
+    float32, handed on in ``x``'s type."""
+    xf = x.astype(jnp.float32)
+    if cfg.norm == "layernorm":
+        out = _ln(xf, where[f"{name}_g"], where[f"{name}_b"], cfg.norm_eps)
+    else:
+        out = _rms(xf, where[f"{name}_g"].astype(jnp.float32), cfg.norm_eps)
+    return out.astype(x.dtype)
 
 
+def _rope(x, positions, theta: float):
+    """Rotate-half rotary embedding over all of the head's dimensions:
+    x [..., H, D], positions [...] (the leading axes of x)."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[..., None] * inv  # [..., D/2]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)[..., None, :]
+    xf = x.astype(jnp.float32)
+    half = jnp.concatenate([-xf[..., d // 2:], xf[..., : d // 2]], axis=-1)
+    return (xf * cos + half * sin).astype(x.dtype)
+
+
+def _embed(cfg: DecoderConfig, params, tokens, positions):
+    x = params["tok_embed"][tokens]
+    if cfg.positions == "learned":
+        x = x + params["pos_embed"][positions]
+    return x
+
+
+def _head(cfg: DecoderConfig, params, x):
+    x = _norm(cfg, x, params, "final_ln")
+    if cfg.tied_head:
+        return jnp.einsum("...e,ve->...v", x, params["tok_embed"], preferred_element_type=jnp.float32)
+    return jnp.einsum("...e,ev->...v", x, params["lm_head"], preferred_element_type=jnp.float32)
+
+
+def _qkv(cfg: DecoderConfig, layer, h, positions):
+    q = _mm("...e,ehd->...hd", h, layer["wq"])
+    k = _mm("...e,ehd->...hd", h, layer["wk"])
+    v = _mm("...e,ehd->...hd", h, layer["wv"])
+    if cfg.qk_norm:
+        q = _rms(q.astype(jnp.float32), layer["q_norm_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
+        k = _rms(k.astype(jnp.float32), layer["k_norm_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
+    if cfg.positions == "rotary":
+        q, k = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def conv_window(state, z):
+    """``z`` [B, T, E] behind the ``K - 1`` rows that came before it
+    (``state`` [B, K-1, E]; zeros at the start of a sequence): the
+    padded rows [B, T + K - 1, E] a causal convolution of kernel ``K``
+    reads. Row ``t + K - 1`` is ``z_t``; the state after ``n`` of the
+    window's tokens is rows ``n .. n + K - 2`` (:func:`state_at`)."""
+    return jnp.concatenate([state.astype(z.dtype), z], axis=1)
+
+
+def state_at(zpad, n, k: int):
+    """The convolution state after each sequence's first ``n[b]`` window
+    tokens, out of its padded rows: [B, T + K - 1, E] -> [B, K - 1, E]."""
+    idx = n[:, None] + jnp.arange(k - 1)[None, :]
+    return jnp.take_along_axis(zpad, idx[:, :, None], axis=1)
+
+
+def _conv_mix(layer, zpad, t: int):
+    """``c_t = sum_j w[:, j] * z_{t-K+1+j}`` for the window's ``t``
+    tokens, in float32, out of the padded rows."""
+    w = layer["conv_w"].astype(jnp.float32)
+    zf = zpad.astype(jnp.float32)
+    return sum(w[:, j] * zf[:, j : j + t] for j in range(w.shape[1])).astype(zpad.dtype)
+
+
+# the router's arithmetic is float32 throughout (the configuration's
+# `assumed`): at a near-tie of two experts' scores a coarser product
+# picks another expert, which is another model
+_ROUTER_PRECISION = jax.lax.Precision.HIGHEST
+def route(cfg: DecoderConfig, layer, v):
+    """Gates [T, N] (zero where an expert is not among a token's top-k)
+    and the choice [T, k], for rows ``v`` [T, E]: ``s = sigmoid(W_g v)``,
+    ``I = top_k(s + b)``, ``g_i = s_i / (sum_{j in I} s_j + 1e-6) *
+    routed_scaling_factor``. The bias moves the choice, never the gate."""
+    s = jax.nn.sigmoid(jnp.dot(
+        v.astype(jnp.float32), layer["router"].astype(jnp.float32), precision=_ROUTER_PRECISION
+    ))
+    _, chosen = jax.lax.top_k(s + layer["router_bias"].astype(jnp.float32), cfg.experts_per_token)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    gate = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6) * cfg.routed_scaling_factor
+    rows = jnp.arange(v.shape[0])[:, None]
+    return jnp.zeros_like(s).at[rows, chosen].set(gate), chosen
+
+
+def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = None):
+    """The routed feed-forward of rows ``v`` [T, E]: ``sum_{i in I} g_i
+    W2_i (silu(W1_i v) * W3_i v)``, exactly (no capacity, no dropped
+    token), and the gates it used ([T, N], for the counters).
+
+    ``held`` names the experts whose weights this call has, in the order
+    ``layer["ew1"]`` / ``ew3`` / ``ew2`` stack them (None: all of them).
+    The router always scores every expert; the result is the sum over
+    the held ones alone, so the results of calls that hold disjoint
+    shares add up to the whole layer's (a chip of an expert-sharded
+    deployment runs this with its share and the exchange adds them).
+    """
+    gates, _ = route(cfg, layer, v)
+    mine = gates if held is None else gates[:, jnp.asarray(tuple(held))]
+    # every expert multiplies every row, masked by the gate: the weights
+    # are read once either way, and up to 256 rows the 8 x multiply-adds
+    # hide behind those reads. Sorting the rows by expert (`ragged_dot`)
+    # was measured at these widths on the v5e, one layer, ms dense /
+    # sorted (my chip run, PR 27): 32 rows 0.99 / 1.62; 256: 1.09 / 2.76;
+    # 512: 2.05 / 3.03; 1024: 3.96 / 3.72 — no prompt bucket a cell uses
+    # is on its side, so it is not here.
+    up = jnp.einsum("te,nef->ntf", v, layer["ew1"], preferred_element_type=jnp.float32)
+    gate_up = jnp.einsum("te,nef->ntf", v, layer["ew3"], preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(up) * gate_up * mine.T[:, :, None]).astype(v.dtype)
+    out = jnp.einsum("ntf,nfe->te", hidden, layer["ew2"], preferred_element_type=jnp.float32)
+    return out.astype(v.dtype), gates
+
+
+def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List]):
+    """``x + FFN(norm(x))`` of layer ``li``. ``live`` ([...] bool, the
+    leading axes of x) says which rows are real tokens: only those are
+    counted into ``counts`` (one [N] int32 row per expert layer)."""
+    kind = cfg.ffn_kind(li)
+    if kind == "experts":
+        with jax.named_scope("router"):
+            h = _norm(cfg, x, layer, "ln2")
+            rows = h.reshape(-1, h.shape[-1])
+        with jax.named_scope("experts"):
+            out, gates = expert_ffn(cfg, layer, rows)
+        if counts is not None:
+            with jax.named_scope("router"):
+                counts.append(jnp.sum((gates > 0) & live.reshape(-1, 1), axis=0, dtype=jnp.int32))
+        return x + out.reshape(x.shape)
+    with jax.named_scope("mlp"):
+        h = _norm(cfg, x, layer, "ln2")
+        if kind == "swiglu":
+            up = jnp.einsum("...e,ef->...f", h, layer["w1"], preferred_element_type=jnp.float32)
+            gate_up = jnp.einsum("...e,ef->...f", h, layer["w3"], preferred_element_type=jnp.float32)
+            return x + _mm("...f,fe->...e", (jax.nn.silu(up) * gate_up).astype(x.dtype), layer["w2"])
+        h = jax.nn.gelu(_mm("...e,ef->...f", h, layer["ff1"]) + layer["ff1_b"])
+        return x + _mm("...f,fe->...e", h, layer["ff2"]) + layer["ff2_b"]
+
+
+def _layers(
+    cfg: DecoderConfig,
+    params: DecoderParams,
+    x,
+    positions,
+    live,
+    attend: Callable,
+    convolve: Callable,
+    counts: Optional[List] = None,
+):
+    """THE block definition: every layer of ``params`` applied to ``x``
+    ([..., E]; ``positions`` and ``live`` over its leading axes). What
+    differs between the four forwards is where the operators' context
+    comes from, and that is all the two callbacks hold:
+
+    * ``attend(ai, q, k, v)`` -> the attention context of the ``ai``-th
+      attention layer for its projected (normed, rotated) q, k, v;
+    * ``convolve(ci, z)`` -> the padded rows (:func:`conv_window`) of the
+      ``ci``-th convolution layer behind its gated input ``z``.
+
+    Scope names land in the instructions' op_name, so a device trace can
+    be grouped by them: ``layer<i>/attention | cache_write | conv |
+    conv_state | mlp | router | experts``."""
+    ai = ci = 0
+    for li, layer in enumerate(params["layers"]):
+        with jax.named_scope(f"layer{li}"):
+            if cfg.operator(li) == "attention":
+                with jax.named_scope("attention"):
+                    h = _norm(cfg, x, layer, "ln1")
+                    q, k, v = _qkv(cfg, layer, h, positions)
+                ctx = attend(ai, q, k, v)
+                with jax.named_scope("attention"):
+                    x = x + _mm("...hd,hde->...e", ctx, layer["wo"])
+                ai += 1
+            else:
+                with jax.named_scope("conv"):
+                    h = _norm(cfg, x, layer, "ln1")
+                    b_, c_, x_ = jnp.split(_mm("...e,ef->...f", h, layer["conv_in"]), 3, axis=-1)
+                    z = b_ * x_
+                zpad = convolve(ci, z)
+                with jax.named_scope("conv"):
+                    t = zpad.shape[1] - layer["conv_w"].shape[1] + 1
+                    c = _conv_mix(layer, zpad, t).reshape(z.shape)
+                    x = x + _mm("...e,ef->...f", c_ * c, layer["conv_out"])
+                ci += 1
+            x = _ffn(cfg, li, layer, x, live, counts)
+    return x
+
+
+def _no_conv(ci, z):
+    raise ValueError("a convolution layer needs its configuration: pass cfg")
+
+
+# ---------------------------------------------------------------- forwards
 def forward_full(
     params: DecoderParams,
     tokens: jax.Array,
     lengths: Optional[jax.Array] = None,
+    cfg: Optional[TransformerConfig] = None,
 ) -> jax.Array:
     """Full-context causal forward: [B, S] int32 -> logits [B, S, V].
     ``lengths`` masks padded key positions (bucketed prompts)."""
-    b, s = tokens.shape
-    x = _embed(params, tokens, jnp.arange(s)[None, :])
-    lens = lengths if lengths is not None else jnp.full((b,), s, jnp.int32)
-    for layer in params["layers"]:
-        h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-        q = jnp.einsum("bse,ehd->bshd", h, layer["wq"])
-        k = jnp.einsum("bse,ehd->bshd", h, layer["wk"])
-        v = jnp.einsum("bse,ehd->bshd", h, layer["wv"])
-        ctx = masked_attention(q, k, v, lens, causal=True)
-        x = x + jnp.einsum("bshd,hde->bse", ctx, layer["wo"])
-        x = _ffn(layer, x)
-    x = _ln(x, params["final_ln_g"], params["final_ln_b"])
-    return x @ params["lm_head"]
+    return prefill(params, tokens, lengths, cfg)[0]
 
 
 def prefill(
     params: DecoderParams,
     tokens: jax.Array,
-    lengths: jax.Array,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Prefill forward: logits [B, S, V] plus every layer's K/V
-    ([L, B, S, H, D] each) for the engine to write into the cache."""
+    lengths: Optional[jax.Array] = None,
+    cfg: Optional[TransformerConfig] = None,
+    counts: Optional[List] = None,
+):
+    """Prefill forward: logits [B, S, V] plus every attention layer's
+    K/V ([n_attn, B, S, Hkv, D] each) for the engine to write into the
+    cache and, for a configuration with convolution layers, a fourth
+    result: their padded ``z`` rows [n_conv, B, S + K - 1, E]."""
+    cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     b, s = tokens.shape
+    lens = lengths if lengths is not None else jnp.full((b,), s, jnp.int32)
+    positions = jnp.arange(s)[None, :]
     with jax.named_scope("embed"):
-        x = _embed(params, tokens, jnp.arange(s)[None, :])
-    ks, vs = [], []
-    for li, layer in enumerate(params["layers"]):
-        with jax.named_scope(f"layer{li}"):
-            with jax.named_scope("attention"):
-                h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-                q = jnp.einsum("bse,ehd->bshd", h, layer["wq"])
-                k = jnp.einsum("bse,ehd->bshd", h, layer["wk"])
-                v = jnp.einsum("bse,ehd->bshd", h, layer["wv"])
-                ks.append(k)
-                vs.append(v)
-                ctx = masked_attention(q, k, v, lengths, causal=True)
-                x = x + jnp.einsum("bshd,hde->bse", ctx, layer["wo"])
-            with jax.named_scope("mlp"):
-                x = _ffn(layer, x)
+        x = _embed(cfg, params, tokens, positions)
+    ks, vs, zs = [], [], []
+
+    def attend(ai, q, k, v):
+        ks.append(k)
+        vs.append(v)
+        with jax.named_scope("attention"):
+            return masked_attention(q, k, v, lens, causal=True)
+
+    def convolve(ci, z):
+        with jax.named_scope("conv_state"):
+            zs.append(conv_window(jnp.zeros((b, cfg.conv_kernel - 1, z.shape[-1]), z.dtype), z))
+        return zs[-1]
+
+    live = positions < lens[:, None]
+    x = _layers(cfg, params, x, positions, live, attend, convolve, counts)
     with jax.named_scope("head"):
-        x = _ln(x, params["final_ln_g"], params["final_ln_b"])
-        return x @ params["lm_head"], jnp.stack(ks), jnp.stack(vs)
+        empty = jnp.zeros((0,), x.dtype)  # a configuration without attention layers
+        out = (_head(cfg, params, x), jnp.stack(ks) if ks else empty, jnp.stack(vs) if vs else empty)
+    return out + (jnp.stack(zs),) if zs else out
 
 
 def write_rows(cache, layer: int, block, offset, rows):
@@ -185,48 +519,57 @@ def decode_step(
     context_lens: jax.Array,
     backend: str = "cpu",
     mesh=None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    cfg: Optional[TransformerConfig] = None,
+    conv: Optional[jax.Array] = None,
+    counts: Optional[List] = None,
+):
     """One decode step for every batch slot.
 
     tokens/positions: [B] int32 (the token being decoded and its cache
-    position); cache_k/cache_v: [L, num_blocks, block_size, R, LW] (the
-    stored form of [..., H, D]); block_tables: [B, max_blocks];
-    context_lens: [B] — valid cache positions INCLUDING this token
-    (``positions + 1`` for live slots, 0 for inactive ones, whose writes
-    land in scratch block 0).
-    Returns (logits [B, V], cache_k, cache_v) with the K/V written:
-    the two arrays pass through whole (rows scattered in place when the
-    caller donates them, the kernel reading blocks of the same arrays).
+    position); cache_k/cache_v: [n_attn, num_blocks, block_size, R, LW]
+    (the stored form of [..., Hkv, D], one entry per ATTENTION layer);
+    block_tables: [B, max_blocks]; context_lens: [B] — valid cache
+    positions INCLUDING this token (``positions + 1`` for live slots, 0
+    for inactive ones, whose writes land in scratch block 0).
+    ``conv``: [n_conv, B, K - 1, E], every slot's convolution state.
+    Returns (logits [B, V], cache_k, cache_v) with the K/V written — the
+    two arrays pass through whole (rows scattered in place when the
+    caller donates them, the kernel reading blocks of the same arrays) —
+    and, given ``conv``, a fourth result: the state shifted by this
+    token for the live slots, written in place likewise.
     """
+    cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     bs = cache_k.shape[2]
+    state = {"k": cache_k, "v": cache_v, "conv": conv}
+    live = context_lens > 0
     with jax.named_scope("embed"):
-        x = _embed(params, tokens, positions)  # [B, E]
+        x = _embed(cfg, params, tokens, positions)  # [B, E]
         block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, positions)
-    for li, layer in enumerate(params["layers"]):
-        # scope names land in the instructions' op_name: a device trace
-        # can be grouped by them (layer<i>/attention | cache_write | mlp)
-        with jax.named_scope(f"layer{li}"):
-            with jax.named_scope("attention"):
-                h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-                q = jnp.einsum("be,ehd->bhd", h, layer["wq"])
-                k = jnp.einsum("be,ehd->bhd", h, layer["wk"])
-                v = jnp.einsum("be,ehd->bhd", h, layer["wv"])
-            # write this token's K/V, then attend over the updated cache
-            # so the token sees itself (context_lens includes it)
-            with jax.named_scope("cache_write"):
-                cache_k = write_rows(cache_k, li, block, offset, k)
-                cache_v = write_rows(cache_v, li, block, offset, v)
-            with jax.named_scope("attention"):
-                ctx = decode_attention_core(
-                    q, cache_k, cache_v, li, block_tables, context_lens,
-                    backend=backend, mesh=mesh,
-                )
-                x = x + jnp.einsum("bhd,hde->be", ctx, layer["wo"])
-            with jax.named_scope("mlp"):
-                x = _ffn(layer, x)
+
+    def attend(ai, q, k, v):
+        # write this token's K/V, then attend over the updated cache
+        # so the token sees itself (context_lens includes it)
+        with jax.named_scope("cache_write"):
+            state["k"] = write_rows(state["k"], ai, block, offset, k)
+            state["v"] = write_rows(state["v"], ai, block, offset, v)
+        with jax.named_scope("attention"):
+            return decode_attention_core(
+                q, state["k"], state["v"], ai, block_tables, context_lens,
+                backend=backend, mesh=mesh,
+            )
+
+    def convolve(ci, z):
+        with jax.named_scope("conv_state"):
+            old = state["conv"][ci]
+            zpad = conv_window(old, z[:, None])
+            new = jnp.where(live[:, None, None], zpad[:, 1:], old)
+            state["conv"] = state["conv"].at[ci].set(new.astype(old.dtype))
+        return zpad
+
+    x = _layers(cfg, params, x, positions, live, attend, convolve if conv is not None else _no_conv, counts)
     with jax.named_scope("head"):
-        x = _ln(x, params["final_ln_g"], params["final_ln_b"])
-        return x @ params["lm_head"], cache_k, cache_v
+        out = (_head(cfg, params, x), state["k"], state["v"])
+    return out if conv is None else out + (state["conv"],)
 
 
 def verify_step(
@@ -238,7 +581,10 @@ def verify_step(
     block_tables: jax.Array,
     backend: str = "cpu",
     mesh=None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    cfg: Optional[TransformerConfig] = None,
+    conv_in: Optional[jax.Array] = None,
+    counts: Optional[List] = None,
+):
     """One chunked-append (speculative verification) step for every
     batch slot.
 
@@ -248,43 +594,55 @@ def verify_step(
     window slots (fixed-shape windows with fewer real drafts): their
     K/V scatter to scratch block 0 and their attention/logits rows are
     meaningless (the caller's acceptance logic never reads them).
-    cache_k/cache_v: [L, num_blocks, block_size, R, LW]; block_tables:
-    [B, max_blocks]. Returns (logits [B, W, V], cache_k, cache_v) with
+    cache_k/cache_v: [n_attn, num_blocks, block_size, R, LW];
+    block_tables: [B, max_blocks]. Returns (logits [B, W, V], cache_k,
+    cache_v) with
     all W tokens' K/V written — accepted positions hold exactly the K/V
     sequential decode would have written (a window token's K/V depends
     only on its prefix, which is valid up to the first rejection);
     rejected/later positions hold garbage that the next window
     overwrites before any masked read can see it.
+
+    ``conv_in`` ([n_conv, B, K - 1, E]) is the convolution state the
+    window continues from (a suffix prefill behind a prefix hit: the
+    real tokens are the window's first, padding after them). A fourth
+    result then holds the layers' padded ``z`` rows [n_conv, B,
+    W + K - 1, E], as :func:`prefill` returns them.
     """
+    cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     bs = cache_k.shape[2]
+    state = {"k": cache_k, "v": cache_v}
+    zs = []
     with jax.named_scope("embed"):
         safe_pos = jnp.maximum(positions, 0)
-        x = _embed(params, tokens, safe_pos)  # [B, W, E]
+        x = _embed(cfg, params, tokens, safe_pos)  # [B, W, E]
         block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, safe_pos)
         # padding -> scratch block 0, offset 0
         block = jnp.where(positions >= 0, block, 0).reshape(-1)
         offset = jnp.where(positions >= 0, offset, 0).reshape(-1)
-    for li, layer in enumerate(params["layers"]):
-        with jax.named_scope(f"layer{li}"):
-            with jax.named_scope("attention"):
-                h = _ln(x, layer["ln1_g"], layer["ln1_b"])
-                q = jnp.einsum("bwe,ehd->bwhd", h, layer["wq"])
-                k = jnp.einsum("bwe,ehd->bwhd", h, layer["wk"])
-                v = jnp.einsum("bwe,ehd->bwhd", h, layer["wv"])
-            # write the whole window's K/V, then attend over the updated
-            # cache with per-query position masks (each token sees itself
-            # and everything before it, nothing after)
-            with jax.named_scope("cache_write"):
-                cache_k = write_rows(cache_k, li, block, offset, k.reshape(-1, *k.shape[2:]))
-                cache_v = write_rows(cache_v, li, block, offset, v.reshape(-1, *v.shape[2:]))
-            with jax.named_scope("attention"):
-                ctx = append_attention_core(
-                    q, cache_k, cache_v, li, block_tables, positions,
-                    backend=backend, mesh=mesh,
-                )
-                x = x + jnp.einsum("bwhd,hde->bwe", ctx, layer["wo"])
-            with jax.named_scope("mlp"):
-                x = _ffn(layer, x)
+
+    def attend(ai, q, k, v):
+        # write the whole window's K/V, then attend over the updated
+        # cache with per-query position masks (each token sees itself
+        # and everything before it, nothing after)
+        with jax.named_scope("cache_write"):
+            state["k"] = write_rows(state["k"], ai, block, offset, k.reshape(-1, *k.shape[2:]))
+            state["v"] = write_rows(state["v"], ai, block, offset, v.reshape(-1, *v.shape[2:]))
+        with jax.named_scope("attention"):
+            return append_attention_core(
+                q, state["k"], state["v"], ai, block_tables, positions,
+                backend=backend, mesh=mesh,
+            )
+
+    def convolve(ci, z):
+        with jax.named_scope("conv_state"):
+            zs.append(conv_window(conv_in[ci], z))
+        return zs[-1]
+
+    x = _layers(
+        cfg, params, x, safe_pos, positions >= 0, attend,
+        convolve if conv_in is not None else _no_conv, counts,
+    )
     with jax.named_scope("head"):
-        x = _ln(x, params["final_ln_g"], params["final_ln_b"])
-        return x @ params["lm_head"], cache_k, cache_v
+        out = (_head(cfg, params, x), state["k"], state["v"])
+    return out + (jnp.stack(zs),) if zs else out

@@ -58,8 +58,16 @@ from ..obs.capacity import ProgramRegistry, ServingFlops
 from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
 from ..runtime import faults
-from .cache import BlockAllocator, CacheConfig, KVCache, slot_mapping
-from .decoder import DecoderParams, decode_step, prefill, verify_step, write_rows
+from .cache import BlockAllocator, CacheConfig, KVCache, StateConfig, slot_mapping
+from .decoder import (
+    DecoderParams,
+    decode_step,
+    decoder_config,
+    prefill,
+    state_at,
+    verify_step,
+    write_rows,
+)
 from .prefix import KVHandoffPayload, PackedBlock, PrefixCache, PrefixEntry
 from .sharding import ServingLayout
 
@@ -182,15 +190,22 @@ class InFlightDecode:
     :meth:`GenerationEngine.consume_decode`. Loop-thread only."""
 
     __slots__ = (
-        "out", "ok", "prev_k", "prev_v", "ck", "cv", "t0", "t_disp",
+        "out", "ok", "prev_k", "prev_v", "prev_conv", "prev_counts", "ck", "cv", "t0", "t_disp",
         "t_started", "traced", "n_active", "ctx_sum", "consumed",
     )
 
-    def __init__(self, out, ok, prev_k, prev_v, ck, cv, t0, t_disp, traced, n_active, ctx_sum):
+    def __init__(self, out, ok, prev_k, prev_v, ck, cv, t0, t_disp, traced, n_active, ctx_sum,
+                 prev_conv=None, prev_counts=None):
         self.out = out
         self.ok = ok
         self.prev_k = prev_k
         self.prev_v = prev_v
+        # the slots' convolution state before the step: rolled back with
+        # K/V (None for a configuration without one)
+        self.prev_conv = prev_conv
+        # the expert counters before the step (never donated, so always
+        # held): a failed step's own are poisoned with its other results
+        self.prev_counts = prev_counts
         # this step's cache outputs: rollback applies only while these
         # are still the engine's current refs (a failed chain is rolled
         # back once, to the OLDEST intact refs, never forward again)
@@ -235,8 +250,40 @@ class GenerationEngine:
         expected_prefix_sharing: float = 0.0,
     ):
         self.cfg = cfg
+        # the block's choices as data (decoder.py): a plain
+        # TransformerConfig is the GPT-2 setting of every one of them
+        self.dcfg = decoder_config(cfg)
         self.max_seq_len = max_seq_len or cfg.seq_length
         self.max_batch_slots = max_batch_slots
+        # paths that cannot carry a layer's per-sequence state yet, each
+        # refused by name (ROADMAP "What the system cannot run yet"):
+        # `unsupported[path]` is the reason, raised when the path is taken
+        self.unsupported: Dict[str, str] = {}
+        if self.dcfg.stateful:
+            self.unsupported = {
+                "speculation": (
+                    "speculative verification (engine.verify) is refused for a configuration "
+                    "with convolution layers: the window's z rows would have to be kept and "
+                    "each slot's convolution state chosen at its accepted length"
+                ),
+                "kv_handoff": (
+                    "the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused "
+                    "for a configuration with convolution layers: a payload carries K/V blocks "
+                    "and no convolution state, so the decode side could not continue from it"
+                ),
+                "tensor_parallel": (
+                    "tp_degree > 1 is refused for a configuration with convolution layers: the "
+                    "serving layout shards attention heads and the FFN, and has no placement "
+                    "for the convolution operator, its state or the expert weights"
+                ),
+            }
+            wants_tp = (
+                (tp_degree or 1) > 1
+                or (mesh is not None and int(dict(mesh.shape).get("model", 1)) > 1)
+                or (tp_degree is None and mesh is None and (mesh_devices or 1) > 1)
+            )
+            if wants_tp:
+                raise NotImplementedError(self.unsupported["tensor_parallel"])
         # ------------------------------------------------- serving mesh
         # Mesh-native engine (ISSUE 15): decoder weights and the KV
         # cache shard along the head axis over a "model" mesh axis
@@ -258,7 +305,7 @@ class GenerationEngine:
                 from ..parallel.mesh import MODEL_AXIS
 
                 tp = int(mesh.shape.get(MODEL_AXIS, 1))
-                self.layout = ServingLayout.build(cfg.num_heads, tp, mesh=mesh)
+                self.layout = ServingLayout.build(self.dcfg.kv_heads, tp, mesh=mesh)
             else:
                 n_dev = mesh_devices or tp_degree
             self.serving_strategy = choose_serving_strategy(
@@ -275,7 +322,7 @@ class GenerationEngine:
             )
             if self.layout is None:
                 self.layout = ServingLayout.build(
-                    cfg.num_heads, self.serving_strategy.tp_degree
+                    self.dcfg.kv_heads, self.serving_strategy.tp_degree
                 )
         self.tp_degree = self.layout.tp_degree if self.layout else 1
         self.mesh_devices = self.layout.mesh.size if self.layout else 1
@@ -283,38 +330,70 @@ class GenerationEngine:
             self.layout.shard_params(params) if self.layout else params
         )
         if cache_config is None:
+            # K/V is paged for the ATTENTION layers alone, at the K/V
+            # heads' width, in the type the configuration serves in
+            kv = dict(
+                num_layers=len(self.dcfg.attention_layers),
+                num_heads=self.dcfg.kv_heads,
+                head_dim=self.dcfg.dim_per_head,
+                block_size=block_size,
+                dtype=cfg.dtype,
+            )
             if cache_budget_bytes is not None:
                 # per-device HBM budget: the head-sharded cache holds
                 # H/tp heads of every block per chip, so the same chip
                 # budget buys tp x the blocks (ISSUE 15 satellite)
                 cache_config = CacheConfig.from_budget(
-                    cache_budget_bytes,
-                    num_layers=cfg.num_layers,
-                    num_heads=cfg.num_heads,
-                    head_dim=cfg.hidden_size // cfg.num_heads,
-                    block_size=block_size,
-                    kv_shards=self.tp_degree,
+                    cache_budget_bytes, kv_shards=self.tp_degree, **kv
                 )
             else:
                 # enough for every slot to reach max_seq_len (discounted
                 # by expected prefix sharing), plus scratch
                 cache_config = CacheConfig.for_slots(
-                    num_layers=cfg.num_layers,
-                    num_heads=cfg.num_heads,
-                    head_dim=cfg.hidden_size // cfg.num_heads,
                     max_seq_len=self.max_seq_len,
                     max_batch_slots=max_batch_slots,
-                    block_size=block_size,
                     expected_prefix_sharing=expected_prefix_sharing,
+                    **kv,
                 )
         if cache_config.kv_shards != self.tp_degree:
             # rows are packed shard by shard (CacheConfig.row_shape)
             cache_config = dataclasses.replace(cache_config, kv_shards=self.tp_degree)
         self.cache_config = cache_config
+        # the second kind of state (cache.py): what the convolution
+        # layers keep per sequence, per slot and per cached block
+        self.state_config: Optional[StateConfig] = None
+        if self.dcfg.stateful:
+            self.state_config = StateConfig(
+                num_layers=len(self.dcfg.conv_layers),
+                rows=self.dcfg.conv_kernel - 1,
+                width=cfg.hidden_size,
+                slots=max_batch_slots,
+                dtype=cfg.dtype,
+            )
         self.cache = KVCache.create(
             cache_config,
             sharding=self.layout.cache_sharding if self.layout else None,
+            state_config=self.state_config,
         )
+        # tokens every expert of every expert layer was handed, and the
+        # calls that handed them, counted ON THE DEVICE: the step
+        # programs take these arrays and return them grown, and nothing
+        # reads them back but expert_stats() (a /v2/stats scrape). NOT
+        # donated: a scrape reads the current arrays from another thread
+        # while the loop thread dispatches the next step, and a donated
+        # buffer would be gone under it; 2 KB a step is the price.
+        # int32: a counter wraps after 2**31 tokens to one expert.
+        self.expert_counts: Dict[str, jax.Array] = {}
+        if self.dcfg.expert_layers:
+            self.expert_counts = {
+                "tokens": jnp.zeros((len(self.dcfg.expert_layers), self.dcfg.num_experts), jnp.int32),
+                "calls": jnp.zeros((2,), jnp.int32),  # decode, prefill
+            }
+        # convolution-state traffic of the prefix cache (the conv_state
+        # section of /v2/stats): hits that restored a slot's state from a
+        # block's snapshot, snapshots written with registered blocks
+        self.state_restores_total = 0
+        self.state_snapshots_total = 0
         self.allocator = BlockAllocator(cache_config)
         self.max_blocks_per_seq = cache_config.blocks_for(self.max_seq_len)
         self.buckets = tuple(sorted(prompt_buckets or default_buckets(self.max_seq_len)))
@@ -456,7 +535,13 @@ class GenerationEngine:
         # chained fixed-shape programs), tokens/ok/emit counts come back
         # replicated so the host bookkeeping reads one copy. On the
         # legacy (no-mesh) path the jits are built exactly as before.
-        dec_donate = (3, 4) if self.donate else ()  # cache_k, cache_v
+        # (every step program also takes and returns two dicts, `state`
+        # and `counts`: the convolution state it carries and the expert
+        # counters. Both are EMPTY for a configuration without such
+        # layers, and an empty pytree adds no parameter and no result to
+        # a program: GPT-2's are what they were. `state` is donated with
+        # the cache, `counts` never: see expert_counts above.)
+        dec_donate = (3, 4, 13) if self.donate else ()  # cache_k, cache_v, state
         ver_donate = (4, 5) if self.donate else ()
         if self.layout is None:
             sharded = {}
@@ -464,7 +549,7 @@ class GenerationEngine:
         else:
             repl = self.layout.replicated
             csh = self.layout.cache_sharding
-            sharded = {"out_shardings": (repl, repl, csh, csh)}
+            sharded = {"out_shardings": (repl, repl, csh, csh, repl, repl)}
             dec_sh = dict(sharded)
             ver_sh = {"out_shardings": (repl, repl, repl, csh, csh)}
         self._prefill_jit = jax.jit(self._prefill_impl, **sharded)
@@ -482,6 +567,9 @@ class GenerationEngine:
         self.prefix_cache = PrefixCache(
             self.allocator, cache_config,
             enabled=prefix_cache, host_budget_bytes=host_cache_bytes,
+            state_bytes_per_block=(
+                self.state_config.bytes_per_sequence if self.state_config else 0
+            ),
         )
         if self.layout is None:
             blk_sh = rd_sh = {}
@@ -492,6 +580,9 @@ class GenerationEngine:
             blk_sh = {"out_shardings": (csh, csh)}
             rd_sh = {"out_shardings": (repl, repl)}
         self._prefix_prefill_jit = jax.jit(self._prefix_prefill_impl, **sharded)
+        # (the admission-time programs donate nothing, state included: a
+        # failed prefill leaves every array as it was, for the retry)
+        self._restore_state_jit = jax.jit(self._restore_state_impl)
         self._copy_block_jit = jax.jit(self._copy_block_impl, **blk_sh)
         self._read_block_jit = jax.jit(self._read_block_impl, **rd_sh)
         self._write_block_jit = jax.jit(self._write_block_impl, **blk_sh)
@@ -565,6 +656,13 @@ class GenerationEngine:
         into the fresh cache."""
         self.cache.reset()
         self.allocator.reset()
+        if self.expert_counts:
+            # cumulative across a reset, unless the failed program's
+            # results (a donating engine has no older ones) are all it has
+            try:
+                jax.block_until_ready(self.expert_counts)
+            except Exception:
+                self.expert_counts = jax.tree.map(jnp.zeros_like, self.expert_counts)
         # the prefix index is provenance-bound to the dead cache: drop
         # every entry (resident ids AND host copies) wholesale — replay
         # re-matches against the empty index, which is recompute,
@@ -582,7 +680,44 @@ class GenerationEngine:
         )
 
     # ------------------------------------------------------- jitted bodies
-    def _prefill_impl(self, params, tokens, length, cache_k, cache_v, block_table, temp, top_k, key, mask):
+    def _write_state(self, state, zs, slot, n_tokens, block_table, first_block):
+        """What a prefill leaves of its convolution layers' padded ``z``
+        rows (``zs`` [n_conv, 1, T + K - 1, E], decoder.py): the slot's
+        state after the window's ``n_tokens`` real tokens into ``conv``,
+        and into ``snap`` the state at the end of every block the window
+        filled (the window starts at the table's block ``first_block``;
+        a block it did not fill to its end writes to scratch block 0).
+        A block joins the prefix index only with that snapshot."""
+        bs = self.cache_config.block_size
+        rows = self.state_config.rows
+        n_blocks = (zs.shape[2] - rows) // bs
+        with jax.named_scope("conv_state"):
+            now = jax.vmap(lambda z: state_at(z, n_tokens[None], rows + 1)[0])(zs)  # [n_conv, K-1, E]
+            conv = jax.lax.dynamic_update_slice_in_dim(
+                state["conv"], now[:, None].astype(state["conv"].dtype), slot, axis=1
+            )
+            snap = state["snap"]
+            if n_blocks:
+                ends = (jnp.arange(n_blocks, dtype=jnp.int32) + 1) * bs  # tokens before a block's end
+                at_end = zs[:, 0][:, ends[:, None] + jnp.arange(rows)[None, :]]  # [n_conv, n_blocks, K-1, E]
+                idx = jnp.clip(first_block + jnp.arange(n_blocks), 0, block_table.shape[0] - 1)
+                dst = jnp.where(ends <= n_tokens, block_table[idx], 0)
+                snap = snap.at[:, dst].set(at_end.astype(snap.dtype))
+        return {"conv": conv, "snap": snap}
+
+    def _count(self, counts, rows, kind: int):
+        """The step's per-expert token counts (``rows``: one [N] row per
+        expert layer, decoder.py) added into the carried counters."""
+        if not counts:
+            return counts
+        with jax.named_scope("router"):
+            return {
+                "tokens": counts["tokens"] + jnp.stack(rows),
+                "calls": counts["calls"].at[kind].add(1),
+            }
+
+    def _prefill_impl(self, params, tokens, length, cache_k, cache_v, block_table, temp, top_k, key, mask,
+                      state=None, slot=None, counts=None):
         s = tokens.shape[1]
         self.trace_counts[f"prefill[{s}]"] = self.trace_counts.get(f"prefill[{s}]", 0) + 1
         self.programs.note_trace(f"prefill[{s}]", {
@@ -590,7 +725,13 @@ class GenerationEngine:
             "cache_k": cache_k, "block_table": block_table,
             "temp": temp, "top_k": top_k, "key": key, "mask": mask,
         })
-        logits, ks, vs = prefill(params, tokens, jnp.full((1,), length, jnp.int32))
+        state, counts, rows = state or {}, counts or {}, []
+        logits, ks, vs, *zs = prefill(
+            params, tokens, jnp.full((1,), length, jnp.int32), cfg=self.dcfg, counts=rows
+        )
+        if self.state_config is not None:
+            state = self._write_state(state, zs[0], slot, length, block_table, 0)
+        counts = self._count(counts, rows, 1)
         positions = jnp.arange(s, dtype=jnp.int32)
         block, offset = slot_mapping(block_table, positions, cache_k.shape[2])
         block = jnp.where(positions < length, block, 0)  # padding -> scratch
@@ -609,10 +750,11 @@ class GenerationEngine:
             # gate above still sees model NaN, never the mask)
             last = last + mask
             token = _sample(last[None], temp[None], top_k[None], key[None])[0]
-        return token, ok, cache_k, cache_v
+        return token, ok, cache_k, cache_v, state, counts
 
     def _decode_impl(
-        self, params, tokens, positions, cache_k, cache_v, block_tables, context_lens, temps, top_ks, bias, seeds, counts, mask
+        self, params, tokens, positions, cache_k, cache_v, block_tables, context_lens, temps, top_ks, bias, seeds, counts, mask,
+        state=None, expert_counts=None,
     ):
         self.trace_counts["decode"] = self.trace_counts.get("decode", 0) + 1
         self.programs.note_trace("decode", {
@@ -621,10 +763,17 @@ class GenerationEngine:
             "context_lens": context_lens, "temps": temps, "top_ks": top_ks,
             "bias": bias, "seeds": seeds, "counts": counts, "mask": mask,
         })
-        logits, cache_k, cache_v = decode_step(
+        state, expert_counts, rows = state or {}, expert_counts or {}, []
+        logits, cache_k, cache_v, *conv = decode_step(
             params, tokens, positions, cache_k, cache_v, block_tables,
             context_lens, backend=self.backend, mesh=self._kernel_mesh,
+            cfg=self.dcfg, conv=state.get("conv"), counts=rows,
         )
+        if self.state_config is not None:
+            # a decode step carries the slots' state alone: the blocks'
+            # snapshots are the prefill programs' to write
+            state = {"conv": conv[0]}
+        expert_counts = self._count(expert_counts, rows, 0)
         # bias is the fault plan's per-slot NaN poison (zeros outside
         # chaos runs); applying it before the finiteness reduce makes the
         # injected poison indistinguishable from model-produced NaN/inf.
@@ -638,7 +787,7 @@ class GenerationEngine:
             # host fold_in/stack on the critical path, same key bits as
             # before
             keys = derive_keys(seeds, counts)
-            return _sample(logits, temps, top_ks, keys), ok, cache_k, cache_v
+            return _sample(logits, temps, top_ks, keys), ok, cache_k, cache_v, state, expert_counts
 
     def _verify_impl(
         self, params, tokens, start, n_draft, cache_k, cache_v, block_tables, temps, top_ks, bias, seeds, counts, mask
@@ -664,7 +813,7 @@ class GenerationEngine:
         positions = jnp.where(offs <= n_draft[:, None], start[:, None] + offs, -1)
         logits, cache_k, cache_v = verify_step(
             params, tokens, positions, cache_k, cache_v, block_tables,
-            backend=self.backend, mesh=self._kernel_mesh,
+            backend=self.backend, mesh=self._kernel_mesh, cfg=self.dcfg,
         )
         # per-position grammar mask [B, W, V] rides next to the NaN-poison
         # bias; draft and target score the SAME masked logits, so
@@ -683,8 +832,19 @@ class GenerationEngine:
         )
         return out, jnp.where(n_draft >= 0, n_emitted, 0), ok, cache_k, cache_v
 
+    def _restore_state_impl(self, state, slot, block):
+        """A prefix hit's restore: the slot's convolution state becomes
+        the snapshot stored with the last matched block, from which the
+        suffix prefill continues."""
+        self.trace_counts["state_restore"] = self.trace_counts.get("state_restore", 0) + 1
+        self.programs.note_trace("state_restore", {"state": state, "slot": slot, "block": block})
+        at = jax.lax.dynamic_index_in_dim(state["snap"], block, axis=1)  # [n_conv, 1, K-1, E]
+        conv = jax.lax.dynamic_update_slice_in_dim(state["conv"], at, slot, axis=1)
+        return {"conv": conv, "snap": state["snap"]}
+
     def _prefix_prefill_impl(
-        self, params, tokens, start, n_real, cache_k, cache_v, block_table, temp, top_k, key, mask
+        self, params, tokens, start, n_real, cache_k, cache_v, block_table, temp, top_k, key, mask,
+        state=None, slot=None, counts=None,
     ):
         """Suffix-only prefill against a cached prefix: the [1, W]
         suffix window attends over the block table (shared prefix
@@ -704,15 +864,29 @@ class GenerationEngine:
         })
         offs = jnp.arange(w, dtype=jnp.int32)
         positions = jnp.where(offs < n_real, start + offs, -1)[None, :]
-        logits, cache_k, cache_v = verify_step(
+        state, counts, rows = state or {}, counts or {}, []
+        conv_in = None
+        if self.state_config is not None:
+            # the window continues from the state the slot holds: the
+            # restore (_restore_state_impl) put the matched prefix's there
+            conv_in = jax.lax.dynamic_slice_in_dim(state["conv"], slot, 1, axis=1)
+        logits, cache_k, cache_v, *zs = verify_step(
             params, tokens, positions, cache_k, cache_v, block_table[None],
-            backend=self.backend, mesh=self._kernel_mesh,
+            backend=self.backend, mesh=self._kernel_mesh, cfg=self.dcfg,
+            conv_in=conv_in, counts=rows,
         )
+        if self.state_config is not None:
+            # a reused prefix ends on a block boundary for such a
+            # configuration (prefix_plan), so the window's blocks are whole
+            state = self._write_state(
+                state, zs[0], slot, n_real, block_table, start // self.cache_config.block_size
+            )
+        counts = self._count(counts, rows, 1)
         last = logits[0, n_real - 1]
         ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
         last = last + mask  # grammar mask: [V], finite (see _prefill_impl)
         token = _sample(last[None], temp[None], top_k[None], key[None])[0]
-        return token, ok, cache_k, cache_v
+        return token, ok, cache_k, cache_v, state, counts
 
     def _copy_block_impl(self, cache_k, cache_v, src, dst):
         """COW: duplicate one block's K/V across all layers (the first
@@ -728,17 +902,22 @@ class GenerationEngine:
             jax.lax.dynamic_update_slice_in_dim(cache_v, v, dst, axis=1),
         )
 
-    def _read_block_impl(self, cache_k, cache_v, src):
+    def _read_block_impl(self, cache_k, cache_v, src, snap=None):
         """Host-tier swap-out read: one block's K/V ([L, bs, H, D]
         each: off the device a block has its logical shape, whatever
         rows the cache stores it in), fetched with a traced index so
-        every block id shares ONE program."""
+        every block id shares ONE program. With ``snap`` (a
+        configuration with convolution layers) a third result: the
+        state stored with the block, [n_conv, K - 1, E]."""
         self.trace_counts["kv_block_read"] = self.trace_counts.get("kv_block_read", 0) + 1
         self.programs.note_trace("kv_block_read", {"cache_k": cache_k, "src": src})
-        return (
+        out = (
             self._logical(jax.lax.dynamic_index_in_dim(cache_k, src, axis=1, keepdims=False)),
             self._logical(jax.lax.dynamic_index_in_dim(cache_v, src, axis=1, keepdims=False)),
         )
+        if self.state_config is None:
+            return out
+        return out + (jax.lax.dynamic_index_in_dim(snap, src, axis=1, keepdims=False),)
 
     def _logical(self, blocks):
         """Stored [L, ..., bs, R, LW] -> logical [L, ..., bs, H, D]."""
@@ -750,20 +929,26 @@ class GenerationEngine:
         stores it: [L, ..., bs, R, LW], in its dtype."""
         return blocks.reshape(*blocks.shape[:-2], *like.shape[3:]).astype(like.dtype)
 
-    def _write_block_impl(self, cache_k, cache_v, dst, host_k, host_v):
-        """Host-tier swap-in write: place one block's K/V back into the
-        device cache at ``dst``."""
+    def _write_block_impl(self, cache_k, cache_v, dst, host_k, host_v, snap=None, host_s=None):
+        """Host-tier swap-in write: place one block's K/V (and, with
+        ``snap``, the state stored with it) back into the device cache
+        at ``dst``."""
         self.trace_counts["kv_block_write"] = self.trace_counts.get("kv_block_write", 0) + 1
         self.programs.note_trace("kv_block_write", {
             "cache_k": cache_k, "dst": dst, "host_k": host_k,
         })
-        return (
+        out = (
             jax.lax.dynamic_update_slice_in_dim(
                 cache_k, self._stored(host_k[:, None], cache_k), dst, axis=1
             ),
             jax.lax.dynamic_update_slice_in_dim(
                 cache_v, self._stored(host_v[:, None], cache_v), dst, axis=1
             ),
+        )
+        if self.state_config is None:
+            return out
+        return out + (
+            jax.lax.dynamic_update_slice_in_dim(snap, host_s[:, None].astype(snap.dtype), dst, axis=1),
         )
 
     def _read_blocks_impl(self, cache_k, cache_v, srcs):
@@ -836,10 +1021,13 @@ class GenerationEngine:
         key: jax.Array,
         prefix_len: int = 0,
         mask=None,
+        slot: int = 0,
     ) -> int:
         """Prefill one sequence into its allocated blocks and sample its
         first generated token. ``block_table`` is the sequence's block
         ids (padded internally to the engine's fixed table width).
+        ``slot`` is the batch slot the sequence will decode in: where a
+        configuration's convolution layers keep its state.
         ``prefix_len`` > 0 means positions [0, prefix_len) are already
         cached (shared prefix blocks at the front of the table): only
         the suffix is computed, attending to the cached prefix — the
@@ -848,7 +1036,7 @@ class GenerationEngine:
         to the sampled position; None stages the shared zeros row."""
         faults.inject(faults.GENERATION_PREFILL, prompt)
         if prefix_len > 0:
-            return self._prefill_suffix(prompt, block_table, sampling, key, prefix_len, mask)
+            return self._prefill_suffix(prompt, block_table, sampling, key, prefix_len, mask, slot)
         self.step_counts["prefill"] += 1
         with phase("engine.prefill.dispatch") as disp:
             n = len(prompt)
@@ -858,7 +1046,7 @@ class GenerationEngine:
             tokens[0, :n] = prompt
             table = np.zeros((self.max_blocks_per_seq,), np.int32)
             table[: len(block_table)] = block_table
-            token, ok, ck, cv = self._prefill_jit(
+            token, ok, ck, cv, state, counts = self._prefill_jit(
                 self.params,
                 self._dev(tokens),
                 jnp.int32(n),
@@ -869,11 +1057,13 @@ class GenerationEngine:
                 jnp.int32(sampling.top_k),
                 self._dev(key),
                 self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
+                *self._state_args(slot),
             )
         with phase("engine.prefill.block") as block:
-            jax.block_until_ready((token, ok, ck, cv))  # device execution done
+            jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
-            self.cache.update(ck, cv)
+            self.cache.update(ck, cv, **state)
+            self.expert_counts = counts
             self.last_finite = np.asarray(ok).reshape(1)
             out = int(token)  # result sync lands inside the readback span
         elapsed, execute_s = self._record_step_phases("prefill", disp, block, read)
@@ -916,12 +1106,15 @@ class GenerationEngine:
         key: jax.Array,
         prefix_len: int,
         mask=None,
+        slot: int = 0,
     ) -> int:
         """Suffix-only prefill: positions [prefix_len, len(prompt))
         computed against the cached prefix. Accounting mirrors
         prefill(): step/FLOPs/time under the "prefill" kind, compile
         calls registry-stamped, steady calls ledger-paired."""
         self.step_counts["prefill"] += 1
+        if self.state_config is not None:
+            self._restore_state(slot, block_table[prefix_len // self.cache_config.block_size - 1])
         with phase("engine.prefill.dispatch") as disp:
             n = len(prompt)
             suffix = list(prompt[prefix_len:])
@@ -932,7 +1125,7 @@ class GenerationEngine:
             tokens[0, : len(suffix)] = suffix
             table = np.zeros((self.max_blocks_per_seq,), np.int32)
             table[: len(block_table)] = block_table
-            token, ok, ck, cv = self._prefix_prefill_jit(
+            token, ok, ck, cv, state, counts = self._prefix_prefill_jit(
                 self.params,
                 self._dev(tokens),
                 jnp.int32(prefix_len),
@@ -944,11 +1137,13 @@ class GenerationEngine:
                 jnp.int32(sampling.top_k),
                 self._dev(key),
                 self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
+                *self._state_args(slot),
             )
         with phase("engine.prefill.block") as block:
-            jax.block_until_ready((token, ok, ck, cv))  # device execution done
+            jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
-            self.cache.update(ck, cv)
+            self.cache.update(ck, cv, **state)
+            self.expert_counts = counts
             self.last_finite = np.asarray(ok).reshape(1)
             out = int(token)  # result sync lands inside the readback span
         elapsed, execute_s = self._record_step_phases("prefill", disp, block, read)
@@ -975,6 +1170,24 @@ class GenerationEngine:
             )
         return out
 
+    def _state_args(self, slot: int) -> tuple:
+        """The trailing (state, slot, counts) of the prefill programs."""
+        return (
+            self.cache.state,
+            jnp.int32(slot) if self.state_config is not None else None,
+            self.expert_counts,
+        )
+
+    def _restore_state(self, slot: int, block: int) -> None:
+        """A prefix hit on a configuration with convolution layers: the
+        slot takes the state stored with the last matched block (span
+        ``ff.cache.state_restore``; ``conv_state.restores_total``)."""
+        with phase("cache.state_restore", slot=slot):
+            self.cache.state = self._restore_state_jit(
+                self.cache.state, jnp.int32(slot), jnp.int32(block)
+            )
+        self.state_restores_total += 1
+
     # ------------------------------------------------------ prefix caching
     def prefix_plan(self, prompt: Sequence[int]) -> PrefixPlan:
         """Match ``prompt`` against the radix index and decide what to
@@ -999,6 +1212,13 @@ class GenerationEngine:
         reuse = min(len(run) * bs, len(prompt) - 1)
         n_shared = reuse // bs
         cow = run[n_shared] if (reuse % bs and len(run) > n_shared) else None
+        if self.state_config is not None:
+            # a convolution state is stored at a block's END and nowhere
+            # else: reuse stops on the last whole block (a fully covered
+            # prompt recomputes its last block instead of copying it)
+            reuse, cow = n_shared * bs, None
+            if not reuse:
+                return EMPTY_PREFIX_PLAN
         entries = run[:n_shared]
         off_idx = [i for i, e in enumerate(entries) if not e.resident]
         cow_off = cow is not None and not cow.resident
@@ -1127,12 +1347,7 @@ class GenerationEngine:
                 buf = pc.take_host_copy(entry)
                 if buf is None:  # corrupted or already dropped
                     raise ValueError("host-tier block failed CRC verification")
-                hk, hv = buf
-                ck, cv = self._write_block_jit(
-                    self.cache.k, self.cache.v, jnp.int32(dst),
-                    self._dev(hk), self._dev(hv),
-                )
-                self.cache.update(ck, cv)
+                self._write_host_copy(dst, buf)
             except Exception:
                 pc.swap_in_failures += 1
                 pc.recompute_fallbacks += 1
@@ -1165,18 +1380,24 @@ class GenerationEngine:
             buf = pc.take_host_copy(src)
             if buf is None:
                 raise ValueError("host-tier block failed CRC verification")
-            hk, hv = buf
-            ck, cv = self._write_block_jit(
-                self.cache.k, self.cache.v, jnp.int32(dst),
-                self._dev(hk), self._dev(hv),
-            )
-            self.cache.update(ck, cv)
+            self._write_host_copy(dst, buf)
         except Exception:
             pc.swap_in_failures += 1
             pc.recompute_fallbacks += 1
             return False
         pc.swaps_in_total += 1
         return True
+
+    def _write_host_copy(self, dst: int, buf) -> None:
+        """One host-tier copy (K, V and, where blocks carry one, the
+        convolution state stored with the block) into device block
+        ``dst``."""
+        hk, hv, *hs = buf
+        extra = (self.cache.state["snap"], self._dev(hs[0])) if self.state_config is not None else ()
+        ck, cv, *snap = self._write_block_jit(
+            self.cache.k, self.cache.v, jnp.int32(dst), self._dev(hk), self._dev(hv), *extra
+        )
+        self.cache.update(ck, cv, **({"snap": snap[0]} if snap else {}))
 
     def register_prefix(
         self,
@@ -1201,9 +1422,13 @@ class GenerationEngine:
             pc.hits += 1
             pc.tokens_reused_total += prefix_len
             pc.blocks_reused_total += len(entries)
-        self.prefix_cache.register_chain(
+        n_new = self.prefix_cache.register_chain(
             prompt, table, shared_idx, entries, len(prompt)
         )
+        if self.state_config is not None:
+            # every block a prefill fills to its end got its snapshot
+            # from the same program (_write_state)
+            self.state_snapshots_total += n_new
 
     def stash_prefix(self, state) -> None:
         """Preemption stash: register the victim's full blocks below
@@ -1216,6 +1441,12 @@ class GenerationEngine:
         req = state.req
         tokens = list(req.original_prompt) + list(req.generated)
         upto = min(state.cached_len, len(tokens))
+        if self.state_config is not None:
+            # blocks filled while DECODING carry no convolution state (a
+            # decode step writes no snapshot: cache.py) and so cannot be
+            # resumed from: only what the admission's prefill wrote is
+            # registered, and that is registered already
+            upto = min(upto, len(req.prompt))
         self.prefix_cache.register_chain(
             tokens, state.blocks, state.shared_idx, state.shared_entries, upto
         )
@@ -1240,10 +1471,11 @@ class GenerationEngine:
 
         def read(block_id: int):
             faults.inject(faults.GENERATION_KV_OFFLOAD, ("out", 1))
-            k, v = self._read_block_jit(
-                self.cache.k, self.cache.v, jnp.int32(block_id)
-            )
-            return np.asarray(k), np.asarray(v)
+            snap = self.cache.state.get("snap")
+            extra = () if snap is None else (snap,)
+            return tuple(np.asarray(a) for a in self._read_block_jit(
+                self.cache.k, self.cache.v, jnp.int32(block_id), *extra
+            ))
 
         # one span per call: victim selection, the device reads and the
         # CRCs are all what evicting to the host tier costs an admission
@@ -1262,6 +1494,7 @@ class GenerationEngine:
         replicated out_shardings gather every head even when this
         engine's cache is sharded, so the payload is TP-agnostic), each
         block CRC-stamped at packing time."""
+        self._refuse("kv_handoff")
         bs = self.cache_config.block_size
         n_blocks = self.cache_config.blocks_for(n_positions)
         ids = list(table[:n_blocks])
@@ -1286,6 +1519,7 @@ class GenerationEngine:
         reshard the full-head payload onto this engine's own head
         partitioning, so differing pool TP degrees need no explicit
         reshard step."""
+        self._refuse("kv_handoff")
         ck, cv = self._write_block_jit(
             self.cache.k, self.cache.v, jnp.int32(dst),
             self._dev(host_k), self._dev(host_v),
@@ -1298,6 +1532,7 @@ class GenerationEngine:
         to ``max_blocks_per_seq`` by repeating the last block, so a
         decode-pool replica pays one dispatch per adopted stream, not
         one per block, between its decode steps."""
+        self._refuse("kv_handoff")
         ids = list(dsts)
         pad = self.max_blocks_per_seq - len(ids)
         idx = ids + [ids[-1]] * pad
@@ -1309,6 +1544,12 @@ class GenerationEngine:
             self._dev(hk), self._dev(hv),
         )
         self.cache.update(ck, cv)
+
+    def _refuse(self, path: str) -> None:
+        """Raise the reason this configuration cannot take ``path``
+        (``self.unsupported``, filled at construction), if it cannot."""
+        if path in self.unsupported:
+            raise NotImplementedError(self.unsupported[path])
 
     def _stage(self, name: str, host: np.ndarray) -> jax.Array:
         """Device-resident staging: upload ``host`` once and reuse the
@@ -1354,6 +1595,10 @@ class GenerationEngine:
                 mask, "decode_mask",
                 (self.max_batch_slots, self.cfg.vocab_size),
             ),
+            # the slots' convolution state alone (not the blocks'
+            # snapshots, which no decode step touches), and the counters
+            {"conv": self.cache.state["conv"]} if self.state_config is not None else {},
+            self.expert_counts,
         ), context_lens
 
     def decode(
@@ -1388,11 +1633,12 @@ class GenerationEngine:
                 positions, block_tables, active, temps, top_ks, seeds,
                 counts, bias, mask,
             )
-            out, ok, ck, cv = self._decode_jit(self.params, self._dev(masked), *args)
+            out, ok, ck, cv, state, counts = self._decode_jit(self.params, self._dev(masked), *args)
         with phase("engine.decode.block") as block:
-            jax.block_until_ready((out, ok, ck, cv))  # device execution done
+            jax.block_until_ready((out, ok, ck, cv, state))  # device execution done
         with phase("engine.decode.readback") as read:
-            self.cache.update(ck, cv)
+            self.cache.update(ck, cv, **state)
+            self.expert_counts = counts
             self.last_finite = np.asarray(ok)
             result = np.asarray(out)  # result sync lands in the readback span
         elapsed, execute_s = self._record_step_phases("decode", disp, block, read)
@@ -1482,19 +1728,32 @@ class GenerationEngine:
                 counts, bias, mask,
             )
             tok_arg = tokens_dev if tokens_dev is not None else self._dev(masked)
-            prev_k, prev_v = (None, None) if self.donate else (self.cache.k, self.cache.v)
-            out, ok, ck, cv = self._decode_jit(self.params, tok_arg, *args)
+            prev_k, prev_v, prev_conv = (None, None, None) if self.donate else (
+                self.cache.k, self.cache.v, self.cache.state.get("conv")
+            )
+            out, ok, ck, cv, state, counts = self._decode_jit(self.params, tok_arg, *args)
         # start the device->host copies NOW; consume_decode's numpy
         # conversion then finds the bytes already resident
         out.copy_to_host_async()
         ok.copy_to_host_async()
-        self.cache.update(ck, cv)
+        self.cache.update(ck, cv, **state)
+        prev_counts, self.expert_counts = self.expert_counts, counts
         self.phase_time_s["decode"]["dispatch"] += disp.seconds
         return InFlightDecode(
             out, ok, prev_k, prev_v, ck, cv, disp.t0, disp.t1,
             traced=self.trace_counts.get("decode", 0) > traces_before,
             n_active=int(active.sum()), ctx_sum=int(context_lens.sum()),
+            prev_conv=prev_conv, prev_counts=prev_counts,
         )
+
+    def rollback_decode(self, step: InFlightDecode) -> None:
+        """Put the cache back to what it was before ``step`` (a failed
+        or voided step of a non-donating engine): K, V and the slots'
+        convolution state together, or the state would be one token
+        ahead of the K/V it is replayed against."""
+        state = {} if step.prev_conv is None else {"conv": step.prev_conv}
+        self.cache.update(step.prev_k, step.prev_v, **state)
+        self.expert_counts = step.prev_counts
 
     def consume_decode(self, step: InFlightDecode) -> np.ndarray:
         """Block on an in-flight decode step and finish its accounting:
@@ -1517,7 +1776,7 @@ class GenerationEngine:
                     # are still intact. A successor's own discard must
                     # NOT restore forward over this (it checks its
                     # outputs are still current).
-                    self.cache.update(step.prev_k, step.prev_v)
+                    self.rollback_decode(step)
                 raise
         with phase("engine.decode.readback") as read:
             self.last_finite = np.asarray(step.ok)
@@ -1589,6 +1848,7 @@ class GenerationEngine:
         budget). ONE fixed-shape jit: per-request adaptive k only
         changes ``n_draft`` values, never the shape.
         """
+        self._refuse("speculation")
         window = window_tokens.astype(np.int32)
         window, bias = faults.inject(faults.GENERATION_VERIFY, (window, self._zero_bias))
         if self.tp_degree > 1:
@@ -1673,6 +1933,35 @@ class GenerationEngine:
             if not sched.step():
                 break
         return [h.result(timeout=0) for h in handles]
+
+    def expert_stats(self) -> Dict:
+        """The ``experts`` section of ``/v2/stats``: cumulative tokens
+        handed to every expert (per expert layer, and summed over the
+        layers), and the calls that handed them. This is the ONE place
+        the device's counters are read back (a few KB, and a wait for
+        the step in flight): a scrape pays for it, a step never does."""
+        tokens = np.asarray(self.expert_counts["tokens"]).astype(np.int64)
+        calls = np.asarray(self.expert_counts["calls"])
+        return {
+            "layers": [int(l) for l in self.dcfg.expert_layers],
+            "experts": int(self.dcfg.num_experts),
+            "experts_per_token": int(self.dcfg.experts_per_token),
+            "tokens_total": [int(t) for t in tokens.sum(axis=0)],
+            "tokens_total_by_layer": [[int(t) for t in row] for row in tokens],
+            "decode_calls_total": int(calls[0]),
+            "prefill_calls_total": int(calls[1]),
+        }
+
+    def conv_state_stats(self) -> Dict:
+        """The ``conv_state`` section of ``/v2/stats``."""
+        sc = self.state_config
+        return {
+            "layers": sc.num_layers,
+            "bytes_per_sequence": sc.bytes_per_sequence,
+            "bytes": sc.total_bytes(self.cache_config.num_blocks),
+            "restores_total": self.state_restores_total,
+            "snapshots_total": self.state_snapshots_total,
+        }
 
     def recompiles(self) -> Dict[str, int]:
         """Retraces beyond the first compile, per program."""

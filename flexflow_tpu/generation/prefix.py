@@ -78,7 +78,10 @@ class PrefixEntry:
     """One cached block of prefix content: a radix-trie node.
 
     ``block`` is the device block id while resident; ``host_k/host_v``
-    hold the content while offloaded (exactly one tier is populated).
+    hold the content while offloaded (exactly one tier is populated),
+    and ``host_s`` beside them the convolution state stored with the
+    block, for a configuration whose blocks carry one (cache.py): a
+    block is resumed from only together with it, so it moves with it.
     ``refs`` counts live sequences whose block tables include this
     block; the index itself keeps the entry alive at refs == 0 until
     eviction. ``children`` counts child entries (any tier) — an entry
@@ -87,7 +90,7 @@ class PrefixEntry:
 
     __slots__ = (
         "eid", "parent_eid", "tokens", "depth", "block",
-        "host_k", "host_v", "crc", "refs", "children", "last_touch",
+        "host_k", "host_v", "host_s", "crc", "refs", "children", "last_touch",
     )
 
     def __init__(self, eid: int, parent_eid: int, tokens: Tuple[int, ...],
@@ -99,6 +102,7 @@ class PrefixEntry:
         self.block: Optional[int] = block
         self.host_k: Optional[np.ndarray] = None
         self.host_v: Optional[np.ndarray] = None
+        self.host_s: Optional[np.ndarray] = None
         self.crc: Optional[int] = None
         self.refs = 0
         self.children = 0
@@ -109,8 +113,9 @@ class PrefixEntry:
         return self.block is not None
 
 
-def _crc(k: np.ndarray, v: np.ndarray) -> int:
-    return zlib.crc32(v.tobytes(), zlib.crc32(k.tobytes()))
+def _crc(k: np.ndarray, v: np.ndarray, s: Optional[np.ndarray] = None) -> int:
+    crc = zlib.crc32(v.tobytes(), zlib.crc32(k.tobytes()))
+    return crc if s is None else zlib.crc32(s.tobytes(), crc)
 
 
 class PackedBlock:
@@ -195,14 +200,20 @@ class PrefixCache:
         host_budget_bytes: Optional[int] = None,
         host_link_bytes_per_s: float = DEFAULT_HOST_LINK_BYTES_PER_S,
         clock: Callable[[], float] = time.monotonic,
+        state_bytes_per_block: int = 0,
     ):
         self.allocator = allocator
         self.config = config
         self.enabled = enabled
+        # what one block occupies on either tier: its K/V and, for a
+        # configuration whose blocks carry one, the convolution state
+        # stored with it
+        self.bytes_per_block = config.bytes_per_block + state_bytes_per_block
         # default host tier: as large as the device cache — every
         # evicted block has somewhere to go until real pressure
         self.host_budget_bytes = (
-            config.total_bytes if host_budget_bytes is None else host_budget_bytes
+            config.num_blocks * self.bytes_per_block
+            if host_budget_bytes is None else host_budget_bytes
         )
         self.host_link_bytes_per_s = host_link_bytes_per_s
         self.swap_overhead_s = SWAP_OVERHEAD_S
@@ -388,9 +399,10 @@ class PrefixCache:
     # ------------------------------------------------------------- eviction
     def _drop_host(self, entry: PrefixEntry) -> None:
         if entry.host_k is not None:
-            self.host_bytes -= self.config.bytes_per_block
+            self.host_bytes -= self.bytes_per_block
         entry.host_k = None
         entry.host_v = None
+        entry.host_s = None
         entry.crc = None
 
     def _remove(self, entry: PrefixEntry) -> None:
@@ -408,7 +420,7 @@ class PrefixCache:
     def reclaim(
         self,
         n_blocks: int,
-        read_block: Optional[Callable[[int], Tuple[np.ndarray, np.ndarray]]] = None,
+        read_block: Optional[Callable[[int], Tuple[np.ndarray, ...]]] = None,
     ) -> int:
         """Free up to ``n_blocks`` device blocks by evicting refcount-0
         resident entries, LRU by last touch. Each eviction offloads the
@@ -441,16 +453,18 @@ class PrefixCache:
             if (
                 reachable
                 and read_block is not None
-                and self.host_bytes + self.config.bytes_per_block
+                and self.host_bytes + self.bytes_per_block
                 <= self.host_budget_bytes
             ):
                 try:
-                    hk, hv = read_block(victim.block)
+                    # (K, V) or, where blocks carry one, (K, V, state)
+                    hk, hv, *hs = read_block(victim.block)
                     with self._lock:
                         victim.host_k = np.asarray(hk)
                         victim.host_v = np.asarray(hv)
-                        victim.crc = _crc(victim.host_k, victim.host_v)
-                        self.host_bytes += self.config.bytes_per_block
+                        victim.host_s = np.asarray(hs[0]) if hs else None
+                        victim.crc = _crc(victim.host_k, victim.host_v, victim.host_s)
+                        self.host_bytes += self.bytes_per_block
                         self.swaps_out_total += 1
                     offloaded = True
                 except Exception:
@@ -494,25 +508,26 @@ class PrefixCache:
     # --------------------------------------------------------------- tiers
     def take_host_copy(
         self, entry: PrefixEntry
-    ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-        """The entry's host buffers, CRC-verified. None (and the entry
+    ) -> Optional[Tuple[np.ndarray, ...]]:
+        """The entry's host buffers — (K, V) or, where blocks carry one,
+        (K, V, state) — CRC-verified. None (and the entry
         dropped from the trie entirely) when the content is corrupt —
         the caller falls back to recompute. Removal, not just a host
         drop: a tier-less node would still count as ``offloaded`` and
         break the host-bytes conservation invariant on the next scrape
         (a held ref is safe — release() ignores removed entries)."""
         with self._lock:
-            hk, hv, crc = entry.host_k, entry.host_v, entry.crc
+            hk, hv, hs, crc = entry.host_k, entry.host_v, entry.host_s, entry.crc
         if hk is None or hv is None:
             return None
-        if _crc(hk, hv) != crc:
+        if _crc(hk, hv, hs) != crc:
             with self._lock:
                 if self._by_id.get(entry.eid) is entry:
                     self._remove(entry)
                 else:
                     self._drop_host(entry)
             return None
-        return hk, hv
+        return (hk, hv) if hs is None else (hk, hv, hs)
 
     def note_swapped_in(self, entry: PrefixEntry, block: int) -> None:
         """The entry's content was written into device ``block``: it is
@@ -527,7 +542,7 @@ class PrefixCache:
 
     # ------------------------------------------------------ decision model
     def swap_in_cost_s(self, n_blocks: int) -> float:
-        bytes_total = n_blocks * self.config.bytes_per_block
+        bytes_total = n_blocks * self.bytes_per_block
         return self.swap_overhead_s + bytes_total / self.host_link_bytes_per_s
 
     # ------------------------------------------------------------ lifecycle

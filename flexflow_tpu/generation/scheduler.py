@@ -648,6 +648,12 @@ class ContinuousBatchingScheduler:
             # the cache's own spans (ff.cache.offload / .restore) land
             # in this model's windows: timed where the work happens
             engine.prefix_cache.observe = self.stats.observe
+        # what the layers that are not attention-and-MLP count (cumulative,
+        # /v2/stats): absent for a configuration without them
+        if engine.expert_counts:
+            self.stats.add_section("experts", engine.expert_stats)
+        if engine.state_config is not None:
+            self.stats.add_section("conv_state", engine.conv_state_stats)
         self.spec_stats = SpeculationStats()
         self.spec_stats.register_gauges(self.stats)
         # capacity & compute observability (obs/capacity.py, obs/slo.py):
@@ -891,6 +897,9 @@ class ContinuousBatchingScheduler:
             spec = speculation if speculation is not None else self.speculation_default
             drafter = None
             if spec is not None and spec.enabled:
+                # a configuration the engine cannot verify for refuses
+                # here, by name, before anything is queued
+                self.engine._refuse("speculation")
                 # clamp to the engine's compiled verify window so per-
                 # request k NEVER changes the jit shape
                 if spec.k > self.engine.max_spec_tokens:
@@ -1763,7 +1772,7 @@ class ContinuousBatchingScheduler:
                 token = self._device(
                     lambda: self.engine.prefill_one(
                         req.prompt, table, req.sampling, key,
-                        prefix_len=prefix_len, mask=pf_mask,
+                        prefix_len=prefix_len, mask=pf_mask, slot=slot,
                     )
                 )
         except Exception as e:
@@ -2376,7 +2385,7 @@ class ContinuousBatchingScheduler:
             # reaches here on the reset + replay path.)
             h = f.handle
             if h.prev_k is not None and self.engine.cache.k is h.ck:
-                self.engine.cache.update(h.prev_k, h.prev_v)
+                self.engine.rollback_decode(h)
         self.pipe_discards += 1
         self._heartbeat = None
 

@@ -111,13 +111,14 @@ class ServingLayout:
 
         def layer_shardings(layer: Dict[str, Any]) -> Dict[str, Any]:
             out = {k: repl for k in layer}
-            out["wq"] = out["wk"] = out["wv"] = head_in
-            out["wo"] = head_out
+            if "wq" in layer:  # an attention layer (decoder.py: layer_types)
+                out["wq"] = out["wk"] = out["wv"] = head_in
+                out["wo"] = head_out
             # Megatron MLP: column-parallel up, row-parallel down — only
             # when the mesh degree divides the ff width; an odd width
             # degrades to replicated FFN compute instead of failing the
             # build
-            if layer["ff1"].shape[1] % self.tp_degree == 0:
+            if "ff1" in layer and layer["ff1"].shape[1] % self.tp_degree == 0:
                 out["ff1"] = self.sharding(None, MODEL_AXIS)
                 out["ff2"] = self.sharding(MODEL_AXIS, None)
             return out

@@ -333,8 +333,15 @@ class ServingFlops:
 
     @classmethod
     def from_config(cls, cfg, dtype: DataType = DataType.FLOAT, chip=None) -> "ServingFlops":
-        """Build from a TransformerConfig (the engine's ``cfg``)."""
-        return cls(
+        """Build from a TransformerConfig (the engine's ``cfg``). A
+        ``DecoderConfig`` (generation/decoder.py) whose layers differ
+        among themselves has its constants summed layer by layer: a
+        token's matmuls through the operator and the feed-forward each
+        layer really has (of the experts, the ``experts_per_token`` a
+        token is routed to), every weight's bytes (a decode step of a
+        full batch reads every expert), K/V for the attention layers
+        alone."""
+        model = cls(
             num_layers=cfg.num_layers,
             hidden_size=cfg.hidden_size,
             ff_size=cfg.ff_size,
@@ -342,6 +349,32 @@ class ServingFlops:
             dtype=dtype,
             chip=chip,
         )
+        if not hasattr(cfg, "layer_types"):
+            return model
+        e, v = cfg.hidden_size, cfg.vocab_size
+        q, kv = cfg.num_heads * cfg.dim_per_head, cfg.kv_heads * cfg.dim_per_head
+        flops, params, n_attn = 2 * e * v, v * e * (1 if cfg.tied_head else 2), 0
+        for l in range(cfg.num_layers):
+            if cfg.operator(l) == "attention":
+                op = 2 * e * q + 2 * e * kv
+                n_attn += 1
+            else:
+                op = 4 * e * e + cfg.conv_kernel * e
+            kind = cfg.ffn_kind(l)
+            if kind == "experts":
+                per_expert = 3 * e * cfg.moe_ff_size
+                ffn, ffn_params = cfg.experts_per_token * per_expert + e * cfg.num_experts, (
+                    cfg.num_experts * per_expert + e * cfg.num_experts)
+            else:
+                ffn = ffn_params = (3 if kind == "swiglu" else 2) * e * cfg.ff_size
+            flops += 2 * (op + ffn)
+            params += op + ffn_params
+        model.per_token_flops = flops
+        model.per_ctx_flops = n_attn * 4 * q
+        model.param_count = params
+        model.param_bytes = params * model.dtype_bytes
+        model.kv_bytes_per_pos = 2 * n_attn * kv * model.dtype_bytes
+        return model
 
     def prefill_flops(self, prompt_len: int) -> float:
         """One prompt of ``prompt_len`` true tokens (bucket padding is

@@ -241,11 +241,15 @@ def _paged_kernel_tp(kernel, backend, mesh, head_axis, num_heads, head_dim, cach
     if backend != "tpu" or not on_tpu():
         return 0
     tp = 1 if mesh is None else int(dict(mesh.shape).get(head_axis, 1))
-    if num_heads % tp:
-        reason = f"{num_heads} heads do not divide over {tp} shards"
+    # grouped queries: the cache holds the K/V heads, and the kernel
+    # walks each of a group's query heads as one more window query
+    kv_heads = cache.shape[3] * cache.shape[4] // head_dim
+    group = max(1, num_heads // kv_heads)
+    if num_heads % tp or kv_heads % tp:
+        reason = f"{num_heads} heads over {kv_heads} K/V heads do not divide over {tp} shards"
     else:
         reason = paged_kernel_refusal(
-            num_heads // tp, head_dim, cache.shape[2], window, cache.dtype.itemsize
+            kv_heads // tp, head_dim, cache.shape[2], window * group, cache.dtype.itemsize
         )
     if reason is not None:
         _note_refusal(kernel, reason)
@@ -346,6 +350,8 @@ def masked_attention(q, k, v, lengths, causal=True, scale=None):
     real tokens, so prefill logits match the unpadded forward."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if q.shape[2] != k.shape[2]:
+        return _grouped_masked_attention(q, k, v, lengths, causal, scale)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     sq, sk = logits.shape[-2], logits.shape[-1]
     mask = jnp.arange(sk)[None, :] < lengths[:, None]  # [B, Sk]
@@ -360,6 +366,25 @@ def masked_attention(q, k, v, lengths, causal=True, scale=None):
     p = jnp.where(mask, jnp.exp(logits - jnp.maximum(m, -1e30)), 0.0)
     l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     return jnp.einsum("bhqk,bkhd->bqhd", (p / l).astype(v.dtype), v)
+
+
+def _grouped_masked_attention(q, k, v, lengths, causal, scale):
+    """:func:`masked_attention` with grouped queries: q [B, S, H, D] over
+    k/v [B, S, Hkv, D], query head ``i`` reading K/V head ``i // (H //
+    Hkv)``. K and V are never repeated: the group is an axis of q."""
+    b, sq, h, d = q.shape
+    sk, hk = k.shape[1], k.shape[2]
+    qg = q.reshape(b, sq, hk, h // hk, d)
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32) * scale
+    mask = (jnp.arange(sk)[None, :] < lengths[:, None])[:, None, None, None, :]
+    if causal:
+        mask = jnp.logical_and(mask, jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)[None, None, None])
+    logits = jnp.where(mask, logits, -jnp.inf)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(logits - jnp.maximum(m, -1e30)), 0.0)
+    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", (p / l).astype(v.dtype), v, preferred_element_type=jnp.float32)
+    return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
 def reference_attention(q, k, v, causal=False, scale=None):

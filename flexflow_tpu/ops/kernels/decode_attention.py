@@ -39,6 +39,18 @@ sequential decode logits exactly. ``q_position < 0`` marks a padding
 query (fixed-shape windows with fewer real draft tokens): it attends to
 nothing and emits zeros.
 
+**Grouped queries** (ISSUE 27). The cache holds the K/V heads, and a
+query may have ``group`` times as many (query head ``i`` reads K/V head
+``i // group``; :func:`query_group` reads the group off the shapes, 1
+for plain multi-head attention, whose programs are what they were). The
+kernel's body does not know: the window is folded, ``[B, W, Hkv * G, D]
+-> [B, W * G, Hkv, D]``, so that window query ``w * G + g`` carries the
+``g``-th query head of every group and is laid out over the K/V heads
+exactly as a cache position is (:func:`_fold_group`). A block of K/V is
+DMA'd ONCE for all the query heads that read it, each walked as one
+more window query at the same position; LFM2-8B-A1B's 32 query heads
+over 8 K/V heads of 64 are a window of 4 over rows ``[4, 128]``.
+
 Two lowerings:
 
 * :func:`reference_paged_append_attention` — gather the table'd blocks
@@ -80,6 +92,34 @@ def cache_row_shape(num_heads: int, head_dim: int) -> Tuple[int, int]:
     return num_heads, head_dim
 
 
+def query_group(num_heads: int, head_dim: int, row_shape) -> int:
+    """Query heads per stored K/V head: the cache's rows hold ``R * LW /
+    head_dim`` K/V heads, and ``num_heads`` query heads read them in
+    groups (1: plain multi-head attention)."""
+    r, lw = row_shape
+    kv_heads = r * lw // head_dim
+    if kv_heads * head_dim != r * lw or num_heads % kv_heads or (lw != head_dim and lw % head_dim):
+        raise ValueError(
+            f"cache rows {r} x {lw} do not hold {num_heads} heads of {head_dim}, nor the K/V heads of groups of them"
+        )
+    return num_heads // kv_heads
+
+
+def _fold_group(q: jax.Array, group: int) -> jax.Array:
+    """[B, W, Hkv * G, D] -> [B, W * G, Hkv, D]: window query ``w * G +
+    g`` carries, for every K/V head, the ``g``-th query head of its
+    group. Beside the K/V heads the folded window is laid out exactly as
+    a cache position is, which is all the kernel asks of a query."""
+    b, w, h, d = q.shape
+    return q.reshape(b, w, h // group, group, d).transpose(0, 1, 3, 2, 4).reshape(b, w * group, h // group, d)
+
+
+def _unfold_group(out: jax.Array, group: int) -> jax.Array:
+    """The inverse of :func:`_fold_group`, for the attention output."""
+    b, wg, hk, d = out.shape
+    return out.reshape(b, wg // group, group, hk, d).transpose(0, 1, 3, 2, 4).reshape(b, wg // group, hk * group, d)
+
+
 def reference_paged_append_attention(
     q: jax.Array,
     k_cache: jax.Array,
@@ -105,6 +145,15 @@ def reference_paged_append_attention(
         scale = q.shape[-1] ** -0.5
     bs = k_cache.shape[2]
     b, max_blocks = block_tables.shape
+    group = query_group(q.shape[2], q.shape[3], k_cache.shape[3:])
+    if group > 1:
+        # each of a group's query heads as one more window query over
+        # the K/V heads (query head i reads K/V head i // group)
+        out = reference_paged_append_attention(
+            _fold_group(q, group), k_cache, v_cache, layer, block_tables,
+            jnp.repeat(q_positions, group, axis=1), scale,
+        )
+        return _unfold_group(out, group)
     # one gather out of the whole cache: [B, max_blocks, bs, R, LW] -> [B, S_max, H, D]
     k = k_cache[layer, block_tables].reshape(b, max_blocks * bs, *q.shape[2:])
     v = v_cache[layer, block_tables].reshape(b, max_blocks * bs, *q.shape[2:])
@@ -347,10 +396,18 @@ def paged_append_attention(
     otherwise serializes the whole chip on one sequence's history."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    group = query_group(q.shape[2], q.shape[3], k_cache.shape[3:])
+    if group > 1:
+        # grouped queries: a block of K/V is read ONCE for all the query
+        # heads of its groups, each walked as one more window query
+        out = paged_append_attention(
+            _fold_group(q, group), k_cache, v_cache, layer, block_tables,
+            jnp.repeat(q_positions, group, axis=1), scale=scale, interpret=interpret,
+            kv_splits=kv_splits,
+        )
+        return _unfold_group(out, group)
     b, w, h, d = q.shape
     block_size, r, lw = k_cache.shape[2:]
-    if r * lw != h * d or (lw != d and lw % d):
-        raise ValueError(f"cache rows {r} x {lw} do not hold {h} heads of {d}")
     sw = lw if lw != d else 1  # a head's max / denominator: on its lanes, or a column
     out_dtype = q.dtype
     q = q.reshape(b, w, r, lw)  # the window, laid out as the cache lays a position out

@@ -176,7 +176,8 @@ class AdaptiveLimiter:
     called once per scheduler iteration on the injectable clock —
     re-evaluates the pressure signals at ``interval_s`` boundaries:
     an overloaded interval (queue-time/TTFT p95 past target or cache
-    pressure, with the queue at least ``min_queue_frac`` occupied)
+    pressure, with the queue at least ``min_queue_frac`` occupied and
+    deeper than the engine's slots)
     cuts the limit multiplicatively; a healthy interval raises it
     additively toward the ceiling.
     """
@@ -200,6 +201,7 @@ class AdaptiveLimiter:
         self.ttft_p95 = ttft_p95
         self.cache_pressure = cache_pressure
         self.max_queue = max(1, max_queue)
+        self.slots = max(1, slots)
         self.min_limit = float(
             cfg.min_limit if cfg.min_limit is not None else max(1, slots)
         )
@@ -275,10 +277,15 @@ class AdaptiveLimiter:
         """The cut signal: a latency or capacity symptom AND a queue
         actually forming. The occupancy floor keeps a benign burst of
         co-submitted requests (whose queue-time window legitimately
-        grows while they wait for slots) from reading as overload."""
+        grows while they wait for slots) from reading as overload. So
+        does a queue no deeper than the engine's slots: it is one batch
+        waiting its turn, which the next turnover drains (a closed loop
+        of 2 x slots clients holds exactly that, for as long as it
+        runs), whatever share of ``max_queue`` the slots happen to be."""
         cfg = self.cfg
-        qfrac = self.queue_depth() / self.max_queue
-        if qfrac < cfg.min_queue_frac:
+        depth = self.queue_depth()
+        qfrac = depth / self.max_queue
+        if qfrac < cfg.min_queue_frac or depth <= self.slots:
             return False
         if qfrac >= cfg.hard_queue_frac:
             return True

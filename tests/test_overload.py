@@ -235,6 +235,25 @@ def test_limiter_occupancy_floor_blocks_benign_cuts():
     assert lim.snapshot()["cuts_total"] == 0
 
 
+@pytest.mark.parametrize("depth, cuts", [(64, False), (65, True)])
+def test_limiter_floor_scales_with_slots(depth, cuts):
+    """A queue as deep as the slots is one batch waiting its turn (a
+    closed loop of 2 x slots clients), whatever share of ``max_queue``
+    that is: 64 slots against the default 256 sit AT ``min_queue_frac``.
+    One request more is a queue forming."""
+    clock = FakeClock()
+    lim = AdaptiveLimiter(
+        OverloadConfig(limiter_interval_s=1.0), clock=clock, slots=64, max_queue=256,
+        queue_depth=lambda: depth, queue_p95=lambda: 9.9, ttft_p95=lambda: 0.0,
+        cache_pressure=lambda: False,
+    )
+    lim.tick()
+    for _ in range(5):
+        clock.advance(1.0)
+        lim.tick()
+    assert (lim.snapshot()["cuts_total"] > 0) == cuts
+
+
 def test_limiter_priority_headroom():
     """Best-effort hits the limit first; interactive keeps a reserve."""
     clock = FakeClock()
