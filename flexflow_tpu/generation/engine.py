@@ -132,25 +132,52 @@ def topk_scaled_logits(logits, temps, top_ks):
     logits [..., V]; temps/top_ks shaped logits.shape[:-1] (callers
     broadcast). temp <= 0 rows are scaled by 1 (greedy callers argmax
     the RAW logits); top_k <= 0 disables the top-k filter.
+
+    Only a batch in which some row sets ``top_k`` sorts the vocabulary,
+    decided in the program (``lax.cond``: one compiled program, one
+    branch run); the two branches return the same values, bit for bit.
     """
     v = logits.shape[-1]
     safe_t = jnp.where(temps <= 0.0, 1.0, temps)
     scaled = logits / safe_t[..., None]
-    k = jnp.where(top_ks <= 0, v, jnp.clip(top_ks, 1, v)).astype(jnp.int32)
-    sorted_desc = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)
-    thresh = jnp.take_along_axis(sorted_desc, k[..., None] - 1, axis=-1)
-    return jnp.where(scaled >= thresh, scaled, NEG_INF)
+
+    def unfiltered():
+        # k = V keeps everything but NaN (no comparison with NaN holds)
+        return jnp.where(jnp.isnan(scaled), NEG_INF, scaled)
+
+    def by_sort():
+        k = jnp.where(top_ks <= 0, v, jnp.clip(top_ks, 1, v)).astype(jnp.int32)
+        sorted_desc = jnp.flip(jnp.sort(scaled, axis=-1), axis=-1)
+        thresh = jnp.take_along_axis(sorted_desc, k[..., None] - 1, axis=-1)
+        return jnp.where(scaled >= thresh, scaled, NEG_INF)
+
+    return jax.lax.cond(jnp.any(top_ks > 0), by_sort, unfiltered)
 
 
 def _sample(logits, temps, top_ks, keys):
     """Vectorized sampling: greedy where temp<=0, else temperature +
-    optional top-k. logits [B, V]; temps/top_ks [B]; keys [B] PRNG."""
+    optional top-k. logits [B, V]; temps/top_ks [B]; keys [B] PRNG.
+    A batch whose rows are all greedy runs the argmax and nothing else."""
     v = logits.shape[-1]
-    greedy = temps <= 0.0
-    masked = topk_scaled_logits(logits, temps, top_ks)
-    gumbel = jax.vmap(lambda key: jax.random.gumbel(key, (v,)))(keys)
-    sampled = jnp.argmax(masked + gumbel, axis=-1)
-    return jnp.where(greedy, jnp.argmax(logits, axis=-1), sampled).astype(jnp.int32)
+
+    def greedy():
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def mixed():
+        masked = topk_scaled_logits(logits, temps, top_ks)
+        gumbel = jax.vmap(lambda key: jax.random.gumbel(key, (v,)))(keys)
+        sampled = jnp.argmax(masked + gumbel, axis=-1)
+        return jnp.where(temps <= 0.0, jnp.argmax(logits, axis=-1), sampled).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.all(temps <= 0.0), greedy, mixed)
+
+
+def sampling_branch(temps: np.ndarray, top_ks: np.ndarray) -> str:
+    """Which branch of :func:`_sample` a step with these host arrays
+    takes on the device (the predicates above, on the host)."""
+    if np.all(temps <= 0.0):
+        return "greedy"
+    return "sort" if np.any(top_ks > 0) else "plain"
 
 
 def derive_keys(seeds, counts):
@@ -530,6 +557,9 @@ class GenerationEngine:
         # step. Keyed by arg name; each entry is (host snapshot, device
         # array). Loop-thread only (like the cache refs).
         self._staged: Dict[str, Tuple[np.ndarray, jax.Array]] = {}
+        # decode steps by the branch of `_sample` they ran (cumulative,
+        # /v2/stats "sampling")
+        self.sampling_steps: Dict[str, int] = {"greedy": 0, "plain": 0, "sort": 0}
         # sharded jits with EXPLICIT out-shardings (ISSUE 15): cache
         # outputs stay head-sharded across steps (no resharding between
         # chained fixed-shape programs), tokens/ok/emit counts come back
@@ -1580,6 +1610,7 @@ class GenerationEngine:
         # would otherwise write its position-0 K/V into that slot's
         # first real block and silently corrupt the surviving stream
         tables = np.where(active[:, None], block_tables, 0).astype(np.int32)
+        self.sampling_steps[sampling_branch(temps, top_ks)] += 1
         return (
             self._dev(safe_pos),
             self.cache.k,
@@ -1951,6 +1982,12 @@ class GenerationEngine:
             "decode_calls_total": int(calls[0]),
             "prefill_calls_total": int(calls[1]),
         }
+
+    def sampling_stats(self) -> Dict:
+        """The ``sampling`` section of ``/v2/stats``: decode steps by the
+        branch of the sampling transform they ran; the three add up to
+        ``step_counts["decode"]``."""
+        return {f"{branch}_steps_total": n for branch, n in self.sampling_steps.items()}
 
     def conv_state_stats(self) -> Dict:
         """The ``conv_state`` section of ``/v2/stats``."""

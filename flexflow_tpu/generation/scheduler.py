@@ -648,6 +648,7 @@ class ContinuousBatchingScheduler:
             # the cache's own spans (ff.cache.offload / .restore) land
             # in this model's windows: timed where the work happens
             engine.prefix_cache.observe = self.stats.observe
+        self.stats.add_section("sampling", engine.sampling_stats)
         # what the layers that are not attention-and-MLP count (cumulative,
         # /v2/stats): absent for a configuration without them
         if engine.expert_counts:

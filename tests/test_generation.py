@@ -397,8 +397,11 @@ def test_engine_step_programs_on_the_v5e_hold_no_cache_sized_temporary(
     Mosaic kernel is in every layer, both cache arrays are aliased at
     their nominal, unpadded size, no layer-sized copy or slice is left
     and the program's temporaries stay under 0.5 GiB (4.67 GiB before
-    ISSUE 24, beside four relayouts of the cache a step). Nothing runs:
-    shapes in, a compiled program out."""
+    ISSUE 24, beside four relayouts of the cache a step); and the
+    computation every step runs holds no sort of the vocabulary and no
+    reverse: those sit in the branch of a conditional, which only a
+    batch that samples with ``top_k`` enters (ISSUE 28).
+    Nothing runs: shapes in, a compiled program out."""
     import flexflow_tpu.ops.attention as attention
 
     monkeypatch.setattr(attention, "on_tpu", lambda: True)  # the chip's dispatch
@@ -427,6 +430,11 @@ def test_engine_step_programs_on_the_v5e_hold_no_cache_sized_temporary(
     nominal = 2 * 24 * 514 * 16 * 16 * 64 * 4
     assert m.alias_size_in_bytes == nominal, m
     assert m.temp_size_in_bytes < 0.5 * 2**30, m
+    entry = text[text.index("\nENTRY "):]
+    sorts = (" sort(", " reverse(")
+    assert " conditional(" in entry
+    assert [s for s in sorts if s in entry] == []
+    assert [s for s in sorts if s in text] == list(sorts)  # in the branches
 
 
 @pytest.mark.parametrize("kv_shards", [1, 4])
