@@ -18,7 +18,7 @@ from typing import Dict, FrozenSet, Union
 # injectable clock so virtual-clock tests control time.
 CLOCK_WHITELIST: Dict[str, Union[str, FrozenSet[str]]] = {
     # Offline bench/diagnostic harnesses: measuring physical wall time
-    # is their job (genbench/perfwatch/chaoscheck/obsreport/calib_debug),
+    # is their job (chaoscheck/obsreport/calib_debug/loadgen/simfleet),
     # and their watchdog waits bound real blocking calls.
     "tools/": "*",
     # Kernel calibration measures device wall time by definition.

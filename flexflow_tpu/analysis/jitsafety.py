@@ -2,9 +2,9 @@
 single-program contract inside jit-traced function bodies.
 
 The whole serving design (SURVEY.md §2.2) rests on ONE fixed-shape
-decode/verify program and zero steady-state retraces — tools/genbench.py
-measures that invariant, this rule prevents the code shapes that
-violate it from landing at all.
+decode/verify program and zero steady-state retraces — the retrace tests
+and the benchmark's ``correct`` measure that invariant, this rule prevents
+the code shapes that violate it from landing at all.
 
 Which functions are "jitted": a function is in scope when it
 

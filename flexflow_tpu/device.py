@@ -1,7 +1,7 @@
 """Which device this process has, and where its compiled programs are kept.
 
 The one place that answers both. Kernel dispatch asks :func:`on_tpu`;
-anything that reports a device number (``chip_smoke.py``, ``bench.py``,
+anything that reports a device number (``chip_smoke.py``,
 ``benchmark/run.py``) calls :func:`require_tpu` and fails without a
 chip instead of measuring the CPU; every launcher calls
 :func:`enable_compile_cache` before its first jit.

@@ -18,7 +18,7 @@ request always reaches the grammar's accepting state before max_new
 truncates it mid-object.
 
 ``validate_json`` implements the same subset semantics the compiler
-emits, so genbench/chaoscheck can assert "every constrained stream
+emits, so tests and chaoscheck can assert "every constrained stream
 parses AND validates" without a jsonschema dependency.
 """
 from __future__ import annotations

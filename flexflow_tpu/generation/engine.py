@@ -11,10 +11,12 @@ programs instead:
 * **decode** — ONE jitted program, period: always ``max_batch_slots``
   sequences (inactive slots masked to scratch block 0), always the same
   block-table width. Steady-state decode NEVER recompiles, whatever
-  joins or leaves the batch — the property tools/genbench.py asserts.
+  joins or leaves the batch (tests/test_generation.py::
+  test_steady_state_decode_never_recompiles; the benchmark's ``correct``
+  needs zero programs traced inside a window).
 
 ``trace_counts`` counts actual retraces (the Python body only runs at
-trace time), so tests and the bench can assert the compile behavior
+trace time), so tests and the benchmark can assert the compile behavior
 instead of trusting it.
 
 Sampling (greedy / temperature / top-k) runs inside the jitted steps —
@@ -452,10 +454,11 @@ class GenerationEngine:
             else None
         )
         # retrace counters: the Python body runs only when XLA traces, so
-        # these count compiles, not calls (genbench's recompile guard)
+        # these count compiles, not calls (read by benchmark/drivers/serve*.py
+        # for ``correct``, by chip_smoke.py and by the retrace tests)
         self.trace_counts: Dict[str, int] = {}
-        # host-call counters: engine steps actually issued (genbench's
-        # tokens-per-engine-step accounting)
+        # host-call counters: engine steps actually issued (the divisor of
+        # benchmark/layer_metrics/decode_step_ms.py and batch_occupancy.py)
         self.step_counts: Dict[str, int] = {"prefill": 0, "decode": 0, "verify": 0}
         # per-kind step-phase seconds (the device_time_s split, ISSUE
         # 12): dispatch = host arg prep + XLA dispatch (jit call entry

@@ -492,6 +492,13 @@ class _Frontier:
         self.seq0 = seq0
 
 
+# how long the loop thread sleeps when a step found no work (a submit
+# wakes it sooner), and how many finished request traces
+# GET /v2/debug/traces keeps
+IDLE_WAIT_S = 0.002
+TRACE_RING_SIZE = 256
+
+
 class ContinuousBatchingScheduler:
     def __init__(
         self,
@@ -501,14 +508,12 @@ class ContinuousBatchingScheduler:
         clock: Callable[[], float] = time.monotonic,
         breaker: Optional[CircuitBreaker] = None,
         retry: Optional[RetryPolicy] = None,
-        idle_wait_s: float = 0.002,
         speculation: Optional[SpeculationConfig] = None,
         draft_params=None,
         recovery: Optional[RecoveryPolicy] = None,
         watchdog: Optional[WatchdogPolicy] = None,
         observability: bool = True,
         journeys: Optional[bool] = None,
-        trace_ring_size: int = 256,
         flight_capacity: int = 512,
         trace_progress_every: int = 8,
         slo_objectives=None,
@@ -540,7 +545,6 @@ class ContinuousBatchingScheduler:
         self.clock = clock
         self.breaker = breaker or CircuitBreaker(clock=clock)
         self.retry = retry or RetryPolicy()
-        self.idle_wait_s = idle_wait_s
         self._queue: deque = deque()
         self._running: Dict[int, _Running] = {}  # slot -> state
         self._free_slots = list(range(engine.max_batch_slots - 1, -1, -1))
@@ -593,15 +597,16 @@ class ContinuousBatchingScheduler:
         # RequestTrace per submit, finished traces in a bounded ring
         # (GET /v2/debug/traces); one flight record per scheduler step
         # (GET /v2/debug/timeline, quarantine/restart postmortems).
-        # observability=False turns both into no-ops (genbench's
-        # tracing-overhead baseline).
+        # observability=False turns both into no-ops: the reference side
+        # of "tracing never changes a stream" (tests/test_observability.py,
+        # test_steptrace.py, test_journey.py); no cell or launcher passes it.
         self.obs_enabled = observability
         self.trace_progress_every = trace_progress_every
-        self.trace_ring = TraceRing(trace_ring_size)
+        self.trace_ring = TraceRing(TRACE_RING_SIZE)
         # fleet-wide journeys (ISSUE 20): one span ring per replica,
         # stitched across the fleet by JourneyIndex at query time. Rides
         # observability by default; ``journeys=False`` keeps tracing on
-        # with journeys off (genbench's journey-overhead baseline). The
+        # with journeys off (passed by tests/test_journey.py only). The
         # lane label starts as the fault scope (the replica id in fleet
         # mode) and the fleet renames it at spawn.
         self.journey_stats = JourneyStats()
@@ -775,7 +780,7 @@ class ContinuousBatchingScheduler:
         # stays the documented GIL-atomic tuple swap.
         self.overlap = True if overlap is None else bool(overlap)
         self._pipe: Optional[_Frontier] = None
-        # plain counters (read by tests/genbench, not /metrics gauges):
+        # plain counters (read by tests/test_overlap.py, not /metrics gauges):
         # dispatches that went through the pipeline, frontier drains by
         # reason, and in-flight steps discarded (recomputed exactly by
         # the next sequential step)
@@ -1434,7 +1439,7 @@ class ContinuousBatchingScheduler:
     def _loop(self) -> None:
         while (self._alive or (self._draining and self.has_work())) and not self._hard_stop:
             if not self.step():
-                self._wake.wait(timeout=self.idle_wait_s)
+                self._wake.wait(timeout=IDLE_WAIT_S)
                 self._wake.clear()
 
     # ---------------------------------------------------------- internals

@@ -36,8 +36,8 @@ Block tables and the host-side allocator are therefore device-count-
 agnostic: a block id means the same (block, offset) slot on every
 shard, only the head slice living there differs. A 1-device mesh makes
 every spec a no-op — the engine is bit-for-bit the single-device
-engine, which is the exactness anchor the multi-device tests and
-``genbench --mesh`` compare against.
+engine, which is the exactness anchor the multi-device tests
+(tests/test_mesh_generation.py) compare against.
 """
 from __future__ import annotations
 

@@ -35,13 +35,12 @@ this module answers the *resource* dimension with three pieces:
   abstract arguments against the registered signature and produces a
   human-readable *blame* string ("decode retraced: tokens int32[4] ->
   int32[5]") — attached to the flight recorder and served on
-  ``GET /v2/debug/programs``. The genbench retrace guard says *that* a
-  program retraced; the registry says *why*.
+  ``GET /v2/debug/programs``. ``trace_counts`` says *that* a program
+  retraced; the registry says *why*.
 
 Everything here is host-side Python arithmetic: no device calls, no
 extra dispatches, and the per-step cost is a handful of integer adds
-(enforced by genbench's 3% tracing-overhead budget, which runs with
-capacity telemetry enabled).
+(no reader measures it alone: every cell runs with it on).
 """
 from __future__ import annotations
 

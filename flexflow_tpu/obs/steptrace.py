@@ -70,8 +70,8 @@ of TPU behavior, where dispatch returns early and ``execute`` covers
 real device compute. The README "Step anatomy" section documents this.
 
 Cost: observe_step is a handful of dict/float ops per scheduler
-iteration under one lock — covered by genbench's 3% tracing-overhead
-budget, which runs with anatomy enabled. ``enabled=False`` makes every
+iteration under one lock (no reader measures it alone: every cell runs
+with anatomy on). ``enabled=False`` makes every
 method a cheap no-op (mirrors ``observability=False``).
 """
 from __future__ import annotations
@@ -403,9 +403,9 @@ class StepAnatomy:
         fully pipelined loop. ``projected_speedup`` is the go/no-go
         number for ROADMAP item 4 (and its gate once overlap lands);
         ``host_s_per_hot_step`` (hidden host seconds / steps) is the
-        UNCLAMPED trajectory perfwatch gates — the bubble ratio
-        saturates at 1.0 on host-bound CPU hosts, so a ratio gate could
-        never fire there."""
+        UNCLAMPED form of the bubble ratio, which saturates at 1.0 on a
+        host-bound loop; its readers are this report's ``headroom`` block
+        and tests/test_steptrace.py, no benchmark metric."""
         with self._lock:
             n, wall, execute, projected, tokens = self._window_sums_locked()
         if wall <= 0.0 or n == 0:

@@ -44,8 +44,8 @@ ride ``/metrics``; and ``search/calibration.py``'s
 ``op:*`` entries back into fresh calibration-table entries.
 
 Everything is host-side arithmetic under one lock — a ledger observe is
-a dict lookup, a deque append, and a couple of float ops, far inside
-genbench's 3% tracing-overhead budget. The clock is injectable so
+a dict lookup, a deque append, and a couple of float ops (no reader
+measures it alone: every cell runs with it on). The clock is injectable so
 drift tests run entirely on virtual time.
 """
 from __future__ import annotations

@@ -57,8 +57,9 @@ replica signal from sustained limiter state (``GET
 
 Everything runs on injectable clocks so chaos tests drive saturation,
 shedding, and recovery on deterministic virtual time; the machinery is
-inert off the pressure path (``tools/genbench.py`` asserts zero
-limiter/shed/degrade activations on fault-free runs).
+inert off the pressure path (tests/test_overload.py::
+test_overload_machinery_inert_off_pressure_path asserts zero
+limiter/shed/degrade activations on a fault-free run).
 """
 from __future__ import annotations
 
@@ -650,8 +651,9 @@ class OverloadController:
             }
 
     def activations(self) -> Dict[str, int]:
-        """The inertness counters genbench asserts zero on fault-free
-        runs: any nonzero value means the overload machinery acted."""
+        """The inertness counters, zero on a fault-free run (asserted by
+        tests/test_overload.py; reported by tools/loadgen.py and
+        chaoscheck): any nonzero value means the overload machinery acted."""
         lim = self.limiter.counts()
         with self._lock:
             sheds = self.sheds_total

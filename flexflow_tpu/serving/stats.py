@@ -389,7 +389,7 @@ class ConstrainedStats:
 
     Writers: the scheduler loop thread (mask assembly/advance) and
     serving submit threads (the grammar cache); the lock keeps counts
-    exact so chaoscheck/genbench can assert them.
+    exact so chaoscheck and tests/test_constrained.py can assert them.
     """
 
     FIELDS = (

@@ -2,7 +2,8 @@
 
 Reference: measured op costs feeding the search (operator.h:127
 inner_measure_operator_cost; cache simulator.cc:588-628). The numeric
-predicted-vs-measured comparison on real hardware lives in bench.py;
+predicted-vs-measured comparison on real hardware is the benchmark's
+``predicted_over_measured`` (benchmark/layer_metrics/);
 here we validate the machinery on the CPU mesh: measurement produces
 times, calibration round-trips to disk, the cost model consumes it, and
 the simulator's strategy ranking is sane (more devices -> faster step

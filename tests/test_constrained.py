@@ -305,13 +305,20 @@ def test_seeded_temperature_exact_within_config(engine):
 def test_constrained_adds_no_steady_state_programs(engine):
     """After the exactness matrix warmed every path, further
     constrained runs must hit only cached jit traces — the mask is a
-    staged operand on the existing programs, not a new program."""
+    staged operand on the existing programs, not a new program — and
+    under plain load a mask is applied and no stream dead-ends."""
     before = dict(engine.trace_counts)
-    _run(engine, SamplingParams(max_new_tokens=24), overlap=False, spec_k=3)
-    _run(engine, SamplingParams(max_new_tokens=24), overlap=True, spec_k=0)
+    scheds = [
+        _run(engine, SamplingParams(max_new_tokens=24), overlap=False, spec_k=3)[2],
+        _run(engine, SamplingParams(max_new_tokens=24), overlap=True, spec_k=0)[2],
+    ]
     grown = {k: c - before.get(k, 0) for k, c in engine.trace_counts.items()
              if c - before.get(k, 0) > 0}
     assert grown == {}, f"constrained batches retraced: {grown}"
+    # fault-free: every step of the constrained slot was masked, none dead-ended
+    for sched in scheds:
+        cs = sched.constrained_stats
+        assert cs.masked_steps > 0 and cs.dead_end_failures == 0
     assert_blocks_conserved(engine)
 
 

@@ -1,9 +1,11 @@
 """Device selection that does not hide the device (ISSUE 21): the
 compile-cache placement rule, unknown chips as errors, kernels that are
 never interpreted unless a test asks, the dispatch gate's stated
-refusals, and chip_smoke.py failing without a TPU."""
+refusals, chip_smoke.py failing without a TPU, and records that name
+only files the checkout holds."""
 import os
 import pathlib
+import re
 import subprocess
 import sys
 
@@ -58,14 +60,11 @@ def test_require_tpu_names_the_missing_device():
 
 
 def test_unknown_device_kind_has_no_assumed_peak():
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.remove(str(REPO))
-    assert bench.peak_flops_per_device("TPU v5 lite") == 197e12
+    from flexflow_tpu.search.calibration import chip_spec_for
+
+    assert chip_spec_for("TPU v5 lite").bf16_flops == 197e12
     with pytest.raises(ValueError, match="weird future chip"):
-        bench.peak_flops_per_device("weird future chip")
+        chip_spec_for("weird future chip")
 
 
 def test_calibration_tables_come_from_the_checkout_only(monkeypatch, tmp_path):
@@ -191,3 +190,35 @@ def test_chip_smoke_fails_without_a_tpu():
     assert proc.returncode != 0
     assert "no TPU" in proc.stderr and "platform='cpu'" in proc.stderr
     assert '"ok"' not in proc.stdout
+
+
+# a script a record tells its reader to run or read: `python <path>.py`, or
+# a path under a directory of scripts
+_SCRIPT = re.compile(
+    r"python3?\s+(?:-u\s+)?([\w./-]+\.py)"
+    r"|(?<![\w./-])((?:tools|benchmark|examples)/[\w./-]+\.py)"
+)
+
+
+def _tier1_line():
+    return next(
+        l for l in (REPO / "ROADMAP.md").read_text().splitlines() if "Tier-1 verify" in l
+    )
+
+
+@pytest.mark.parametrize(
+    "record",
+    [
+        lambda: (REPO / "README.md").read_text(),
+        lambda: (REPO / ".github" / "workflows" / "tpu-ci.yml").read_text(),
+        lambda: _tier1_line() + (REPO / "PERF.md").read_text(),
+    ],
+    ids=["README.md", "tpu-ci.yml", "ROADMAP-tier1+PERF.md"],
+)
+def test_records_name_scripts_that_exist(record):
+    """What a record says to run resolves in the checkout (PR 29: the
+    README's "Running" was built on three scripts the ledger had replaced)."""
+    text = record()
+    named = {m.group(1) or m.group(2) for m in _SCRIPT.finditer(text)}
+    assert named, "the record names no script: the pattern has rotted"
+    assert sorted(n for n in named if not (REPO / n).exists()) == []

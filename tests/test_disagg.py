@@ -117,6 +117,15 @@ def kv_imports(pool):
 
 PROMPTS = [[1, 2, 3], [4, 5, 6, 7], [9, 8, 7, 6, 5], [1, 2, 3, 4, 4]]
 GREEDY = SamplingParams(max_new_tokens=12)
+# one request per PROMPT: greedy across a block boundary (12 > BLOCK), two
+# seeded temperatures, and a speculative stream
+MIXED_SAMPLING = [
+    GREEDY,
+    SamplingParams(max_new_tokens=10, temperature=0.8, top_k=10, seed=42),
+    SamplingParams(max_new_tokens=10, temperature=0.7, top_k=8, seed=7),
+    SamplingParams(max_new_tokens=10),
+]
+MIXED_SPECS = [None, None, None, SpeculationConfig(k=3, method="ngram")]
 
 
 # ---------------------------------------------------------------------------
@@ -194,14 +203,7 @@ def test_disagg_streams_byte_exact_mixed(decoder_params, sync_fleet):
     stream rode a delivered handoff (no replay fallback), decode-side
     imports account for every stream, admission stays on the prefill
     pool, and both pools return every cache block."""
-    spec = SpeculationConfig(k=3, method="ngram")
-    samp = [
-        GREEDY,
-        SamplingParams(max_new_tokens=10, temperature=0.8, top_k=10, seed=42),
-        SamplingParams(max_new_tokens=10, temperature=0.7, top_k=8, seed=7),
-        SamplingParams(max_new_tokens=10),
-    ]
-    specs = [None, None, None, spec]
+    samp, specs = MIXED_SAMPLING, MIXED_SPECS
     ref = solo_reference(decoder_params, PROMPTS, samp, specs)
 
     dfleet, _clock = sync_fleet
@@ -225,6 +227,27 @@ def test_disagg_streams_byte_exact_mixed(decoder_params, sync_fleet):
     for pool in (dfleet.prefill, dfleet.decode):
         for r in pool._replicas_snapshot():
             assert no_leaked_blocks(r.engine), f"leaked blocks on {r.id}"
+
+
+def test_disagg_steady_state_traces_nothing(sync_fleet):
+    """Once a mix of greedy, seeded and speculative streams has crossed
+    the handoff, the same mix again traces no program on any replica
+    engine of either pool, and none was ever traced twice."""
+    dfleet, _clock = sync_fleet
+    engines = [r.engine for pool in (dfleet.prefill, dfleet.decode)
+               for r in pool._replicas_snapshot()]
+
+    def wave():
+        handles = [dfleet.submit(p, s, speculation=sp)
+                   for p, s, sp in zip(PROMPTS, MIXED_SAMPLING, MIXED_SPECS)]
+        drive(dfleet, handles)
+        return [h.result(timeout=0) for h in handles]
+
+    first = wave()
+    warm = [dict(e.trace_counts) for e in engines]
+    assert wave() == first
+    assert [dict(e.trace_counts) for e in engines] == warm
+    assert [e.recompiles() for e in engines] == [{}] * len(engines)
 
 
 # ---------------------------------------------------------------------------

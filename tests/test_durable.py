@@ -310,6 +310,36 @@ def test_journal_mirrors_admissions_tokens_and_ends(tmp_path, decoder_params):
     assert admits[0]["max_new"] == 12
 
 
+def test_fault_free_journal_is_a_pure_observer(tmp_path, decoder_params):
+    """The same batch on one warmed engine with and without the WAL
+    (real fsyncs): byte-identical streams, nothing degraded off the log,
+    the log written and synced, no program traced for the journaling arm,
+    and nothing self-healed."""
+    samps = [GREEDY, SEEDED, GREEDY]
+    eng = make_engine(decoder_params)
+
+    def run(durable):
+        sched = make_sched(eng)
+        dur = Durability(sched, DurabilityConfig(wal_dir=str(tmp_path))) if durable else None
+        handles = [sched.submit(p, s) for p, s in zip(PROMPTS, samps)]
+        drive(sched, handles)
+        outs = [h.result(0) for h in handles]
+        if dur is not None:
+            dur.close()
+        return outs, sched, dur
+
+    plain, _, _ = run(False)
+    warm = dict(eng.trace_counts)
+    journaled, sched, dur = run(True)
+    assert journaled == plain
+    assert dur.journal.degraded_count() == 0
+    wal = dur.wal.counters()
+    assert wal["appends"] > 0 and wal["fsyncs"] > 0
+    assert dict(eng.trace_counts) == warm
+    rs = sched.recovery_stats
+    assert (eng.resets, rs.recoveries, rs.quarantined, rs.watchdog_trips, rs.step_retries) == (0,) * 5
+
+
 def test_warm_restart_byte_exact_after_abandon(tmp_path, decoder_params):
     """Simulated process death mid-decode (scheduler + Durability
     abandoned, never closed) warm-restarts into byte-identical streams

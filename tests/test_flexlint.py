@@ -56,7 +56,7 @@ class TestClockRule:
 
     def test_whitelist_file(self):
         src = "import time\n\ndef f():\n    return time.perf_counter()\n"
-        assert findings(src, "clock-discipline", relpath="tools/genbench.py") == []
+        assert findings(src, "clock-discipline", relpath="tools/chaoscheck.py") == []
         # the scheduler whitelist covers perf_counter ONLY (PR 6 dual-stamp)
         assert findings(
             src, "clock-discipline",

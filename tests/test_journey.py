@@ -391,11 +391,17 @@ def test_grpc_metadata_traceparent_round_trip(served):
 # ---------------------------------------------------------------------------
 
 
-def test_journeys_off_is_inert_and_byte_exact(decoder_params):
+@pytest.fixture(scope="module")
+def switch_engine(decoder_params):
+    """One engine for both tests of the switches: each drains it fully."""
+    return make_engine(decoder_params)
+
+
+def test_journeys_off_is_inert_and_byte_exact(switch_engine):
     """``observability=False`` (everything off) and ``journeys=False``
     (tracing on, journeys off) both produce byte-identical streams to
     the engine's own reference, with NULL contexts end to end."""
-    eng = make_engine(decoder_params)
+    eng = switch_engine
     ref = [eng.generate([list(p)], GREEDY)[0] for p in PROMPTS]
 
     for kwargs, trace_expected in (
@@ -418,6 +424,31 @@ def test_journeys_off_is_inert_and_byte_exact(decoder_params):
     # full drain: every block is back, or warm in the prefix index
     from conftest import assert_blocks_conserved
     assert_blocks_conserved(eng)
+
+
+def test_journeys_on_is_byte_exact_and_every_journey_stitches(switch_engine):
+    """The on side of the switch, on one scheduler with no fleet around
+    it: a batch that joins and leaves mid-flight streams what the
+    journeys-off scheduler streams, and every request's journey stitches
+    gap-free with every hop it took."""
+    eng = switch_engine
+
+    def run(**kwargs):
+        sched = ContinuousBatchingScheduler(
+            eng, recovery=NO_SLEEP, clock=FakeClock(), **kwargs)
+        handles = [sched.submit(p, GREEDY) for p in PROMPTS]
+        drive(sched.step, handles)
+        return [h.result(0) for h in handles], handles, sched
+
+    off, _, _ = run(journeys=False)
+    on, handles, sched = run()
+    assert on == off
+    assert sched.journey_stats.spans > 0
+    index = JourneyIndex().add(sched.journeys)
+    for h in handles:
+        journey = index.get(h._request.journey.journey_id)
+        assert_gap_free(journey)
+        assert journey["n_spans"] == h._request.journey.hops
 
 
 # ---------------------------------------------------------------------------

@@ -257,6 +257,8 @@ for name, samp in modes.items():
     res[f"sampling:{name}"] = e1.generate(prompts, samp) == e4.generate(prompts, samp)
 res["cache_sharded"] = "model" in str(e4.cache.k.sharding.spec)
 res["zero_retraces_tp4"] = e4.recompiles() == {}
+res["strategy_follows_mesh"] = e4.serving_strategy_block().get("tp_degree") == 4
+res["chip_spec_follows_mesh"] = e4.flops_model.chip.name.endswith(" x4")
 
 # speculative
 motif = [5, 9, 2]

@@ -222,7 +222,8 @@ def test_limiter_aimd_convergence():
 
 def test_limiter_occupancy_floor_blocks_benign_cuts():
     """Latency symptoms with an (almost) empty queue never cut — the
-    inertness property genbench gates on."""
+    limiter's half of the inertness property
+    (test_overload_machinery_inert_off_pressure_path holds the whole)."""
     clock = FakeClock()
     lim = _limiter(
         clock, queue_depth=lambda: 1, queue_p95=lambda: 99.0,
@@ -460,8 +461,10 @@ def test_serving_admission_fault_site(engine):
 
 def test_overload_machinery_inert_off_pressure_path(engine):
     """A fault-free, unpressured run activates nothing: no throttles,
-    cuts, sheds, infeasible denials, or ladder transitions."""
+    cuts, sheds, infeasible denials, or ladder transitions, and none of
+    the self-healing (engine restart, quarantine, watchdog, step retry)."""
     sched, clock = make_sched(engine)
+    resets = engine.resets
     sampling = SamplingParams(max_new_tokens=4)
     handles = [sched.submit([i + 1, i + 2, i + 3], sampling)
                for i in range(6)]
@@ -475,6 +478,9 @@ def test_overload_machinery_inert_off_pressure_path(engine):
         "throttled": 0, "limit_cuts": 0, "sheds": 0, "infeasible": 0,
         "rejected": 0, "degrade_transitions": 0, "degrade_level": 0,
     }
+    rs = sched.recovery_stats
+    assert (engine.resets - resets, rs.recoveries, rs.quarantined,
+            rs.watchdog_trips, rs.step_retries) == (0,) * 5
 
 
 # ---------------------------------------------------------------------------

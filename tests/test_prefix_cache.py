@@ -115,6 +115,7 @@ def test_exactness_matrix_on_off(decoder_params, mode):
     assert on.trace_counts["decode"] == 1
     if mode == "speculative":
         assert on.trace_counts["verify"] == 1
+    assert on.recompiles() == {}
 
 
 def test_cow_keeps_shared_block_immutable(decoder_params):

@@ -94,7 +94,7 @@ def test_bubble_ratio_and_headroom_math_exact():
     assert hr["projected_tokens_per_s"] == pytest.approx(2 / 2.0)
     assert hr["projected_speedup"] == pytest.approx(2.0)
     assert hr["hidden_host_s"] == pytest.approx(2.0)
-    # the perfwatch-gated trajectory: unclamped hidden host s / step
+    # the bubble ratio without its clamp: hidden host s / step
     assert hr["host_s_per_hot_step"] == pytest.approx(1.0)
 
 
@@ -298,6 +298,8 @@ def test_anatomy_disabled_is_inert_and_exact(engine):
     assert gv["step_device_bubble_ratio"] is None
     assert gv["step_anatomy_steps_observed"] is None
     assert on.anatomy.steps_observed() > 0
+    # a real run's report is not empty: the bubble ratio is a share
+    assert 0.0 <= on.anatomy.device_bubble_ratio() <= 1.0
 
 
 # ------------------------------------------------------------ exposition

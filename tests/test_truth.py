@@ -10,14 +10,7 @@ Covers:
     calls excluded, and drift alarms landing on the flight ring
   * cost-model predictions tagged onto CostMetrics and the
     recalibration suggestion hook back into search/calibration.py
-  * tools/perfwatch.py — pass on back-to-back identical benches, fail
-    on a synthetic 20% tokens/s regression
 """
-import json
-import subprocess
-import sys
-from pathlib import Path
-
 import jax
 import pytest
 
@@ -32,8 +25,6 @@ from flexflow_tpu.models.transformer import TransformerConfig
 from flexflow_tpu.obs.truth import PredictionLedger
 
 pytestmark = pytest.mark.truth
-
-REPO = Path(__file__).resolve().parent.parent
 
 from conftest import FakeClock  # noqa: E402
 
@@ -270,62 +261,3 @@ def test_cost_metrics_tagged_and_recalibration_applies():
     applied = apply_recalibration(cal, ledger=led)
     assert cal.entries[key] == 4.0e-4
     assert applied == sugg
-
-
-# -------------------------------------------------------------- perfwatch
-def _history_line(tok_s: float, ts: str = "2026-01-01T00:00:00") -> str:
-    return json.dumps({
-        "ts": ts, "git_sha": "abc1234", "backend": "cpu", "mode": "baseline",
-        "metrics": {"decode_tokens_per_s": tok_s, "prefill_tokens_per_s": 500.0,
-                    "ttft_p50_s": 0.01},
-    })
-
-
-def _run_perfwatch(history: Path):
-    return subprocess.run(
-        [sys.executable, str(REPO / "tools" / "perfwatch.py"),
-         "--history", str(history)],
-        capture_output=True, text=True, cwd=str(REPO), timeout=120,
-    )
-
-
-def test_perfwatch_passes_on_identical_benches(tmp_path):
-    h = tmp_path / "BENCH_HISTORY.jsonl"
-    h.write_text("\n".join([_history_line(100.0)] * 5) + "\n")
-    r = _run_perfwatch(h)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "OK" in r.stdout
-
-
-def test_perfwatch_fails_on_20pct_regression(tmp_path):
-    h = tmp_path / "BENCH_HISTORY.jsonl"
-    lines = [_history_line(100.0)] * 5 + [_history_line(80.0)]
-    h.write_text("\n".join(lines) + "\n")
-    r = _run_perfwatch(h)
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert "decode_tokens_per_s" in r.stdout and "REGRESSED" in r.stdout
-
-
-def test_perfwatch_tolerates_noise_within_floor(tmp_path):
-    h = tmp_path / "BENCH_HISTORY.jsonl"
-    lines = [_history_line(v) for v in (100.0, 104.0, 97.0, 101.0, 99.0, 95.0)]
-    h.write_text("\n".join(lines) + "\n")
-    r = _run_perfwatch(h)
-    assert r.returncode == 0, r.stdout + r.stderr
-
-
-def test_perfwatch_skips_without_history(tmp_path):
-    h = tmp_path / "BENCH_HISTORY.jsonl"
-    h.write_text(_history_line(100.0) + "\n")  # one run: nothing to gate
-    r = _run_perfwatch(h)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "skipping" in r.stdout or "insufficient" in r.stdout
-
-
-def test_perfwatch_ignores_malformed_lines(tmp_path):
-    h = tmp_path / "BENCH_HISTORY.jsonl"
-    lines = [_history_line(100.0), "{not json", _history_line(100.0),
-             _history_line(100.0)]
-    h.write_text("\n".join(lines) + "\n")
-    r = _run_perfwatch(h)
-    assert r.returncode == 0, r.stdout + r.stderr

@@ -1,8 +1,8 @@
-"""Shared ``--mesh N`` bootstrap for the bench/chaos CLIs (genbench,
-chaoscheck): forcing N host devices must happen BEFORE jax initializes
-its backend — ``--xla_force_host_platform_device_count`` in XLA_FLAGS
-cannot take effect after import — so the tools re-exec themselves once
-with the flag set. One copy here; both CLIs call it first thing."""
+"""The ``--mesh N`` bootstrap of ``chaoscheck``: forcing N host devices
+must happen BEFORE jax initializes its backend —
+``--xla_force_host_platform_device_count`` in XLA_FLAGS cannot take
+effect after import — so the tool re-execs itself once with the flag
+set, first thing."""
 import os
 import sys
 

@@ -11,7 +11,7 @@ a prompt, and a deadline; the report breaks goodput, shed rate, and
 TTFT out per class, which is how the overload-storm smoke proves
 "best-effort absorbed the burst, interactive never shed".
 
-Three drive modes:
+Two drive modes:
 
 * **in-process** (default): builds a tiny CPU engine + continuous-
   batching scheduler and drives the schedule deterministically on a
@@ -22,23 +22,10 @@ Three drive modes:
   running server (serving/server.py): one thread per arrival fires a
   ``POST /v2/models/{name}/generate`` at its scheduled wall time;
   503 + Retry-After answers count as sheds, per priority.
-* **--disagg-ab** (ISSUE 16): the disaggregated-serving A/B — the SAME
-  seeded open-loop schedule of mixed long/short prompts through a
-  2-replica unified fleet and a 1 prefill + 1 decode disaggregated
-  fleet (equal engine budget), interleaved best-of-N. Per arm: TTFT
-  p95 (long prefills queue behind decode iterations on a unified
-  replica; a dedicated prefill replica admits back-to-back) and
-  decode TPOT p50 (a dedicated decode replica's fixed-shape step loop
-  is never interrupted by a prefill). Gates: byte-identical streams
-  across arms, zero steady-state retraces on every replica engine
-  (ProgramRegistry-backed trace_counts), and both improvement ratios
-  over their floors; appends a perfwatch-gated line to
-  BENCH_HISTORY.jsonl.
 
 Usage:
   python tools/loadgen.py --rate 50 --duration 2 --mix 0.2,0.2,0.6
   python tools/loadgen.py --url http://127.0.0.1:8000 --model lm ...
-  python tools/loadgen.py --disagg-ab --out disagg_bench.json
 """
 from __future__ import annotations
 
@@ -436,242 +423,6 @@ def run_http(args) -> Dict:
     return out
 
 
-# ------------------------------------------------------------ disagg A/B
-def _pct(xs: Sequence[float], p: float) -> Optional[float]:
-    if not xs:
-        return None
-    xs = sorted(xs)
-    return xs[min(len(xs) - 1, math.ceil(p * len(xs)) - 1)]
-
-
-def _git_sha() -> str:
-    try:
-        import subprocess
-
-        out = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"],
-            capture_output=True, text=True, timeout=10,
-        ).stdout.strip()
-        return out or "unknown"
-    except Exception:
-        return "unknown"
-
-
-def _append_ab_history(path: str, report: Dict) -> None:
-    """One perfwatch-schema line (same shape as genbench's
-    append_history): timestamped, git-sha-stamped, ok-flagged so a run
-    that failed its own gate never enters the rolling baseline."""
-    if not path:
-        return
-    import jax
-
-    entry = {
-        "ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
-        "git_sha": _git_sha(),
-        "backend": jax.default_backend(),
-        "mode": "disagg_ab",
-        "ok": bool(report.get("ok")),
-        "metrics": {
-            "disagg_ttft_p95_ratio": report.get("ttft_p95_ratio"),
-            "disagg_tpot_p50_ratio": report.get("tpot_p50_ratio"),
-            "disagg_ttft_p95_s": (report.get("disagg") or {}).get("ttft_p95_s"),
-        },
-    }
-    try:
-        with open(path, "a") as f:
-            f.write(json.dumps(entry) + "\n")
-    except OSError as e:
-        print(f"WARNING: could not append bench history to {path}: {e}",
-              file=sys.stderr)
-
-
-def run_disagg_ab(args) -> Dict:
-    """Unified vs disaggregated A/B on live fleets (real clock, real
-    threads — the contention being measured IS wall time: prefills
-    interleaving into a unified replica's decode loop). Both arms get
-    the same engine budget (two engines), the same seeded schedule,
-    and fully pre-warmed replicas, so the measured phase is steady
-    state and the only difference is pool specialization."""
-    import os
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    from flexflow_tpu.generation import (
-        GenerationEngine,
-        SamplingParams,
-        init_decoder_params,
-    )
-    from flexflow_tpu.models.transformer import TransformerConfig
-    from flexflow_tpu.serving.fleet import DisaggregatedFleet, Fleet
-
-    buckets = (8, 128)
-    cfg = TransformerConfig(
-        num_layers=1, hidden_size=64, num_heads=4, ff_size=128,
-        seq_length=160, vocab_size=args.vocab, causal=True,
-    )
-    params = init_decoder_params(jax.random.key(0), cfg)
-
-    def make_engine():
-        # prefix_cache off (genbench's bench idiom): radix reuse would
-        # vary prefill suffix shapes and reclaim through the host tier
-        # mid-run, which is retrace noise, not the A/B's contention
-        return GenerationEngine(
-            params, cfg, max_batch_slots=args.slots, block_size=8,
-            prompt_buckets=buckets, prefix_cache=False,
-        )
-
-    # mixed long/short prompts (3..120 tokens spans both buckets), all
-    # standard priority, no deadlines: every arrival must COMPLETE in
-    # both arms or the byte-exactness comparison is meaningless
-    schedule = build_schedule(
-        args.rate, args.duration, mix=(0.0, 1.0, 0.0), seed=args.seed,
-        vocab=args.vocab, prompt_len_lo=3, prompt_len_hi=120,
-        deadlines_s=(None,), max_new=args.max_new,
-    )
-    sk = dict(max_queue=max(256, args.max_queue))
-
-    def run_arm(gen):
-        """Drive the schedule open-loop; returns (results, retraces)
-        with results = [(arrival, tokens|None, ttft_s, total_s)]."""
-        reps = list(gen.replicas)
-        # steady state: compile every prompt bucket + the decode
-        # program on every replica engine BEFORE the measured phase
-        for r in reps:
-            for b in buckets:
-                n = min(b, cfg.seq_length - args.max_new - 2)
-                r.engine.generate([[1] * n], SamplingParams(max_new_tokens=2))
-        warm = [dict(r.engine.trace_counts) for r in reps]
-        gen.start()
-        results, lock, threads = [], threading.Lock(), []
-
-        def waiter(a, h, t_sub):
-            try:
-                tokens = h.result(timeout=120.0)
-            except Exception:
-                with lock:
-                    results.append((a, None, None, None))
-                return
-            total_s = time.monotonic() - t_sub
-            tr = h.trace_dict()
-            with lock:
-                results.append((a, tokens, tr.get("ttft_s"), total_s))
-
-        t0 = time.monotonic()
-        for a in schedule:
-            delay = a.t - (time.monotonic() - t0)
-            if delay > 0:
-                time.sleep(delay)
-            t_sub = time.monotonic()
-            h = gen.submit(
-                a.prompt, SamplingParams(max_new_tokens=a.max_new),
-                priority=a.priority,
-            )
-            th = threading.Thread(target=waiter, args=(a, h, t_sub), daemon=True)
-            th.start()
-            threads.append(th)
-        for th in threads:
-            th.join(timeout=120)
-        retraces = {}
-        for w, r in zip(warm, reps):
-            for k, v in r.engine.trace_counts.items():
-                d = v - w.get(k, 0)
-                if d > 0:
-                    retraces[k] = retraces.get(k, 0) + d
-        gen.stop()
-        return results, retraces
-
-    def metrics(results):
-        comp = [x for x in results if x[1] is not None]
-        ttfts = [t for (_, _, t, _) in comp if t is not None]
-        tpots = [
-            (tot - ttft) / max(1, len(toks) - 1)
-            for (_, toks, ttft, tot) in comp
-            if ttft is not None and len(toks) > 1
-        ]
-        return {
-            "completed": len(comp),
-            "ttft_p95_s": _pct(ttfts, 0.95),
-            "tpot_p50_s": _pct(tpots, 0.50),
-        }
-
-    def build(name):
-        # equal engine budget per arm: n prefill + n decode specialized
-        # replicas vs 2n unified ones
-        if name == "unified":
-            return Fleet(
-                make_engine, n=2 * args.ab_replicas, name=args.model,
-                scheduler_kwargs=sk,
-            )
-        return DisaggregatedFleet(
-            make_engine, n_prefill=args.ab_replicas,
-            n_decode=args.ab_replicas, name=args.model,
-            scheduler_kwargs=sk,
-        )
-
-    per_rep = {"unified": [], "disagg": []}
-    streams: Dict[str, List] = {}
-    retrace_totals = {"unified": 0, "disagg": 0}
-    problems: List[str] = []
-    for rep in range(args.ab_repeats):
-        for name in ("unified", "disagg"):  # interleaved: shared noise
-            results, retraces = run_arm(build(name))
-            m = metrics(results)
-            per_rep[name].append(m)
-            retrace_totals[name] += sum(retraces.values())
-            if retraces:
-                problems.append(f"{name} rep {rep}: steady-state retraces {retraces}")
-            if m["completed"] != len(schedule):
-                problems.append(
-                    f"{name} rep {rep}: {m['completed']}/{len(schedule)} completed"
-                )
-            if rep == 0:
-                streams[name] = sorted(
-                    (tuple(a.prompt), tuple(toks))
-                    for (a, toks, _, _) in results if toks is not None
-                )
-
-    exact = streams.get("unified") == streams.get("disagg")
-    if not exact:
-        problems.append("streams diverged between the unified and disagg arms")
-    best = {
-        name: {
-            "ttft_p95_s": min(m["ttft_p95_s"] for m in per_rep[name]),
-            "tpot_p50_s": min(m["tpot_p50_s"] for m in per_rep[name]),
-            "per_rep": per_rep[name],
-        }
-        for name in ("unified", "disagg")
-    }
-    ttft_ratio = best["unified"]["ttft_p95_s"] / max(1e-9, best["disagg"]["ttft_p95_s"])
-    tpot_ratio = best["unified"]["tpot_p50_s"] / max(1e-9, best["disagg"]["tpot_p50_s"])
-    if ttft_ratio < args.min_ttft_improvement:
-        problems.append(
-            f"TTFT p95 ratio {ttft_ratio:.3f} below floor {args.min_ttft_improvement}"
-        )
-    if tpot_ratio < args.min_tpot_improvement:
-        problems.append(
-            f"decode TPOT ratio {tpot_ratio:.3f} below floor {args.min_tpot_improvement}"
-        )
-    report = {
-        "mode": "disagg_ab",
-        "schedule": {
-            "arrivals": len(schedule), "rate_rps": args.rate,
-            "duration_s": args.duration, "seed": args.seed,
-            "max_new": args.max_new,
-        },
-        "unified": best["unified"],
-        "disagg": best["disagg"],
-        "ttft_p95_ratio": ttft_ratio,
-        "tpot_p50_ratio": tpot_ratio,
-        "exact": exact,
-        "steady_state_retraces": retrace_totals,
-        "problems": problems,
-        "ok": not problems,
-    }
-    _append_ab_history(args.history_out, report)
-    return report
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(
         description=__doc__,
@@ -696,15 +447,11 @@ def main() -> int:
     ap.add_argument("--record-only", action="store_true",
                     help="with --record-schedule: write the schedule and "
                     "exit without driving it")
-    ap.add_argument("--max-new", type=int, default=None,
-                    help="tokens per request (default 8; 32 in --disagg-ab, "
-                    "long enough to amortize the handoff over the stream "
-                    "and keep the decode batch resident)")
+    ap.add_argument("--max-new", type=int, default=8,
+                    help="tokens per request")
     ap.add_argument("--vocab", type=int, default=40)
-    ap.add_argument("--slots", type=int, default=None,
-                    help="in-process engine batch slots (default 4; 32 in "
-                    "--disagg-ab — the padded decode step IS the unified "
-                    "arm's admission interference)")
+    ap.add_argument("--slots", type=int, default=4,
+                    help="in-process engine batch slots")
     ap.add_argument("--max-queue", type=int, default=32,
                     help="in-process scheduler queue bound")
     ap.add_argument("--dt", type=float, default=0.01,
@@ -713,26 +460,8 @@ def main() -> int:
                     help="drive a live server instead of in-process")
     ap.add_argument("--model", default="lm", help="model name (--url mode)")
     ap.add_argument("--out", default="", help="write the JSON report here")
-    ap.add_argument("--disagg-ab", action="store_true",
-                    help="unified vs disaggregated fleet A/B (ISSUE 16)")
-    ap.add_argument("--ab-repeats", type=int, default=3,
-                    help="interleaved repeats per arm (best-of)")
-    ap.add_argument("--ab-replicas", type=int, default=1,
-                    help="disagg-ab pool width: n prefill + n decode vs "
-                    "2n unified replicas (keep small on CPU hosts — "
-                    "every replica is a thread)")
-    ap.add_argument("--min-ttft-improvement", type=float, default=1.0,
-                    help="disagg-ab gate: unified/disagg TTFT p95 ratio floor")
-    ap.add_argument("--min-tpot-improvement", type=float, default=1.0,
-                    help="disagg-ab gate: unified/disagg decode TPOT ratio floor")
-    ap.add_argument("--history-out", default="BENCH_HISTORY.jsonl",
-                    help="disagg-ab: append a perfwatch line here ('' disables)")
     args = ap.parse_args()
 
-    if args.max_new is None:
-        args.max_new = 32 if args.disagg_ab else 8
-    if args.slots is None:
-        args.slots = 32 if args.disagg_ab else 4
     args.mix_t = tuple(float(x) for x in args.mix.split(","))
     args.deadlines_t = tuple(
         None if x.strip().lower() == "none" else float(x)
@@ -748,9 +477,7 @@ def main() -> int:
         from flexflow_tpu.device import enable_compile_cache
 
         enable_compile_cache()
-    if args.disagg_ab:
-        report = run_disagg_ab(args)
-    elif args.url:
+    if args.url:
         report = run_http(args)
     else:
         report = run_inprocess(args)
@@ -759,10 +486,6 @@ def main() -> int:
     if args.out:
         with open(args.out, "w") as f:
             f.write(text + "\n")
-    if args.disagg_ab and not report["ok"]:
-        for p in report["problems"]:
-            print(f"FAIL: {p}", file=sys.stderr)
-        return 1
     return 0
 
 
