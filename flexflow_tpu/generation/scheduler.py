@@ -70,7 +70,15 @@ so chaos tests run on virtual time. Fault sites: ``generation.prefill``,
   semantics — token streams are byte-identical with overlap on/off
   (tests/test_overlap.py). The speculative verify path stays
   sequential by design: drafting needs step N's committed tokens on
-  the host, so there is no overlap window.
+  the host, so there is no overlap window. An EMPTY FREE LIST is not
+  pressure (ISSUE 30): with the prefix cache on the pool is full by
+  design, so the pipeline's block growth takes its block from the
+  cache's unreferenced entries (``engine.reclaim_cached``, as ``_grow``
+  does) with the step in flight — no running stream's table names a
+  victim. What drains for ``pressure`` is a pool with nothing left to
+  evict: the next resorts (capping speculation, preempt-by-recompute)
+  mutate running slots and stay sequential. ``/v2/stats`` section
+  ``pipeline`` counts the loop's decisions.
 
 The scheduler is synchronous-by-design: ``step()`` does one iteration
 and returns, so property tests drive it deterministically; ``start()``
@@ -497,6 +505,8 @@ class _Frontier:
 # GET /v2/debug/traces keeps
 IDLE_WAIT_S = 0.002
 TRACE_RING_SIZE = 256
+# why _drain_frontier emptied the overlap pipeline (its callers' reasons)
+_DRAIN_REASONS = ("nonsteady", "finish", "pressure", "idle")
 
 
 class ContinuousBatchingScheduler:
@@ -780,13 +790,17 @@ class ContinuousBatchingScheduler:
         # stays the documented GIL-atomic tuple swap.
         self.overlap = True if overlap is None else bool(overlap)
         self._pipe: Optional[_Frontier] = None
-        # plain counters (read by tests/test_overlap.py, not /metrics gauges):
-        # dispatches that went through the pipeline, frontier drains by
-        # reason, and in-flight steps discarded (recomputed exactly by
-        # the next sequential step)
+        # plain counters (the `pipeline` section of /v2/stats, read by
+        # benchmark/layer_metrics/pipelined_step_share.py and by
+        # tests/test_overlap.py): dispatches that went through the
+        # pipeline, blocks its growth took from the prefix cache,
+        # frontier drains by reason, and in-flight steps discarded
+        # (recomputed exactly by the next sequential step; tests only)
         self.pipe_dispatches = 0
-        self.pipe_drains: Dict[str, int] = {}
+        self.pipe_reclaims = 0
+        self.pipe_drains: Dict[str, int] = dict.fromkeys(_DRAIN_REASONS, 0)
         self.pipe_discards = 0
+        self.stats.add_section("pipeline", self.pipeline_stats)
         # self-healing (recovery.py): journal + supervisor + watchdog.
         # _heartbeat is (seq, started_at) while a device call is in
         # flight — the watchdog's stall signal
@@ -2405,7 +2419,7 @@ class ContinuousBatchingScheduler:
         f, self._pipe = self._pipe, None
         if f is None:
             return
-        self.pipe_drains[reason] = self.pipe_drains.get(reason, 0) + 1
+        self.pipe_drains[reason] += 1
         try:
             self._consume_and_finish(f)
         except Exception as e:
@@ -2578,6 +2592,45 @@ class ContinuousBatchingScheduler:
         self._step_info["handled_failure"] = False
         self._step_info["emitted"] = n_live
 
+    def _pipeline_reclaim(self, f: Optional["_Frontier"]) -> int:
+        """Evict one unreferenced cached prefix for the pipeline's block
+        growth; returns the blocks freed (0: nothing evictable is left).
+        Sound with ``f`` in flight: a victim has ``refs == 0``, so no
+        running stream's table names it and the in-flight step neither
+        reads nor writes it; the step that will write it is dispatched
+        after, on ``f``'s cache outputs, so the device orders the two;
+        and a swap-out read (host tier below its budget) is enqueued on
+        those same outputs, behind ``f``. That read is the one part that
+        can wedge, and it wedges behind ``f``: ``f``'s own heartbeat
+        stamp stands in for it (``_stamped`` would clear that stamp on
+        exit and shift the sequence ``f``'s stall flags are scoped by).
+        With no step in flight the reclaim takes a stamp of its own,
+        as in ``_grow``."""
+        if f is not None:
+            freed = self.engine.reclaim_cached(1)
+        else:
+            with self._stamped():
+                freed = self.engine.reclaim_cached(1)
+        self.pipe_reclaims += freed
+        return freed
+
+    def pipeline_stats(self) -> Dict:
+        """The ``pipeline`` section of ``/v2/stats``: monotone totals of
+        the overlap loop's decisions. ``decode_steps_total`` is every
+        decode step dispatched (``engine.step_counts``), of which
+        ``pipelined_steps_total`` went through ``_dispatch_pipeline``;
+        ``reclaims_total`` the blocks the pipeline's growth took from
+        the prefix cache; ``drains_total`` the frontier drains by reason
+        (``_drain_frontier``'s callers). Read by a scrape's thread:
+        ``pipe_drains`` has its keys from the start, so the copy never
+        meets a dict that changes size."""
+        return {
+            "decode_steps_total": self.engine.step_counts["decode"],
+            "pipelined_steps_total": self.pipe_dispatches,
+            "reclaims_total": self.pipe_reclaims,
+            "drains_total": dict(self.pipe_drains),
+        }
+
     def _try_pipeline(self) -> Optional[bool]:
         """One overlapped-decode iteration. Returns None when the
         iteration must run sequentially instead (the frontier is
@@ -2613,15 +2666,21 @@ class ContinuousBatchingScheduler:
                 if s.req.n_generated + pend >= s.req.max_new:
                     continue
                 live.append(s)
-            # grow block tables for the dispatch positions (plain
-            # allocation only: reclaim/preempt pressure is handled
-            # sequentially)
+            # grow block tables for the dispatch positions. An empty
+            # free list is the prefix cache's steady state, not
+            # pressure: take the block from the cache's unreferenced
+            # entries, as _grow does, with the step in flight. Only a
+            # pool with nothing left to evict is short: capping
+            # speculation and preempting mutate running slots, so that
+            # pressure drains and is handled sequentially
             short = False
             for s in live:
                 pend = 1 if s.slot in covered else 0
                 need = self.engine.cache_config.blocks_for(s.cached_len + pend + 1)
                 while len(s.blocks) < need:
                     got = self.engine.allocator.allocate(1)
+                    if got is None and self._pipeline_reclaim(f):
+                        got = self.engine.allocator.allocate(1)
                     if got is None:
                         short = True
                         break

@@ -6,6 +6,7 @@ likewise fakes multi-node with multi-process on one box,
 tests/multinode_helpers/mpi_wrapper1.sh — here XLA gives us real SPMD
 partitioning without processes).
 """
+import functools
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -41,3 +42,32 @@ class FakeClock:
 
     def advance(self, dt: float) -> None:
         self.t += dt
+
+
+def _with_pipeline_section(make):
+    ctx = make()
+    if "stats_open" in ctx:
+        section = lambda k: {  # noqa: E731
+            "decode_steps_total": 100 * k, "pipelined_steps_total": 60 * k, "reclaims_total": 0,
+            "drains_total": {"nonsteady": 5 * k, "finish": 5 * k, "pressure": 0, "idle": 0},
+        }
+        ctx["stats_open"]["pipeline"], ctx["stats_close"]["pipeline"] = section(1), section(2)
+    return ctx
+
+
+def pytest_collection_modifyitems(items):
+    """The hand-made serving run of ``benchmark_yardstick/test_yardstick.py``
+    dates from before the scheduler counted its pipeline's decisions
+    (PR 30), and one test there wants EVERY per-layer metric of the cell
+    from it. That directory is the benchmark's: a PR that adds a metric may
+    add a file there and edit none, its ``conftest.py`` (which completes
+    the same run with PR 23's counters, and says why) included. So the
+    ``pipeline`` section is added from here, composed with that hook
+    whichever runs first. For the next ``benchmark`` issue: fold both into
+    ``_serve_ctx``."""
+    for item in items:
+        if item.path.name == "test_yardstick.py" and item.originalname == (
+            "test_result_object_without_a_trace_holds_the_cell_s_end_to_end_metrics"
+        ):
+            make = item.callspec.params["ctx"]
+            item.callspec.params["ctx"] = functools.partial(_with_pipeline_section, make)

@@ -21,6 +21,12 @@ Coverage (the ISSUE acceptance matrix):
   * steptrace lanes — pipelined captures genuinely diverge: an execute
     span may begin before its iteration (it started during the previous
     one), the sequential block==execute mirror is broken
+  * a full pool is not pressure (ISSUE 30) — with the prefix cache on
+    and the free list empty, the pipeline's block growth evicts an
+    unreferenced entry with a step in flight (dropping it, or reading
+    it out to a host tier with room) and stays pipelined; only a pool
+    with nothing left to evict drains for pressure; the ``pipeline``
+    section of /v2/stats counts all of it
 """
 import contextlib
 
@@ -490,3 +496,159 @@ def test_pipelined_lanes_genuinely_diverge(decoder_params):
         )):
             diverged = True
     assert diverged, "pipelined captures still mirror block==execute"
+
+
+# ------------------------------------------- a full pool is not pressure
+# 24 unshared requests over 6 slots, prompts 9-29 and replies 12-29 (so
+# streams cross block boundaries and finish out of step), prefix cache
+# ON: every finished stream's full blocks stay in the index, the free
+# list empties, and every further block is an eviction
+_POOL_RNG = np.random.default_rng(5)
+POOL_PROMPTS = [
+    [int(x) for x in _POOL_RNG.integers(1, 64, size=int(n))]
+    for n in _POOL_RNG.integers(9, 30, size=24)
+]
+POOL_NEW = [int(n) for n in _POOL_RNG.integers(12, 30, size=24)]
+POOL_SLOTS = 6
+HOST_FULL = 0      # host tier at its budget: a victim is dropped, nothing read
+HOST_ROOM = None   # the default budget: a victim's content is read out
+
+
+def run_full_pool(decoder_params, *, overlap, num_blocks, host_cache_bytes,
+                  temperature=0.0, sched_kw=None, each_step=None):
+    """Drive POOL_PROMPTS to completion; returns (streams, engine,
+    scheduler, reclaim log). The log has one row per
+    ``engine.reclaim_cached`` call: (the heartbeat sequence of the step
+    in flight or None, the heartbeat before, the heartbeat after, blocks
+    freed)."""
+    eng = make_engine(decoder_params, num_blocks=num_blocks, slots=POOL_SLOTS,
+                      prefix_cache=True, host_cache_bytes=host_cache_bytes)
+    sched = ContinuousBatchingScheduler(eng, overlap=overlap, **(sched_kw or {}))
+    log = []
+    reclaim = eng.reclaim_cached
+
+    def logged(n):
+        f, before = sched._pipe, sched._heartbeat
+        freed = reclaim(n)
+        log.append((f and f.hb_seq, before, sched._heartbeat, freed))
+        return freed
+
+    eng.reclaim_cached = logged
+    handles = [
+        sched.submit(p, SamplingParams(
+            max_new_tokens=n, temperature=temperature,
+            top_k=8 if temperature else 0, seed=7 + i,
+        ))
+        for i, (p, n) in enumerate(zip(POOL_PROMPTS, POOL_NEW))
+    ]
+    steps = 0
+    while any(not h.done() for h in handles):
+        if not sched.step():
+            break
+        if each_step is not None:
+            each_step(sched)
+        steps += 1
+        assert steps < 5000, "scheduler failed to converge"
+    return [h.result(timeout=0) for h in handles], eng, sched, log
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "temp_topk"])
+def test_full_pool_with_evictable_entries_stays_pipelined(decoder_params, temperature):
+    """(a) The free list empties, evictable entries never run out: no
+    drain for pressure, most decode steps pipelined, streams exact."""
+    kw = dict(num_blocks=32, host_cache_bytes=HOST_FULL, temperature=temperature)
+    off, _, _, _ = run_full_pool(decoder_params, overlap=False, **kw)
+    on, eng, sched, log = run_full_pool(decoder_params, overlap=True, **kw)
+    assert on == off
+    # the index never ran dry: every reclaim came back with a block
+    assert sched.preemptions == 0 and all(freed >= 1 for *_, freed in log)
+    assert sched.pipe_drains["pressure"] == 0
+    assert sched.pipe_reclaims >= 10, "the pipeline's growth never had to evict"
+    assert any(seq is not None for seq, *_ in log), "no eviction with a step in flight"
+    assert 2 * sched.pipe_dispatches >= eng.step_counts["decode"]
+    assert eng.prefix_cache.swaps_out_total == 0  # tier at its budget: dropped
+    assert eng.trace_counts["decode"] == 1 and eng.recompiles() == {}
+
+
+def test_full_pool_reads_a_victim_out_behind_the_step_in_flight(decoder_params):
+    """(b) Host tier with room: the eviction READS the victim's block
+    with a step in flight, queued behind it on the step's own heartbeat
+    stamp: that stamp is neither cleared nor re-sequenced (``_stamped``
+    would do both), the watchdog sees no stall, nothing recovers."""
+    clock = FakeClock()
+
+    def tick(sched):
+        # under the stall timeout a step, far over it across the run
+        clock.advance(6.0)
+        sched.watchdog.check()
+
+    kw = dict(num_blocks=32, host_cache_bytes=HOST_ROOM)
+    off, _, _, _ = run_full_pool(decoder_params, overlap=False, **kw)
+    on, eng, sched, log = run_full_pool(
+        decoder_params, overlap=True, each_step=tick, sched_kw=dict(
+            clock=clock, watchdog=WatchdogPolicy(enabled=True, stall_timeout_s=10.0),
+        ), **kw)
+    assert on == off
+    behind = [row for row in log if row[0] is not None]
+    assert behind, "no eviction with a step in flight"
+    assert all(before == after and before[0] == seq for seq, before, after, _ in behind)
+    # without a step in flight a reclaim runs under a stamp of its own
+    assert all(before is not None for seq, before, _, _ in log if seq is None)
+    assert eng.prefix_cache.swaps_out_total == sum(freed for *_, freed in log) > 0  # every victim read out
+    assert sched.pipe_drains["pressure"] == 0 and sched.pipe_reclaims >= len(behind)
+    rs = sched.recovery_stats
+    assert rs.watchdog_trips == 0 and rs.recoveries == 0 and rs.step_retries == 0
+    assert eng.resets == 0
+
+
+def test_full_pool_with_nothing_left_to_evict_drains_and_preempts(decoder_params):
+    """(c) A pool so small that the evictable entries run out
+    mid-stream: the pipeline evicts while it can, then drains for
+    pressure and the sequential body preempts by recompute. Exact."""
+    kw = dict(num_blocks=28, host_cache_bytes=HOST_FULL)
+    off, _, sched_off, _ = run_full_pool(decoder_params, overlap=False, **kw)
+    on, eng, sched, log = run_full_pool(decoder_params, overlap=True, **kw)
+    assert on == off
+    assert sched_off.preemptions >= 1 and sched.preemptions >= 1
+    assert sched.pipe_reclaims >= 1
+    assert sched.pipe_drains["pressure"] >= 1
+    assert any(freed == 0 for *_, freed in log), "the index never ran dry"
+
+
+def test_pipeline_section_of_stats_counts_the_loop_s_decisions(decoder_params):
+    """(d) ``/v2/stats`` ``pipeline``: monotone totals, pipelined steps
+    a part of all decode steps, the reasons adding up to the drains."""
+    drained, snaps = [0], []
+
+    def each_step(sched):
+        if not snaps:  # after the first step (an admission: nothing in flight yet)
+            drain = sched._drain_frontier
+
+            def counted(reason):  # an independent count of the real drains
+                drained[0] += sched._pipe is not None
+                drain(reason)
+
+            sched._drain_frontier = counted
+        snaps.append(sched.stats.snapshot()["pipeline"])
+
+    _, eng, sched, log = run_full_pool(
+        decoder_params, overlap=True, num_blocks=28, host_cache_bytes=HOST_FULL,
+        each_step=each_step)
+    flat = lambda p: [p["decode_steps_total"], p["pipelined_steps_total"], p["reclaims_total"],
+                      *(p["drains_total"][r] for r in ("nonsteady", "finish", "pressure", "idle"))]  # noqa: E731
+    assert all(a <= b for p, q in zip(snaps, snaps[1:]) for a, b in zip(flat(p), flat(q)))
+    assert all(0 <= p["pipelined_steps_total"] <= p["decode_steps_total"] for p in snaps)
+    last = sched.stats.snapshot()["pipeline"]
+    assert set(last) == {"decode_steps_total", "pipelined_steps_total", "reclaims_total", "drains_total"}
+    assert set(last["drains_total"]) == {"nonsteady", "finish", "pressure", "idle"}
+    assert sum(last["drains_total"].values()) == drained[0] > 0
+    assert last["drains_total"]["pressure"] >= 1 and last["drains_total"]["finish"] >= 1
+    assert last["decode_steps_total"] == eng.step_counts["decode"]
+    assert 0 < last["pipelined_steps_total"] == sched.pipe_dispatches
+    assert 0 < last["reclaims_total"] == sched.pipe_reclaims <= sum(freed for *_, freed in log)
+    # a sequential scheduler has the section too, and pipelines nothing
+    _, eng_off, sched_off, _ = run_full_pool(
+        decoder_params, overlap=False, num_blocks=28, host_cache_bytes=HOST_FULL)
+    off = sched_off.stats.snapshot()["pipeline"]
+    assert off["decode_steps_total"] == eng_off.step_counts["decode"] > 0
+    assert off["pipelined_steps_total"] == off["reclaims_total"] == 0 == sum(off["drains_total"].values())
