@@ -57,6 +57,28 @@ describes it, and :class:`KVCache` holds it beside K/V as
 A configuration without such layers has neither array, and its programs
 are what they were.
 
+**Two kinds of attention layer in one manager.** A sliding-window layer
+(``DecoderConfig.window``) never reads a position more than ``window``
+behind its query, so keeping its K/V for a sequence's whole history
+would hold blocks nothing can reach. Its K/V lives in arrays of its own,
+``wk`` / ``wv`` ``[n_window, window_blocks, block_size, R, LW]`` (held in
+``KVCache.state`` beside the convolution arrays, carried and donated
+with them), over a POOL of its own (a second :class:`CacheConfig` and
+:class:`BlockAllocator`) and a table of its own per sequence
+(:class:`WindowTable`): the blocks of consecutive block indices from
+``first`` on, where ``first`` moves up as the sequence decodes. Before a
+step is dispatched, every block wholly behind ``position - window + 1``
+is released (back to the pool, or to the prefix entry that owns it) and
+a block is taken where the step's position starts one: a live sequence
+holds at most ``ceil(window / block_size) + 1`` blocks of the window
+pool, and the window layers' paged call walks that many columns and not
+the history's (ops/kernels/decode_attention.py takes the table with the
+position of its column 0). The full layers keep ``k`` / ``v`` and the
+one table a sequence has always had. The pools are sized by what a
+sequence can hold of each (:meth:`CacheConfig.for_slots` with
+``window=``, :func:`pools_from_budget`). A configuration without window
+layers has one pool, and builds the cache it has always built.
+
 Block 0 is reserved as a **scratch block**: padded prompt positions and
 inactive decode slots scatter their (meaningless) K/V there, so the
 jitted steps never need dynamic shapes or masked scatters to avoid
@@ -94,6 +116,9 @@ class CacheConfig:
     block_size: int = 16
     dtype: DataType = DataType.FLOAT
     kv_shards: int = 1
+    # > 0: the pool of the sliding-window layers, whose sequences keep
+    # only the blocks the `window` positions behind a query can touch
+    window: int = 0
 
     def __post_init__(self):
         if self.num_blocks < 2:
@@ -133,6 +158,12 @@ class CacheConfig:
     def blocks_for(self, num_tokens: int) -> int:
         """Blocks needed to hold ``num_tokens`` cache positions."""
         return -(-max(0, num_tokens) // self.block_size)
+
+    def blocks_per_sequence(self, max_seq_len: int) -> int:
+        """The most blocks one live sequence holds of this pool: its
+        whole length or, in a window pool, the window and the rest of
+        the block it starts in."""
+        return _per_sequence(max_seq_len, self.block_size, self.window)
 
     @classmethod
     def from_budget(
@@ -191,6 +222,8 @@ class CacheConfig:
         block_size: int = 16,
         dtype: DataType = DataType.FLOAT,
         expected_prefix_sharing: float = 0.0,
+        window: int = 0,
+        extra_blocks: int = 0,
     ) -> "CacheConfig":
         """Worst-case slot sizing with the sharing-aware discount
         (ROADMAP item 2): the default bound gives every slot room to
@@ -203,15 +236,25 @@ class CacheConfig:
         reservation — floored at one slot's full bound plus one block
         per remaining slot, so a single unshared stream can always run
         to ``max_seq_len`` and every slot can hold at least its COW
-        boundary block.
+        boundary block. ``window`` > 0 sizes a window pool: a slot
+        holds :meth:`blocks_per_sequence` and no more, whatever its
+        length (no sharing discount: the bound is what the release
+        relies on), plus ``extra_blocks`` for the one admission whose
+        suffix prefill reads a matched prefix's window beside its own
+        blocks.
         """
         if not 0.0 <= expected_prefix_sharing < 1.0:
             raise ValueError(
                 f"expected_prefix_sharing must be in [0, 1), got "
                 f"{expected_prefix_sharing}"
             )
-        per_seq = -(-max_seq_len // block_size)
+        per_seq = _per_sequence(max_seq_len, block_size, window)
         worst = per_seq * max_batch_slots
+        if window:
+            return cls(
+                num_layers=num_layers, num_heads=num_heads, head_dim=head_dim,
+                num_blocks=1 + worst + extra_blocks, block_size=block_size, dtype=dtype, window=window,
+            )
         discounted = int(-(-worst * (1.0 - expected_prefix_sharing) // 1))
         floor = per_seq + max(0, max_batch_slots - 1)
         return cls(
@@ -222,6 +265,64 @@ class CacheConfig:
             block_size=block_size,
             dtype=dtype,
         )
+
+
+def _per_sequence(max_seq_len: int, block_size: int, window: int) -> int:
+    whole = -(-max_seq_len // block_size)
+    return min(whole, -(-window // block_size) + 1) if window else whole
+
+
+def pools_from_budget(
+    budget_bytes: int, max_seq_len: int, full: Dict, window: Dict, kv_shards: int = 1
+) -> Tuple[CacheConfig, CacheConfig]:
+    """The two pools of a configuration with window layers against ONE
+    per-device HBM budget, sized by what a sequence can really hold of
+    each: a sequence of ``max_seq_len`` holds ``ceil(max_seq_len /
+    block_size)`` blocks of the full layers' pool and ``ceil(window /
+    block_size) + 1`` of the window layers', so the budget buys
+
+        sequences = budget / (per_seq_full * bytes_full + per_seq_window * bytes_window)
+
+    and each pool that many sequences' blocks (plus scratch). ``full`` /
+    ``window``: the keyword arguments of :class:`CacheConfig` less
+    ``num_blocks`` (``window`` with its ``window``)."""
+    probe_f = CacheConfig(num_blocks=2, kv_shards=kv_shards, **full)
+    probe_w = CacheConfig(num_blocks=2, kv_shards=kv_shards, **window)
+    per_f, per_w = probe_f.blocks_per_sequence(max_seq_len), probe_w.blocks_per_sequence(max_seq_len)
+    per_seq_bytes = per_f * probe_f.bytes_per_block + per_w * probe_w.bytes_per_block
+    sequences = budget_bytes * kv_shards / per_seq_bytes
+    blocks_f, blocks_w = 1 + int(sequences * per_f), 1 + int(sequences * per_w)
+    if blocks_f < 2 or blocks_w < 2:
+        raise ValueError(
+            f"cache budget {budget_bytes}B x {kv_shards} shard(s) holds {sequences:.3f} sequences of "
+            f"{per_seq_bytes}B ({per_f} full + {per_w} window blocks); need a block of each pool beside scratch"
+        )
+    return dataclasses.replace(probe_f, num_blocks=blocks_f), dataclasses.replace(probe_w, num_blocks=blocks_w)
+
+
+class WindowTable:
+    """One live sequence's blocks of the window pool (module docstring):
+    ``blocks[i]`` holds block index ``first + i`` of the sequence;
+    ``shared`` maps the block indices whose block a prefix entry owns
+    (generation/prefix.py: the sequence holds a reference on the entry's
+    window half, not the block) to that entry."""
+
+    __slots__ = ("first", "blocks", "shared")
+
+    def __init__(self, first: int = 0):
+        self.first = first
+        self.blocks: List[int] = []
+        self.shared: Dict[int, object] = {}
+
+    @property
+    def end(self) -> int:
+        """The block index after the last held."""
+        return self.first + len(self.blocks)
+
+    def block_at(self, index: int) -> int:
+        """The pool block of block index ``index``; 0 (scratch) where
+        the sequence does not hold it."""
+        return self.blocks[index - self.first] if self.first <= index < self.end else 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -250,9 +351,10 @@ class StateConfig:
 class KVCache:
     """Device storage: ``k``/``v`` of shape [L, num_blocks, block_size,
     R, LW] (``CacheConfig.row_shape``; [..., H, D] after a row-major
-    reshape) and, with a :class:`StateConfig`, ``state``: the dict
+    reshape) and ``state``: with a :class:`StateConfig` the arrays
     ``{"conv": [n, slots, rows, width], "snap": [n, num_blocks, rows,
-    width]}`` (empty without one: an empty pytree adds nothing to a
+    width]}``, with a ``window_config`` the window layers' ``{"wk",
+    "wv"}`` (empty with neither: an empty pytree adds nothing to a
     program). Functional updates — jitted steps take the arrays and
     return replacements; this object just holds the current ones.
 
@@ -264,22 +366,28 @@ class KVCache:
     program."""
 
     def __init__(self, config: CacheConfig, k: jax.Array, v: jax.Array,
-                 sharding=None, state_config: Optional[StateConfig] = None):
+                 sharding=None, state_config: Optional[StateConfig] = None,
+                 window_config: Optional[CacheConfig] = None):
         self.config = config
         self.k = k
         self.v = v
         self.sharding = sharding
         self.state_config = state_config
+        self.window_config = window_config
         self.state: Dict[str, jax.Array] = self._state_zeros()
 
     def _state_zeros(self) -> Dict[str, jax.Array]:
+        state: Dict[str, jax.Array] = {}
         sc = self.state_config
-        if sc is None:
-            return {}
-        return {
-            "conv": jnp.zeros((sc.num_layers, sc.slots, sc.rows, sc.width), sc.dtype.jnp),
-            "snap": jnp.zeros((sc.num_layers, self.config.num_blocks, sc.rows, sc.width), sc.dtype.jnp),
-        }
+        if sc is not None:
+            state.update(
+                conv=jnp.zeros((sc.num_layers, sc.slots, sc.rows, sc.width), sc.dtype.jnp),
+                snap=jnp.zeros((sc.num_layers, self.config.num_blocks, sc.rows, sc.width), sc.dtype.jnp),
+            )
+        if self.window_config is not None:
+            # the window layers' K and V, a pool of their own (module docstring)
+            state.update(wk=self._zeros(self.window_config, None), wv=self._zeros(self.window_config, None))
+        return state
 
     @staticmethod
     def _zeros(config: CacheConfig, sharding) -> jax.Array:
@@ -298,13 +406,15 @@ class KVCache:
 
     @classmethod
     def create(cls, config: CacheConfig, sharding=None,
-               state_config: Optional[StateConfig] = None) -> "KVCache":
+               state_config: Optional[StateConfig] = None,
+               window_config: Optional[CacheConfig] = None) -> "KVCache":
         return cls(
             config,
             cls._zeros(config, sharding),
             cls._zeros(config, sharding),
             sharding=sharding,
             state_config=state_config,
+            window_config=window_config,
         )
 
     def update(self, k: jax.Array, v: jax.Array, **state: jax.Array) -> None:

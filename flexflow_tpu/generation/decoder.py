@@ -14,16 +14,23 @@ setting of every one of them):
 * operator per layer (``layer_types``): ``attention`` — causal softmax
   attention, optionally grouped (``num_kv_heads`` K/V heads, query head
   ``i`` reading K/V head ``i // group``) and with a per-head RMSNorm on
-  q and k (``qk_norm``) — or ``conv``, a gated short convolution:
+  q and k (``qk_norm``) — ``window``, the same attention over the
+  ``window`` positions up to the query's own and no further back (its
+  K/V lives in arrays and tables of its own, which hold what the window
+  can still reach: generation/cache.py), with rotary parameters of its
+  own kind (``rope_parameters``: a theta, and for YaRN the scaled
+  frequencies and the factor on cos and sin) — or ``conv``, a gated
+  short convolution:
   ``[B, C, X] = split3(W_in u)``, ``z_t = B_t * X_t``, ``c_t = sum_j
   w[:, j] * z_{t-K+1+j}`` (depthwise, causal, kernel ``K``, zeros before
   the sequence), ``out = W_out (C_t * c_t)``. Its state after position
   ``t`` is the last ``K - 1`` rows of ``z``;
 * feed-forward per layer: ``gelu`` (two matrices with biases), ``swiglu``
   (``W2 (silu(W1 v) * W3 v)``) for the first ``num_dense_layers``, and
-  routed experts after them (:func:`expert_ffn`: sigmoid router, a
-  selection bias used for the choice only, top-k, renormalised gates,
-  SwiGLU experts, no capacity and no dropped token);
+  routed experts after them (:func:`expert_ffn`: a ``sigmoid`` router
+  with a selection bias used for the choice only, or a ``softmax`` one
+  without; top-k, renormalised gates, SwiGLU experts, no capacity and
+  no dropped token);
 * dtype: the weights' own. Every matmul accumulates in float32 and
   hands its result on in the activations' type; norms, softmax, the
   rotary angles and the router are computed in float32.
@@ -58,6 +65,7 @@ token-for-token — asserted by tests/test_speculative.py.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -85,7 +93,13 @@ class DecoderConfig(TransformerConfig):
     qk_norm: bool = False
     num_kv_heads: int = 0  # 0: as many as query heads
     head_dim: int = 0  # 0: hidden_size // num_heads
-    layer_types: Tuple[str, ...] = ()  # per layer "attention" | "conv"; (): all attention
+    layer_types: Tuple[str, ...] = ()  # per layer "attention" | "window" | "conv"; (): all attention
+    window: int = 0  # positions a "window" layer's query attends, its own included
+    # rotary parameters by attention kind ("attention" / "window"); a kind
+    # without an entry has plain `rope_theta`. Keys: "theta" and, for YaRN,
+    # "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "attention_factor" (:func:`_rope`)
+    rope_parameters: Dict[str, Dict[str, float]] = dataclasses.field(default_factory=dict)
     conv_kernel: int = 3
     ffn: str = "gelu"  # the dense feed-forward: "gelu" | "swiglu"
     num_dense_layers: int = -1  # layers from here on are routed experts; -1: none is
@@ -93,14 +107,19 @@ class DecoderConfig(TransformerConfig):
     experts_per_token: int = 0
     moe_ff_size: int = 0
     routed_scaling_factor: float = 1.0
+    router: str = "sigmoid"  # | "softmax" (no selection bias)
     tied_head: bool = False  # logits = x E^T, no output matrix of its own
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.num_layers:
             raise ValueError(f"{len(self.layer_types)} layer_types for {self.num_layers} layers")
         for kind in self.layer_types:
-            if kind not in ("attention", "conv"):
-                raise ValueError(f"layer type {kind!r}: 'attention' or 'conv'")
+            if kind not in ("attention", "window", "conv"):
+                raise ValueError(f"layer type {kind!r}: 'attention', 'window' or 'conv'")
+        if "window" in self.layer_types and self.window < 1:
+            raise ValueError("a 'window' layer needs window >= 1")
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"router {self.router!r}: 'sigmoid' or 'softmax'")
 
     @property
     def kv_heads(self) -> int:
@@ -118,7 +137,28 @@ class DecoderConfig(TransformerConfig):
 
     @property
     def attention_layers(self) -> Tuple[int, ...]:
+        """Layers with K/V, of either kind, in layer order."""
+        return tuple(l for l in range(self.num_layers) if self.operator(l) != "conv")
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "window")
+
+    @property
+    def full_layers(self) -> Tuple[int, ...]:
         return tuple(l for l in range(self.num_layers) if self.operator(l) == "attention")
+
+    @property
+    def kv_index(self) -> Tuple[Tuple[str, int], ...]:
+        """For the ``ai``-th attention layer: its kind and its index in
+        that kind's K/V arrays."""
+        seen = {"attention": 0, "window": 0}
+        out = []
+        for l in self.attention_layers:
+            kind = self.operator(l)
+            out.append((kind, seen[kind]))
+            seen[kind] += 1
+        return tuple(out)
 
     @property
     def conv_layers(self) -> Tuple[int, ...]:
@@ -191,7 +231,7 @@ def init_decoder_params(
         layer: Dict[str, Any] = {"ln1_g": ones}
         if cfg.norm == "layernorm":
             layer["ln1_b"] = zeros
-        if cfg.operator(li) == "attention":
+        if cfg.operator(li) != "conv":
             layer.update(
                 wq=_glorot(next(keys), (e, h, d), dt), wk=_glorot(next(keys), (e, hk, d), dt),
                 wv=_glorot(next(keys), (e, hk, d), dt), wo=_glorot(next(keys), (h, d, e), dt),
@@ -220,9 +260,10 @@ def init_decoder_params(
             )
         else:
             n, fe = cfg.num_experts, cfg.moe_ff_size
+            layer.update(router=_glorot(next(keys), (e, n)))
+            if cfg.router == "sigmoid":
+                layer.update(router_bias=0.02 * jax.random.normal(next(keys), (n,), jnp.float32))
             layer.update(
-                router=_glorot(next(keys), (e, n)),
-                router_bias=0.02 * jax.random.normal(next(keys), (n,), jnp.float32),
                 ew1=_glorot(next(keys), (n, e, fe), dt), ew3=_glorot(next(keys), (n, e, fe), dt),
                 ew2=_glorot(next(keys), (n, fe, e), dt),
             )
@@ -259,14 +300,33 @@ def _norm(cfg: DecoderConfig, x, where: Dict, name: str):
     return out.astype(x.dtype)
 
 
-def _rope(x, positions, theta: float):
+def _yarn(inv, d: int, theta: float, yarn: Dict[str, float]):
+    """YaRN's frequencies: dimensions that turn fewer than ``beta_slow``
+    times over the original context are slowed by ``factor``, those that
+    turn more than ``beta_fast`` times are left, a linear ramp between."""
+    span = float(yarn["original_max_position_embeddings"])
+    turns = lambda r: d * math.log(span / (2 * math.pi * r)) / (2 * math.log(theta))  # noqa: E731
+    low = max(math.floor(turns(float(yarn["beta_fast"]))), 0)
+    high = min(math.ceil(turns(float(yarn["beta_slow"]))), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return inv / float(yarn["factor"]) * ramp + inv * (1.0 - ramp)
+
+
+def _rope(x, positions, theta: float, yarn: Optional[Dict[str, float]] = None):
     """Rotate-half rotary embedding over all of the head's dimensions:
-    x [..., H, D], positions [...] (the leading axes of x)."""
+    x [..., H, D], positions [...] (the leading axes of x). ``yarn``
+    (the kind's ``rope_parameters`` where they hold a ``factor``): the
+    frequencies scaled as :func:`_yarn` says, and cos and sin both
+    multiplied by ``attention_factor``."""
     d = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if yarn:
+        inv = _yarn(inv, d, theta, yarn)
     ang = positions.astype(jnp.float32)[..., None] * inv  # [..., D/2]
     cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], axis=-1)[..., None, :]
     sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], axis=-1)[..., None, :]
+    if yarn:
+        cos, sin = cos * float(yarn["attention_factor"]), sin * float(yarn["attention_factor"])
     xf = x.astype(jnp.float32)
     half = jnp.concatenate([-xf[..., d // 2:], xf[..., : d // 2]], axis=-1)
     return (xf * cos + half * sin).astype(x.dtype)
@@ -286,7 +346,7 @@ def _head(cfg: DecoderConfig, params, x):
     return jnp.einsum("...e,ev->...v", x, params["lm_head"], preferred_element_type=jnp.float32)
 
 
-def _qkv(cfg: DecoderConfig, layer, h, positions):
+def _qkv(cfg: DecoderConfig, layer, h, positions, kind: str = "attention"):
     q = _mm("...e,ehd->...hd", h, layer["wq"])
     k = _mm("...e,ehd->...hd", h, layer["wk"])
     v = _mm("...e,ehd->...hd", h, layer["wv"])
@@ -294,7 +354,9 @@ def _qkv(cfg: DecoderConfig, layer, h, positions):
         q = _rms(q.astype(jnp.float32), layer["q_norm_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
         k = _rms(k.astype(jnp.float32), layer["k_norm_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
     if cfg.positions == "rotary":
-        q, k = _rope(q, positions, cfg.rope_theta), _rope(k, positions, cfg.rope_theta)
+        rope = cfg.rope_parameters.get(kind, {})
+        theta, yarn = float(rope.get("theta", cfg.rope_theta)), rope if "factor" in rope else None
+        q, k = _rope(q, positions, theta, yarn), _rope(k, positions, theta, yarn)
     return q, k, v
 
 
@@ -330,10 +392,16 @@ def route(cfg: DecoderConfig, layer, v):
     """Gates [T, N] (zero where an expert is not among a token's top-k)
     and the choice [T, k], for rows ``v`` [T, E]: ``s = sigmoid(W_g v)``,
     ``I = top_k(s + b)``, ``g_i = s_i / (sum_{j in I} s_j + 1e-6) *
-    routed_scaling_factor``. The bias moves the choice, never the gate."""
-    s = jax.nn.sigmoid(jnp.dot(
-        v.astype(jnp.float32), layer["router"].astype(jnp.float32), precision=_ROUTER_PRECISION
-    ))
+    routed_scaling_factor``. The bias moves the choice, never the gate.
+    A ``softmax`` router: ``s = softmax(W_g v)`` over the experts, ``I =
+    top_k(s)``, ``g_i = s_i / sum_{j in I} s_j * routed_scaling_factor``."""
+    scores = jnp.dot(v.astype(jnp.float32), layer["router"].astype(jnp.float32), precision=_ROUTER_PRECISION)
+    if cfg.router == "softmax":
+        s = jax.nn.softmax(scores, axis=-1)
+        picked, chosen = jax.lax.top_k(s, cfg.experts_per_token)
+        gate = picked / jnp.sum(picked, axis=-1, keepdims=True) * cfg.routed_scaling_factor
+        return jnp.zeros_like(s).at[jnp.arange(v.shape[0])[:, None], chosen].set(gate), chosen
+    s = jax.nn.sigmoid(scores)
     _, chosen = jax.lax.top_k(s + layer["router_bias"].astype(jnp.float32), cfg.experts_per_token)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     gate = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6) * cfg.routed_scaling_factor
@@ -416,16 +484,20 @@ def _layers(
 
     Scope names land in the instructions' op_name, so a device trace can
     be grouped by them: ``layer<i>/attention | cache_write | conv |
-    conv_state | mlp | router | experts``."""
+    conv_state | mlp | router | experts``; a configuration with window
+    layers names the two kinds apart, ``attention.window`` and
+    ``attention.full`` (:func:`attention_scope`)."""
     ai = ci = 0
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
-            if cfg.operator(li) == "attention":
-                with jax.named_scope("attention"):
+            kind = cfg.operator(li)
+            if kind != "conv":
+                scope = attention_scope(cfg, kind)
+                with jax.named_scope(scope):
                     h = _norm(cfg, x, layer, "ln1")
-                    q, k, v = _qkv(cfg, layer, h, positions)
+                    q, k, v = _qkv(cfg, layer, h, positions, kind)
                 ctx = attend(ai, q, k, v)
-                with jax.named_scope("attention"):
+                with jax.named_scope(scope):
                     x = x + _mm("...hd,hde->...e", ctx, layer["wo"])
                 ai += 1
             else:
@@ -441,6 +513,13 @@ def _layers(
                 ci += 1
             x = _ffn(cfg, li, layer, x, live, counts)
     return x
+
+
+def attention_scope(cfg: DecoderConfig, kind: str) -> str:
+    """The scope an attention layer's operations are named under."""
+    if not cfg.window_layers:
+        return "attention"
+    return "attention.window" if kind == "window" else "attention.full"
 
 
 def _no_conv(ci, z):
@@ -467,8 +546,9 @@ def prefill(
     counts: Optional[List] = None,
 ):
     """Prefill forward: logits [B, S, V] plus every attention layer's
-    K/V ([n_attn, B, S, Hkv, D] each) for the engine to write into the
-    cache and, for a configuration with convolution layers, a fourth
+    K/V ([n_attn, B, S, Hkv, D] each, both kinds in layer order:
+    ``cfg.kv_index`` says which array each belongs in) for the engine to
+    write into the cache and, for a configuration with convolution layers, a fourth
     result: their padded ``z`` rows [n_conv, B, S + K - 1, E]."""
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     b, s = tokens.shape
@@ -481,7 +561,10 @@ def prefill(
     def attend(ai, q, k, v):
         ks.append(k)
         vs.append(v)
-        with jax.named_scope("attention"):
+        kind = cfg.kv_index[ai][0]
+        with jax.named_scope(attention_scope(cfg, kind)):
+            if kind == "window":
+                return masked_attention(q, k, v, lens, causal=True, window=cfg.window)
             return masked_attention(q, k, v, lens, causal=True)
 
     def convolve(ci, z):
@@ -522,6 +605,7 @@ def decode_step(
     cfg: Optional[TransformerConfig] = None,
     conv: Optional[jax.Array] = None,
     counts: Optional[List] = None,
+    window: Optional[Dict[str, jax.Array]] = None,
 ):
     """One decode step for every batch slot.
 
@@ -537,6 +621,14 @@ def decode_step(
     caller donates them, the kernel reading blocks of the same arrays) —
     and, given ``conv``, a fourth result: the state shifted by this
     token for the live slots, written in place likewise.
+
+    ``window`` (a configuration with window layers): ``{"k", "v"}`` the
+    window layers' arrays [n_window, num_blocks_w, block_size, R, LW],
+    ``"tables"`` [B, columns] the blocks each sequence still holds of
+    them and ``"first"`` [B] the cache position of column 0
+    (generation/cache.py); ``cache_k`` / ``cache_v`` / ``block_tables``
+    are then the full layers' alone. The last result is then ``{"k",
+    "v"}``, the window layers' arrays with the token's rows written.
     """
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     bs = cache_k.shape[2]
@@ -545,17 +637,26 @@ def decode_step(
     with jax.named_scope("embed"):
         x = _embed(cfg, params, tokens, positions)  # [B, E]
         block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, positions)
+        if window is not None:
+            state.update(wk=window["k"], wv=window["v"])
+            wblock, woffset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(
+                window["tables"], positions - window["first"]
+            )
 
     def attend(ai, q, k, v):
         # write this token's K/V, then attend over the updated cache
         # so the token sees itself (context_lens includes it)
+        kind, at = cfg.kv_index[ai]
+        # a window layer's arrays, table and bounds, or the full layers'
+        kk, vv, tables, blk, off, bounds = ("k", "v", block_tables, block, offset, {}) if kind != "window" else (
+            "wk", "wv", window["tables"], wblock, woffset, {"window": cfg.window, "first_positions": window["first"]})
         with jax.named_scope("cache_write"):
-            state["k"] = write_rows(state["k"], ai, block, offset, k)
-            state["v"] = write_rows(state["v"], ai, block, offset, v)
-        with jax.named_scope("attention"):
+            state[kk] = write_rows(state[kk], at, blk, off, k)
+            state[vv] = write_rows(state[vv], at, blk, off, v)
+        with jax.named_scope(attention_scope(cfg, kind)):
             return decode_attention_core(
-                q, state["k"], state["v"], ai, block_tables, context_lens,
-                backend=backend, mesh=mesh,
+                q, state[kk], state[vv], at, tables, context_lens,
+                backend=backend, mesh=mesh, **bounds,
             )
 
     def convolve(ci, z):
@@ -569,7 +670,9 @@ def decode_step(
     x = _layers(cfg, params, x, positions, live, attend, convolve if conv is not None else _no_conv, counts)
     with jax.named_scope("head"):
         out = (_head(cfg, params, x), state["k"], state["v"])
-    return out if conv is None else out + (state["conv"],)
+    if conv is not None:
+        out += (state["conv"],)
+    return out if window is None else out + ({"k": state["wk"], "v": state["wv"]},)
 
 
 def verify_step(
@@ -584,6 +687,7 @@ def verify_step(
     cfg: Optional[TransformerConfig] = None,
     conv_in: Optional[jax.Array] = None,
     counts: Optional[List] = None,
+    window: Optional[Dict[str, jax.Array]] = None,
 ):
     """One chunked-append (speculative verification) step for every
     batch slot.
@@ -607,31 +711,41 @@ def verify_step(
     window continues from (a suffix prefill behind a prefix hit: the
     real tokens are the window's first, padding after them). A fourth
     result then holds the layers' padded ``z`` rows [n_conv, B,
-    W + K - 1, E], as :func:`prefill` returns them.
+    W + K - 1, E], as :func:`prefill` returns them. ``window`` as in
+    :func:`decode_step`, and the window layers' arrays the last result.
     """
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     bs = cache_k.shape[2]
     state = {"k": cache_k, "v": cache_v}
     zs = []
+
+    def slots(tables, pos):
+        block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(tables, pos)
+        # padding -> scratch block 0, offset 0
+        return jnp.where(positions >= 0, block, 0).reshape(-1), jnp.where(positions >= 0, offset, 0).reshape(-1)
+
     with jax.named_scope("embed"):
         safe_pos = jnp.maximum(positions, 0)
         x = _embed(cfg, params, tokens, safe_pos)  # [B, W, E]
-        block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(block_tables, safe_pos)
-        # padding -> scratch block 0, offset 0
-        block = jnp.where(positions >= 0, block, 0).reshape(-1)
-        offset = jnp.where(positions >= 0, offset, 0).reshape(-1)
+        block, offset = slots(block_tables, safe_pos)
+        if window is not None:
+            state.update(wk=window["k"], wv=window["v"])
+            wblock, woffset = slots(window["tables"], jnp.maximum(positions - window["first"][:, None], 0))
 
     def attend(ai, q, k, v):
         # write the whole window's K/V, then attend over the updated
         # cache with per-query position masks (each token sees itself
         # and everything before it, nothing after)
+        kind, at = cfg.kv_index[ai]
+        kk, vv, tables, blk, off, bounds = ("k", "v", block_tables, block, offset, {}) if kind != "window" else (
+            "wk", "wv", window["tables"], wblock, woffset, {"window": cfg.window, "first_positions": window["first"]})
         with jax.named_scope("cache_write"):
-            state["k"] = write_rows(state["k"], ai, block, offset, k.reshape(-1, *k.shape[2:]))
-            state["v"] = write_rows(state["v"], ai, block, offset, v.reshape(-1, *v.shape[2:]))
-        with jax.named_scope("attention"):
+            state[kk] = write_rows(state[kk], at, blk, off, k.reshape(-1, *k.shape[2:]))
+            state[vv] = write_rows(state[vv], at, blk, off, v.reshape(-1, *v.shape[2:]))
+        with jax.named_scope(attention_scope(cfg, kind)):
             return append_attention_core(
-                q, state["k"], state["v"], ai, block_tables, positions,
-                backend=backend, mesh=mesh,
+                q, state[kk], state[vv], at, tables, positions,
+                backend=backend, mesh=mesh, **bounds,
             )
 
     def convolve(ci, z):
@@ -645,4 +759,6 @@ def verify_step(
     )
     with jax.named_scope("head"):
         out = (_head(cfg, params, x), state["k"], state["v"])
-    return out + (jnp.stack(zs),) if zs else out
+    if zs:
+        out += (jnp.stack(zs),)
+    return out if window is None else out + ({"k": state["wk"], "v": state["wv"]},)

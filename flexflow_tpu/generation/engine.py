@@ -60,7 +60,9 @@ from ..obs.capacity import ProgramRegistry, ServingFlops
 from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
 from ..runtime import faults
-from .cache import BlockAllocator, CacheConfig, KVCache, StateConfig, slot_mapping
+from .cache import (
+    BlockAllocator, CacheConfig, KVCache, StateConfig, WindowTable, pools_from_budget, slot_mapping,
+)
 from .decoder import (
     DecoderParams,
     decode_step,
@@ -219,12 +221,12 @@ class InFlightDecode:
     :meth:`GenerationEngine.consume_decode`. Loop-thread only."""
 
     __slots__ = (
-        "out", "ok", "prev_k", "prev_v", "prev_conv", "prev_counts", "ck", "cv", "t0", "t_disp",
+        "out", "ok", "prev_k", "prev_v", "prev_conv", "prev_window", "prev_counts", "ck", "cv", "t0", "t_disp",
         "t_started", "traced", "n_active", "ctx_sum", "consumed",
     )
 
     def __init__(self, out, ok, prev_k, prev_v, ck, cv, t0, t_disp, traced, n_active, ctx_sum,
-                 prev_conv=None, prev_counts=None):
+                 prev_conv=None, prev_counts=None, prev_window=None):
         self.out = out
         self.ok = ok
         self.prev_k = prev_k
@@ -232,6 +234,9 @@ class InFlightDecode:
         # the slots' convolution state before the step: rolled back with
         # K/V (None for a configuration without one)
         self.prev_conv = prev_conv
+        # the window layers' K/V before the step ({"wk", "wv"}; None
+        # without window layers), rolled back likewise
+        self.prev_window = prev_window
         # the expert counters before the step (never donated, so always
         # held): a failed step's own are poisoned with its other results
         self.prev_counts = prev_counts
@@ -288,6 +293,11 @@ class GenerationEngine:
         # refused by name (ROADMAP "What the system cannot run yet"):
         # `unsupported[path]` is the reason, raised when the path is taken
         self.unsupported: Dict[str, str] = {}
+        wants_tp = (
+            (tp_degree or 1) > 1
+            or (mesh is not None and int(dict(mesh.shape).get("model", 1)) > 1)
+            or (tp_degree is None and mesh is None and (mesh_devices or 1) > 1)
+        )
         if self.dcfg.stateful:
             self.unsupported = {
                 "speculation": (
@@ -306,11 +316,31 @@ class GenerationEngine:
                     "for the convolution operator, its state or the expert weights"
                 ),
             }
-            wants_tp = (
-                (tp_degree or 1) > 1
-                or (mesh is not None and int(dict(mesh.shape).get("model", 1)) > 1)
-                or (tp_degree is None and mesh is None and (mesh_devices or 1) > 1)
-            )
+            if wants_tp:
+                raise NotImplementedError(self.unsupported["tensor_parallel"])
+        if self.dcfg.window_layers:
+            if self.dcfg.stateful:
+                raise NotImplementedError(
+                    "a configuration with both convolution and sliding-window layers is refused: a "
+                    "cached block would carry a convolution snapshot and a window half at once"
+                )
+            w = self.dcfg.window
+            self.unsupported = {
+                "speculation": (
+                    f"speculative verification (engine.verify) is refused for a configuration with "
+                    f"sliding-window layers (window {w}): a rejected draft would have to give back the "
+                    f"window blocks its positions took and take back those they released"
+                ),
+                "kv_handoff": (
+                    f"the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a "
+                    f"configuration with sliding-window layers (window {w}): a payload carries one table's "
+                    f"blocks, and the decode side would need the window layers' for the last {w} positions"
+                ),
+                "tensor_parallel": (
+                    f"tp_degree > 1 is refused for a configuration with sliding-window layers (window {w}): "
+                    f"the head-sharded paged kernel takes no window, and the window pool has no sharding"
+                ),
+            }
             if wants_tp:
                 raise NotImplementedError(self.unsupported["tensor_parallel"])
         # ------------------------------------------------- serving mesh
@@ -358,11 +388,43 @@ class GenerationEngine:
         self.params = (
             self.layout.shard_params(params) if self.layout else params
         )
+        self.buckets = tuple(sorted(prompt_buckets or default_buckets(self.max_seq_len)))
+        # the window layers' pool (cache.py): sized for what a sequence
+        # can hold of it, plus the one admission whose suffix prefill
+        # reads a matched prefix's window beside its own blocks
+        self.window_config: Optional[CacheConfig] = None
+        self.window_columns = 0
+        if self.dcfg.window_layers:
+            wkv = dict(
+                num_layers=len(self.dcfg.window_layers), num_heads=self.dcfg.kv_heads,
+                head_dim=self.dcfg.dim_per_head, block_size=cache_config.block_size if cache_config else block_size,
+                dtype=cfg.dtype, window=self.dcfg.window,
+            )
+            if cache_config is None and cache_budget_bytes is not None:
+                fkv = dict(wkv, num_layers=len(self.dcfg.full_layers), window=0)
+                cache_config, self.window_config = pools_from_budget(
+                    cache_budget_bytes, self.max_seq_len, fkv, wkv
+                )
+            else:
+                self.window_config = CacheConfig.for_slots(
+                    max_seq_len=self.max_seq_len, max_batch_slots=max_batch_slots,
+                    extra_blocks=-(-max(self.buckets[-1], self.max_seq_len) // wkv["block_size"]), **wkv,
+                )
+            # what a live sequence keeps of it at most: the columns of a decode step's window table
+            self.window_columns = self.window_config.blocks_per_sequence(self.max_seq_len)
+            need = 1 + max_batch_slots * self.window_columns
+            if self.window_config.num_blocks < need:
+                raise ValueError(
+                    f"the window layers' pool holds {self.window_config.num_blocks} blocks; {max_batch_slots} "
+                    f"slots need {need} (a live sequence keeps up to {self.window_columns}, and the release counts on it)"
+                )
         if cache_config is None:
             # K/V is paged for the ATTENTION layers alone, at the K/V
             # heads' width, in the type the configuration serves in
+            # (the FULL layers where the configuration has window layers
+            # too: those have the pool above)
             kv = dict(
-                num_layers=len(self.dcfg.attention_layers),
+                num_layers=len(self.dcfg.full_layers),
                 num_heads=self.dcfg.kv_heads,
                 head_dim=self.dcfg.dim_per_head,
                 block_size=block_size,
@@ -403,7 +465,16 @@ class GenerationEngine:
             cache_config,
             sharding=self.layout.cache_sharding if self.layout else None,
             state_config=self.state_config,
+            window_config=self.window_config,
         )
+        # the live sequences' tables of the window pool, by batch slot,
+        # and what the release has given back (the `cache` section of
+        # /v2/stats: cache_stats)
+        self.window_allocator = BlockAllocator(self.window_config) if self.window_config else None
+        self.window_tables: Dict[int, WindowTable] = {}
+        self.window_released_total = 0
+        self._live_blocks = (0, 0)  # (full, window) blocks the last decode step's sequences held
+        self.window_held_peak = 0  # the most window blocks any one sequence held at a step
         # tokens every expert of every expert layer was handed, and the
         # calls that handed them, counted ON THE DEVICE: the step
         # programs take these arrays and return them grown, and nothing
@@ -425,7 +496,6 @@ class GenerationEngine:
         self.state_snapshots_total = 0
         self.allocator = BlockAllocator(cache_config)
         self.max_blocks_per_seq = cache_config.blocks_for(self.max_seq_len)
-        self.buckets = tuple(sorted(prompt_buckets or default_buckets(self.max_seq_len)))
         if self.buckets[-1] > self.max_seq_len:
             raise ValueError(
                 f"bucket {self.buckets[-1]} exceeds max_seq_len {self.max_seq_len}"
@@ -601,9 +671,11 @@ class GenerationEngine:
             self.allocator, cache_config,
             enabled=prefix_cache, host_budget_bytes=host_cache_bytes,
             state_bytes_per_block=(
-                self.state_config.bytes_per_sequence if self.state_config else 0
+                self.state_config.bytes_per_sequence if self.state_config
+                else self.window_config.bytes_per_block if self.window_config else 0
             ),
         )
+        self.prefix_cache.window_allocator = self.window_allocator
         if self.layout is None:
             blk_sh = rd_sh = {}
         else:
@@ -619,6 +691,8 @@ class GenerationEngine:
         self._copy_block_jit = jax.jit(self._copy_block_impl, **blk_sh)
         self._read_block_jit = jax.jit(self._read_block_impl, **rd_sh)
         self._write_block_jit = jax.jit(self._write_block_impl, **blk_sh)
+        self._read_window_jit = jax.jit(self._read_window_impl)
+        self._write_window_jit = jax.jit(self._write_window_impl)
         # batched handoff-wire programs (one dispatch per payload, not
         # per block): padded to max_blocks_per_seq so ONE fixed-shape
         # program serves every prompt length
@@ -689,6 +763,9 @@ class GenerationEngine:
         into the fresh cache."""
         self.cache.reset()
         self.allocator.reset()
+        if self.window_allocator is not None:
+            self.window_allocator.reset()
+            self.window_tables.clear()
         if self.expert_counts:
             # cumulative across a reset, unless the failed program's
             # results (a donating engine has no older ones) are all it has
@@ -750,7 +827,7 @@ class GenerationEngine:
             }
 
     def _prefill_impl(self, params, tokens, length, cache_k, cache_v, block_table, temp, top_k, key, mask,
-                      state=None, slot=None, counts=None):
+                      state=None, slot=None, counts=None, wtable=None):
         s = tokens.shape[1]
         self.trace_counts[f"prefill[{s}]"] = self.trace_counts.get(f"prefill[{s}]", 0) + 1
         self.programs.note_trace(f"prefill[{s}]", {
@@ -769,13 +846,28 @@ class GenerationEngine:
         block, offset = slot_mapping(block_table, positions, cache_k.shape[2])
         block = jnp.where(positions < length, block, 0)  # padding -> scratch
         offset = jnp.where(positions < length, offset, 0)
+        if self.window_config is not None:
+            # the window layers' rows go to the window pool's blocks: of
+            # the positions behind what the table still holds nothing is
+            # kept (scratch), the next query cannot reach them
+            held = jnp.logical_and(positions < length, positions >= wtable["first"])
+            wblock, woffset = slot_mapping(wtable["tables"], positions - wtable["first"], cache_k.shape[2])
+            wblock, woffset = jnp.where(held, wblock, 0), jnp.where(held, woffset, 0)
+            wk, wv = state["wk"], state["wv"]
         with jax.named_scope("cache_write"):
             # layer by layer, as a decode step writes: one scatter over
             # all layers would have the compiler transpose the whole
             # cache to bring the scattered axes to the front, and back
             for li in range(ks.shape[0]):
-                cache_k = write_rows(cache_k, li, block, offset, ks[li, 0])
-                cache_v = write_rows(cache_v, li, block, offset, vs[li, 0])
+                kind, at = self.dcfg.kv_index[li]
+                if kind == "window":
+                    wk = write_rows(wk, at, wblock, woffset, ks[li, 0])
+                    wv = write_rows(wv, at, wblock, woffset, vs[li, 0])
+                    continue
+                cache_k = write_rows(cache_k, at, block, offset, ks[li, 0])
+                cache_v = write_rows(cache_v, at, block, offset, vs[li, 0])
+        if self.window_config is not None:
+            state = dict(state, wk=wk, wv=wv)
         with jax.named_scope("sample"):
             last = logits[0, length - 1]
             ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
@@ -787,7 +879,7 @@ class GenerationEngine:
 
     def _decode_impl(
         self, params, tokens, positions, cache_k, cache_v, block_tables, context_lens, temps, top_ks, bias, seeds, counts, mask,
-        state=None, expert_counts=None,
+        state=None, expert_counts=None, wtables=None,
     ):
         self.trace_counts["decode"] = self.trace_counts.get("decode", 0) + 1
         self.programs.note_trace("decode", {
@@ -797,15 +889,20 @@ class GenerationEngine:
             "bias": bias, "seeds": seeds, "counts": counts, "mask": mask,
         })
         state, expert_counts, rows = state or {}, expert_counts or {}, []
+        window = None
+        if self.window_config is not None:
+            window = {"k": state["wk"], "v": state["wv"], **wtables}
         logits, cache_k, cache_v, *conv = decode_step(
             params, tokens, positions, cache_k, cache_v, block_tables,
             context_lens, backend=self.backend, mesh=self._kernel_mesh,
-            cfg=self.dcfg, conv=state.get("conv"), counts=rows,
+            cfg=self.dcfg, conv=state.get("conv"), counts=rows, window=window,
         )
         if self.state_config is not None:
             # a decode step carries the slots' state alone: the blocks'
             # snapshots are the prefill programs' to write
             state = {"conv": conv[0]}
+        if self.window_config is not None:
+            state = {"wk": conv[-1]["k"], "wv": conv[-1]["v"]}
         expert_counts = self._count(expert_counts, rows, 0)
         # bias is the fault plan's per-slot NaN poison (zeros outside
         # chaos runs); applying it before the finiteness reduce makes the
@@ -877,7 +974,7 @@ class GenerationEngine:
 
     def _prefix_prefill_impl(
         self, params, tokens, start, n_real, cache_k, cache_v, block_table, temp, top_k, key, mask,
-        state=None, slot=None, counts=None,
+        state=None, slot=None, counts=None, wtable=None,
     ):
         """Suffix-only prefill against a cached prefix: the [1, W]
         suffix window attends over the block table (shared prefix
@@ -903,11 +1000,17 @@ class GenerationEngine:
             # the window continues from the state the slot holds: the
             # restore (_restore_state_impl) put the matched prefix's there
             conv_in = jax.lax.dynamic_slice_in_dim(state["conv"], slot, 1, axis=1)
+        window = None
+        if self.window_config is not None:
+            window = {"k": state["wk"], "v": state["wv"],
+                      "tables": wtable["tables"][None], "first": wtable["first"][None]}
         logits, cache_k, cache_v, *zs = verify_step(
             params, tokens, positions, cache_k, cache_v, block_table[None],
             backend=self.backend, mesh=self._kernel_mesh, cfg=self.dcfg,
-            conv_in=conv_in, counts=rows,
+            conv_in=conv_in, counts=rows, window=window,
         )
+        if self.window_config is not None:
+            state = dict(state, wk=zs[-1]["k"], wv=zs[-1]["v"])
         if self.state_config is not None:
             # a reused prefix ends on a block boundary for such a
             # configuration (prefix_plan), so the window's blocks are whole
@@ -951,6 +1054,25 @@ class GenerationEngine:
         if self.state_config is None:
             return out
         return out + (jax.lax.dynamic_index_in_dim(snap, src, axis=1, keepdims=False),)
+
+    def _read_window_impl(self, wk, wv, src):
+        """The window half of a cached block, read out with it for the
+        host tier: [2, n_window, bs, H, D], K then V."""
+        self.trace_counts["kv_window_read"] = self.trace_counts.get("kv_window_read", 0) + 1
+        self.programs.note_trace("kv_window_read", {"wk": wk, "src": src})
+        return jnp.stack([
+            self._logical(jax.lax.dynamic_index_in_dim(wk, src, axis=1, keepdims=False)),
+            self._logical(jax.lax.dynamic_index_in_dim(wv, src, axis=1, keepdims=False)),
+        ])
+
+    def _write_window_impl(self, wk, wv, dst, host_w):
+        """A swap-in's window half, back into the window pool at ``dst``."""
+        self.trace_counts["kv_window_write"] = self.trace_counts.get("kv_window_write", 0) + 1
+        self.programs.note_trace("kv_window_write", {"wk": wk, "dst": dst, "host_w": host_w})
+        return (
+            jax.lax.dynamic_update_slice_in_dim(wk, self._stored(host_w[0][:, None], wk), dst, axis=1),
+            jax.lax.dynamic_update_slice_in_dim(wv, self._stored(host_w[1][:, None], wv), dst, axis=1),
+        )
 
     def _logical(self, blocks):
         """Stored [L, ..., bs, R, LW] -> logical [L, ..., bs, H, D]."""
@@ -1071,6 +1193,9 @@ class GenerationEngine:
         if prefix_len > 0:
             return self._prefill_suffix(prompt, block_table, sampling, key, prefix_len, mask, slot)
         self.step_counts["prefill"] += 1
+        if self.window_config is not None and slot not in self.window_tables:
+            # (a caller that assembled its own table: prepare_prefix does this for the scheduler)
+            self._window_admit(slot, len(prompt), 0, [])
         with phase("engine.prefill.dispatch") as disp:
             n = len(prompt)
             bucket = self.bucket_for(n)
@@ -1090,7 +1215,7 @@ class GenerationEngine:
                 jnp.int32(sampling.top_k),
                 self._dev(key),
                 self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
-                *self._state_args(slot),
+                *self._state_args(slot, self.window_columns),
             )
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
@@ -1170,7 +1295,7 @@ class GenerationEngine:
                 jnp.int32(sampling.top_k),
                 self._dev(key),
                 self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
-                *self._state_args(slot),
+                *self._state_args(slot, self._suffix_columns(w)),
             )
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
@@ -1179,6 +1304,10 @@ class GenerationEngine:
             self.expert_counts = counts
             self.last_finite = np.asarray(ok).reshape(1)
             out = int(token)  # result sync lands inside the readback span
+        if self.window_config is not None:
+            # the matched prefix's window was this prefill's to read: what
+            # lies behind the next query's goes back now, not a step later
+            self._window_advance(self.window_tables[slot], n, grow=False)
         elapsed, execute_s = self._record_step_phases("prefill", disp, block, read)
         # useful work = suffix tokens only, each attending its full live
         # context (causal): ctx = sum_{p=prefix_len}^{n-1} (p + 1)
@@ -1203,13 +1332,161 @@ class GenerationEngine:
             )
         return out
 
-    def _state_args(self, slot: int) -> tuple:
-        """The trailing (state, slot, counts) of the prefill programs."""
-        return (
+    def _state_args(self, slot: int, window_columns: int = 0) -> tuple:
+        """The trailing (state, slot, counts) of the prefill programs
+        and, where the configuration has window layers, the slot's table
+        of the window pool at ``window_columns`` columns."""
+        args = (
             self.cache.state,
             jnp.int32(slot) if self.state_config is not None else None,
             self.expert_counts,
         )
+        if self.window_config is None:
+            return args
+        t = self.window_tables[slot]
+        table = np.zeros((window_columns,), np.int32)
+        table[: len(t.blocks)] = t.blocks[:window_columns]
+        return args + ({"tables": self._dev(table), "first": jnp.int32(t.first * self.window_config.block_size)},)
+
+    def _suffix_columns(self, bucket: int) -> int:
+        """Columns of the window table a suffix prefill of ``bucket``
+        tokens reads: the window behind its first query and its own."""
+        return self.window_columns + self.window_config.blocks_for(bucket) if self.window_config else 0
+
+    # ------------------------------------------------- the window pool
+    def _window_allocate(self, n: int) -> Optional[List[int]]:
+        """``n`` blocks of the window pool; the window halves of cached
+        prefixes that no live sequence's table includes are what it
+        takes back first (the pool's size guarantees the rest:
+        CacheConfig.for_slots)."""
+        got = self.window_allocator.allocate(n)
+        if got is None:
+            # a batch a scan: every slot asks for a block once a block's worth of steps
+            self.prefix_cache.reclaim_window(max(n - self.window_allocator.num_free, self.max_batch_slots))
+            got = self.window_allocator.allocate(n)
+        return got
+
+    def _window_release(self, t: WindowTable, upto: int, counted: bool = True) -> None:
+        """Give back the table's blocks of block indices below ``upto``:
+        a private block to the pool, a prefix entry's half to the entry
+        (one reference fewer on it). ``counted``: into
+        ``window_released_total``, which is what a sequence gives back
+        while it lives, not what it leaves when it goes."""
+        while t.blocks and t.first < upto:
+            block = t.blocks.pop(0)
+            entry = t.shared.pop(t.first, None)
+            if entry is not None:
+                entry.wrefs -= 1
+            else:
+                self.window_allocator.free([block])
+            t.first += 1
+            self.window_released_total += counted
+        if not t.blocks:
+            t.first = max(t.first, upto)
+
+    def _window_advance(self, t: WindowTable, position: int, grow: bool = True) -> None:
+        """Bring a sequence's window table to where a query at
+        ``position`` needs it: every block wholly behind ``position -
+        window + 1`` released, and (``grow``) the block ``position``
+        lies in taken if the table ends before it."""
+        wc = self.window_config
+        self._window_release(t, max(0, position - wc.window + 1) // wc.block_size)
+        while grow and t.end <= position // wc.block_size:
+            got = self._window_allocate(1)
+            if got is None:
+                raise RuntimeError(
+                    f"the window layers' pool is out of blocks ({wc.num_blocks}) with every live "
+                    f"sequence inside its bound: sized for fewer slots than are decoding"
+                )
+            t.blocks.extend(got)
+
+    def _window_admit(self, slot: int, n_tokens: int, prefix_len: int, entries: Sequence[PrefixEntry]) -> None:
+        """The slot's window table for an admission of ``n_tokens``
+        whose first ``prefix_len`` (whole blocks) are reused from
+        ``entries``: the entries' window halves for the window behind
+        the first computed position (a reference on each), then private
+        blocks up to the block the first decode step writes in. A plain
+        prefill keeps only what the next query can reach."""
+        wc = self.window_config
+        self.release_slot(slot)
+        start = prefix_len if prefix_len else n_tokens
+        t = WindowTable(first=max(0, start - wc.window + 1) // wc.block_size)
+        for j in range(t.first, prefix_len // wc.block_size):
+            entry = entries[j]
+            t.blocks.append(entry.wblock)
+            t.shared[j] = entry
+            entry.wrefs += 1
+        self.window_tables[slot] = t
+        need = wc.blocks_for(n_tokens + 1) - t.end
+        if need > 0:
+            got = self._window_allocate(need)
+            if got is None:
+                self.release_slot(slot)
+                raise RuntimeError(f"the window layers' pool cannot hold an admission of {need} blocks")
+            t.blocks.extend(got)
+
+    def release_slot(self, slot: int) -> None:
+        """A sequence left ``slot`` (finished, preempted, failed): its
+        window table's blocks go back. A no-op without window layers."""
+        t = self.window_tables.pop(slot, None)
+        if t is not None:
+            self._window_release(t, t.end, counted=False)
+
+    def _window_boundary(self, entries: Sequence[PrefixEntry]) -> int:
+        """The longest run of ``entries`` a prefix can be resumed after:
+        the largest ``m`` such that every entry of the window behind
+        position ``m * block_size`` still has its window half."""
+        wc = self.window_config
+        best = held = 0  # held: entries up to the m-th, without a gap, that have their halves
+        for m, entry in enumerate(entries, start=1):
+            held = held + 1 if self.prefix_cache.has_window(entry) else 0
+            if held >= m - max(0, m * wc.block_size - wc.window + 1) // wc.block_size:
+                best = m
+        return best
+
+    def _share_window(self, slot: int, entries: Sequence[PrefixEntry]) -> None:
+        """Newly registered ``entries`` take over the window blocks the
+        slot holds at their block indices (the sequence keeps a
+        reference, as it does on the full half)."""
+        t = self.window_tables.get(slot)
+        if t is None:
+            return
+        for entry in entries:
+            j = entry.depth
+            if t.first <= j < t.end and j not in t.shared and not entry.wblock:
+                entry.wblock = t.blocks[j - t.first]
+                entry.wrefs += 1
+                t.shared[j] = entry
+
+    def cache_stats(self) -> Dict:
+        """The ``cache`` section of ``/v2/stats``, by kind of layer: each
+        pool's blocks, what decoding has released of the window pool,
+        the bytes the sequences of the last decode step held, and the
+        bytes ONE table for all attention layers would hold for them."""
+        cc, wc = self.cache_config, self.window_config
+        full, window = self._live_blocks
+        return {
+            "full": {"layers": cc.num_layers, "blocks_total": self.allocator.num_total,
+                     "blocks_used": self.allocator.num_total - self.allocator.num_free,
+                     "bytes_per_block": cc.bytes_per_block},
+            "window": {"layers": wc.num_layers, "window": wc.window,
+                       "blocks_total": self.window_allocator.num_total,
+                       "blocks_used": self.window_allocator.num_total - self.window_allocator.num_free,
+                       "bytes_per_block": wc.bytes_per_block,
+                       "blocks_per_sequence": self.window_columns,
+                       "held_by_a_sequence_peak": self.window_held_peak},
+            "window_released_total": self.window_released_total,
+            "live_bytes": full * cc.bytes_per_block + window * wc.bytes_per_block,
+            "one_table_bytes": full * (cc.bytes_per_block + wc.bytes_per_block),
+        }
+
+    def blocks_in_use(self) -> Tuple[int, int]:
+        """(used, total) blocks of the pool that is fuller: what
+        ``cache_blocks_used`` / ``cache_blocks_total`` report. With one
+        pool, that pool."""
+        pools = [self.allocator] + ([self.window_allocator] if self.window_allocator else [])
+        fuller = max(pools, key=lambda a: 1.0 - a.num_free / max(1, a.num_total))
+        return fuller.num_total - fuller.num_free, fuller.num_total
 
     def _restore_state(self, slot: int, block: int) -> None:
         """A prefix hit on a configuration with convolution layers: the
@@ -1252,6 +1529,13 @@ class GenerationEngine:
             reuse, cow = n_shared * bs, None
             if not reuse:
                 return EMPTY_PREFIX_PLAN
+        if self.window_config is not None:
+            # a prefix is resumed at a block boundary behind which the
+            # window layers' blocks are still held: the longest such, or none
+            n_shared = self._window_boundary(run[:n_shared])
+            reuse, cow = n_shared * bs, None
+            if not reuse:
+                return EMPTY_PREFIX_PLAN
         entries = run[:n_shared]
         off_idx = [i for i, e in enumerate(entries) if not e.resident]
         cow_off = cow is not None and not cow.resident
@@ -1280,6 +1564,7 @@ class GenerationEngine:
         prompt: Sequence[int],
         plan: PrefixPlan,
         new_blocks: List[int],
+        slot: int = 0,
     ) -> Optional[Tuple[List[int], set, List[PrefixEntry], int]]:
         """Assemble one admission's block table from a plan: shared
         entries first (swapping offloaded ones back in), then the
@@ -1330,6 +1615,13 @@ class GenerationEngine:
             if cow is not None:
                 pc.release([cow])
                 cow = None
+        if self.window_config is not None:
+            # a swap-in may have come back without its window half, or
+            # pushed another entry's out: hold the rule on what is kept
+            m = self._window_boundary(kept)
+            pc.release(kept[m:])
+            del kept[m:], shared[m:]
+            entries, reuse = list(kept), m * bs
         # re-balance the private pool against the full table budget.
         # The plan's resident count can go stale between planning and
         # assembly: a reclaim (this admission's own, or the allocator
@@ -1365,6 +1657,8 @@ class GenerationEngine:
             surplus = table[need_total:]
             del table[need_total:]
             self.allocator.free(surplus)
+        if self.window_config is not None:
+            self._window_admit(slot, len(prompt), reuse, kept)
         return table, set(range(len(shared))), kept, reuse
 
     def _swap_in(self, entry: PrefixEntry, dst: int) -> bool:
@@ -1380,12 +1674,12 @@ class GenerationEngine:
                 buf = pc.take_host_copy(entry)
                 if buf is None:  # corrupted or already dropped
                     raise ValueError("host-tier block failed CRC verification")
-                self._write_host_copy(dst, buf)
+                wblock = self._write_host_copy(dst, buf)
             except Exception:
                 pc.swap_in_failures += 1
                 pc.recompute_fallbacks += 1
                 return False
-            pc.note_swapped_in(entry, dst)
+            pc.note_swapped_in(entry, dst, wblock)
         pc.observe("cache_restore", restore.seconds)
         if self.trace_counts.get("kv_block_write", 0) == traces_before:
             self.ledger.observe(
@@ -1421,16 +1715,29 @@ class GenerationEngine:
         pc.swaps_in_total += 1
         return True
 
-    def _write_host_copy(self, dst: int, buf) -> None:
+    def _write_host_copy(self, dst: int, buf) -> int:
         """One host-tier copy (K, V and, where blocks carry one, the
         convolution state stored with the block) into device block
-        ``dst``."""
+        ``dst``. A window half that came with it goes into a block of
+        the window pool, whose id is returned (0: none, or no room)."""
         hk, hv, *hs = buf
+        if self.window_config is not None:
+            ck, cv = self._write_block_jit(self.cache.k, self.cache.v, jnp.int32(dst), self._dev(hk), self._dev(hv))
+            self.cache.update(ck, cv)
+            got = self._window_allocate(1) if hs else None
+            if not got:
+                return 0
+            wk, wv = self._write_window_jit(
+                self.cache.state["wk"], self.cache.state["wv"], jnp.int32(got[0]), self._dev(hs[0])
+            )
+            self.cache.state.update(wk=wk, wv=wv)
+            return got[0]
         extra = (self.cache.state["snap"], self._dev(hs[0])) if self.state_config is not None else ()
         ck, cv, *snap = self._write_block_jit(
             self.cache.k, self.cache.v, jnp.int32(dst), self._dev(hk), self._dev(hv), *extra
         )
         self.cache.update(ck, cv, **({"snap": snap[0]} if snap else {}))
+        return 0
 
     def register_prefix(
         self,
@@ -1439,6 +1746,7 @@ class GenerationEngine:
         shared_idx: set,
         entries: List[PrefixEntry],
         prefix_len: int = 0,
+        slot: int = 0,
     ) -> None:
         """Post-prefill registration: the prompt's freshly written full
         blocks join the radix index (ownership moves to the index; the
@@ -1455,9 +1763,12 @@ class GenerationEngine:
             pc.hits += 1
             pc.tokens_reused_total += prefix_len
             pc.blocks_reused_total += len(entries)
+        held = len(entries)
         n_new = self.prefix_cache.register_chain(
             prompt, table, shared_idx, entries, len(prompt)
         )
+        if self.window_config is not None:
+            self._share_window(slot, entries[held:])
         if self.state_config is not None:
             # every block a prefill fills to its end got its snapshot
             # from the same program (_write_state)
@@ -1480,12 +1791,17 @@ class GenerationEngine:
             # resumed from: only what the admission's prefill wrote is
             # registered, and that is registered already
             upto = min(upto, len(req.prompt))
+        held = len(state.shared_entries)
         self.prefix_cache.register_chain(
             tokens, state.blocks, state.shared_idx, state.shared_entries, upto
         )
+        if self.window_config is not None:
+            # the window halves of the blocks the victim still holds: its
+            # re-admission resumes at a boundary inside them
+            self._share_window(state.slot, state.shared_entries[held:])
 
     def release_admission(
-        self, table: List[int], shared_idx: set, entries: List[PrefixEntry]
+        self, table: List[int], shared_idx: set, entries: List[PrefixEntry], slot: Optional[int] = None
     ) -> None:
         """Undo one admission's block bookkeeping (failed or poisoned
         prefill): private blocks back to the allocator, shared refs
@@ -1494,6 +1810,8 @@ class GenerationEngine:
             [b for i, b in enumerate(table) if i not in shared_idx]
         )
         self.prefix_cache.release(entries)
+        if slot is not None:
+            self.release_slot(slot)
 
     def reclaim_cached(self, n_blocks: int) -> int:
         """Free device blocks held by unreferenced cached prefixes (LRU;
@@ -1502,13 +1820,18 @@ class GenerationEngine:
         if not self.prefix_cache.enabled:
             return 0
 
-        def read(block_id: int):
+        def read(block_id: int, window_block: int = 0):
             faults.inject(faults.GENERATION_KV_OFFLOAD, ("out", 1))
             snap = self.cache.state.get("snap")
             extra = () if snap is None else (snap,)
-            return tuple(np.asarray(a) for a in self._read_block_jit(
+            out = tuple(np.asarray(a) for a in self._read_block_jit(
                 self.cache.k, self.cache.v, jnp.int32(block_id), *extra
             ))
+            if window_block:
+                out += (np.asarray(self._read_window_jit(
+                    self.cache.state["wk"], self.cache.state["wv"], jnp.int32(window_block)
+                )),)
+            return out
 
         # one span per call: victim selection, the device reads and the
         # CRCs are all what evicting to the host tier costs an admission
@@ -1603,9 +1926,11 @@ class GenerationEngine:
         self._staged[name] = (host.copy(), dev)
         return dev
 
-    def _decode_args(self, positions, block_tables, active, temps, top_ks, seeds, counts, bias, mask=None):
+    def _decode_args(self, positions, block_tables, active, temps, top_ks, seeds, counts, bias, mask=None, window=None):
         """Assemble the decode jit's argument tuple (minus the token
-        array, which the pipelined path carries device-resident)."""
+        array, which the pipelined path carries device-resident).
+        ``window``: what :meth:`advance_windows` returned for this step,
+        where the caller has made that call already."""
         context_lens = np.where(active, positions + 1, 0).astype(np.int32)
         safe_pos = np.where(active, positions, 0).astype(np.int32)
         # scratch-mask inactive slots' tables too: an inactive slot with
@@ -1614,6 +1939,10 @@ class GenerationEngine:
         # first real block and silently corrupt the surviving stream
         tables = np.where(active[:, None], block_tables, 0).astype(np.int32)
         self.sampling_steps[sampling_branch(temps, top_ks)] += 1
+        if self.window_config is None:
+            window = ()
+        else:
+            window = (self.advance_windows(safe_pos, active) if window is None else window,)
         return (
             self._dev(safe_pos),
             self.cache.k,
@@ -1630,10 +1959,47 @@ class GenerationEngine:
                 (self.max_batch_slots, self.cfg.vocab_size),
             ),
             # the slots' convolution state alone (not the blocks'
-            # snapshots, which no decode step touches), and the counters
-            {"conv": self.cache.state["conv"]} if self.state_config is not None else {},
+            # snapshots, which no decode step touches), the window
+            # layers' K/V, and the counters
+            self._step_state(),
             self.expert_counts,
+            *window,
         ), context_lens
+
+    def _step_state(self) -> Dict[str, jax.Array]:
+        """What a decode step carries (and donates) beside K/V."""
+        return {k: a for k, a in self.cache.state.items() if k != "snap"}
+
+    def advance_windows(self, positions: np.ndarray, active: np.ndarray) -> Dict[str, jax.Array]:
+        """Before a decode step is dispatched: every live sequence's
+        window table brought to its position (blocks behind the window
+        released, the block a position starts taken; span
+        ``ff.cache.window_release``), and the tables as the step takes
+        them: ``[slots, blocks_per_sequence]`` and each column 0's
+        position. Host work only: nothing waits for the device, so the
+        overlap pipeline keeps its step in flight. Once a step: the
+        scheduler calls this inside its own ``sched.schedule`` span (the
+        release is scheduling work, and is accounted there) and hands
+        the answer to :meth:`decode_async`; a sequential :meth:`decode`
+        calls it while it assembles its arguments."""
+        wc = self.window_config
+        positions = np.where(active, positions, 0)
+        tables = np.zeros((self.max_batch_slots, self.window_columns), np.int32)
+        first = np.zeros((self.max_batch_slots,), np.int32)
+        full = held = 0
+        with phase("cache.window_release"):
+            for slot in np.nonzero(active)[0]:
+                t = self.window_tables.get(int(slot))
+                if t is None:  # a caller with tables of its own, decoding from position 0
+                    t = self.window_tables[int(slot)] = WindowTable()
+                self._window_advance(t, int(positions[slot]))
+                tables[slot, : len(t.blocks)] = t.blocks
+                first[slot] = t.first * wc.block_size
+                full += int(positions[slot]) // wc.block_size + 1
+                held += len(t.blocks)
+                self.window_held_peak = max(self.window_held_peak, len(t.blocks))
+        self._live_blocks = (full, held)
+        return {"tables": self._stage("decode.wtables", tables), "first": self._stage("decode.wfirst", first)}
 
     def decode(
         self,
@@ -1727,6 +2093,7 @@ class GenerationEngine:
         counts: np.ndarray,
         tokens_dev: Optional[jax.Array] = None,
         mask: Optional[np.ndarray] = None,
+        window: Optional[Dict[str, jax.Array]] = None,
     ) -> InFlightDecode:
         """Dispatch one decode step WITHOUT blocking on it: the overlap
         pipeline's front half. Returns an :class:`InFlightDecode` whose
@@ -1744,7 +2111,9 @@ class GenerationEngine:
         still fires with the same (tokens, bias) value shape). Inactive
         slots in carry mode embed whatever garbage token the dead slot
         sampled; their writes land in scratch and their outputs are
-        dropped, exactly like the host-masked path."""
+        dropped, exactly like the host-masked path. ``window``: the
+        window tables :meth:`advance_windows` made for these positions,
+        from a caller that has advanced them already."""
         if tokens_dev is None:
             masked = np.where(active, tokens, 0).astype(np.int32)
         else:
@@ -1759,12 +2128,15 @@ class GenerationEngine:
             traces_before = self.trace_counts.get("decode", 0)
             args, context_lens = self._decode_args(
                 positions, block_tables, active, temps, top_ks, seeds,
-                counts, bias, mask,
+                counts, bias, mask, window,
             )
             tok_arg = tokens_dev if tokens_dev is not None else self._dev(masked)
             prev_k, prev_v, prev_conv = (None, None, None) if self.donate else (
                 self.cache.k, self.cache.v, self.cache.state.get("conv")
             )
+            prev_window = None
+            if self.window_config is not None and not self.donate:
+                prev_window = {k: self.cache.state[k] for k in ("wk", "wv")}
             out, ok, ck, cv, state, counts = self._decode_jit(self.params, tok_arg, *args)
         # start the device->host copies NOW; consume_decode's numpy
         # conversion then finds the bytes already resident
@@ -1777,7 +2149,7 @@ class GenerationEngine:
             out, ok, prev_k, prev_v, ck, cv, disp.t0, disp.t1,
             traced=self.trace_counts.get("decode", 0) > traces_before,
             n_active=int(active.sum()), ctx_sum=int(context_lens.sum()),
-            prev_conv=prev_conv, prev_counts=prev_counts,
+            prev_conv=prev_conv, prev_counts=prev_counts, prev_window=prev_window,
         )
 
     def rollback_decode(self, step: InFlightDecode) -> None:
@@ -1786,7 +2158,7 @@ class GenerationEngine:
         convolution state together, or the state would be one token
         ahead of the K/V it is replayed against."""
         state = {} if step.prev_conv is None else {"conv": step.prev_conv}
-        self.cache.update(step.prev_k, step.prev_v, **state)
+        self.cache.update(step.prev_k, step.prev_v, **state, **(step.prev_window or {}))
         self.expert_counts = step.prev_counts
 
     def consume_decode(self, step: InFlightDecode) -> np.ndarray:

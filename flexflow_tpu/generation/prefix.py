@@ -86,10 +86,22 @@ class PrefixEntry:
     block; the index itself keeps the entry alive at refs == 0 until
     eviction. ``children`` counts child entries (any tier) — an entry
     with children is never dropped from the trie, or its descendants
-    would become unreachable."""
+    would become unreachable.
+
+    A configuration with sliding-window layers caches a block in two
+    halves (cache.py): ``block`` in the full layers' pool and ``wblock``
+    (0: not held) in the window layers'. The window half is kept only as
+    long as something may still want it: ``wrefs`` counts the live
+    sequences whose window tables include it (a sequence drops that
+    reference as it decodes past the block, long before it drops
+    ``refs``), and at ``wrefs == 0`` the half is the first thing the
+    window pool takes back (:meth:`PrefixCache.reclaim_window`). On the
+    host tier the window half rides in ``host_s``. A prefix can be
+    resumed at a boundary only if the entries of the window behind it
+    still have their halves (:meth:`PrefixCache.has_window`)."""
 
     __slots__ = (
-        "eid", "parent_eid", "tokens", "depth", "block",
+        "eid", "parent_eid", "tokens", "depth", "block", "wblock", "wrefs",
         "host_k", "host_v", "host_s", "crc", "refs", "children", "last_touch",
     )
 
@@ -100,6 +112,8 @@ class PrefixEntry:
         self.tokens = tokens
         self.depth = depth  # block index within the prefix (0-based)
         self.block: Optional[int] = block
+        self.wblock = 0
+        self.wrefs = 0
         self.host_k: Optional[np.ndarray] = None
         self.host_v: Optional[np.ndarray] = None
         self.host_s: Optional[np.ndarray] = None
@@ -203,6 +217,10 @@ class PrefixCache:
         state_bytes_per_block: int = 0,
     ):
         self.allocator = allocator
+        # the window layers' pool, where the configuration has one (the
+        # engine sets it): entries then cache a block in two halves
+        self.window_allocator: Optional[BlockAllocator] = None
+        self.window_dropped_total = 0
         self.config = config
         self.enabled = enabled
         # what one block occupies on either tier: its K/V and, for a
@@ -314,6 +332,11 @@ class PrefixCache:
                 matched += bs
                 parent = entry.eid
         return min(matched, len(prompt) - 1)
+
+    @staticmethod
+    def has_window(entry: PrefixEntry) -> bool:
+        """The entry still has its window half, on either tier."""
+        return bool(entry.wblock) if entry.resident else entry.host_s is not None
 
     # ------------------------------------------------------------ refcounts
     def acquire(self, entries: Sequence[PrefixEntry]) -> None:
@@ -457,8 +480,9 @@ class PrefixCache:
                 <= self.host_budget_bytes
             ):
                 try:
-                    # (K, V) or, where blocks carry one, (K, V, state)
-                    hk, hv, *hs = read_block(victim.block)
+                    # (K, V) or, where blocks carry one, (K, V, state);
+                    # a window half held is read out with its block
+                    hk, hv, *hs = read_block(victim.block, victim.wblock) if victim.wblock else read_block(victim.block)
                     with self._lock:
                         victim.host_k = np.asarray(hk)
                         victim.host_v = np.asarray(hv)
@@ -471,6 +495,7 @@ class PrefixCache:
                     offloaded = False  # failed swap-out: drop instead
             with self._lock:
                 block, victim.block = victim.block, None
+                wblock, victim.wblock = victim.wblock, 0
                 if not offloaded:
                     # dropped: no tier holds the content, so the node
                     # leaves the trie. Descendants are orphaned (the
@@ -481,9 +506,30 @@ class PrefixCache:
                     self._remove(victim)
                 self.evicted_total += 1
             self.allocator.free([block])
+            if wblock:
+                self.window_allocator.free([wblock])
             freed += 1
         self._enforce_host_budget()
         return freed
+
+    def reclaim_window(self, n_blocks: int) -> int:
+        """Free up to ``n_blocks`` blocks of the WINDOW pool by dropping
+        the window halves no live sequence's table includes (``wrefs ==
+        0``), LRU by last touch. The entry stays, with its full half: it
+        still serves as the history of a longer prefix, and can be
+        resumed FROM only while the halves of the window behind the
+        boundary are held. Nothing is read out: a half alone has no
+        place on the host tier."""
+        with self._lock:
+            cands = [e for e in self._by_id.values() if e.resident and e.wblock and e.wrefs == 0]
+            cands.sort(key=lambda e: (e.last_touch, -e.depth))
+            blocks = []
+            for victim in cands[:max(0, n_blocks)]:
+                blocks.append(victim.wblock)
+                victim.wblock = 0
+            self.window_dropped_total += len(blocks)
+        self.window_allocator.free(blocks)
+        return len(blocks)
 
     def _enforce_host_budget(self) -> None:
         """Drop LRU offloaded leaves until the host tier fits its
@@ -529,7 +575,7 @@ class PrefixCache:
             return None
         return (hk, hv) if hs is None else (hk, hv, hs)
 
-    def note_swapped_in(self, entry: PrefixEntry, block: int) -> None:
+    def note_swapped_in(self, entry: PrefixEntry, block: int, wblock: int = 0) -> None:
         """The entry's content was written into device ``block``: it is
         resident again; the host copy is retained only if budget is
         slack (re-offload is then free) — dropped here for simplicity
@@ -537,6 +583,7 @@ class PrefixCache:
         with self._lock:
             self._drop_host(entry)
             entry.block = block
+            entry.wblock = wblock
             entry.last_touch = self.clock()
             self.swaps_in_total += 1
 
@@ -583,6 +630,7 @@ class PrefixCache:
             "recompute_fallbacks": self.recompute_fallbacks,
             "registered_total": self.registered_total,
             "evicted_total": self.evicted_total,
+            **({"window_dropped_total": self.window_dropped_total} if self.window_allocator is not None else {}),
         }
 
     def tier_residency(self) -> List[Dict]:

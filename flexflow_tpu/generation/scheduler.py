@@ -575,11 +575,11 @@ class ContinuousBatchingScheduler:
         self.stats.add_gauge("tokens_generated", lambda: self.token_rate.total)
         self.stats.add_gauge("tokens_per_s", self.token_rate.rate)
         self.stats.add_gauge("preemptions", lambda: self.preemptions)
-        self.stats.add_gauge(
-            "cache_blocks_used",
-            lambda: self.engine.allocator.num_total - self.engine.allocator.num_free,
-        )
-        self.stats.add_gauge("cache_blocks_total", lambda: self.engine.allocator.num_total)
+        # (of the pool that is fuller, where the window layers have one
+        # of their own: engine.blocks_in_use; the `cache` section of
+        # /v2/stats has both)
+        self.stats.add_gauge("cache_blocks_used", lambda: self.engine.blocks_in_use()[0])
+        self.stats.add_gauge("cache_blocks_total", lambda: self.engine.blocks_in_use()[1])
         # mesh-native serving (ISSUE 15): mesh geometry + the per-shard
         # cache view — each device holds H/tp heads of every block, so
         # the per-shard byte load is total / tp_degree
@@ -670,6 +670,8 @@ class ContinuousBatchingScheduler:
             self.stats.add_section("experts", engine.expert_stats)
         if engine.state_config is not None:
             self.stats.add_section("conv_state", engine.conv_state_stats)
+        if engine.window_config is not None:
+            self.stats.add_section("cache", engine.cache_stats)
         self.spec_stats = SpeculationStats()
         self.spec_stats.register_gauges(self.stats)
         # capacity & compute observability (obs/capacity.py, obs/slo.py):
@@ -1466,6 +1468,7 @@ class ContinuousBatchingScheduler:
             [b for i, b in enumerate(state.blocks) if i not in state.shared_idx]
         )
         self.engine.prefix_cache.release(state.shared_entries)
+        self.engine.release_slot(state.slot)  # its blocks of the window layers' pool, where there is one
         state.blocks = []
         state.shared_idx = set()
         state.shared_entries = []
@@ -1760,7 +1763,7 @@ class ContinuousBatchingScheduler:
         # heartbeat covers them like any other step
         with self._phase("sched.prefix_plan", request=req.id) as p_prep:
             with self._stamped():
-                prep = self.engine.prepare_prefix(req.prompt, plan, blocks)
+                prep = self.engine.prepare_prefix(req.prompt, plan, blocks, slot=slot)
         with self._phase("sched.admit", request=req.id):
             if prep is None:
                 # a mid-assembly swap-in fallback could not replace the
@@ -1798,7 +1801,7 @@ class ContinuousBatchingScheduler:
         except Exception as e:
             self._admitting = None
             self._admitting_blocks = None
-            self.engine.release_admission(table, shared_idx, entries)
+            self.engine.release_admission(table, shared_idx, entries, slot=slot)
             self._free_slots.append(slot)
             if self.supervisor.failed:
                 # half-open probe against a still-dead engine: a HELD
@@ -1831,7 +1834,7 @@ class ContinuousBatchingScheduler:
                 # a single-sequence step needs no bisection to assign blame
                 self._admitting = None
                 self._admitting_blocks = None
-                self.engine.release_admission(table, shared_idx, entries)
+                self.engine.release_admission(table, shared_idx, entries, slot=slot)
                 self._free_slots.append(slot)
                 err = PoisonedRequestError(
                     f"request {req.id} produced non-finite logits at prefill",
@@ -1851,7 +1854,7 @@ class ContinuousBatchingScheduler:
             # shared content another request could reuse (reuse telemetry
             # also counts here, so failed admissions never inflate it)
             self.engine.register_prefix(
-                req.prompt, table, shared_idx, entries, prefix_len=prefix_len
+                req.prompt, table, shared_idx, entries, prefix_len=prefix_len, slot=slot
             )
             state = _Running(
                 req, slot, table, cached_len=len(req.prompt),
@@ -2542,11 +2545,18 @@ class ContinuousBatchingScheduler:
         seq0 = prev.seq0 if prev is not None else self._hb_seq
         self._hb_seq += 1
         seq = self._hb_seq
+        window = None
+        if self.engine.window_config is not None:
+            # the window layers' blocks behind each sequence's window go
+            # back, and the block a position starts is taken, with the
+            # predecessor in flight: scheduling work, in its span
+            with self._phase("sched.schedule"):
+                window = self.engine.advance_windows(positions, active)
         self._heartbeat = (seq, self.clock())  # dispatch stamp
         try:
             handle = self.engine.decode_async(
                 tokens_host, positions, tables, active, temps, top_ks,
-                seeds, counts, tokens_dev=tokens_dev,
+                seeds, counts, tokens_dev=tokens_dev, window=window,
             )
         except Exception:
             self._heartbeat = hb_prev  # the step never went in flight

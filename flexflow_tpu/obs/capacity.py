@@ -329,6 +329,11 @@ class ServingFlops:
         self.param_count = 2 * v * e + l * (4 * e * e + 2 * e * f)
         self.param_bytes = self.param_count * self.dtype_bytes
         self.kv_bytes_per_pos = 2 * l * e * self.dtype_bytes  # k + v
+        # sliding-window layers (from_config): what they add per (token,
+        # position of the `window` behind it), beside the full layers' above
+        self.window = 0
+        self.window_ctx_flops = 0
+        self.window_kv_bytes_per_pos = 0
 
     @classmethod
     def from_config(cls, cfg, dtype: DataType = DataType.FLOAT, chip=None) -> "ServingFlops":
@@ -339,7 +344,9 @@ class ServingFlops:
         layer really has (of the experts, the ``experts_per_token`` a
         token is routed to), every weight's bytes (a decode step of a
         full batch reads every expert), K/V for the attention layers
-        alone."""
+        alone, and by kind of attention layer: a full layer attends a
+        token's whole context, a sliding-window layer the ``window``
+        positions behind it (``windowed``)."""
         model = cls(
             num_layers=cfg.num_layers,
             hidden_size=cfg.hidden_size,
@@ -352,11 +359,14 @@ class ServingFlops:
             return model
         e, v = cfg.hidden_size, cfg.vocab_size
         q, kv = cfg.num_heads * cfg.dim_per_head, cfg.kv_heads * cfg.dim_per_head
-        flops, params, n_attn = 2 * e * v, v * e * (1 if cfg.tied_head else 2), 0
+        flops, params, n_attn, n_window = 2 * e * v, v * e * (1 if cfg.tied_head else 2), 0, 0
         for l in range(cfg.num_layers):
             if cfg.operator(l) == "attention":
                 op = 2 * e * q + 2 * e * kv
                 n_attn += 1
+            elif cfg.operator(l) == "window":
+                op = 2 * e * q + 2 * e * kv
+                n_window += 1
             else:
                 op = 4 * e * e + cfg.conv_kernel * e
             kind = cfg.ffn_kind(l)
@@ -373,37 +383,52 @@ class ServingFlops:
         model.param_count = params
         model.param_bytes = params * model.dtype_bytes
         model.kv_bytes_per_pos = 2 * n_attn * kv * model.dtype_bytes
+        model.window = getattr(cfg, "window", 0) if n_window else 0
+        model.window_ctx_flops = n_window * 4 * q
+        model.window_kv_bytes_per_pos = 2 * n_window * kv * model.dtype_bytes
         return model
+
+    def windowed(self, n_tokens: int, context_sum: int) -> int:
+        """Positions the window layers attend for ``n_tokens`` tokens
+        whose contexts sum to ``context_sum``: no token more than the
+        window (an upper estimate where some contexts are shorter)."""
+        return min(context_sum, n_tokens * self.window) if self.window else 0
 
     def prefill_flops(self, prompt_len: int) -> float:
         """One prompt of ``prompt_len`` true tokens (bucket padding is
         not useful work); causal context sum = n(n+1)/2."""
         n = max(0, prompt_len)
-        return n * self.per_token_flops + self.per_ctx_flops * (n * (n + 1) // 2)
+        w = min(n, self.window)
+        in_window = w * (w + 1) // 2 + (n - w) * w  # sum over positions of min(position + 1, window)
+        return n * self.per_token_flops + self.per_ctx_flops * (n * (n + 1) // 2) + self.window_ctx_flops * in_window
 
     def decode_flops(self, n_active: int, context_sum: int) -> float:
         """One decode step: ``n_active`` live tokens attending to
         ``context_sum`` total live context positions."""
-        return n_active * self.per_token_flops + self.per_ctx_flops * context_sum
+        return (n_active * self.per_token_flops + self.per_ctx_flops * context_sum
+                + self.window_ctx_flops * self.windowed(n_active, context_sum))
 
     def verify_flops(self, n_tokens: int, context_sum: int) -> float:
         """One verify step: ``n_tokens`` live window tokens (committed +
         drafts across slots) with ``context_sum`` live attended
         positions (window token j at position p attends to p+1)."""
-        return n_tokens * self.per_token_flops + self.per_ctx_flops * context_sum
+        return (n_tokens * self.per_token_flops + self.per_ctx_flops * context_sum
+                + self.window_ctx_flops * self.windowed(n_tokens, context_sum))
 
     # ------------------------------------------ predicted step time (truth)
     def prefill_bytes(self, prompt_len: int) -> float:
         n = max(0, prompt_len)
-        return self.param_bytes + self.kv_bytes_per_pos * n
+        return self.param_bytes + (self.kv_bytes_per_pos + self.window_kv_bytes_per_pos) * n
 
     def decode_bytes(self, n_active: int, context_sum: int) -> float:
         """HBM bytes for one decode step: weights once, KV read per live
         context position, KV write per active token."""
-        return self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_active)
+        return (self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_active)
+                + self.window_kv_bytes_per_pos * (self.windowed(n_active, context_sum) + n_active))
 
     def verify_bytes(self, n_tokens: int, context_sum: int) -> float:
-        return self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_tokens)
+        return (self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_tokens)
+                + self.window_kv_bytes_per_pos * (self.windowed(n_tokens, context_sum) + n_tokens))
 
     def roofline_s(self, flops: float, bytes_hbm: float) -> float:
         """The search cost model's roofline applied to one serving step

@@ -257,6 +257,16 @@ def _paged_kernel_tp(kernel, backend, mesh, head_axis, num_heads, head_dim, cach
     return tp
 
 
+def _windowed(tp: int, kernel, reference):
+    """The lowering of a sliding-window layer's paged call: the kernel
+    on one device, the XLA composition where the kernel is refused. The
+    head-sharded kernel does not carry the window (the engine refuses
+    ``tp_degree > 1`` for such a configuration by name)."""
+    if tp > 1:
+        raise NotImplementedError("the head-sharded paged kernel carries no attention window")
+    return kernel if tp == 1 else reference
+
+
 def decode_attention_core(
     q: jax.Array,
     k_cache: jax.Array,
@@ -268,6 +278,8 @@ def decode_attention_core(
     scale: Optional[float] = None,
     mesh=None,
     head_axis: str = "model",
+    window: int = 0,
+    first_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Decode-mode attention: one query token per sequence ([B, H, D])
     over static ``layer`` of the whole block-structured KV cache
@@ -282,11 +294,21 @@ def decode_attention_core(
     selects the HEAD-SHARDED kernel path (ISSUE 15): each shard's kernel
     runs over its local KV heads via shard_map; the reference path needs
     no mesh plumb — GSPMD partitions the plain-XLA composition itself.
+
+    ``window`` > 0 is a sliding-window layer's call: ``k_cache`` /
+    ``v_cache`` are the window layers' arrays, ``block_tables`` holds the
+    columns a sequence keeps and ``first_positions`` [B] the cache
+    position of column 0 (kernels/decode_attention.py).
     """
     tp = _paged_kernel_tp(
         "paged_decode_attention", backend, mesh, head_axis,
         q.shape[1], q.shape[2], k_cache, 1,
     )
+    if window:
+        return _windowed(tp, paged_decode_attention, reference_paged_attention)(
+            q, k_cache, v_cache, layer, block_tables, context_lens, scale=scale,
+            window=window, first_positions=first_positions,
+        )
     if tp > 1:
         return sharded_paged_decode_attention(
             q, k_cache, v_cache, layer, block_tables, context_lens,
@@ -312,6 +334,8 @@ def append_attention_core(
     scale: Optional[float] = None,
     mesh=None,
     head_axis: str = "model",
+    window: int = 0,
+    first_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Chunked-append attention: a W-token window per sequence
     ([B, W, H, D], K/V already written) over static ``layer`` of the
@@ -324,11 +348,17 @@ def append_attention_core(
 
     Dispatch as in :func:`decode_attention_core`; windows past the
     kernel's bound (suffix-prefill buckets) take the XLA composition.
+    ``window`` / ``first_positions`` as in :func:`decode_attention_core`.
     """
     tp = _paged_kernel_tp(
         "paged_append_attention", backend, mesh, head_axis,
         q.shape[2], q.shape[3], k_cache, q.shape[1],
     )
+    if window:
+        return _windowed(tp, paged_append_attention, reference_paged_append_attention)(
+            q, k_cache, v_cache, layer, block_tables, q_positions, scale=scale,
+            window=window, first_positions=first_positions,
+        )
     if tp > 1:
         return sharded_paged_append_attention(
             q, k_cache, v_cache, layer, block_tables, q_positions,
@@ -343,15 +373,23 @@ def append_attention_core(
     )
 
 
-def masked_attention(q, k, v, lengths, causal=True, scale=None):
+def _behind_window(sq: int, sk: int, window: int):
+    """[Sq, Sk] bool: key ``s`` lies within the ``window`` positions up
+    to query ``t`` (``s > t - window``; the causal mask holds ``s <= t``)."""
+    return jnp.triu(jnp.ones((sq, sk), bool), k=sk - sq - window + 1)
+
+
+def masked_attention(q, k, v, lengths, causal=True, scale=None, window=0):
     """Causal attention over [B, S, H, D] with a per-sequence valid
     length: key positions >= lengths[b] are masked. The prefill side of
     the decode split — bucketed (padded) prompts attend only over their
-    real tokens, so prefill logits match the unpadded forward."""
+    real tokens, so prefill logits match the unpadded forward.
+    ``window`` > 0 (a sliding-window layer): a query attends only the
+    ``window`` positions up to its own."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if q.shape[2] != k.shape[2]:
-        return _grouped_masked_attention(q, k, v, lengths, causal, scale)
+        return _grouped_masked_attention(q, k, v, lengths, causal, scale, window)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     sq, sk = logits.shape[-2], logits.shape[-1]
     mask = jnp.arange(sk)[None, :] < lengths[:, None]  # [B, Sk]
@@ -360,6 +398,8 @@ def masked_attention(q, k, v, lengths, causal=True, scale=None):
         mask = jnp.logical_and(
             mask, jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)[None, None]
         )
+    if window:
+        mask = jnp.logical_and(mask, _behind_window(sq, sk, window)[None, None])
     logits = jnp.where(mask, logits, -jnp.inf)
     m = jnp.max(logits, axis=-1, keepdims=True)
     # fully-masked rows (padding queries) get uniform-zero probs, not NaN
@@ -368,7 +408,7 @@ def masked_attention(q, k, v, lengths, causal=True, scale=None):
     return jnp.einsum("bhqk,bkhd->bqhd", (p / l).astype(v.dtype), v)
 
 
-def _grouped_masked_attention(q, k, v, lengths, causal, scale):
+def _grouped_masked_attention(q, k, v, lengths, causal, scale, window=0):
     """:func:`masked_attention` with grouped queries: q [B, S, H, D] over
     k/v [B, S, Hkv, D], query head ``i`` reading K/V head ``i // (H //
     Hkv)``. K and V are never repeated: the group is an axis of q."""
@@ -379,6 +419,8 @@ def _grouped_masked_attention(q, k, v, lengths, causal, scale):
     mask = (jnp.arange(sk)[None, :] < lengths[:, None])[:, None, None, None, :]
     if causal:
         mask = jnp.logical_and(mask, jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)[None, None, None])
+    if window:
+        mask = jnp.logical_and(mask, _behind_window(sq, sk, window)[None, None, None])
     logits = jnp.where(mask, logits, -jnp.inf)
     m = jnp.max(logits, axis=-1, keepdims=True)
     p = jnp.where(mask, jnp.exp(logits - jnp.maximum(m, -1e30)), 0.0)

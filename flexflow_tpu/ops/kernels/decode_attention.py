@@ -51,6 +51,20 @@ DMA'd ONCE for all the query heads that read it, each walked as one
 more window query at the same position; LFM2-8B-A1B's 32 query heads
 over 8 K/V heads of 64 are a window of 4 over rows ``[4, 128]``.
 
+**A window** (PR 31). A sliding-window layer's call (``window`` > 0)
+adds a lower bound a query (it attends the ``window`` positions up to
+its own) and takes a table that starts at the sequence's first HELD
+block, with the cache position of column 0 a sequence
+(``first_positions``; generation/cache.py releases the blocks behind the
+window). Both lowerings take the two arguments. The kernel prefetches
+``first_positions`` and the least position any query of the window still
+attends as two further scalars, skips a column wholly behind that as it
+skips one past every query, and runs as a Pallas call of its own name,
+``paged_window_attention`` (a device trace carries no scope path: a
+reader finds a kernel by its name). The grid is ``(batch, table
+columns)`` (:func:`paged_append_attention`), so such a call walks the
+columns the window holds, not the history's.
+
 Two lowerings:
 
 * :func:`reference_paged_append_attention` — gather the table'd blocks
@@ -128,6 +142,8 @@ def reference_paged_append_attention(
     block_tables: jax.Array,
     q_positions: jax.Array,
     scale: Optional[float] = None,
+    window: int = 0,
+    first_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Masked window attention over gathered cache blocks, in plain XLA.
 
@@ -140,6 +156,13 @@ def reference_paged_append_attention(
     cache positions ``<= q_positions[b, w]`` (its own history including
     itself); ``q_positions[b, w] < 0`` marks a padding query, which
     produces zeros, not NaN. Returns [B, W, H, D].
+
+    ``window`` > 0 (a sliding-window layer) adds a lower bound a query:
+    only positions ``> q_positions[b, w] - window`` are attended, and
+    ``first_positions`` [B] is the cache position of each table's column
+    0 (a multiple of the block size: such a table starts at the
+    sequence's first HELD block, the blocks behind the window having
+    been released; generation/cache.py).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -151,7 +174,7 @@ def reference_paged_append_attention(
         # the K/V heads (query head i reads K/V head i // group)
         out = reference_paged_append_attention(
             _fold_group(q, group), k_cache, v_cache, layer, block_tables,
-            jnp.repeat(q_positions, group, axis=1), scale,
+            jnp.repeat(q_positions, group, axis=1), scale, window, first_positions,
         )
         return _unfold_group(out, group)
     # one gather out of the whole cache: [B, max_blocks, bs, R, LW] -> [B, S_max, H, D]
@@ -159,7 +182,11 @@ def reference_paged_append_attention(
     v = v_cache[layer, block_tables].reshape(b, max_blocks * bs, *q.shape[2:])
     s = jnp.einsum("bwhd,bkhd->bhwk", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     pos = jnp.arange(max_blocks * bs)[None, None, None, :]  # key positions
+    if first_positions is not None:
+        pos = pos + first_positions[:, None, None, None]
     valid = pos <= q_positions[:, None, :, None]  # [B, 1, W, S_max]
+    if window:
+        valid = valid & (pos > q_positions[:, None, :, None] - window)
     s = jnp.where(valid, s, NEG_INF)
     # max over an all-masked row is NEG_INF; subtracting keeps exp at 1
     # on masked lanes, so zero the probabilities explicitly instead of
@@ -179,13 +206,16 @@ def reference_paged_attention(
     block_tables: jax.Array,
     context_lens: jax.Array,
     scale: Optional[float] = None,
+    window: int = 0,
+    first_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """One-token (decode) form: q [B, H, D], context_lens [B] int32 (the
     number of valid cache positions INCLUDING the current token's
     already-written K/V; 0 marks an inactive slot). The W = 1 special
     case of :func:`reference_paged_append_attention`."""
     out = reference_paged_append_attention(
-        q[:, None], k_cache, v_cache, layer, block_tables, context_lens[:, None] - 1, scale
+        q[:, None], k_cache, v_cache, layer, block_tables, context_lens[:, None] - 1, scale,
+        window, first_positions,
     )
     return out[:, 0]
 
@@ -225,10 +255,11 @@ def _head_scores(prod, head_dim):
 
 
 def _accumulate_block(
-    qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, b, block_start, *, scale, head_dim
+    qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, b, block_start, *, scale, head_dim, window=0
 ):
     """Fold one cache block into every window query's online-softmax
-    state (m/l [W, R, LW or 1], acc [W, R, LW])."""
+    state (m/l [W, R, LW or 1], acc [W, R, LW]). ``window`` > 0: a query
+    attends only the ``window`` positions up to its own."""
     k = k_ref[:].astype(jnp.float32)  # [bs, R, LW]
     v = v_ref[:].astype(jnp.float32)
     pos = block_start + jax.lax.broadcasted_iota(
@@ -240,6 +271,8 @@ def _accumulate_block(
         q = q_ref[w].astype(jnp.float32) * scale  # [R, LW]
         s = _head_scores(k * q[None], head_dim)  # [bs, R, LW or 1]
         valid = pos <= qp  # causal-within-window + history
+        if window:
+            valid = jnp.logical_and(valid, pos > qp - window)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_ref[w]  # [R, LW or 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
@@ -259,6 +292,29 @@ def _init_state(m_ref, l_ref, acc_ref):
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
 
+def _block_span(col, b, maxpos_ref, bounds, block_size):
+    """Where table column ``col`` of sequence ``b`` starts in the cache,
+    and whether any of the window's queries can see it. ``bounds`` (the
+    windowed call's two further scalar-prefetched refs): ``minlow`` [B],
+    the least position any query of the window still attends, and
+    ``first`` [B], the position of column 0."""
+    if bounds is None:
+        start = col * block_size
+        return start, start <= maxpos_ref[b]
+    minlow_ref, first_ref = bounds
+    start = first_ref[b] + col * block_size
+    return start, jnp.logical_and(start <= maxpos_ref[b], start + block_size > minlow_ref[b])
+
+
+def _with_bounds(kernel):
+    """``kernel`` behind the windowed call's five scalar-prefetched refs
+    (tables, positions, max position, least attended position, first
+    position): the last two reach it as ``bounds``."""
+    def windowed(bt_ref, qpos_ref, maxpos_ref, minlow_ref, first_ref, *refs, **static):
+        return kernel(bt_ref, qpos_ref, maxpos_ref, *refs, bounds=(minlow_ref, first_ref), **static)
+    return windowed
+
+
 def _append_kernel(
     bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
     qpos_ref,  # scalar-prefetch: [B, W] per-query cache positions (-1 = pad)
@@ -274,6 +330,8 @@ def _append_kernel(
     scale,
     block_size,
     head_dim,
+    window=0,
+    bounds=None,
 ):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -282,13 +340,15 @@ def _append_kernel(
     def _init():
         _init_state(m_ref, l_ref, acc_ref)
 
+    start, live = _block_span(j, b, maxpos_ref, bounds, block_size)
+
     # whole block past every query's position: nothing to accumulate
     # (its DMA read the scratch block; the data is ignored)
-    @pl.when(j * block_size <= maxpos_ref[b])
+    @pl.when(live)
     def _accum():
         _accumulate_block(
             qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-            b, j * block_size, scale=scale, head_dim=head_dim,
+            b, start, scale=scale, head_dim=head_dim, window=window,
         )
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -318,6 +378,8 @@ def _append_kernel_split(
     head_dim,
     blocks_per_split,
     max_blocks,
+    window=0,
+    bounds=None,
 ):
     """Split-KV (flash-decoding) variant of :func:`_append_kernel`: the
     grid gains a KV-split axis, each split accumulates online-softmax
@@ -337,11 +399,13 @@ def _append_kernel_split(
     # skip padding grid steps (the split axis may overshoot the table;
     # their DMA re-read a clamped block — the data is ignored) and
     # whole blocks past every query's position
-    @pl.when(jnp.logical_and(jj < max_blocks, jj * block_size <= maxpos_ref[b]))
+    start, live = _block_span(jj, b, maxpos_ref, bounds, block_size)
+
+    @pl.when(jnp.logical_and(jj < max_blocks, live))
     def _accum():
         _accumulate_block(
             qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-            b, jj * block_size, scale=scale, head_dim=head_dim,
+            b, start, scale=scale, head_dim=head_dim, window=window,
         )
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -384,6 +448,8 @@ def paged_append_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
     kv_splits: int = 1,
+    window: int = 0,
+    first_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Pallas paged chunked-append attention (shapes as in
     :func:`reference_paged_append_attention`). Compiled by Mosaic; only
@@ -393,7 +459,17 @@ def paged_append_attention(
     into ``kv_splits`` independent slices whose partial softmaxes
     recombine exactly — parallelism across the KV length for
     long-context, small-batch decode, where the sequential block grid
-    otherwise serializes the whole chip on one sequence's history."""
+    otherwise serializes the whole chip on one sequence's history.
+
+    ``window`` > 0 is the sliding-window layer's call, a Pallas call of
+    its own name (``paged_window_attention``: a device trace finds
+    kernels by name): ``first_positions`` [B], the cache position of
+    each table's column 0, and the least position any query of a window
+    still attends are scalar-prefetched beside the tables, a query masks
+    what lies ``window`` or more positions behind it, and a column wholly
+    behind every query's window is skipped as one past its position is.
+    The table holds only the columns a sequence keeps (generation/
+    cache.py), so the grid walks the window and not the history."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     group = query_group(q.shape[2], q.shape[3], k_cache.shape[3:])
@@ -403,7 +479,7 @@ def paged_append_attention(
         out = paged_append_attention(
             _fold_group(q, group), k_cache, v_cache, layer, block_tables,
             jnp.repeat(q_positions, group, axis=1), scale=scale, interpret=interpret,
-            kv_splits=kv_splits,
+            kv_splits=kv_splits, window=window, first_positions=first_positions,
         )
         return _unfold_group(out, group)
     b, w, h, d = q.shape
@@ -417,6 +493,14 @@ def paged_append_attention(
     block_tables = block_tables.astype(jnp.int32)
     q_positions = q_positions.astype(jnp.int32)
     prefetch = (block_tables, q_positions, jnp.max(q_positions, axis=1))
+    name, static, behind = "paged_append_attention", {}, (lambda kernel: kernel)
+    if window:
+        # a padding query (-1) attends nothing: it must not hold the
+        # window's lower edge down
+        low = jnp.where(q_positions >= 0, q_positions - (window - 1), jnp.iinfo(jnp.int32).max)
+        prefetch += (jnp.min(low, axis=1), first_positions.astype(jnp.int32))
+        name, static, behind = "paged_window_attention", {"window": int(window)}, _with_bounds
+    n_prefetch = len(prefetch)
     scratch_shapes = [
         pltpu.VMEM((w, r, sw), jnp.float32),
         pltpu.VMEM((w, r, sw), jnp.float32),
@@ -425,17 +509,17 @@ def paged_append_attention(
     if kv_splits > 1:
         bps = -(-max_blocks // kv_splits)  # blocks per split (ceil)
 
-        def kv_map(i, s, j, bt, qp, mp):
+        def kv_map(i, s, j, bt, *_):
             return (layer, bt[i, jnp.minimum(s * bps + j, max_blocks - 1)], 0, 0, 0)
 
-        def out_map(i, s, j, bt, qp, mp):
+        def out_map(i, s, j, *_):
             return (i, s, 0, 0, 0)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=n_prefetch,
             grid=(b, kv_splits, bps),
             in_specs=[
-                pl.BlockSpec((None, w, r, lw), lambda i, s, j, bt, qp, mp: (i, 0, 0, 0)),
+                pl.BlockSpec((None, w, r, lw), lambda i, s, j, *_: (i, 0, 0, 0)),
                 pl.BlockSpec((None, None, block_size, r, lw), kv_map),
                 pl.BlockSpec((None, None, block_size, r, lw), kv_map),
             ],
@@ -447,8 +531,8 @@ def paged_append_attention(
             scratch_shapes=scratch_shapes,
         )
         kernel = functools.partial(
-            _append_kernel_split, scale=float(scale), block_size=block_size,
-            head_dim=d, blocks_per_split=bps, max_blocks=max_blocks,
+            behind(_append_kernel_split), scale=float(scale), block_size=block_size,
+            head_dim=d, blocks_per_split=bps, max_blocks=max_blocks, **static,
         )
         acc, m, l = pl.pallas_call(
             kernel,
@@ -459,33 +543,33 @@ def paged_append_attention(
                 jax.ShapeDtypeStruct((b, kv_splits, w, r, sw), jnp.float32),
             ],
             interpret=interpret,
-            name="paged_append_attention_split",
+            name=name + "_split",
         )(*prefetch, q, k_cache, v_cache)
         return _combine_splits(acc, m, l, q_positions, out_dtype).reshape(b, w, h, d)
 
-    def kv_map(i, j, bt, qp, mp):
+    def kv_map(i, j, bt, *_):
         return (layer, bt[i, j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=n_prefetch,
         grid=(b, max_blocks),
         in_specs=[
-            pl.BlockSpec((None, w, r, lw), lambda i, j, bt, qp, mp: (i, 0, 0, 0)),
+            pl.BlockSpec((None, w, r, lw), lambda i, j, *_: (i, 0, 0, 0)),
             pl.BlockSpec((None, None, block_size, r, lw), kv_map),
             pl.BlockSpec((None, None, block_size, r, lw), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, w, r, lw), lambda i, j, bt, qp, mp: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, w, r, lw), lambda i, j, *_: (i, 0, 0, 0)),
         scratch_shapes=scratch_shapes,
     )
     kernel = functools.partial(
-        _append_kernel, scale=float(scale), block_size=block_size, head_dim=d
+        behind(_append_kernel), scale=float(scale), block_size=block_size, head_dim=d, **static
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, w, r, lw), out_dtype),
         interpret=interpret,
-        name="paged_append_attention",
+        name=name,
     )(*prefetch, q, k_cache, v_cache).reshape(b, w, h, d)
 
 
@@ -518,6 +602,8 @@ def paged_decode_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
     kv_splits: Optional[int] = None,
+    window: int = 0,
+    first_positions: Optional[jax.Array] = None,
 ) -> jax.Array:
     """One-token (decode) form of :func:`paged_append_attention`
     (shapes as in :func:`reference_paged_attention`). ``kv_splits``
@@ -525,6 +611,7 @@ def paged_decode_attention(
     flash-decoding path for long-context single/dual-stream decode."""
     if kv_splits is None:
         kv_splits = default_kv_splits(q.shape[0], block_tables.shape[1])
+    bounds = {"window": window, "first_positions": first_positions} if window else {}
     out = paged_append_attention(
         q[:, None],
         k_cache,
@@ -535,6 +622,7 @@ def paged_decode_attention(
         scale=scale,
         interpret=interpret,
         kv_splits=kv_splits,
+        **bounds,
     )
     return out[:, 0]
 
