@@ -13,9 +13,13 @@ What the default run does, at 24 layers / hidden 1024 / 16 heads of 64 /
 FFN 4096 (BERT-Large read as an encoder, GPT-2-medium read as a causal
 decoder), weights random from a seed:
 
-* kernels — the paged decode/append kernel (W = 1, 5, 32; kv_splits 1
-  and 4) and the flash kernel (seq 512, forward and both backward
-  kernels) against their plain-XLA references, compiled by Mosaic;
+* kernels — the paged decode/append kernel at group 1 (float32 rows of
+  8 x 128; W = 1, 5, 32; kv_splits 1 and 4) and at the grouped-query
+  cells' own decode calls (``GROUPED_CALLS``: LFM2's and Mellum2's full
+  and windowed, bfloat16, against the XLA composition in the stated
+  arithmetic, error and milliseconds a call logged), and the flash
+  kernel (seq 512, forward and both backward kernels) against their
+  plain-XLA references, compiled by Mosaic;
 * server — ``GenerationEngine`` (8 slots, block 16, 1,024 positions,
   vocabulary 50,257, float32) behind ``InferenceServer``: the program
   family is warmed through ``engine.generate`` BEFORE the scheduler
@@ -115,6 +119,137 @@ def mosaic_calls(fn, *args) -> int:
 
 # ---------------------------------------------------------------- kernels
 
+# The decode calls of the benchmark's grouped-query cells, at the cells'
+# sizes (benchmark/workloads/lfm2-8b-a1b.gen-batch.json, mellum2-12b.code-gen.json):
+# query heads over K/V heads of ``head_dim``, bfloat16, ``slots`` rows over
+# ``columns`` table columns of ``block`` positions, contexts drawn from
+# ``contexts``. Mellum2's window layers call with a table that starts at
+# the first block a sequence still holds.
+GROUPED_CALLS = {
+    "lfm2": dict(heads=32, kv_heads=8, head_dim=64, block=16, slots=64, columns=64,
+                 layers=4, contexts=(64, 1000), window=0),
+    "mellum2_full": dict(heads=32, kv_heads=4, head_dim=128, block=64, slots=48, columns=48,
+                         layers=3, contexts=(1100, 2700), window=0),
+    "mellum2_window": dict(heads=32, kv_heads=4, head_dim=128, block=64, slots=48, columns=17,
+                           layers=9, contexts=(1100, 2700), window=1024),
+}
+
+
+def grouped_call(name: str, seed: int):
+    """The arguments of one of ``GROUPED_CALLS`` as the engine's decode
+    step would pass them: ``(q, k_cache, v_cache, layer, tables,
+    context_lens, first_positions)``. Row 0 is inactive (context 0), row
+    1 ends one position into a block."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ops.kernels.decode_attention import cache_row_shape
+
+    c = GROUPED_CALLS[name]
+    rs = np.random.RandomState(seed)
+    b, bs, cols = c["slots"], c["block"], c["columns"]
+    nb = b * cols + 1
+    shape = (c["layers"], nb, bs, *cache_row_shape(c["kv_heads"], c["head_dim"]))
+    kq, kk, kv = jax.random.split(jax.random.key(seed), 3)
+    k_cache = jax.random.normal(kk, shape, jnp.bfloat16)
+    v_cache = jax.random.normal(kv, shape, jnp.bfloat16)
+    q = jax.random.normal(kq, (b, c["heads"], c["head_dim"]), jnp.bfloat16)
+    ctx = rs.randint(*c["contexts"], size=b)
+    ctx[0], ctx[1] = 0, (ctx[1] // bs) * bs + 1
+    first = np.zeros(b, np.int64)
+    if c["window"]:
+        # the blocks wholly behind the window of the LAST position are released
+        first = np.maximum(ctx - c["window"], 0) // bs * bs
+        check(int(np.max((ctx - 1 - first) // bs)) < cols, f"{name}: a window past its table")
+    tables = jnp.asarray(1 + rs.permutation(nb - 1).reshape(b, cols), jnp.int32)
+    return (q, k_cache, v_cache, c["layers"] - 1, tables, jnp.asarray(ctx, jnp.int32),
+            jnp.asarray(first, jnp.int32))
+
+
+def stated_paged_attention(q, k_cache, v_cache, layer, tables, ctx, window=0, first_positions=None):
+    """The grouped decode call in the arithmetic a bfloat16 configuration
+    states (benchmark/reference/mellum2.py ``_attention``, lfm2.py):
+    scores summed in float32, a float32 softmax, the probabilities
+    rounded to the cache's dtype times V with float32 accumulation. In
+    plain XLA over gathered blocks. Returns the float32 result and, per
+    element, what the roundings of a bfloat16 computation (8 significant
+    bits: unit roundoff 2^-8) can move a kernel's result from it by:
+    2^-8 of ``sum p |v|`` for the probabilities' rounding, the kernel's
+    (before the normalisation) and this one's (after), and 2^-8 of the
+    result for each side's rounding of it."""
+    import jax
+    import jax.numpy as jnp
+
+    b, h, d = q.shape
+    bs = k_cache.shape[2]
+    s_max = tables.shape[1] * bs
+    k = k_cache[layer, tables].reshape(b, s_max, -1, d)
+    v = v_cache[layer, tables].reshape(b, s_max, -1, d)
+    qg = q.reshape(b, k.shape[2], -1, d)
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bhgd,bkhd->bhgk", qg, k, preferred_element_type=jnp.float32, precision=hi) * d ** -0.5
+    pos = jnp.arange(s_max)[None, :] + (0 if first_positions is None else first_positions[:, None])
+    valid = pos < ctx[:, None]
+    if window:
+        valid &= pos > ctx[:, None] - 1 - window
+    valid = valid[:, None, None, :]
+    p = jnp.where(valid, jnp.exp(s - jnp.max(jnp.where(valid, s, -1e30), axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    pv = lambda p_, v_: jnp.einsum("bhgk,bkhd->bhgd", p_, v_, preferred_element_type=jnp.float32, precision=hi)
+    out = pv(p.astype(v.dtype), v)
+    room = 2.0 ** -7 * (pv(p, jnp.abs(v).astype(jnp.float32)) + jnp.abs(out)) + 1e-6
+    return out.reshape(b, h, d), room.reshape(b, h, d)
+
+
+def grouped_kernels_check() -> dict:
+    """The grouped-query decode calls of ``GROUPED_CALLS``, compiled by
+    Mosaic, against :func:`stated_paged_attention`: every element inside
+    the room the arithmetic leaves, an inactive row exact zeros; the
+    kernel's time a call on the host's clock (20 calls, one wait)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ops.kernels.decode_attention import (
+        kernel_body, paged_decode_attention, paged_kernel_refusal, query_group,
+    )
+
+    out = {}
+    for name, c in GROUPED_CALLS.items():
+        q, k_cache, v_cache, layer, tables, ctx, first = args = grouped_call(name, SEED)
+        group = query_group(c["heads"], c["head_dim"], k_cache.shape[3:])
+        check(group == c["heads"] // c["kv_heads"], f"{name}: group {group}")
+        reason = paged_kernel_refusal(c["kv_heads"], c["head_dim"], c["block"], group, 2, group=group)
+        check(reason is None, f"{name}: the gate refuses the cell's own call: {reason}")
+
+        def over(attend):
+            def run(q, k, v, layer, t, n, first):
+                bounds = {"window": c["window"], "first_positions": first} if c["window"] else {}
+                return attend(q, k, v, layer, t, n, **bounds)
+            return jax.jit(run, static_argnums=3)
+
+        call = over(paged_decode_attention)
+        got = call(*args).astype(jnp.float32)
+        want, room = over(stated_paged_attention)(*args)
+        err = jnp.abs(got - want)
+        worst, of_room = float(jnp.max(err)), float(jnp.max(err / room))
+        check(np.isfinite(worst) and of_room <= 1.0,
+              f"{name}: max err {worst}, {of_room:.2f} of the room bfloat16 leaves")
+        check(bool(jnp.all(got[0] == 0.0)), f"{name}: an inactive row must emit zeros")
+        jax.block_until_ready(call(*args))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            r = call(*args)
+        jax.block_until_ready(r)
+        out[name] = {
+            "body": kernel_body(group), "group": group, "max_abs_err": worst,
+            "err_of_room": round(of_room, 3), "ms_a_call": round((time.perf_counter() - t0) / 20 * 1e3, 4),
+        }
+        log(f"grouped paged kernel {name}: {out[name]}")
+        del q, k_cache, v_cache, args, got, want, room, err, r
+    return out
+
 
 def kernels_phase(rs) -> dict:
     """Both Pallas kernels against their XLA references at the shapes
@@ -166,6 +301,7 @@ def kernels_phase(rs) -> dict:
             )
             out[f"paged_w{w}_s{splits}_max_abs_err"] = err
     log(f"paged kernel on layers 0-2 of a 5-D cache of 8 x 128 rows matches the reference: {out}")
+    out["grouped"] = grouped_kernels_check()
 
     q, k, v = (jnp.asarray(rs.randn(2, 512, 16, 64), jnp.bfloat16) for _ in range(3))
     wgt = jnp.asarray(rs.randn(2, 512, 16, 64), jnp.float32)
@@ -474,6 +610,8 @@ def server_phase(rs, num_layers: int = 24) -> dict:
     check(stats["completed"] == len(bodies) + 1, f"completed {stats['completed']}")
     check(model.breaker.state == "closed", f"breaker {model.breaker.state}")
     check(stats["prefix_cache_tokens_reused_total"] >= shared, "served follow-up missed the prefix cache")
+    # plain multi-head attention: the kernel's VPU body, not the XLA composition
+    check(stats["kernels"] == {"full": {"body": "vpu", "group": 1}}, f"/v2/stats kernels: {stats.get('kernels')}")
     log(f"zero new traces, zero recoveries/retries/quarantines/watchdog trips; readiness {ready.get('ready')}")
 
     out["teacher_forcing"] = check_teacher_forcing(

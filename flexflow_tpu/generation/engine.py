@@ -59,6 +59,7 @@ from ..models.transformer import TransformerConfig
 from ..obs.capacity import ProgramRegistry, ServingFlops
 from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
+from ..ops.attention import paged_call_lowering
 from ..runtime import faults
 from .cache import (
     BlockAllocator, CacheConfig, KVCache, StateConfig, WindowTable, pools_from_budget, slot_mapping,
@@ -523,6 +524,10 @@ class GenerationEngine:
             if self.layout is not None and self.tp_degree > 1 and on_tpu()
             else None
         )
+        # which body each attention kind's decode call lowers to, and at
+        # what group: static per program (the `kernels` section of
+        # /v2/stats, the server's start-up line)
+        self.attention_kernels: Dict[str, Dict] = self.paged_lowerings()
         # retrace counters: the Python body runs only when XLA traces, so
         # these count compiles, not calls (read by benchmark/drivers/serve*.py
         # for ``correct``, by chip_smoke.py and by the retrace tests)
@@ -2357,6 +2362,30 @@ class GenerationEngine:
             "decode_calls_total": int(calls[0]),
             "prefill_calls_total": int(calls[1]),
         }
+
+    def paged_lowerings(self) -> Dict[str, Dict]:
+        """``{"body", "group"}`` per attention kind the model has
+        (``full``, ``window``), asked of the gate the step programs'
+        dispatch asks (ops/attention.py ``paged_call_lowering``)."""
+        kinds = {"full": self.cache.k}
+        if self.window_config is not None:
+            kinds["window"] = self.cache.state["wk"]
+        return {
+            kind: paged_call_lowering(
+                self.dcfg.num_heads, self.dcfg.dim_per_head, arrays,
+                backend=self.backend, mesh=self._kernel_mesh,
+            )
+            for kind, arrays in kinds.items() if arrays.shape[0]
+        }
+
+    def kernel_stats(self) -> Dict:
+        """The ``kernels`` section of ``/v2/stats``: per attention kind of
+        the loaded model (``full``, ``window``), the body its paged decode
+        call lowered to — ``mxu`` (a grouped call), ``vpu`` (plain
+        multi-head) or ``reference`` (the XLA composition: the CPU
+        backend, or a shape the kernel's gate refused) — and the group
+        the shapes show."""
+        return {kind: dict(low) for kind, low in self.attention_kernels.items()}
 
     def sampling_stats(self) -> Dict:
         """The ``sampling`` section of ``/v2/stats``: decode steps by the

@@ -664,6 +664,7 @@ class ContinuousBatchingScheduler:
             # in this model's windows: timed where the work happens
             engine.prefix_cache.observe = self.stats.observe
         self.stats.add_section("sampling", engine.sampling_stats)
+        self.stats.add_section("kernels", engine.kernel_stats)
         # what the layers that are not attention-and-MLP count (cumulative,
         # /v2/stats): absent for a configuration without them
         if engine.expert_counts:

@@ -27,9 +27,11 @@ from ..core.types import DataType, OpType
 from ..device import on_tpu
 from .base import LowerCtx, OpCost, OpDef, WeightSpec, io_cost, register_op
 from .kernels.decode_attention import (
+    kernel_body,
     paged_append_attention,
     paged_decode_attention,
     paged_kernel_refusal,
+    query_group,
     reference_paged_append_attention,
     reference_paged_attention,
     sharded_paged_append_attention,
@@ -241,20 +243,33 @@ def _paged_kernel_tp(kernel, backend, mesh, head_axis, num_heads, head_dim, cach
     if backend != "tpu" or not on_tpu():
         return 0
     tp = 1 if mesh is None else int(dict(mesh.shape).get(head_axis, 1))
-    # grouped queries: the cache holds the K/V heads, and the kernel
-    # walks each of a group's query heads as one more window query
+    # grouped queries: the cache holds the K/V heads, and each of a
+    # group's query heads is one more window query of the kernel
     kv_heads = cache.shape[3] * cache.shape[4] // head_dim
     group = max(1, num_heads // kv_heads)
     if num_heads % tp or kv_heads % tp:
         reason = f"{num_heads} heads over {kv_heads} K/V heads do not divide over {tp} shards"
     else:
         reason = paged_kernel_refusal(
-            kv_heads // tp, head_dim, cache.shape[2], window * group, cache.dtype.itemsize
+            kv_heads // tp, head_dim, cache.shape[2], window * group, cache.dtype.itemsize, group=group
         )
     if reason is not None:
         _note_refusal(kernel, reason)
         return 0
     return tp
+
+
+def paged_call_lowering(num_heads, head_dim, cache, backend="tpu", mesh=None, head_axis="model", window=1):
+    """What a paged call of ``num_heads`` query heads over ``cache``
+    ([L, num_blocks, block_size, R, LW]; an array or its shape and
+    dtype) lowers to, as :func:`decode_attention_core` will decide it:
+    ``{"body", "group"}``. The body is the kernel's for the group the
+    shapes show (``"mxu"`` grouped, ``"vpu"`` plain multi-head:
+    kernels/decode_attention.py ``kernel_body``), or ``"reference"``,
+    the XLA composition, on the CPU backend and where the gate refuses."""
+    group = query_group(num_heads, head_dim, cache.shape[3:])
+    tp = _paged_kernel_tp("paged_decode_attention", backend, mesh, head_axis, num_heads, head_dim, cache, window)
+    return {"body": kernel_body(group) if tp else "reference", "group": group}
 
 
 def _windowed(tp: int, kernel, reference):

@@ -39,17 +39,44 @@ sequential decode logits exactly. ``q_position < 0`` marks a padding
 query (fixed-shape windows with fewer real draft tokens): it attends to
 nothing and emits zeros.
 
-**Grouped queries** (ISSUE 27). The cache holds the K/V heads, and a
-query may have ``group`` times as many (query head ``i`` reads K/V head
-``i // group``; :func:`query_group` reads the group off the shapes, 1
-for plain multi-head attention, whose programs are what they were). The
-kernel's body does not know: the window is folded, ``[B, W, Hkv * G, D]
--> [B, W * G, Hkv, D]``, so that window query ``w * G + g`` carries the
-``g``-th query head of every group and is laid out over the K/V heads
-exactly as a cache position is (:func:`_fold_group`). A block of K/V is
-DMA'd ONCE for all the query heads that read it, each walked as one
-more window query at the same position; LFM2-8B-A1B's 32 query heads
-over 8 K/V heads of 64 are a window of 4 over rows ``[4, 128]``.
+**Grouped queries** (ISSUE 27; the body of PR 33). The cache holds the
+K/V heads, and a query may have ``group`` times as many (query head
+``i`` reads K/V head ``i // group``; :func:`query_group` reads the group
+off the shapes). The window is folded, ``[B, W, Hkv * G, D] -> [B, W * G,
+Hkv, D]``, so that window query ``w * G + g`` carries the ``g``-th query
+head of every group, laid out over the K/V heads exactly as a cache
+position is (:func:`_fold_group`): a block of K/V is DMA'd ONCE for all
+the query heads that read it. LFM2-8B-A1B's 32 query heads over 8 K/V
+heads of 64 are a window of 4 over rows ``[4, 128]``, Mellum2's 32 over
+4 heads of 128 a window of 8 over the same rows.
+
+**Two bodies, picked by the group the shapes show** (:func:`kernel_body`;
+no flag, no environment variable, no model's name). The grid, the block
+tables, the scalar prefetch, the skip of dead columns and the Pallas
+calls' names are the same for both; what differs is how one cache block
+is folded into the online-softmax state.
+
+* ``group == 1`` (plain multi-head attention: GPT-2's decode and verify
+  calls, the head-sharded wrappers) — :func:`_accumulate_block`, on the
+  VPU, one window query at a time in float32. A group of one has nothing
+  to batch into a matrix product (one query row a K/V head), its cells
+  serve float32 weights whose ``correct`` leaves a float32 product on
+  the MXU no room (PR 32 was refused there), and this call lowers to
+  what it lowered to before the other body existed
+  (``tests/test_attention_kernels.py`` pins the kernel's jaxpr).
+* ``group > 1`` — :func:`_accumulate_block_mxu`: the block is scored for
+  ALL query rows of its K/V heads by one ``dot_general`` on the stored
+  dtype with float32 accumulation (no block-sized conversion), one
+  update of the running max and denominator for all rows, and a second
+  product, the probabilities in the cache's dtype times V, accumulated
+  in float32. For a bfloat16 cache this changes no stated arithmetic: a
+  product of two bfloat16 values is exact in float32, and the
+  configurations' references state the weighted values as rounded
+  probabilities times V with float32 accumulation. On the v5e (PR 33,
+  ``chip_smoke.py``) the call of Mellum2's full layers takes 1.09 ms
+  where the VPU body took 5.32, its windowed call 0.51 against 2.96,
+  LFM2's 1.51 against 2.35; what is left is the grid (0.4-0.5 us a
+  step).
 
 **A window** (PR 31). A sliding-window layer's call (``window`` > 0)
 adds a lower bound a query (it attends the ``window`` positions up to
@@ -132,6 +159,31 @@ def _unfold_group(out: jax.Array, group: int) -> jax.Array:
     """The inverse of :func:`_fold_group`, for the attention output."""
     b, wg, hk, d = out.shape
     return out.reshape(b, wg // group, group, hk, d).transpose(0, 1, 3, 2, 4).reshape(b, wg // group, hk * group, d)
+
+
+def _query_rows(q: jax.Array, rows: int, lanes: int) -> jax.Array:
+    """[B, W, Hkv, D] (a folded window) -> [B, W * R * P, LW], the
+    grouped body's matrix of query rows: row ``(w * R + r) * P + p`` is
+    cache row ``r`` of window query ``w`` with every lane but those of
+    its ``p``-th head zeroed (``P = LW // D`` heads share a row), so a
+    product over a row's ``LW`` lanes is ONE head's ``q . k``."""
+    b, w, _, d = q.shape
+    per_row = lanes // d
+    q = q.reshape(b, w, rows, per_row, 1, d)
+    if per_row > 1:
+        own = jnp.eye(per_row, dtype=bool)[:, :, None]  # [p, head of the row, 1]
+        q = jnp.where(own, q, jnp.zeros((), q.dtype))
+    return q.reshape(b, w * rows * per_row, lanes)
+
+
+def _head_rows(out: jax.Array, w: int, rows: int, head_dim: int) -> jax.Array:
+    """The inverse of :func:`_query_rows` for the grouped body's result
+    [B, W * R * P, LW]: query row ``p`` of a cache row summed V over all
+    its lanes; its own head's are kept. Returns [B, W, Hkv, D]."""
+    b, _, lanes = out.shape
+    per_row = lanes // head_dim
+    out = out.reshape(b, w, rows, per_row, per_row, head_dim)
+    return jnp.einsum("bwrppd->bwrpd", out).reshape(b, w, rows * per_row, head_dim)
 
 
 def reference_paged_append_attention(
@@ -224,19 +276,21 @@ def reference_paged_attention(
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
 #
-# Everything inside the kernel keeps the cache's own [block_size, R, LW]
-# arrangement: rows on sublanes, a row's heads side by side on lanes.
-# Scores are a lane reduction of k * q (VPU + XLU) — one reduction per
-# head of the row, each over its own lanes, the result left on those
-# lanes — and the value sum is a reduction over the block's leading
-# axis, so the body needs no transposes, no batched matmul and no vector
-# loads from SMEM — the three things Mosaic refused in the first version
-# of this kernel, which computed [H, W, block_size] scores with a
-# dot_general batched over a non-leading K axis. Per-query state is
-# [W, R, LW] (a head's running max and denominator repeated over its
-# lanes: a [.., 1] column occupies whole vector registers anyway) and
-# the window is walked one query at a time; each query's cache position
-# is a scalar SMEM read.
+# The group-1 body (:func:`_accumulate_block`). Everything inside it keeps
+# the cache's own [block_size, R, LW] arrangement: rows on sublanes, a
+# row's heads side by side on lanes. Scores are a lane reduction of k * q
+# (VPU + XLU) — one reduction per head of the row, each over its own
+# lanes, the result left on those lanes — and the value sum is a
+# reduction over the block's leading axis, so the body needs no
+# transposes, no batched matmul and no vector loads from SMEM — the three
+# things Mosaic refused in the first version of this kernel, which
+# computed [H, W, block_size] scores with a dot_general batched over a
+# non-leading K axis. Per-query state is [W, R, LW] (a head's running max
+# and denominator repeated over its lanes: a [.., 1] column occupies
+# whole vector registers anyway) and the window is walked one query at a
+# time; each query's cache position is a scalar SMEM read. The grouped
+# body, further down, needs none of the three either: its two products
+# are plain 2-D matmuls over the block read as the matrix it is in memory.
 
 
 def _head_scores(prod, head_dim):
@@ -286,6 +340,69 @@ def _accumulate_block(
     jax.lax.fori_loop(0, q_ref.shape[0], one_query, 0)
 
 
+# The grouped body (PR 33). A group's query heads read the SAME K/V head,
+# so a block can be scored for all of them by one matrix product, which
+# the group-1 call has no use for (one query row a K/V head is a matrix
+# of one row). The wrapper stacks the folded window into a matrix of
+# QUERY ROWS, ``[M, LW]`` with ``M = W * G * R * (LW // D)``: row ``((w *
+# R + r) * P + p)`` holds cache row ``r`` of window query ``w`` with every
+# lane but head ``p``'s zeroed (``P`` heads share a row; 1 where a head
+# fills it). The block is read as the matrix it already is in memory,
+# ``[bs * R, LW]`` (line ``t * R + r`` is row ``r`` of position ``t``: no
+# transpose, no strided load, no conversion to float32), and ``Q x K^T``
+# is ONE product for every K/V head of the block. A query row meets the
+# lines of the other ``R - 1`` cache rows too; those scores are masked
+# with the causal mask, so the second product, ``P x V``, sums a row's
+# own head alone. The MXU computes ``R`` times the scores that are
+# needed, on matrices of a few vector registers: what it replaces is
+# ``W * G`` passes of the VPU over the whole block.
+
+
+def _line_index(lines, rows):
+    """``(lines // rows, lines % rows)`` of a non-negative int32 array;
+    shifts where ``rows`` is a power of two (a vector integer division
+    is emulated on the VPU)."""
+    if rows & (rows - 1) == 0:
+        return lines >> (rows.bit_length() - 1), lines & (rows - 1)
+    return lines // rows, lines % rows
+
+
+def _accumulate_block_mxu(
+    qpos_ref, q_ref, rowpos_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, b, block_start, *, scale, head_dim, window=0
+):
+    """:func:`_accumulate_block` for a grouped call: one block folded
+    into the state of ALL query rows (m/l [M, 1], acc [M, LW]) by two
+    matrix products. ``rowpos_ref`` [M, 1] is each query row's cache
+    position (the scalars of ``qpos_ref``, as a vector)."""
+    del qpos_ref, b
+    bs, r, lw = k_ref.shape
+    q = q_ref[...]  # [M, LW], the stored dtype
+    k = k_ref[...].reshape(bs * r, lw).astype(q.dtype)  # the block's lines
+    v = v_ref[...].reshape(bs * r, lw)
+    # Q x K^T, float32 accumulation: [M, bs * R]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+    # line t * R + r of the block against query row (w * R + r') * P + p
+    t, line_row = _line_index(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1), r)
+    query_line, _ = _line_index(jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), lw // head_dim)
+    _, query_row = _line_index(query_line, r)
+    pos = block_start + t
+    qp = rowpos_ref[...]  # [M, 1]
+    valid = jnp.logical_and(line_row == query_row, pos <= qp)
+    if window:
+        valid = jnp.logical_and(valid, pos > qp - window)
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    # P x V: probabilities in the cache's dtype, float32 accumulation
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
 def _init_state(m_ref, l_ref, acc_ref):
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
@@ -319,20 +436,19 @@ def _append_kernel(
     bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
     qpos_ref,  # scalar-prefetch: [B, W] per-query cache positions (-1 = pad)
     maxpos_ref,  # scalar-prefetch: [B] max over the window's positions
-    q_ref,  # [W, R, LW] this sequence's query window, laid out as a cache row
-    k_ref,  # [block_size, R, LW] the grid step's cache block
-    v_ref,  # [block_size, R, LW]
-    o_ref,  # [W, R, LW]
-    m_ref,  # scratch [W, R, LW or 1] running max per query
-    l_ref,  # scratch [W, R, LW or 1] running denominator per query
-    acc_ref,  # scratch [W, R, LW] running numerator per query
-    *,
+    *refs,  # the body's inputs (:func:`_accumulate_block`: q, k, v), then:
+    # o_ref [W, R, LW]; scratch m_ref / l_ref [W, R, LW or 1] running max /
+    # denominator per query, acc_ref [W, R, LW] running numerator (a
+    # grouped call: [M, LW] / [M, 1] per query row, its body's fourth
+    # input the rows' positions)
     scale,
     block_size,
     head_dim,
+    accumulate,  # the body: :func:`_accumulate_block` or `_mxu`
     window=0,
     bounds=None,
 ):
+    *ins, o_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -346,8 +462,8 @@ def _append_kernel(
     # (its DMA read the scratch block; the data is ignored)
     @pl.when(live)
     def _accum():
-        _accumulate_block(
-            qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+        accumulate(
+            qpos_ref, *ins, m_ref, l_ref, acc_ref,
             b, start, scale=scale, head_dim=head_dim, window=window,
         )
 
@@ -363,21 +479,16 @@ def _append_kernel_split(
     bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
     qpos_ref,  # scalar-prefetch: [B, W] per-query cache positions (-1 = pad)
     maxpos_ref,  # scalar-prefetch: [B] max over the window's positions
-    q_ref,  # [W, R, LW] this sequence's query window
-    k_ref,  # [block_size, R, LW] the grid step's cache block
-    v_ref,  # [block_size, R, LW]
-    acc_out_ref,  # [W, R, LW] this split's UNNORMALIZED numerator
-    m_out_ref,  # [W, R, LW or 1] this split's running max
-    l_out_ref,  # [W, R, LW or 1] this split's denominator
-    m_ref,  # scratch [W, R, LW or 1]
-    l_ref,  # scratch [W, R, LW or 1]
-    acc_ref,  # scratch [W, R, LW]
-    *,
+    *refs,  # the body's inputs as in :func:`_append_kernel`, then this
+    # split's UNNORMALIZED numerator acc_out_ref [W, R, LW], its running
+    # max m_out_ref and denominator l_out_ref [W, R, LW or 1], and the
+    # three scratch refs of the same shapes
     scale,
     block_size,
     head_dim,
     blocks_per_split,
     max_blocks,
+    accumulate,
     window=0,
     bounds=None,
 ):
@@ -388,6 +499,7 @@ def _append_kernel_split(
     broken), and emits unnormalized partials (acc, m, l) that
     :func:`_combine_splits` recombines exactly. Long-context
     single-stream decode stops serializing over the whole block table."""
+    *ins, acc_out_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(2)
     jj = pl.program_id(1) * blocks_per_split + j  # global block-table column
@@ -403,8 +515,8 @@ def _append_kernel_split(
 
     @pl.when(jnp.logical_and(jj < max_blocks, live))
     def _accum():
-        _accumulate_block(
-            qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+        accumulate(
+            qpos_ref, *ins, m_ref, l_ref, acc_ref,
             b, start, scale=scale, head_dim=head_dim, window=window,
         )
 
@@ -473,25 +585,30 @@ def paged_append_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     group = query_group(q.shape[2], q.shape[3], k_cache.shape[3:])
-    if group > 1:
-        # grouped queries: a block of K/V is read ONCE for all the query
-        # heads of its groups, each walked as one more window query
-        out = paged_append_attention(
-            _fold_group(q, group), k_cache, v_cache, layer, block_tables,
-            jnp.repeat(q_positions, group, axis=1), scale=scale, interpret=interpret,
-            kv_splits=kv_splits, window=window, first_positions=first_positions,
-        )
-        return _unfold_group(out, group)
-    b, w, h, d = q.shape
-    block_size, r, lw = k_cache.shape[2:]
-    sw = lw if lw != d else 1  # a head's max / denominator: on its lanes, or a column
     out_dtype = q.dtype
-    q = q.reshape(b, w, r, lw)  # the window, laid out as the cache lays a position out
+    block_size, r, lw = k_cache.shape[2:]
+    mxu = kernel_body(group) == "mxu"
+    q_positions = q_positions.astype(jnp.int32)
+    if mxu:
+        # grouped queries: a block of K/V is read ONCE for all the query
+        # heads of its groups, each one more window query at the same
+        # position, and scored for all of them on the MXU
+        q = _fold_group(q, group)
+        q_positions = jnp.repeat(q_positions, group, axis=1)
+    b, w, h, d = q.shape
+    if mxu:
+        per_query = r * (lw // d)  # query rows a window query: one a K/V head
+        row_positions = jnp.repeat(q_positions, per_query, axis=1)
+        ins = (_query_rows(q, r, lw), row_positions[:, :, None])
+        state, acc_shape, accumulate = (w * per_query, 1), (w * per_query, lw), _accumulate_block_mxu
+    else:
+        sw = lw if lw != d else 1  # a head's max / denominator: on its lanes, or a column
+        ins = (q.reshape(b, w, r, lw),)  # the window, laid out as the cache lays a position out
+        state, acc_shape, accumulate = (w, r, sw), (w, r, lw), _accumulate_block
     layer = int(layer)  # static: part of the index map, not an operand
     max_blocks = block_tables.shape[1]
     kv_splits = max(1, min(int(kv_splits), max_blocks))
     block_tables = block_tables.astype(jnp.int32)
-    q_positions = q_positions.astype(jnp.int32)
     prefetch = (block_tables, q_positions, jnp.max(q_positions, axis=1))
     name, static, behind = "paged_append_attention", {}, (lambda kernel: kernel)
     if window:
@@ -502,50 +619,61 @@ def paged_append_attention(
         name, static, behind = "paged_window_attention", {"window": int(window)}, _with_bounds
     n_prefetch = len(prefetch)
     scratch_shapes = [
-        pltpu.VMEM((w, r, sw), jnp.float32),
-        pltpu.VMEM((w, r, sw), jnp.float32),
-        pltpu.VMEM((w, r, lw), jnp.float32),
+        pltpu.VMEM(state, jnp.float32),
+        pltpu.VMEM(state, jnp.float32),
+        pltpu.VMEM(acc_shape, jnp.float32),
     ]
+
+    def whole(shape, lead):
+        """A sequence's (and split's) whole ``shape``, whatever the
+        grid step's cache block."""
+        return pl.BlockSpec(
+            (None,) * lead + shape, lambda i, *at: (i, *at[:lead - 1]) + (0,) * len(shape)
+        )
+
+    def unstack(out):
+        """The kernel's result as [B, W, H, D]."""
+        if mxu:
+            return _unfold_group(_head_rows(out, w, r, d), group)
+        return out.reshape(b, w, h, d)
+
     if kv_splits > 1:
         bps = -(-max_blocks // kv_splits)  # blocks per split (ceil)
 
         def kv_map(i, s, j, bt, *_):
             return (layer, bt[i, jnp.minimum(s * bps + j, max_blocks - 1)], 0, 0, 0)
 
-        def out_map(i, s, j, *_):
-            return (i, s, 0, 0, 0)
-
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_prefetch,
             grid=(b, kv_splits, bps),
             in_specs=[
-                pl.BlockSpec((None, w, r, lw), lambda i, s, j, *_: (i, 0, 0, 0)),
+                *(whole(x.shape[1:], 1) for x in ins),
                 pl.BlockSpec((None, None, block_size, r, lw), kv_map),
                 pl.BlockSpec((None, None, block_size, r, lw), kv_map),
             ],
-            out_specs=[
-                pl.BlockSpec((None, None, w, r, lw), out_map),
-                pl.BlockSpec((None, None, w, r, sw), out_map),
-                pl.BlockSpec((None, None, w, r, sw), out_map),
-            ],
+            out_specs=[whole(acc_shape, 2), whole(state, 2), whole(state, 2)],
             scratch_shapes=scratch_shapes,
         )
         kernel = functools.partial(
             behind(_append_kernel_split), scale=float(scale), block_size=block_size,
-            head_dim=d, blocks_per_split=bps, max_blocks=max_blocks, **static,
+            head_dim=d, blocks_per_split=bps, max_blocks=max_blocks, accumulate=accumulate, **static,
         )
         acc, m, l = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((b, kv_splits, w, r, lw), jnp.float32),
-                jax.ShapeDtypeStruct((b, kv_splits, w, r, sw), jnp.float32),
-                jax.ShapeDtypeStruct((b, kv_splits, w, r, sw), jnp.float32),
+                jax.ShapeDtypeStruct((b, kv_splits) + acc_shape, jnp.float32),
+                jax.ShapeDtypeStruct((b, kv_splits) + state, jnp.float32),
+                jax.ShapeDtypeStruct((b, kv_splits) + state, jnp.float32),
             ],
             interpret=interpret,
             name=name + "_split",
-        )(*prefetch, q, k_cache, v_cache)
-        return _combine_splits(acc, m, l, q_positions, out_dtype).reshape(b, w, h, d)
+        )(*prefetch, *ins, k_cache, v_cache)
+        if mxu:
+            # a query row as a window query of one cache row
+            acc, m, l = (x[:, :, :, None] for x in (acc, m, l))
+            return unstack(_combine_splits(acc, m, l, row_positions, out_dtype)[:, :, 0])
+        return unstack(_combine_splits(acc, m, l, q_positions, out_dtype))
 
     def kv_map(i, j, bt, *_):
         return (layer, bt[i, j], 0, 0, 0)
@@ -554,23 +682,24 @@ def paged_append_attention(
         num_scalar_prefetch=n_prefetch,
         grid=(b, max_blocks),
         in_specs=[
-            pl.BlockSpec((None, w, r, lw), lambda i, j, *_: (i, 0, 0, 0)),
+            *(whole(x.shape[1:], 1) for x in ins),
             pl.BlockSpec((None, None, block_size, r, lw), kv_map),
             pl.BlockSpec((None, None, block_size, r, lw), kv_map),
         ],
-        out_specs=pl.BlockSpec((None, w, r, lw), lambda i, j, *_: (i, 0, 0, 0)),
+        out_specs=whole(acc_shape, 1),
         scratch_shapes=scratch_shapes,
     )
     kernel = functools.partial(
-        behind(_append_kernel), scale=float(scale), block_size=block_size, head_dim=d, **static
+        behind(_append_kernel), scale=float(scale), block_size=block_size, head_dim=d,
+        accumulate=accumulate, **static,
     )
-    return pl.pallas_call(
+    return unstack(pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, w, r, lw), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((b,) + acc_shape, out_dtype),
         interpret=interpret,
         name=name,
-    )(*prefetch, q, k_cache, v_cache).reshape(b, w, h, d)
+    )(*prefetch, *ins, k_cache, v_cache))
 
 
 def default_kv_splits(batch: int, max_blocks: int) -> int:
@@ -716,48 +845,76 @@ def sharded_paged_decode_attention(
     return out[:, 0]
 
 
-# The kernel walks the window one query at a time on the VPU (no MXU), so
-# its cost is linear in W: speculative windows are a handful of tokens,
-# while a suffix-prefill bucket of hundreds belongs to the XLA
-# composition, whose scores are one matmul.
+def kernel_body(group: int) -> str:
+    """Which body folds a cache block into the softmax state of a call
+    whose shapes show ``group`` query heads a K/V head (:func:`query_group`):
+    ``"mxu"`` (:func:`_accumulate_block_mxu`) for a grouped call,
+    ``"vpu"`` (:func:`_accumulate_block`) for plain multi-head attention.
+    The one rule; :func:`paged_append_attention` applies it."""
+    return "mxu" if group > 1 else "vpu"
+
+
+# A group-1 call walks the window one query at a time on the VPU, so its
+# cost is linear in W; a grouped call holds W * G query rows a K/V head in
+# its score matrix. Either way speculative windows are a handful of
+# tokens, while a suffix-prefill bucket of hundreds belongs to the XLA
+# composition, whose scores are one matmul over the whole context.
 MAX_KERNEL_WINDOW = 32
 # Mosaic's only refusal of this kernel is running out of VMEM (v5e,
 # libtpu 0.0.34: 1-16 heads, head_dim 32-256, block sizes 1-64, f32 and
 # bf16 all compiled; footprints that :func:`_vmem_bytes` puts above
-# ~28 MiB did not). The gate keeps well inside the 16 MiB default scoped
-# limit, so a refusal is a dispatch decision and never a compiler error
-# inside a serving step.
+# ~28 MiB did not; the grouped body, PR 33: 8 and 4 K/V heads of 64 and
+# 128, groups 4 and 8, blocks of 16 and 64, W = 1 and 5, split and
+# windowed, bf16 and f32 compiled for the v5e and ran there). The gate
+# keeps well inside the 16 MiB default scoped limit, so a refusal is a
+# dispatch decision and never a compiler error inside a serving step.
 _VMEM_BUDGET_BYTES = 12 << 20
 
 
-def _vmem_bytes(num_heads: int, head_dim: int, block_size: int, window: int, itemsize: int) -> int:
-    """Upper estimate of the kernel's VMEM footprint: double-buffered
-    K/V and Q/O blocks, the online-softmax scratch, and the block-sized
-    float32 temporaries, with a position's (R, LW) rows padded to the
-    (8, 128) tile."""
+def _vmem_bytes(
+    num_heads: int, head_dim: int, block_size: int, window: int, itemsize: int, group: int = 1
+) -> int:
+    """Upper estimate of the kernel's VMEM footprint, for the body that
+    will run (:func:`kernel_body`): double-buffered K/V and Q/O blocks,
+    the online-softmax scratch and the body's temporaries, with a
+    position's (R, LW) rows padded to the (8, 128) tile. ``window``
+    counts the window queries the kernel holds (W x group). The VPU body
+    keeps three block-sized float32 temporaries; the MXU body the
+    ``[M, block_size * R]`` scores (as scores, mask, probabilities and
+    their rounded copy) and ``[M, ...]`` state, ``M = window * R * heads
+    a row`` query rows, and the block itself once more as a value."""
     rows, lanes = cache_row_shape(num_heads, head_dim)
-    rows = -(-rows // 8) * 8
-    row = rows * -(-lanes // LANES) * LANES  # one position's slab, in elements
+    per_row = lanes // head_dim  # heads sharing a row
+    padded = -(-rows // 8) * 8
+    row = padded * -(-lanes // LANES) * LANES  # one position's slab, in elements
     kv = 2 * 2 * block_size * row * itemsize
-    qo = 2 * 2 * window * row * itemsize
-    scratch = window * (row + 2 * rows * LANES) * 4  # acc + m + l
-    temporaries = 3 * block_size * row * 4
-    return kv + qo + scratch + temporaries
+    if kernel_body(group) == "vpu":
+        qo = 2 * 2 * window * row * itemsize
+        scratch = window * (row + 2 * padded * LANES) * 4  # acc + m + l
+        return kv + qo + scratch + 3 * block_size * row * 4
+    m = -(-window * rows * per_row // 8) * 8  # query rows
+    line = -(-lanes // LANES) * LANES
+    qo = 2 * (2 * m * line * itemsize + m * LANES * 4)  # Q, O and the rows' positions
+    scratch = m * (line + 2 * LANES) * 4
+    scores = 4 * m * -(-block_size * rows // LANES) * LANES * 4
+    return kv + qo + scratch + scores + kv // 2
 
 
 def paged_kernel_refusal(
-    num_heads: int, head_dim: int, block_size: int, window: int = 1, itemsize: int = 4
+    num_heads: int, head_dim: int, block_size: int, window: int = 1, itemsize: int = 4, group: int = 1
 ) -> Optional[str]:
     """Why :func:`paged_append_attention` will not take this shape
-    (per-shard head count, ``window`` = 1 for decode), or None when it
+    (per-shard K/V head count; ``window`` = the window queries the kernel
+    holds, W x ``group``: 1 for a plain decode call), or None when it
     will. The dispatch in ops/attention.py sends a refused shape to the
     XLA reference and logs the reason once."""
     if window > MAX_KERNEL_WINDOW:
-        return (
-            f"window {window} > {MAX_KERNEL_WINDOW}: the kernel scores one query "
-            f"at a time on the VPU"
+        how = (
+            "the kernel scores one query at a time on the VPU" if kernel_body(group) == "vpu" else
+            f"the kernel holds every query row of a block's K/V heads (group {group}) in one score matrix"
         )
-    need = _vmem_bytes(num_heads, head_dim, block_size, window, itemsize)
+        return f"window {window} > {MAX_KERNEL_WINDOW}: {how}"
+    need = _vmem_bytes(num_heads, head_dim, block_size, window, itemsize, group)
     if need > _VMEM_BUDGET_BYTES:
         return (
             f"~{need >> 20} MiB of VMEM for heads={num_heads} head_dim={head_dim} "

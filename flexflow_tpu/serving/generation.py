@@ -25,6 +25,7 @@ model's stats block on ``GET /v2/stats``. Pass ``recovery=`` /
 """
 from __future__ import annotations
 
+import logging
 from typing import Dict, List, Optional, Sequence
 
 from ..generation.constrained import (
@@ -35,6 +36,9 @@ from ..generation.constrained import (
 from ..generation.engine import GenerationEngine, SamplingParams
 from ..generation.scheduler import ContinuousBatchingScheduler, GenerationHandle
 from ..generation.speculative import SpeculationConfig
+
+
+_log = logging.getLogger(__name__)
 
 
 class GenerationModel:
@@ -68,6 +72,16 @@ class GenerationModel:
 
     # --------------------------------------------------------- lifecycle
     def start(self) -> None:
+        # the one start-up line: what the paged decode call of each
+        # attention kind lowered to (static per program; /v2/stats
+        # `kernels` says the same for as long as the server lives)
+        _log.info(
+            "generation model %r starts: paged attention %s", self.name,
+            ", ".join(
+                f"{kind}: {low['body']} body at group {low['group']}"
+                for kind, low in self.engine.attention_kernels.items()
+            ),
+        )
         self.scheduler.start()
 
     def stop(self, drain: bool = True) -> None:
