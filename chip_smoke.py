@@ -251,6 +251,83 @@ def grouped_kernels_check() -> dict:
     return out
 
 
+# The latent layers' decode call of the benchmark's latent cell, at the
+# cell's sizes (benchmark/workloads/joyai-llm-flash.long-gen.json): 32
+# heads over ONE row a position, 576 values stored at 640 lanes, the
+# values its first 512 columns, bfloat16.
+LATENT_CALL = dict(heads=32, width=576, value_width=512, score_width=192, block=64, slots=32, columns=48,
+                   layers=4, contexts=(1280, 2816))
+
+
+def latent_kernel_check() -> dict:
+    """``paged_latent_attention`` at :data:`LATENT_CALL`, compiled by
+    Mosaic, against the XLA composition in the stated arithmetic
+    (``reference_paged_latent_attention``: bfloat16 queries and rows,
+    float32 scores and softmax, the probabilities rounded to bfloat16
+    times the rows' first 512 columns, float32 accumulation), computed
+    here in float32 with the room bfloat16's roundings leave as
+    :func:`stated_paged_attention` reckons it; an inactive row exact
+    zeros; both lowerings' time a call on the host's clock."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ops.kernels.decode_attention import (
+        latent_kernel_refusal, latent_row_width, paged_latent_attention, reference_paged_latent_attention,
+    )
+
+    c = LATENT_CALL
+    rs = np.random.RandomState(SEED)
+    b, bs, cols, rw, vw = c["slots"], c["block"], c["columns"], latent_row_width(c["width"]), c["value_width"]
+    nb = b * cols + 1
+    check(latent_kernel_refusal(c["heads"], rw, bs, 2) is None, "latent: the gate refuses the cell's own call")
+    kq, kc = jax.random.split(jax.random.key(SEED))
+    live = (jnp.arange(rw) < c["width"])  # the fill is zero, in the rows and in the queries
+    cache = jnp.where(live, jax.random.normal(kc, (c["layers"], nb, bs, rw), jnp.bfloat16), 0).astype(jnp.bfloat16)
+    q = jnp.where(live, jax.random.normal(kq, (b, 1, c["heads"], rw), jnp.bfloat16), 0).astype(jnp.bfloat16)
+    ctx = rs.randint(*c["contexts"], size=b)
+    ctx[0], ctx[1] = 0, (ctx[1] // bs) * bs + 1
+    tables = jnp.asarray(1 + rs.permutation(nb - 1).reshape(b, cols), jnp.int32)
+    positions = jnp.asarray(ctx - 1, jnp.int32)[:, None]
+    layer, scale = c["layers"] - 1, c["score_width"] ** -0.5
+
+    def stated(q, cache, tables, positions):
+        rows = cache[layer, tables].reshape(b, cols * bs, rw)
+        hi = jax.lax.Precision.HIGHEST
+        s = jnp.einsum("bwhc,bkc->bhwk", q, rows, preferred_element_type=jnp.float32, precision=hi) * scale
+        valid = jnp.arange(cols * bs)[None, None, None, :] <= positions[:, None, :, None]
+        p = jnp.where(valid, jnp.exp(s - jnp.max(jnp.where(valid, s, -1e30), axis=-1, keepdims=True)), 0.0)
+        p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+        pv = lambda p_, v_: jnp.einsum("bhwk,bkc->bwhc", p_, v_, preferred_element_type=jnp.float32, precision=hi)  # noqa: E731
+        out = pv(p.astype(rows.dtype), rows[..., :vw])
+        return out, 2.0 ** -7 * (pv(p, jnp.abs(rows[..., :vw]).astype(jnp.float32)) + jnp.abs(out)) + 1e-6
+
+    kernel = jax.jit(lambda q, c_, t, p: paged_latent_attention(q, c_, layer, t, p, vw, scale))
+    composed = jax.jit(lambda q, c_, t, p: reference_paged_latent_attention(q, c_, layer, t, p, vw, scale))
+    args = (q, cache, tables, positions)
+    got = kernel(*args).astype(jnp.float32)
+    want, room = jax.jit(stated)(*args)
+    err = jnp.abs(got - want)
+    worst, of_room = float(jnp.max(err)), float(jnp.max(err / room))
+    check(np.isfinite(worst) and of_room <= 1.0, f"latent: max err {worst}, {of_room:.2f} of the room bfloat16 leaves")
+    check(bool(jnp.all(got[0] == 0.0)), "latent: an inactive row must emit zeros")
+    text = kernel.lower(*args).compile().as_text()
+    check("paged_latent_attention" in text, "the latent call's Mosaic custom call is not in its program")
+    ms = {}
+    for name, call in (("kernel", kernel), ("xla_composition", composed)):
+        jax.block_until_ready(call(*args))
+        t0 = time.perf_counter()
+        for _ in range(20):
+            r = call(*args)
+        jax.block_until_ready(r)
+        ms[name] = round((time.perf_counter() - t0) / 20 * 1e3, 4)
+    read = float(np.sum(ctx)) * c["width"] * 2
+    out = {"max_abs_err": worst, "err_of_room": round(of_room, 3), "ms_a_call": ms,
+           "rows_read_gb": round(read / 1e9, 4), "gb_per_s": round(read / 1e9 / (ms["kernel"] / 1e3), 1)}
+    log(f"latent paged kernel at the cell's sizes: {out}")
+    return out
+
+
 def kernels_phase(rs) -> dict:
     """Both Pallas kernels against their XLA references at the shapes
     the server and the trainer run, compiled (never interpreted)."""
@@ -302,6 +379,7 @@ def kernels_phase(rs) -> dict:
             out[f"paged_w{w}_s{splits}_max_abs_err"] = err
     log(f"paged kernel on layers 0-2 of a 5-D cache of 8 x 128 rows matches the reference: {out}")
     out["grouped"] = grouped_kernels_check()
+    out["latent"] = latent_kernel_check()
 
     q, k, v = (jnp.asarray(rs.randn(2, 512, 16, 64), jnp.bfloat16) for _ in range(3))
     wgt = jnp.asarray(rs.randn(2, 512, 16, 64), jnp.float32)
@@ -872,6 +950,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run the sharded legs on a four-chip host instead")
+    ap.add_argument("--latent-kernel", action="store_true",
+                    help="the latent paged kernel alone, at the latent cell's sizes")
     args = ap.parse_args(argv)
 
     import jax
@@ -898,6 +978,8 @@ def main(argv=None) -> int:
     rs = np.random.RandomState(SEED)
     if args.four_chips:
         summary["four_chips"] = four_chip_phase(rs)
+    elif args.latent_kernel:
+        summary["kernels"] = {"latent": latent_kernel_check()}
     else:
         summary["calibration"] = calibration_hit(dev.device_kind)
         log(f"calibration lookup for {dev.device_kind!r}: {summary['calibration']}")
@@ -917,7 +999,8 @@ def main(argv=None) -> int:
     log(f"compile cache: {entries_before} -> {entries_after} entries; wall {summary['wall_s']}s")
     out_dir = REPO / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
-    name = "chip_smoke_four_chips.json" if args.four_chips else "chip_smoke.json"
+    name = "chip_smoke_four_chips.json" if args.four_chips else (
+        "chip_smoke_latent.json" if args.latent_kernel else "chip_smoke.json")
     (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

@@ -79,6 +79,22 @@ sequence can hold of each (:meth:`CacheConfig.for_slots` with
 ``window=``, :func:`pools_from_budget`). A configuration without window
 layers has one pool, and builds the cache it has always built.
 
+**Latent rows.** A latent-attention layer (generation/decoder.py) caches
+ONE row a position — ``[c, k_r]``, ``kv_lora_rank + qk_rope_head_dim``
+values shared by all heads — from which the absorbed form reads scores
+and values alike. Such a configuration's :class:`CacheConfig` is
+``latent``: ``k`` is ``[L, num_blocks, block_size, RW]`` with ``RW`` the
+row's width at the next multiple of 128 lanes (576 -> 640, the fill
+zero; ``CacheConfig.row_shape``), ``v`` has NO width (``[L, num_blocks,
+block_size, 0]``: every program carries it as it carries an empty
+pytree, and block reads, copies and the host tier move nothing for it),
+and everything that counts bytes — blocks, pools, the host tier's budget
+— counts the one stored row. Why this shape and not ``[..., 5, 128]`` or
+a second array for the rotary part: ops/kernels/decode_attention.py. The
+three conditions above hold for it as they do for K/V: default layout
+row-major, donated, one scatter on the whole operand, the kernel taking
+the whole array and a static layer index.
+
 Block 0 is reserved as a **scratch block**: padded prompt positions and
 inactive decode slots scatter their (meaningless) K/V there, so the
 jitted steps never need dynamic shapes or masked scatters to avoid
@@ -95,7 +111,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.types import DataType
-from ..ops.kernels.decode_attention import cache_row_shape
+from ..ops.kernels.decode_attention import cache_row_shape, latent_row_width
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,6 +135,9 @@ class CacheConfig:
     # > 0: the pool of the sliding-window layers, whose sequences keep
     # only the blocks the `window` positions behind a query can touch
     window: int = 0
+    # a latent layer's cache (module docstring): ONE row of `num_heads x
+    # head_dim` values a position (one "head" of the row's width), no V
+    latent: bool = False
 
     def __post_init__(self):
         if self.num_blocks < 2:
@@ -132,20 +151,29 @@ class CacheConfig:
         store them (ops/kernels/decode_attention.py::cache_row_shape,
         applied to one shard's heads so that sharding the row axis is
         sharding the heads)."""
+        if self.latent:
+            return (latent_row_width(self.num_heads * self.head_dim),)
         rows, lanes = cache_row_shape(self.num_heads // self.kv_shards, self.head_dim)
         return rows * self.kv_shards, lanes
 
     @property
+    def value_row_shape(self) -> Tuple[int, ...]:
+        """What ``v`` stores a position: K's shape, or nothing where the
+        values are read out of K's row (a latent cache)."""
+        return (0,) if self.latent else self.row_shape
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Bytes one cached position occupies across all layers, as
+        stored: K + V, or a latent cache's one row at its lane-filled
+        width."""
+        return _token_bytes(self.num_layers, self.num_heads, self.head_dim, self.dtype, self.latent)
+
+    @property
     def bytes_per_block(self) -> int:
-        """K + V bytes one block occupies across all layers."""
-        return (
-            2
-            * self.num_layers
-            * self.block_size
-            * self.num_heads
-            * self.head_dim
-            * self.dtype.size_bytes
-        )
+        """Bytes one block occupies across all layers (K + V; a latent
+        cache: its rows)."""
+        return self.block_size * self.bytes_per_token
 
     @property
     def total_bytes(self) -> int:
@@ -175,6 +203,7 @@ class CacheConfig:
         block_size: int = 16,
         dtype: DataType = DataType.FLOAT,
         kv_shards: int = 1,
+        latent: bool = False,
     ) -> "CacheConfig":
         """Size the cache against a PER-DEVICE HBM budget:
 
@@ -193,7 +222,7 @@ class CacheConfig:
         from .sharding import validate_kv_shards
 
         validate_kv_shards(num_heads, kv_shards)
-        per_block = 2 * num_layers * block_size * num_heads * head_dim * dtype.size_bytes
+        per_block = block_size * _token_bytes(num_layers, num_heads, head_dim, dtype, latent)
         num_blocks = budget_bytes * kv_shards // per_block
         if num_blocks < 2:
             raise ValueError(
@@ -209,6 +238,7 @@ class CacheConfig:
             block_size=block_size,
             dtype=dtype,
             kv_shards=kv_shards,
+            latent=latent,
         )
 
     @classmethod
@@ -224,6 +254,7 @@ class CacheConfig:
         expected_prefix_sharing: float = 0.0,
         window: int = 0,
         extra_blocks: int = 0,
+        latent: bool = False,
     ) -> "CacheConfig":
         """Worst-case slot sizing with the sharing-aware discount
         (ROADMAP item 2): the default bound gives every slot room to
@@ -264,7 +295,14 @@ class CacheConfig:
             num_blocks=1 + max(floor, discounted),
             block_size=block_size,
             dtype=dtype,
+            latent=latent,
         )
+
+
+def _token_bytes(num_layers: int, num_heads: int, head_dim: int, dtype: DataType, latent: bool) -> int:
+    if latent:
+        return num_layers * latent_row_width(num_heads * head_dim) * dtype.size_bytes
+    return 2 * num_layers * num_heads * head_dim * dtype.size_bytes
 
 
 def _per_sequence(max_seq_len: int, block_size: int, window: int) -> int:
@@ -390,17 +428,18 @@ class KVCache:
         return state
 
     @staticmethod
-    def _zeros(config: CacheConfig, sharding) -> jax.Array:
+    def _zeros(config: CacheConfig, sharding, value: bool = False) -> jax.Array:
         """One zeroed cache array, allocated where it will live: with a
         sharding each device materializes only its own head shard (never
         the whole array on device 0, then moved). K and V each get their
         own call — the decode/verify jits donate both, and XLA refuses
-        one buffer donated twice."""
+        one buffer donated twice. ``value``: V's shape (a latent cache's
+        has no width)."""
         shape = (
             config.num_layers,
             config.num_blocks,
             config.block_size,
-            *config.row_shape,
+            *(config.value_row_shape if value else config.row_shape),
         )
         return jnp.zeros(shape, config.dtype.jnp, device=sharding)
 
@@ -411,7 +450,7 @@ class KVCache:
         return cls(
             config,
             cls._zeros(config, sharding),
-            cls._zeros(config, sharding),
+            cls._zeros(config, sharding, value=True),
             sharding=sharding,
             state_config=state_config,
             window_config=window_config,
@@ -430,7 +469,7 @@ class KVCache:
         prefills, and rezeroing also clears any NaN a poisoned batch may
         have written."""
         self.k = self._zeros(self.config, self.sharding)
-        self.v = self._zeros(self.config, self.sharding)
+        self.v = self._zeros(self.config, self.sharding, value=True)
         self.state = self._state_zeros()
 
 

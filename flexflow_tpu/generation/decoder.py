@@ -19,8 +19,9 @@ setting of every one of them):
   K/V lives in arrays and tables of its own, which hold what the window
   can still reach: generation/cache.py), with rotary parameters of its
   own kind (``rope_parameters``: a theta, and for YaRN the scaled
-  frequencies and the factor on cos and sin) — or ``conv``, a gated
-  short convolution:
+  frequencies and the factor on cos and sin) — ``latent``, attention
+  whose K and V come from ONE low-rank row a token shared by all heads
+  (below) — or ``conv``, a gated short convolution:
   ``[B, C, X] = split3(W_in u)``, ``z_t = B_t * X_t``, ``c_t = sum_j
   w[:, j] * z_{t-K+1+j}`` (depthwise, causal, kernel ``K``, zeros before
   the sequence), ``out = W_out (C_t * c_t)``. Its state after position
@@ -30,12 +31,40 @@ setting of every one of them):
   routed experts after them (:func:`expert_ffn`: a ``sigmoid`` router
   with a selection bias used for the choice only, or a ``softmax`` one
   without; top-k, renormalised gates, SwiGLU experts, no capacity and
-  no dropped token);
+  no dropped token), beside them ``num_shared_experts`` experts every
+  token goes through (one SwiGLU of their summed width, added to the
+  routed sum). ``experts_held`` names the routed experts whose weights
+  THIS engine holds (one chip's share of an expert-sharded deployment:
+  the router scores all of them, the sum runs over the held ones, and
+  nothing stands in for the absent chips' part);
 * dtype: the weights' own. Every matmul accumulates in float32 and
   hands its result on in the activations' type; norms, softmax, the
   rotary angles and the router are computed in float32.
 
 Every layer is ``h = x + Op(norm(x))``, ``y = h + FFN(norm(h))``.
+
+**A latent layer** (multi-head latent attention). With ``h`` the normed
+input: ``c_q = RMSNorm(h W_DQ)``, ``q = c_q W_UQ`` -> per head ``[q_nope
+(qk_nope_head_dim), q_rope (qk_rope_head_dim)]``; ``[c_kv (kv_lora_rank),
+k_r (qk_rope_head_dim)] = h W_DKV``, ``c = RMSNorm(c_kv)``; ``q_rope``
+and the ONE ``k_r`` all heads share rotated over interleaved pairs
+``(2i, 2i+1)``; ``[k_nope_i, v_i] = c W_UKV`` per head. The cache holds
+a position's ``[c, k_r]`` (after the norm and the rotation), one row of
+``kv_lora_rank + qk_rope_head_dim`` values stored at the next multiple of
+128 lanes (generation/cache.py), and the layer has TWO forms that are
+equal in exact arithmetic:
+
+* *expanded* (``prefill``): K and V are expanded per head out of the
+  rows, ``s_i(t, j) = (q_nope_i(t) . k_nope_i(j) + q_rope_i(t) . k_r(j))
+  / sqrt(qk_nope + qk_rope)``, through :func:`masked_attention` (score
+  width and value width differ);
+* *absorbed* (``decode_step``, ``verify_step`` and with it the suffix
+  prefill behind a prefix hit): ``W_UK`` goes into the query, ``q~_i =
+  q_nope_i W_UK_i^T``, the scores are ``(q~_i . c(j) + q_rope_i . k_r(j))``
+  straight over the cached rows, the values are the rows' first
+  ``kv_lora_rank`` columns, and ``W_UV`` goes onto the result, ``o_i =
+  (sum_j p_i(t, j) c(j)) W_UV_i``. ``W_UK`` / ``W_UV`` are the two halves
+  of the stored ``W_UKV``, sliced where they are used.
 
 Four forwards over one params pytree, all through :func:`_layers`:
 
@@ -73,7 +102,8 @@ import jax.numpy as jnp
 
 from ..core.types import DataType
 from ..models.transformer import TransformerConfig
-from ..ops.attention import append_attention_core, decode_attention_core, masked_attention
+from ..ops.attention import append_attention_core, decode_attention_core, latent_attention_core, masked_attention
+from ..ops.kernels.decode_attention import latent_row_width
 from .cache import slot_mapping
 
 # a decoder is a plain pytree: jit-friendly, checkpoint-friendly
@@ -93,7 +123,7 @@ class DecoderConfig(TransformerConfig):
     qk_norm: bool = False
     num_kv_heads: int = 0  # 0: as many as query heads
     head_dim: int = 0  # 0: hidden_size // num_heads
-    layer_types: Tuple[str, ...] = ()  # per layer "attention" | "window" | "conv"; (): all attention
+    layer_types: Tuple[str, ...] = ()  # per layer "attention" | "window" | "latent" | "conv"; (): all attention
     window: int = 0  # positions a "window" layer's query attends, its own included
     # rotary parameters by attention kind ("attention" / "window"); a kind
     # without an entry has plain `rope_theta`. Keys: "theta" and, for YaRN,
@@ -109,17 +139,39 @@ class DecoderConfig(TransformerConfig):
     routed_scaling_factor: float = 1.0
     router: str = "sigmoid"  # | "softmax" (no selection bias)
     tied_head: bool = False  # logits = x E^T, no output matrix of its own
+    # a "latent" layer (module docstring): the query's bottleneck, the
+    # cached row's low-rank part, the score's unrotated and rotated
+    # widths and the value's width a head
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False  # rotate pairs (2i, 2i+1), not (i, i + D/2)
+    num_shared_experts: int = 0  # experts every token goes through, beside the routed ones
+    # the routed experts whose weights this engine holds, in the order
+    # ew1 / ew3 / ew2 stack them; (): all `num_experts` of them
+    experts_held: Tuple[int, ...] = ()
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.num_layers:
             raise ValueError(f"{len(self.layer_types)} layer_types for {self.num_layers} layers")
         for kind in self.layer_types:
-            if kind not in ("attention", "window", "conv"):
-                raise ValueError(f"layer type {kind!r}: 'attention', 'window' or 'conv'")
+            if kind not in ("attention", "window", "latent", "conv"):
+                raise ValueError(f"layer type {kind!r}: 'attention', 'window', 'latent' or 'conv'")
         if "window" in self.layer_types and self.window < 1:
             raise ValueError("a 'window' layer needs window >= 1")
         if self.router not in ("sigmoid", "softmax"):
             raise ValueError(f"router {self.router!r}: 'sigmoid' or 'softmax'")
+        if "latent" in self.layer_types:
+            if set(self.layer_types) != {"latent"}:
+                raise ValueError("latent layers beside another kind: one cache holds rows of one width")
+            widths = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim)
+            if min(widths) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(f"a 'latent' layer needs its five widths (and an even rotary one), got {widths}")
+        self.experts_held = tuple(int(i) for i in self.experts_held)
+        if self.experts_held and not all(0 <= i < self.num_experts for i in self.experts_held):
+            raise ValueError(f"experts_held {self.experts_held} outside the {self.num_experts} routed experts")
 
     @property
     def kv_heads(self) -> int:
@@ -146,13 +198,28 @@ class DecoderConfig(TransformerConfig):
 
     @property
     def full_layers(self) -> Tuple[int, ...]:
-        return tuple(l for l in range(self.num_layers) if self.operator(l) == "attention")
+        """Layers whose cache keeps every position: the main pool's."""
+        return tuple(l for l in range(self.num_layers) if self.operator(l) in ("attention", "latent"))
+
+    @property
+    def latent_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "latent")
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches a position: ``[c, k_r]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights a layer holds here."""
+        return len(self.experts_held) or self.num_experts
 
     @property
     def kv_index(self) -> Tuple[Tuple[str, int], ...]:
         """For the ``ai``-th attention layer: its kind and its index in
         that kind's K/V arrays."""
-        seen = {"attention": 0, "window": 0}
+        seen = {"attention": 0, "window": 0, "latent": 0}
         out = []
         for l in self.attention_layers:
             kind = self.operator(l)
@@ -215,7 +282,9 @@ def init_decoder_params(
     e, h, hk, d = cfg.hidden_size, cfg.num_heads, cfg.kv_heads, cfg.dim_per_head
     f, v = cfg.ff_size, cfg.vocab_size
     p = max_positions or cfg.seq_length
-    keys = iter(jax.random.split(rng, 4 + 10 * cfg.num_layers))
+    # (a configuration without latent layers or shared experts draws the keys it always drew)
+    per_layer = 14 if cfg.latent_layers or cfg.num_shared_experts else 10
+    keys = iter(jax.random.split(rng, 4 + per_layer * cfg.num_layers))
     ones, zeros = jnp.ones((e,), dt), jnp.zeros((e,), dt)
     params: DecoderParams = {"tok_embed": _glorot(next(keys), (v, e), dt)}
     pos_key, head_key = next(keys), next(keys)
@@ -231,7 +300,15 @@ def init_decoder_params(
         layer: Dict[str, Any] = {"ln1_g": ones}
         if cfg.norm == "layernorm":
             layer["ln1_b"] = zeros
-        if cfg.operator(li) != "conv":
+        if cfg.operator(li) == "latent":
+            rq, rkv, dn, dr, dv = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+            layer.update(
+                w_dq=_glorot(next(keys), (e, rq), dt), q_lora_g=jnp.ones((rq,), dt),
+                w_uq=_glorot(next(keys), (rq, h, dn + dr), dt),
+                w_dkv=_glorot(next(keys), (e, rkv + dr), dt), kv_lora_g=jnp.ones((rkv,), dt),
+                w_ukv=_glorot(next(keys), (rkv, h, dn + dv), dt), wo=_glorot(next(keys), (h, dv, e), dt),
+            )
+        elif cfg.operator(li) != "conv":
             layer.update(
                 wq=_glorot(next(keys), (e, h, d), dt), wk=_glorot(next(keys), (e, hk, d), dt),
                 wv=_glorot(next(keys), (e, hk, d), dt), wo=_glorot(next(keys), (h, d, e), dt),
@@ -263,10 +340,17 @@ def init_decoder_params(
             layer.update(router=_glorot(next(keys), (e, n)))
             if cfg.router == "sigmoid":
                 layer.update(router_bias=0.02 * jax.random.normal(next(keys), (n,), jnp.float32))
+            n = cfg.held_experts  # the router scores every expert; the weights are the held ones'
             layer.update(
                 ew1=_glorot(next(keys), (n, e, fe), dt), ew3=_glorot(next(keys), (n, e, fe), dt),
                 ew2=_glorot(next(keys), (n, fe, e), dt),
             )
+            if cfg.num_shared_experts:
+                fs = cfg.num_shared_experts * fe
+                layer.update(
+                    sw1=_glorot(next(keys), (e, fs), dt), sw3=_glorot(next(keys), (e, fs), dt),
+                    sw2=_glorot(next(keys), (fs, e), dt),
+                )
         params["layers"].append(layer)
     return params
 
@@ -360,6 +444,68 @@ def _qkv(cfg: DecoderConfig, layer, h, positions, kind: str = "attention"):
     return q, k, v
 
 
+def _rope_pairs(x, positions, theta: float):
+    """Rotary embedding over interleaved pairs ``(2i, 2i+1)`` of the
+    head's dimensions (``rope_interleave``): x [..., H, D], positions
+    [...] (the leading axes of x), as :func:`_rope` takes them."""
+    d = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = (positions.astype(jnp.float32)[..., None] * inv)[..., None, :]  # [..., 1, D/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    even, odd = xf[..., 0], xf[..., 1]
+    out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _latent_qkv(cfg: DecoderConfig, layer, h, positions):
+    """A latent layer's projections (module docstring): the queries
+    [..., H, qk_nope + qk_rope], their rotary part rotated, and the
+    position's cache row [..., RW]: ``[c, k_r]`` after the norm and the
+    rotation, zero-filled to the stored width."""
+    dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    rotate = _rope_pairs if cfg.rope_interleave else _rope
+    c_q = _mm("...e,er->...r", h, layer["w_dq"])
+    c_q = _rms(c_q.astype(jnp.float32), layer["q_lora_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
+    q = _mm("...r,rhd->...hd", c_q, layer["w_uq"])
+    q = jnp.concatenate([q[..., :dn], rotate(q[..., dn:], positions, cfg.rope_theta)], axis=-1)
+    kv = _mm("...e,er->...r", h, layer["w_dkv"])
+    c = _rms(kv[..., :rkv].astype(jnp.float32), layer["kv_lora_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
+    k_r = rotate(kv[..., None, rkv:], positions, cfg.rope_theta)[..., 0, :]  # ONE rotary key, all heads'
+    fill = jnp.zeros(kv.shape[:-1] + (latent_row_width(cfg.latent_width) - cfg.latent_width,), h.dtype)
+    return q, jnp.concatenate([c, k_r, fill], axis=-1)
+
+
+def _expanded(cfg: DecoderConfig, q, rows, w_ukv, lens):
+    """The latent layer's EXPANDED form over a whole window of rows
+    ([B, S, RW]): K and V per head out of the rows, then masked causal
+    attention at score width ``qk_nope + qk_rope`` and value width
+    ``v_head_dim``. Returns [B, S, H, v_head_dim]."""
+    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = _mm("bsc,chd->bshd", rows[..., :rkv], w_ukv)
+    k_r = jnp.broadcast_to(rows[:, :, None, rkv:cfg.latent_width], kv.shape[:3] + (cfg.qk_rope_head_dim,))
+    k = jnp.concatenate([kv[..., :dn], k_r], axis=-1)
+    return masked_attention(q, k, kv[..., dn:], lens, causal=True)
+
+
+def _absorbed(cfg: DecoderConfig, q, w_ukv, cache, at: int, tables, q_positions, backend: str):
+    """The latent layer's ABSORBED form over the cache: q [B, W, H,
+    qk_nope + qk_rope] (its rows already written at ``q_positions`` [B,
+    W]) -> [B, W, H, v_head_dim]. ``W_UK`` goes into the query and
+    ``W_UV`` onto the attended rows, both halves of ``w_ukv`` sliced
+    here; the scale is the expanded scores' (``1 / sqrt(qk_nope +
+    qk_rope)``, NOT of the row's width)."""
+    rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q_abs = _mm("bwhd,chd->bwhc", q[..., :dn], w_ukv[..., :dn])
+    fill = jnp.zeros(q.shape[:-1] + (cache.shape[-1] - cfg.latent_width,), q.dtype)
+    q_row = jnp.concatenate([q_abs, q[..., dn:], fill], axis=-1)  # laid out as a cache row is
+    ctx = latent_attention_core(
+        q_row, cache, at, tables, q_positions, value_width=rkv, scale=(dn + cfg.qk_rope_head_dim) ** -0.5,
+        backend=backend,
+    )
+    return _mm("bwhc,chd->bwhd", ctx, w_ukv[..., dn:])
+
+
 def conv_window(state, z):
     """``z`` [B, T, E] behind the ``K - 1`` rows that came before it
     (``state`` [B, K-1, E]; zeros at the start of a sequence): the
@@ -437,6 +583,14 @@ def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = Non
     return out.astype(v.dtype), gates
 
 
+def _swiglu(h, w1, w3, w2):
+    """``W2 (silu(W1 h) * W3 h)``: both products in float32, their gated
+    product handed on in the activations' type."""
+    up = jnp.einsum("...e,ef->...f", h, w1, preferred_element_type=jnp.float32)
+    gate_up = jnp.einsum("...e,ef->...f", h, w3, preferred_element_type=jnp.float32)
+    return _mm("...f,fe->...e", (jax.nn.silu(up) * gate_up).astype(h.dtype), w2)
+
+
 def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List]):
     """``x + FFN(norm(x))`` of layer ``li``. ``live`` ([...] bool, the
     leading axes of x) says which rows are real tokens: only those are
@@ -447,17 +601,27 @@ def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List]):
             h = _norm(cfg, x, layer, "ln2")
             rows = h.reshape(-1, h.shape[-1])
         with jax.named_scope("experts"):
-            out, gates = expert_ffn(cfg, layer, rows)
+            out, gates = expert_ffn(cfg, layer, rows, held=cfg.experts_held or None)
+        if cfg.num_shared_experts:
+            with jax.named_scope("shared_expert"):
+                out = out + _swiglu(rows, layer["sw1"], layer["sw3"], layer["sw2"])
         if counts is not None:
             with jax.named_scope("router"):
-                counts.append(jnp.sum((gates > 0) & live.reshape(-1, 1), axis=0, dtype=jnp.int32))
+                chosen = (gates > 0) & live.reshape(-1, 1)
+                if cfg.experts_held:
+                    # a share of the experts: the held ones' tokens, in the order they are
+                    # held, and in one more column the live tokens none of them was chosen for
+                    chosen = chosen[:, jnp.asarray(cfg.experts_held)]
+                    nowhere = jnp.sum(live.reshape(-1) & ~jnp.any(chosen, axis=1), dtype=jnp.int32)
+                    row = jnp.concatenate([jnp.sum(chosen, axis=0, dtype=jnp.int32), nowhere[None]])
+                else:
+                    row = jnp.sum(chosen, axis=0, dtype=jnp.int32)
+                counts.append(row)
         return x + out.reshape(x.shape)
     with jax.named_scope("mlp"):
         h = _norm(cfg, x, layer, "ln2")
         if kind == "swiglu":
-            up = jnp.einsum("...e,ef->...f", h, layer["w1"], preferred_element_type=jnp.float32)
-            gate_up = jnp.einsum("...e,ef->...f", h, layer["w3"], preferred_element_type=jnp.float32)
-            return x + _mm("...f,fe->...e", (jax.nn.silu(up) * gate_up).astype(x.dtype), layer["w2"])
+            return x + _swiglu(h, layer["w1"], layer["w3"], layer["w2"])
         h = jax.nn.gelu(_mm("...e,ef->...f", h, layer["ff1"]) + layer["ff1_b"])
         return x + _mm("...f,fe->...e", h, layer["ff2"]) + layer["ff2_b"]
 
@@ -484,14 +648,26 @@ def _layers(
 
     Scope names land in the instructions' op_name, so a device trace can
     be grouped by them: ``layer<i>/attention | cache_write | conv |
-    conv_state | mlp | router | experts``; a configuration with window
-    layers names the two kinds apart, ``attention.window`` and
-    ``attention.full`` (:func:`attention_scope`)."""
+    conv_state | mlp | router | experts | shared_expert``; a configuration
+    with window layers names the two kinds apart, ``attention.window``
+    and ``attention.full`` (:func:`attention_scope`); a latent layer is
+    ``attention.latent``, its attention proper ``attention.latent.expand``
+    or ``attention.latent.absorb`` by the form the forward runs."""
     ai = ci = 0
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
             kind = cfg.operator(li)
-            if kind != "conv":
+            if kind == "latent":
+                # the callback takes the position's cache row for k and the
+                # layer's up-projection for v: it expands or absorbs
+                with jax.named_scope("attention.latent"):
+                    h = _norm(cfg, x, layer, "ln1")
+                    q, row = _latent_qkv(cfg, layer, h, positions)
+                ctx = attend(ai, q, row, layer["w_ukv"])
+                with jax.named_scope("attention.latent"):
+                    x = x + _mm("...hd,hde->...e", ctx, layer["wo"])
+                ai += 1
+            elif kind != "conv":
                 scope = attention_scope(cfg, kind)
                 with jax.named_scope(scope):
                     h = _norm(cfg, x, layer, "ln1")
@@ -547,7 +723,8 @@ def prefill(
 ):
     """Prefill forward: logits [B, S, V] plus every attention layer's
     K/V ([n_attn, B, S, Hkv, D] each, both kinds in layer order:
-    ``cfg.kv_index`` says which array each belongs in) for the engine to
+    ``cfg.kv_index`` says which array each belongs in; latent layers:
+    their rows [n, B, S, RW] and a V of no width) for the engine to
     write into the cache and, for a configuration with convolution layers, a fourth
     result: their padded ``z`` rows [n_conv, B, S + K - 1, E]."""
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
@@ -559,9 +736,14 @@ def prefill(
     ks, vs, zs = [], [], []
 
     def attend(ai, q, k, v):
+        kind = cfg.kv_index[ai][0]
+        if kind == "latent":
+            ks.append(k)  # the rows, as stored; V has no width
+            vs.append(k[..., :0])
+            with jax.named_scope("attention.latent.expand"):
+                return _expanded(cfg, q, k, v, lens)
         ks.append(k)
         vs.append(v)
-        kind = cfg.kv_index[ai][0]
         with jax.named_scope(attention_scope(cfg, kind)):
             if kind == "window":
                 return masked_attention(q, k, v, lens, causal=True, window=cfg.window)
@@ -584,7 +766,8 @@ def write_rows(cache, layer: int, block, offset, rows):
     """Write a step's rows ([n, H, D]) of static ``layer`` at
     ``(block[n], offset[n])`` with ONE scatter on the whole [L,
     num_blocks, block_size, R, LW] operand (a position's H x D values
-    stored row-major as R x LW: generation/cache.py). With the operand
+    stored row-major as R x LW: generation/cache.py; a latent layer's
+    [n, RW] rows into [L, num_blocks, block_size, RW]). With the operand
     donated the scatter runs in place: the step touches n rows, not a
     layer. Taking ``cache[layer]`` out, or writing a rebuilt layer back,
     would make every step copy layer-sized values (ISSUE 24)."""
@@ -647,6 +830,11 @@ def decode_step(
         # write this token's K/V, then attend over the updated cache
         # so the token sees itself (context_lens includes it)
         kind, at = cfg.kv_index[ai]
+        if kind == "latent":
+            with jax.named_scope("cache_write"):
+                state["k"] = write_rows(state["k"], at, block, offset, k)
+            with jax.named_scope("attention.latent.absorb"):
+                return _absorbed(cfg, q[:, None], v, state["k"], at, block_tables, context_lens[:, None] - 1, backend)[:, 0]
         # a window layer's arrays, table and bounds, or the full layers'
         kk, vv, tables, blk, off, bounds = ("k", "v", block_tables, block, offset, {}) if kind != "window" else (
             "wk", "wv", window["tables"], wblock, woffset, {"window": cfg.window, "first_positions": window["first"]})
@@ -737,6 +925,11 @@ def verify_step(
         # cache with per-query position masks (each token sees itself
         # and everything before it, nothing after)
         kind, at = cfg.kv_index[ai]
+        if kind == "latent":
+            with jax.named_scope("cache_write"):
+                state["k"] = write_rows(state["k"], at, block, offset, k.reshape(-1, k.shape[-1]))
+            with jax.named_scope("attention.latent.absorb"):
+                return _absorbed(cfg, q, v, state["k"], at, block_tables, positions, backend)
         kk, vv, tables, blk, off, bounds = ("k", "v", block_tables, block, offset, {}) if kind != "window" else (
             "wk", "wv", window["tables"], wblock, woffset, {"window": cfg.window, "first_positions": window["first"]})
         with jax.named_scope("cache_write"):

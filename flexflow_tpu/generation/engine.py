@@ -59,7 +59,7 @@ from ..models.transformer import TransformerConfig
 from ..obs.capacity import ProgramRegistry, ServingFlops
 from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
-from ..ops.attention import paged_call_lowering
+from ..ops.attention import latent_call_lowering, paged_call_lowering
 from ..runtime import faults
 from .cache import (
     BlockAllocator, CacheConfig, KVCache, StateConfig, WindowTable, pools_from_budget, slot_mapping,
@@ -210,6 +210,66 @@ def derive_window_keys(seeds, counts, window: int):
     )(seeds, counts)
 
 
+def unsupported_paths(kind: str, dcfg) -> Dict[str, str]:
+    """``{path: reason}`` for the paths that cannot carry what a layer of
+    ``kind`` keeps per sequence, each refused by name when it is taken
+    (``GenerationEngine.unsupported``; ROADMAP "What the system cannot
+    run yet")."""
+    w = dcfg.window
+    return {
+        "conv": {
+            "speculation": (
+                "speculative verification (engine.verify) is refused for a configuration "
+                "with convolution layers: the window's z rows would have to be kept and "
+                "each slot's convolution state chosen at its accepted length"
+            ),
+            "kv_handoff": (
+                "the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused "
+                "for a configuration with convolution layers: a payload carries K/V blocks "
+                "and no convolution state, so the decode side could not continue from it"
+            ),
+            "tensor_parallel": (
+                "tp_degree > 1 is refused for a configuration with convolution layers: the "
+                "serving layout shards attention heads and the FFN, and has no placement "
+                "for the convolution operator, its state or the expert weights"
+            ),
+        },
+        "window": {
+            "speculation": (
+                f"speculative verification (engine.verify) is refused for a configuration with "
+                f"sliding-window layers (window {w}): a rejected draft would have to give back the "
+                f"window blocks its positions took and take back those they released"
+            ),
+            "kv_handoff": (
+                f"the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a "
+                f"configuration with sliding-window layers (window {w}): a payload carries one table's "
+                f"blocks, and the decode side would need the window layers' for the last {w} positions"
+            ),
+            "tensor_parallel": (
+                f"tp_degree > 1 is refused for a configuration with sliding-window layers (window {w}): "
+                f"the head-sharded paged kernel takes no window, and the window pool has no sharding"
+            ),
+        },
+        "latent": {
+            "speculation": (
+                "speculative verification (engine.verify) is refused for a configuration with latent "
+                "layers: the verify program takes K and V of one head width, and the model's own "
+                "next-token module, the drafter such a model is served with, is not loaded"
+            ),
+            "kv_handoff": (
+                "the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a "
+                "configuration with latent layers: the wire format is K and V blocks of heads, and a "
+                "latent block is one array of rows that no importing engine's shape check knows"
+            ),
+            "tensor_parallel": (
+                "tp_degree > 1 is refused for a configuration with latent layers: one latent row a "
+                "position cannot be sharded by head, and the serving layout has no placement for "
+                "data-parallel attention beside shares of experts"
+            ),
+        },
+    }[kind]
+
+
 class InFlightDecode:
     """One dispatched-but-unconsumed decode step (the overlap pipeline's
     frontier unit). Holds the device result refs, the async host copies
@@ -299,51 +359,16 @@ class GenerationEngine:
             or (mesh is not None and int(dict(mesh.shape).get("model", 1)) > 1)
             or (tp_degree is None and mesh is None and (mesh_devices or 1) > 1)
         )
-        if self.dcfg.stateful:
-            self.unsupported = {
-                "speculation": (
-                    "speculative verification (engine.verify) is refused for a configuration "
-                    "with convolution layers: the window's z rows would have to be kept and "
-                    "each slot's convolution state chosen at its accepted length"
-                ),
-                "kv_handoff": (
-                    "the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused "
-                    "for a configuration with convolution layers: a payload carries K/V blocks "
-                    "and no convolution state, so the decode side could not continue from it"
-                ),
-                "tensor_parallel": (
-                    "tp_degree > 1 is refused for a configuration with convolution layers: the "
-                    "serving layout shards attention heads and the FFN, and has no placement "
-                    "for the convolution operator, its state or the expert weights"
-                ),
-            }
-            if wants_tp:
-                raise NotImplementedError(self.unsupported["tensor_parallel"])
-        if self.dcfg.window_layers:
-            if self.dcfg.stateful:
-                raise NotImplementedError(
-                    "a configuration with both convolution and sliding-window layers is refused: a "
-                    "cached block would carry a convolution snapshot and a window half at once"
-                )
-            w = self.dcfg.window
-            self.unsupported = {
-                "speculation": (
-                    f"speculative verification (engine.verify) is refused for a configuration with "
-                    f"sliding-window layers (window {w}): a rejected draft would have to give back the "
-                    f"window blocks its positions took and take back those they released"
-                ),
-                "kv_handoff": (
-                    f"the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a "
-                    f"configuration with sliding-window layers (window {w}): a payload carries one table's "
-                    f"blocks, and the decode side would need the window layers' for the last {w} positions"
-                ),
-                "tensor_parallel": (
-                    f"tp_degree > 1 is refused for a configuration with sliding-window layers (window {w}): "
-                    f"the head-sharded paged kernel takes no window, and the window pool has no sharding"
-                ),
-            }
-            if wants_tp:
-                raise NotImplementedError(self.unsupported["tensor_parallel"])
+        if self.dcfg.window_layers and self.dcfg.stateful:
+            raise NotImplementedError(
+                "a configuration with both convolution and sliding-window layers is refused: a "
+                "cached block would carry a convolution snapshot and a window half at once"
+            )
+        for kind in ("conv", "window", "latent"):
+            if kind in self.dcfg.layer_types:
+                self.unsupported.update(unsupported_paths(kind, self.dcfg))
+        if wants_tp and "tensor_parallel" in self.unsupported:
+            raise NotImplementedError(self.unsupported["tensor_parallel"])
         # ------------------------------------------------- serving mesh
         # Mesh-native engine (ISSUE 15): decoder weights and the KV
         # cache shard along the head axis over a "model" mesh axis
@@ -431,6 +456,9 @@ class GenerationEngine:
                 block_size=block_size,
                 dtype=cfg.dtype,
             )
+            if self.dcfg.latent_layers:
+                # one row a position, all heads': cache.py "Latent rows"
+                kv.update(num_heads=1, head_dim=self.dcfg.latent_width, latent=True)
             if cache_budget_bytes is not None:
                 # per-device HBM budget: the head-sharded cache holds
                 # H/tp heads of every block per chip, so the same chip
@@ -475,6 +503,12 @@ class GenerationEngine:
         self.window_tables: Dict[int, WindowTable] = {}
         self.window_released_total = 0
         self._live_blocks = (0, 0)  # (full, window) blocks the last decode step's sequences held
+        # a latent cache (the `cache.latent` section of /v2/stats): the
+        # positions the last decode step's sequences held, and the
+        # layers' attention calls by form
+        self.latent_tokens_held = 0
+        self.latent_calls: Dict[str, int] = {"absorbed": 0, "expanded": 0}
+        self._n_latent = len(self.dcfg.latent_layers)  # 0: a step counts none of this
         self.window_held_peak = 0  # the most window blocks any one sequence held at a step
         # tokens every expert of every expert layer was handed, and the
         # calls that handed them, counted ON THE DEVICE: the step
@@ -487,7 +521,10 @@ class GenerationEngine:
         self.expert_counts: Dict[str, jax.Array] = {}
         if self.dcfg.expert_layers:
             self.expert_counts = {
-                "tokens": jnp.zeros((len(self.dcfg.expert_layers), self.dcfg.num_experts), jnp.int32),
+                # (a share of the experts: the held ones' columns and one more, the tokens none of them was chosen for)
+                "tokens": jnp.zeros(
+                    (len(self.dcfg.expert_layers), self.dcfg.held_experts + bool(self.dcfg.experts_held)), jnp.int32
+                ),
                 "calls": jnp.zeros((2,), jnp.int32),  # decode, prefill
             }
         # convolution-state traffic of the prefix cache (the conv_state
@@ -1080,13 +1117,19 @@ class GenerationEngine:
         )
 
     def _logical(self, blocks):
-        """Stored [L, ..., bs, R, LW] -> logical [L, ..., bs, H, D]."""
+        """Stored [L, ..., bs, R, LW] -> logical [L, ..., bs, H, D]. (A
+        latent cache's rows, and its V of no width, leave the device as
+        they are stored.)"""
         cc = self.cache_config
+        if cc.latent:
+            return blocks
         return blocks.reshape(*blocks.shape[:-2], cc.num_heads, cc.head_dim)
 
     def _stored(self, blocks, like):
         """Logical [L, ..., bs, H, D] -> as ``like`` (a cache array)
         stores it: [L, ..., bs, R, LW], in its dtype."""
+        if self.cache_config.latent:
+            return blocks.astype(like.dtype)
         return blocks.reshape(*blocks.shape[:-2], *like.shape[3:]).astype(like.dtype)
 
     def _write_block_impl(self, cache_k, cache_v, dst, host_k, host_v, snap=None, host_s=None):
@@ -1198,6 +1241,7 @@ class GenerationEngine:
         if prefix_len > 0:
             return self._prefill_suffix(prompt, block_table, sampling, key, prefix_len, mask, slot)
         self.step_counts["prefill"] += 1
+        self.latent_calls["expanded"] += self._n_latent
         if self.window_config is not None and slot not in self.window_tables:
             # (a caller that assembled its own table: prepare_prefix does this for the scheduler)
             self._window_admit(slot, len(prompt), 0, [])
@@ -1276,6 +1320,7 @@ class GenerationEngine:
         prefill(): step/FLOPs/time under the "prefill" kind, compile
         calls registry-stamped, steady calls ledger-paired."""
         self.step_counts["prefill"] += 1
+        self.latent_calls["absorbed"] += self._n_latent
         if self.state_config is not None:
             self._restore_state(slot, block_table[prefix_len // self.cache_config.block_size - 1])
         with phase("engine.prefill.dispatch") as disp:
@@ -1469,6 +1514,8 @@ class GenerationEngine:
         the bytes the sequences of the last decode step held, and the
         bytes ONE table for all attention layers would hold for them."""
         cc, wc = self.cache_config, self.window_config
+        if cc.latent:
+            return {"latent": self.latent_stats()}
         full, window = self._live_blocks
         return {
             "full": {"layers": cc.num_layers, "blocks_total": self.allocator.num_total,
@@ -1483,6 +1530,23 @@ class GenerationEngine:
             "window_released_total": self.window_released_total,
             "live_bytes": full * cc.bytes_per_block + window * wc.bytes_per_block,
             "one_table_bytes": full * (cc.bytes_per_block + wc.bytes_per_block),
+        }
+
+    def latent_stats(self) -> Dict:
+        """``cache.latent`` of ``/v2/stats``: what a cached position
+        costs (all latent layers, as stored), the positions the last
+        decode step's sequences held and their bytes, what per-head K/V
+        of the same sequences would hold, and the latent layers'
+        attention calls by the form they ran."""
+        cc, d = self.cache_config, self.dcfg
+        per_head = cc.num_layers * d.num_heads * (d.qk_nope_head_dim + d.qk_rope_head_dim + d.v_head_dim)
+        held = self.latent_tokens_held
+        return {
+            "layers": cc.num_layers, "entry_width": d.latent_width, "stored_width": cc.row_shape[0],
+            "bytes_per_token": cc.bytes_per_token, "tokens_held": held, "live_bytes": held * cc.bytes_per_token,
+            "per_head_bytes": held * per_head * cc.dtype.size_bytes,
+            "absorbed_calls_total": self.latent_calls["absorbed"],
+            "expanded_calls_total": self.latent_calls["expanded"],
         }
 
     def blocks_in_use(self) -> Tuple[int, int]:
@@ -2063,6 +2127,9 @@ class GenerationEngine:
         the truth ledger."""
         flops = self.flops_model.decode_flops(n_active, ctx_sum)
         self.flops_by_kind["decode"] += flops
+        if self._n_latent:
+            self.latent_tokens_held = ctx_sum
+            self.latent_calls["absorbed"] += self._n_latent
         if traced:
             self.programs.set_compile_time("decode", elapsed)
         else:
@@ -2353,7 +2420,15 @@ class GenerationEngine:
         the step in flight): a scrape pays for it, a step never does."""
         tokens = np.asarray(self.expert_counts["tokens"]).astype(np.int64)
         calls = np.asarray(self.expert_counts["calls"])
+        held: Dict = {}
+        if self.dcfg.experts_held:
+            # a share of the experts: `tokens_total` lists the held ones
+            # (in the order `held` names them), and the last column the
+            # tokens none of the held was chosen for, counted once a layer
+            held = {"held": [int(i) for i in self.dcfg.experts_held], "unrouted_here_total": int(tokens[:, -1].sum())}
+            tokens = tokens[:, :-1]
         return {
+            **held,
             "layers": [int(l) for l in self.dcfg.expert_layers],
             "experts": int(self.dcfg.num_experts),
             "experts_per_token": int(self.dcfg.experts_per_token),
@@ -2365,8 +2440,11 @@ class GenerationEngine:
 
     def paged_lowerings(self) -> Dict[str, Dict]:
         """``{"body", "group"}`` per attention kind the model has
-        (``full``, ``window``), asked of the gate the step programs'
+        (``full``, ``window``; ``latent`` alone where the layers are
+        latent), asked of the gate the step programs'
         dispatch asks (ops/attention.py ``paged_call_lowering``)."""
+        if self.cache_config.latent:
+            return {"latent": latent_call_lowering(self.dcfg.num_heads, self.cache.k, backend=self.backend)}
         kinds = {"full": self.cache.k}
         if self.window_config is not None:
             kinds["window"] = self.cache.state["wk"]

@@ -671,7 +671,7 @@ class ContinuousBatchingScheduler:
             self.stats.add_section("experts", engine.expert_stats)
         if engine.state_config is not None:
             self.stats.add_section("conv_state", engine.conv_state_stats)
-        if engine.window_config is not None:
+        if engine.window_config is not None or engine.cache_config.latent:
             self.stats.add_section("cache", engine.cache_stats)
         self.spec_stats = SpeculationStats()
         self.spec_stats.register_gauges(self.stats)

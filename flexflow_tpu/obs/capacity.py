@@ -346,7 +346,12 @@ class ServingFlops:
         full batch reads every expert), K/V for the attention layers
         alone, and by kind of attention layer: a full layer attends a
         token's whole context, a sliding-window layer the ``window``
-        positions behind it (``windowed``)."""
+        positions behind it (``windowed``). A latent layer: its five
+        matrices, attention in the absorbed form (per head and attended
+        position ``latent_width`` multiply-adds for the score and
+        ``kv_lora_rank`` for the value), and ONE row a position in the
+        cache, at its stored width; the shared experts every token goes
+        through; of the routed experts the bytes of those held here."""
         model = cls(
             num_layers=cfg.num_layers,
             hidden_size=cfg.hidden_size,
@@ -360,8 +365,13 @@ class ServingFlops:
         e, v = cfg.hidden_size, cfg.vocab_size
         q, kv = cfg.num_heads * cfg.dim_per_head, cfg.kv_heads * cfg.dim_per_head
         flops, params, n_attn, n_window = 2 * e * v, v * e * (1 if cfg.tied_head else 2), 0, 0
+        n_latent = len(cfg.latent_layers)
         for l in range(cfg.num_layers):
-            if cfg.operator(l) == "attention":
+            if cfg.operator(l) == "latent":
+                h, qk = cfg.num_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                op = (e * cfg.q_lora_rank + cfg.q_lora_rank * h * qk + e * cfg.latent_width
+                      + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim) + h * cfg.v_head_dim * e)
+            elif cfg.operator(l) == "attention":
                 op = 2 * e * q + 2 * e * kv
                 n_attn += 1
             elif cfg.operator(l) == "window":
@@ -372,8 +382,8 @@ class ServingFlops:
             kind = cfg.ffn_kind(l)
             if kind == "experts":
                 per_expert = 3 * e * cfg.moe_ff_size
-                ffn, ffn_params = cfg.experts_per_token * per_expert + e * cfg.num_experts, (
-                    cfg.num_experts * per_expert + e * cfg.num_experts)
+                ffn, ffn_params = (cfg.experts_per_token + cfg.num_shared_experts) * per_expert + e * cfg.num_experts, (
+                    (cfg.held_experts + cfg.num_shared_experts) * per_expert + e * cfg.num_experts)
             else:
                 ffn = ffn_params = (3 if kind == "swiglu" else 2) * e * cfg.ff_size
             flops += 2 * (op + ffn)
@@ -383,6 +393,11 @@ class ServingFlops:
         model.param_count = params
         model.param_bytes = params * model.dtype_bytes
         model.kv_bytes_per_pos = 2 * n_attn * kv * model.dtype_bytes
+        if n_latent:
+            from ..ops.kernels.decode_attention import latent_row_width
+
+            model.per_ctx_flops = n_latent * 2 * cfg.num_heads * (cfg.latent_width + cfg.kv_lora_rank)
+            model.kv_bytes_per_pos = n_latent * latent_row_width(cfg.latent_width) * model.dtype_bytes
         model.window = getattr(cfg, "window", 0) if n_window else 0
         model.window_ctx_flops = n_window * 4 * q
         model.window_kv_bytes_per_pos = 2 * n_window * kv * model.dtype_bytes

@@ -28,12 +28,15 @@ from ..device import on_tpu
 from .base import LowerCtx, OpCost, OpDef, WeightSpec, io_cost, register_op
 from .kernels.decode_attention import (
     kernel_body,
+    latent_kernel_refusal,
     paged_append_attention,
     paged_decode_attention,
     paged_kernel_refusal,
+    paged_latent_attention,
     query_group,
     reference_paged_append_attention,
     reference_paged_attention,
+    reference_paged_latent_attention,
     sharded_paged_append_attention,
     sharded_paged_decode_attention,
 )
@@ -388,6 +391,46 @@ def append_attention_core(
     )
 
 
+def latent_call_lowering(num_heads: int, cache, backend: str = "tpu", window: int = 1):
+    """What a latent layer's paged call of ``window`` queries of
+    ``num_heads`` heads over ``cache`` ([L, num_blocks, block_size, RW])
+    lowers to, as :func:`latent_attention_core` will decide it: ``{"body",
+    "group"}``, the body ``"mxu"`` (kernels/decode_attention.py
+    ``paged_latent_attention``: every head reads the one row, a group of
+    all of them) or ``"reference"``, the XLA composition, on the CPU
+    backend and where the gate refuses."""
+    body = "reference"
+    if backend == "tpu" and on_tpu():
+        reason = latent_kernel_refusal(window * num_heads, cache.shape[3], cache.shape[2], cache.dtype.itemsize)
+        if reason is None:
+            body = "mxu"
+        else:
+            _note_refusal("paged_latent_attention", reason)
+    return {"body": body, "group": num_heads}
+
+
+def latent_attention_core(
+    q: jax.Array,
+    cache: jax.Array,
+    layer: int,
+    block_tables: jax.Array,
+    q_positions: jax.Array,
+    value_width: int,
+    scale: float,
+    backend: str = "tpu",
+) -> jax.Array:
+    """A latent layer's absorbed attention: a window of queries laid out
+    as cache rows ([B, W, H, RW]) over static ``layer`` of the whole
+    latent cache ([L, num_blocks, block_size, RW]: one row a position,
+    read once for scores and values). Returns [B, W, H, value_width].
+    The Pallas kernel on the TPU backend, the same arithmetic as an XLA
+    composition on the CPU backend and for windows past the kernel's
+    bound (a suffix-prefill bucket)."""
+    lowering = latent_call_lowering(q.shape[2], cache, backend, window=q.shape[1])
+    call = paged_latent_attention if lowering["body"] == "mxu" else reference_paged_latent_attention
+    return call(q, cache, layer, block_tables, q_positions, value_width, scale)
+
+
 def _behind_window(sq: int, sk: int, window: int):
     """[Sq, Sk] bool: key ``s`` lies within the ``window`` positions up
     to query ``t`` (``s > t - window``; the causal mask holds ``s <= t``)."""
@@ -395,8 +438,9 @@ def _behind_window(sq: int, sk: int, window: int):
 
 
 def masked_attention(q, k, v, lengths, causal=True, scale=None, window=0):
-    """Causal attention over [B, S, H, D] with a per-sequence valid
-    length: key positions >= lengths[b] are masked. The prefill side of
+    """Causal attention over [B, S, H, D] (v's width may differ from q's
+    and k's: a latent layer scores at 192 and weighs values of 128) with
+    a per-sequence valid length: key positions >= lengths[b] are masked. The prefill side of
     the decode split — bucketed (padded) prompts attend only over their
     real tokens, so prefill logits match the unpadded forward.
     ``window`` > 0 (a sliding-window layer): a query attends only the

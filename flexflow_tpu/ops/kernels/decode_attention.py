@@ -92,6 +92,30 @@ reader finds a kernel by its name). The grid is ``(batch, table
 columns)`` (:func:`paged_append_attention`), so such a call walks the
 columns the window holds, not the history's.
 
+**Latent rows** (a latent-attention layer, generation/decoder.py). Such a
+layer caches ONE row a position, ``[c, k_r]`` of ``kv_lora_rank +
+qk_rope_head_dim`` values shared by all heads, stored at the next
+multiple of 128 lanes (:func:`latent_row_width`: 576 -> 640, the fill
+zero) as ``[L, num_blocks, block_size, RW]``, whose default device
+layout is row-major and unpadded. (``[..., 5, 128]`` is not: the TPU
+compiler puts the 5 before ``block_size``; and a second array for the 64
+rotary values would be laid out with ``num_blocks`` on the lanes and
+padded to 1.5 x, as any array whose last axis is 64 is. Both read off a
+deviceless v5e compile, PR 34.) The ABSORBED form scores every query
+head against the same row and takes its values from the row's first
+``value_width`` columns, so :func:`paged_latent_attention` — a Pallas
+call of its own name — reads a block ONCE for scores and values: the
+grouped body at a group of all the heads, ``[W * H, RW] x [RW, bs]`` on
+the MXU, probabilities ``x [bs, value_width]`` out of the block already
+in VMEM, float32 softmax state. Its grid is ``(batch, table columns /
+columns a step)``: the same array is handed to the call once per column
+of a step, each with its own index map, so one grid step DMAs several
+blocks (consecutive positions) and folds them as ONE matrix of rows: the
+grid's fixed cost a step (0.4-0.5 us, what the grouped calls above are
+left with) is paid once for up to 16 blocks, and the MXU's tiles are
+filled (a block of 64 rows alone half-fills them). :func:`reference_paged_latent_attention`
+is the same arithmetic as an XLA composition.
+
 Two lowerings:
 
 * :func:`reference_paged_append_attention` — gather the table'd blocks
@@ -126,17 +150,36 @@ LANES = 128  # of a TPU vector register: the width a cache row fills
 def cache_row_shape(num_heads: int, head_dim: int) -> Tuple[int, int]:
     """``(R, LW)``: how one cache position's ``num_heads x head_dim``
     values are stored (see the module docstring). ``num_heads`` is what
-    ONE device holds: a head-sharded cache packs each shard's heads."""
+    ONE device holds: a head-sharded cache packs each shard's heads. A
+    head wider than the lanes that does not fill whole rows of them has
+    no stored shape here (a latent layer's row goes through
+    :func:`latent_row_width`)."""
+    if head_dim > LANES and head_dim % LANES:
+        raise ValueError(
+            f"a head of {head_dim} values neither packs into nor fills rows of {LANES} lanes: K/V heads are stored "
+            f"at widths that divide or are multiples of {LANES}; a latent layer's row is stored by latent_row_width"
+        )
     per_row = LANES // head_dim if LANES % head_dim == 0 else 1
     if per_row > 1 and num_heads % per_row == 0:
         return num_heads // per_row, LANES
     return num_heads, head_dim
 
 
+def latent_row_width(width: int) -> int:
+    """Lanes a latent layer's cache row of ``width`` values is stored
+    at: the next multiple of :data:`LANES` (576 -> 640)."""
+    return -(-width // LANES) * LANES
+
+
 def query_group(num_heads: int, head_dim: int, row_shape) -> int:
     """Query heads per stored K/V head: the cache's rows hold ``R * LW /
     head_dim`` K/V heads, and ``num_heads`` query heads read them in
     groups (1: plain multi-head attention)."""
+    if len(row_shape) != 2:
+        raise ValueError(
+            f"cache rows of shape {tuple(row_shape)} hold no K/V heads: a latent layer's rows are read by "
+            f"paged_latent_attention, whose every query head reads the one row"
+        )
     r, lw = row_shape
     kv_heads = r * lw // head_dim
     if kv_heads * head_dim != r * lw or num_heads % kv_heads or (lw != head_dim and lw % head_dim):
@@ -700,6 +743,182 @@ def paged_append_attention(
         interpret=interpret,
         name=name,
     )(*prefetch, *ins, k_cache, v_cache))
+
+
+# ---------------------------------------------------------------------------
+# Latent rows (module docstring)
+# ---------------------------------------------------------------------------
+# Query rows a latent call may hold in its score matrix: W window queries
+# of H heads each. A decode call is 32; a suffix-prefill bucket of
+# hundreds of queries belongs to the XLA composition.
+MAX_LATENT_QUERY_ROWS = 256
+# Table columns one grid step folds (each a DMA of its own, all in flight
+# together, then ONE matrix of rows in VMEM): the largest divisor of the
+# table's width that is at most this. A call of 64 rows over 48 columns
+# of 64 positions (`chip_smoke.py --latent-kernel`, my chip runs, PR 34),
+# ms a call: 4 columns folded block by block 1.09; 8 as one matrix 0.45;
+# 16 as one matrix 0.39 (the rows of 16 blocks are 1.3 MB, 1.6 us of HBM
+# time a step), and 0.25 at the 32 rows the benchmark's cell runs.
+LATENT_COLUMNS_PER_STEP = 16
+
+
+def latent_columns_per_step(max_blocks: int) -> int:
+    """Table columns a grid step of the latent call folds, for a table
+    of ``max_blocks`` columns."""
+    return max(k for k in range(1, LATENT_COLUMNS_PER_STEP + 1) if max_blocks % k == 0)
+
+
+def reference_paged_latent_attention(
+    q: jax.Array,
+    cache: jax.Array,
+    layer: int,
+    block_tables: jax.Array,
+    q_positions: jax.Array,
+    value_width: int,
+    scale: float,
+) -> jax.Array:
+    """The absorbed latent attention over gathered rows, in plain XLA.
+
+    q: [B, W, H, RW], each query laid out as a cache row is (the
+    absorbed unrotated part, the rotated part, zeros); cache: [L,
+    num_blocks, block_size, RW], of which static ``layer`` is read;
+    block_tables [B, max_blocks]; q_positions [B, W] (``< 0``: a padding
+    query, which emits zeros). Scores are ``q . row * scale`` over the
+    rows at positions ``<= q_positions``, the softmax is float32, the
+    probabilities are rounded to the cache's type and the values are the
+    rows' first ``value_width`` columns, accumulated in float32 (what
+    the kernel computes). Returns [B, W, H, value_width]."""
+    b, max_blocks = block_tables.shape
+    bs = cache.shape[2]
+    rows = cache[layer, block_tables].reshape(b, max_blocks * bs, cache.shape[-1])
+    s = jnp.einsum("bwhc,bkc->bhwk", q.astype(cache.dtype), rows, preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(max_blocks * bs)[None, None, None, :] <= q_positions[:, None, :, None]
+    s = jnp.where(valid, s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(valid, jnp.exp(s - m), 0.0)
+    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum(
+        "bhwk,bkc->bwhc", p.astype(cache.dtype), rows[..., :value_width], preferred_element_type=jnp.float32
+    ) / jnp.swapaxes(l, 1, 2)
+    return out.astype(q.dtype)
+
+
+def _latent_kernel(
+    bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
+    maxpos_ref,  # scalar-prefetch: [B] the window's largest position
+    q_ref,  # [M, RW] query rows, M = W x H
+    rowpos_ref,  # [M, 1] each query row's cache position
+    *refs,  # the step's blocks [bs, RW] (one ref a column), o_ref [M, VW], scratch m / l [M, 1], acc [M, VW]
+    scale,
+    block_size,
+    value_width,
+):
+    *blocks, o_ref, m_ref, l_ref, acc_ref = refs
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        _init_state(m_ref, l_ref, acc_ref)
+
+    start = j * len(blocks) * block_size  # a step's columns are consecutive positions
+
+    # a step whose first block lies past every query's position holds nothing to fold in
+    @pl.when(start <= maxpos_ref[b])
+    def _accum():
+        q = q_ref[...]
+        # the step's blocks as ONE matrix of rows: scores AND values come out of this one read, and
+        # the MXU takes its tiles filled (a block of 64 rows alone half-fills them)
+        rows = jnp.concatenate([rows_ref[...] for rows_ref in blocks], axis=0) if len(blocks) > 1 else blocks[0][...]
+        s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = pos <= rowpos_ref[...]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :value_width], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_latent_attention(
+    q: jax.Array,
+    cache: jax.Array,
+    layer: int,
+    block_tables: jax.Array,
+    q_positions: jax.Array,
+    value_width: int,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """Pallas paged latent attention (shapes and arithmetic as in
+    :func:`reference_paged_latent_attention`; module docstring). The
+    Pallas call is named ``paged_latent_attention``."""
+    b, w, h, rw = q.shape
+    block_size = cache.shape[2]
+    max_blocks = block_tables.shape[1]
+    per_step = latent_columns_per_step(max_blocks)
+    layer = int(layer)
+    m = w * h
+    q_positions = q_positions.astype(jnp.int32)
+    row_positions = jnp.repeat(q_positions, h, axis=1)[:, :, None]  # [B, M, 1]
+
+    def column(c):
+        return pl.BlockSpec(
+            (None, None, block_size, rw), lambda i, j, bt, mp: (layer, bt[i, j * per_step + c], 0, 0)
+        )
+
+    def whole(shape):
+        return pl.BlockSpec((None,) + shape, lambda i, j, bt, mp: (i,) + (0,) * len(shape))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, max_blocks // per_step),
+        in_specs=[whole((m, rw)), whole((m, 1)), *(column(c) for c in range(per_step))],
+        out_specs=whole((m, value_width)),
+        scratch_shapes=[
+            pltpu.VMEM((m, 1), jnp.float32), pltpu.VMEM((m, 1), jnp.float32), pltpu.VMEM((m, value_width), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_kernel, scale=float(scale), block_size=block_size, value_width=int(value_width)
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, m, value_width), q.dtype),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(
+        block_tables.astype(jnp.int32), jnp.max(q_positions, axis=1),
+        q.reshape(b, m, rw).astype(cache.dtype), row_positions, *([cache] * per_step),
+    )
+    return out.reshape(b, w, h, value_width)
+
+
+def latent_kernel_refusal(query_rows: int, row_width: int, block_size: int, itemsize: int) -> Optional[str]:
+    """Why :func:`paged_latent_attention` will not take this shape, or
+    None when it will (the dispatch in ops/attention.py sends a refused
+    shape to the XLA composition)."""
+    if query_rows > MAX_LATENT_QUERY_ROWS:
+        return (
+            f"{query_rows} query rows > {MAX_LATENT_QUERY_ROWS}: the kernel holds every query row of the window "
+            f"in one score matrix"
+        )
+    # a step's blocks double-buffered and once more as one matrix; the scores of all its positions
+    blocks = 3 * LATENT_COLUMNS_PER_STEP * block_size * row_width * itemsize
+    state = query_rows * (3 * row_width * itemsize + 2 * row_width * 4 + 6 * max(LATENT_COLUMNS_PER_STEP * block_size, LANES) * 4)
+    if blocks + state > _VMEM_BUDGET_BYTES:
+        return f"~{(blocks + state) >> 20} MiB of VMEM exceeds the {_VMEM_BUDGET_BYTES >> 20} MiB budget"
+    return None
 
 
 def default_kv_splits(batch: int, max_blocks: int) -> int:

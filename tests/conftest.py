@@ -66,21 +66,27 @@ def _as_pr_30_left_it(bench):
     return dict(bench, per_layer=_through(bench["per_layer"], "pipelined_step_share.served"))
 
 
-def _as_pr_27_left_it(bench):
-    """``BENCHMARK.json`` cut after PR 27's cell and configuration, the
-    later cells' names taken off every ``workloads`` list, and an entry
-    that lists later cells alone left out."""
-    cells = _through(bench["workloads"], "lfm2-8b-a1b.gen-batch")
-    had = {w["name"] for w in cells}
+def _as_it_was_after(cell: str, config: str):
+    """``BENCHMARK.json`` cut after ``cell`` and ``config`` (the last a PR
+    added), the later cells' names taken off every ``workloads`` list,
+    and an entry that lists later cells alone left out."""
+    def view(bench):
+        cells = _through(bench["workloads"], cell)
+        had = {w["name"] for w in cells}
 
-    def strip(metrics):
-        kept = [dict(m, workloads=[c for c in m["workloads"] if c in had]) if "workloads" in m else m for m in metrics]
-        return [m for m in kept if m.get("workloads", True)]
+        def strip(metrics):
+            kept = [dict(m, workloads=[c for c in m["workloads"] if c in had]) if "workloads" in m else m for m in metrics]
+            return [m for m in kept if m.get("workloads", True)]
 
-    return dict(
-        bench, workloads=cells, configs=_through(bench["configs"], "lfm2-8b-a1b"),
-        end_to_end=strip(bench["end_to_end"]), per_layer=strip(bench["per_layer"]),
-    )
+        return dict(
+            bench, workloads=cells, configs=_through(bench["configs"], config),
+            end_to_end=strip(bench["end_to_end"]), per_layer=strip(bench["per_layer"]),
+        )
+    return view
+
+
+_as_pr_27_left_it = _as_it_was_after("lfm2-8b-a1b.gen-batch", "lfm2-8b-a1b")
+_as_pr_31_left_it = _as_it_was_after("mellum2-12b.code-gen", "mellum2-12b")
 
 
 def _seeing(item, view):
@@ -104,10 +110,11 @@ def _seeing(item, view):
 # moves: each sees the file cut where its own PR left it, whatever was
 # appended since (the files there are the benchmark's, and no PR but a
 # `benchmark` one edits them: for the next `benchmark` issue, unpin the
-# two tail assertions and take this out)
+# three tail assertions and take this out)
 _PINNED_TAILS = {
     ("test_pipelined_step_share.py", "test_benchmark_json_asks_for_it_in_the_three_serving_cells"): _as_pr_30_left_it,
     ("test_lfm2_cell.py", "test_every_new_metric_lists_the_cell_and_is_read_there"): _as_pr_27_left_it,
+    ("test_mellum2_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_metrics_that_list_it"): _as_pr_31_left_it,
 }
 
 
