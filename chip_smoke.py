@@ -3,6 +3,8 @@
 
     python chip_smoke.py                 # one chip: kernels, server, trainer
     python chip_smoke.py --four-chips    # one four-chip host: the sharded legs
+    python chip_smoke.py --latent-kernel     # the latent paged kernel alone
+    python chip_smoke.py --expert-product    # the routed experts' sum alone: dense against grouped
 
 ONE process. It refuses to start unless JAX's first device is a TPU, and
 any failed check raises: the exit code is non-zero and no result line is
@@ -325,6 +327,130 @@ def latent_kernel_check() -> dict:
     out = {"max_abs_err": worst, "err_of_room": round(of_room, 3), "ms_a_call": ms,
            "rows_read_gb": round(read / 1e9, 4), "gb_per_s": round(read / 1e9 / (ms["kernel"] / 1e3), 1)}
     log(f"latent paged kernel at the cell's sizes: {out}")
+    return out
+
+
+# One expert layer of each expert configuration as its cell holds it
+# (benchmark/configs/*.json): the routed sum's two lowerings are timed over
+# these, and `expert_form`'s constants come from the table this prints.
+EXPERT_LAYERS = {
+    "lfm2": dict(hidden=2048, width=1792, experts=32, held=32, k=4, router="sigmoid"),
+    "mellum2": dict(hidden=2304, width=896, experts=64, held=64, k=8, router="softmax"),
+    "joyai": dict(hidden=2048, width=768, experts=256, held=16, k=8, router="sigmoid"),
+}
+EXPERT_ROWS = (32, 64, 256, 512, 1024, 1536, 2048)
+
+
+def expert_product_check() -> dict:
+    """The routed experts' sum of ONE layer (``decoder.expert_ffn``) at
+    :data:`EXPERT_LAYERS` over :data:`EXPERT_ROWS` rows, compiled: the
+    dense form, the grouped form with the product the program keeps
+    (``megablox.gmm``) and with the other candidate (``lax.ragged_dot``),
+    ms a call each on the host's clock, and each one's largest
+    difference from the float32 composition (the same sum at ``highest``
+    from the same bfloat16 weights), which bfloat16's roundings of the
+    hidden product and of the result bound, and from the dense form's
+    result (``from_dense``: the largest, and the share of elements that
+    differ at all). Where the rule
+    (``ops/expert_product.py::expert_form``) picks the grouped form it
+    must not be more than a tenth slower than the dense one; where the
+    rule keeps the dense form and the grouped one reads ahead, the line
+    says so (``left``) and nothing fails: the rule's row count is the
+    lowest at which every configuration's grouped form is well ahead."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.generation import decoder
+    from flexflow_tpu.ops import expert_product
+
+    rule = expert_product.expert_form
+    hi = jax.lax.Precision.HIGHEST
+
+    def ragged(lhs, rhs, sizes):
+        return jax.lax.ragged_dot(lhs, rhs, sizes, preferred_element_type=jnp.float32)
+
+    out = {}
+    for name, c in EXPERT_LAYERS.items():
+        held = None if c["held"] == c["experts"] else tuple(range(c["held"]))
+        cfg = decoder.DecoderConfig(
+            num_layers=1, hidden_size=c["hidden"], num_heads=16, ff_size=c["width"], seq_length=64, vocab_size=128,
+            num_dense_layers=0, num_experts=c["experts"], experts_per_token=c["k"], moe_ff_size=c["width"],
+            router=c["router"], experts_held=held or ())
+        shapes = dict(router=(c["hidden"], c["experts"]), router_bias=(c["experts"],),
+                      ew1=(c["held"], c["hidden"], c["width"]), ew3=(c["held"], c["hidden"], c["width"]),
+                      ew2=(c["held"], c["width"], c["hidden"]))
+        keys = dict(zip(shapes, jax.random.split(jax.random.key(SEED), len(shapes))))
+        layer = {k: jax.random.normal(keys[k], s, jnp.float32) * (s[-2] if len(s) > 1 else 2500.0) ** -0.5
+                 for k, s in shapes.items()}
+        layer = {k: a if k.startswith("router") else a.astype(jnp.bfloat16) for k, a in layer.items()}
+
+        def traced_as(form):
+            def call(layer, v, live=None):
+                expert_product.expert_form = lambda *shape: form  # the rule, held still while this traces
+                try:
+                    return decoder.expert_ffn(cfg, layer, v, held=held, live=live)[0]
+                finally:
+                    expert_product.expert_form = rule
+            return jax.jit(call)
+
+        def other_candidate(layer, v):
+            gates, chosen = decoder.route(cfg, layer, v)
+            return expert_product.grouped_expert_sum(
+                v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, product=ragged)
+
+        def composition(layer, v):
+            gates, _ = decoder.route(cfg, layer, v)
+            mine = gates if held is None else gates[:, jnp.asarray(held)]
+            x = v.astype(jnp.float32)
+
+            def one(acc, w):
+                w1, w3, w2, g = w
+                up, gate_up = (jnp.dot(x, m.astype(jnp.float32), precision=hi) for m in (w1, w3))
+                return acc + jnp.dot(jax.nn.silu(up) * gate_up * g[:, None], w2.astype(jnp.float32), precision=hi), None
+            return jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32), (layer["ew1"], layer["ew3"], layer["ew2"], mine.T))[0]
+
+        forms = {"dense": traced_as("dense"), "grouped": traced_as("grouped"), "grouped_ragged_dot": jax.jit(other_candidate)}
+        for rows in EXPERT_ROWS:
+            v = jax.random.normal(jax.random.key(rows), (rows, c["hidden"]), jnp.bfloat16)
+            want = jax.jit(composition)(layer, v)
+            line = {"rule": rule(rows, c["held"], c["k"]), "largest_value": float(jnp.max(jnp.abs(want)))}
+            for form, call in forms.items():
+                try:
+                    got = jax.block_until_ready(call(layer, v))
+                except Exception as e:  # noqa: BLE001 - a candidate the compiler refuses is a finding, not a failure
+                    check(form != line["rule"], f"experts {name} rows {rows}: the form the rule picks does not compile: {e}")
+                    line[form] = f"refused: {str(e)[:160]}"
+                    continue
+                t0 = time.perf_counter()
+                for _ in range(20):
+                    got = call(layer, v)
+                jax.block_until_ready(got)
+                took = round((time.perf_counter() - t0) / 20 * 1e3, 4)
+                got = got.astype(jnp.float32)
+                dense = got if form == "dense" else dense
+                line[form] = {"ms": took,
+                              "max_abs_err": float(jnp.max(jnp.abs(got - want))),
+                              # (the same roundings at the same points: the last cast lands one step apart at most)
+                              "from_dense": {"max_abs": float(jnp.max(jnp.abs(got - dense))), "share_differing": float(jnp.mean(got != dense))}}
+                # two bfloat16 roundings (the hidden product, the result): 2**-7 of the largest value, with room
+                check(line[form]["max_abs_err"] <= 2.0 ** -6 * line["largest_value"] + 1e-6,
+                      f"experts {name} rows {rows} {form}: {line[form]['max_abs_err']} from the float32 composition")
+            if isinstance(line["grouped"], dict):
+                # a prompt that fills 85 % of the bucket: the grouped form skips the rows behind it and hands back zeros
+                # there; the rows ahead of it are the dense form's (the kernel then stops short of its last row tiles)
+                n = int(0.85 * rows)
+                got = forms["grouped"](layer, v, jnp.arange(rows) < n).astype(jnp.float32)
+                line["grouped"]["padded"] = {"max_abs_from_dense": float(jnp.max(jnp.abs(got[:n] - dense[:n]))),
+                                             "share_differing": float(jnp.mean(got[:n] != dense[:n])),
+                                             "zeros_behind": not bool(jnp.any(got[n:]))}
+                check(line["grouped"]["padded"]["zeros_behind"] and
+                      line["grouped"]["padded"]["max_abs_from_dense"] <= 2.0 ** -6 * line["largest_value"] + 1e-6,
+                      f"experts {name} rows {rows} grouped, {n} rows live: {line['grouped']['padded']}")
+            ms = {form: line[form]["ms"] for form in ("dense", "grouped") if isinstance(line[form], dict)}
+            check(line["rule"] == "dense" or ms["grouped"] <= 1.1 * ms["dense"], f"experts {name} rows {rows}: the rule picks grouped at {ms}")
+            line["left"] = round(ms["dense"] - min(ms.values()), 4)  # ms a layer the rule leaves where it keeps the dense form
+            out[f"{name}[{rows}]"] = line
+            log(f"expert product {name} rows {rows}: {line}")
     return out
 
 
@@ -952,6 +1078,8 @@ def main(argv=None) -> int:
                     help="run the sharded legs on a four-chip host instead")
     ap.add_argument("--latent-kernel", action="store_true",
                     help="the latent paged kernel alone, at the latent cell's sizes")
+    ap.add_argument("--expert-product", action="store_true",
+                    help="the routed experts' sum alone: dense against grouped, one layer of each expert cell")
     args = ap.parse_args(argv)
 
     import jax
@@ -980,6 +1108,8 @@ def main(argv=None) -> int:
         summary["four_chips"] = four_chip_phase(rs)
     elif args.latent_kernel:
         summary["kernels"] = {"latent": latent_kernel_check()}
+    elif args.expert_product:
+        summary["experts"] = expert_product_check()
     else:
         summary["calibration"] = calibration_hit(dev.device_kind)
         log(f"calibration lookup for {dev.device_kind!r}: {summary['calibration']}")
@@ -999,8 +1129,8 @@ def main(argv=None) -> int:
     log(f"compile cache: {entries_before} -> {entries_after} entries; wall {summary['wall_s']}s")
     out_dir = REPO / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
-    name = "chip_smoke_four_chips.json" if args.four_chips else (
-        "chip_smoke_latent.json" if args.latent_kernel else "chip_smoke.json")
+    name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel
+            else "chip_smoke_experts.json" if args.expert_product else "chip_smoke.json")
     (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

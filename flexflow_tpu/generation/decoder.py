@@ -103,6 +103,7 @@ import jax.numpy as jnp
 from ..core.types import DataType
 from ..models.transformer import TransformerConfig
 from ..ops.attention import append_attention_core, decode_attention_core, latent_attention_core, masked_attention
+from ..ops.expert_product import expert_lowering, grouped_expert_sum
 from ..ops.kernels.decode_attention import latent_row_width
 from .cache import slot_mapping
 
@@ -555,7 +556,7 @@ def route(cfg: DecoderConfig, layer, v):
     return jnp.zeros_like(s).at[rows, chosen].set(gate), chosen
 
 
-def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = None):
+def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = None, live=None):
     """The routed feed-forward of rows ``v`` [T, E]: ``sum_{i in I} g_i
     W2_i (silu(W1_i v) * W3_i v)``, exactly (no capacity, no dropped
     token), and the gates it used ([T, N], for the counters).
@@ -566,16 +567,23 @@ def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = Non
     the held ones alone, so the results of calls that hold disjoint
     shares add up to the whole layer's (a chip of an expert-sharded
     deployment runs this with its share and the exchange adds them).
+
+    The sum has two lowerings, chosen by what the call's shapes show
+    (ops/expert_product.py ``expert_form``: its table of chip timings is
+    there): the dense one below, and from the row count where the rows'
+    arithmetic outweighs the weights' reads the one over (row, chosen
+    expert) pairs grouped by expert, which also skips rows that are not
+    ``live`` ([T] bool: padding behind a prompt's length, whose result
+    nothing reads; they get zeros).
     """
-    gates, _ = route(cfg, layer, v)
+    gates, chosen = route(cfg, layer, v)
+    if expert_lowering(v.shape[0], layer["ew1"].shape[0], cfg.experts_per_token) == "grouped":
+        out = grouped_expert_sum(v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, live=live)
+        return out, gates
     mine = gates if held is None else gates[:, jnp.asarray(tuple(held))]
     # every expert multiplies every row, masked by the gate: the weights
-    # are read once either way, and up to 256 rows the 8 x multiply-adds
-    # hide behind those reads. Sorting the rows by expert (`ragged_dot`)
-    # was measured at these widths on the v5e, one layer, ms dense /
-    # sorted (my chip run, PR 27): 32 rows 0.99 / 1.62; 256: 1.09 / 2.76;
-    # 512: 2.05 / 3.03; 1024: 3.96 / 3.72 — no prompt bucket a cell uses
-    # is on its side, so it is not here.
+    # are read once either way, and at a decode step's or a short
+    # bucket's rows the 8 x multiply-adds hide behind those reads
     up = jnp.einsum("te,nef->ntf", v, layer["ew1"], preferred_element_type=jnp.float32)
     gate_up = jnp.einsum("te,nef->ntf", v, layer["ew3"], preferred_element_type=jnp.float32)
     hidden = (jax.nn.silu(up) * gate_up * mine.T[:, :, None]).astype(v.dtype)
@@ -601,7 +609,7 @@ def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List]):
             h = _norm(cfg, x, layer, "ln2")
             rows = h.reshape(-1, h.shape[-1])
         with jax.named_scope("experts"):
-            out, gates = expert_ffn(cfg, layer, rows, held=cfg.experts_held or None)
+            out, gates = expert_ffn(cfg, layer, rows, held=cfg.experts_held or None, live=live.reshape(-1))
         if cfg.num_shared_experts:
             with jax.named_scope("shared_expert"):
                 out = out + _swiglu(rows, layer["sw1"], layer["sw3"], layer["sw2"])

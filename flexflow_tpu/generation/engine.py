@@ -60,6 +60,7 @@ from ..obs.capacity import ProgramRegistry, ServingFlops
 from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
 from ..ops.attention import latent_call_lowering, paged_call_lowering
+from ..ops.expert_product import expert_lowering
 from ..runtime import faults
 from .cache import (
     BlockAllocator, CacheConfig, KVCache, StateConfig, WindowTable, pools_from_budget, slot_mapping,
@@ -527,6 +528,11 @@ class GenerationEngine:
                 ),
                 "calls": jnp.zeros((2,), jnp.int32),  # decode, prefill
             }
+        # step programs whose expert layers took the grouped form
+        # (ops/expert_product.py): the form is static a program, so the
+        # host counts it at dispatch, with no device counter to read back
+        self.expert_grouped_calls = 0
+        self._expert_forms: Dict[int, str] = {}
         # convolution-state traffic of the prefix cache (the conv_state
         # section of /v2/stats): hits that restored a slot's state from a
         # block's snapshot, snapshots written with registered blocks
@@ -1242,6 +1248,7 @@ class GenerationEngine:
             return self._prefill_suffix(prompt, block_table, sampling, key, prefix_len, mask, slot)
         self.step_counts["prefill"] += 1
         self.latent_calls["expanded"] += self._n_latent
+        self._count_expert_form(self.bucket_for(len(prompt)))
         if self.window_config is not None and slot not in self.window_tables:
             # (a caller that assembled its own table: prepare_prefix does this for the scheduler)
             self._window_admit(slot, len(prompt), 0, [])
@@ -1321,6 +1328,7 @@ class GenerationEngine:
         calls registry-stamped, steady calls ledger-paired."""
         self.step_counts["prefill"] += 1
         self.latent_calls["absorbed"] += self._n_latent
+        self._count_expert_form(self.bucket_for(len(prompt) - prefix_len))
         if self.state_config is not None:
             self._restore_state(slot, block_table[prefix_len // self.cache_config.block_size - 1])
         with phase("engine.prefill.dispatch") as disp:
@@ -2096,6 +2104,7 @@ class GenerationEngine:
             # wedge like any device work — chaos plans target it here
             faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
         self.step_counts["decode"] += 1
+        self._count_expert_form(self.max_batch_slots)
         with phase("engine.decode.dispatch") as disp:
             traces_before = self.trace_counts.get("decode", 0)
             args, context_lens = self._decode_args(
@@ -2196,6 +2205,7 @@ class GenerationEngine:
         if self.tp_degree > 1:
             faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
         self.step_counts["decode"] += 1
+        self._count_expert_form(self.max_batch_slots)
         with phase("engine.decode.dispatch") as disp:
             traces_before = self.trace_counts.get("decode", 0)
             args, context_lens = self._decode_args(
@@ -2332,6 +2342,7 @@ class GenerationEngine:
         if self.tp_degree > 1:
             faults.inject(faults.GENERATION_COLLECTIVE, ("verify", self.tp_degree))
         self.step_counts["verify"] += 1
+        self._count_expert_form(self.max_batch_slots * self.spec_window)
         # useful verify work: per live slot, n_draft+1 window tokens;
         # window token j at position start+j attends to start+j+1 live
         # context positions -> (nd+1)(start+1) + nd(nd+1)/2. Computed
@@ -2436,7 +2447,30 @@ class GenerationEngine:
             "tokens_total_by_layer": [[int(t) for t in row] for row in tokens],
             "decode_calls_total": int(calls[0]),
             "prefill_calls_total": int(calls[1]),
+            "grouped_calls_total": self.expert_grouped_calls,
+            "forms": self.expert_lowerings(),
         }
+
+    def expert_form(self, rows: int) -> str:
+        """``"dense"`` or ``"grouped"``: the lowering the expert layers
+        of a step program over ``rows`` rows take, asked of the rule
+        ``decoder.expert_ffn`` asks (ops/expert_product.py)."""
+        if rows not in self._expert_forms:
+            held = self.params["layers"][self.dcfg.expert_layers[0]]["ew1"].shape[0]
+            self._expert_forms[rows] = expert_lowering(rows, held, self.dcfg.experts_per_token)
+        return self._expert_forms[rows]
+
+    def _count_expert_form(self, rows: int) -> None:
+        if self.expert_counts and self.expert_form(rows) == "grouped":
+            self.expert_grouped_calls += 1
+
+    def expert_lowerings(self) -> Dict[str, str]:
+        """The form per step program of a model with expert layers, as
+        :meth:`paged_lowerings` reports a kernel's body: the decode step
+        (its rows are the slots) and every ``prefill[N]`` bucket (a
+        suffix prefill of ``N`` rows takes the same form)."""
+        programs = {"decode": self.max_batch_slots, **{f"prefill[{b}]": b for b in self.buckets}}
+        return {name: self.expert_form(rows) for name, rows in programs.items()}
 
     def paged_lowerings(self) -> Dict[str, Dict]:
         """``{"body", "group"}`` per attention kind the model has

@@ -164,6 +164,19 @@ _NP_TO_V2 = {
 }
 
 
+class _Listener(ThreadingHTTPServer):
+    """The listening socket. ``socketserver``'s backlog of 5 is for a
+    desk: a closed loop's clients all connect in the same instant, and
+    the accept loop shares the interpreter's lock with handler threads
+    that parse prompts of thousands of tokens. Connections past the
+    backlog are RESET once they send their request (14 of a run's first
+    96 read ``ConnectionResetError`` at the client, about one benchmark
+    run in forty: PERF.md §7), so the queue holds a burst of any closed
+    loop a cell runs (the kernel caps it at ``net.core.somaxconn``)."""
+
+    request_queue_size = 1024
+
+
 class InferenceServer:
     """Serves one or more InferenceModels over HTTP with dynamic batching.
 
@@ -1116,7 +1129,7 @@ class InferenceServer:
                 }
                 return self._json(200, resp)
 
-        self._httpd = ThreadingHTTPServer((self.host, self.port), Handler)
+        self._httpd = _Listener((self.host, self.port), Handler)
         self.port = self._httpd.server_address[1]  # resolve port 0
         for b in self.batchers.values():
             b.start()

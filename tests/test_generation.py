@@ -453,6 +453,47 @@ def test_stored_cache_shape_is_row_major_and_unpadded_on_the_v5e(kv_shards, one_
     assert compiled.memory_analysis().argument_size_in_bytes == int(np.prod(shard)) * 4
 
 
+@pytest.mark.parametrize("hidden, width, held, experts, k, heads", [(2304, 896, 64, 64, 8, 32), (2048, 768, 16, 256, 8, 64)])
+@pytest.mark.parametrize("rows", [1536, 2048])
+def test_the_grouped_expert_layer_compiles_for_the_v5e_at_the_cells_buckets(rows, hidden, width, held, experts, k, heads, one_v5e_chip):
+    """One expert layer of Mellum2 (64 of 64 experts) and of JoyAI (16 of
+    256 held) over the rows of ``prefill[1536]`` and ``prefill[2048]``,
+    compiled by the TPU's own compiler in the grouped form
+    (ops/expert_product.py): three Mosaic calls (one grouped product a
+    weight matrix), no dense product left, and temporaries under
+    the float32 scores of the same prefill's attention (heads x rows x
+    rows), which set the program's peak; where every expert is held,
+    under the dense form's too (16 held of 256 leave the dense form
+    little to hold, and the grouped one still has a place for every one
+    of the rows x k pairs). The compiler has refused this program for want of fast
+    memory at SOME row counts (its own gather of 12,288 rows of 2,304
+    among them; a product's tile at LFM2's 2,048 x 1,792): a compile here
+    is what says a bucket is safe. Nothing runs."""
+    from flexflow_tpu.ops import expert_product
+
+    sds = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_v5e_chip)  # noqa: E731
+    stack = (None if held == experts else tuple(range(held)))
+    args = (sds(jnp.bfloat16, rows, hidden), sds(np.float32, rows, experts), sds(np.int32, rows, k),
+            sds(jnp.bfloat16, held, hidden, width), sds(jnp.bfloat16, held, hidden, width), sds(jnp.bfloat16, held, width, hidden),
+            sds(np.bool_, rows))
+
+    def grouped(v, gates, chosen, w1, w3, w2, live):
+        return expert_product.grouped_expert_sum(v, gates, chosen, w1, w3, w2, held=stack, live=live)
+
+    def dense(v, gates, chosen, w1, w3, w2, live):
+        mine = gates if stack is None else gates[:, jnp.asarray(stack)]
+        up, gate_up = (jnp.einsum("te,nef->ntf", v, w, preferred_element_type=jnp.float32) for w in (w1, w3))
+        hidden_ = (jax.nn.silu(up) * gate_up * mine.T[:, :, None]).astype(v.dtype)
+        return jnp.einsum("ntf,nfe->te", hidden_, w2, preferred_element_type=jnp.float32).astype(v.dtype)
+
+    compiled = _compile_uncached(jax.jit(grouped), *args)
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < heads * rows * rows * 4
+    if held == experts:
+        assert temporaries < _compile_uncached(jax.jit(dense), *args).memory_analysis().temp_size_in_bytes
+
+
 def test_packed_cache_through_every_engine_program():
     """An engine whose heads share cache rows (four heads of 32 in one
     128-lane row) against the stateless forward: prefill, decode, a

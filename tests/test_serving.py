@@ -538,3 +538,51 @@ def test_grpc_raw_contents_round_trip(served_model):
             call("ModelInfer", bad, pb.ModelInferResponse)
         assert ei.value.code() == _grpc.StatusCode.INVALID_ARGUMENT
         channel.close()
+
+
+def test_the_listener_holds_a_closed_loops_burst_of_connections():
+    """96 clients connect in the same instant (a closed loop's start)
+    while the accept loop is held up for 50 ms (the interpreter's lock,
+    under handler threads parsing long prompts): every one is served.
+    With ``socketserver``'s backlog of 5 most of them read
+    ``ConnectionResetError`` once they send their request, which a
+    benchmark run counts as failed requests (PR 35)."""
+    import http.client
+    import time
+    from http.server import BaseHTTPRequestHandler
+
+    from flexflow_tpu.serving.server import _Listener
+
+    class Handler(BaseHTTPRequestHandler):
+        def do_POST(self):
+            self.rfile.read(int(self.headers["Content-Length"]))
+            self.send_response(200)
+            self.send_header("Content-Length", "2")
+            self.end_headers()
+            self.wfile.write(b"ok")
+
+        def log_message(self, *args):
+            pass
+
+    listener = _Listener(("127.0.0.1", 0), Handler)
+    outcomes = []
+
+    def client():
+        try:
+            conn = http.client.HTTPConnection("127.0.0.1", listener.server_address[1], timeout=60)
+            conn.request("POST", "/v2/models/lm/generate", body=b"7" * 20000)
+            outcomes.append(conn.getresponse().read())
+        except OSError as e:
+            outcomes.append(e)
+
+    clients = [threading.Thread(target=client) for _ in range(96)]
+    for c in clients:
+        c.start()
+    time.sleep(0.05)
+    serving = threading.Thread(target=listener.serve_forever, daemon=True)
+    serving.start()
+    for c in clients:
+        c.join()
+    listener.shutdown()
+    listener.server_close()
+    assert outcomes == [b"ok"] * 96
