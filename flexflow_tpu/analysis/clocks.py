@@ -10,8 +10,8 @@ injected clock) only stays fixed if new code cannot silently call
 ``time.time()`` again.
 
 What counts as a violation: a CALL to ``time.time`` /
-``time.monotonic`` / ``time.perf_counter`` (including ``from time
-import monotonic`` aliases). A bare REFERENCE as a default argument
+``time.monotonic`` / ``time.perf_counter`` / ``time.thread_time``
+(including ``from time import monotonic`` aliases). A bare REFERENCE as a default argument
 (``clock: Callable[[], float] = time.monotonic``) is the injectable
 pattern itself and is always allowed.
 
@@ -31,7 +31,10 @@ from typing import Dict, FrozenSet, List, Union
 from .config import CLOCK_STRICT_PATHS, CLOCK_WHITELIST
 from .core import Context, Finding, Rule, SourceFile
 
-CLOCK_FUNCS = frozenset({"time", "monotonic", "perf_counter"})
+# thread_time (the calling thread's CPU clock) is policed with the wall
+# clocks: it is as physical as they are, and a span that reads it is
+# opened through obs/steptrace.phase like every other
+CLOCK_FUNCS = frozenset({"time", "monotonic", "perf_counter", "thread_time"})
 
 
 def _whitelisted(relpath: str, func: str) -> bool:
@@ -49,7 +52,7 @@ def _whitelisted(relpath: str, func: str) -> bool:
 class ClockRule(Rule):
     name = "clock-discipline"
     description = (
-        "time.time()/monotonic()/perf_counter() calls outside the "
+        "time.time()/monotonic()/perf_counter()/thread_time() calls outside the "
         "whitelist; use the component's injectable clock"
     )
 

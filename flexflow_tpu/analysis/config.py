@@ -13,7 +13,8 @@ from typing import Dict, FrozenSet, Union
 # --------------------------------------------------------------- clocks
 # Wall-clock whitelist for the clock-discipline rule. Keys are
 # repo-relative paths (a trailing "/" whitelists the directory); values
-# are "*" (any of time.time / time.monotonic / time.perf_counter) or
+# are "*" (any of time.time / time.monotonic / time.perf_counter /
+# time.thread_time) or
 # the frozenset of allowed function names. Everything else must take an
 # injectable clock so virtual-clock tests control time.
 CLOCK_WHITELIST: Dict[str, Union[str, FrozenSet[str]]] = {
@@ -33,8 +34,12 @@ CLOCK_WHITELIST: Dict[str, Union[str, FrozenSet[str]]] = {
     # and the data loader read no clock of their own; what is left here
     # is the scheduler's iteration wall and the device-lane "execute"
     # stamp, which are not host spans. Only perf_counter is exempt —
-    # time.time/monotonic in this file is still a violation.
-    "flexflow_tpu/generation/scheduler.py": frozenset({"perf_counter"}),
+    # time.time/monotonic in this file is still a violation — and,
+    # since ISSUE 37, thread_time: the thread's CPU clock at the two
+    # ends of a working iteration (/v2/stats "loop" cpu_total_s), which
+    # is no timestamp at all and can sit on no timeline, virtual or
+    # physical; only differences of it are kept.
+    "flexflow_tpu/generation/scheduler.py": frozenset({"perf_counter", "thread_time"}),
     # Grammar-compile telemetry (ISSUE 18): compile_seconds is physical
     # profiling data like the engine's phase spans — perf_counter only.
     "flexflow_tpu/generation/constrained/tokens.py": frozenset({"perf_counter"}),
@@ -42,8 +47,11 @@ CLOCK_WHITELIST: Dict[str, Union[str, FrozenSet[str]]] = {
     # is opened (ISSUE 23): perf_counter-only physical profiling per
     # the PR 6 dual-clock decision — phase() stamps every host span,
     # StepAnatomy aggregates the stamps, and neither may mix in the
-    # scheduler's injectable (possibly virtual) clock.
-    "flexflow_tpu/obs/steptrace.py": frozenset({"perf_counter"}),
+    # scheduler's injectable (possibly virtual) clock. thread_time
+    # (ISSUE 37): phase(cpu=True) reads the thread's CPU clock inside
+    # its two stamps, so a span's wall less its CPU seconds says how
+    # long the thread held no core; a duration, never a timestamp.
+    "flexflow_tpu/obs/steptrace.py": frozenset({"perf_counter", "thread_time"}),
     # Durable WAL (ISSUE 19): fsync DURATION is physical profiling data
     # (perf_counter only). Journal-record wall stamps ride the
     # injectable wall_clock passed to WriteAheadLog — time.time /

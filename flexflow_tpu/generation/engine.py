@@ -284,7 +284,7 @@ class InFlightDecode:
 
     __slots__ = (
         "out", "ok", "prev_k", "prev_v", "prev_conv", "prev_window", "prev_counts", "ck", "cv", "t0", "t_disp",
-        "t_started", "traced", "n_active", "ctx_sum", "consumed",
+        "t_started", "traced", "n_active", "ctx_sum", "consumed", "post", "children",
     )
 
     def __init__(self, out, ok, prev_k, prev_v, ck, cv, t0, t_disp, traced, n_active, ctx_sum,
@@ -318,6 +318,12 @@ class InFlightDecode:
         self.n_active = n_active
         self.ctx_sum = ctx_sum
         self.consumed = False
+        # the dispatch's sibling span (``post``: the device-to-host
+        # copies, the cache swap, this handle) and the dispatch span's
+        # children (``args`` / ``upload`` / ``call``), for the
+        # scheduler's anatomy; set by decode_async once both have closed
+        self.post: Tuple[str, float, float] = ("post", t_disp, t_disp)
+        self.children: List[Tuple[str, float, float]] = []
 
 
 class GenerationEngine:
@@ -503,6 +509,9 @@ class GenerationEngine:
         self.window_allocator = BlockAllocator(self.window_config) if self.window_config else None
         self.window_tables: Dict[int, WindowTable] = {}
         self.window_released_total = 0
+        # the ``ff.cache.window_release`` spans: how many, and their seconds
+        self.window_releases_total = 0
+        self.window_release_total_s = 0.0
         self._live_blocks = (0, 0)  # (full, window) blocks the last decode step's sequences held
         # a latent cache (the `cache.latent` section of /v2/stats): the
         # positions the last decode step's sequences held, and the
@@ -596,6 +605,24 @@ class GenerationEngine:
         # read by the scheduler loop thread that made the call, never
         # concurrently
         self.last_step_spans: List[Tuple[str, float, float]] = []
+        # the parts of that step's dispatch span, ``args`` (host
+        # arithmetic), ``upload`` (host-to-device transfers) and ``call``
+        # (the jit call): children, inside ``dispatch`` and so in no sum
+        # of the host lane. ``_children`` collects the dispatch in hand.
+        self.last_step_children: List[Tuple[str, float, float]] = []
+        self._children: List[Tuple[str, float, float]] = []
+        # host-to-device transfers through ``_dev`` (every one the
+        # engine makes, whatever the step) and what ``_stage`` answered
+        # (cumulative, /v2/stats "uploads")
+        self.uploads = {"uploads_total": 0, "upload_bytes_total": 0, "staged_hits_total": 0, "staged_misses_total": 0}
+        # a scheduler that keeps a step anatomy switches this on for the
+        # iterations it samples (one in ``CPU_CLOCK_EVERY``): the decode
+        # dispatch span then reads the thread's CPU clock at its two
+        # ends, and ``decode_dispatch_clock`` holds the span's cumulative
+        # (wall, CPU) seconds of THOSE calls, as one tuple so that a
+        # scrape reads a pair of the same steps
+        self.cpu_stamps = False
+        self.decode_dispatch_clock: Tuple[float, float] = (0.0, 0.0)
         # serving FLOPs accounting (obs/capacity.py): model-shaped FLOPs
         # per step kind — true prompt lengths and live context only, so
         # MFU = flops / execute seconds / chip peak is padding-honest.
@@ -754,9 +781,29 @@ class GenerationEngine:
         (call-stable input shardings — a drifting placement would
         recompile the fixed-shape programs); the legacy engine keeps the
         plain uncommitted ``jnp.asarray``."""
+        self.uploads["uploads_total"] += 1
+        self.uploads["upload_bytes_total"] += getattr(x, "nbytes", 0)
         if self.layout is not None:
             return self.layout.put_replicated(x)
         return jnp.asarray(x)
+
+    def _part(self, kind: str, part: str) -> phase:
+        """Open one child of the ``ff.engine.<kind>.dispatch`` span in
+        hand: ``args`` (host arithmetic: masks, casts, ``_lookup``'s
+        compares), ``upload`` (every host-to-device transfer) or
+        ``call`` (the jit call alone). The parent's self time is its
+        seconds less these."""
+        return phase(f"engine.{kind}.dispatch.{part}", into=self._children)
+
+    def _count_dispatch(self, disp: phase) -> None:
+        """Add a decode dispatch span to ``decode_dispatch_clock``."""
+        if disp.c0 is not None:
+            wall, cpu = self.decode_dispatch_clock
+            self.decode_dispatch_clock = (wall + disp.seconds, cpu + disp.cpu_seconds)
+
+    def upload_stats(self) -> Dict[str, int]:
+        """The ``uploads`` section of ``/v2/stats``."""
+        return dict(self.uploads)
 
     def _register_strategy_predictions(self) -> None:
         """Put the chosen serving layout's predicted step times into the
@@ -1220,6 +1267,7 @@ class GenerationEngine:
         self.last_step_spans = [
             disp.span, block.span, ("execute", block.t0, block.t1), read.span,
         ]
+        self.last_step_children = self._children
         return read.t1 - disp.t0, block.seconds
 
     def prefill_one(
@@ -1252,27 +1300,31 @@ class GenerationEngine:
         if self.window_config is not None and slot not in self.window_tables:
             # (a caller that assembled its own table: prepare_prefix does this for the scheduler)
             self._window_admit(slot, len(prompt), 0, [])
+        self._children = []
         with phase("engine.prefill.dispatch") as disp:
-            n = len(prompt)
-            bucket = self.bucket_for(n)
-            traces_before = self.trace_counts.get(f"prefill[{bucket}]", 0)
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :n] = prompt
-            table = np.zeros((self.max_blocks_per_seq,), np.int32)
-            table[: len(block_table)] = block_table
-            token, ok, ck, cv, state, counts = self._prefill_jit(
-                self.params,
-                self._dev(tokens),
-                jnp.int32(n),
-                self.cache.k,
-                self.cache.v,
-                self._dev(table),
-                jnp.float32(sampling.temperature),
-                jnp.int32(sampling.top_k),
-                self._dev(key),
-                self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
-                *self._state_args(slot, self.window_columns),
-            )
+            with self._part("prefill", "args"):
+                n = len(prompt)
+                bucket = self.bucket_for(n)
+                traces_before = self.trace_counts.get(f"prefill[{bucket}]", 0)
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :n] = prompt
+                table = np.zeros((self.max_blocks_per_seq,), np.int32)
+                table[: len(block_table)] = block_table
+            with self._part("prefill", "upload"):
+                args = (
+                    self._dev(tokens),
+                    jnp.int32(n),
+                    self.cache.k,
+                    self.cache.v,
+                    self._dev(table),
+                    jnp.float32(sampling.temperature),
+                    jnp.int32(sampling.top_k),
+                    self._dev(key),
+                    self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
+                    *self._state_args(slot, self.window_columns),
+                )
+            with self._part("prefill", "call"):
+                token, ok, ck, cv, state, counts = self._prefill_jit(self.params, *args)
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
@@ -1331,30 +1383,34 @@ class GenerationEngine:
         self._count_expert_form(self.bucket_for(len(prompt) - prefix_len))
         if self.state_config is not None:
             self._restore_state(slot, block_table[prefix_len // self.cache_config.block_size - 1])
+        self._children = []
         with phase("engine.prefill.dispatch") as disp:
-            n = len(prompt)
-            suffix = list(prompt[prefix_len:])
-            w = self.bucket_for(len(suffix))
-            name = f"prefix_prefill[{w}]"
-            traces_before = self.trace_counts.get(name, 0)
-            tokens = np.zeros((1, w), np.int32)
-            tokens[0, : len(suffix)] = suffix
-            table = np.zeros((self.max_blocks_per_seq,), np.int32)
-            table[: len(block_table)] = block_table
-            token, ok, ck, cv, state, counts = self._prefix_prefill_jit(
-                self.params,
-                self._dev(tokens),
-                jnp.int32(prefix_len),
-                jnp.int32(len(suffix)),
-                self.cache.k,
-                self.cache.v,
-                self._dev(table),
-                jnp.float32(sampling.temperature),
-                jnp.int32(sampling.top_k),
-                self._dev(key),
-                self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
-                *self._state_args(slot, self._suffix_columns(w)),
-            )
+            with self._part("prefill", "args"):
+                n = len(prompt)
+                suffix = list(prompt[prefix_len:])
+                w = self.bucket_for(len(suffix))
+                name = f"prefix_prefill[{w}]"
+                traces_before = self.trace_counts.get(name, 0)
+                tokens = np.zeros((1, w), np.int32)
+                tokens[0, : len(suffix)] = suffix
+                table = np.zeros((self.max_blocks_per_seq,), np.int32)
+                table[: len(block_table)] = block_table
+            with self._part("prefill", "upload"):
+                args = (
+                    self._dev(tokens),
+                    jnp.int32(prefix_len),
+                    jnp.int32(len(suffix)),
+                    self.cache.k,
+                    self.cache.v,
+                    self._dev(table),
+                    jnp.float32(sampling.temperature),
+                    jnp.int32(sampling.top_k),
+                    self._dev(key),
+                    self._mask_arg(mask, "prefill_mask", (self.cfg.vocab_size,)),
+                    *self._state_args(slot, self._suffix_columns(w)),
+                )
+            with self._part("prefill", "call"):
+                token, ok, ck, cv, state, counts = self._prefix_prefill_jit(self.params, *args)
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
@@ -1536,6 +1592,8 @@ class GenerationEngine:
                        "blocks_per_sequence": self.window_columns,
                        "held_by_a_sequence_peak": self.window_held_peak},
             "window_released_total": self.window_released_total,
+            "window_releases_total": self.window_releases_total,
+            "window_release_total_s": self.window_release_total_s,
             "live_bytes": full * cc.bytes_per_block + window * wc.bytes_per_block,
             "one_table_bytes": full * (cc.bytes_per_block + wc.bytes_per_block),
         }
@@ -1984,13 +2042,14 @@ class GenerationEngine:
         if path in self.unsupported:
             raise NotImplementedError(self.unsupported[path])
 
-    def _stage(self, name: str, host: np.ndarray) -> jax.Array:
-        """Device-resident staging: upload ``host`` once and reuse the
-        device array until the contents change. Slot-constant decode/
-        verify args (block tables, sampling params, seeds) change only
-        on batch-composition events, so steady state stops paying a
-        fresh ``jnp.asarray`` per arg per step. The host snapshot is
-        copied — callers may mutate their arrays in place afterwards."""
+    def _lookup(self, name: str, host: np.ndarray):
+        """Device-resident staging, the half that is host arithmetic:
+        the device array ``name`` was uploaded as while its contents are
+        still ``host``'s (a hit), else ``(name, host)`` for
+        :meth:`_upload` to send (a miss). Slot-constant decode/verify
+        args (block tables, sampling params, seeds) change only on
+        batch-composition events, so steady state stops paying a fresh
+        ``jnp.asarray`` per arg per step."""
         cached = self._staged.get(name)
         if (
             cached is not None
@@ -1998,43 +2057,77 @@ class GenerationEngine:
             and cached[0].dtype == host.dtype
             and np.array_equal(cached[0], host)
         ):
+            self.uploads["staged_hits_total"] += 1
             return cached[1]
-        dev = self._dev(host)
-        self._staged[name] = (host.copy(), dev)
-        return dev
+        self.uploads["staged_misses_total"] += 1
+        return name, host
 
-    def _decode_args(self, positions, block_tables, active, temps, top_ks, seeds, counts, bias, mask=None, window=None):
-        """Assemble the decode jit's argument tuple (minus the token
-        array, which the pipelined path carries device-resident).
-        ``window``: what :meth:`advance_windows` returned for this step,
-        where the caller has made that call already."""
-        context_lens = np.where(active, positions + 1, 0).astype(np.int32)
-        safe_pos = np.where(active, positions, 0).astype(np.int32)
-        # scratch-mask inactive slots' tables too: an inactive slot with
-        # a REAL table (a bisection probe deactivating a live slot)
-        # would otherwise write its position-0 K/V into that slot's
-        # first real block and silently corrupt the surviving stream
-        tables = np.where(active[:, None], block_tables, 0).astype(np.int32)
-        self.sampling_steps[sampling_branch(temps, top_ks)] += 1
+    def _upload(self, x) -> jax.Array:
+        """The half that transfers: a fresh host array, or a miss of
+        :meth:`_lookup` (whose host snapshot is copied: callers may
+        mutate their arrays in place afterwards), goes to the device; a
+        hit, or an array that is on the device already, passes."""
+        if isinstance(x, tuple):
+            name, host = x
+            dev = self._dev(host)
+            self._staged[name] = (host.copy(), dev)
+            return dev
+        return self._dev(x) if isinstance(x, np.ndarray) else x
+
+    def _stage(self, name: str, host: np.ndarray) -> jax.Array:
+        """Both halves at once, for a caller outside a dispatch span."""
+        return self._upload(self._lookup(name, host))
+
+    def _decode_args(self, tokens, positions, block_tables, active, temps, top_ks, seeds, counts, bias, mask=None,
+                     window=None):
+        """Assemble the decode jit's argument tuple after the
+        parameters, in the two children of the dispatch span that the
+        work falls into: ``args`` (the masks, the casts, the staging
+        compares, the sampling-branch count) and ``upload`` (every
+        transfer: three fresh vectors a step, a staging miss, and
+        ``tokens`` where it is a host array; the pipelined path carries
+        it device-resident, and it passes). ``window``: what
+        :meth:`advance_windows` returned for this step, where the caller
+        has made that call already; a caller that has not pays it here,
+        before ``args`` opens, in the parent's self time."""
         if self.window_config is None:
             window = ()
         else:
-            window = (self.advance_windows(safe_pos, active) if window is None else window,)
+            window = (self.advance_windows(positions, active) if window is None else window,)
+        with self._part("decode", "args"):
+            context_lens = np.where(active, positions + 1, 0).astype(np.int32)
+            safe_pos = np.where(active, positions, 0).astype(np.int32)
+            # scratch-mask inactive slots' tables too: an inactive slot with
+            # a REAL table (a bisection probe deactivating a live slot)
+            # would otherwise write its position-0 K/V into that slot's
+            # first real block and silently corrupt the surviving stream
+            tables = np.where(active[:, None], block_tables, 0).astype(np.int32)
+            self.sampling_steps[sampling_branch(temps, top_ks)] += 1
+            counts = counts.astype(np.int32)
+            staged = [
+                self._lookup("decode.tables", tables),
+                self._lookup("decode.temps", temps.astype(np.float32)),
+                self._lookup("decode.top_ks", top_ks.astype(np.int32)),
+                self._lookup("decode.seeds", seeds.astype(np.uint32)),
+            ]
+        with self._part("decode", "upload"):
+            tokens, safe_pos, lens, counts = (self._upload(x) for x in (tokens, safe_pos, context_lens, counts))
+            tables, temps, top_ks, seeds = (self._upload(x) for x in staged)
+            bias = self._bias_arg(bias)
+            mask = self._mask_arg(mask, "decode_mask", (self.max_batch_slots, self.cfg.vocab_size))
         return (
-            self._dev(safe_pos),
+            tokens,
+            safe_pos,
             self.cache.k,
             self.cache.v,
-            self._stage("decode.tables", tables),
-            self._dev(context_lens),
-            self._stage("decode.temps", temps.astype(np.float32)),
-            self._stage("decode.top_ks", top_ks.astype(np.int32)),
-            self._bias_arg(bias),
-            self._stage("decode.seeds", seeds.astype(np.uint32)),
-            self._dev(counts.astype(np.int32)),
-            self._mask_arg(
-                mask, "decode_mask",
-                (self.max_batch_slots, self.cfg.vocab_size),
-            ),
+            tables,
+            lens,
+            temps,
+            top_ks,
+            bias,
+            seeds,
+            counts,
+            mask,
             # the slots' convolution state alone (not the blocks'
             # snapshots, which no decode step touches), the window
             # layers' K/V, and the counters
@@ -2064,7 +2157,7 @@ class GenerationEngine:
         tables = np.zeros((self.max_batch_slots, self.window_columns), np.int32)
         first = np.zeros((self.max_batch_slots,), np.int32)
         full = held = 0
-        with phase("cache.window_release"):
+        with phase("cache.window_release") as release:
             for slot in np.nonzero(active)[0]:
                 t = self.window_tables.get(int(slot))
                 if t is None:  # a caller with tables of its own, decoding from position 0
@@ -2076,6 +2169,8 @@ class GenerationEngine:
                 held += len(t.blocks)
                 self.window_held_peak = max(self.window_held_peak, len(t.blocks))
         self._live_blocks = (full, held)
+        self.window_releases_total += 1
+        self.window_release_total_s += release.seconds
         return {"tables": self._stage("decode.wtables", tables), "first": self._stage("decode.wfirst", first)}
 
     def decode(
@@ -2105,13 +2200,16 @@ class GenerationEngine:
             faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
         self.step_counts["decode"] += 1
         self._count_expert_form(self.max_batch_slots)
-        with phase("engine.decode.dispatch") as disp:
+        self._children = []
+        with phase("engine.decode.dispatch", cpu=self.cpu_stamps) as disp:
             traces_before = self.trace_counts.get("decode", 0)
             args, context_lens = self._decode_args(
-                positions, block_tables, active, temps, top_ks, seeds,
+                masked, positions, block_tables, active, temps, top_ks, seeds,
                 counts, bias, mask,
             )
-            out, ok, ck, cv, state, counts = self._decode_jit(self.params, self._dev(masked), *args)
+            with self._part("decode", "call"):
+                out, ok, ck, cv, state, counts = self._decode_jit(self.params, *args)
+        self._count_dispatch(disp)
         with phase("engine.decode.block") as block:
             jax.block_until_ready((out, ok, ck, cv, state))  # device execution done
         with phase("engine.decode.readback") as read:
@@ -2133,34 +2231,37 @@ class GenerationEngine:
         """Post-success decode accounting, shared by the blocking and
         pipelined paths: FLOPs accrue next to the time they pair with;
         a compile call registry-stamps its wall time instead of feeding
-        the truth ledger."""
-        flops = self.flops_model.decode_flops(n_active, ctx_sum)
-        self.flops_by_kind["decode"] += flops
-        if self._n_latent:
-            self.latent_tokens_held = ctx_sum
-            self.latent_calls["absorbed"] += self._n_latent
-        if traced:
-            self.programs.set_compile_time("decode", elapsed)
-        else:
-            # EXECUTED work: the fixed-shape program runs every batch
-            # slot's projections/FFN (inactive rows masked to scratch,
-            # but computed); only attention context is truly live-only
-            b = self.max_batch_slots
-            self.ledger.observe(
-                "decode",
-                self.flops_model.roofline_s(
-                    self.flops_model.decode_flops(b, ctx_sum),
-                    self.flops_model.decode_bytes(b, ctx_sum),
-                ),
-                execute_s,
-                label=f"decode ({self.flops_model.chip.name})",
-                provenance="serving roofline (ServingFlops x chip peak)",
-                alarm=self._roofline_alarm,
-            )
-            if self.serving_strategy is not None:
-                # pair the measured step against the layout-search
-                # estimate too: drift telemetry covers the DECISION
-                self.ledger.measure("serving_strategy:decode", execute_s)
+        the truth ledger. In a span of its own
+        (``ff.engine.decode.account``, a host-lane sibling after the
+        readback: 30-40 us a step that no span named)."""
+        with phase("engine.decode.account", into=self.last_step_spans):
+            flops = self.flops_model.decode_flops(n_active, ctx_sum)
+            self.flops_by_kind["decode"] += flops
+            if self._n_latent:
+                self.latent_tokens_held = ctx_sum
+                self.latent_calls["absorbed"] += self._n_latent
+            if traced:
+                self.programs.set_compile_time("decode", elapsed)
+            else:
+                # EXECUTED work: the fixed-shape program runs every batch
+                # slot's projections/FFN (inactive rows masked to scratch,
+                # but computed); only attention context is truly live-only
+                b = self.max_batch_slots
+                self.ledger.observe(
+                    "decode",
+                    self.flops_model.roofline_s(
+                        self.flops_model.decode_flops(b, ctx_sum),
+                        self.flops_model.decode_bytes(b, ctx_sum),
+                    ),
+                    execute_s,
+                    label=f"decode ({self.flops_model.chip.name})",
+                    provenance="serving roofline (ServingFlops x chip peak)",
+                    alarm=self._roofline_alarm,
+                )
+                if self.serving_strategy is not None:
+                    # pair the measured step against the layout-search
+                    # estimate too: drift telemetry covers the DECISION
+                    self.ledger.measure("serving_strategy:decode", execute_s)
 
     def decode_async(
         self,
@@ -2206,33 +2307,38 @@ class GenerationEngine:
             faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
         self.step_counts["decode"] += 1
         self._count_expert_form(self.max_batch_slots)
-        with phase("engine.decode.dispatch") as disp:
+        children = self._children = []
+        with phase("engine.decode.dispatch", cpu=self.cpu_stamps) as disp:
             traces_before = self.trace_counts.get("decode", 0)
             args, context_lens = self._decode_args(
-                positions, block_tables, active, temps, top_ks, seeds,
-                counts, bias, mask, window,
+                masked if tokens_dev is None else tokens_dev, positions, block_tables, active, temps, top_ks,
+                seeds, counts, bias, mask, window,
             )
-            tok_arg = tokens_dev if tokens_dev is not None else self._dev(masked)
             prev_k, prev_v, prev_conv = (None, None, None) if self.donate else (
                 self.cache.k, self.cache.v, self.cache.state.get("conv")
             )
             prev_window = None
             if self.window_config is not None and not self.donate:
                 prev_window = {k: self.cache.state[k] for k in ("wk", "wv")}
-            out, ok, ck, cv, state, counts = self._decode_jit(self.params, tok_arg, *args)
-        # start the device->host copies NOW; consume_decode's numpy
-        # conversion then finds the bytes already resident
-        out.copy_to_host_async()
-        ok.copy_to_host_async()
-        self.cache.update(ck, cv, **state)
-        prev_counts, self.expert_counts = self.expert_counts, counts
-        self.phase_time_s["decode"]["dispatch"] += disp.seconds
-        return InFlightDecode(
-            out, ok, prev_k, prev_v, ck, cv, disp.t0, disp.t1,
-            traced=self.trace_counts.get("decode", 0) > traces_before,
-            n_active=int(active.sum()), ctx_sum=int(context_lens.sum()),
-            prev_conv=prev_conv, prev_counts=prev_counts, prev_window=prev_window,
-        )
+            with self._part("decode", "call"):
+                out, ok, ck, cv, state, counts = self._decode_jit(self.params, *args)
+        with phase("engine.decode.post") as post:
+            # start the device->host copies NOW; consume_decode's numpy
+            # conversion then finds the bytes already resident
+            out.copy_to_host_async()
+            ok.copy_to_host_async()
+            self.cache.update(ck, cv, **state)
+            prev_counts, self.expert_counts = self.expert_counts, counts
+            self.phase_time_s["decode"]["dispatch"] += disp.seconds
+            self._count_dispatch(disp)
+            step = InFlightDecode(
+                out, ok, prev_k, prev_v, ck, cv, disp.t0, disp.t1,
+                traced=self.trace_counts.get("decode", 0) > traces_before,
+                n_active=int(active.sum()), ctx_sum=int(context_lens.sum()),
+                prev_conv=prev_conv, prev_counts=prev_counts, prev_window=prev_window,
+            )
+        step.post, step.children = post.span, children
+        return step
 
     def rollback_decode(self, step: InFlightDecode) -> None:
         """Put the cache back to what it was before ``step`` (a failed
@@ -2280,6 +2386,7 @@ class GenerationEngine:
         self.last_step_spans = [
             block.span, ("execute", step.t_started, t_exec), read.span,
         ]
+        self.last_step_children = []  # the dispatch and its parts went to the scheduler with the handle
         self._account_decode(
             step.n_active, step.ctx_sum, step.traced,
             elapsed=read.t1 - step.t0,
@@ -2352,26 +2459,30 @@ class GenerationEngine:
         live = n_draft >= 0
         w_tok = np.where(live, nd + 1, 0)
         ctx = np.where(live, w_tok * (start.astype(np.int64) + 1) + nd * (nd + 1) // 2, 0)
+        self._children = []
         with phase("engine.verify.dispatch") as disp:
             traces_before = self.trace_counts.get("verify", 0)
-            out, n_emitted, ok, ck, cv = self._verify_jit(
-                self.params,
-                self._dev(window),
-                self._dev(start.astype(np.int32)),
-                self._dev(n_draft.astype(np.int32)),
-                self.cache.k,
-                self.cache.v,
-                self._stage("verify.tables", block_tables.astype(np.int32)),
-                self._stage("verify.temps", temps.astype(np.float32)),
-                self._stage("verify.top_ks", top_ks.astype(np.int32)),
-                self._bias_arg(bias),
-                self._stage("verify.seeds", seeds.astype(np.uint32)),
-                self._dev(counts.astype(np.int32)),
-                self._mask_arg(
-                    mask, "verify_mask",
-                    (self.max_batch_slots, self.spec_window, self.cfg.vocab_size),
-                ),
-            )
+            with self._part("verify", "args"):
+                fresh = [window, start.astype(np.int32), n_draft.astype(np.int32), counts.astype(np.int32)]
+                staged = [
+                    self._lookup("verify.tables", block_tables.astype(np.int32)),
+                    self._lookup("verify.temps", temps.astype(np.float32)),
+                    self._lookup("verify.top_ks", top_ks.astype(np.int32)),
+                    self._lookup("verify.seeds", seeds.astype(np.uint32)),
+                ]
+            with self._part("verify", "upload"):
+                window, start_dev, n_draft_dev, counts_dev = (self._upload(x) for x in fresh)
+                tables, temps_dev, top_ks_dev, seeds_dev = (self._upload(x) for x in staged)
+                args = (
+                    window, start_dev, n_draft_dev, self.cache.k, self.cache.v, tables, temps_dev, top_ks_dev,
+                    self._bias_arg(bias), seeds_dev, counts_dev,
+                    self._mask_arg(
+                        mask, "verify_mask",
+                        (self.max_batch_slots, self.spec_window, self.cfg.vocab_size),
+                    ),
+                )
+            with self._part("verify", "call"):
+                out, n_emitted, ok, ck, cv = self._verify_jit(self.params, *args)
         with phase("engine.verify.block") as block:
             jax.block_until_ready((out, n_emitted, ok, ck, cv))  # execution done
         with phase("engine.verify.readback") as read:

@@ -150,7 +150,7 @@ from .speculative.drafter import SpeculationConfig, build_drafter
 _END = object()  # token-stream sentinel
 
 # the engine's host spans: one "device" total in a flight record
-_ENGINE_PHASES = frozenset({"dispatch", "block", "readback"})
+_ENGINE_PHASES = frozenset({"dispatch", "block", "readback", "account"})
 
 
 def _flight_phases(spans, walls: Dict[str, float]) -> Dict[str, float]:
@@ -159,7 +159,7 @@ def _flight_phases(spans, walls: Dict[str, float]) -> Dict[str, float]:
     is no anatomy span — ``device``, the wall of the supervised device
     step (failed attempts, retries and bisection included, which is
     what an incident needs), and the pipeline's ``dispatch``. The
-    engine's own dispatch / block / readback sit inside ``device``; the
+    engine's own dispatch / block / readback / account sit inside ``device``; the
     device-lane execute span is the record's ``execute_s``."""
     out = dict(walls)
     for name, t0, t1 in spans:
@@ -504,6 +504,12 @@ class _Frontier:
 # wakes it sooner), and how many finished request traces
 # GET /v2/debug/traces keeps
 IDLE_WAIT_S = 0.002
+# the thread's CPU clock (time.thread_time) is read on one iteration in
+# this many: a system call whose cost grows with the process's threads
+# (~30 us a read in a serving process on the chip's host, against 0.3 us
+# alone: PERF.md, PR 37), so every iteration would pay 3-4 % of a
+# 3 ms step for it. The totals it feeds are of the sampled iterations.
+CPU_CLOCK_EVERY = 16
 TRACE_RING_SIZE = 256
 # why _drain_frontier emptied the overlap pipeline (its callers' reasons)
 _DRAIN_REASONS = ("nonsteady", "finish", "pressure", "idle")
@@ -639,16 +645,24 @@ class ContinuousBatchingScheduler:
         self._step_recorded = False
         # step-anatomy profiler (obs/steptrace.py): first-class host
         # spans + the device execute span per iteration, feeding the
-        # flexflow_serving_step_phase_seconds histograms, the
-        # device-bubble/overlap-headroom gauges, and the on-demand
-        # two-lane capture on GET /v2/debug/anatomy. _step_spans holds
-        # THIS iteration's (phase, t0, t1) perf_counter stamps, as
-        # obs/steptrace.phase leaves them — the one account of a step:
-        # the flight record's phase durations are summed from it too.
-        # Loop thread only.
+        # flexflow_serving_step_phase_seconds histograms, the conserved
+        # account of this thread's seconds (/v2/stats "step_phases" and
+        # "loop") and the on-demand two-lane capture on GET
+        # /v2/debug/anatomy. _step_spans holds THIS iteration's
+        # (phase, t0, t1) perf_counter stamps, as obs/steptrace.phase
+        # leaves them — the one account of a step: the flight record's
+        # phase durations are summed from it too. _step_children holds
+        # the parts of its dispatch spans (inside them, so in no sum of
+        # the lane). Loop thread only.
         self.anatomy = StepAnatomy(enabled=observability)
         self.anatomy.register_gauges(self.stats)
         self._step_spans: List = []
+        self._step_children: List = []
+        # what the anatomy's last observation cost (ff.sched.observe):
+        # handed to the next one, so the layer's own cost is a phase
+        self._observe_carry = 0.0
+        # iterations until the thread's CPU clock is read again
+        self._cpu_turn = 0
         # the flight record's walls that are no anatomy span ("device",
         # the pipeline's "dispatch"), for THIS iteration; loop thread only
         self._step_walls: Dict[str, float] = {}
@@ -663,6 +677,8 @@ class ContinuousBatchingScheduler:
             # the cache's own spans (ff.cache.offload / .restore) land
             # in this model's windows: timed where the work happens
             engine.prefix_cache.observe = self.stats.observe
+            self.stats.add_section("loop", self._loop_section)
+            self.stats.add_section("uploads", engine.upload_stats)
         self.stats.add_section("sampling", engine.sampling_stats)
         self.stats.add_section("kernels", engine.kernel_stats)
         # what the layers that are not attention-and-MLP count (cumulative,
@@ -1454,10 +1470,27 @@ class ContinuousBatchingScheduler:
         )
 
     def _loop(self) -> None:
-        while (self._alive or (self._draining and self.has_work())) and not self._hard_stop:
-            if not self.step():
-                self._wake.wait(timeout=IDLE_WAIT_S)
-                self._wake.clear()
+        # this thread's wall is counted from here (/v2/stats "loop"):
+        # every second of it is a working iteration's, an empty one's,
+        # a wait's, or the few lines of this loop between them
+        self.anatomy.loop_started(time.perf_counter())
+        try:
+            while (self._alive or (self._draining and self.has_work())) and not self._hard_stop:
+                if not self.step():
+                    parked = time.perf_counter()
+                    self._wake.wait(timeout=IDLE_WAIT_S)
+                    self._wake.clear()
+                    self.anatomy.observe_wait(parked, time.perf_counter())
+        finally:
+            self.anatomy.loop_stopped()
+
+    def _loop_section(self) -> Dict[str, float]:
+        """``loop`` of ``/v2/stats``: the anatomy's account of this
+        thread and, from the same stamps' places as the decode dispatch
+        span, that span's wall and CPU seconds over the sampled
+        iterations (wall less CPU: the thread held no core)."""
+        wall, cpu = self.engine.decode_dispatch_clock
+        return dict(self.anatomy.loop(), decode_dispatch_wall_total_s=wall, decode_dispatch_cpu_total_s=cpu)
 
     # ---------------------------------------------------------- internals
     def _release(self, state: _Running) -> None:
@@ -2461,8 +2494,8 @@ class ContinuousBatchingScheduler:
             # the watchdog tripped while this chain was in flight: the
             # late result is stale — discard everything and replay
             # (exactly run_step's post-success stall arbitration). The
-            # restart-inflated iteration stays out of the hot anatomy
-            # window, like every handled failure (the PR 12 rule).
+            # flight record marks the restart-inflated iteration, like
+            # every handled failure.
             self._step_info["handled_failure"] = True
             self._discard_frontier()
             self.supervisor._restart_and_replay(
@@ -2484,6 +2517,13 @@ class ContinuousBatchingScheduler:
         with self._phase("sched.bookkeep"):
             n_live, finish = self._scatter_decode(f.states, out, defer_finish=True)
             self.token_rate.record(n_live)
+        # the consumed step's handle goes HERE, in a span, and not
+        # wherever the frame that holds the frontier returns: dropping
+        # its device arrays waits for the successor in flight, which
+        # reads them (0.3-1 ms a step in chat-steady, 7 in gen-batch:
+        # PERF.md, PR 37), and no span named that wait
+        with self._phase("sched.release"):
+            f.handle = None
         if finish:
             # finish/EOS is a non-steady event: the successor step may
             # still be writing into the finishing streams' blocks —
@@ -2505,43 +2545,44 @@ class ContinuousBatchingScheduler:
         the predecessor's, bumped in place — steady state rebuilds
         nothing and re-uploads nothing but three [B] scalars-per-slot
         vectors."""
-        b = self.engine.max_batch_slots
-        sig = tuple((s.slot, s.req.id, len(s.blocks)) for s in live)
-        covered = {s.slot for s in prev.states} if prev is not None else set()
-        if prev is not None and prev.sig == sig:
-            positions, active = prev.positions, prev.active
-            temps, top_ks = prev.temps, prev.top_ks
-            seeds, counts, tables = prev.seeds, prev.counts, prev.tables
-            for s in live:  # same composition: everyone advances by one
-                positions[s.slot] += 1
-                counts[s.slot] += 1
-        else:
-            positions = np.zeros((b,), np.int32)
-            active = np.zeros((b,), bool)
-            temps = np.zeros((b,), np.float32)
-            top_ks = np.zeros((b,), np.int32)
-            seeds = np.zeros((b,), np.uint32)
-            counts = np.zeros((b,), np.int32)
-            tables = np.zeros((b, self.engine.max_blocks_per_seq), np.int32)
-            for s in live:
-                i = s.slot
-                pend = 1 if i in covered else 0
-                positions[i] = s.cached_len + pend
-                counts[i] = s.req.n_generated + pend
-                active[i] = True
-                temps[i] = s.req.sampling.temperature
-                top_ks[i] = s.req.sampling.top_k
-                seeds[i] = s.req.sampling.seed & 0xFFFFFFFF
-                tables[i, : len(s.blocks)] = s.blocks
-        tokens_host = None
-        tokens_dev = prev.handle.out if prev is not None else None
-        if prev is None:
-            tokens_host = np.zeros((b,), np.int32)
-            for s in live:
-                req = s.req
-                tokens_host[s.slot] = (
-                    req.generated[-1] if req.generated else req.prompt[-1]
-                )
+        with self._phase("sched.stage"):
+            b = self.engine.max_batch_slots
+            sig = tuple((s.slot, s.req.id, len(s.blocks)) for s in live)
+            covered = {s.slot for s in prev.states} if prev is not None else set()
+            if prev is not None and prev.sig == sig:
+                positions, active = prev.positions, prev.active
+                temps, top_ks = prev.temps, prev.top_ks
+                seeds, counts, tables = prev.seeds, prev.counts, prev.tables
+                for s in live:  # same composition: everyone advances by one
+                    positions[s.slot] += 1
+                    counts[s.slot] += 1
+            else:
+                positions = np.zeros((b,), np.int32)
+                active = np.zeros((b,), bool)
+                temps = np.zeros((b,), np.float32)
+                top_ks = np.zeros((b,), np.int32)
+                seeds = np.zeros((b,), np.uint32)
+                counts = np.zeros((b,), np.int32)
+                tables = np.zeros((b, self.engine.max_blocks_per_seq), np.int32)
+                for s in live:
+                    i = s.slot
+                    pend = 1 if i in covered else 0
+                    positions[i] = s.cached_len + pend
+                    counts[i] = s.req.n_generated + pend
+                    active[i] = True
+                    temps[i] = s.req.sampling.temperature
+                    top_ks[i] = s.req.sampling.top_k
+                    seeds[i] = s.req.sampling.seed & 0xFFFFFFFF
+                    tables[i, : len(s.blocks)] = s.blocks
+            tokens_host = None
+            tokens_dev = prev.handle.out if prev is not None else None
+            if prev is None:
+                tokens_host = np.zeros((b,), np.int32)
+                for s in live:
+                    req = s.req
+                    tokens_host[s.slot] = (
+                        req.generated[-1] if req.generated else req.prompt[-1]
+                    )
         hb_prev = self._heartbeat
         seq0 = prev.seq0 if prev is not None else self._hb_seq
         self._hb_seq += 1
@@ -2563,7 +2604,8 @@ class ContinuousBatchingScheduler:
             self._heartbeat = hb_prev  # the step never went in flight
             self._hb_seq = seq  # seq stays burned; stall flags on it are void
             raise
-        self._step_spans.append(("dispatch", handle.t0, handle.t_disp))
+        self._step_spans += [("dispatch", handle.t0, handle.t_disp), handle.post]
+        self._step_children += handle.children
         self._add_wall("dispatch", handle.t_disp - handle.t0)
         return _Frontier(
             handle, list(live), positions, active, temps, top_ks, seeds,
@@ -2948,11 +2990,14 @@ class ContinuousBatchingScheduler:
         field."""
         spans = self.engine.last_step_spans
         self._step_spans.extend(spans)
+        self._step_children.extend(self.engine.last_step_children)
         if decode_result:
-            t = spans[-1][2]  # the readback's end
-            for _ in range(self._stalled_admits):
-                self.stats.observe("admit_stall", max(0.0, t - self._stall_from))
-            self._stalled_admits = 0
+            t = max(s1 for name, _, s1 in spans if name == "readback")  # the readback's end (the engine's accounting follows it)
+            if self._stalled_admits:
+                with self._phase("sched.observe"):
+                    for _ in range(self._stalled_admits):
+                        self.stats.observe("admit_stall", max(0.0, t - self._stall_from))
+                self._stalled_admits = 0
             self._result_t = t
         return sum(s1 - s0 for name, s0, s1 in spans if name == "execute")
 
@@ -2974,15 +3019,16 @@ class ContinuousBatchingScheduler:
         if self._step_recorded or not self.flight.enabled:
             return
         self._step_recorded = True
-        info = dict(self._step_info)
-        self.flight.record_step(
-            info.pop("kind", "admit"),
-            phases=_flight_phases(self._step_spans, self._step_walls),
-            occupancy=len(self._running),
-            queue_depth=len(self._queue),
-            blocks_free=self.engine.allocator.num_free,
-            **info,
-        )
+        with self._phase("sched.observe"):
+            info = dict(self._step_info)
+            self.flight.record_step(
+                info.pop("kind", "admit"),
+                phases=_flight_phases(self._step_spans, self._step_walls),
+                occupancy=len(self._running),
+                queue_depth=len(self._queue),
+                blocks_free=self.engine.allocator.num_free,
+                **info,
+            )
 
     # ---------------------------------------------------------------- step
     def step(self) -> bool:
@@ -3003,11 +3049,19 @@ class ContinuousBatchingScheduler:
     def _step_impl(self) -> bool:
         info = self._step_info = {}
         self._step_spans = []
+        self._step_children = []
         self._step_walls = {}
         self._step_recorded = False
         if not self._running:
             self._result_t = None  # nothing decodes: no stream to hold
         t0 = time.perf_counter()
+        # wall against CPU, on one iteration in CPU_CLOCK_EVERY: the
+        # thread's CPU clock at the iteration's two ends and, read by the
+        # engine, at its decode dispatch's two ends (four reads)
+        sampled = self.obs_enabled and self._cpu_turn == 0
+        self._cpu_turn = (self._cpu_turn + 1) % CPU_CLOCK_EVERY
+        self.engine.cpu_stamps = sampled
+        cpu0 = time.thread_time() if sampled else None
         admitted = 0
         # overlapped decode: steady-state iterations pipeline
         # dispatch/consume; any non-steady event drains the frontier
@@ -3050,18 +3104,26 @@ class ContinuousBatchingScheduler:
             # pressure flag
             self.capacity.tick()
             self._overload_tick()
+        if not self.obs_enabled:
+            return did
         if did:
             # one anatomy observation per working iteration: host spans
             # + the device execute lane, under the iteration's step kind
             # (admission work inside a decode iteration charges the
             # decode critical path — which is exactly where it sits).
-            # Handled-failure iterations stay out of the hot window:
-            # they have no execute span and a retry-inflated wall that
-            # would skew the bubble/headroom math for a whole window.
-            self.anatomy.observe_step(
-                info.get("kind", kind), self._step_spans, t0,
-                time.perf_counter(),
-                tokens=int(info.get("emitted", 0)) + admitted,
-                hot=not info.get("handled_failure", False),
-            )
+            # The iteration ends where the observation begins; what the
+            # observation costs is timed here and carried into the next
+            # one as ``observe`` time (the tracing layer's own cost).
+            cpu_s = time.thread_time() - cpu0 if sampled else None
+            with phase("sched.observe") as p_obs:
+                self.anatomy.observe_step(
+                    info.get("kind", kind), self._step_spans, t0, p_obs.t0,
+                    tokens=int(info.get("emitted", 0)) + admitted,
+                    children=self._step_children,
+                    carried_s=self._observe_carry, cpu_s=cpu_s,
+                )
+            self._observe_carry = p_obs.seconds
+        else:
+            # an iteration that found nothing to do: counted, not dropped
+            self.anatomy.observe_empty(t0, time.perf_counter())
         return did

@@ -38,15 +38,15 @@ PR 7 adds the *truth* dimension:
 PR 12 adds the *step-anatomy* dimension:
 
 * :mod:`steptrace` — the :class:`StepAnatomy` profiler: first-class
-  host spans (schedule / admit / prefix_plan / draft / sample /
-  dispatch / block / readback / bookkeep) plus an independently
+  host spans (schedule / admit / prefix_plan / draft / stage /
+  dispatch with its parts args, upload and call / post / block /
+  readback / account / bookkeep / release / housekeep / observe) plus an independently
   measured device ``execute`` span per scheduler iteration, feeding
   per-``{kind, phase}`` histograms
-  (``flexflow_serving_step_phase_seconds``), a rolling
-  ``device_bubble_ratio`` with host-bound/device-bound classification,
-  an on-demand K-step capture rendered as a two-lane real-offset
-  chrome://tracing timeline, and the Amdahl-style overlap-headroom
-  projection gating ROADMAP item 4
+  (``flexflow_serving_step_phase_seconds``), the conserved account of
+  the scheduler thread's seconds (``/v2/stats`` ``step_phases`` with
+  its ``unspanned`` remainder, and ``loop``), and an on-demand K-step
+  capture rendered as a two-lane real-offset chrome://tracing timeline
   (``GET /v2/debug/anatomy?capture=K``).
 
 PR 20 adds the *fleet* dimension:

@@ -108,11 +108,7 @@ _HELP = {
     "flexflow_sim_prediction_pairs_total": "Measured samples joined with a registered prediction, per key.",
     "flexflow_sim_prediction_unpredicted_total": "Measured samples that had no registered prediction (counted, not dropped).",
     "flexflow_sim_drift_alarms_total": "Calibration-drift alarms raised by the process-wide prediction ledger.",
-    "step_phase_seconds": "Step-anatomy phase durations per step kind (host spans + the device execute lane).",
-    "step_device_bubble_ratio": "Fraction of hot-path step wall time the device sat idle while the host worked (rolling window).",
-    "step_host_bound": "Rolling-window classification: 1 host-bound, 0 device-bound (absent before enough steps).",
-    "step_overlap_projected_tokens_per_s": "Amdahl projection: tokens/s if host phases were hidden behind device execution.",
-    "step_overlap_projected_speedup": "Projected step-wall speedup from fully overlapping host work with device execution.",
+    "step_phase_seconds": "Step-anatomy phase durations per step kind (host spans, the parts of a dispatch, the unspanned remainder, the device execute lane).",
     "step_anatomy_steps_observed": "Scheduler iterations folded into the step-anatomy aggregator.",
     "overload_limit": "AdaptiveLimiter's live AIMD concurrency limit (queued + running requests).",
     "overload_inflight": "Live requests currently counted against the adaptive concurrency limit.",

@@ -1,42 +1,44 @@
-"""Step-anatomy profiler: critical-path spans, device-bubble accounting,
-and the overlap-headroom report for the decode hot path.
+"""Step-anatomy profiler: critical-path spans and the conserved account
+of the scheduler thread's seconds.
 
 The flight recorder (obs/flight.py) records per-step phase *durations*
 and renders them back-to-back — a synthetic layout that cannot show
-WHERE inside the step each phase sat, nor how much of the step the
-device actually computed. Before the host/device overlap refactor
-(ROADMAP item 4) can be built or gated, serving needs the instrument
-that answers three questions:
+WHERE inside the step each phase sat. This module answers two
+questions:
 
 1. **Where does a step's wall time go?** Every scheduler iteration
    decomposes into first-class host spans — ``schedule`` (expire /
    speculation planning / growth / slot collection), ``admit``
    (queue pop, block acquisition, post-prefill bookkeeping),
-   ``prefix_plan`` (radix match + table assembly, PR 11's new hot
-   cost), ``draft`` (speculative proposal), ``sample`` (per-request
-   PRNG key assembly), ``dispatch`` (host arg prep + XLA dispatch),
-   ``block`` (host parked in ``block_until_ready``), ``readback``
-   (device->host sync + numpy conversion), ``bookkeep`` (token
-   scatter) — plus an independently measured device-lane ``execute``
-   span (dispatch-return to ``block_until_ready`` completion, so XLA's
-   async dispatch separates device compute from host-blocked waiting).
-   Spans carry real ``perf_counter`` offsets, not just durations.
+   ``prefix_plan`` (radix match + table assembly), ``draft``
+   (speculative proposal), ``stage`` (the pipeline's assembly of a
+   step's arrays), ``dispatch`` (host arg prep + XLA dispatch),
+   ``post`` (a pipelined dispatch's device-to-host copies, cache swap
+   and handle), ``block`` (host parked in ``block_until_ready``),
+   ``readback`` (device->host sync + numpy conversion), ``account``
+   (the engine's FLOPs and truth-ledger pair for the step), ``bookkeep``
+   (token scatter), ``release`` (the consumed step's handle dropped:
+   a wait for the successor in flight), ``housekeep``, ``observe``
+   (this layer's own record-keeping) — plus an independently measured device-lane
+   ``execute`` span. Spans carry real ``perf_counter`` offsets, not
+   just durations. A dispatch has CHILDREN, ``dispatch.args`` (host
+   arithmetic), ``dispatch.upload`` (host-to-device transfers) and
+   ``dispatch.call`` (the jit call): they lie inside their parent, are
+   no host-lane spans, and the parent's self time is its seconds less
+   theirs.
 
-2. **Is steady-state decode host-bound or device-bound?** The
+2. **Is every second of the scheduler's thread accounted for?** The
    always-on aggregator keeps per-``{kind, phase}`` histograms
-   (exported as ``flexflow_serving_step_phase_seconds`` on /metrics)
-   and a rolling window of token-emitting steps from which it derives
-   ``device_bubble_ratio`` — the fraction of step wall time the device
-   sat idle while the host worked — and a host-bound / device-bound
-   classification.
-
-3. **What would overlap buy?** :meth:`overlap_headroom` is the
-   Amdahl-style projection: if every host phase were hidden behind
-   device execution (step wall -> max(execute, dispatch), dispatch
-   being the serial residue that must still issue each program), what
-   tokens/s would the same window have produced? That projected number
-   is the go/no-go input — and, once the overlap refactor lands, the
-   gate that proves the bubbles shrank.
+   (exported as ``flexflow_serving_step_phase_seconds`` on /metrics,
+   their monotone totals as ``step_phases`` on ``/v2/stats``), and per
+   working iteration the wall less the union of its host-lane spans
+   accumulates as the pseudo-phase ``unspanned``. The ``loop`` section
+   of ``/v2/stats`` carries the thread's totals: ``wall = working +
+   empty + idle_wait + (the loop's own overhead)`` and ``working =
+   host-lane phases + unspanned``, both exact under the overlap
+   pipeline (where an iteration's ``execute`` span begins in the
+   iteration before, so no per-iteration ratio of execute to wall
+   means anything).
 
 On-demand detail: :meth:`arm_capture` retains the next K steps' FULL
 span lists in a bounded ring; :meth:`to_chrome_trace` renders them as a
@@ -58,28 +60,33 @@ catalogue is in README "Step anatomy".
 
 Clock discipline (the PR 6 dual-clock decision): span stamps are
 ``time.perf_counter`` values — physical profiling data even in
-virtual-clock tests. :class:`phase` is the one place they are read
-(this module is whitelisted in analysis/config.py for perf_counter
-only); StepAnatomy itself only aggregates the stamps it is handed.
+virtual-clock tests. :class:`phase` is the one place they are read,
+and, for a span opened with ``cpu=True``, the one place the thread's
+CPU clock (``time.thread_time``) is read beside them: wall less CPU is
+the time the thread held no core (this module is whitelisted in
+analysis/config.py for these two only); StepAnatomy itself only
+aggregates the stamps it is handed.
 
 CPU-backend caveat: XLA:CPU completes small programs *inside* the
-dispatch call, so the measured ``execute`` span can be near zero and
-the bubble ratio near one on tiny CPU models — a true statement about
-that configuration (decode IS host-bound there), but not a prediction
-of TPU behavior, where dispatch returns early and ``execute`` covers
-real device compute. The README "Step anatomy" section documents this.
+dispatch call, so the measured ``execute`` span can be near zero on
+tiny CPU models — a true statement about that configuration, but not a
+prediction of TPU behavior, where dispatch returns early and
+``execute`` covers real device compute.
 
 Cost: observe_step is a handful of dict/float ops per scheduler
-iteration under one lock (no reader measures it alone: every cell runs
-with anatomy on). ``enabled=False`` makes every
+iteration under one lock; the scheduler times the call itself
+(``ff.sched.observe``) and hands the seconds to the next observation,
+so the ``observe`` phase is what the record-keeping costs (the
+benchmark's ``trace_record_share``). ``enabled=False`` makes every
 method a cheap no-op (mirrors ``observability=False``).
 """
 from __future__ import annotations
 
 import math
 import threading
+from bisect import bisect_left
 from collections import deque
-from time import perf_counter
+from time import perf_counter, thread_time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from jax.profiler import TraceAnnotation
@@ -92,10 +99,9 @@ from jax.profiler import TraceAnnotation
 # dispatch, and host bookkeeping sits under it on the other lane.
 DEVICE_PHASES = frozenset({"execute"})
 
-# step kinds whose iterations emit tokens — the decode hot path the
-# bubble/headroom window is computed over (admission-only iterations
-# are aggregated in the histograms but excluded from the window)
-HOT_KINDS = frozenset({"decode", "verify"})
+# a working iteration's wall less the union of its host-lane spans: a
+# pseudo-phase of ``step_phases``, so that what no span names is counted
+UNSPANNED = "unspanned"
 
 # phase-duration buckets (seconds): step phases live in the us..ms
 # range on warm engines; the tail covers cold CI hosts
@@ -116,21 +122,30 @@ class phase:
     encloses the stamped interval by well under a microsecond. ``into``,
     a list, receives ``p.span`` on exit, early returns and exceptions
     included. Request-scoped spans pass ``request=<id>``: the spans of
-    one request then share an argument in the trace."""
+    one request then share an argument in the trace. ``cpu=True`` reads
+    the thread's CPU clock inside the two stamps as well (``p.c0`` /
+    ``p.c1``, else None): ``p.seconds - p.cpu_seconds`` is the time the
+    thread held no core inside the span (waiting for the interpreter's
+    lock or a runtime's, or blocked in a transfer)."""
 
-    __slots__ = ("name", "t0", "t1", "_ann", "_into")
+    __slots__ = ("name", "t0", "t1", "c0", "c1", "_ann", "_into")
 
-    def __init__(self, name: str, into: Optional[List[Span]] = None, **args):
+    def __init__(self, name: str, into: Optional[List[Span]] = None, cpu: bool = False, **args):
         self.name = name
         self._into = into
+        self.c0 = self.c1 = 0.0 if cpu else None
         self._ann = TraceAnnotation("ff." + name, **args)
 
     def __enter__(self) -> "phase":
         self._ann.__enter__()
         self.t0 = perf_counter()
+        if self.c0 is not None:
+            self.c0 = thread_time()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if self.c0 is not None:
+            self.c1 = thread_time()
         self.t1 = perf_counter()
         self._ann.__exit__(exc_type, exc, tb)
         if self._into is not None:
@@ -140,6 +155,10 @@ class phase:
     @property
     def seconds(self) -> float:
         return self.t1 - self.t0
+
+    @property
+    def cpu_seconds(self) -> Optional[float]:
+        return None if self.c0 is None else self.c1 - self.c0
 
     @property
     def span(self) -> Span:
@@ -168,11 +187,7 @@ class _PhaseHist:
         self.sum = 0.0
 
     def observe(self, value: float) -> None:
-        i = 0
-        for i, b in enumerate(self.BOUNDS):  # noqa: B007 — tiny fixed scan
-            if value <= b:
-                break
-        self.counts[i] += 1
+        self.counts[bisect_left(self.BOUNDS, value)] += 1  # the first bound the value does not pass
         self.count += 1
         self.sum += value
 
@@ -198,49 +213,38 @@ class _PhaseHist:
         return self.BOUNDS[-2]
 
 
-class _WindowSample:
-    """One hot-path step in the rolling window."""
-
-    __slots__ = ("kind", "wall", "execute", "dispatch", "host", "tokens")
-
-    def __init__(self, kind, wall, execute, dispatch, host, tokens):
-        self.kind = kind
-        self.wall = wall
-        self.execute = execute
-        self.dispatch = dispatch
-        self.host = host
-        self.tokens = tokens
-
-
 class StepAnatomy:
     """Span-based step-anatomy aggregator for one scheduler.
 
-    Writers: the scheduler loop thread (``observe_step``). Readers:
-    scrape threads (gauges, ``report``, ``prom_snapshot``), the debug
-    endpoint (``arm_capture``, ``to_chrome_trace``). One lock guards
-    all mutable state.
+    Writers: the scheduler loop thread (``observe_step``,
+    ``observe_empty``, ``observe_wait``, ``loop_started`` /
+    ``loop_stopped``). Readers: scrape threads (``cumulative``,
+    ``loop``, ``report``, ``prom_snapshot``), the debug endpoint
+    (``arm_capture``, ``to_chrome_trace``). One lock guards all mutable
+    state, so a scrape reads totals of whole iterations.
     """
 
-    def __init__(
-        self,
-        enabled: bool = True,
-        window: int = 128,
-        capture_capacity: int = 256,
-        host_bound_threshold: float = 0.5,
-        min_steps: int = 8,
-    ):
+    def __init__(self, enabled: bool = True, capture_capacity: int = 256):
         self.enabled = enabled
-        self.window_size = max(1, window)
         self.capture_capacity = max(1, capture_capacity)
-        self.host_bound_threshold = host_bound_threshold
-        self.min_steps = max(1, min_steps)
         self._lock = threading.Lock()
         self._hists: Dict[Tuple[str, str], _PhaseHist] = {}  # guarded-by: _lock
-        self._window: deque = deque(maxlen=self.window_size)  # guarded-by: _lock
         self.steps_total = 0  # guarded-by: _lock
         self._capture_left = 0  # guarded-by: _lock
         self._captures: deque = deque(maxlen=self.capture_capacity)  # guarded-by: _lock
         self.captures_total = 0  # guarded-by: _lock
+        # the loop thread's conserved account (``loop``): seconds and
+        # iterations by what the iteration was, monotone since start
+        self._loop: Dict[str, float] = {  # guarded-by: _lock
+            "working_total_s": 0.0, "working_iterations_total": 0,
+            "empty_total_s": 0.0, "empty_iterations_total": 0,
+            "cpu_total_s": 0.0, "cpu_wall_total_s": 0.0,
+        }
+        # the perf_counter stamp up to which ``wall_total_s`` has been
+        # counted: None while no loop of the scheduler's own runs (a
+        # fleet's loop or a test drives ``step()``), and then the two
+        # totals that only such a loop can know are absent
+        self._loop_mark: Optional[float] = None  # guarded-by: _lock
 
     # ---------------------------------------------------------- recording
     def observe_step(
@@ -250,31 +254,51 @@ class StepAnatomy:
         t_start: float,
         t_end: float,
         tokens: int = 0,
-        hot: bool = True,
+        children: Sequence[Span] = (),
+        carried_s: float = 0.0,
+        cpu_s: Optional[float] = None,
     ) -> None:
-        """Fold one scheduler iteration into the aggregator. ``spans``
-        are (phase, t0, t1) perf_counter stamps; host-lane spans must be
-        disjoint (the conservation invariant tests assert), device-lane
-        spans mirror host ``block`` time on the other lane and are
-        excluded from the host sum. ``hot=False`` keeps the step out of
-        the rolling bubble/headroom window (histograms and capture
-        still record it): a handled-failure iteration has no execute
-        span but a retry/backoff-inflated wall, and one such sample
-        would pin the bubble ratio near 1 for a whole window."""
+        """Fold one WORKING scheduler iteration into the aggregator.
+        ``spans`` are (phase, t0, t1) perf_counter stamps; host-lane
+        spans must be disjoint (the conservation invariant tests
+        assert), device-lane spans mirror host ``block`` time on the
+        other lane and are excluded from the host sum. ``children``
+        are the dispatch spans' parts (``args`` / ``upload`` /
+        ``call``): they lie inside a host-lane ``dispatch`` span, are
+        recorded as ``dispatch.<part>`` and are in no sum of the lane.
+        The iteration's wall less the union of its host-lane spans is
+        its ``unspanned`` pseudo-phase. ``carried_s``: what the
+        observation BEFORE this one cost (the scheduler times this call
+        and hands the seconds to the next), counted as ``observe`` time
+        of this iteration and on top of its wall, so ``working`` stays
+        the sum of the phases. ``cpu_s``: the thread's CPU seconds
+        between the two stamps, on the iterations the scheduler read
+        them (one in ``CPU_CLOCK_EVERY``): summed beside those
+        iterations' wall, ``cpu_wall_total_s``."""
         if not self.enabled:
             return
         wall = max(0.0, t_end - t_start)
         per_phase: Dict[str, float] = {}
-        host = execute = dispatch = 0.0
+        host: List[Tuple[float, float]] = []
         for name, s0, s1 in spans:
-            d = max(0.0, s1 - s0)
-            per_phase[name] = per_phase.get(name, 0.0) + d
-            if name in DEVICE_PHASES:
-                execute += d
-            else:
-                host += d
-            if name == "dispatch":
-                dispatch += d
+            per_phase[name] = per_phase.get(name, 0.0) + max(0.0, s1 - s0)
+            if name not in DEVICE_PHASES:
+                host.append((s0, s1))
+        for name, s0, s1 in children:
+            name = "dispatch." + name
+            per_phase[name] = per_phase.get(name, 0.0) + max(0.0, s1 - s0)
+        # the union, not the sum: a span that overlapped another would
+        # otherwise hide as much unspanned time as it double-counts
+        host.sort()
+        covered, edge = 0.0, t_start
+        for s0, s1 in host:
+            s0, s1 = max(s0, edge), min(s1, t_end)
+            if s1 > s0:
+                covered += s1 - s0
+                edge = s1
+        per_phase[UNSPANNED] = max(0.0, wall - covered)
+        if carried_s > 0.0:
+            per_phase["observe"] = per_phase.get("observe", 0.0) + carried_s
         with self._lock:
             self.steps_total += 1
             for name, d in per_phase.items():
@@ -282,10 +306,13 @@ class StepAnatomy:
                 if h is None:
                     h = self._hists[(kind, name)] = _PhaseHist()
                 h.observe(d)
-            if hot and kind in HOT_KINDS:
-                self._window.append(
-                    _WindowSample(kind, wall, execute, dispatch, host, tokens)
-                )
+            acct = self._loop
+            acct["working_total_s"] += wall + carried_s
+            acct["working_iterations_total"] += 1
+            if cpu_s is not None:
+                acct["cpu_total_s"] += cpu_s
+                acct["cpu_wall_total_s"] += wall
+            self._mark_locked(t_end)
             if self._capture_left > 0:
                 self._capture_left -= 1
                 self.captures_total += 1
@@ -295,7 +322,51 @@ class StepAnatomy:
                     "t_end": t_end,
                     "tokens": int(tokens),
                     "spans": [(n, float(s0), float(s1)) for n, s0, s1 in spans],
+                    "children": [(n, float(s0), float(s1)) for n, s0, s1 in children],
                 })
+
+    def observe_empty(self, t_start: float, t_end: float) -> None:
+        """An iteration in which ``step()`` found nothing to do."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._loop["empty_total_s"] += max(0.0, t_end - t_start)
+            self._loop["empty_iterations_total"] += 1
+            self._mark_locked(t_end)
+
+    def loop_started(self, t: float) -> None:
+        """The scheduler's own loop thread starts at ``t``: from here
+        its wall is counted, up to the end of each iteration and wait."""
+        if not self.enabled:
+            return
+        with self._lock:
+            self._loop.setdefault("wall_total_s", 0.0)
+            self._loop.setdefault("idle_wait_total_s", 0.0)
+            self._loop_mark = t
+
+    def observe_wait(self, t_start: float, t_end: float) -> None:
+        """The loop thread was parked for work from ``t_start`` to
+        ``t_end`` (no loop of the scheduler's own: not counted)."""
+        if not self.enabled:
+            return
+        with self._lock:
+            if self._loop_mark is not None:
+                self._loop["idle_wait_total_s"] += max(0.0, t_end - t_start)
+                self._mark_locked(t_end)
+
+    def _mark_locked(self, t: float) -> None:
+        """The loop thread reached ``t``: ``wall_total_s`` grows by the
+        seconds since the mark before. What lies between an iteration's
+        end and the next one's start (the loop's own overhead, the
+        observation of the one before) is in the wall and in no other
+        total but ``working``'s ``carried_s``."""
+        if self._loop_mark is not None:
+            self._loop["wall_total_s"] += max(0.0, t - self._loop_mark)
+            self._loop_mark = t
+
+    def loop_stopped(self) -> None:
+        with self._lock:
+            self._loop_mark = None
 
     # ------------------------------------------------------------ capture
     def arm_capture(self, k: int) -> int:
@@ -342,7 +413,8 @@ class StepAnatomy:
             return {"traceEvents": events, "displayTimeUnit": "ms"}
         t0 = captures[0]["t_start"]
         for i, cap in enumerate(captures):
-            for span, s0, s1 in cap["spans"]:
+            children = [("dispatch." + n, s0, s1) for n, s0, s1 in cap["children"]]
+            for span, s0, s1 in cap["spans"] + children:  # a child nests in its parent on the host lane
                 events.append({
                     "name": span,
                     "ph": "X",
@@ -359,77 +431,6 @@ class StepAnatomy:
                 "args": {"step": i, "tokens": cap["tokens"]},
             })
         return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    # ---------------------------------------------------------- analysis
-    def _window_sums_locked(self) -> Tuple[int, float, float, float, int]:
-        """(n, wall, execute, projected, tokens) over the rolling
-        window in ONE pass — the shared input for the bubble,
-        classification, and headroom reads, so a scrape sums in-lock
-        instead of copying the window once per gauge."""
-        n = wall = execute = projected = tokens = 0
-        for s in self._window:
-            n += 1
-            wall += s.wall
-            execute += s.execute
-            projected += max(s.execute, s.dispatch)
-            tokens += s.tokens
-        return n, wall, execute, projected, tokens
-
-    def device_bubble_ratio(self) -> Optional[float]:
-        """Fraction of hot-path step wall time the device sat idle
-        while the host worked: 1 - execute/wall over the rolling
-        window. None before any token-emitting step."""
-        with self._lock:
-            _, wall, execute, _, _ = self._window_sums_locked()
-        if wall <= 0.0:
-            return None
-        return max(0.0, min(1.0, 1.0 - execute / wall))
-
-    def classification(self) -> str:
-        """"host_bound" / "device_bound" over the rolling window, or
-        "unknown" before ``min_steps`` hot-path steps accumulated."""
-        with self._lock:
-            n, wall, execute, _, _ = self._window_sums_locked()
-        if n < self.min_steps or wall <= 0.0:
-            return "unknown"
-        bubble = max(0.0, min(1.0, 1.0 - execute / wall))
-        return "host_bound" if bubble >= self.host_bound_threshold else "device_bound"
-
-    def overlap_headroom(self) -> Dict:
-        """Amdahl-style projection over the rolling window: tokens/s if
-        every host phase were hidden behind device execution. Per step
-        the projected wall is max(execute, dispatch) — dispatch is the
-        serial residue that must still issue the program even in a
-        fully pipelined loop. ``projected_speedup`` is the go/no-go
-        number for ROADMAP item 4 (and its gate once overlap lands);
-        ``host_s_per_hot_step`` (hidden host seconds / steps) is the
-        UNCLAMPED form of the bubble ratio, which saturates at 1.0 on a
-        host-bound loop; its readers are this report's ``headroom`` block
-        and tests/test_steptrace.py, no benchmark metric."""
-        with self._lock:
-            n, wall, execute, projected, tokens = self._window_sums_locked()
-        if wall <= 0.0 or n == 0:
-            return {
-                "steps": n, "tokens": tokens,
-                "measured_tokens_per_s": None,
-                "projected_tokens_per_s": None,
-                "projected_speedup": None,
-                "hidden_host_s": None,
-                "host_s_per_hot_step": None,
-            }
-        # a fully host-bound window (execute ~ 0) still pays dispatch;
-        # floor keeps the projection finite instead of infinite
-        projected = max(projected, 1e-9)
-        hidden = max(0.0, wall - projected)
-        return {
-            "steps": n,
-            "tokens": tokens,
-            "measured_tokens_per_s": tokens / wall,
-            "projected_tokens_per_s": tokens / projected,
-            "projected_speedup": wall / projected,
-            "hidden_host_s": hidden,
-            "host_s_per_hot_step": hidden / n,
-        }
 
     # ---------------------------------------------------------- reporting
     def phases_summary(self) -> Dict[str, Dict[str, Dict]]:
@@ -458,16 +459,26 @@ class StepAnatomy:
                 for (kind, name), h in sorted(self._hists.items())
             }
 
+    def loop(self) -> Dict[str, float]:
+        """The ``loop`` section of ``/v2/stats``: the scheduler
+        thread's monotone totals. ``working_total_s`` is the sum of the
+        host-lane phases and ``unspanned`` of ``step_phases``;
+        ``wall_total_s`` (the loop thread's clock since it started) is
+        ``working + empty + idle_wait`` plus the loop's own overhead;
+        ``cpu_total_s`` is the thread's CPU clock over the sampled
+        working iterations, whose wall is ``cpu_wall_total_s``. Where
+        something else drives ``step()``,
+        ``wall_total_s`` and ``idle_wait_total_s`` are absent."""
+        with self._lock:
+            return dict(self._loop)
+
     def report(self) -> Dict:
         """The ``GET /v2/debug/anatomy`` payload for one unit."""
         return {
             "enabled": self.enabled,
             "steps_observed": self.steps_observed(),
-            "window_size": self.window_size,
             "phases": self.phases_summary(),
-            "device_bubble_ratio": self.device_bubble_ratio(),
-            "classification": self.classification(),
-            "headroom": self.overlap_headroom(),
+            "loop": self.loop(),
             "capture": self.capture_state(),
         }
 
@@ -491,28 +502,15 @@ class StepAnatomy:
         ]
 
     def register_gauges(self, stats) -> None:
-        """Surface the window-derived signals as ServingStats gauges
-        (``flexflow_serving_step_*`` on /metrics). A gauge returning
-        None is skipped by the exposition — a disabled or not-yet-warm
-        anatomy emits nothing rather than zeros that look like data.
-        The cumulative phase sums join ``/v2/stats`` as ``step_phases``."""
+        """Surface the aggregator on a ServingStats: the cumulative
+        phase sums join ``/v2/stats`` as ``step_phases`` (not for a
+        disabled anatomy; the scheduler adds ``loop`` beside it, with
+        the engine's dispatch clocks), the number of observations as a
+        gauge on /metrics. A gauge returning None is skipped by the
+        exposition — a disabled anatomy emits nothing rather than zeros
+        that look like data."""
         if self.enabled:
             stats.add_section("step_phases", self.cumulative)
-        stats.add_gauge("step_device_bubble_ratio", self.device_bubble_ratio)
-        stats.add_gauge(
-            "step_host_bound",
-            lambda: {"host_bound": 1.0, "device_bound": 0.0}.get(
-                self.classification()
-            ),
-        )
-        stats.add_gauge(
-            "step_overlap_projected_tokens_per_s",
-            lambda: self.overlap_headroom()["projected_tokens_per_s"],
-        )
-        stats.add_gauge(
-            "step_overlap_projected_speedup",
-            lambda: self.overlap_headroom()["projected_speedup"],
-        )
         stats.add_gauge(
             "step_anatomy_steps_observed",
             lambda: self.steps_observed() if self.enabled else None,

@@ -52,6 +52,23 @@ def _with_pipeline_section(make):
             "drains_total": {"nonsteady": 5 * k, "finish": 5 * k, "pressure": 0, "idle": 0},
         }
         ctx["stats_open"]["pipeline"], ctx["stats_close"]["pipeline"] = section(1), section(2)
+        # ... and with what PR 37 counts: the scheduler thread's account
+        # (``loop``) and the new keys of ``step_phases``, which the
+        # benchmark's own hook gives as they were before it
+        loop = lambda k: {  # noqa: E731
+            "wall_total_s": 10.0 * k, "working_total_s": 7.0 * k, "working_iterations_total": 100 * k,
+            "empty_total_s": 0.5 * k, "empty_iterations_total": 40 * k, "idle_wait_total_s": 2.4 * k,
+            "cpu_total_s": 0.3 * k, "cpu_wall_total_s": 0.4 * k, "decode_dispatch_wall_total_s": 0.04 * k,
+            "decode_dispatch_cpu_total_s": 0.03 * k,
+        }
+        for k, snap in ((1, ctx["stats_open"]), (2, ctx["stats_close"])):
+            snap["loop"] = loop(k)
+            snap.setdefault("step_phases", {}).update({
+                "decode.dispatch.upload": {"count": 100 * k, "total_s": 0.15 * k},
+                "decode.dispatch.call": {"count": 100 * k, "total_s": 0.2 * k},
+                "decode.observe": {"count": 100 * k, "total_s": 0.01 * k},
+                "decode.unspanned": {"count": 100 * k, "total_s": 0.05 * k},
+            })
     return ctx
 
 
@@ -66,11 +83,15 @@ def _as_pr_30_left_it(bench):
     return dict(bench, per_layer=_through(bench["per_layer"], "pipelined_step_share.served"))
 
 
-def _as_it_was_after(cell: str, config: str):
+def _as_it_was_after(cell: str, config: str, metric: str = ""):
     """``BENCHMARK.json`` cut after ``cell`` and ``config`` (the last a PR
     added), the later cells' names taken off every ``workloads`` list,
-    and an entry that lists later cells alone left out."""
+    and an entry that lists later cells alone left out; ``per_layer`` cut
+    after ``metric`` first, where a later PR appended metrics that list
+    this PR's cell."""
     def view(bench):
+        if metric:
+            bench = dict(bench, per_layer=_through(bench["per_layer"], metric))
         cells = _through(bench["workloads"], cell)
         had = {w["name"] for w in cells}
 
@@ -86,7 +107,13 @@ def _as_it_was_after(cell: str, config: str):
 
 
 _as_pr_27_left_it = _as_it_was_after("lfm2-8b-a1b.gen-batch", "lfm2-8b-a1b")
-_as_pr_31_left_it = _as_it_was_after("mellum2-12b.code-gen", "mellum2-12b")
+_as_pr_31_left_it = _as_it_was_after("mellum2-12b.code-gen", "mellum2-12b", "window_cache_saving.served")
+
+
+def _as_pr_34_left_it(bench):
+    """``per_layer`` cut after the entry that PR 34 appended last (it
+    pins the SET of metrics that list its cell; PR 37 appended ten)."""
+    return dict(bench, per_layer=_through(bench["per_layer"], "latent_cache_share.served"))
 
 
 def _seeing(item, view):
@@ -110,11 +137,12 @@ def _seeing(item, view):
 # moves: each sees the file cut where its own PR left it, whatever was
 # appended since (the files there are the benchmark's, and no PR but a
 # `benchmark` one edits them: for the next `benchmark` issue, unpin the
-# three tail assertions and take this out)
+# tail assertions and take this out)
 _PINNED_TAILS = {
     ("test_pipelined_step_share.py", "test_benchmark_json_asks_for_it_in_the_three_serving_cells"): _as_pr_30_left_it,
     ("test_lfm2_cell.py", "test_every_new_metric_lists_the_cell_and_is_read_there"): _as_pr_27_left_it,
     ("test_mellum2_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_metrics_that_list_it"): _as_pr_31_left_it,
+    ("test_joyai_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_three_metrics_that_list_it"): _as_pr_34_left_it,
 }
 
 

@@ -266,10 +266,6 @@ def _golden_stats():
     s.add_gauge("degrade_level", lambda: 2)
     s.add_gauge("degrade_transitions_total", lambda: 3)
     # ISSUE 12 step-anatomy families (binary-exact values)
-    s.add_gauge("step_device_bubble_ratio", lambda: 0.75)
-    s.add_gauge("step_host_bound", lambda: 1)
-    s.add_gauge("step_overlap_projected_tokens_per_s", lambda: 256)
-    s.add_gauge("step_overlap_projected_speedup", lambda: 2)
     s.add_gauge("step_anatomy_steps_observed", lambda: 7)
     # ISSUE 16 disaggregated-serving KV import counters (binary-exact)
     s.add_gauge("kv_imports", lambda: 2)

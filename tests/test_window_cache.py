@@ -178,6 +178,23 @@ def test_the_release_runs_inside_the_pipelined_step(weights, wanted):
     assert section["window"]["blocks_total"] == eng.window_allocator.num_total and "live_bytes" in section
 
 
+def test_the_release_span_has_a_counter_beside_it(weights, wanted):
+    """``ff.cache.window_release`` opens once a decode step: the ``cache``
+    section counts the spans and their seconds, monotone."""
+    prompts, want = wanted
+    eng = make_engine(weights)
+    sched = ContinuousBatchingScheduler(eng, overlap=True)
+    assert eng.cache_stats()["window_releases_total"] == 0 and eng.cache_stats()["window_release_total_s"] == 0.0
+    handles = [sched.submit(p, SamplingParams(max_new_tokens=24)) for p in prompts]
+    seen = []
+    while not all(h.done() for h in handles):
+        sched.step()
+        section = sched.stats.snapshot()["cache"]
+        seen.append((section["window_releases_total"], section["window_release_total_s"]))
+    assert [h.result(timeout=0) for h in handles] == want
+    assert seen == sorted(seen) and seen[-1][0] == eng.step_counts["decode"] and seen[-1][1] > 0
+
+
 def test_the_gauges_report_the_fuller_pool(weights):
     eng = make_engine(weights)
     eng.window_allocator.allocate(10)

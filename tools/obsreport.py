@@ -55,14 +55,16 @@ Against a live server (serving/server.py):
   python tools/obsreport.py --url ... anatomy [--capture K]
       [--anatomy-out anatomy.json]
       Step-anatomy view (GET /v2/debug/anatomy): per-kind phase
-      breakdown (p50/mean per schedule/admit/prefix_plan/draft/sample/
-      dispatch/block/readback/bookkeep span), the device-bubble ratio
-      with host/device-bound classification, and the overlap-headroom
-      projection (tokens/s if host phases were hidden behind device
-      work) — the "is decode host-bound, and what would overlap buy?"
-      answer. --capture K arms a K-step two-lane capture (scrape again
-      once the engine has stepped); --anatomy-out dumps the captured
-      chrome://tracing timeline.
+      breakdown (p50/mean per schedule/admit/prefix_plan/draft/stage/
+      dispatch and its parts/post/block/readback/bookkeep/release/observe span)
+      and, from /v2/stats, the conserved account of the scheduler
+      thread's seconds: wall = working + empty + idle wait + the loop's
+      own, working = host-lane phases + unspanned, the decode dispatch's
+      wall against its CPU seconds — the "is this server host-bound, and
+      where?" answer, exact under the overlap pipeline. --capture K arms
+      a K-step two-lane capture (scrape again once the engine has
+      stepped); --anatomy-out dumps the captured chrome://tracing
+      timeline.
 
 CI self-check (no server needed; used by .github/workflows/tpu-ci.yml):
 
@@ -354,39 +356,87 @@ def export_predictions(base: str, out: str) -> int:
     return 0
 
 
+def thread_account(phases: dict, loop: dict) -> dict:
+    """The conserved account of one scheduler thread, from the
+    ``step_phases`` and ``loop`` sections of one ``/v2/stats`` snapshot:
+    host-lane seconds by phase (summed over the kinds of iteration;
+    ``execute`` is the device's lane and a ``dispatch.<part>`` lies
+    inside its dispatch, so neither is in the sum), the parts of the
+    dispatches, and the two remainders, which conserve when near 0:
+    ``working_gap_s`` (working less phases and unspanned) and
+    ``loop_own_s`` (wall less working, empty and idle wait: the loop's
+    own few lines; None where no loop of the scheduler's own runs)."""
+    lane, parts = {}, {}
+    for key, v in phases.items():
+        name = key.split(".", 1)[1]
+        if name.startswith("dispatch."):
+            parts[name] = parts.get(name, 0.0) + v["total_s"]
+        elif name != "execute":
+            lane[name] = lane.get(name, 0.0) + v["total_s"]
+    out = {"lane": lane, "parts": parts,
+           "working_gap_s": loop["working_total_s"] - sum(lane.values()), "loop_own_s": None}
+    if "wall_total_s" in loop:
+        out["loop_own_s"] = loop["wall_total_s"] - (
+            loop["working_total_s"] + loop["empty_total_s"] + loop["idle_wait_total_s"])
+    return out
+
+
+def _print_thread_account(snap: dict) -> None:
+    loop, acct = snap["loop"], thread_account(snap["step_phases"], snap["loop"])
+    whole = loop.get("wall_total_s") or loop["working_total_s"] or 1.0
+    row = lambda label, s, note="": print(  # noqa: E731
+        f"        {label:<22} {s:10.3f} s {100.0 * s / whole:6.2f}%  {note}")
+    print("    the scheduler thread's seconds (/v2/stats loop + step_phases):")
+    if "wall_total_s" in loop:
+        row("wall", loop["wall_total_s"])
+    row("working", loop["working_total_s"],
+        f"{loop['working_iterations_total']} iteration(s); sampled ones: CPU {loop['cpu_total_s']:.3f} s "
+        f"of {loop.get('cpu_wall_total_s', 0.0):.3f} s wall")
+    for name, s in sorted(acct["lane"].items(), key=lambda kv: -kv[1]):
+        row("  " + name, s)
+        if name == "dispatch":
+            for part, ps in sorted(acct["parts"].items()):
+                row("    " + part.split(".", 1)[1], ps)
+    row("empty", loop["empty_total_s"], f"{loop['empty_iterations_total']} iteration(s)")
+    if acct["loop_own_s"] is not None:
+        row("idle wait", loop["idle_wait_total_s"])
+        row("the loop's own", acct["loop_own_s"], "wall - working - empty - idle wait")
+    wall, cpu = loop.get("decode_dispatch_wall_total_s", 0.0), loop.get("decode_dispatch_cpu_total_s", 0.0)
+    if wall > 0:
+        print(f"        decode dispatch (sampled): wall {wall:.3f} s, CPU {cpu:.3f} s, "
+              f"off the CPU {wall - cpu:.3f} s ({100.0 * (wall - cpu) / wall:.1f}% of it)")
+    gap = abs(acct["working_gap_s"]) / max(loop["working_total_s"], 1e-12)
+    own = None if acct["loop_own_s"] is None else acct["loop_own_s"] / max(loop["wall_total_s"], 1e-12)
+    ok = gap <= 0.01 and (own is None or -1e-9 <= own <= 0.01)
+    print(f"        conserved: {'yes' if ok else 'NO'} (working vs phases + unspanned off by {gap:.3%}"
+          + ("" if own is None else f", the loop's own {own:.3%} of wall") + ")")
+
+
 def show_anatomy(base: str, capture=None, out: str = "") -> int:
-    """Phase breakdown + bubble/headroom per generation unit."""
+    """Phase breakdown + the conserved thread account per generation unit."""
     url = f"{base}/v2/debug/anatomy"
     if capture:
         url += f"?capture={int(capture)}"
     payload = _get_json(url)
+    stats = _get_json(f"{base}/v2/stats").get("generation", {})
     for name, unit in sorted(payload.get("models", {}).items()):
         rep = unit["report"]
         if not rep.get("enabled", False):
             print(f"model {name!r}: anatomy disabled (observability off)")
             continue
-        print(f"model {name!r}: {rep['steps_observed']} step(s) observed, "
-              f"classification={rep['classification']}")
+        print(f"model {name!r}: {rep['steps_observed']} step(s) observed")
         if unit.get("armed") is not None:
             print(f"    armed a {unit['armed']}-step capture "
                   f"(scrape again after the engine steps)")
-        bubble = rep.get("device_bubble_ratio")
-        if bubble is not None:
-            print(f"    device_bubble_ratio={bubble:.1%} "
-                  f"(device idle while the host works, rolling window)")
         for kind, phases in sorted(rep.get("phases", {}).items()):
             print(f"    {kind}:")
-            print("        phase         count     mean        p50")
+            print("        phase            count     mean        p50")
             for phase, p in sorted(phases.items()):
-                print(f"        {phase:<12} {p['count']:<7} "
+                print(f"        {phase:<15} {p['count']:<7} "
                       f"{p['mean_s'] * 1e3:8.3f}ms {p['p50_s'] * 1e3:8.3f}ms")
-        hr = rep.get("headroom", {})
-        if hr.get("measured_tokens_per_s") is not None:
-            print(f"    overlap headroom ({hr['steps']} hot step(s)): "
-                  f"{hr['measured_tokens_per_s']:.1f} -> "
-                  f"{hr['projected_tokens_per_s']:.1f} tok/s "
-                  f"({hr['projected_speedup']:.2f}x) if host phases were "
-                  f"hidden behind device work")
+        snap = stats.get(name) or {}
+        if snap.get("loop") and snap.get("step_phases"):
+            _print_thread_account(snap)
         cap = rep.get("capture", {})
         print(f"    capture: {cap.get('captured', 0)} step(s) retained, "
               f"{cap.get('remaining', 0)} armed")
@@ -905,10 +955,9 @@ def selfcheck() -> int:
 
         # -------------------- step anatomy: report + forced capture
         # (ISSUE 12) the profiler must have folded the healthy steps
-        # above into a non-empty report with a finite bubble ratio, and
-        # an armed capture must retain real two-lane spans
-        import math as _math
-
+        # above into a non-empty report whose account of the scheduler
+        # thread conserves (ISSUE 37), and an armed capture must retain
+        # real two-lane spans
         anat = _get_json(f"{base}/v2/debug/anatomy?capture=6")
         check(anat["models"]["lm"].get("armed") == 6,
               f"anatomy capture did not arm: {anat['models']['lm'].get('armed')}")
@@ -919,14 +968,15 @@ def selfcheck() -> int:
         rep = anat["report"]
         check(rep["steps_observed"] >= 3,
               f"anatomy observed too few steps: {rep['steps_observed']}")
-        bubble = rep.get("device_bubble_ratio")
-        check(bubble is not None and _math.isfinite(bubble) and 0.0 <= bubble <= 1.0,
-              f"device_bubble_ratio not finite in [0,1]: {bubble}")
-        hr = rep.get("headroom", {})
-        check(hr.get("projected_tokens_per_s") is not None
-              and hr.get("projected_speedup") is not None
-              and _math.isfinite(hr["projected_speedup"]),
-              f"overlap-headroom projection missing: {hr}")
+        snap = _get_json(f"{base}/v2/stats")["generation"]["lm"]
+        acct = thread_account(snap["step_phases"], snap["loop"])
+        loop = snap["loop"]
+        check(abs(acct["working_gap_s"]) <= 0.01 * loop["working_total_s"],
+              f"working != host-lane phases + unspanned: off by {acct['working_gap_s']} s of {loop['working_total_s']}")
+        check(acct["loop_own_s"] is not None and -1e-9 <= acct["loop_own_s"] <= 0.01 * loop["wall_total_s"],
+              f"loop wall not conserved: the loop's own {acct['loop_own_s']} s of {loop.get('wall_total_s')}")
+        check(loop["decode_dispatch_wall_total_s"] > 0 and acct["parts"].get("dispatch.call", 0) > 0,
+              f"the decode dispatch was not split: {loop} {acct['parts']}")
         decode_phases = rep.get("phases", {}).get("decode", {})
         for phase in ("dispatch", "execute", "readback", "bookkeep"):
             check(decode_phases.get(phase, {}).get("count", 0) >= 1,
@@ -1235,8 +1285,8 @@ def selfcheck() -> int:
           "retrace produced a correct blame string, SLO + readiness "
           "rationale live, truth ledger joined prefill/decode/verify + an "
           "executor program, a scaled calibration entry tripped the "
-          "drift alarm with correct blame, the step-anatomy profiler "
-          "reported a finite bubble ratio + overlap headroom with a "
+          "drift alarm with correct blame, the step-anatomy profiler's "
+          "account of the scheduler thread conserved (to 1%) with a "
           "successful forced two-lane capture, an abandoned durable "
           "journal warm-restarted with a non-empty replay report, and "
           "request journeys joined the client traceparent, stitched "
@@ -1256,8 +1306,8 @@ def main() -> int:
                     help="view: summary (default), cache (block "
                          "residency), slo (burn rates), predict "
                          "(cost-model truth: error table + drift alarms), "
-                         "anatomy (step phases, device bubble, overlap "
-                         "headroom), overload (limiter state, ladder "
+                         "anatomy (step phases, the scheduler thread's "
+                         "conserved account), overload (limiter state, ladder "
                          "history, shed table, autoscale signal), disagg "
                          "(pool states, KV handoff outcomes + latency, "
                          "in-flight transfers), constrained (grammar-cache "
