@@ -5,6 +5,7 @@
     python chip_smoke.py --four-chips    # one four-chip host: the sharded legs
     python chip_smoke.py --latent-kernel     # the latent paged kernel alone
     python chip_smoke.py --expert-product    # the routed experts' sum alone: dense against grouped
+    python chip_smoke.py --release-probe     # what the drop of a consumed step's device arrays waits for
 
 ONE process. It refuses to start unless JAX's first device is a TPU, and
 any failed check raises: the exit code is non-zero and no result line is
@@ -451,6 +452,281 @@ def expert_product_check() -> dict:
             line["left"] = round(ms["dense"] - min(ms.values()), 4)  # ms a layer the rule leaves where it keeps the dense form
             out[f"{name}[{rows}]"] = line
             log(f"expert product {name} rows {rows}: {line}")
+    return out
+
+
+# The drop of a consumed decode step's device arrays (PERF.md §6, PR 37 and
+# PR 38): a stand-in step program of about a decode step's device time, its
+# small results dropped where the scheduler drops them, and beside them what
+# a serving process adds: one thread a stream, woken by the step's tokens.
+RELEASE_PROBE = dict(width=4096, rows=2048, step_ms=20.0, reps=40, streams=64, stream_ms=0.17)
+
+
+def release_probe(width=None, rows=None, step_ms=None, reps=None, streams=None, stream_ms=None) -> dict:
+    """What the scheduler thread waits for when it drops a consumed
+    step's ``InFlightDecode``. A step program of ``step_ms`` of device
+    time (matrix products in a loop; a donated ``cache``; results
+    ``out`` int32 and ``ok`` bool of one element a row, ``out`` carried
+    into the next call as the engine carries its tokens) runs back to
+    back, and the drop of a FINISHED step's ``out`` and ``ok`` (host
+    copies started at dispatch and read, as ``consume_decode`` does) is
+    timed, ms a drop over ``reps`` drops:
+
+    * ``a_idle``: nothing in flight;
+    * ``b_in_flight``: its successor, which reads ``out``, in flight, on
+      the dispatching thread; ``b_held_longer``: the successor's
+      successor in flight, which does not read it (PR 37's experiment);
+    * ``c_second_thread``: as (b), the arrays handed to a second thread
+      through a ``SimpleQueue`` while the first spins in Python:
+      the second thread's drop, ``c_first_thread_stall`` the first's
+      longest stall between two clock reads, ``c_first_thread_put`` its
+      hand-over;
+    * ``d_donated``: the reference to the ``cache`` that was donated to
+      the program in flight.
+
+    And with ``streams`` threads parked each on a ``queue.Queue`` of its
+    own, as the server's stream handlers are, each of which does
+    ``stream_ms`` of Python a token: every thread is given a token (the
+    bookkeeping's ``_emit``) and then the same drop is timed,
+    ``e_streams_in_flight`` beside a program in flight and
+    ``f_streams_idle`` beside none; ``g_streams_sleep0`` times
+    ``time.sleep(0)`` there instead of a drop (the interpreter's lock
+    given up and taken back, no device array in sight), ``h_streams_numpy``
+    the drop of two numpy arrays (the lock is kept), and
+    ``i_streams_spin`` how long the dropping thread runs on in pure
+    Python, holding its references, before the streams' threads have
+    all had their turn (the interpreter's switch interval at work).
+
+    Last, threads that do for a token what the server's stream handler
+    does (``serving/server.py``: a ``get`` with a timeout on the
+    handle's queue, the event's JSON and text, its bytes to a socket)
+    and the same drop after a token to each, nothing in flight:
+    ``j_handlers_queue`` on a ``queue.Queue`` with the event's ``id:``
+    and ``data:`` lines in a ``sendall`` each (the server up to PR 37),
+    ``k_handlers_simple_queue`` the same on a ``queue.SimpleQueue``
+    (since PR 38), ``l_handlers_simple_queue_one_write`` with one
+    ``sendall`` an event besides (not taken: it read no gain in the
+    cell): what a token costs the thread that gives up the lock is its
+    handlers' turns at it."""
+    import queue
+    import statistics
+    import threading
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    c = dict(RELEASE_PROBE)
+    c.update({k: v for k, v in dict(width=width, rows=rows, step_ms=step_ms, reps=reps, streams=streams,
+                                    stream_ms=stream_ms).items() if v is not None})
+    n_rows, wd = c["rows"], c["width"]
+    w = (jax.random.normal(jax.random.key(SEED), (wd, wd), jnp.float32) * wd ** -0.5).astype(jnp.bfloat16)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def step(w, cache, tok, n):
+        x = jnp.broadcast_to(((tok % 13).astype(jnp.bfloat16) * 0.1)[:, None], (n_rows, wd))
+        x = jax.lax.fori_loop(0, n, lambda _, x: jnp.tanh(jnp.dot(x, w, preferred_element_type=jnp.float32)).astype(jnp.bfloat16), x)
+        out = jnp.argmax(x, axis=-1).astype(jnp.int32)
+        return out, jnp.isfinite(x.astype(jnp.float32)).all(axis=-1), cache.at[0].add(1)
+
+    cache = jnp.zeros((256, 1024, 128), jnp.bfloat16)  # 64 MiB, donated and aliased call after call
+    tok0 = jnp.zeros((n_rows,), jnp.int32)
+
+    def dispatch(tok, n):
+        nonlocal cache
+        old = cache
+        out, ok, cache = step(w, cache, tok, n)
+        out.copy_to_host_async()
+        ok.copy_to_host_async()
+        return [out, ok], old
+
+    def consume(h):
+        jax.block_until_ready(h)
+        np.asarray(h[1]), np.asarray(h[0])
+
+    # the loop count that gives step_ms of device time
+    n = jnp.int32(16)
+    consume(dispatch(tok0, n)[0])
+    t0 = time.perf_counter()
+    consume(dispatch(tok0, n)[0])
+    n = jnp.int32(max(1, round(16 * c["step_ms"] / ((time.perf_counter() - t0) * 1e3))))
+    t0 = time.perf_counter()
+    for _ in range(5):
+        consume(dispatch(tok0, n)[0])
+    program_ms = (time.perf_counter() - t0) / 5 * 1e3
+
+    def timed_drop(h):
+        t0 = time.perf_counter()
+        h.clear()
+        return (time.perf_counter() - t0) * 1e3
+
+    times = {}
+
+    def note(name, ms):
+        times.setdefault(name, []).append(ms)
+
+    # ---- the streams' threads: parked on a queue each, woken with a token each
+    body = {"token": 12345, "text": "x" * 24, "index": 7}
+    t0 = time.perf_counter()
+    for _ in range(2000):
+        json.dumps(body)
+    per_token = max(1, round(c["stream_ms"] * 1e-3 / ((time.perf_counter() - t0) / 2000)))
+
+    def streams(queue_kind=queue.Queue, writes=0):
+        """``streams`` threads, each parked on a queue of ``queue_kind``;
+        for a token one does ``stream_ms`` of Python (``writes`` 0) or what
+        the server's handler does, the event's lines in ``writes`` calls
+        of ``sendall``. Returns (a token to each, wait for every turn, stop)."""
+        import socket
+
+        boxes, done, socks = [queue_kind() for _ in range(c["streams"])], threading.Semaphore(0), []
+
+        def serve(box, sock):
+            count = 0
+            while (tok := box.get(timeout=600)) is not None:
+                if writes == 0:
+                    for _ in range(per_token):
+                        json.dumps(body)
+                else:
+                    lines = [f"id: {count}\n", f"data: {json.dumps({'token': int(tok), 'index': count})}\n\n"]
+                    for part in (lines if writes == 2 else ["".join(lines)]):
+                        sock.sendall(part.encode())
+                count += 1
+                done.release()
+
+        mine = []
+        for box in boxes:
+            a, b = socket.socketpair()  # the far end is never read: a run's events fit its buffer
+            socks += [a, b]
+            mine.append(threading.Thread(target=serve, args=(box, a), daemon=True))
+            mine[-1].start()
+
+        def emit_all():
+            for box in boxes:
+                box.put(12345)
+
+        def wait_all():
+            for _ in boxes:
+                done.acquire()
+
+        def stop():
+            for box in boxes:
+                box.put(None)
+            for t in mine:
+                t.join(5)
+            for sock in socks:
+                sock.close()
+
+        return emit_all, wait_all, stop
+
+    emit, all_had_their_turn, stop_streams = streams()
+
+    reaper_in, reaper_out = queue.SimpleQueue(), queue.SimpleQueue()
+
+    def reaper():
+        while True:
+            h = reaper_in.get()
+            if h is None:
+                return
+            reaper_out.put(timed_drop(h))
+
+    second = threading.Thread(target=reaper, daemon=True)
+    second.start()
+
+    for rep in range(c["reps"]):
+        # (a) nothing in flight
+        h0, _ = dispatch(tok0, n)
+        consume(h0)
+        note("a_idle", timed_drop(h0))
+        # (b) the successor, which reads out, in flight
+        h0, _ = dispatch(tok0, n)
+        h1, _ = dispatch(h0[0], n)
+        consume(h0)
+        note("b_in_flight", timed_drop(h0))
+        # (b') held one step longer: the successor's successor in flight
+        h2, _ = dispatch(h1[0], n)
+        consume(h1)
+        h3, _ = dispatch(h2[0], n)
+        consume(h2)
+        note("b_held_longer", timed_drop(h1))
+        # (c) on a second thread, the first spinning
+        h4, _ = dispatch(h3[0], n)
+        consume(h3)
+        t0 = time.perf_counter()
+        reaper_in.put(h3)
+        del h3
+        last = time.perf_counter()
+        note("c_first_thread_put", (last - t0) * 1e3)
+        stall = 0.0
+        while reaper_out.empty():
+            now = time.perf_counter()
+            stall, last = max(stall, now - last), now
+        note("c_second_thread", reaper_out.get())
+        note("c_first_thread_stall", stall * 1e3)
+        # (d) the donated cache's old reference, its program in flight
+        h5, old = dispatch(h4[0], n)
+        old = [old]
+        note("d_donated", timed_drop(old))
+        consume(h4), consume(h5)
+        del h2, h4
+        # (e) the streams woken, a program in flight
+        h6, _ = dispatch(h5[0], n)
+        h7, _ = dispatch(h6[0], n)
+        consume(h6)
+        emit()
+        note("e_streams_in_flight", timed_drop(h6))
+        all_had_their_turn()
+        consume(h7)
+        # (f) the streams woken, nothing in flight
+        emit()
+        note("f_streams_idle", timed_drop(h7))
+        all_had_their_turn()
+        # (g) the lock given up without a device array, (h) a drop that keeps it
+        emit()
+        t0 = time.perf_counter()
+        time.sleep(0)
+        note("g_streams_sleep0", (time.perf_counter() - t0) * 1e3)
+        all_had_their_turn()
+        arrays = [np.zeros((n_rows,), np.int32), np.zeros((n_rows,), bool)]
+        emit()
+        note("h_streams_numpy", timed_drop(arrays))
+        all_had_their_turn()
+        # (i) pure Python after the tokens went out: until the first stall of over a millisecond
+        emit()
+        t0 = last = time.perf_counter()
+        ran = None
+        while time.perf_counter() - t0 < 0.05:
+            now = time.perf_counter()
+            if ran is None and now - last > 1e-3:
+                ran = (last - t0) * 1e3
+            last = now
+        note("i_streams_spin", 50.0 if ran is None else ran)
+        all_had_their_turn()
+        del h0, h1, h5, h6, h7
+    reaper_in.put(None)
+    second.join(5)
+    stop_streams()
+    # (j, k, l) the server's handlers, one kind awake at a time
+    for name, queue_kind, writes in (("j_handlers_queue", queue.Queue, 2), ("k_handlers_simple_queue", queue.SimpleQueue, 2),
+                                     ("l_handlers_simple_queue_one_write", queue.SimpleQueue, 1)):
+        emit_all, wait_all, stop = streams(queue_kind, writes)
+        for rep in range(c["reps"]):
+            h, _ = dispatch(tok0, n)
+            consume(h)
+            emit_all()
+            note(name, timed_drop(h))
+            wait_all()
+        stop()
+
+    def summary(v):
+        v = sorted(v)
+        return {"median": round(statistics.median(v), 4), "p90": round(v[int(0.9 * (len(v) - 1))], 4),
+                "max": round(v[-1], 4), "n": len(v)}
+
+    out = {"program_ms": round(program_ms, 3), "loop_count": int(n), "streams": c["streams"],
+           "stream_python_ms_a_token": c["stream_ms"], "switch_interval_ms": sys.getswitchinterval() * 1e3,
+           "ms_a_drop": {k: summary(v) for k, v in times.items()}}
+    log(f"release probe: {json.dumps(out)}")
     return out
 
 
@@ -1080,6 +1356,8 @@ def main(argv=None) -> int:
                     help="the latent paged kernel alone, at the latent cell's sizes")
     ap.add_argument("--expert-product", action="store_true",
                     help="the routed experts' sum alone: dense against grouped, one layer of each expert cell")
+    ap.add_argument("--release-probe", action="store_true",
+                    help="the drop of a finished step's device arrays, timed beside a program in flight and beside woken stream threads")
     args = ap.parse_args(argv)
 
     import jax
@@ -1110,6 +1388,11 @@ def main(argv=None) -> int:
         summary["kernels"] = {"latent": latent_kernel_check()}
     elif args.expert_product:
         summary["experts"] = expert_product_check()
+    elif args.release_probe:
+        summary["release_probe"] = release_probe()
+        (REPO / "chiprun_out" / "pr38").mkdir(parents=True, exist_ok=True)
+        (REPO / "chiprun_out" / "pr38" / "release_probe.json").write_text(json.dumps(summary["release_probe"]) + "\n")
+        print(json.dumps(summary["release_probe"]), flush=True)
     else:
         summary["calibration"] = calibration_hit(dev.device_kind)
         log(f"calibration lookup for {dev.device_kind!r}: {summary['calibration']}")
@@ -1130,7 +1413,8 @@ def main(argv=None) -> int:
     out_dir = REPO / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel
-            else "chip_smoke_experts.json" if args.expert_product else "chip_smoke.json")
+            else "chip_smoke_experts.json" if args.expert_product
+            else "chip_smoke_release.json" if args.release_probe else "chip_smoke.json")
     (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

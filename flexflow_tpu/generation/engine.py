@@ -48,7 +48,7 @@ legacy engine.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -623,6 +623,10 @@ class GenerationEngine:
         # scrape reads a pair of the same steps
         self.cpu_stamps = False
         self.decode_dispatch_clock: Tuple[float, float] = (0.0, 0.0)
+        # called with no argument after every dispatch, before the wait
+        # for its result (_dispatched); the scheduler that serves from
+        # this engine sets it
+        self.on_dispatched: Optional[Callable[[], None]] = None
         # serving FLOPs accounting (obs/capacity.py): model-shaped FLOPs
         # per step kind — true prompt lengths and live context only, so
         # MFU = flops / execute seconds / chip peak is padding-honest.
@@ -794,6 +798,18 @@ class GenerationEngine:
         ``call`` (the jit call alone). The parent's self time is its
         seconds less these."""
         return phase(f"engine.{kind}.dispatch.{part}", into=self._children)
+
+    def _dispatched(self) -> None:
+        """A step program is with the device and the NEXT thing this
+        thread does is park for it: tell whoever asked (the scheduler,
+        which wakes the streams' threads here and not while a dispatch
+        is still its to make). The blocking calls only: after
+        ``decode_async`` the caller says itself when it parks
+        (``consume_decode``), and the arguments' device arrays die in
+        between, each destructor giving up the interpreter's lock to
+        whoever is awake."""
+        if self.on_dispatched is not None:
+            self.on_dispatched()
 
     def _count_dispatch(self, disp: phase) -> None:
         """Add a decode dispatch span to ``decode_dispatch_clock``."""
@@ -1325,6 +1341,7 @@ class GenerationEngine:
                 )
             with self._part("prefill", "call"):
                 token, ok, ck, cv, state, counts = self._prefill_jit(self.params, *args)
+        self._dispatched()
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
@@ -1411,6 +1428,7 @@ class GenerationEngine:
                 )
             with self._part("prefill", "call"):
                 token, ok, ck, cv, state, counts = self._prefix_prefill_jit(self.params, *args)
+        self._dispatched()
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
@@ -2210,6 +2228,7 @@ class GenerationEngine:
             with self._part("decode", "call"):
                 out, ok, ck, cv, state, counts = self._decode_jit(self.params, *args)
         self._count_dispatch(disp)
+        self._dispatched()
         with phase("engine.decode.block") as block:
             jax.block_until_ready((out, ok, ck, cv, state))  # device execution done
         with phase("engine.decode.readback") as read:
@@ -2483,6 +2502,7 @@ class GenerationEngine:
                 )
             with self._part("verify", "call"):
                 out, n_emitted, ok, ck, cv = self._verify_jit(self.params, *args)
+        self._dispatched()
         with phase("engine.verify.block") as block:
             jax.block_until_ready((out, n_emitted, ok, ck, cv))  # execution done
         with phase("engine.verify.readback") as read:

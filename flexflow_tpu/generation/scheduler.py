@@ -176,7 +176,16 @@ class GenerationHandle:
     def __init__(self, request: "Request"):
         self._request = request
         self.future: Future = Future()
-        self._tokens: "queue.Queue" = queue.Queue()
+        # a SimpleQueue: its put and its get are C calls, where
+        # queue.Queue's are ~40 lines of Python under two locks, paid
+        # by the scheduler's thread a token and by the stream's reader
+        # with the interpreter's lock held
+        self._tokens: "queue.SimpleQueue" = queue.SimpleQueue()
+        # tokens the scheduler has bookkept and not yet put on the queue
+        # (_emit_later): they go out when its thread next parks for the
+        # device, and before this handle's end or error whoever settles
+        # it, so a reader has them in order and whole
+        self._held: List[int] = []
         # settle arbitration: the loop and watchdog threads race to
         # finish/fail a handle; the claim winner owns BOTH the future
         # and the trace, and closes the trace BEFORE the future settles
@@ -225,7 +234,28 @@ class GenerationHandle:
 
     # -------------------------------------------------------- scheduler
     def _emit(self, token: int) -> None:
+        self._release_held()
         self._tokens.put(token)
+
+    def _emit_later(self, token: int) -> None:
+        """Keep ``token`` for :meth:`_release_held`. A put wakes the
+        stream's reader, and a woken thread takes the interpreter's lock
+        the first time the scheduler's thread gives it up: the scheduler
+        says when that may be. Scheduler thread only."""
+        with self._settle_lock:
+            self._held.append(token)
+
+    def _release_held(self) -> int:
+        """Put the held tokens on the queue; how many there were. Under
+        the settle lock: the scheduler's thread and a settling one (the
+        watchdog failing a deadline) put no token twice or out of order."""
+        if not self._held:
+            return 0
+        with self._settle_lock:
+            held, self._held = self._held, []
+            for token in held:
+                self._tokens.put(token)
+        return len(held)
 
     def _finish(self, tokens: List[int]) -> None:
         # idempotent under races: the watchdog thread may reap a
@@ -237,6 +267,7 @@ class GenerationHandle:
         # trace first: a client thread woken by the settling future may
         # immediately read trace_dict() for its response
         self._request._trace_done("completed", None)
+        self._release_held()
         try:
             self.future.set_result(tokens)
         except Exception:
@@ -253,6 +284,7 @@ class GenerationHandle:
         # shutdown — lands exactly one finished trace in the ring and
         # error responses never embed a half-open trace
         self._request._trace_done(type(err).__name__, err)
+        self._release_held()  # what was bookkept before the failure is the stream's
         try:
             self.future.set_exception(err)
         except Exception:
@@ -809,6 +841,18 @@ class ContinuousBatchingScheduler:
         # stays the documented GIL-atomic tuple swap.
         self.overlap = True if overlap is None else bool(overlap)
         self._pipe: Optional[_Frontier] = None
+        # the handles that hold decode tokens bookkept and not yet on
+        # their streams' queues (GenerationHandle._emit_later). A put
+        # wakes the stream's handler thread, and a woken thread takes
+        # the interpreter's lock the first time this one gives it up
+        # (any jax.Array's destructor does); this thread has it back
+        # only after every woken handler has had its turn (11 ms at 64
+        # streams: PERF.md §6, PR 38). Woken at the bookkeeping, they
+        # take that turn before the next dispatch, with the device
+        # running dry behind it. So a decode step's tokens go out where
+        # this thread next parks for the device (_emit_late).
+        self._late: List[GenerationHandle] = []
+        self.engine.on_dispatched = self._emit_late
         # plain counters (the `pipeline` section of /v2/stats, read by
         # benchmark/layer_metrics/pipelined_step_share.py and by
         # tests/test_overlap.py): dispatches that went through the
@@ -819,6 +863,8 @@ class ContinuousBatchingScheduler:
         self.pipe_reclaims = 0
         self.pipe_drains: Dict[str, int] = dict.fromkeys(_DRAIN_REASONS, 0)
         self.pipe_discards = 0
+        self.emits_deferred = 0
+        self.release_wait_s = 0.0
         self.stats.add_section("pipeline", self.pipeline_stats)
         # self-healing (recovery.py): journal + supervisor + watchdog.
         # _heartbeat is (seq, started_at) while a device call is in
@@ -2093,9 +2139,17 @@ class ContinuousBatchingScheduler:
         )
         return True
 
-    def _emit_token(self, state: _Running, token: int) -> None:
+    def _emit_token(self, state: _Running, token: int, later: bool = False) -> None:
+        """``later``: a decode step's token, one of a batch's, which its
+        stream hears of when this thread next parks for the device
+        (:meth:`_emit_late`); a prefill's first token and a verify
+        window's go out at once."""
         state.req.generated.append(int(token))
-        state.req.handle._emit(int(token))
+        if later:
+            state.req.handle._emit_later(int(token))
+            self._late.append(state.req.handle)
+        else:
+            state.req.handle._emit(int(token))
         # durable serving: the journal mirrors the token delta into its
         # WAL buffer (a no-op on the base journal) — host bookkeeping
         # that the overlap pipeline hides under device execution, like
@@ -2369,7 +2423,7 @@ class ContinuousBatchingScheduler:
             if state.req.finished():
                 continue  # finished at a previous pipeline consume
             state.cached_len += 1
-            self._emit_token(state, int(out[state.slot]))
+            self._emit_token(state, int(out[state.slot]), later=True)
             state.req.trace.note_tokens(1, "decode")
             n_live += 1
             if state.req.finished():
@@ -2419,6 +2473,29 @@ class ContinuousBatchingScheduler:
                 return True
         return False
 
+    def _emit_late(self) -> None:
+        """Put the decode tokens bookkept since the last call on their
+        streams' queues. Called where the next thing this thread does
+        is park for the device: before a frontier is consumed (in steady
+        state right after the dispatch of step N+2, with step N's
+        tokens, and the park is ``block(N+1)``; at a drain or the stream
+        tail without a dispatch before it), by the engine between the
+        dispatch and the wait of a blocking call (``on_dispatched``: a
+        drained or a sequential step's tokens go out behind the
+        admission's prefill or the next step), before a frontier is
+        discarded, and at the end of an iteration that no dispatch is
+        sure to follow. So the handler threads take their turn at the
+        interpreter's lock while the device works, and not between a
+        step's bookkeeping and the next dispatch. A handle that is
+        settled first releases its own tokens (``_finish``, ``_fail``):
+        a stream reads them in order and whole before its end or error.
+        Bookkeeping, in its span."""
+        if not self._late:
+            return
+        late, self._late = self._late, []
+        with self._phase("sched.bookkeep"):
+            self.emits_deferred += sum(h._release_held() for h in late)
+
     def _discard_frontier(self) -> None:
         """Drop the in-flight step WITHOUT bookkeeping: its sampled
         tokens are never emitted, so the next sequential step recomputes
@@ -2426,7 +2503,10 @@ class ContinuousBatchingScheduler:
         rewrites of the same positions from the same inputs). Used when
         the in-flight result is tainted (NaN blame, stall, failure) or
         moot (shutdown, engine reset). Swallows the step's own error —
-        the caller decides how the failure is handled."""
+        the caller decides how the failure is handled. The tokens of
+        the step BEFORE it were bookkept and are owed to their streams:
+        no dispatch may follow, so they go out first."""
+        self._emit_late()
         f, self._pipe = self._pipe, None
         if f is None:
             return
@@ -2470,6 +2550,7 @@ class ContinuousBatchingScheduler:
         when a failure was fully handled here (restart or whole-batch
         blame). Device errors propagate to the caller's
         pipeline-failure handling."""
+        self._emit_late()
         faults.inject(faults.GENERATION_ASYNC_READBACK, ("decode", len(f.states)))
         with phase("sched.device_step") as p_dev:
             out = self.engine.consume_decode(f.handle)
@@ -2518,12 +2599,17 @@ class ContinuousBatchingScheduler:
             n_live, finish = self._scatter_decode(f.states, out, defer_finish=True)
             self.token_rate.record(n_live)
         # the consumed step's handle goes HERE, in a span, and not
-        # wherever the frame that holds the frontier returns: dropping
-        # its device arrays waits for the successor in flight, which
-        # reads them (0.3-1 ms a step in chat-steady, 7 in gen-batch:
-        # PERF.md, PR 37), and no span named that wait
-        with self._phase("sched.release"):
+        # wherever the frame that holds the frontier returns. No free
+        # waits for the device (0.03 ms an array beside a program in
+        # flight or none: chip_smoke.py --release-probe), but every
+        # jax.Array's destructor gives up the interpreter's lock, and
+        # this thread has it back only after the threads that were
+        # waiting for it have had their turn. The span reads that turn:
+        # the streams' handlers are woken elsewhere (_emit_late), so it
+        # is short unless they have not finished the one before
+        with self._phase("sched.release") as p_rel:
             f.handle = None
+        self.release_wait_s += p_rel.seconds
         if finish:
             # finish/EOS is a non-steady event: the successor step may
             # still be writing into the finishing streams' blocks —
@@ -2674,7 +2760,14 @@ class ContinuousBatchingScheduler:
         ``pipelined_steps_total`` went through ``_dispatch_pipeline``;
         ``reclaims_total`` the blocks the pipeline's growth took from
         the prefix cache; ``drains_total`` the frontier drains by reason
-        (``_drain_frontier``'s callers). Read by a scrape's thread:
+        (``_drain_frontier``'s callers); ``emits_deferred_total`` the
+        decode tokens that went to their streams where the thread next
+        parked for the device (``_emit_late``) and ``emits_pending`` the
+        handles that hold some still (0 after an iteration that leaves
+        nothing running); ``release_wait_total_s`` the seconds this
+        thread spent dropping consumed steps' handles (the
+        ``ff.sched.release`` spans, counted with observability off
+        too). Read by a scrape's thread:
         ``pipe_drains`` has its keys from the start, so the copy never
         meets a dict that changes size."""
         return {
@@ -2682,6 +2775,9 @@ class ContinuousBatchingScheduler:
             "pipelined_steps_total": self.pipe_dispatches,
             "reclaims_total": self.pipe_reclaims,
             "drains_total": dict(self.pipe_drains),
+            "emits_deferred_total": self.emits_deferred,
+            "emits_pending": len(self._late),
+            "release_wait_total_s": self.release_wait_s,
         }
 
     def _try_pipeline(self) -> Optional[bool]:
@@ -3104,6 +3200,10 @@ class ContinuousBatchingScheduler:
             # pressure flag
             self.capacity.tick()
             self._overload_tick()
+        if self._late and self._pipe is None and not (did and self._running):
+            # nothing is in flight and no stream is left to dispatch a
+            # step for: held tokens do not wait for one
+            self._emit_late()
         if not self.obs_enabled:
             return did
         if did:

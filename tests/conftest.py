@@ -67,6 +67,7 @@ def _with_pipeline_section(make):
                 "decode.dispatch.upload": {"count": 100 * k, "total_s": 0.15 * k},
                 "decode.dispatch.call": {"count": 100 * k, "total_s": 0.2 * k},
                 "decode.observe": {"count": 100 * k, "total_s": 0.01 * k},
+                "decode.release": {"count": 100 * k, "total_s": 0.02 * k},  # PR 38's reader (the span is PR 37's)
                 "decode.unspanned": {"count": 100 * k, "total_s": 0.05 * k},
             })
     return ctx
@@ -77,10 +78,13 @@ def _through(entries, name):
     return entries[: [e["name"] for e in entries].index(name) + 1]
 
 
-def _as_pr_30_left_it(bench):
-    """``BENCHMARK.json`` with ``per_layer`` cut after the entry that PR 30
-    appended last: nothing to extend when a later PR appends."""
-    return dict(bench, per_layer=_through(bench["per_layer"], "pipelined_step_share.served"))
+def _per_layer_through(metric: str):
+    """``BENCHMARK.json`` with ``per_layer`` cut after ``metric``, the entry
+    a PR appended last: nothing to extend when a later PR appends."""
+    return lambda bench: dict(bench, per_layer=_through(bench["per_layer"], metric))
+
+
+_as_pr_30_left_it = _per_layer_through("pipelined_step_share.served")
 
 
 def _as_it_was_after(cell: str, config: str, metric: str = ""):
@@ -110,10 +114,10 @@ _as_pr_27_left_it = _as_it_was_after("lfm2-8b-a1b.gen-batch", "lfm2-8b-a1b")
 _as_pr_31_left_it = _as_it_was_after("mellum2-12b.code-gen", "mellum2-12b", "window_cache_saving.served")
 
 
-def _as_pr_34_left_it(bench):
-    """``per_layer`` cut after the entry that PR 34 appended last (it
-    pins the SET of metrics that list its cell; PR 37 appended ten)."""
-    return dict(bench, per_layer=_through(bench["per_layer"], "latent_cache_share.served"))
+# PR 34 pins the SET of metrics that list its cell (PR 37 appended ten);
+# PR 37 pins its ten as the list's tail (PR 38 appended two)
+_as_pr_34_left_it = _per_layer_through("latent_cache_share.served")
+_as_pr_37_left_it = _per_layer_through("trace_record_share.served")
 
 
 def _seeing(item, view):
@@ -143,6 +147,7 @@ _PINNED_TAILS = {
     ("test_lfm2_cell.py", "test_every_new_metric_lists_the_cell_and_is_read_there"): _as_pr_27_left_it,
     ("test_mellum2_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_metrics_that_list_it"): _as_pr_31_left_it,
     ("test_joyai_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_three_metrics_that_list_it"): _as_pr_34_left_it,
+    ("test_thread_account.py", "test_benchmark_json_asks_for_them_in_the_five_serving_cells"): _as_pr_37_left_it,
 }
 
 

@@ -27,8 +27,17 @@ Coverage (the ISSUE acceptance matrix):
     it out to a host tier with room) and stays pipelined; only a pool
     with nothing left to evict drains for pressure; the ``pipeline``
     section of /v2/stats counts all of it
+  * the streams are woken where the thread parks (ISSUE 38) — a step
+    consumed with its successor in flight is bookkept at once and its
+    tokens are put on their streams' queues when the frontier is next
+    consumed or discarded: every stream reads the sequential
+    scheduler's tokens, in order and whole, before its end or its
+    error; nothing is pending when nothing is in flight (a drain, a
+    crash with reset and replay, a shutdown); the deferral and the
+    drop's seconds are counted
 """
 import contextlib
+import dataclasses
 
 import jax
 import numpy as np
@@ -639,7 +648,8 @@ def test_pipeline_section_of_stats_counts_the_loop_s_decisions(decoder_params):
     assert all(a <= b for p, q in zip(snaps, snaps[1:]) for a, b in zip(flat(p), flat(q)))
     assert all(0 <= p["pipelined_steps_total"] <= p["decode_steps_total"] for p in snaps)
     last = sched.stats.snapshot()["pipeline"]
-    assert set(last) == {"decode_steps_total", "pipelined_steps_total", "reclaims_total", "drains_total"}
+    assert set(last) == {"decode_steps_total", "pipelined_steps_total", "reclaims_total", "drains_total",
+                         "emits_deferred_total", "emits_pending", "release_wait_total_s"}
     assert set(last["drains_total"]) == {"nonsteady", "finish", "pressure", "idle"}
     assert sum(last["drains_total"].values()) == drained[0] > 0
     assert last["drains_total"]["pressure"] >= 1 and last["drains_total"]["finish"] >= 1
@@ -652,3 +662,208 @@ def test_pipeline_section_of_stats_counts_the_loop_s_decisions(decoder_params):
     off = sched_off.stats.snapshot()["pipeline"]
     assert off["decode_steps_total"] == eng_off.step_counts["decode"] > 0
     assert off["pipelined_steps_total"] == off["reclaims_total"] == 0 == sum(off["drains_total"].values())
+
+
+# ------------------------------------- the streams are woken late (ISSUE 38)
+LATE_PROMPTS = [[(7 * i + j) % 60 + 1 for j in range(3 + i % 6)] for i in range(18)]
+
+
+def streamed(handle):
+    """What a stream's reader gets, read after the fact: the tokens on
+    the handle's queue up to its end; its error ends the list."""
+    got = []
+    try:
+        for tok in handle.tokens(timeout=0):
+            got.append(tok)
+    except Exception as e:  # noqa: BLE001 - the stream's own failure, kept as its last item
+        got.append(type(e).__name__)
+    return got
+
+
+def submit_late(sched, sampling, prompts=LATE_PROMPTS):
+    """Budgets of 0-9 fewer tokens, so that streams finish beside running ones."""
+    return [sched.submit(p, dataclasses.replace(sampling, max_new_tokens=sampling.max_new_tokens - 3 * (i % 4)))
+            for i, p in enumerate(prompts)]
+
+
+def run_late(decoder_params, *, overlap, sampling, plan=None, sched_kw=None, each_step=None):
+    eng = make_engine(decoder_params, num_blocks=60, slots=3)
+    sched = ContinuousBatchingScheduler(eng, overlap=overlap, **(sched_kw or {}))
+    with (plan.active() if plan is not None else contextlib.nullcontext()):
+        handles = submit_late(sched, sampling)
+        steps = 0
+        while any(not h.done() for h in handles):
+            if not sched.step():
+                break
+            steps += 1
+            if each_step is not None:
+                each_step(sched)
+            assert steps < 5000
+    return handles, eng, sched
+
+
+@pytest.mark.parametrize("sampling", [
+    SamplingParams(max_new_tokens=46),
+    SamplingParams(max_new_tokens=43, temperature=0.8, top_k=8, seed=11),
+], ids=["greedy", "temp_topk"])
+def test_streams_read_the_sequential_tokens_with_the_emission_deferred(decoder_params, sampling):
+    """Eighteen requests through three slots, 34-46 tokens each: over 200
+    pipelined steps, streams finishing beside running ones, admissions.
+    Every stream's reader gets what the sequential scheduler's gets,
+    token for token, and what ``result()`` holds."""
+    pending = []
+
+    def each_step(sched):
+        p = sched.stats.snapshot()["pipeline"]["emits_pending"]
+        # held only while a dispatch is sure to follow: a step in flight, or streams still running
+        assert p == len(sched._late) and (p == 0 or sched._pipe is not None or sched._running)
+        pending.append(p)
+
+    off, _, sched_off = run_late(decoder_params, overlap=False, sampling=sampling)
+    on, eng, sched = run_late(decoder_params, overlap=True, sampling=sampling, each_step=each_step)
+    assert [streamed(h) for h in on] == [streamed(h) for h in off] == [h.result(timeout=0) for h in off]
+    assert [h.result(timeout=0) for h in on] == [h.result(timeout=0) for h in off]
+    stats = sched.stats.snapshot()["pipeline"]
+    assert stats["pipelined_steps_total"] >= 200 and sum(stats["drains_total"].values()) >= 6
+    assert max(pending) > 0 and pending[-1] == 0 == stats["emits_pending"]
+    # a prefill's first token goes out at once, every decode step's tokens late: where the thread parks
+    # (counted), or with the stream's end when that comes first (its last token at most)
+    total = sum(len(h.result(timeout=0)) for h in on)
+    assert total - 2 * len(on) <= stats["emits_deferred_total"] <= total - len(on)
+    assert stats["release_wait_total_s"] > 0.0
+    # the sequential scheduler wakes its streams late too (after the next step's dispatch), and drops no handle
+    quiet = sched_off.stats.snapshot()["pipeline"]
+    assert total - 2 * len(on) <= quiet["emits_deferred_total"] <= total - len(on) and quiet["emits_pending"] == 0
+    assert quiet["release_wait_total_s"] == 0.0
+
+
+@pytest.mark.parametrize("fault", ["crash", "readback", "whole_batch_nan", "one_slot_nan"])
+def test_a_failure_in_flight_owes_no_stream_a_token(decoder_params, fault):
+    """The step before a failed one was bookkept: its tokens reach their
+    streams before the restart, the re-run or the quarantine, and after
+    the recovery nothing is pending. Each stream reads what its
+    ``result()`` holds; the quarantined one its tokens, then its error."""
+    plan = FaultPlan(seed=0)
+    if fault == "crash":
+        plan.on("generation.decode_step", mode="error", error=RuntimeError("device crash"), nth=(6, 7))
+    elif fault == "readback":
+        plan.on("generation.async_readback", mode="error", error=FaultInjected("readback lost"), nth=(4,))
+    elif fault == "whole_batch_nan":
+        plan.on("generation.decode_step", mode="nan", nth=(5,))
+    else:
+        plan.on("generation.decode_step", mode="nan", nth=(5,), select=lambda v: np.asarray([True, False, False]))
+    sampling = SamplingParams(max_new_tokens=16)
+    seen = []
+
+    def each_step(sched):
+        seen.append((len(sched._late), sched._pipe is not None or bool(sched._running)))
+
+    handles, eng, sched = run_late(
+        decoder_params, overlap=True, sampling=sampling, plan=plan, each_step=each_step,
+        sched_kw={"recovery": RecoveryPolicy(sleep=lambda _s: None)})
+    assert all(more_to_come or not late for late, more_to_come in seen)
+    assert sched.stats.snapshot()["pipeline"]["emits_pending"] == 0 and sched._pipe is None
+    assert sched.stats.snapshot()["pipeline"]["emits_deferred_total"] > 0
+    off, _, _ = run_late(decoder_params, overlap=False, sampling=sampling, plan=None)
+    poisoned = 0
+    for h, ref in zip(handles, off):
+        got = streamed(h)
+        if got and got[-1] == "PoisonedRequestError":
+            poisoned += 1
+            assert got[:-1] == ref.result(timeout=0)[: len(got) - 1]  # its tokens first, then its error
+        else:
+            assert got == h.result(timeout=0) == ref.result(timeout=0)
+    assert poisoned == (1 if fault == "one_slot_nan" else 0)
+    if fault in ("crash", "whole_batch_nan"):
+        assert eng.resets >= 1
+
+
+def test_nothing_is_pending_after_a_shutdown_and_no_thread_is_left(decoder_params):
+    """The threaded loop: streams read as they are served; a graceful
+    stop ends with nothing in flight, nothing pending and the loop's
+    thread gone; a hard stop with work in flight still gives each
+    stream the tokens that were bookkept before its error."""
+    import threading
+
+    sampling = SamplingParams(max_new_tokens=30)
+    ref, _, _ = run_late(decoder_params, overlap=False, sampling=sampling)
+    before = {t.ident for t in threading.enumerate()}
+    eng = make_engine(decoder_params, num_blocks=60, slots=3)
+    eng.generate([LATE_PROMPTS[0]], SamplingParams(max_new_tokens=2))  # warm: no compile on the loop's thread
+    eng.reset()
+    sched = ContinuousBatchingScheduler(eng, overlap=True)
+    sched.start()
+    handles = submit_late(sched, sampling)
+    got = [list(h.tokens(timeout=60)) for h in handles]  # read live, as a handler thread does
+    sched.stop()
+    assert got == [h.result(timeout=0) for h in ref]
+    p = sched.stats.snapshot()["pipeline"]
+    assert p["emits_pending"] == 0 and sched._pipe is None and p["emits_deferred_total"] > 0
+    assert {t.ident for t in threading.enumerate() if t.is_alive()} <= before
+
+    sched = ContinuousBatchingScheduler(make_engine(decoder_params, num_blocks=60, slots=3), overlap=True)
+    handles = submit_late(sched, sampling, LATE_PROMPTS[:3])
+    while sched._pipe is None or not sched._late:
+        assert sched.step()
+    owed = {id(h): h._held[-1] for h in sched._late}
+    assert owed
+    sched.stop(drain=False)
+    assert sched._pipe is None and not sched._late
+    for h in handles:
+        got = streamed(h)
+        assert got[-1] == "ShuttingDownError" and got[:-1] == h._request.generated
+        assert id(h) not in owed or got[-2] == owed[id(h)]
+
+
+@pytest.mark.parametrize("settle", ["finish", "fail", "emit"])
+def test_a_handle_gives_its_held_tokens_before_whatever_comes_next(settle):
+    """``_emit_later`` keeps a token off the queue; whoever settles the
+    handle (the loop, the watchdog's thread) or emits at once after it
+    puts the held ones out first, in order."""
+    from flexflow_tpu.generation.scheduler import Request
+
+    h = Request([1, 2, 3], SamplingParams(max_new_tokens=4)).handle
+    h._emit(7)
+    h._emit_later(8)
+    h._emit_later(9)
+    assert h._tokens.qsize() == 1 and h._held == [8, 9]
+    if settle == "finish":
+        h._finish([7, 8, 9])
+        assert streamed(h) == [7, 8, 9] and h.result(timeout=0) == [7, 8, 9]
+    elif settle == "fail":
+        assert h._fail(TimeoutError("deadline"))
+        assert streamed(h) == [7, 8, 9, "TimeoutError"]
+    else:
+        h._emit(10)
+        assert h._held == [] and h._release_held() == 0
+        h._finish([7, 8, 9, 10])
+        assert streamed(h) == [7, 8, 9, 10]
+
+
+def test_the_engine_reports_a_blocking_call_s_dispatch_and_not_a_pipelined_one(decoder_params):
+    """``on_dispatched`` fires once a blocking call, after its program
+    went to the device and before the wait (the scheduler wakes the
+    streams there); ``decode_async`` leaves the moment to its caller."""
+    def serve(overlap):
+        eng = make_engine(decoder_params)
+        sched = ContinuousBatchingScheduler(eng, overlap=overlap)
+        assert eng.on_dispatched == sched._emit_late  # the scheduler that serves from the engine takes the hook
+        seen = []
+
+        def hook():
+            seen.append(dict(eng.step_counts))
+            sched._emit_late()
+
+        eng.on_dispatched = hook
+        h = sched.submit([5, 6, 7], SamplingParams(max_new_tokens=12))
+        while not h.done():
+            assert sched.step()
+        assert streamed(h) == h.result(timeout=0) and len(h.result(timeout=0)) == 12
+        return seen, eng, sched
+
+    seen, eng, _ = serve(overlap=False)
+    assert len(seen) == eng.step_counts["prefill"] + eng.step_counts["decode"] == 1 + 11
+    assert seen[0]["prefill"] == 1 and seen[0]["decode"] == 0  # the count is the dispatched call's own
+    seen, eng, sched = serve(overlap=True)
+    # the admission's prefill reported; of the 11 decode steps only the sequential ones did
+    assert sched.pipe_dispatches > 0 and len(seen) == 1 + eng.step_counts["decode"] - sched.pipe_dispatches
