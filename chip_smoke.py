@@ -6,6 +6,7 @@
     python chip_smoke.py --latent-kernel     # the latent paged kernel alone
     python chip_smoke.py --expert-product    # the routed experts' sum alone: dense against grouped
     python chip_smoke.py --release-probe     # what the drop of a consumed step's device arrays waits for
+    python chip_smoke.py --dispatch-probe    # ms a decode dispatch (args / upload / call), steady and after a change
     python chip_smoke.py --group16           # the group-16 paged calls and the streamed prefill kernel alone
 
 ONE process. It refuses to start unless JAX's first device is a TPU, and
@@ -839,6 +840,89 @@ def release_probe(width=None, rows=None, step_ms=None, reps=None, streams=None, 
     return out
 
 
+def dispatch_probe(slots_list=(8, 64), num_layers: int = 24, short: int = 40, long: int = 100) -> dict:
+    """What a decode dispatch costs the scheduler's thread, by child
+    span (``args`` / ``upload`` / ``call``, ms, medians), at each of
+    ``slots_list`` batch slots of the GPT-2-medium decoder: ``slots``
+    requests of 12 tokens through a pipelined scheduler stepped by hand,
+    half of them replies of ``long`` tokens and half shorter ones, each
+    of a length of its own from ``short`` up (a finish apiece), every dispatch of
+    ``engine.decode`` / ``decode_async`` recorded with what it added to
+    ``engine.uploads``:
+
+    * ``steady``: dispatched on the token array of the step in flight,
+      in the composition of the step before it (its positions are that
+      step's plus one in every active slot, its mask that step's), and
+      staging missed nothing (no block table grew);
+    * ``changed``: a step in another composition than the one before it
+      (an admission, a finish, a stream left out of the step after its
+      last);
+    * ``other``: the rest (a table that grew, a pipeline's first step).
+
+    It asks the engine nothing a parent commit lacks, so ``--tree DIR``
+    runs it over that tree's ``flexflow_tpu`` for the other side."""
+    import statistics
+
+    import jax
+    import numpy as np
+
+    from flexflow_tpu.generation import CacheConfig, ContinuousBatchingScheduler, GenerationEngine, SamplingParams, init_decoder_params
+
+    cfg = decoder_config(num_layers)
+    params = init_decoder_params(jax.random.key(SEED), cfg)
+    out = {}
+    for slots in slots_list:
+        blocks = 1 + slots * (-(-(12 + long) // 16) + 1)
+        cache = CacheConfig(num_layers=cfg.num_layers, num_heads=cfg.num_heads, head_dim=cfg.hidden_size // cfg.num_heads,
+                            block_size=16, num_blocks=blocks)
+        engine = GenerationEngine(params, cfg, max_batch_slots=slots, cache_config=cache, prompt_buckets=[16], max_seq_len=128,
+                                  prefix_cache=False)
+        t0 = time.monotonic()
+        engine.generate([[7] * 12], SamplingParams(max_new_tokens=3))  # compiles prefill[16] and the decode program
+        log(f"dispatch probe: {slots} slots, {blocks} blocks, programs ready in {time.monotonic() - t0:.1f}s")
+        sched = ContinuousBatchingScheduler(engine, overlap=True)
+        records, last = [], [None]
+
+        def recorded(fn):
+            def call(tokens, positions, tables, active, *a, **kw):
+                before = dict(engine.uploads)
+                # (the scheduler bumps these arrays in place for the next step)
+                expected, last[0] = last[0], (positions + active, active.copy())
+                same = expected is not None and all(np.array_equal(x, y) for x, y in zip(expected, (positions, active)))
+                result = fn(tokens, positions, tables, active, *a, **kw)
+                grew = {k: v - before.get(k, 0) for k, v in engine.uploads.items()}
+                kind = ("changed" if not same else
+                        "steady" if kw.get("tokens_dev") is not None and not grew["staged_misses_total"] else "other")
+                records.append((kind, {name: (t1 - t0) * 1e3 for name, t0, t1 in engine._children}, grew))
+                return result
+            return call
+
+        engine.decode, engine.decode_async = recorded(engine.decode), recorded(engine.decode_async)
+        rs = np.random.RandomState(slots)
+        handles = [sched.submit([int(t) for t in rs.randint(0, cfg.vocab_size, size=12)],
+                                SamplingParams(max_new_tokens=min(short + i, long) if i % 2 else long)) for i in range(slots)]
+        while any(not h.done() for h in handles):
+            check(sched.step() is not False, "the probe's scheduler ran out of work with requests open")
+        check(all(short <= len(h.result(timeout=0)) <= long for h in handles), "a probe request came back short")
+        check(engine.trace_counts["decode"] == 1, "the decode program was traced again")
+        table = {}
+        for kind in ("steady", "changed", "other"):
+            rows = [(parts, grew) for k, parts, grew in records if k == kind]
+            if rows:
+                table[kind] = {
+                    "n": len(rows),
+                    **{f"{part}_ms": round(statistics.median(parts[part] for parts, _ in rows), 4) for part in ("args", "upload", "call")},
+                    "dispatch_ms": round(statistics.median(sum(parts.values()) for parts, _ in rows), 4),
+                    "uploads_a_step": round(statistics.mean(grew["uploads_total"] for _, grew in rows), 2),
+                    "upload_bytes_a_step": round(statistics.mean(grew["upload_bytes_total"] for _, grew in rows), 1),
+                }
+        out[str(slots)] = table
+        log(f"dispatch probe: {slots} slots: {json.dumps(table)}")
+        del engine, sched
+        gc.collect()
+    return out
+
+
 def kernels_phase(rs) -> dict:
     """Both Pallas kernels against their XLA references at the shapes
     the server and the trainer run, compiled (never interpreted)."""
@@ -1469,7 +1553,13 @@ def main(argv=None) -> int:
                     help="the group-16 paged calls and the streamed prefill kernel alone, at the long-document cell's sizes")
     ap.add_argument("--release-probe", action="store_true",
                     help="the drop of a finished step's device arrays, timed beside a program in flight and beside woken stream threads")
+    ap.add_argument("--dispatch-probe", action="store_true",
+                    help="ms a decode dispatch by child span, a steady step beside one after a composition change, at 8 and 64 slots")
+    ap.add_argument("--tree", default=None, metavar="DIR",
+                    help="import flexflow_tpu from DIR (a parent commit unpacked under the checkout) and not from here")
     args = ap.parse_args(argv)
+    if args.tree:
+        sys.path.insert(0, str(pathlib.Path(args.tree).resolve()))
 
     import jax
     import numpy as np
@@ -1507,6 +1597,12 @@ def main(argv=None) -> int:
         (REPO / "chiprun_out" / "pr38").mkdir(parents=True, exist_ok=True)
         (REPO / "chiprun_out" / "pr38" / "release_probe.json").write_text(json.dumps(summary["release_probe"]) + "\n")
         print(json.dumps(summary["release_probe"]), flush=True)
+    elif args.dispatch_probe:
+        summary["dispatch_probe"] = {"tree": args.tree or ".", "ms_a_dispatch": dispatch_probe()}
+        (REPO / "chiprun_out" / "pr40").mkdir(parents=True, exist_ok=True)
+        name = "dispatch_probe_" + (pathlib.Path(args.tree).name.strip(".") if args.tree else "change") + ".json"
+        (REPO / "chiprun_out" / "pr40" / name).write_text(json.dumps(summary["dispatch_probe"]) + "\n")
+        print(json.dumps(summary["dispatch_probe"]), flush=True)
     else:
         summary["calibration"] = calibration_hit(dev.device_kind)
         log(f"calibration lookup for {dev.device_kind!r}: {summary['calibration']}")
@@ -1528,7 +1624,8 @@ def main(argv=None) -> int:
     out_dir.mkdir(exist_ok=True)
     name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel
             else "chip_smoke_group16.json" if args.group16 else "chip_smoke_experts.json" if args.expert_product is not None
-            else "chip_smoke_release.json" if args.release_probe else "chip_smoke.json")
+            else "chip_smoke_release.json" if args.release_probe else "chip_smoke_dispatch.json" if args.dispatch_probe
+            else "chip_smoke.json")
     (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

@@ -96,6 +96,8 @@ class PrefixPlan:
 
 
 EMPTY_PREFIX_PLAN = PrefixPlan([], None, 0, 0)
+# the staged entries that are a decode program's RESULTS (engine._staged)
+CARRIED = ("decode.positions", "decode.counts")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -641,7 +643,8 @@ class GenerationEngine:
         # host-to-device transfers through ``_dev`` (every one the
         # engine makes, whatever the step) and what ``_stage`` answered
         # (cumulative, /v2/stats "uploads")
-        self.uploads = {"uploads_total": 0, "upload_bytes_total": 0, "staged_hits_total": 0, "staged_misses_total": 0}
+        self.uploads = {"uploads_total": 0, "upload_bytes_total": 0, "staged_hits_total": 0, "staged_misses_total": 0,
+                        "carried_hits_total": 0, "carried_misses_total": 0}
         # a scheduler that keeps a step anatomy switches this on for the
         # iterations it samples (one in ``CPU_CLOCK_EVERY``): the decode
         # dispatch span then reads the thread's CPU clock at its two
@@ -730,11 +733,17 @@ class GenerationEngine:
             donate_cache if donate_cache is not None
             else jax.default_backend() != "cpu"
         )
-        # device-resident staging for slot-constant decode/verify args
-        # (block tables, sampling params): re-uploaded only when the
-        # host-side contents change, not rebuilt via jnp.asarray every
-        # step. Keyed by arg name; each entry is (host snapshot, device
-        # array). Loop-thread only (like the cache refs).
+        # device-resident staging for decode/verify args: re-uploaded
+        # only when the host-side contents change, not rebuilt via
+        # jnp.asarray every step. Keyed by arg name; each entry is (host
+        # snapshot, device array). The slot-constant ones (block tables,
+        # sampling params, the active mask) are uploads; a decode step's
+        # positions and counts are CARRIED: the entry is what the step
+        # program itself returned (its inputs advanced by one in every
+        # active slot) beside the host's same sum, so a step that
+        # follows in the same composition uploads nothing (CARRIED
+        # below; dropped wherever the program that made them may have
+        # failed). Loop-thread only (like the cache refs).
         self._staged: Dict[str, Tuple[np.ndarray, jax.Array]] = {}
         # decode steps by the branch of `_sample` they ran (cumulative,
         # /v2/stats "sampling")
@@ -759,7 +768,8 @@ class GenerationEngine:
             repl = self.layout.replicated
             csh = self.layout.cache_sharding
             sharded = {"out_shardings": (repl, repl, csh, csh, repl, repl)}
-            dec_sh = dict(sharded)
+            # a decode step also returns the positions and counts it advanced
+            dec_sh = {"out_shardings": sharded["out_shardings"] + (repl, repl)}
             ver_sh = {"out_shardings": (repl, repl, repl, csh, csh)}
         self._prefill_jit = jax.jit(self._prefill_impl, **sharded)
         self._decode_jit = jax.jit(
@@ -919,6 +929,7 @@ class GenerationEngine:
         into the fresh cache."""
         self.cache.reset()
         self.allocator.reset()
+        self._drop_carried()
         if self.window_allocator is not None:
             self.window_allocator.reset()
             self.window_tables.clear()
@@ -1034,16 +1045,28 @@ class GenerationEngine:
         return token, ok, cache_k, cache_v, state, counts
 
     def _decode_impl(
-        self, params, tokens, positions, cache_k, cache_v, block_tables, context_lens, temps, top_ks, bias, seeds, counts, mask,
+        self, params, tokens, positions, cache_k, cache_v, block_tables, active, temps, top_ks, bias, seeds, counts, mask,
         state=None, expert_counts=None, wtables=None,
     ):
+        """One decode step. ``positions`` and ``counts`` are the slots'
+        raw vectors and ``active`` their 0 / 1 mask (int32): the
+        position an inactive slot writes at (0, in scratch) and every
+        slot's context length are derived here, and the last two results
+        are ``positions`` and ``counts`` advanced by one in every active
+        slot: the next step's arguments, if its composition is this
+        one's (:meth:`_decode_args`)."""
         self.trace_counts["decode"] = self.trace_counts.get("decode", 0) + 1
         self.programs.note_trace("decode", {
             "params": params, "tokens": tokens, "positions": positions,
             "cache_k": cache_k, "block_tables": block_tables,
-            "context_lens": context_lens, "temps": temps, "top_ks": top_ks,
+            "active": active, "temps": temps, "top_ks": top_ks,
             "bias": bias, "seeds": seeds, "counts": counts, "mask": mask,
         })
+        # (primitives, not jnp's jitted wrappers: the mask is 0 / 1, so a
+        # product selects, and the program's trace gains no nested call)
+        next_positions, next_counts = jax.lax.add(positions, active), jax.lax.add(counts, active)
+        context_lens = jax.lax.mul(next_positions, active)
+        positions = jax.lax.mul(positions, active)
         state, expert_counts, rows = state or {}, expert_counts or {}, []
         window = None
         if self.window_config is not None:
@@ -1073,7 +1096,8 @@ class GenerationEngine:
             # host fold_in/stack on the critical path, same key bits as
             # before
             keys = derive_keys(seeds, counts)
-            return _sample(logits, temps, top_ks, keys), ok, cache_k, cache_v, state, expert_counts
+            token = _sample(logits, temps, top_ks, keys)
+        return token, ok, cache_k, cache_v, state, expert_counts, next_positions, next_counts
 
     def _verify_impl(
         self, params, tokens, start, n_draft, cache_k, cache_v, block_tables, temps, top_ks, bias, seeds, counts, mask
@@ -2142,50 +2166,74 @@ class GenerationEngine:
         """Both halves at once, for a caller outside a dispatch span."""
         return self._upload(self._lookup(name, host))
 
+    def _drop_carried(self) -> None:
+        """Forget the staged entries a decode program returned: that
+        program may have failed, and what a failed program returned is
+        poisoned (an uploaded entry is not, and stays)."""
+        for name in CARRIED:
+            self._staged.pop(name, None)
+
     def _decode_args(self, tokens, positions, block_tables, active, temps, top_ks, seeds, counts, bias, mask=None,
                      window=None):
         """Assemble the decode jit's argument tuple after the
         parameters, in the two children of the dispatch span that the
         work falls into: ``args`` (the masks, the casts, the staging
         compares, the sampling-branch count) and ``upload`` (every
-        transfer: three fresh vectors a step, a staging miss, and
+        transfer: a staging miss, a poisoned bias, a mask, and
         ``tokens`` where it is a host array; the pipelined path carries
-        it device-resident, and it passes). ``window``: what
-        :meth:`advance_windows` returned for this step, where the caller
-        has made that call already; a caller that has not pays it here,
-        before ``args`` opens, in the parent's self time."""
+        it device-resident, and it passes). ``positions`` and ``counts``
+        go in raw and are staged like the rest, but their entries are
+        what the step before RETURNED (:meth:`_carry`): where the
+        host's vectors are that step's advanced by one in every active
+        slot, as a step of the same composition's are, they pass, and a
+        steady step uploads nothing at all. Anything else (the first
+        step, an admission, a finish, a preemption, a probe that clears
+        a slot, a step voided or retried) is a miss and one upload each,
+        counted in ``carried_hits_total`` / ``carried_misses_total``.
+        ``window``: what :meth:`advance_windows` returned for this step,
+        where the caller has made that call already; a caller that has
+        not pays it here, before ``args`` opens, in the parent's self
+        time. Returns the arguments, the context lengths (the
+        accounting's) and the host's side of what the program will
+        return for the next step."""
         if self.window_config is None:
             window = ()
         else:
             window = (self.advance_windows(positions, active) if window is None else window,)
         with self._part("decode", "args"):
-            context_lens = np.where(active, positions + 1, 0).astype(np.int32)
-            safe_pos = np.where(active, positions, 0).astype(np.int32)
+            act = active.astype(np.int32)
+            # (copies: the caller bumps its arrays in place for the next step)
+            positions, counts = positions.astype(np.int32), counts.astype(np.int32)
+            context_lens = np.where(active, positions + 1, 0)
             # scratch-mask inactive slots' tables too: an inactive slot with
             # a REAL table (a bisection probe deactivating a live slot)
             # would otherwise write its position-0 K/V into that slot's
             # first real block and silently corrupt the surviving stream
             tables = np.where(active[:, None], block_tables, 0).astype(np.int32)
             self.sampling_steps[sampling_branch(temps, top_ks)] += 1
-            counts = counts.astype(np.int32)
+            carried = [self._lookup(name, host) for name, host in zip(CARRIED, (positions, counts))]
+            missed = any(isinstance(x, tuple) for x in carried)
+            self.uploads["carried_misses_total" if missed else "carried_hits_total"] += 1
             staged = [
                 self._lookup("decode.tables", tables),
+                self._lookup("decode.active", act),
                 self._lookup("decode.temps", temps.astype(np.float32)),
                 self._lookup("decode.top_ks", top_ks.astype(np.int32)),
                 self._lookup("decode.seeds", seeds.astype(np.uint32)),
             ]
+            advanced = (positions + act, counts + act)
         with self._part("decode", "upload"):
-            tokens, safe_pos, lens, counts = (self._upload(x) for x in (tokens, safe_pos, context_lens, counts))
-            tables, temps, top_ks, seeds = (self._upload(x) for x in staged)
+            tokens, positions, counts = (self._upload(x) for x in (tokens, *carried))
+            tables, act, temps, top_ks, seeds = (self._upload(x) for x in staged)
             bias = self._bias_arg(bias)
             mask = self._mask_arg(mask, "decode_mask", (self.max_batch_slots, self.cfg.vocab_size))
         return (
             tokens,
-            safe_pos,
+            positions,
             self.cache.k,
             self.cache.v,
             tables,
-            lens,
+            act,
             temps,
             top_ks,
             bias,
@@ -2198,7 +2246,18 @@ class GenerationEngine:
             self._step_state(),
             self.expert_counts,
             *window,
-        ), context_lens
+        ), context_lens, advanced
+
+    def _carry(self, advanced, positions, counts) -> None:
+        """After a decode program's call: the positions and counts it
+        returned become the staged entries the next step's are compared
+        with, beside the host's same sums (``advanced``). (The call
+        itself stays in ``decode`` / ``decode_async``: every Python
+        frame between them and the jit is one more frame in the location
+        of every operation the program's first trace and lowering emit,
+        and two of them cost a 24-layer program 4 s of set-up.)"""
+        for name, host, dev in zip(CARRIED, advanced, (positions, counts)):
+            self._staged[name] = (host, dev)
 
     def _step_state(self) -> Dict[str, jax.Array]:
         """What a decode step carries (and donates) beside K/V."""
@@ -2256,27 +2315,32 @@ class GenerationEngine:
         finite — the supervisor's per-slot NaN blame vector.
         ``seeds``/``counts`` replace the old host-built key array: the
         per-slot sampling key derives in-jit (see :func:`derive_keys`)."""
-        masked = np.where(active, tokens, 0).astype(np.int32)
-        masked, bias = faults.inject(faults.GENERATION_DECODE_STEP, (masked, self._zero_bias))
-        if self.tp_degree > 1:
-            # sharded step: the cross-shard psum boundary can fail or
-            # wedge like any device work — chaos plans target it here
-            faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
-        self.step_counts["decode"] += 1
-        self._count_expert_form(self.max_batch_slots)
-        self._children = []
-        with phase("engine.decode.dispatch", cpu=self.cpu_stamps) as disp:
-            traces_before = self.trace_counts.get("decode", 0)
-            args, context_lens = self._decode_args(
-                masked, positions, block_tables, active, temps, top_ks, seeds,
-                counts, bias, mask,
-            )
-            with self._part("decode", "call"):
-                out, ok, ck, cv, state, counts = self._decode_jit(self.params, *args)
-        self._count_dispatch(disp)
-        self._dispatched()
-        with phase("engine.decode.block") as block:
-            jax.block_until_ready((out, ok, ck, cv, state))  # device execution done
+        try:  # whatever raises from the fault site to the program's end leaves no carried entry behind
+            masked = np.where(active, tokens, 0).astype(np.int32)
+            masked, bias = faults.inject(faults.GENERATION_DECODE_STEP, (masked, self._zero_bias))
+            if self.tp_degree > 1:
+                # sharded step: the cross-shard psum boundary can fail or
+                # wedge like any device work — chaos plans target it here
+                faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
+            self.step_counts["decode"] += 1
+            self._count_expert_form(self.max_batch_slots)
+            self._children = []
+            with phase("engine.decode.dispatch", cpu=self.cpu_stamps) as disp:
+                traces_before = self.trace_counts.get("decode", 0)
+                args, context_lens, advanced = self._decode_args(
+                    masked, positions, block_tables, active, temps, top_ks, seeds,
+                    counts, bias, mask,
+                )
+                with self._part("decode", "call"):
+                    out, ok, ck, cv, state, counts, *carried = self._decode_jit(self.params, *args)
+                self._carry(advanced, *carried)
+            self._count_dispatch(disp)
+            self._dispatched()
+            with phase("engine.decode.block") as block:
+                jax.block_until_ready((out, ok, ck, cv, state))  # device execution done
+        except BaseException:
+            self._drop_carried()
+            raise
         with phase("engine.decode.readback") as read:
             self.cache.update(ck, cv, **state)
             self.expert_counts = counts
@@ -2365,28 +2429,33 @@ class GenerationEngine:
             masked = np.where(active, tokens, 0).astype(np.int32)
         else:
             masked = None
-        masked, bias = faults.inject(
-            faults.GENERATION_DECODE_STEP, (masked, self._zero_bias)
-        )
-        if self.tp_degree > 1:
-            faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
-        self.step_counts["decode"] += 1
-        self._count_expert_form(self.max_batch_slots)
-        children = self._children = []
-        with phase("engine.decode.dispatch", cpu=self.cpu_stamps) as disp:
-            traces_before = self.trace_counts.get("decode", 0)
-            args, context_lens = self._decode_args(
-                masked if tokens_dev is None else tokens_dev, positions, block_tables, active, temps, top_ks,
-                seeds, counts, bias, mask, window,
+        try:  # as in decode(): a raised dispatch leaves no carried entry behind
+            masked, bias = faults.inject(
+                faults.GENERATION_DECODE_STEP, (masked, self._zero_bias)
             )
-            prev_k, prev_v, prev_conv = (None, None, None) if self.donate else (
-                self.cache.k, self.cache.v, self.cache.state.get("conv")
-            )
-            prev_window = None
-            if self.window_config is not None and not self.donate:
-                prev_window = {k: self.cache.state[k] for k in ("wk", "wv")}
-            with self._part("decode", "call"):
-                out, ok, ck, cv, state, counts = self._decode_jit(self.params, *args)
+            if self.tp_degree > 1:
+                faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
+            self.step_counts["decode"] += 1
+            self._count_expert_form(self.max_batch_slots)
+            children = self._children = []
+            with phase("engine.decode.dispatch", cpu=self.cpu_stamps) as disp:
+                traces_before = self.trace_counts.get("decode", 0)
+                args, context_lens, advanced = self._decode_args(
+                    masked if tokens_dev is None else tokens_dev, positions, block_tables, active, temps, top_ks,
+                    seeds, counts, bias, mask, window,
+                )
+                prev_k, prev_v, prev_conv = (None, None, None) if self.donate else (
+                    self.cache.k, self.cache.v, self.cache.state.get("conv")
+                )
+                prev_window = None
+                if self.window_config is not None and not self.donate:
+                    prev_window = {k: self.cache.state[k] for k in ("wk", "wv")}
+                with self._part("decode", "call"):
+                    out, ok, ck, cv, state, counts, *carried = self._decode_jit(self.params, *args)
+                self._carry(advanced, *carried)
+        except BaseException:
+            self._drop_carried()
+            raise
         with phase("engine.decode.post") as post:
             # start the device->host copies NOW; consume_decode's numpy
             # conversion then finds the bytes already resident
@@ -2413,6 +2482,7 @@ class GenerationEngine:
         state = {} if step.prev_conv is None else {"conv": step.prev_conv}
         self.cache.update(step.prev_k, step.prev_v, **state, **(step.prev_window or {}))
         self.expert_counts = step.prev_counts
+        self._drop_carried()  # the voided step's results, or a successor's chained on them
 
     def consume_decode(self, step: InFlightDecode) -> np.ndarray:
         """Block on an in-flight decode step and finish its accounting:
@@ -2428,6 +2498,7 @@ class GenerationEngine:
             try:
                 jax.block_until_ready((step.out, step.ok))
             except Exception:
+                self._drop_carried()  # a donating engine's too, which rolls nothing back
                 if step.prev_k is not None:
                     # roll the cache back to the pre-step refs: the
                     # failed program's outputs (and any successor

@@ -2630,8 +2630,11 @@ class ContinuousBatchingScheduler:
         unconsumed predecessor, the token array is its device-resident
         output (no host round trip at all) and the argument arrays are
         the predecessor's, bumped in place — steady state rebuilds
-        nothing and re-uploads nothing but three [B] scalars-per-slot
-        vectors."""
+        nothing and uploads nothing: the bumped positions and counts
+        are what the predecessor's program returned, which the engine
+        kept on the device and finds equal (``uploads``
+        ``carried_hits_total``); a step of another composition uploads
+        what differs."""
         with self._phase("sched.stage"):
             b = self.engine.max_batch_slots
             sig = tuple((s.slot, s.req.id, len(s.blocks)) for s in live)

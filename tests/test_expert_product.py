@@ -244,11 +244,15 @@ def decode_program(name, monkeypatch):
     return texts[0]
 
 
-# sha256 of the PARENT's decode programs (commit d49c0a9, the same helper run there)
+# sha256 of the decode programs as ISSUE 40 left them (the same helper). They are the programs of commit d49c0a9
+# (92f90174..., 63acac12..., d6aaf48a..., pinned here until then) plus 4 lines each and two more results: the positions
+# and the counts advanced by the 0 / 1 active mask (two `add`s), and from them the two vectors the host used to send,
+# as products with the mask (two `multiply`s: the context lengths, the positions with the inactive slots' at 0); with
+# the values numbered anew, every other line is the parent's (CHANGES.md, PR 40)
 PARENT_DECODE = {
-    "lfm2-8b-a1b": "92f90174109a87b6c965e0b57f981763b9ae058fe7459244b8a25a8e8dd4a88b",
-    "mellum2-12b": "63acac123a135ea129b38dc599f2192cae432377083a4faf50c8bd4982f15840",
-    "joyai-llm-flash": "d6aaf48a09687ed971ec24fb9d14121a57c830cf6e167fab9ac674ca48cde434",
+    "lfm2-8b-a1b": "5229345364afea572e7f7e9d408f52a5029da564e69b6ba1e1010252b0318ca8",
+    "mellum2-12b": "eb6d0db61fe504ffd6ace9ea3ae892925e53d561d9daac88ecf3ec7980bfb6c7",
+    "joyai-llm-flash": "0483a28320e40537a595d837b4db4888ac3c2c8aa5be721642eff7468d322211",
 }
 
 

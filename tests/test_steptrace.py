@@ -480,22 +480,36 @@ def test_an_empty_iteration_is_counted_and_a_working_one_is_counted_once(engine)
 
 
 def test_a_carried_steady_step_uploads_exactly_its_three_fresh_vectors(engine):
+    """(The name is PR 37's: the three vectors were ``safe_pos``,
+    ``context_lens`` and ``counts``, uploaded every step. Since ISSUE 40
+    the decode program returns the positions and counts advanced, the
+    engine keeps them staged, and a steady step uploads NOTHING.)"""
     sched = ContinuousBatchingScheduler(engine, overlap=True)
-    handles = [sched.submit(p, SamplingParams(max_new_tokens=14)) for p in ([1, 2, 3, 4], [9, 8, 7])]
-    carried = []
+    handles = [sched.submit(p, SamplingParams(max_new_tokens=n)) for p, n in (([1, 2, 3, 4], 14), ([9, 8, 7], 9))]
+    carried, after_finish = [], None
     while any(not h.done() for h in handles):
-        before, had = dict(engine.uploads), sched._pipe
+        before, had, live = dict(engine.uploads), sched._pipe, len(sched._running)
         sched.step()
         grew = {k: engine.uploads[k] - v for k, v in before.items()}
         if had is not None and sched._pipe is not None and sched._pipe.handle.children:
             carried.append(grew)  # a step dispatched on the token array of the one in flight
+        elif live == 2 and len(sched._running) == 1:
+            after_finish = grew  # the iteration that finished a stream and dispatched the other's next step
     assert len(carried) >= 6
     for grew in carried:
-        # safe_pos, context_lens, counts, and whatever staging missed (a block table that grew); the tokens never
-        assert grew["uploads_total"] == 3 + grew["staged_misses_total"]
-        assert grew["staged_hits_total"] + grew["staged_misses_total"] == 4  # tables, temps, top_ks, seeds
+        # positions and counts are what the step in flight returned; whatever staging missed (a block table that
+        # grew) goes up; the tokens never
+        assert grew["carried_hits_total"] == 1 and grew["carried_misses_total"] == 0
+        assert grew["uploads_total"] == grew["staged_misses_total"]
+        # positions, counts; tables, active, temps, top_ks, seeds
+        assert grew["staged_hits_total"] + grew["staged_misses_total"] == 7
     steady = [g for g in carried if g["staged_misses_total"] == 0]
-    assert steady and all(g["uploads_total"] == 3 and g["upload_bytes_total"] == 3 * 4 * engine.max_batch_slots for g in steady)
+    assert steady and all(g["uploads_total"] == 0 and g["upload_bytes_total"] == 0 for g in steady)
+    # a finish: the freed slot's position, count, mask and table row change, and each vector goes up once
+    assert after_finish["carried_misses_total"] == 1 and after_finish["carried_hits_total"] == 0
+    assert after_finish["staged_misses_total"] == after_finish["uploads_total"] == 4
+    assert after_finish["upload_bytes_total"] == 3 * 4 * engine.max_batch_slots + engine._staged["decode.tables"][0].nbytes
+    assert sched.stats.snapshot()["uploads"]["carried_hits_total"] == engine.uploads["carried_hits_total"] >= len(carried)
 
 
 def test_with_observability_off_the_sections_are_absent_and_no_cpu_clock_is_read(decoder_params, monkeypatch):
