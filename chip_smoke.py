@@ -4,6 +4,7 @@
     python chip_smoke.py                 # one chip: kernels, server, trainer
     python chip_smoke.py --four-chips    # one four-chip host: the sharded legs
     python chip_smoke.py --latent-kernel     # the latent paged kernel alone
+    python chip_smoke.py --latent 64         # ... at the sizes of the cell whose latent layers have 64 heads
     python chip_smoke.py --expert-product    # the routed experts' sum alone: dense against grouped
     python chip_smoke.py --release-probe     # what the drop of a consumed step's device arrays waits for
     python chip_smoke.py --dispatch-probe    # ms a decode dispatch (args / upload / call), steady and after a change
@@ -365,10 +366,13 @@ def materialised_calls_check() -> dict:
 # values its first 512 columns, bfloat16.
 LATENT_CALL = dict(heads=32, width=576, value_width=512, score_width=192, block=64, slots=32, columns=48,
                    layers=4, contexts=(1280, 2816))
+# ... and of the cell whose latent layers have 64 heads (longcat-flash-chat.agent-turns: 24 slots, tables of 72
+# columns, contexts 2,176-4,608, 8 sub-layers' rows in the array): `--latent 64`
+LATENT_CALLS = {32: LATENT_CALL, 64: dict(LATENT_CALL, heads=64, slots=24, columns=72, layers=8, contexts=(2176, 4608))}
 
 
-def latent_kernel_check() -> dict:
-    """``paged_latent_attention`` at :data:`LATENT_CALL`, compiled by
+def latent_kernel_check(c=None) -> dict:
+    """``paged_latent_attention`` at :data:`LATENT_CALL` (or the call ``c``), compiled by
     Mosaic, against the XLA composition in the stated arithmetic
     (``reference_paged_latent_attention``: bfloat16 queries and rows,
     float32 scores and softmax, the probabilities rounded to bfloat16
@@ -384,7 +388,7 @@ def latent_kernel_check() -> dict:
         latent_kernel_refusal, latent_row_width, paged_latent_attention, reference_paged_latent_attention,
     )
 
-    c = LATENT_CALL
+    c = c or LATENT_CALL
     rs = np.random.RandomState(SEED)
     b, bs, cols, rw, vw = c["slots"], c["block"], c["columns"], latent_row_width(c["width"]), c["value_width"]
     nb = b * cols + 1
@@ -432,7 +436,7 @@ def latent_kernel_check() -> dict:
     read = float(np.sum(ctx)) * c["width"] * 2
     out = {"max_abs_err": worst, "err_of_room": round(of_room, 3), "ms_a_call": ms,
            "rows_read_gb": round(read / 1e9, 4), "gb_per_s": round(read / 1e9 / (ms["kernel"] / 1e3), 1)}
-    log(f"latent paged kernel at the cell's sizes: {out}")
+    log(f"latent paged kernel at the cell's sizes ({c['heads']} heads, {b} slots x {cols} columns): {out}")
     return out
 
 
@@ -446,6 +450,8 @@ EXPERT_LAYERS = {
     "command_a": dict(hidden=4096, width=4096, experts=128, held=16, k=8, router="sigmoid", rows=(16, 5120, 6144)),
     # JoyAI's share at the row counts the rule's second entry moves (no bucket of its cell is that long)
     "joyai_long": dict(hidden=2048, width=768, experts=256, held=16, k=8, router="sigmoid", rows=(4096, 6144)),
+    # LongCat's routed branch: 16 held of 512 experts beside 256 identity experts (768 outputs), gates 6 p_j unrenormalised
+    "longcat": dict(hidden=6144, width=2048, experts=512, zero=256, held=16, k=12, router="softmax", rows=(16, 64, 1024, 4096)),
 }
 EXPERT_ROWS = (32, 64, 256, 512, 1024, 1536, 2048)
 
@@ -486,8 +492,11 @@ def expert_product_check(names=()) -> dict:
         cfg = decoder.DecoderConfig(
             num_layers=1, hidden_size=c["hidden"], num_heads=16, ff_size=c["width"], seq_length=64, vocab_size=128,
             num_dense_layers=0, num_experts=c["experts"], experts_per_token=c["k"], moe_ff_size=c["width"],
-            router=c["router"], experts_held=held or ())
-        shapes = dict(router=(c["hidden"], c["experts"]), router_bias=(c["experts"],),
+            router=c["router"], experts_held=held or (),
+            **(dict(zero_experts=c["zero"], router_softmax_bias=True, router_renormalise=False, routed_scaling_factor=6.0)
+               if c.get("zero") else {}))
+        outputs = cfg.router_outputs
+        shapes = dict(router=(c["hidden"], outputs), router_bias=(outputs,),
                       ew1=(c["held"], c["hidden"], c["width"]), ew3=(c["held"], c["hidden"], c["width"]),
                       ew2=(c["held"], c["width"], c["hidden"]))
         keys = dict(zip(shapes, jax.random.split(jax.random.key(SEED), len(shapes))))
@@ -506,25 +515,27 @@ def expert_product_check(names=()) -> dict:
 
         def other_candidate(layer, v):
             gates, chosen = decoder.route(cfg, layer, v)
+            identity = jnp.sum(gates[:, cfg.num_experts:], axis=1) if cfg.zero_experts else None
             return expert_product.grouped_expert_sum(
-                v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, product=ragged)
+                v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, product=ragged, identity=identity)
 
         def composition(layer, v):
             gates, _ = decoder.route(cfg, layer, v)
             mine = gates if held is None else gates[:, jnp.asarray(held)]
             x = v.astype(jnp.float32)
+            start = jnp.sum(gates[:, cfg.num_experts:], axis=1)[:, None] * x  # the identity experts' term (none: zeros)
 
             def one(acc, w):
                 w1, w3, w2, g = w
                 up, gate_up = (jnp.dot(x, m.astype(jnp.float32), precision=hi) for m in (w1, w3))
                 return acc + jnp.dot(jax.nn.silu(up) * gate_up * g[:, None], w2.astype(jnp.float32), precision=hi), None
-            return jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32), (layer["ew1"], layer["ew3"], layer["ew2"], mine.T))[0]
+            return jax.lax.scan(one, start, (layer["ew1"], layer["ew3"], layer["ew2"], mine.T))[0]
 
         forms = {"dense": traced_as("dense"), "grouped": traced_as("grouped"), "grouped_ragged_dot": jax.jit(other_candidate)}
         for rows in c.get("rows", EXPERT_ROWS):
             v = jax.random.normal(jax.random.key(rows), (rows, c["hidden"]), jnp.bfloat16)
             want = jax.jit(composition)(layer, v)
-            line = {"rule": rule(rows, c["held"], c["k"]), "largest_value": float(jnp.max(jnp.abs(want)))}
+            line = {"rule": rule(rows, c["held"], c["k"], outputs), "largest_value": float(jnp.max(jnp.abs(want)))}
             for form, call in forms.items():
                 try:
                     got = jax.block_until_ready(call(layer, v))
@@ -1547,6 +1558,8 @@ def main(argv=None) -> int:
                     help="run the sharded legs on a four-chip host instead")
     ap.add_argument("--latent-kernel", action="store_true",
                     help="the latent paged kernel alone, at the latent cell's sizes")
+    ap.add_argument("--latent", type=int, default=None, metavar="HEADS", choices=sorted(LATENT_CALLS),
+                    help="the latent paged kernel alone, at the sizes of the latent cell whose layers have HEADS heads")
     ap.add_argument("--expert-product", nargs="*", default=None, metavar="LAYER", choices=sorted(EXPERT_LAYERS),
                     help="the routed experts' sum alone: dense against grouped, one layer of each expert cell (or of those named)")
     ap.add_argument("--group16", action="store_true",
@@ -1585,8 +1598,8 @@ def main(argv=None) -> int:
     rs = np.random.RandomState(SEED)
     if args.four_chips:
         summary["four_chips"] = four_chip_phase(rs)
-    elif args.latent_kernel:
-        summary["kernels"] = {"latent": latent_kernel_check()}
+    elif args.latent_kernel or args.latent:
+        summary["kernels"] = {"latent": latent_kernel_check(LATENT_CALLS[args.latent or 32])}
     elif args.group16:
         summary["kernels"] = {"grouped": grouped_kernels_check(GROUP16_CALLS), "prefill_stream": stream_kernel_check(),
                               "materialised_prefill": materialised_calls_check()}
@@ -1622,7 +1635,7 @@ def main(argv=None) -> int:
     log(f"compile cache: {entries_before} -> {entries_after} entries; wall {summary['wall_s']}s")
     out_dir = REPO / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
-    name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel
+    name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel or args.latent
             else "chip_smoke_group16.json" if args.group16 else "chip_smoke_experts.json" if args.expert_product is not None
             else "chip_smoke_release.json" if args.release_probe else "chip_smoke_dispatch.json" if args.dispatch_probe
             else "chip_smoke.json")

@@ -49,7 +49,24 @@ setting of every one of them):
   ``experts_held`` names the routed experts whose weights
   THIS engine holds (one chip's share of an expert-sharded deployment:
   the router scores all of them, the sum runs over the held ones, and
-  nothing stands in for the absent chips' part);
+  nothing stands in for the absent chips' part). A softmax router may
+  have a selection bias too (``router_softmax_bias``), gates that are
+  NOT renormalised (``router_renormalise`` False: ``g_i = s_i x
+  routed_scaling_factor``), and behind its ``num_experts`` outputs
+  ``zero_experts`` more that are identity experts: a pick of one adds
+  ``g_i x v``, the experts' own input, and multiplies nothing (they hold
+  no weights, so under a share every chip computes their term whole);
+* a routed branch on a SHORTCUT (``shortcut_experts`` = the period P, in
+  layers): every layer has its dense feed-forward, and every P-th, from
+  the first, also routes that feed-forward's normed input ``u`` through
+  the experts; the branch's sum ``R`` is carried past the next ``P - 1``
+  layers and lands in the residual behind the LAST feed-forward of the
+  period: ``x_l = h_l + D_l(u_l)``, ..., ``x_{l+P-1} = h_{l+P-1} +
+  D_{l+P-1}(u_{l+P-1}) + R(u_l)``. With P = 2 and latent layers this is a
+  published layer of two attentions and two dense feed-forwards whose
+  experts hide their exchange behind the second pair. The stack counts
+  SUB-layers: the cache, ``kv_index`` and the engine see ``num_layers``
+  ordinary layers;
 * dtype: the weights' own. Every matmul accumulates in float32 and
   hands its result on in the activations' type; norms, softmax, the
   rotary angles and the router are computed in float32.
@@ -70,7 +87,10 @@ input: ``c_q = RMSNorm(h W_DQ)``, ``q = c_q W_UQ`` -> per head ``[q_nope
 (qk_nope_head_dim), q_rope (qk_rope_head_dim)]``; ``[c_kv (kv_lora_rank),
 k_r (qk_rope_head_dim)] = h W_DKV``, ``c = RMSNorm(c_kv)``; ``q_rope``
 and the ONE ``k_r`` all heads share rotated over interleaved pairs
-``(2i, 2i+1)``; ``[k_nope_i, v_i] = c W_UKV`` per head. The cache holds
+``(2i, 2i+1)``; ``[k_nope_i, v_i] = c W_UKV`` per head
+(``latent_q_scale`` multiplies ``q`` behind ``W_UQ`` and
+``latent_kv_scale`` the normed ``c``, before ``W_UKV`` and before the
+cache: the row holds the scaled ``c``, and ``k_r`` is not scaled). The cache holds
 a position's ``[c, k_r]`` (after the norm and the rotation), one row of
 ``kv_lora_rank + qk_rope_head_dim`` values stored at the next multiple of
 128 lanes (generation/cache.py), and the layer has TWO forms that are
@@ -78,8 +98,9 @@ equal in exact arithmetic:
 
 * *expanded* (``prefill``): K and V are expanded per head out of the
   rows, ``s_i(t, j) = (q_nope_i(t) . k_nope_i(j) + q_rope_i(t) . k_r(j))
-  / sqrt(qk_nope + qk_rope)``, through :func:`masked_attention` (score
-  width and value width differ);
+  / sqrt(qk_nope + qk_rope)``, through ``prefill_attention`` as any
+  prefill's attention goes (score width and value width differ; past the
+  score bound it streams, and no ``[H, S, S]`` value exists);
 * *absorbed* (``decode_step``, ``verify_step`` and with it the suffix
   prefill behind a prefix hit): ``W_UK`` goes into the query, ``q~_i =
   q_nope_i W_UK_i^T``, the scores are ``(q~_i . c(j) + q_rope_i . k_r(j))``
@@ -125,7 +146,7 @@ import jax.numpy as jnp
 from ..core.types import DataType
 from ..models.transformer import TransformerConfig
 from ..ops.attention import (
-    append_attention_core, decode_attention_core, latent_attention_core, masked_attention, prefill_attention,
+    append_attention_core, decode_attention_core, latent_attention_core, prefill_attention,
 )
 from ..ops.expert_product import expert_lowering, grouped_expert_sum
 from ..ops.kernels.decode_attention import latent_row_width
@@ -181,6 +202,21 @@ class DecoderConfig(TransformerConfig):
     # the routed experts whose weights this engine holds, in the order
     # ew1 / ew3 / ew2 stack them; (): all `num_experts` of them
     experts_held: Tuple[int, ...] = ()
+    # a routed branch beside the dense feed-forwards (module docstring):
+    # every `shortcut_experts`-th sub-layer, from the first, also routes
+    # its feed-forward's normed input through the experts, and that sum
+    # lands in the residual behind the feed-forward of the LAST sub-layer
+    # of its period. 0: no such branch
+    shortcut_experts: int = 0
+    # router outputs behind the `num_experts` real ones that are identity
+    # experts: a pick of one adds `gate x (the experts' input)`
+    zero_experts: int = 0
+    router_renormalise: bool = True  # gates divided by the picked sum; False: `s_i x routed_scaling_factor`
+    router_softmax_bias: bool = False  # a softmax router's selection bias (the choice only, never the gate)
+    # a latent layer's LoRA scales: on q behind W_UQ, and on the normed c
+    # (the cached row holds the scaled c)
+    latent_q_scale: float = 1.0
+    latent_kv_scale: float = 1.0
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.num_layers:
@@ -211,6 +247,15 @@ class DecoderConfig(TransformerConfig):
         self.experts_held = tuple(int(i) for i in self.experts_held)
         if self.experts_held and not all(0 <= i < self.num_experts for i in self.experts_held):
             raise ValueError(f"experts_held {self.experts_held} outside the {self.num_experts} routed experts")
+        if self.shortcut_experts:
+            if self.block != "sequential" or self.num_dense_layers >= 0 or self.num_layers % self.shortcut_experts:
+                raise ValueError(
+                    "a shortcut expert branch runs beside dense feed-forwards of a sequential block, over whole periods "
+                    f"(block {self.block!r}, num_dense_layers {self.num_dense_layers}, {self.num_layers} layers, "
+                    f"period {self.shortcut_experts})"
+                )
+        if self.zero_experts < 0 or self.router_softmax_bias and self.router != "softmax":
+            raise ValueError("zero_experts counts router outputs; router_softmax_bias is a softmax router's")
 
     @property
     def kv_heads(self) -> int:
@@ -270,9 +315,25 @@ class DecoderConfig(TransformerConfig):
     def conv_layers(self) -> Tuple[int, ...]:
         return tuple(l for l in range(self.num_layers) if self.operator(l) == "conv")
 
+    def shortcut(self, layer: int) -> bool:
+        """``layer`` carries a shortcut expert branch (beside its dense
+        feed-forward)."""
+        return bool(self.shortcut_experts) and layer % self.shortcut_experts == 0
+
     @property
     def expert_layers(self) -> Tuple[int, ...]:
-        return tuple(l for l in range(self.num_layers) if self.ffn_kind(l) == "experts")
+        """Layers that route: their feed-forward is the experts, or they
+        carry a shortcut branch."""
+        return tuple(l for l in range(self.num_layers) if self.ffn_kind(l) == "experts" or self.shortcut(l))
+
+    @property
+    def router_outputs(self) -> int:
+        return self.num_experts + self.zero_experts
+
+    @property
+    def expert_count_columns(self) -> int:
+        """Width of an expert layer's counter row (:func:`_count_row`)."""
+        return self.held_experts + bool(self.experts_held) + (2 + self.experts_per_token if self.zero_experts else 0)
 
     @property
     def stateful(self) -> bool:
@@ -322,7 +383,7 @@ def init_decoder_params(
     f, v = cfg.ff_size, cfg.vocab_size
     p = max_positions or cfg.seq_length
     # (a configuration without latent layers or shared experts draws the keys it always drew)
-    per_layer = 14 if cfg.latent_layers or cfg.num_shared_experts else 10
+    per_layer = 14 if cfg.latent_layers or cfg.num_shared_experts or cfg.shortcut_experts else 10
     keys = iter(jax.random.split(rng, 4 + per_layer * cfg.num_layers))
     ones, zeros = jnp.ones((e,), dt), jnp.zeros((e,), dt)
     params: DecoderParams = {"tok_embed": _glorot(next(keys), (v, e), dt)}
@@ -375,11 +436,13 @@ def init_decoder_params(
                 w1=_glorot(next(keys), (e, f), dt), w3=_glorot(next(keys), (e, f), dt),
                 w2=_glorot(next(keys), (f, e), dt),
             )
-        else:
-            n, fe = cfg.num_experts, cfg.moe_ff_size
+        if kind == "experts" or cfg.shortcut(li):
+            n, fe = cfg.router_outputs, cfg.moe_ff_size
             layer.update(router=_glorot(next(keys), (e, n)))
             if cfg.router == "sigmoid" and cfg.router_selection_bias:
                 layer.update(router_bias=0.02 * jax.random.normal(next(keys), (n,), jnp.float32))
+            elif cfg.router_softmax_bias:  # a tenth of a uniform pick's probability: it moves near-ties
+                layer.update(router_bias=0.1 / n * jax.random.normal(next(keys), (n,), jnp.float32))
             n = cfg.held_experts  # the router scores every expert; the weights are the held ones'
             layer.update(
                 ew1=_glorot(next(keys), (n, e, fe), dt), ew3=_glorot(next(keys), (n, e, fe), dt),
@@ -506,31 +569,40 @@ def _rope_pairs(x, positions, theta: float):
 def _latent_qkv(cfg: DecoderConfig, layer, h, positions):
     """A latent layer's projections (module docstring): the queries
     [..., H, qk_nope + qk_rope], their rotary part rotated, and the
-    position's cache row [..., RW]: ``[c, k_r]`` after the norm and the
-    rotation, zero-filled to the stored width."""
+    position's cache row [..., RW]: ``[c, k_r]`` after the norm, the
+    scale (``latent_kv_scale``, on ``c`` alone) and the rotation,
+    zero-filled to the stored width. ``latent_q_scale`` multiplies the
+    queries behind ``W_UQ``."""
     dn, rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
     rotate = _rope_pairs if cfg.rope_interleave else _rope
     c_q = _mm("...e,er->...r", h, layer["w_dq"])
     c_q = _rms(c_q.astype(jnp.float32), layer["q_lora_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
     q = _mm("...r,rhd->...hd", c_q, layer["w_uq"])
+    if cfg.latent_q_scale != 1.0:
+        q = (q.astype(jnp.float32) * cfg.latent_q_scale).astype(h.dtype)
     q = jnp.concatenate([q[..., :dn], rotate(q[..., dn:], positions, cfg.rope_theta)], axis=-1)
     kv = _mm("...e,er->...r", h, layer["w_dkv"])
-    c = _rms(kv[..., :rkv].astype(jnp.float32), layer["kv_lora_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
+    c = _rms(kv[..., :rkv].astype(jnp.float32), layer["kv_lora_g"].astype(jnp.float32), cfg.norm_eps)
+    if cfg.latent_kv_scale != 1.0:  # the row holds the scaled c; k_r is not scaled
+        c = c * cfg.latent_kv_scale
+    c = c.astype(h.dtype)
     k_r = rotate(kv[..., None, rkv:], positions, cfg.rope_theta)[..., 0, :]  # ONE rotary key, all heads'
     fill = jnp.zeros(kv.shape[:-1] + (latent_row_width(cfg.latent_width) - cfg.latent_width,), h.dtype)
     return q, jnp.concatenate([c, k_r, fill], axis=-1)
 
 
-def _expanded(cfg: DecoderConfig, q, rows, w_ukv, lens):
+def _expanded(cfg: DecoderConfig, q, rows, w_ukv, lens, backend: str = "cpu"):
     """The latent layer's EXPANDED form over a whole window of rows
-    ([B, S, RW]): K and V per head out of the rows, then masked causal
-    attention at score width ``qk_nope + qk_rope`` and value width
-    ``v_head_dim``. Returns [B, S, H, v_head_dim]."""
+    ([B, S, RW]): K and V per head out of the rows, then a prefill's
+    causal attention (ops/attention.py ``prefill_attention``: scores
+    materialised while they stay under its bound, streamed past it) at
+    score width ``qk_nope + qk_rope`` and value width ``v_head_dim``.
+    Returns [B, S, H, v_head_dim]."""
     rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     kv = _mm("bsc,chd->bshd", rows[..., :rkv], w_ukv)
     k_r = jnp.broadcast_to(rows[:, :, None, rkv:cfg.latent_width], kv.shape[:3] + (cfg.qk_rope_head_dim,))
     k = jnp.concatenate([kv[..., :dn], k_r], axis=-1)
-    return masked_attention(q, k, kv[..., dn:], lens, causal=True)
+    return prefill_attention(q, k, kv[..., dn:], lens, backend=backend)
 
 
 def _absorbed(cfg: DecoderConfig, q, w_ukv, cache, at: int, tables, q_positions, backend: str):
@@ -585,15 +657,24 @@ def route(cfg: DecoderConfig, layer, v):
     ``I = top_k(s + b)``, ``g_i = s_i / (sum_{j in I} s_j + 1e-6) *
     routed_scaling_factor``. The bias moves the choice, never the gate.
     A ``softmax`` router: ``s = softmax(W_g v)`` over the experts, ``I =
-    top_k(s)``, ``g_i = s_i / sum_{j in I} s_j * routed_scaling_factor``.
+    top_k(s)`` (``router_softmax_bias``: ``top_k(s + b)``), ``g_i = s_i /
+    sum_{j in I} s_j * routed_scaling_factor``.
     A sigmoid router without a selection bias (``router_selection_bias``
     False): ``I = top_k(s)`` and the same gates over ``s = sigmoid(W_g
-    v)``."""
+    v)``. ``router_renormalise`` False: ``g_i = s_i *
+    routed_scaling_factor``, whatever the picked scores add up to.
+    ``N`` is ``cfg.router_outputs``: the real experts and, behind them,
+    the ``zero_experts`` identity ones."""
     scores = jnp.dot(v.astype(jnp.float32), layer["router"].astype(jnp.float32), precision=_ROUTER_PRECISION)
     if cfg.router == "softmax" or not cfg.router_selection_bias:
         s = jax.nn.softmax(scores, axis=-1) if cfg.router == "softmax" else jax.nn.sigmoid(scores)
-        picked, chosen = jax.lax.top_k(s, cfg.experts_per_token)
-        gate = picked / jnp.sum(picked, axis=-1, keepdims=True) * cfg.routed_scaling_factor
+        if cfg.router_softmax_bias:
+            _, chosen = jax.lax.top_k(s + layer["router_bias"].astype(jnp.float32), cfg.experts_per_token)
+            picked = jnp.take_along_axis(s, chosen, axis=-1)
+        else:
+            picked, chosen = jax.lax.top_k(s, cfg.experts_per_token)
+        gate = picked / jnp.sum(picked, axis=-1, keepdims=True) if cfg.router_renormalise else picked
+        gate = gate * cfg.routed_scaling_factor
         return jnp.zeros_like(s).at[jnp.arange(v.shape[0])[:, None], chosen].set(gate), chosen
     s = jax.nn.sigmoid(scores)
     _, chosen = jax.lax.top_k(s + layer["router_bias"].astype(jnp.float32), cfg.experts_per_token)
@@ -603,7 +684,7 @@ def route(cfg: DecoderConfig, layer, v):
     return jnp.zeros_like(s).at[rows, chosen].set(gate), chosen
 
 
-def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = None, live=None):
+def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = None, live=None, routed=None):
     """The routed feed-forward of rows ``v`` [T, E]: ``sum_{i in I} g_i
     W2_i (silu(W1_i v) * W3_i v)``, exactly (no capacity, no dropped
     token), and the gates it used ([T, N], for the counters).
@@ -622,12 +703,27 @@ def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = Non
     expert) pairs grouped by expert, which also skips rows that are not
     ``live`` ([T] bool: padding behind a prompt's length, whose result
     nothing reads; they get zeros).
+
+    ``cfg.zero_experts``: the router's outputs behind the real experts
+    are identity experts, and the result gains ``(sum of their gates) x
+    v``, added in float32 before the one cast in both lowerings. They
+    have no weights, so the term is whole under any ``held``.
+    ``routed``: :func:`route`'s result for these rows, where the caller
+    has it (and has named it apart in the program).
     """
-    gates, chosen = route(cfg, layer, v)
-    if expert_lowering(v.shape[0], layer["ew1"].shape[0], cfg.experts_per_token) == "grouped":
-        out = grouped_expert_sum(v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, live=live)
+    gates, chosen = routed if routed is not None else route(cfg, layer, v)
+    # identity experts (the router's outputs behind the real ones): a pick
+    # adds gate x v, whatever is held: they have no weights
+    identity = jnp.sum(gates[:, cfg.num_experts:], axis=1) if cfg.zero_experts else None
+    if expert_lowering(v.shape[0], layer["ew1"].shape[0], cfg.experts_per_token, gates.shape[1]) == "grouped":
+        out = grouped_expert_sum(
+            v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, live=live, identity=identity,
+        )
         return out, gates
-    mine = gates if held is None else gates[:, jnp.asarray(tuple(held))]
+    if held is not None:
+        mine = gates[:, jnp.asarray(tuple(held))]
+    else:
+        mine = gates[:, : cfg.num_experts] if cfg.zero_experts else gates
     # every expert multiplies every row, masked by the gate: the weights
     # are read once either way, and at a decode step's or a short
     # bucket's rows the 8 x multiply-adds hide behind those reads
@@ -635,6 +731,9 @@ def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = Non
     gate_up = jnp.einsum("te,nef->ntf", v, layer["ew3"], preferred_element_type=jnp.float32)
     hidden = (jax.nn.silu(up) * gate_up * mine.T[:, :, None]).astype(v.dtype)
     out = jnp.einsum("ntf,nfe->te", hidden, layer["ew2"], preferred_element_type=jnp.float32)
+    if identity is not None:
+        with jax.named_scope("experts.zero"):
+            out = out + identity[:, None] * v.astype(jnp.float32)
     return out.astype(v.dtype), gates
 
 
@@ -644,6 +743,47 @@ def _swiglu(h, w1, w3, w2):
     up = jnp.einsum("...e,ef->...f", h, w1, preferred_element_type=jnp.float32)
     gate_up = jnp.einsum("...e,ef->...f", h, w3, preferred_element_type=jnp.float32)
     return _mm("...f,fe->...e", (jax.nn.silu(up) * gate_up).astype(h.dtype), w2)
+
+
+def _count_row(cfg: DecoderConfig, gates, live):
+    """An expert layer's counter row from its ``gates`` [T, N] and the
+    rows that are ``live`` (over T, any shape): the tokens every expert was chosen for;
+    under a share (``experts_held``) the held ones' tokens, in the order
+    they are held, and in one more column the live tokens none of them
+    was chosen for. With ``zero_experts``, behind those: the picks that
+    went to an identity expert, and ``experts_per_token + 1`` columns
+    that count the live tokens by how many REAL experts they picked (0 ..
+    k: the model's compute a token, as a histogram)."""
+    picked = (gates > 0) & live.reshape(-1, 1)
+    chosen = real = picked[:, : cfg.num_experts] if cfg.zero_experts else picked  # (a real expert's columns)
+    if cfg.experts_held:
+        chosen = chosen[:, jnp.asarray(cfg.experts_held)]
+        nowhere = jnp.sum(live.reshape(-1) & ~jnp.any(chosen, axis=1), dtype=jnp.int32)
+        row = jnp.concatenate([jnp.sum(chosen, axis=0, dtype=jnp.int32), nowhere[None]])
+    else:
+        row = jnp.sum(chosen, axis=0, dtype=jnp.int32)
+    if cfg.zero_experts:
+        per_token = jnp.sum(real, axis=1)  # [T]: real experts a token picked
+        hist = jnp.sum((per_token[:, None] == jnp.arange(cfg.experts_per_token + 1)) & live.reshape(-1, 1), axis=0, dtype=jnp.int32)
+        zero = jnp.sum(picked[:, cfg.num_experts:], dtype=jnp.int32)
+        row = jnp.concatenate([row, zero[None], hist])
+    return row
+
+
+def _shortcut(cfg: DecoderConfig, layer, u, live, counts: Optional[List]):
+    """A shortcut branch's routed sum of ``u`` (the normed input of the
+    sub-layer's dense feed-forward, [..., E]), for :func:`_layers` to add
+    behind the last sub-layer of the period: the held real experts' sum
+    and the identity experts' term (:func:`expert_ffn`)."""
+    rows, alive = u.reshape(-1, u.shape[-1]), live.reshape(-1)
+    with jax.named_scope("router"):
+        routed = route(cfg, layer, rows)
+    with jax.named_scope("experts.shortcut"):
+        out, gates = expert_ffn(cfg, layer, rows, held=cfg.experts_held or None, live=alive, routed=routed)
+    if counts is not None:
+        with jax.named_scope("router"):
+            counts.append(_count_row(cfg, gates, alive))
+    return out.reshape(u.shape)
 
 
 def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List], normed=None):
@@ -668,16 +808,7 @@ def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List], no
                 out = out + shared
         if counts is not None:
             with jax.named_scope("router"):
-                chosen = (gates > 0) & live.reshape(-1, 1)
-                if cfg.experts_held:
-                    # a share of the experts: the held ones' tokens, in the order they are
-                    # held, and in one more column the live tokens none of them was chosen for
-                    chosen = chosen[:, jnp.asarray(cfg.experts_held)]
-                    nowhere = jnp.sum(live.reshape(-1) & ~jnp.any(chosen, axis=1), dtype=jnp.int32)
-                    row = jnp.concatenate([jnp.sum(chosen, axis=0, dtype=jnp.int32), nowhere[None]])
-                else:
-                    row = jnp.sum(chosen, axis=0, dtype=jnp.int32)
-                counts.append(row)
+                counts.append(_count_row(cfg, gates, live))
         return x + out.reshape(x.shape) if normed is None else out.reshape(x.shape)
     with jax.named_scope("mlp"):
         h = _norm(cfg, x, layer, "ln2") if normed is None else normed
@@ -712,7 +843,9 @@ def _layers(
     Scope names land in the instructions' op_name, so a device trace can
     be grouped by them: ``layer<i>/attention | cache_write | conv |
     conv_state | mlp | router | experts | shared_expert`` (``shared_experts``
-    where there are several); a parallel block's norm and its one sum are
+    where there are several; a shortcut branch's held experts' sum and the
+    add that lands it are ``experts.shortcut``, the identity experts' term
+    inside it ``experts.zero``); a parallel block's norm and its one sum are
     ``block.parallel``; a configuration
     with window layers names the two kinds apart, ``attention.window``
     and ``attention.full`` (:func:`attention_scope`); a latent layer is
@@ -768,7 +901,19 @@ def _layers(
                     c = _conv_mix(layer, zpad, t).reshape(z.shape)
                     x = x + _mm("...e,ef->...f", c_ * c, layer["conv_out"])
                 ci += 1
-            x = _ffn(cfg, li, layer, x, live, counts)
+            if not cfg.shortcut_experts:
+                x = _ffn(cfg, li, layer, x, live, counts)
+                continue
+            # a dense feed-forward, and from the first sub-layer of a period a routed
+            # branch off the same normed input, added behind the period's last
+            with jax.named_scope("mlp"):
+                u = _norm(cfg, x, layer, "ln2")
+            if cfg.shortcut(li):
+                branch = _shortcut(cfg, layer, u, live, counts)
+            x = x + _ffn(cfg, li, layer, x, live, counts, normed=u)
+            if cfg.shortcut(li + 1):  # (the period's last sub-layer)
+                with jax.named_scope("experts.shortcut"):
+                    x = x + branch
     return x
 
 
@@ -823,7 +968,7 @@ def prefill(
             ks.append(k)  # the rows, as stored; V has no width
             vs.append(k[..., :0])
             with jax.named_scope("attention.latent.expand"):
-                return _expanded(cfg, q, k, v, lens)
+                return _expanded(cfg, q, k, v, lens, backend)
         ks.append(k)
         vs.append(v)
         with jax.named_scope(attention_scope(cfg, kind)), jax.named_scope("prefill_attention"):

@@ -270,6 +270,23 @@ def unsupported_paths(kind: str, dcfg) -> Dict[str, str]:
                 "the vocabulary, and the head-sharded paged kernel takes no window"
             ),
         },
+        "shortcut": {
+            "speculation": (
+                "speculative verification (engine.verify) is refused for a configuration with a shortcut expert "
+                "branch: it is served over latent layers, whose verify program takes K and V of one head width, "
+                "and no drafter of such a model is loaded"
+            ),
+            "kv_handoff": (
+                "the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a configuration "
+                "with a shortcut expert branch: it is served over latent layers, and a latent block is one array "
+                "of rows that no importing engine's shape check knows"
+            ),
+            "tensor_parallel": (
+                "tp_degree > 1 is refused for a configuration with a shortcut expert branch: the serving layout "
+                "has no placement for data-parallel latent attention and dense feed-forwards beside a share of "
+                "the experts, nor for the branch's sum held across a sub-layer"
+            ),
+        },
         "latent": {
             "speculation": (
                 "speculative verification (engine.verify) is refused for a configuration with latent "
@@ -395,6 +412,8 @@ class GenerationEngine:
                 self.unsupported.update(unsupported_paths(kind, self.dcfg))
         if self.dcfg.block == "parallel":
             self.unsupported.update(unsupported_paths("parallel", self.dcfg))
+        if self.dcfg.shortcut_experts:
+            self.unsupported.update(unsupported_paths("shortcut", self.dcfg))
         if wants_tp and "tensor_parallel" in self.unsupported:
             raise NotImplementedError(self.unsupported["tensor_parallel"])
         # ------------------------------------------------- serving mesh
@@ -552,10 +571,9 @@ class GenerationEngine:
         self.expert_counts: Dict[str, jax.Array] = {}
         if self.dcfg.expert_layers:
             self.expert_counts = {
-                # (a share of the experts: the held ones' columns and one more, the tokens none of them was chosen for)
-                "tokens": jnp.zeros(
-                    (len(self.dcfg.expert_layers), self.dcfg.held_experts + bool(self.dcfg.experts_held)), jnp.int32
-                ),
+                # (a share of the experts: the held ones' columns and one more, the tokens none of them was
+                # chosen for; identity experts: their picks and the tokens by real experts picked: decoder._count_row)
+                "tokens": jnp.zeros((len(self.dcfg.expert_layers), self.dcfg.expert_count_columns), jnp.int32),
                 "calls": jnp.zeros((2,), jnp.int32),  # decode, prefill
             }
         # step programs whose expert layers took the grouped form
@@ -2679,15 +2697,27 @@ class GenerationEngine:
         the step in flight): a scrape pays for it, a step never does."""
         tokens = np.asarray(self.expert_counts["tokens"]).astype(np.int64)
         calls = np.asarray(self.expert_counts["calls"])
-        held: Dict = {}
+        extra: Dict = {}
+        if self.dcfg.zero_experts:
+            # identity experts: behind the experts' columns, the picks that went to one and the tokens
+            # by how many REAL experts they picked (0 .. k), per layer and summed
+            k = self.dcfg.experts_per_token
+            zero, real, tokens = tokens[:, -k - 2], tokens[:, -k - 1:], tokens[:, : -k - 2]
+            picks = int(real.sum()) * k
+            extra = {
+                "zero_experts": int(self.dcfg.zero_experts), "zero_picks_total": int(zero.sum()),
+                "zero_picks_total_by_layer": [int(z) for z in zero],
+                "zero_pick_share": float(zero.sum()) / picks if picks else 0.0,
+                "real_experts_per_token_total": [int(n) for n in real.sum(axis=0)],
+            }
         if self.dcfg.experts_held:
             # a share of the experts: `tokens_total` lists the held ones
             # (in the order `held` names them), and the last column the
             # tokens none of the held was chosen for, counted once a layer
-            held = {"held": [int(i) for i in self.dcfg.experts_held], "unrouted_here_total": int(tokens[:, -1].sum())}
+            extra.update(held=[int(i) for i in self.dcfg.experts_held], unrouted_here_total=int(tokens[:, -1].sum()))
             tokens = tokens[:, :-1]
         return {
-            **held,
+            **extra,
             "layers": [int(l) for l in self.dcfg.expert_layers],
             "experts": int(self.dcfg.num_experts),
             "experts_per_token": int(self.dcfg.experts_per_token),
@@ -2705,7 +2735,7 @@ class GenerationEngine:
         ``decoder.expert_ffn`` asks (ops/expert_product.py)."""
         if rows not in self._expert_forms:
             held = self.params["layers"][self.dcfg.expert_layers[0]]["ew1"].shape[0]
-            self._expert_forms[rows] = expert_lowering(rows, held, self.dcfg.experts_per_token)
+            self._expert_forms[rows] = expert_lowering(rows, held, self.dcfg.experts_per_token, self.dcfg.router_outputs)
         return self._expert_forms[rows]
 
     def _count_expert_form(self, rows: int) -> None:
@@ -2754,14 +2784,15 @@ class GenerationEngine:
         ``prefill_call_lowering``)."""
         if bucket not in self._prefill_lowerings:
             d = self.dcfg
+            # (a latent layer's expanded form: every head has K of its own, at the score's width)
+            heads, width = (d.num_heads, d.qk_nope_head_dim + d.qk_rope_head_dim) if self._n_latent else (d.kv_heads, d.dim_per_head)
             self._prefill_lowerings[bucket] = prefill_call_lowering(
-                (1, bucket, d.num_heads, d.dim_per_head), (1, bucket, d.kv_heads, d.dim_per_head),
-                d.dtype.size_bytes, backend=self.backend,
+                (1, bucket, d.num_heads, width), (1, bucket, heads, width), d.dtype.size_bytes, backend=self.backend,
             )
         return self._prefill_lowerings[bucket]
 
     def _count_prefill_attention(self, bucket: int, tokens: int) -> None:
-        n = len(self.dcfg.attention_layers) - self._n_latent
+        n = len(self.dcfg.attention_layers)
         if not n:
             return
         low, calls = self.prefill_lowering(bucket), self.prefill_attention_calls
@@ -2776,7 +2807,7 @@ class GenerationEngine:
     def prefill_attention_stats(self) -> Dict:
         """The ``prefill_attention`` section of ``/v2/stats``: the
         attention calls whole-prompt prefills made (one a layer with K/V
-        of heads), the query rows they scored, how many took the streamed
+        of heads or a latent row), the query rows they scored, how many took the streamed
         form and how many materialised their scores, the prompt tokens
         those prefills took, what was refused, by reason, and why prefix
         reuse is off where it is (``unsupported["prefix_reuse"]``)."""
@@ -2787,10 +2818,10 @@ class GenerationEngine:
             "refused_total": dict(self.prefill_attention_refused),
             # decided at construction: why no prefix hit is taken (None where hits are)
             "prefix_reuse_refused": self.unsupported.get("prefix_reuse"),
-            # per ``prefill[N]`` program the form and the kernel of its calls (none where the
-            # layers are latent: their expanded form has a call of its own)
+            # per ``prefill[N]`` program the form and the kernel of its calls (a latent
+            # layer's: of its expanded form, scored at qk_nope + qk_rope)
             "programs": {f"prefill[{b}]": dict(self.prefill_lowering(b)) for b in self.buckets}
-            if len(self.dcfg.attention_layers) > self._n_latent else {},
+            if self.dcfg.attention_layers else {},
         }
 
     def sampling_stats(self) -> Dict:

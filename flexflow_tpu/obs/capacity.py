@@ -351,7 +351,10 @@ class ServingFlops:
         position ``latent_width`` multiply-adds for the score and
         ``kv_lora_rank`` for the value), and ONE row a position in the
         cache, at its stored width; the shared experts every token goes
-        through; of the routed experts the bytes of those held here."""
+        through; of the routed experts the bytes of those held here. A
+        shortcut expert branch (``shortcut_experts``): beside the layer's
+        dense feed-forward, the router, the picks that land on a held
+        expert and the identity experts' picks at ``2 E`` flops each."""
         model = cls(
             num_layers=cfg.num_layers,
             hidden_size=cfg.hidden_size,
@@ -386,6 +389,14 @@ class ServingFlops:
                     (cfg.held_experts + cfg.num_shared_experts) * per_expert + e * cfg.num_experts)
             else:
                 ffn = ffn_params = (3 if kind == "swiglu" else 2) * e * cfg.ff_size
+            if cfg.shortcut(l):
+                # a routed branch BESIDE the dense feed-forward: the router over all its outputs; of a
+                # token's k picks those that land on a held expert (uniform picks: k x held / outputs)
+                # and those on an identity expert, which is E multiply-adds a pick and no weight
+                per_expert, outputs = 3 * e * cfg.moe_ff_size, cfg.router_outputs
+                landed, zero = (cfg.experts_per_token * n / outputs for n in (cfg.held_experts, cfg.zero_experts))
+                ffn += int(landed * per_expert + zero * e) + e * outputs
+                ffn_params += cfg.held_experts * per_expert + e * outputs
             flops += 2 * (op + ffn)
             params += op + ffn_params
         model.per_token_flops = flops

@@ -466,8 +466,10 @@ def prefill_call_lowering(q_shape, k_shape, itemsize: int, backend: str = "tpu")
 
 def prefill_attention(q, k, v, lengths, window: int = 0, backend: str = "cpu"):
     """A prefill's causal attention over [B, S, H, D] (grouped K/V, a
-    valid length a sequence, ``window`` > 0 for a sliding-window layer),
-    in the lowering :func:`prefill_call_lowering` names."""
+    valid length a sequence, ``window`` > 0 for a sliding-window layer;
+    ``v``'s width may differ from the scores', whose scale is of ``q``'s
+    width: a latent layer's expanded form), in the lowering
+    :func:`prefill_call_lowering` names."""
     low = prefill_call_lowering(q.shape, k.shape, q.dtype.itemsize, backend)
     if low["form"] == "materialised":
         return masked_attention(q, k, v, lengths, causal=True, window=window)

@@ -551,9 +551,11 @@ def reference_prefill_stream_attention(
     """:func:`prefill_stream_attention`'s arithmetic as an XLA
     composition: a scan over chunks of query rows, each scored against
     the ``chunk + window - 1`` keys its rows can reach (a full layer's:
-    the sequence), masked and weighed as ``masked_attention`` does it.
-    The largest temporary is one chunk's scores."""
+    the sequence), masked and weighed as ``masked_attention`` does it
+    (``v``'s width may differ from the scores': a latent layer's expanded
+    form). The largest temporary is one chunk's scores."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     hk = k.shape[2]
     group = h // hk
     if scale is None:
@@ -580,7 +582,7 @@ def reference_prefill_stream_attention(
         p = jnp.where(mask, jnp.exp(logits - jnp.maximum(m, -1e30)), 0.0)
         l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
         out = jnp.einsum("bhgqk,bkhd->bqhgd", (p / l).astype(v.dtype), vc, preferred_element_type=jnp.float32)
-        return out.reshape(b, chunk, h, d).astype(q.dtype)
+        return out.reshape(b, chunk, h, dv).astype(q.dtype)
 
-    out = jax.lax.map(one, jnp.arange(s // chunk))  # [chunks, B, chunk, H, D]
-    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
+    out = jax.lax.map(one, jnp.arange(s // chunk))  # [chunks, B, chunk, H, Dv]
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, dv)
