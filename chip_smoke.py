@@ -9,6 +9,7 @@
     python chip_smoke.py --release-probe     # what the drop of a consumed step's device arrays waits for
     python chip_smoke.py --dispatch-probe    # ms a decode dispatch (args / upload / call), steady and after a change
     python chip_smoke.py --group16           # the group-16 paged calls and the streamed prefill kernel alone
+    python chip_smoke.py --latent-prefill    # the streamed prefill kernel alone at the 64-head latent cell's call (192 / 128, group 1)
 
 ONE process. It refuses to start unless JAX's first device is a TPU, and
 any failed check raises: the exit code is non-zero and no result line is
@@ -322,6 +323,65 @@ def stream_kernel_check() -> dict:
             row[f"{label}_ms_a_call"] = round((time.perf_counter() - t0) / 5 * 1e3, 3)
         out[name] = row
         log(f"streamed prefill kernel {name}: {row}")
+        del got, want, r
+    return out
+
+
+# The latent layers' expanded prefill call of longcat-flash-chat.agent-turns, at the cell's two buckets past the
+# bound: 64 heads with K/V of their own, scores at 192 (qk_nope 128 + qk_rope 64) beside values of 128, bfloat16.
+LATENT_STREAM_CALLS = {"prefill[3072]": dict(heads=64, score_width=192, value_width=128, seq=3072, length=2900),
+                       "prefill[4096]": dict(heads=64, score_width=192, value_width=128, seq=4096, length=3800)}
+
+
+def latent_stream_check() -> dict:
+    """``prefill_stream_attention`` at :data:`LATENT_STREAM_CALLS` (one
+    query head a K/V head, head-major, two widths), compiled by Mosaic,
+    against the XLA chunks computed in float32 at highest precision from
+    the same bfloat16 operands: the live rows' largest error, and ms a
+    call of the kernel as the program calls it (``kernel``: the
+    contraction over 192 whole), of the same kernel over q and k
+    zero-filled to 256 lanes by the caller (``kernel_padded``: a third
+    more score arithmetic, the same sums) and of the XLA chunks."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.kernels.flash_attention import (
+        prefill_stream_attention, prefill_stream_refusal, reference_prefill_stream_attention,
+    )
+
+    out = {}
+    for name, c in LATENT_STREAM_CALLS.items():
+        kq, kk, kv = jax.random.split(jax.random.key(SEED), 3)
+        q = jax.random.normal(kq, (1, c["seq"], c["heads"], c["score_width"]), jnp.bfloat16)
+        k = jax.random.normal(kk, (1, c["seq"], c["heads"], c["score_width"]), jnp.bfloat16)
+        v = jax.random.normal(kv, (1, c["seq"], c["heads"], c["value_width"]), jnp.bfloat16)
+        lens = jnp.asarray([c["length"]], jnp.int32)
+        reason = prefill_stream_refusal(q.shape, k.shape, 2, v.shape)
+        check(reason is None, f"{name}: the gate refuses the cell's own prefill call: {reason}")
+
+        def padded(q, k, v, n, fill=256 - c["score_width"], scale=c["score_width"] ** -0.5):
+            wide = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, 0), (0, fill)))  # noqa: E731
+            return prefill_stream_attention(wide(q), wide(k), v, n, scale=scale)
+
+        calls = {"kernel": jax.jit(prefill_stream_attention), "kernel_padded": jax.jit(padded),
+                 "xla_chunks": jax.jit(reference_prefill_stream_attention)}
+        exact = jax.jit(lambda q, k, v, n: reference_prefill_stream_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32), n))
+        with jax.default_matmul_precision("highest"):
+            want = exact(q, k, v, lens)[0, : c["length"]]
+        row = {}
+        for label, call in calls.items():
+            got = call(q, k, v, lens)[0, : c["length"]].astype(jnp.float32)
+            worst = float(jnp.max(jnp.abs(got - want)))
+            check(got.shape == want.shape and worst == worst and worst <= 0.05, f"{name} {label}: max err {worst}")
+            row[f"{label}_max_abs_err"] = worst
+            t0 = time.perf_counter()
+            for _ in range(10):
+                r = call(q, k, v, lens)
+            jax.block_until_ready(r)
+            row[f"{label}_ms_a_call"] = round((time.perf_counter() - t0) / 10 * 1e3, 3)
+        out[name] = row
+        log(f"streamed latent prefill call {name}: {row}")
         del got, want, r
     return out
 
@@ -1564,6 +1624,8 @@ def main(argv=None) -> int:
                     help="the routed experts' sum alone: dense against grouped, one layer of each expert cell (or of those named)")
     ap.add_argument("--group16", action="store_true",
                     help="the group-16 paged calls and the streamed prefill kernel alone, at the long-document cell's sizes")
+    ap.add_argument("--latent-prefill", action="store_true",
+                    help="the streamed prefill kernel alone at the 64-head latent cell's two buckets (score width 192, value width 128)")
     ap.add_argument("--release-probe", action="store_true",
                     help="the drop of a finished step's device arrays, timed beside a program in flight and beside woken stream threads")
     ap.add_argument("--dispatch-probe", action="store_true",
@@ -1602,7 +1664,9 @@ def main(argv=None) -> int:
         summary["kernels"] = {"latent": latent_kernel_check(LATENT_CALLS[args.latent or 32])}
     elif args.group16:
         summary["kernels"] = {"grouped": grouped_kernels_check(GROUP16_CALLS), "prefill_stream": stream_kernel_check(),
-                              "materialised_prefill": materialised_calls_check()}
+                              "materialised_prefill": materialised_calls_check(), "latent_prefill_stream": latent_stream_check()}
+    elif args.latent_prefill:
+        summary["kernels"] = {"latent_prefill_stream": latent_stream_check()}
     elif args.expert_product is not None:
         summary["experts"] = expert_product_check(args.expert_product)
     elif args.release_probe:
@@ -1636,7 +1700,8 @@ def main(argv=None) -> int:
     out_dir = REPO / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel or args.latent
-            else "chip_smoke_group16.json" if args.group16 else "chip_smoke_experts.json" if args.expert_product is not None
+            else "chip_smoke_group16.json" if args.group16 else "chip_smoke_latent_prefill.json" if args.latent_prefill
+            else "chip_smoke_experts.json" if args.expert_product is not None
             else "chip_smoke_release.json" if args.release_probe else "chip_smoke_dispatch.json" if args.dispatch_probe
             else "chip_smoke.json")
     (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")

@@ -80,7 +80,9 @@ norm(x)``.
 float32 scores while those stay under 1 GiB a call, and past that the
 streamed form (K/V blocks folded into a running softmax, never a score
 matrix of the sequence's square), which on a TPU is a Pallas call of its
-own name (``prefill_stream_attention``).
+own name (``prefill_stream_attention``: a group of whole tiles of query
+heads a K/V head, or every head with K/V of its own as a latent layer's
+expanded form has them, the score's width beside the value's).
 
 **A latent layer** (multi-head latent attention). With ``h`` the normed
 input: ``c_q = RMSNorm(h W_DQ)``, ``q = c_q W_UQ`` -> per head ``[q_nope
@@ -595,9 +597,10 @@ def _expanded(cfg: DecoderConfig, q, rows, w_ukv, lens, backend: str = "cpu"):
     """The latent layer's EXPANDED form over a whole window of rows
     ([B, S, RW]): K and V per head out of the rows, then a prefill's
     causal attention (ops/attention.py ``prefill_attention``: scores
-    materialised while they stay under its bound, streamed past it) at
-    score width ``qk_nope + qk_rope`` and value width ``v_head_dim``.
-    Returns [B, S, H, v_head_dim]."""
+    materialised while they stay under its bound, streamed past it, on a
+    TPU by the Pallas call ``prefill_stream_attention`` at one query head
+    a K/V head) at score width ``qk_nope + qk_rope`` and value width
+    ``v_head_dim``. Returns [B, S, H, v_head_dim]."""
     rkv, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     kv = _mm("bsc,chd->bshd", rows[..., :rkv], w_ukv)
     k_r = jnp.broadcast_to(rows[:, :, None, rkv:cfg.latent_width], kv.shape[:3] + (cfg.qk_rope_head_dim,))

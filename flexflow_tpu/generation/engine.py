@@ -2784,10 +2784,14 @@ class GenerationEngine:
         ``prefill_call_lowering``)."""
         if bucket not in self._prefill_lowerings:
             d = self.dcfg
-            # (a latent layer's expanded form: every head has K of its own, at the score's width)
-            heads, width = (d.num_heads, d.qk_nope_head_dim + d.qk_rope_head_dim) if self._n_latent else (d.kv_heads, d.dim_per_head)
+            # (a latent layer's expanded form: every head has K of its own, at the score's width, and V at its own)
+            heads, width, value = (
+                (d.num_heads, d.qk_nope_head_dim + d.qk_rope_head_dim, d.v_head_dim) if self._n_latent
+                else (d.kv_heads, d.dim_per_head, d.dim_per_head)
+            )
             self._prefill_lowerings[bucket] = prefill_call_lowering(
                 (1, bucket, d.num_heads, width), (1, bucket, heads, width), d.dtype.size_bytes, backend=self.backend,
+                v_shape=(1, bucket, heads, value),
             )
         return self._prefill_lowerings[bucket]
 

@@ -442,22 +442,24 @@ def latent_attention_core(
 STREAM_SCORE_BYTES = 1 << 30
 
 
-def prefill_call_lowering(q_shape, k_shape, itemsize: int, backend: str = "tpu") -> Dict[str, Optional[str]]:
+def prefill_call_lowering(q_shape, k_shape, itemsize: int, backend: str = "tpu", v_shape=None) -> Dict[str, Optional[str]]:
     """What a prefill's attention call of these shapes lowers to, as
     :func:`prefill_attention` will decide it: ``{"form", "kernel",
     "refused"}``. ``form`` is ``"materialised"`` (:func:`masked_attention`,
     scores of the sequence's square) or ``"streamed"``; a streamed call's
-    ``kernel`` is ``"prefill_stream_attention"`` (the Pallas call) or
-    ``"xla_chunks"`` (the same arithmetic as a scan over chunks of query
-    rows: the CPU backend, and a shape the kernel's gate refuses, whose
-    reason is ``refused``). No call falls from the streamed form to
-    materialised scores."""
+    ``kernel`` is ``"prefill_stream_attention"`` (the Pallas call: a
+    group of whole tiles of query heads a K/V head or of one, a score
+    width the lanes take and a value width of its own, ``v_shape``'s;
+    ``k_shape``'s where none is given) or ``"xla_chunks"`` (the same
+    arithmetic as a scan over chunks of query rows: the CPU backend, and
+    a shape the kernel's gate refuses, whose reason is ``refused``). No
+    call falls from the streamed form to materialised scores."""
     b, s, h, _ = q_shape
     if 4 * b * h * s * k_shape[1] <= STREAM_SCORE_BYTES:
         return {"form": "materialised", "kernel": "masked_attention", "refused": None}
     refused = None
     if backend == "tpu" and on_tpu():
-        refused = prefill_stream_refusal(tuple(q_shape), tuple(k_shape), itemsize)
+        refused = prefill_stream_refusal(tuple(q_shape), tuple(k_shape), itemsize, v_shape)
         if refused is None:
             return {"form": "streamed", "kernel": "prefill_stream_attention", "refused": None}
         _note_refusal("prefill_stream_attention", refused)
@@ -469,8 +471,8 @@ def prefill_attention(q, k, v, lengths, window: int = 0, backend: str = "cpu"):
     valid length a sequence, ``window`` > 0 for a sliding-window layer;
     ``v``'s width may differ from the scores', whose scale is of ``q``'s
     width: a latent layer's expanded form), in the lowering
-    :func:`prefill_call_lowering` names."""
-    low = prefill_call_lowering(q.shape, k.shape, q.dtype.itemsize, backend)
+    :func:`prefill_call_lowering` names for the three shapes."""
+    low = prefill_call_lowering(q.shape, k.shape, q.dtype.itemsize, backend, v_shape=v.shape)
     if low["form"] == "materialised":
         return masked_attention(q, k, v, lengths, causal=True, window=window)
     if low["kernel"] == "prefill_stream_attention":
@@ -532,7 +534,7 @@ def _grouped_masked_attention(q, k, v, lengths, causal, scale, window=0):
     p = jnp.where(mask, jnp.exp(logits - jnp.maximum(m, -1e30)), 0.0)
     l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", (p / l).astype(v.dtype), v, preferred_element_type=jnp.float32)
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def reference_attention(q, k, v, causal=False, scale=None):

@@ -405,7 +405,13 @@ def flash_attention_sharded(
 # of its own name, so that a device trace shows it), and the same
 # arithmetic as an XLA scan over chunks of query rows, each against the
 # keys its rows can reach, which the CPU backend takes and a TPU call
-# whose shapes the gate refuses.
+# whose shapes the gate refuses. The Pallas call lays its query rows out
+# by what the shapes show: a group that is a whole tile of query heads a
+# K/V head scores ``positions x group`` rows a step out of q as it comes;
+# a group of ONE (every head has K/V of its own: plain multi-head, a
+# latent layer's expanded form) takes q and the result head-major and
+# scores a block of positions of one head a step. The score's width
+# (q's and k's) and the value's (v's and the result's) may differ.
 
 STREAM_QUERY_ROWS = 1024  # query rows (positions x the group's heads) a grid step scores at once
 STREAM_BLOCK_K = 512  # key positions folded into the running softmax at a time
@@ -417,47 +423,60 @@ CHUNK_SCORE_BYTES = 256 << 20  # float32 scores one chunk of the XLA composition
 def stream_blocks(seq: int, group: int) -> Tuple[int, int]:
     """(query positions, key positions) a grid step of the streamed
     kernel takes of a sequence of ``seq`` positions at ``group`` query
-    heads a K/V head."""
-    bq = max(8, STREAM_QUERY_ROWS // group)
-    while bq > 8 and seq % bq:
-        bq //= 2
+    heads a K/V head: :data:`STREAM_QUERY_ROWS` rows, and no more
+    positions than a key block holds (a group of one: the block pair on
+    the diagonal is then the only one the causal mask cuts in half)."""
     bk = STREAM_BLOCK_K
     while bk > 128 and seq % bk:
         bk //= 2
+    bq = max(8, min(STREAM_QUERY_ROWS // group, bk))
+    while bq > 8 and seq % bq:
+        bq //= 2
     return min(bq, seq), min(bk, seq)
 
 
-def prefill_stream_refusal(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...], itemsize: int) -> Optional[str]:
+def prefill_stream_refusal(
+    q_shape: Tuple[int, ...], k_shape: Tuple[int, ...], itemsize: int, v_shape: Optional[Tuple[int, ...]] = None,
+) -> Optional[str]:
     """Why :func:`prefill_stream_attention` will not take this shape, or
     None when it will (the XLA composition of the same arithmetic takes
-    a refused one; ops/attention.py counts and names the refusal)."""
+    a refused one; ops/attention.py counts and names the refusal). It
+    takes a group of one query head a K/V head or of whole tiles of them,
+    a score width (``q``'s and ``k``'s) of 128 or more in steps of 64 and
+    a value width (``v_shape``'s; ``k``'s where none is given) in steps
+    of 128."""
     _, s, h, d = q_shape
     hk = k_shape[2]
+    dv = k_shape[3] if v_shape is None else v_shape[3]
     if h % hk:
         return f"{h} query heads over {hk} K/V heads"
     group = h // hk
-    if d % 128:
+    if d < 128 or d % 64:
         return f"head_dim {d} does not fill the 128 lanes"
-    if group % (32 // itemsize):
-        return f"a group of {group} query heads is no whole tile of {32 // itemsize} rows"
+    if dv % 128:
+        return f"value width {dv} does not fill the 128 lanes"
+    if group != 1 and group % (32 // itemsize):
+        return f"a group of {group} query heads is no whole tile of {32 // itemsize} rows (and not 1)"
     bq, bk = stream_blocks(s, group)
     if s % bq or s % bk or s % 8:
         return f"sequence {s} does not divide into blocks ({bq}, {bk})"
-    if 4 * s * d * itemsize > _STREAM_KV_BYTES:
+    lanes = sum(-(-width // 128) * 128 for width in (d, dv))  # a row of K and one of V, as fast memory holds them
+    if 2 * s * lanes * itemsize > _STREAM_KV_BYTES:
         return f"K and V of one head over {s} positions pass {_STREAM_KV_BYTES >> 20} MiB of VMEM"
     return None
 
 
 def _stream_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale, window, block_k):
-    # q_ref / o_ref: [bq, G, D], a block of positions of one K/V head's query heads;
-    # k_ref / v_ref: [S, D], that K/V head's whole sequence
-    bq, g, d = q_ref.shape
-    sk = k_ref.shape[0]
-    rows = bq * g
+    # q_ref [bq, G, D] / o_ref [bq, G, Dv]: a block of positions of one K/V head's query heads; at a
+    # group of one, head-major, [bq, D] / [bq, Dv]: a block of positions of one head;
+    # k_ref [S, D] / v_ref [S, Dv]: that K/V head's whole sequence
+    lead, d = q_ref.shape[:-1], q_ref.shape[-1]
+    bq, rows = lead[0], math.prod(lead)
+    sk, dv = v_ref.shape
     length = lens_ref[pl.program_id(0)]
     first = pl.program_id(2) * bq  # the block's first query position
     q = q_ref[...].reshape(rows, d)  # row t * G + g: position first + t, the group's g-th head
-    row_pos = first + jax.lax.broadcasted_iota(jnp.int32, (bq, g, block_k), 0).reshape(rows, block_k)
+    row_pos = first + jax.lax.broadcasted_iota(jnp.int32, lead + (block_k,), 0).reshape(rows, block_k)
     # key blocks any row of this block can reach: not past its last row or the
     # sequence's length, and (a window) not wholly behind its first row's window
     reach = jnp.minimum(first + bq, length)
@@ -484,54 +503,66 @@ def _stream_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale, window, block
         return m_new, l_new, acc_new
 
     init = (
-        jnp.full((rows, 1), NEG_INF, jnp.float32), jnp.zeros((rows, 1), jnp.float32), jnp.zeros((rows, d), jnp.float32),
+        jnp.full((rows, 1), NEG_INF, jnp.float32), jnp.zeros((rows, 1), jnp.float32), jnp.zeros((rows, dv), jnp.float32),
     )
     _, l, acc = jax.lax.fori_loop(j_lo, j_hi, body, init)
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype).reshape(bq, g, d)
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype).reshape(o_ref.shape)
 
 
 def prefill_stream_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Array, window: int = 0,
     scale: Optional[float] = None, interpret: bool = False,
 ) -> jax.Array:
-    """Causal attention of a prefill, q [B, S, H, D] over k / v [B, S,
-    Hkv, D] (query head ``i`` reads K/V head ``i // (H // Hkv)``), key
-    positions ``>= lengths[b]`` masked and, with ``window`` > 0, those
-    ``window`` or more behind a query: :func:`~flexflow_tpu.ops.attention.masked_attention`'s
-    result without its ``[B, H, S, S]`` scores. A Pallas call of its own
-    name; the grid is ``(batch, K/V heads, query blocks)``, a step scores
-    ``block x group`` query rows against one block of keys at a time on
-    the MXU in the operands' type with float32 accumulation, keeps the
-    softmax state in float32, and walks only the key blocks its rows can
-    reach. Compiled by Mosaic; only a test passes ``interpret=True``."""
+    """Causal attention of a prefill, q [B, S, H, D] over k [B, S, Hkv,
+    D] and v [B, S, Hkv, Dv] (query head ``i`` reads K/V head ``i // (H
+    // Hkv)``; ``Dv`` may differ from ``D``: a latent layer's expanded
+    form scores at 192 and weighs values of 128), key positions ``>=
+    lengths[b]`` masked and, with ``window`` > 0, those ``window`` or
+    more behind a query: :func:`~flexflow_tpu.ops.attention.masked_attention`'s
+    result, [B, S, H, Dv], without its ``[B, H, S, S]`` scores. A Pallas
+    call of its own name; the grid is ``(batch, K/V heads, query
+    blocks)``, a step scores ``block x group`` query rows against one
+    block of keys at a time on the MXU in the operands' type with float32
+    accumulation (the contraction over ``D`` whole, whatever its width),
+    keeps the softmax state in float32, and walks only the key blocks its
+    rows can reach. At a group of one query head a K/V head the rows of a
+    step are a block of positions of ONE head: q and the result go
+    through the call head-major (``[B, H, S, .]``, as K and V always do).
+    Compiled by Mosaic; only a test passes ``interpret=True``."""
     b, s, h, d = q.shape
-    hk = k.shape[2]
+    hk, dv = k.shape[2], v.shape[3]
     group = h // hk
     if scale is None:
         scale = d ** -0.5
     bq, bk = stream_blocks(s, group)
-    kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # [B, Hkv, S, D]: a head's sequence is one block
+    kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # [B, Hkv, S, .]: a head's sequence is one block
     kernel = functools.partial(_stream_kernel, scale=float(scale), window=int(window), block_k=bk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hk, s // bq),
-        in_specs=[
-            pl.BlockSpec((None, bq, group, d), lambda ib, ih, iq, lens: (ib, iq, ih, 0)),
-            pl.BlockSpec((None, None, s, d), lambda ib, ih, iq, lens: (ib, ih, 0, 0)),
-            pl.BlockSpec((None, None, s, d), lambda ib, ih, iq, lens: (ib, ih, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, bq, group, d), lambda ib, ih, iq, lens: (ib, iq, ih, 0)),
-    )
-    return pl.pallas_call(
+
+    def whole(width):
+        return pl.BlockSpec((None, None, s, width), lambda ib, ih, iq, lens: (ib, ih, 0, 0))
+
+    head_major = group == 1  # no tile holds one head of 64: a block of positions of one head, out of [B, H, S, .]
+    if head_major:
+        q = jnp.swapaxes(q, 1, 2)
+
+    def rows(width):
+        if head_major:
+            return pl.BlockSpec((None, None, bq, width), lambda ib, ih, iq, lens: (ib, ih, iq, 0))
+        return pl.BlockSpec((None, bq, group, width), lambda ib, ih, iq, lens: (ib, iq, ih, 0))
+
+    out = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, hk, s // bq), in_specs=[rows(d), whole(d), whole(dv)], out_specs=rows(dv),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, dv) if head_major else (b, s, h, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=_STREAM_VMEM_LIMIT,
         ),
         interpret=interpret,
         name="prefill_stream_attention",
     )(lengths.astype(jnp.int32), q, kt, vt)
+    return jnp.swapaxes(out, 1, 2) if head_major else out
 
 
 def stream_chunk(batch: int, heads: int, span: int) -> int:

@@ -453,6 +453,29 @@ def test_stored_cache_shape_is_row_major_and_unpadded_on_the_v5e(kv_shards, one_
     assert compiled.memory_analysis().argument_size_in_bytes == int(np.prod(shard)) * 4
 
 
+@pytest.mark.parametrize("seq, heads, kv_heads, score, value, window", [
+    (3072, 64, 64, 192, 128, 0), (4096, 64, 64, 192, 128, 0),        # agent-turns: a latent layer's expanded form, head-major
+    (5120, 128, 8, 128, 128, 4096), (6144, 128, 8, 128, 128, 0),    # long-doc: group 16, a window layer and a full one
+])
+def test_the_streamed_prefill_call_compiles_for_the_v5e_at_the_cells_buckets(seq, heads, kv_heads, score, value, window, one_v5e_chip, monkeypatch):
+    """``prefill_attention`` at the two streaming cells' prefill calls
+    (bfloat16), compiled by the TPU's own compiler: the rule names the
+    Pallas call, Mosaic takes it (a contraction over 192, a block of
+    positions of one head, K at 192 beside V at 128 in fast memory), the
+    result has the value's width, and no ``[heads, S, S]`` float32 exists:
+    the temporaries hold the head-major copies alone."""
+    import flexflow_tpu.ops.attention as attention
+
+    sds = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_v5e_chip)  # noqa: E731
+    q, k, v = (sds(jnp.bfloat16, 1, seq, h, w) for h, w in ((heads, score), (kv_heads, score), (kv_heads, value)))
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)  # the chip's dispatch
+    call = jax.jit(lambda q, k, v, n: attention.prefill_attention(q, k, v, n, window=window, backend="tpu"))
+    text = (compiled := _compile_uncached(call, q, k, v, sds(jnp.int32, 1))).as_text()
+    assert text.count("tpu_custom_call") == 1 and "prefill_stream_attention" in text and f"bf16[1,{seq},{heads},{value}]" in text
+    moved = 2 * seq * (heads * (score + value) + kv_heads * (score + value))  # q, the result, K and V, once each
+    assert compiled.memory_analysis().temp_size_in_bytes <= moved < 4 * heads * seq * seq / 8
+
+
 @pytest.mark.parametrize("hidden, width, held, experts, k, heads", [(2304, 896, 64, 64, 8, 32), (2048, 768, 16, 256, 8, 64)])
 @pytest.mark.parametrize("rows", [1536, 2048])
 def test_the_grouped_expert_layer_compiles_for_the_v5e_at_the_cells_buckets(rows, hidden, width, held, experts, k, heads, one_v5e_chip):

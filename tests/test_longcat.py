@@ -205,19 +205,52 @@ def test_the_expanded_form_streams_past_the_bound_and_only_there(model, monkeypa
     np.testing.assert_allclose(np.asarray(got)[0, :50], np.asarray(want)[0, :50], atol=1e-5)
 
 
-def test_the_cells_latent_calls_by_what_their_shapes_show():
+LATENT_CALLS = [
+    # cell, bucket, heads, the lowering on the CPU backend, on a TPU
+    ("long-gen", 1536, 32, "materialised", "materialised"),
+    ("long-gen", 2048, 32, "materialised", "materialised"),
+    ("agent-turns", 2048, 64, "materialised", "materialised"),  # exactly 1 GiB of scores: not past the bound
+    ("agent-turns", 3072, 64, "xla_chunks", "prefill_stream_attention"),
+    ("agent-turns", 4096, 64, "xla_chunks", "prefill_stream_attention"),
+]
+
+
+@pytest.mark.parametrize("cell,bucket,heads,on_cpu,on_chip", LATENT_CALLS)
+def test_the_cells_latent_calls_by_what_their_shapes_show(cell, bucket, heads, on_cpu, on_chip, monkeypatch):
     """``long-gen`` (32 heads x 2,048) keeps materialised scores; this
-    model's buckets (64 heads past 2,048) stream, and on a TPU the streamed
-    kernel's gate refuses them by name (score width 192, group 1), which
-    sends them to the XLA chunks: never back to materialised scores."""
-    low = functools.partial(attention.prefill_call_lowering, itemsize=2, backend="cpu")
-    for bucket in (1536, 2048):
-        assert low((1, bucket, 32, 192), (1, bucket, 32, 192))["form"] == "materialised"
-    assert low((1, 2048, 64, 192), (1, 2048, 64, 192))["form"] == "materialised"  # exactly 1 GiB
+    model's buckets (64 heads past 2,048) stream: on a TPU through the
+    Pallas call (score width 192, value width 128, one query head a K/V
+    head: PR 42), on the CPU backend through the XLA chunks, never back
+    to materialised scores."""
+    shapes = ((1, bucket, heads, 192), (1, bucket, heads, 192))
+    low = functools.partial(attention.prefill_call_lowering, *shapes, 2, v_shape=(1, bucket, heads, 128))
+    named = {"materialised": {"form": "materialised", "kernel": "masked_attention", "refused": None},
+             "xla_chunks": {"form": "streamed", "kernel": "xla_chunks", "refused": None},
+             "prefill_stream_attention": {"form": "streamed", "kernel": "prefill_stream_attention", "refused": None}}
+    assert low(backend="cpu") == named[on_cpu]
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    assert low(backend="tpu") == named[on_chip] and low(backend="cpu") == named[on_cpu]
+    assert flash_attention.prefill_stream_refusal(*shapes, 2, (1, bucket, heads, 128)) is None
+
+
+def test_the_engine_names_the_kernel_for_the_cells_buckets_on_a_tpu(model, monkeypatch):
+    """The engine asks the rule with the value's width beside the score's
+    (``prefill_lowering`` reads the configuration's widths and the
+    backend): holding the cell's real widths on a TPU backend, the
+    ``programs`` of ``/v2/stats`` name the Pallas call for both buckets
+    past the bound and count no refusal."""
+    cfg, params = model
+    eng = GenerationEngine(params, cfg, max_batch_slots=2, block_size=8, prompt_buckets=[32], max_seq_len=64)
+    assert eng.prefill_lowering(32)["kernel"] == "masked_attention"
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    eng.backend, eng.dcfg, eng.buckets = "tpu", longcat.engine_config(FILE, 4608), [2048, 3072, 4096]
+    eng._prefill_lowerings.clear()
+    stats = eng.prefill_attention_stats()
+    assert stats["programs"]["prefill[2048]"]["form"] == "materialised"
     for bucket in (3072, 4096):
-        assert low((1, bucket, 64, 192), (1, bucket, 64, 192)) == {"form": "streamed", "kernel": "xla_chunks", "refused": None}
-        assert "192" in flash_attention.prefill_stream_refusal((1, bucket, 64, 192), (1, bucket, 64, 192), 2)
-    assert "group of 1" in flash_attention.prefill_stream_refusal((1, 4096, 64, 256), (1, 4096, 64, 256), 2)
+        assert stats["programs"][f"prefill[{bucket}]"] == {"form": "streamed", "kernel": "prefill_stream_attention", "refused": None}
+    eng._count_prefill_attention(4096, 3000)
+    assert eng.prefill_attention_stats()["streamed_calls_total"] == 8 and not eng.prefill_attention_stats()["refused_total"]
 
 
 def test_the_engine_counts_latent_prefill_calls_by_form_and_keeps_no_prefix_index_past_the_bound(model, monkeypatch):
