@@ -9,6 +9,8 @@
     python chip_smoke.py --release-probe     # what the drop of a consumed step's device arrays waits for
     python chip_smoke.py --dispatch-probe    # ms a decode dispatch (args / upload / call), steady and after a change
     python chip_smoke.py --group16           # the group-16 paged calls and the streamed prefill kernel alone
+    python chip_smoke.py --grouped-kernels   # every grouped paged call of the cells alone (``--tree .parent``: a parent commit's)
+    python chip_smoke.py --walk-sweep        # ... at 1 to 32 table columns a grid step
     python chip_smoke.py --latent-prefill    # the streamed prefill kernel alone at the 64-head latent cell's call (192 / 128, group 1)
 
 ONE process. It refuses to start unless JAX's first device is a TPU, and
@@ -164,11 +166,12 @@ MATERIALISED_CALLS = {
 }
 
 
-def grouped_call(name: str, seed: int, calls=None):
+def grouped_call(name: str, seed: int, calls=None, half=False):
     """The arguments of one of ``GROUPED_CALLS`` as the engine's decode
     step would pass them: ``(q, k_cache, v_cache, layer, tables,
     context_lens, first_positions)``. Row 0 is inactive (context 0), row
-    1 ends one position into a block."""
+    1 ends one position into a block. ``half``: contexts of at most half
+    the table (a block and up), whose other columns are dead."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -184,7 +187,7 @@ def grouped_call(name: str, seed: int, calls=None):
     k_cache = jax.random.normal(kk, shape, jnp.bfloat16)
     v_cache = jax.random.normal(kv, shape, jnp.bfloat16)
     q = jax.random.normal(kq, (b, c["heads"], c["head_dim"]), jnp.bfloat16)
-    ctx = rs.randint(*c["contexts"], size=b)
+    ctx = rs.randint(*((bs, cols * bs // 2) if half else c["contexts"]), size=b)
     ctx[0], ctx[1] = 0, (ctx[1] // bs) * bs + 1
     first = np.zeros(b, np.int64)
     if c["window"]:
@@ -231,53 +234,101 @@ def stated_paged_attention(q, k_cache, v_cache, layer, tables, ctx, window=0, fi
     return out.reshape(b, h, d), room.reshape(b, h, d)
 
 
-def grouped_kernels_check(calls=None) -> dict:
+def _timed_ms(call, args, reps=20) -> float:
+    """Milliseconds a call of a compiled ``call`` on the host's clock:
+    ``reps`` calls, one wait."""
+    import jax
+
+    jax.block_until_ready(call(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        r = call(*args)
+    jax.block_until_ready(r)
+    return round((time.perf_counter() - t0) / reps * 1e3, 4)
+
+
+def grouped_kernels_check(calls=None, columns=None) -> dict:
     """The grouped-query decode calls of ``calls`` (``GROUPED_CALLS``), compiled by
     Mosaic, against :func:`stated_paged_attention`: every element inside
     the room the arithmetic leaves, an inactive row exact zeros; the
-    kernel's time a call on the host's clock (20 calls, one wait)."""
+    kernel's time a call on the host's clock (20 calls, one wait), at the
+    cell's contexts and at contexts of half the table and less
+    (``<name>_half_table``), with the walk the call ran (table columns a
+    grid step, grid steps a call). ``columns``: that many columns a step
+    and not the rule's (the sweep's; nothing in the program sets it)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from flexflow_tpu.ops.kernels.decode_attention import (
-        kernel_body, paged_decode_attention, paged_kernel_refusal, query_group,
-    )
+    from flexflow_tpu.ops.kernels import decode_attention as kernels
 
     out = {}
     calls = calls or GROUPED_CALLS
-    for name, c in calls.items():
-        q, k_cache, v_cache, layer, tables, ctx, first = args = grouped_call(name, SEED, calls)
-        group = query_group(c["heads"], c["head_dim"], k_cache.shape[3:])
-        check(group == c["heads"] // c["kv_heads"], f"{name}: group {group}")
-        reason = paged_kernel_refusal(c["kv_heads"], c["head_dim"], c["block"], group, 2, group=group)
-        check(reason is None, f"{name}: the gate refuses the cell's own call: {reason}")
+    rule = getattr(kernels, "grouped_columns_per_step", None)  # a tree from before PR 43 walks one column a step
+    if columns is not None:
+        kernels.grouped_columns_per_step = lambda *a, **kw: columns
+    try:
+        for name, c in calls.items():
+            for half in (False, True):
+                q, k_cache, v_cache, layer, tables, ctx, first = args = grouped_call(name, SEED, calls, half=half)
+                group = kernels.query_group(c["heads"], c["head_dim"], k_cache.shape[3:])
+                check(group == c["heads"] // c["kv_heads"], f"{name}: group {group}")
+                reason = kernels.paged_kernel_refusal(c["kv_heads"], c["head_dim"], c["block"], group, 2, group=group)
+                check(reason is None or columns is not None, f"{name}: the gate refuses the cell's own call: {reason}")
 
-        def over(attend):
-            def run(q, k, v, layer, t, n, first):
-                bounds = {"window": c["window"], "first_positions": first} if c["window"] else {}
-                return attend(q, k, v, layer, t, n, **bounds)
-            return jax.jit(run, static_argnums=3)
+                def over(attend):
+                    def run(q, k, v, layer, t, n, first):
+                        bounds = {"window": c["window"], "first_positions": first} if c["window"] else {}
+                        return attend(q, k, v, layer, t, n, **bounds)
+                    return jax.jit(run, static_argnums=3)
 
-        call = over(paged_decode_attention)
-        got = call(*args).astype(jnp.float32)
-        want, room = over(stated_paged_attention)(*args)
-        err = jnp.abs(got - want)
-        worst, of_room = float(jnp.max(err)), float(jnp.max(err / room))
-        check(np.isfinite(worst) and of_room <= 1.0,
-              f"{name}: max err {worst}, {of_room:.2f} of the room bfloat16 leaves")
-        check(bool(jnp.all(got[0] == 0.0)), f"{name}: an inactive row must emit zeros")
-        jax.block_until_ready(call(*args))
-        t0 = time.perf_counter()
-        for _ in range(20):
-            r = call(*args)
-        jax.block_until_ready(r)
-        out[name] = {
-            "body": kernel_body(group), "group": group, "max_abs_err": worst,
-            "err_of_room": round(of_room, 3), "ms_a_call": round((time.perf_counter() - t0) / 20 * 1e3, 4),
-        }
-        log(f"grouped paged kernel {name}: {out[name]}")
-        del q, k_cache, v_cache, args, got, want, room, err, r
+                call = over(kernels.paged_decode_attention)
+                got = call(*args).astype(jnp.float32)
+                want, room = over(stated_paged_attention)(*args)
+                err = jnp.abs(got - want)
+                worst, of_room = float(jnp.max(err)), float(jnp.max(err / room))
+                check(np.isfinite(worst) and of_room <= 1.0,
+                      f"{name}: max err {worst}, {of_room:.2f} of the room bfloat16 leaves")
+                check(bool(jnp.all(got[0] == 0.0)), f"{name}: an inactive row must emit zeros")
+                steps = c["slots"] * c["columns"]  # a tree from before PR 43: a grid step a column
+                walk = {"columns_per_step": 1, "grid_steps": steps, "walk_steps_at_most": steps}
+                if rule is not None:
+                    walk = kernels.paged_walk(c["kv_heads"], c["head_dim"], c["block"], group, 2, group, c["slots"], c["columns"],
+                                              kernels.default_kv_splits(c["slots"], c["columns"]))
+                key = name + ("_half_table" if half else "")
+                out[key] = {
+                    "body": kernels.kernel_body(group), "group": group, **walk, "mean_context": float(np.mean(np.asarray(ctx))),
+                    "max_abs_err": worst, "err_of_room": round(of_room, 3), "ms_a_call": _timed_ms(call, args),
+                }
+                log(f"grouped paged kernel {key}: {out[key]}")
+                del q, k_cache, v_cache, args, got, want, room, err
+    finally:
+        if rule is not None:
+            kernels.grouped_columns_per_step = rule
+    return out
+
+
+def walk_sweep() -> dict:
+    """Every grouped call of the cells at 1, 2, 4 ... 32 table columns a
+    grid step (those the compiler takes: VMEM bounds the widest), ms a
+    call: what ``GROUPED_STEP_POSITIONS`` and the rule beside it
+    (ops/kernels/decode_attention.py) were read from."""
+    out = {}
+    every = {**GROUPED_CALLS, **GROUP16_CALLS}
+    for name, c in every.items():
+        for columns in (1, 2, 4, 8, 16, 32):
+            if columns > c["columns"]:
+                continue
+            try:
+                got = grouped_kernels_check({name: c}, columns=columns)
+            except Exception as e:  # the compiler's refusal (VMEM) is a reading, a failed check is not
+                if "chip_smoke check failed" in str(e):
+                    raise
+                got = {name: {"ms_a_call": "refused: " + str(e)[:120]}}
+            for key, v in got.items():
+                out.setdefault(key, {})[str(columns)] = v["ms_a_call"]
+    for key, v in out.items():
+        log(f"walk sweep {key}: ms a call by columns a step {v}")
     return out
 
 
@@ -1624,6 +1675,10 @@ def main(argv=None) -> int:
                     help="the routed experts' sum alone: dense against grouped, one layer of each expert cell (or of those named)")
     ap.add_argument("--group16", action="store_true",
                     help="the group-16 paged calls and the streamed prefill kernel alone, at the long-document cell's sizes")
+    ap.add_argument("--grouped-kernels", action="store_true",
+                    help="every grouped paged call of the cells alone (GROUPED_CALLS and GROUP16_CALLS), at the cells' contexts and at half the table")
+    ap.add_argument("--walk-sweep", action="store_true",
+                    help="the grouped paged calls at 1 to 32 table columns a grid step, ms a call")
     ap.add_argument("--latent-prefill", action="store_true",
                     help="the streamed prefill kernel alone at the 64-head latent cell's two buckets (score width 192, value width 128)")
     ap.add_argument("--release-probe", action="store_true",
@@ -1665,6 +1720,11 @@ def main(argv=None) -> int:
     elif args.group16:
         summary["kernels"] = {"grouped": grouped_kernels_check(GROUP16_CALLS), "prefill_stream": stream_kernel_check(),
                               "materialised_prefill": materialised_calls_check(), "latent_prefill_stream": latent_stream_check()}
+    elif args.grouped_kernels:
+        summary["tree"] = args.tree or "."
+        summary["kernels"] = {"grouped": grouped_kernels_check({**GROUPED_CALLS, **GROUP16_CALLS})}
+    elif args.walk_sweep:
+        summary["walk_sweep"] = walk_sweep()
     elif args.latent_prefill:
         summary["kernels"] = {"latent_prefill_stream": latent_stream_check()}
     elif args.expert_product is not None:
@@ -1700,7 +1760,9 @@ def main(argv=None) -> int:
     out_dir = REPO / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel or args.latent
-            else "chip_smoke_group16.json" if args.group16 else "chip_smoke_latent_prefill.json" if args.latent_prefill
+            else "chip_smoke_group16.json" if args.group16
+            else "chip_smoke_grouped" + ("_" + pathlib.Path(args.tree).name.strip(".") if args.tree else "") + ".json" if args.grouped_kernels
+            else "chip_smoke_walk_sweep.json" if args.walk_sweep else "chip_smoke_latent_prefill.json" if args.latent_prefill
             else "chip_smoke_experts.json" if args.expert_product is not None
             else "chip_smoke_release.json" if args.release_probe else "chip_smoke_dispatch.json" if args.dispatch_probe
             else "chip_smoke.json")

@@ -2751,21 +2751,25 @@ class GenerationEngine:
         return {name: self.expert_form(rows) for name, rows in programs.items()}
 
     def paged_lowerings(self) -> Dict[str, Dict]:
-        """``{"body", "group"}`` per attention kind the model has
-        (``full``, ``window``; ``latent`` alone where the layers are
-        latent), asked of the gate the step programs'
-        dispatch asks (ops/attention.py ``paged_call_lowering``)."""
+        """``{"body", "group"}`` and, where a kernel runs, its walk
+        (``columns_per_step``, ``grid_steps``, ``walk_steps_at_most``) per
+        attention kind the model has (``full``, ``window``; ``latent``
+        alone where the layers are latent) for the decode step's call
+        (every slot over the kind's whole table), asked of the gate and
+        the rules the step programs' dispatch asks (ops/attention.py
+        ``paged_call_lowering``)."""
+        shape = {"batch": self.max_batch_slots, "max_blocks": self.max_blocks_per_seq}
         if self.cache_config.latent:
-            return {"latent": latent_call_lowering(self.dcfg.num_heads, self.cache.k, backend=self.backend)}
-        kinds = {"full": self.cache.k}
+            return {"latent": latent_call_lowering(self.dcfg.num_heads, self.cache.k, backend=self.backend, **shape)}
+        kinds = {"full": (self.cache.k, shape)}
         if self.window_config is not None:
-            kinds["window"] = self.cache.state["wk"]
+            kinds["window"] = (self.cache.state["wk"], {**shape, "max_blocks": self.window_columns})
         return {
             kind: paged_call_lowering(
                 self.dcfg.num_heads, self.dcfg.dim_per_head, arrays,
-                backend=self.backend, mesh=self._kernel_mesh,
+                backend=self.backend, mesh=self._kernel_mesh, **of,
             )
-            for kind, arrays in kinds.items() if arrays.shape[0]
+            for kind, (arrays, of) in kinds.items() if arrays.shape[0]
         }
 
     def kernel_stats(self) -> Dict:
@@ -2773,8 +2777,10 @@ class GenerationEngine:
         the loaded model (``full``, ``window``), the body its paged decode
         call lowered to — ``mxu`` (a grouped call), ``vpu`` (plain
         multi-head) or ``reference`` (the XLA composition: the CPU
-        backend, or a shape the kernel's gate refused) — and the group
-        the shapes show. (What a prefill's attention calls lower to is in
+        backend, or a shape the kernel's gate refused) — the group the
+        shapes show, and the walk over the block table that call runs:
+        the table columns a step folds, the grid steps a call and the
+        steps the walk takes at most (absent for the composition). (What a prefill's attention calls lower to is in
         the ``prefill_attention`` section, per program.)"""
         return {kind: dict(low) for kind, low in self.attention_kernels.items()}
 

@@ -27,12 +27,15 @@ from ..core.types import DataType, OpType
 from ..device import on_tpu
 from .base import LowerCtx, OpCost, OpDef, WeightSpec, io_cost, register_op
 from .kernels.decode_attention import (
+    default_kv_splits,
     kernel_body,
+    latent_columns_per_step,
     latent_kernel_refusal,
     paged_append_attention,
     paged_decode_attention,
     paged_kernel_refusal,
     paged_latent_attention,
+    paged_walk,
     query_group,
     reference_paged_append_attention,
     reference_paged_attention,
@@ -265,17 +268,31 @@ def _paged_kernel_tp(kernel, backend, mesh, head_axis, num_heads, head_dim, cach
     return tp
 
 
-def paged_call_lowering(num_heads, head_dim, cache, backend="tpu", mesh=None, head_axis="model", window=1):
+def paged_call_lowering(
+    num_heads, head_dim, cache, backend="tpu", mesh=None, head_axis="model", window=1, *, batch: int, max_blocks: int
+):
     """What a paged call of ``num_heads`` query heads over ``cache``
     ([L, num_blocks, block_size, R, LW]; an array or its shape and
-    dtype) lowers to, as :func:`decode_attention_core` will decide it:
-    ``{"body", "group"}``. The body is the kernel's for the group the
-    shapes show (``"mxu"`` grouped, ``"vpu"`` plain multi-head:
-    kernels/decode_attention.py ``kernel_body``), or ``"reference"``,
-    the XLA composition, on the CPU backend and where the gate refuses."""
+    dtype) lowers to, as :func:`decode_attention_core` will decide it
+    for ``batch`` sequences over tables of ``max_blocks`` columns:
+    ``{"body", "group", "columns_per_step", "grid_steps",
+    "walk_steps_at_most"}``. The body is
+    the kernel's for the group the shapes show (``"mxu"`` grouped,
+    ``"vpu"`` plain multi-head: kernels/decode_attention.py
+    ``kernel_body``), or ``"reference"``, the XLA composition, on the
+    CPU backend and where the gate refuses; the walk is the kernel's
+    (``paged_walk``: table columns a step of the walk, the grid steps of a
+    call, the steps its walk takes at most); the composition walks no
+    grid and has ``{"body", "group"}`` alone."""
     group = query_group(num_heads, head_dim, cache.shape[3:])
     tp = _paged_kernel_tp("paged_decode_attention", backend, mesh, head_axis, num_heads, head_dim, cache, window)
-    return {"body": kernel_body(group) if tp else "reference", "group": group}
+    if not tp:
+        return {"body": "reference", "group": group}
+    splits = default_kv_splits(batch, max_blocks) if window == 1 else 1  # the decode call's own rule
+    walk = paged_walk(
+        num_heads // group // tp, head_dim, cache.shape[2], window * group, cache.dtype.itemsize, group, batch, max_blocks, splits
+    )
+    return {"body": kernel_body(group), "group": group, **walk}
 
 
 def _windowed(tp: int, kernel, reference):
@@ -394,22 +411,25 @@ def append_attention_core(
     )
 
 
-def latent_call_lowering(num_heads: int, cache, backend: str = "tpu", window: int = 1):
+def latent_call_lowering(num_heads: int, cache, backend: str = "tpu", window: int = 1, *, batch: int, max_blocks: int):
     """What a latent layer's paged call of ``window`` queries of
     ``num_heads`` heads over ``cache`` ([L, num_blocks, block_size, RW])
-    lowers to, as :func:`latent_attention_core` will decide it: ``{"body",
-    "group"}``, the body ``"mxu"`` (kernels/decode_attention.py
-    ``paged_latent_attention``: every head reads the one row, a group of
-    all of them) or ``"reference"``, the XLA composition, on the CPU
-    backend and where the gate refuses."""
-    body = "reference"
+    lowers to, as :func:`latent_attention_core` will decide it for
+    ``batch`` sequences over tables of ``max_blocks`` columns: ``{"body",
+    "group", "columns_per_step", "grid_steps", "walk_steps_at_most"}``, the body ``"mxu"``
+    (kernels/decode_attention.py ``paged_latent_attention``: every head
+    reads the one row, a group of all of them; its walk
+    ``latent_columns_per_step``) or ``"reference"``, the XLA composition
+    (``{"body", "group"}`` alone), on the CPU backend and where the gate
+    refuses."""
     if backend == "tpu" and on_tpu():
         reason = latent_kernel_refusal(window * num_heads, cache.shape[3], cache.shape[2], cache.dtype.itemsize)
         if reason is None:
-            body = "mxu"
-        else:
-            _note_refusal("paged_latent_attention", reason)
-    return {"body": body, "group": num_heads}
+            columns = latent_columns_per_step(max_blocks)
+            steps = batch * (max_blocks // columns)
+            return {"body": "mxu", "group": num_heads, "columns_per_step": columns, "grid_steps": steps, "walk_steps_at_most": steps}
+        _note_refusal("paged_latent_attention", reason)
+    return {"body": "reference", "group": num_heads}
 
 
 def latent_attention_core(
@@ -429,7 +449,7 @@ def latent_attention_core(
     The Pallas kernel on the TPU backend, the same arithmetic as an XLA
     composition on the CPU backend and for windows past the kernel's
     bound (a suffix-prefill bucket)."""
-    lowering = latent_call_lowering(q.shape[2], cache, backend, window=q.shape[1])
+    lowering = latent_call_lowering(q.shape[2], cache, backend, window=q.shape[1], batch=q.shape[0], max_blocks=block_tables.shape[1])
     call = paged_latent_attention if lowering["body"] == "mxu" else reference_paged_latent_attention
     return call(q, cache, layer, block_tables, q_positions, value_width, scale)
 

@@ -51,10 +51,10 @@ heads of 64 are a window of 4 over rows ``[4, 128]``, Mellum2's 32 over
 4 heads of 128 a window of 8 over the same rows.
 
 **Two bodies, picked by the group the shapes show** (:func:`kernel_body`;
-no flag, no environment variable, no model's name). The grid, the block
-tables, the scalar prefetch, the skip of dead columns and the Pallas
-calls' names are the same for both; what differs is how one cache block
-is folded into the online-softmax state.
+no flag, no environment variable, no model's name). The block tables,
+the scalar prefetch, the online-softmax state and the Pallas calls'
+names are the same for both; what differs is how the table is walked
+and how what a step holds is folded into that state.
 
 * ``group == 1`` (plain multi-head attention: GPT-2's decode and verify
   calls, the head-sharded wrappers) — :func:`_accumulate_block`, on the
@@ -73,10 +73,18 @@ is folded into the online-softmax state.
   product of two bfloat16 values is exact in float32, and the
   configurations' references state the weighted values as rounded
   probabilities times V with float32 accumulation. On the v5e (PR 33,
-  ``chip_smoke.py``) the call of Mellum2's full layers takes 1.09 ms
+  ``chip_smoke.py``) the call of Mellum2's full layers took 1.09 ms
   where the VPU body took 5.32, its windowed call 0.51 against 2.96,
-  LFM2's 1.51 against 2.35; what is left is the grid (0.4-0.5 us a
-  step).
+  LFM2's 1.51 against 2.35, at one block a grid step. Since PR 43 the
+  grouped call's grid step is a SEQUENCE and the kernel walks that
+  sequence's table itself (the section "The grouped call's walk over
+  the block table", below): several consecutive columns a step, each an
+  asynchronous copy of its own into one buffer, folded as ONE matrix
+  of lines by one pair of products and one softmax update, two buffers
+  so that the next step's copies fly meanwhile, and no step, copy or
+  table look-up past the sequence's live context: 0.41, 0.25 and 0.32
+  ms for the three calls. What a step costs now is the MXU loading
+  every K and V tile as weights for a few dozen query rows.
 
 **A window** (PR 31). A sliding-window layer's call (``window`` > 0)
 adds a lower bound a query (it attends the ``window`` positions up to
@@ -86,11 +94,12 @@ block, with the cache position of column 0 a sequence
 window). Both lowerings take the two arguments. The kernel prefetches
 ``first_positions`` and the least position any query of the window still
 attends as two further scalars, skips a column wholly behind that as it
-skips one past every query, and runs as a Pallas call of its own name,
+skips one past every query (the group-1 call; the grouped call's walk
+starts at column 0, which such a table's sequence still attends, and
+masks), and runs as a Pallas call of its own name,
 ``paged_window_attention`` (a device trace carries no scope path: a
-reader finds a kernel by its name). The grid is ``(batch, table
-columns)`` (:func:`paged_append_attention`), so such a call walks the
-columns the window holds, not the history's.
+reader finds a kernel by its name). The table holds the columns the
+window holds, so such a call walks those and not the history's.
 
 **Latent rows** (a latent-attention layer, generation/decoder.py). Such a
 layer caches ONE row a position, ``[c, k_r]`` of ``kv_lora_rank +
@@ -123,19 +132,22 @@ Two lowerings:
   masked softmax in plain XLA. This is the CPU/test path and
   the parity oracle. :func:`reference_paged_attention` is its W = 1
   wrapper (the original decode form).
-* :func:`paged_append_attention` — a Pallas TPU kernel gridded over
-  (batch, cache blocks) with the block tables AND per-query positions
-  scalar-prefetched (``pltpu.PrefetchScalarGridSpec``), so each grid
-  step DMAs exactly one cache block — ``(layer, table[b, j])`` of the
-  5-D array — into VMEM (the PagedAttention access pattern) and
-  accumulates per-query online-softmax state in
-  scratch across the sequential grid. Out-of-range table entries point
-  at the scratch block 0 and are masked, never read out of bounds.
+* :func:`paged_append_attention` — a Pallas TPU kernel with the block
+  tables AND per-query positions scalar-prefetched
+  (``pltpu.PrefetchScalarGridSpec``). A group-1 call is gridded over
+  (batch, cache blocks): each grid step DMAs exactly one cache block —
+  ``(layer, table[b, j])`` of the 5-D array — into VMEM (the
+  PagedAttention access pattern) and accumulates per-query
+  online-softmax state in scratch across the sequential grid;
+  out-of-range table entries point at the scratch block 0 and are
+  masked, never read out of bounds. A grouped call is gridded over the
+  batch and copies its sequence's live blocks itself, several a step.
   :func:`paged_decode_attention` is its W = 1 wrapper.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -410,29 +422,34 @@ def _line_index(lines, rows):
     return lines // rows, lines % rows
 
 
-def _accumulate_block_mxu(
-    qpos_ref, q_ref, rowpos_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, b, block_start, *, scale, head_dim, window=0
+def _accumulate_step_mxu(
+    q_ref, rowpos_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, start, end, *, scale, head_dim, window=0
 ):
-    """:func:`_accumulate_block` for a grouped call: one block folded
-    into the state of ALL query rows (m/l [M, 1], acc [M, LW]) by two
-    matrix products. ``rowpos_ref`` [M, 1] is each query row's cache
-    position (the scalars of ``qpos_ref``, as a vector)."""
-    del qpos_ref, b
-    bs, r, lw = k_ref.shape
+    """:func:`_accumulate_block` for a grouped call: one step of the walk
+    (``k_ref`` / ``v_ref`` [n, R, LW]: the ``n`` positions ``start ..``
+    of the step's consecutive table columns, one block under the other)
+    folded into the state of ALL query rows (m/l [M, 1], acc [M, LW]) as
+    ONE matrix of lines, by two matrix products and one softmax update.
+    ``rowpos_ref`` [M, 1] is each query row's cache position. ``end`` (a
+    split's call): the first position that is not this split's, dead
+    here whatever the queries see."""
+    n, r, lw = k_ref.shape
     q = q_ref[...]  # [M, LW], the stored dtype
-    k = k_ref[...].reshape(bs * r, lw).astype(q.dtype)  # the block's lines
-    v = v_ref[...].reshape(bs * r, lw)
-    # Q x K^T, float32 accumulation: [M, bs * R]
+    k = k_ref[...].reshape(n * r, lw).astype(q.dtype)  # the step's lines, as they are in memory
+    v = v_ref[...].reshape(n * r, lw)
+    # Q x K^T, float32 accumulation: [M, n * R]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-    # line t * R + r of the block against query row (w * R + r') * P + p
+    # line t * R + r of the step against query row (w * R + r') * P + p
     t, line_row = _line_index(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1), r)
     query_line, _ = _line_index(jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), lw // head_dim)
     _, query_row = _line_index(query_line, r)
-    pos = block_start + t
+    pos = start + t
     qp = rowpos_ref[...]  # [M, 1]
     valid = jnp.logical_and(line_row == query_row, pos <= qp)
     if window:
         valid = jnp.logical_and(valid, pos > qp - window)
+    if end is not None:
+        valid = jnp.logical_and(valid, pos < end)
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -479,19 +496,21 @@ def _append_kernel(
     bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
     qpos_ref,  # scalar-prefetch: [B, W] per-query cache positions (-1 = pad)
     maxpos_ref,  # scalar-prefetch: [B] max over the window's positions
-    *refs,  # the body's inputs (:func:`_accumulate_block`: q, k, v), then:
-    # o_ref [W, R, LW]; scratch m_ref / l_ref [W, R, LW or 1] running max /
-    # denominator per query, acc_ref [W, R, LW] running numerator (a
-    # grouped call: [M, LW] / [M, 1] per query row, its body's fourth
-    # input the rows' positions)
+    q_ref,  # [W, R, LW] the window, laid out as the cache lays a position out
+    k_ref,  # [block_size, R, LW] the grid step's cache block
+    v_ref,
+    o_ref,  # [W, R, LW]
+    m_ref,  # scratch [W, R, LW or 1] running max per query
+    l_ref,  # scratch [W, R, LW or 1] running denominator
+    acc_ref,  # scratch [W, R, LW] running numerator
     scale,
     block_size,
     head_dim,
-    accumulate,  # the body: :func:`_accumulate_block` or `_mxu`
     window=0,
     bounds=None,
 ):
-    *ins, o_ref, m_ref, l_ref, acc_ref = refs
+    """The group-1 call's kernel (a grouped call runs
+    :func:`_grouped_kernel`): ONE cache block a grid step."""
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -505,8 +524,8 @@ def _append_kernel(
     # (its DMA read the scratch block; the data is ignored)
     @pl.when(live)
     def _accum():
-        accumulate(
-            qpos_ref, *ins, m_ref, l_ref, acc_ref,
+        _accumulate_block(
+            qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
             b, start, scale=scale, head_dim=head_dim, window=window,
         )
 
@@ -522,16 +541,20 @@ def _append_kernel_split(
     bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
     qpos_ref,  # scalar-prefetch: [B, W] per-query cache positions (-1 = pad)
     maxpos_ref,  # scalar-prefetch: [B] max over the window's positions
-    *refs,  # the body's inputs as in :func:`_append_kernel`, then this
-    # split's UNNORMALIZED numerator acc_out_ref [W, R, LW], its running
-    # max m_out_ref and denominator l_out_ref [W, R, LW or 1], and the
-    # three scratch refs of the same shapes
+    q_ref,  # as in :func:`_append_kernel`
+    k_ref,
+    v_ref,
+    acc_out_ref,  # this split's UNNORMALIZED numerator [W, R, LW]
+    m_out_ref,  # its running max [W, R, LW or 1]
+    l_out_ref,  # its running denominator
+    m_ref,  # the three scratch refs of the same shapes
+    l_ref,
+    acc_ref,
     scale,
     block_size,
     head_dim,
     blocks_per_split,
     max_blocks,
-    accumulate,
     window=0,
     bounds=None,
 ):
@@ -542,7 +565,6 @@ def _append_kernel_split(
     broken), and emits unnormalized partials (acc, m, l) that
     :func:`_combine_splits` recombines exactly. Long-context
     single-stream decode stops serializing over the whole block table."""
-    *ins, acc_out_ref, m_out_ref, l_out_ref, m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
     j = pl.program_id(2)
     jj = pl.program_id(1) * blocks_per_split + j  # global block-table column
@@ -558,8 +580,8 @@ def _append_kernel_split(
 
     @pl.when(jnp.logical_and(jj < max_blocks, live))
     def _accum():
-        accumulate(
-            qpos_ref, *ins, m_ref, l_ref, acc_ref,
+        _accumulate_block(
+            qpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
             b, start, scale=scale, head_dim=head_dim, window=window,
         )
 
@@ -593,6 +615,323 @@ def _combine_splits(acc, m, l, q_positions, out_dtype):
     return out.astype(out_dtype)
 
 
+def _least_attended(q_positions, window):
+    """[B]: the least position any query of a window of ``window``
+    positions still attends. A padding query (-1) attends nothing: it
+    must not hold the window's lower edge down."""
+    low = jnp.where(q_positions >= 0, q_positions - (window - 1), jnp.iinfo(jnp.int32).max)
+    return jnp.min(low, axis=1)
+
+
+# ---------------------------------------------------------------------------
+# The grouped call's walk over the block table (PR 43)
+# ---------------------------------------------------------------------------
+# One cache block a grid step, over every column of the table, left the
+# grouped body waiting on the grid: a step cost 0.4-0.6 us whatever it
+# held, 128 KB of K/V is 0.16 us of HBM time, and a column past the
+# context still paid 0.3 us for the step and a copy of the scratch block
+# (my chip runs, PR 33, PR 39 and PR 43). So a grouped call's grid step is
+# a SEQUENCE (a split of one), and the kernel walks that sequence's table
+# itself: ``k`` consecutive columns a step (:func:`grouped_columns_per_step`),
+# each an asynchronous copy of its own out of the cache in HBM, all in
+# flight together, into ONE buffer that holds the step's positions one
+# under the other, so the step is folded as one matrix of lines
+# (:func:`_accumulate_step_mxu`) with nothing moved inside VMEM. Two such
+# buffers: the next step's copies (at a sequence's last step, the next
+# sequence's first) fly while this one is folded. The walk is bounded by
+# the live context outright: a sequence takes ``ceil(live columns / k)``
+# steps, a column past its largest position is never copied nor looked up
+# in the table, and a sequence of context 0 takes none. The table's width
+# need not divide by ``k``: a last step's missing columns keep what an
+# earlier step left in the buffer (zeros before any), dead under the mask.
+#
+# The other walk tried (my chip runs, PR 43; `chip_smoke.py --walk-sweep`
+# has the table): the same ``k`` columns as ``k`` BlockSpecs a grid step,
+# copied and double-buffered by the pipeline as the latent call's are, a
+# dead slot's index clamped to the block it held the step before so that
+# no copy is issued. Mellum2's full call 1.09 -> 0.57 ms where this one
+# reads 0.41, its windowed 0.51 -> 0.36 against 0.25, LFM2's 1.51 -> 0.57
+# against 0.32: every BlockSpec costs ~65 ns of scalar work a grid step
+# whether or not it copies (a DEAD step of 16 specs 1.0 us), and the
+# blocks had to be concatenated in VMEM.
+
+# Positions a grid step of the grouped call folds, where the VMEM budget
+# allows. Mellum2's full call (48 sequences of 1.1-2.7 k positions in
+# blocks of 64), ms a call by columns a step (my chip runs, PR 43): 1:
+# 0.86, 2: 0.63, 4: 0.43, 8: 0.41, 16: 0.45, 32: 0.48; LFM2's (blocks of
+# 16): 4: 0.46, 8: 0.38, 16: 0.34, 32: 0.35. A step costs ~0.24 us and
+# ~0.23 us a column of 128 KB (the MXU loads every K and V tile as
+# weights for 32 query rows), and its last step's dead columns are folded
+# with the rest: more columns a step are fewer steps and more dead ones.
+GROUPED_STEP_POSITIONS = 512
+# Copies of K (and as many of V) one step may have in flight.
+MAX_COLUMNS_PER_STEP = 32
+
+
+def _grouped_vmem_bytes(rows: int, lanes: int, block_size: int, query_rows: int, itemsize: int, columns: int) -> int:
+    """Upper estimate of the grouped call's VMEM footprint at ``columns``
+    table columns a step: the two buffers of K and of V (a position's
+    (R, LW) rows padded to the (8, 128) tile) and a step's lines once
+    more as the products' operands, Q / O / the rows' positions
+    double-buffered, the ``[M, ...]`` softmax state, and the ``[M,
+    columns * block_size * R]`` scores four times over (scores, mask,
+    probabilities, their rounded copy), for ``query_rows`` = M."""
+    padded = -(-rows // 8) * 8
+    line = -(-lanes // LANES) * LANES
+    m = -(-query_rows // 8) * 8
+    step = 2 * columns * block_size * padded * line * itemsize  # a step's K and V
+    qo = 2 * (2 * m * line * itemsize + m * LANES * 4)
+    scratch = m * (line + 2 * LANES) * 4
+    scores = 4 * m * -(-columns * block_size * rows // LANES) * LANES * 4
+    return 3 * step + qo + scratch + scores
+
+
+def grouped_columns_per_step(block_size: int, row_shape, query_rows: int, itemsize: int, max_blocks: int = 0) -> int:
+    """Table columns one step of the grouped call's walk folds: what
+    fills the step with :data:`GROUPED_STEP_POSITIONS` positions (blocks
+    of 16 positions: 32 columns; of 64: 8), halved until
+    :func:`_grouped_vmem_bytes` fits :data:`_VMEM_BUDGET_BYTES` (a block
+    of 8 K/V heads under 128 query rows: 4). For a table of
+    ``max_blocks`` columns (where given), the least that walks it in as
+    few steps (17 columns at no more than 8 a step are three steps: 6 a
+    step, not 8 + 8 + 1 with seven dead columns folded in the last). The
+    call's shapes decide, nothing else: ``row_shape`` (R, LW),
+    ``query_rows`` M."""
+    rows, lanes = row_shape
+    columns = max(1, min(GROUPED_STEP_POSITIONS // block_size, MAX_COLUMNS_PER_STEP))
+    while columns > 1 and _grouped_vmem_bytes(rows, lanes, block_size, query_rows, itemsize, columns) > _VMEM_BUDGET_BYTES:
+        columns //= 2
+    if max_blocks:
+        columns = -(-max_blocks // -(-max_blocks // columns))
+    return columns
+
+
+def paged_grid(group: int, batch: int, max_blocks: int, kv_splits: int = 1) -> Tuple[int, ...]:
+    """The grid of :func:`paged_append_attention` for ``batch`` sequences
+    over tables of ``max_blocks`` columns. A grouped call: a step a
+    sequence, ``(batch,)``, split ``(batch, splits)`` (the walk is the
+    kernel's own). A group-1 call: a step a column, ``(batch,
+    max_blocks)``, split ``(batch, splits, columns a split)``."""
+    kv_splits = max(1, min(int(kv_splits), max_blocks))
+    grid = (batch, kv_splits) if kv_splits > 1 else (batch,)
+    if kernel_body(group) == "mxu":
+        return grid
+    return grid + (-(-max_blocks // kv_splits),)
+
+
+def paged_walk(
+    kv_heads: int, head_dim: int, block_size: int, window: int, itemsize: int, group: int, batch: int, max_blocks: int,
+    kv_splits: int = 1,
+) -> dict:
+    """``{"columns_per_step", "grid_steps", "walk_steps_at_most"}`` of
+    the call that :func:`paged_append_attention` makes for these shapes
+    (``kv_heads`` what one device holds, ``window`` the window queries
+    the kernel holds, W x ``group``): the table columns a step of the
+    walk folds (a group-1 call: 1), the grid steps of one call, and the
+    steps its walk takes at most, at full tables (a group-1 call walks
+    every column whatever the contexts, a step of the grid each; a
+    grouped call's sequence ``ceil(live columns / columns a step)``)."""
+    splits = max(1, min(int(kv_splits), max_blocks))
+    split_columns = -(-max_blocks // splits)
+    columns = 1
+    if kernel_body(group) == "mxu":
+        rows, lanes = cache_row_shape(kv_heads, head_dim)
+        columns = grouped_columns_per_step(block_size, (rows, lanes), window * rows * (lanes // head_dim), itemsize, split_columns)
+    return {
+        "columns_per_step": columns,
+        "grid_steps": math.prod(paged_grid(group, batch, max_blocks, splits)),
+        "walk_steps_at_most": batch * splits * -(-split_columns // columns),
+    }
+
+
+def _grouped_kernel(
+    bt_ref,  # scalar-prefetch: [B, max_blocks] block tables
+    live_ref,  # scalar-prefetch: [B] table columns up to the last one any query sees (0: an inactive sequence)
+    *refs,  # a windowed call's first_ref [B] (the position of column 0); q_ref [M, LW] the query rows, rowpos_ref
+    # [M, 1] their positions; k_hbm, v_hbm the WHOLE caches where they are (the kernel copies out of them itself);
+    # o_ref [M, LW] (a split call: this split's UNNORMALIZED numerator [M, LW], running max and denominator
+    # [M, 1]); scratch: kbuf, vbuf [2, columns * block_size, R, LW] a step's blocks one under the other, twice;
+    # sem DMA [2, 2] (buffer, K or V); carry SMEM [2] (the buffer the next step goes to, whether the next
+    # program's first step is in flight); m / l [M, 1], acc [M, LW]
+    scale,
+    block_size,
+    head_dim,
+    columns,
+    layer,
+    window=0,
+    splits=1,
+    split_columns=0,
+):
+    """The grouped call's kernel. A program (grid ``(B,)``; split ``(B,
+    splits)``) is one sequence's walk over its live columns (a split:
+    over its share of them), ``columns`` a step; the grid is sequential,
+    so a program hands the next one its first step already in flight."""
+    first_ref = None
+    if window:
+        first_ref, *refs = refs
+    q_ref, rowpos_ref, k_hbm, v_hbm, *refs = refs
+    *outs, kbuf, vbuf, sem, carry, m_ref, l_ref, acc_ref = refs
+    program = pl.program_id(0) * splits + (pl.program_id(1) if splits > 1 else 0)
+    programs = pl.num_programs(0) * splits
+
+    def walk_of(p):
+        """Program ``p``'s sequence, its first column and the column its walk stops before."""
+        if splits == 1:
+            return p, 0, live_ref[p]
+        seq, s = p // splits, p % splits
+        return seq, s * split_columns, jnp.minimum((s + 1) * split_columns, live_ref[seq])
+
+    def steps_of(p):
+        _, c0, c1 = walk_of(p)
+        return jnp.maximum(c1 - c0 + columns - 1, 0) // columns
+
+    def each_copy(p, step, buffer, do):
+        """``do`` the copy of every live column of step ``step`` of program ``p`` into ``buffer`` (a loop, not
+        ``columns`` copies spelt out: the kernel's text does not grow with the columns a step)."""
+        seq, c0, c1 = walk_of(p)
+        column = c0 + step * columns
+
+        def one(c, _):
+            block = bt_ref[seq, column + c]
+            rows = pl.ds(pl.multiple_of(c * block_size, block_size), block_size)
+            for which, (cache, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                do(pltpu.make_async_copy(cache.at[layer, block], buf.at[buffer, rows], sem.at[buffer, which]))
+            return 0
+
+        jax.lax.fori_loop(0, jnp.clip(c1 - column, 0, columns), one, 0)
+
+    start = lambda p, step, buffer: each_copy(p, step, buffer, lambda copy: copy.start())
+    wait = lambda p, step, buffer: each_copy(p, step, buffer, lambda copy: copy.wait())
+
+    @pl.when(program == 0)
+    def _first():
+        # a step's dead columns keep what an earlier step left in the buffer: never uninitialised memory
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        carry[0] = 0
+        carry[1] = 0
+
+    _init_state(m_ref, l_ref, acc_ref)
+    seq, c0, c1 = walk_of(program)
+    steps = steps_of(program)
+    buffer0 = carry[0]
+
+    @pl.when(jnp.logical_and(steps > 0, carry[1] == 0))
+    def _own_first():  # the program before had nothing to fold, or there was none
+        start(program, 0, buffer0)
+
+    base = 0 if first_ref is None else first_ref[seq]
+    end = base + c1 * block_size if splits > 1 else None
+    follows = jnp.logical_and(program + 1 < programs, steps_of(jnp.minimum(program + 1, programs - 1)) > 0)
+
+    def one_step(step, _):
+        buffer = (buffer0 + step) % 2
+        more = step + 1 < steps  # else the next program's first step flies while this one's last is folded
+
+        @pl.when(jnp.logical_or(more, follows))
+        def _next():
+            start(jnp.where(more, program, program + 1), jnp.where(more, step + 1, 0), 1 - buffer)
+
+        wait(program, step, buffer)
+        _accumulate_step_mxu(
+            q_ref, rowpos_ref, kbuf.at[buffer], vbuf.at[buffer], m_ref, l_ref, acc_ref,
+            base + (c0 + step * columns) * block_size, end, scale=scale, head_dim=head_dim, window=window,
+        )
+        return 0
+
+    jax.lax.fori_loop(0, steps, one_step, 0)
+    carry[0] = (buffer0 + steps) % 2
+    carry[1] = jnp.logical_and(steps > 0, follows).astype(jnp.int32)
+    if splits > 1:
+        # UNNORMALIZED partials out, as :func:`_append_kernel_split`
+        acc_out_ref, m_out_ref, l_out_ref = outs
+        m_out_ref[...] = m_ref[...]
+        l_out_ref[...] = l_ref[...]
+        acc_out_ref[...] = acc_ref[...]
+    else:
+        # a padding query (qp < 0) accumulated nothing: acc = l = 0, so it emits exact zeros
+        (o_ref,) = outs
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _live_columns(q_positions, first_positions, block_size: int, max_blocks: int):
+    """[B]: the table columns up to the last one any query of a sequence
+    sees (its largest position's, from the position of column 0), 0
+    where every query is a padding one."""
+    last = jnp.max(q_positions, axis=1)
+    from_first = last if first_positions is None else last - first_positions
+    return jnp.where(last >= 0, jnp.clip(from_first // block_size + 1, 0, max_blocks), 0)
+
+
+def _grouped_append_attention(
+    q, k_cache, v_cache, layer, block_tables, q_positions, scale, interpret, kv_splits, window, first_positions, group
+):
+    """:func:`paged_append_attention` for a grouped call (its arguments
+    already normalised): a block of K/V is read ONCE for all the query
+    heads of its groups, each one more window query at the same
+    position, and scored for all of them on the MXU, several table
+    columns a step of a walk that ends where the context does."""
+    out_dtype = q.dtype
+    block_size, r, lw = k_cache.shape[2:]
+    q = _fold_group(q, group)
+    q_positions = jnp.repeat(q_positions, group, axis=1)
+    b, w, _, d = q.shape
+    per_query = r * (lw // d)  # query rows a window query: one a K/V head
+    m = w * per_query
+    row_positions = jnp.repeat(q_positions, per_query, axis=1)
+    max_blocks = block_tables.shape[1]
+    split_columns = -(-max_blocks // kv_splits)  # the table's width, of a sequential call
+    columns = grouped_columns_per_step(block_size, (r, lw), m, k_cache.dtype.itemsize, split_columns)
+    grid = paged_grid(group, b, max_blocks, kv_splits)
+    name, static, bounds = "paged_append_attention", {}, ()
+    if window:
+        first_positions = first_positions.astype(jnp.int32)
+        name, static, bounds = "paged_window_attention", {"window": window}, (first_positions,)
+    prefetch = (block_tables, _live_columns(q_positions, first_positions if window else None, block_size, max_blocks), *bounds)
+
+    def whole(shape):
+        """A sequence's (and split's) whole ``shape``."""
+        return pl.BlockSpec((None,) * len(grid) + shape, lambda i, *at: (i, *at[:len(grid) - 1]) + (0,) * len(shape))
+
+    in_specs = [pl.BlockSpec((None, m, lw), lambda i, *_: (i, 0, 0)), pl.BlockSpec((None, m, 1), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)]
+    step = (2, columns * block_size, r, lw)
+    scratch_shapes = [
+        pltpu.VMEM(step, k_cache.dtype), pltpu.VMEM(step, v_cache.dtype), pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((2,), jnp.int32),
+        pltpu.VMEM((m, 1), jnp.float32), pltpu.VMEM((m, 1), jnp.float32), pltpu.VMEM((m, lw), jnp.float32),
+    ]
+    kernel = functools.partial(
+        _grouped_kernel, scale=scale, block_size=block_size, head_dim=d, columns=columns, layer=layer,
+        splits=kv_splits, split_columns=split_columns, **static,
+    )
+    operands = (*prefetch, _query_rows(q, r, lw), row_positions[:, :, None], k_cache, v_cache)
+
+    def unstack(out):
+        """The kernel's result [B, M, LW] as [B, W, H, D]."""
+        return _unfold_group(_head_rows(out, w, r, d), group)
+
+    def call(out_specs, out_shape, name):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs, out_specs=out_specs,
+                scratch_shapes=scratch_shapes,
+            ),
+            out_shape=out_shape,
+            interpret=interpret,
+            name=name,
+        )(*operands)
+
+    if kv_splits > 1:
+        partial_ = lambda width: jax.ShapeDtypeStruct((b, kv_splits, m, width), jnp.float32)
+        acc, mx, l = call([whole((m, lw)), whole((m, 1)), whole((m, 1))], [partial_(lw), partial_(1), partial_(1)], name + "_split")
+        # a query row as a window query of one cache row
+        acc, mx, l = (x[:, :, :, None] for x in (acc, mx, l))
+        return unstack(_combine_splits(acc, mx, l, row_positions, out_dtype)[:, :, 0])
+    return unstack(call(whole((m, lw)), jax.ShapeDtypeStruct((b, m, lw), out_dtype), name))
+
+
 def paged_append_attention(
     q: jax.Array,
     k_cache: jax.Array,
@@ -613,7 +952,7 @@ def paged_append_attention(
     flash-decoding split-KV kernel: the cache-block grid axis splits
     into ``kv_splits`` independent slices whose partial softmaxes
     recombine exactly — parallelism across the KV length for
-    long-context, small-batch decode, where the sequential block grid
+    long-context, small-batch decode, where the sequential walk
     otherwise serializes the whole chip on one sequence's history.
 
     ``window`` > 0 is the sliding-window layer's call, a Pallas call of
@@ -624,41 +963,35 @@ def paged_append_attention(
     what lies ``window`` or more positions behind it, and a column wholly
     behind every query's window is skipped as one past its position is.
     The table holds only the columns a sequence keeps (generation/
-    cache.py), so the grid walks the window and not the history."""
+    cache.py), so the call walks the window and not the history.
+
+    A grouped call (the cache holds fewer heads than ``q``) goes to
+    :func:`_grouped_append_attention`: the same names and arguments, a
+    grid step a sequence, and a walk of its own over that sequence's
+    live columns."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     group = query_group(q.shape[2], q.shape[3], k_cache.shape[3:])
+    q_positions = q_positions.astype(jnp.int32)
+    block_tables = block_tables.astype(jnp.int32)
+    layer = int(layer)  # static: part of the index map, not an operand
+    kv_splits = max(1, min(int(kv_splits), block_tables.shape[1]))
+    if kernel_body(group) == "mxu":
+        return _grouped_append_attention(
+            q, k_cache, v_cache, layer, block_tables, q_positions, float(scale), interpret, kv_splits,
+            int(window), first_positions, group,
+        )
     out_dtype = q.dtype
     block_size, r, lw = k_cache.shape[2:]
-    mxu = kernel_body(group) == "mxu"
-    q_positions = q_positions.astype(jnp.int32)
-    if mxu:
-        # grouped queries: a block of K/V is read ONCE for all the query
-        # heads of its groups, each one more window query at the same
-        # position, and scored for all of them on the MXU
-        q = _fold_group(q, group)
-        q_positions = jnp.repeat(q_positions, group, axis=1)
     b, w, h, d = q.shape
-    if mxu:
-        per_query = r * (lw // d)  # query rows a window query: one a K/V head
-        row_positions = jnp.repeat(q_positions, per_query, axis=1)
-        ins = (_query_rows(q, r, lw), row_positions[:, :, None])
-        state, acc_shape, accumulate = (w * per_query, 1), (w * per_query, lw), _accumulate_block_mxu
-    else:
-        sw = lw if lw != d else 1  # a head's max / denominator: on its lanes, or a column
-        ins = (q.reshape(b, w, r, lw),)  # the window, laid out as the cache lays a position out
-        state, acc_shape, accumulate = (w, r, sw), (w, r, lw), _accumulate_block
-    layer = int(layer)  # static: part of the index map, not an operand
+    sw = lw if lw != d else 1  # a head's max / denominator: on its lanes, or a column
+    rows = q.reshape(b, w, r, lw)  # the window, laid out as the cache lays a position out
+    state, acc_shape = (w, r, sw), (w, r, lw)
     max_blocks = block_tables.shape[1]
-    kv_splits = max(1, min(int(kv_splits), max_blocks))
-    block_tables = block_tables.astype(jnp.int32)
     prefetch = (block_tables, q_positions, jnp.max(q_positions, axis=1))
     name, static, behind = "paged_append_attention", {}, (lambda kernel: kernel)
     if window:
-        # a padding query (-1) attends nothing: it must not hold the
-        # window's lower edge down
-        low = jnp.where(q_positions >= 0, q_positions - (window - 1), jnp.iinfo(jnp.int32).max)
-        prefetch += (jnp.min(low, axis=1), first_positions.astype(jnp.int32))
+        prefetch += (_least_attended(q_positions, window), first_positions.astype(jnp.int32))
         name, static, behind = "paged_window_attention", {"window": int(window)}, _with_bounds
     n_prefetch = len(prefetch)
     scratch_shapes = [
@@ -674,12 +1007,6 @@ def paged_append_attention(
             (None,) * lead + shape, lambda i, *at: (i, *at[:lead - 1]) + (0,) * len(shape)
         )
 
-    def unstack(out):
-        """The kernel's result as [B, W, H, D]."""
-        if mxu:
-            return _unfold_group(_head_rows(out, w, r, d), group)
-        return out.reshape(b, w, h, d)
-
     if kv_splits > 1:
         bps = -(-max_blocks // kv_splits)  # blocks per split (ceil)
 
@@ -690,7 +1017,7 @@ def paged_append_attention(
             num_scalar_prefetch=n_prefetch,
             grid=(b, kv_splits, bps),
             in_specs=[
-                *(whole(x.shape[1:], 1) for x in ins),
+                whole(acc_shape, 1),
                 pl.BlockSpec((None, None, block_size, r, lw), kv_map),
                 pl.BlockSpec((None, None, block_size, r, lw), kv_map),
             ],
@@ -699,7 +1026,7 @@ def paged_append_attention(
         )
         kernel = functools.partial(
             behind(_append_kernel_split), scale=float(scale), block_size=block_size,
-            head_dim=d, blocks_per_split=bps, max_blocks=max_blocks, accumulate=accumulate, **static,
+            head_dim=d, blocks_per_split=bps, max_blocks=max_blocks, **static,
         )
         acc, m, l = pl.pallas_call(
             kernel,
@@ -711,12 +1038,8 @@ def paged_append_attention(
             ],
             interpret=interpret,
             name=name + "_split",
-        )(*prefetch, *ins, k_cache, v_cache)
-        if mxu:
-            # a query row as a window query of one cache row
-            acc, m, l = (x[:, :, :, None] for x in (acc, m, l))
-            return unstack(_combine_splits(acc, m, l, row_positions, out_dtype)[:, :, 0])
-        return unstack(_combine_splits(acc, m, l, q_positions, out_dtype))
+        )(*prefetch, rows, k_cache, v_cache)
+        return _combine_splits(acc, m, l, q_positions, out_dtype).reshape(b, w, h, d)
 
     def kv_map(i, j, bt, *_):
         return (layer, bt[i, j], 0, 0, 0)
@@ -725,7 +1048,7 @@ def paged_append_attention(
         num_scalar_prefetch=n_prefetch,
         grid=(b, max_blocks),
         in_specs=[
-            *(whole(x.shape[1:], 1) for x in ins),
+            whole(acc_shape, 1),
             pl.BlockSpec((None, None, block_size, r, lw), kv_map),
             pl.BlockSpec((None, None, block_size, r, lw), kv_map),
         ],
@@ -733,16 +1056,15 @@ def paged_append_attention(
         scratch_shapes=scratch_shapes,
     )
     kernel = functools.partial(
-        behind(_append_kernel), scale=float(scale), block_size=block_size, head_dim=d,
-        accumulate=accumulate, **static,
+        behind(_append_kernel), scale=float(scale), block_size=block_size, head_dim=d, **static,
     )
-    return unstack(pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b,) + acc_shape, out_dtype),
         interpret=interpret,
         name=name,
-    )(*prefetch, *ins, k_cache, v_cache))
+    )(*prefetch, rows, k_cache, v_cache).reshape(b, w, h, d)
 
 
 # ---------------------------------------------------------------------------
@@ -923,9 +1245,11 @@ def latent_kernel_refusal(query_rows: int, row_width: int, block_size: int, item
 
 def default_kv_splits(batch: int, max_blocks: int) -> int:
     """Flash-decoding split heuristic: split the KV axis only where the
-    sequential block grid is the bottleneck — small batch (little
-    batch-axis parallelism) over a long table. Capped so each split
-    still covers >= 4 blocks (partials below that are overhead-bound).
+    sequential walk over one sequence's blocks is the bottleneck (a
+    group-1 call: a grid step a block; a grouped call: a step of its own
+    walk every few blocks) — small batch (little batch-axis parallelism)
+    over a long table. Capped so each split still covers >= 4 blocks
+    (partials below that are overhead-bound).
 
     STATIC shapes only: the grid must be fixed at trace time, so
     ``batch`` is the engine's padded slot count and ``max_blocks`` its
@@ -1094,29 +1418,24 @@ def _vmem_bytes(
     num_heads: int, head_dim: int, block_size: int, window: int, itemsize: int, group: int = 1
 ) -> int:
     """Upper estimate of the kernel's VMEM footprint, for the body that
-    will run (:func:`kernel_body`): double-buffered K/V and Q/O blocks,
-    the online-softmax scratch and the body's temporaries, with a
-    position's (R, LW) rows padded to the (8, 128) tile. ``window``
-    counts the window queries the kernel holds (W x group). The VPU body
-    keeps three block-sized float32 temporaries; the MXU body the
-    ``[M, block_size * R]`` scores (as scores, mask, probabilities and
-    their rounded copy) and ``[M, ...]`` state, ``M = window * R * heads
-    a row`` query rows, and the block itself once more as a value."""
+    will run (:func:`kernel_body`) and the walk it will take
+    (:func:`grouped_columns_per_step`). ``window`` counts the window
+    queries the kernel holds (W x group). The VPU body: double-buffered
+    K/V and Q/O blocks, the online-softmax scratch and three block-sized
+    float32 temporaries, with a position's (R, LW) rows padded to the
+    (8, 128) tile. The MXU body: :func:`_grouped_vmem_bytes` for
+    ``window * R * heads a row`` query rows."""
     rows, lanes = cache_row_shape(num_heads, head_dim)
-    per_row = lanes // head_dim  # heads sharing a row
+    if kernel_body(group) == "mxu":
+        query_rows = window * rows * (lanes // head_dim)
+        columns = grouped_columns_per_step(block_size, (rows, lanes), query_rows, itemsize)
+        return _grouped_vmem_bytes(rows, lanes, block_size, query_rows, itemsize, columns)
     padded = -(-rows // 8) * 8
     row = padded * -(-lanes // LANES) * LANES  # one position's slab, in elements
     kv = 2 * 2 * block_size * row * itemsize
-    if kernel_body(group) == "vpu":
-        qo = 2 * 2 * window * row * itemsize
-        scratch = window * (row + 2 * padded * LANES) * 4  # acc + m + l
-        return kv + qo + scratch + 3 * block_size * row * 4
-    m = -(-window * rows * per_row // 8) * 8  # query rows
-    line = -(-lanes // LANES) * LANES
-    qo = 2 * (2 * m * line * itemsize + m * LANES * 4)  # Q, O and the rows' positions
-    scratch = m * (line + 2 * LANES) * 4
-    scores = 4 * m * -(-block_size * rows // LANES) * LANES * 4
-    return kv + qo + scratch + scores + kv // 2
+    qo = 2 * 2 * window * row * itemsize
+    scratch = window * (row + 2 * padded * LANES) * 4  # acc + m + l
+    return kv + qo + scratch + 3 * block_size * row * 4
 
 
 def paged_kernel_refusal(

@@ -79,6 +79,8 @@ class GenerationModel:
             "generation model %r starts: paged attention %s", self.name,
             ", ".join(
                 f"{kind}: {low['body']} body at group {low['group']}"
+                + (f", {low['columns_per_step']} columns a step over {low['grid_steps']} grid steps a call"
+                   if "grid_steps" in low else "")
                 for kind, low in self.engine.attention_kernels.items()
             ),
         )

@@ -405,18 +405,219 @@ def test_grouped_call_matches_reference(layout, dtype, w, bs, splits, windowed):
     out = f32(paged_append_attention(
         q, k_cache, v_cache, 1, tables, qpos, interpret=True, kv_splits=splits, **bounds
     ))
-    if dtype == jnp.float32:
-        room = 2e-5 + 2e-5 * np.abs(ref)
-    else:
-        weight = f32(reference_paged_append_attention(
-            q.astype(jnp.float32), k_cache.astype(jnp.float32), jnp.abs(v_cache).astype(jnp.float32),
-            1, tables, qpos, **bounds
-        ))
-        room = 2.0 ** -8 * weight + 2.0 ** -7 * np.abs(ref) + 1e-6
+    room = _grouped_room(q, k_cache, v_cache, tables, qpos, bounds, ref, layer=1)
     err = np.abs(out - ref)
     assert np.all(err <= room), (float(err.max()), float((err / room).max()))
     # padding queries and the inactive row emit exact zeros
     assert np.all(out[np.asarray(qpos) < 0] == 0.0)
+
+
+# ---------------------------------------------------------------------------
+# the grouped call's walk over the block table (PR 43): several table
+# columns a grid step folded as one matrix, no copy past the live context
+# ---------------------------------------------------------------------------
+
+WALK_LAYOUTS = {
+    **GROUPED_LAYOUTS,
+    # the long-document cell's: 128 query heads over 8 K/V heads of 128, rows of [8, 128]
+    "head_rows_group16": dict(heads=128, kv_heads=8, head_dim=128, rows=(8, 128)),
+}
+_G4, _G8, _G16 = "packed_rows_group4", "head_rows_group8", "head_rows_group16"
+# (layout, block, table columns, W, windowed, kv_splits, dtype): the widths of the cells' tables (17 and 65
+# the windowed ones, neither a multiple of the columns a step; 48, 64 and 128 the full ones) and narrower
+# ones that end inside a step, at the three groups, blocks of 16 and 64, decode and verify windows
+WALK_CASES = [
+    (_G8, 64, 17, 1, True, 1, "bfloat16"),
+    (_G8, 64, 17, 5, True, 1, "float32"),
+    (_G8, 64, 17, 1, True, 4, "bfloat16"),
+    (_G8, 64, 48, 1, False, 1, "bfloat16"),
+    (_G8, 64, 48, 5, False, 4, "float32"),
+    (_G4, 16, 64, 1, False, 1, "bfloat16"),
+    (_G4, 16, 64, 5, False, 1, "float32"),
+    (_G4, 16, 64, 1, False, 3, "bfloat16"),
+    (_G4, 16, 17, 1, True, 1, "float32"),
+    (_G4, 16, 65, 5, True, 1, "bfloat16"),
+    (_G16, 64, 65, 1, True, 1, "bfloat16"),
+    (_G16, 64, 65, 1, True, 4, "float32"),
+    (_G16, 64, 128, 1, False, 1, "bfloat16"),
+    (_G16, 16, 17, 5, False, 1, "float32"),
+    (_G4, 64, 48, 1, False, 1, "float32"),
+    (_G8, 16, 128, 1, False, 8, "bfloat16"),
+]
+
+
+def _walk_fixtures(seed, layout, dtype, w, bs, cols, windowed):
+    """A grouped call over tables of ``cols`` columns. Sequence 0 has a
+    context of 0 (every query a padding one), sequence 1 ends one
+    position into a block early in the table (the steps after it hold
+    no live column), sequence 2 fills its table to the last position
+    (the last step is the table's remainder), sequence 3 ends somewhere
+    inside and its window's later queries are padding. ``windowed``: a
+    window of ``(cols - 1) * bs - w`` positions, the tables starting at
+    the first block each sequence still holds (sequence 1's context is
+    shorter than the window: its table starts at 0 and ends dead)."""
+    rs = np.random.RandomState(seed)
+    lay = WALK_LAYOUTS[layout]
+    b, span = 4, cols * bs
+    window = (cols - 1) * bs - w if windowed else 0
+    nb = b * cols + 1
+    k_cache = jnp.asarray(rs.randn(1, nb, bs, *lay["rows"]), dtype)
+    v_cache = jnp.asarray(rs.randn(1, nb, bs, *lay["rows"]), dtype)
+    q = jnp.asarray(rs.randn(b, w, lay["heads"], lay["head_dim"]), dtype)
+    tables = 1 + rs.permutation(nb - 1).reshape(b, cols).astype(np.int32)
+    last = np.array([w - 1, 2 * bs + w - 1, span - 1, rs.randint(span // 2, span - bs)])
+    last[1] = max(last[1] - w + 1, w - 1) // bs * bs + w - 1  # the first query one position into a block
+    first = np.zeros(b, np.int64)
+    if windowed:
+        last[2:] += rs.randint(2, 5) * span  # far past the window
+        first = np.maximum(last - w + 1 - (window - 1), 0) // bs * bs
+        last[2] = first[2] + span - 1
+    qpos = last[:, None] - (w - 1) + np.arange(w)[None, :]
+    qpos[3, w // 2 + 1:] = -1  # padding queries
+    qpos[0, :] = -1  # a context of 0
+    assert np.all(qpos[1:, 0] >= 0) and int(np.max(last - first)) < span
+    bounds = {"window": window, "first_positions": jnp.asarray(first, jnp.int32)} if windowed else {}
+    return q, k_cache, v_cache, tables, jnp.asarray(qpos, jnp.int32), bounds, first
+
+
+def _grouped_room(q, k_cache, v_cache, tables, qpos, bounds, ref, layer=0):
+    """What the arithmetic lets a grouped call's result differ from the
+    XLA composition's ``ref`` by, per element
+    (:func:`test_grouped_call_matches_reference` derives it)."""
+    from flexflow_tpu.ops.kernels.decode_attention import reference_paged_append_attention
+
+    if k_cache.dtype == jnp.float32:
+        return 2e-5 + 2e-5 * np.abs(ref)
+    weight = np.asarray(reference_paged_append_attention(
+        q.astype(jnp.float32), k_cache.astype(jnp.float32), jnp.abs(v_cache).astype(jnp.float32),
+        layer, tables, qpos, **bounds
+    ))
+    return 2.0 ** -8 * weight + 2.0 ** -7 * np.abs(ref) + 1e-6
+
+
+@pytest.mark.parametrize("layout,bs,cols,w,windowed,splits,dtype", WALK_CASES)
+def test_grouped_walk_matches_reference(layout, bs, cols, w, windowed, splits, dtype):
+    """The grouped call (interpret mode) against the XLA composition at
+    the cells' table widths: several columns a grid step, a last step of
+    the table's remainder, contexts that end inside a step, steps with no
+    live column, a context of 0 and padding queries, split and
+    sequential. Tolerances as :func:`test_grouped_call_matches_reference`."""
+    from flexflow_tpu.ops.kernels.decode_attention import (
+        grouped_columns_per_step, paged_append_attention, reference_paged_append_attention,
+    )
+
+    dtype = jnp.dtype(dtype)
+    q, k_cache, v_cache, tables, qpos, bounds, _ = _walk_fixtures(900 + cols + w + bs, layout, dtype, w, bs, cols, windowed)
+    lay = WALK_LAYOUTS[layout]
+    per_step = grouped_columns_per_step(bs, lay["rows"], w * lay["heads"], dtype.itemsize, cols)
+    assert per_step > 1, "the case walks one column a step: it tests nothing new"
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+    tables = jnp.asarray(tables)
+    ref = f32(reference_paged_append_attention(q, k_cache, v_cache, 0, tables, qpos, **bounds))
+    out = f32(paged_append_attention(q, k_cache, v_cache, 0, tables, qpos, interpret=True, kv_splits=splits, **bounds))
+    room = _grouped_room(q, k_cache, v_cache, tables, qpos, bounds, ref)
+    err = np.abs(out - ref)
+    assert np.all(err <= room), (float(err.max()), float((err / room).max()))
+    assert np.all(out[np.asarray(qpos) < 0] == 0.0)  # padding queries and the context of 0: exact zeros
+    assert np.any(out[1] != 0.0) and np.any(out[2] != 0.0)
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "windowed"])
+@pytest.mark.parametrize("splits", [1, 3])
+def test_grouped_result_does_not_depend_on_dead_table_entries(splits, windowed):
+    """The columns past a sequence's last position are never copied: with
+    their table entries pointed at a block of NaN (whose products with a
+    zero probability would still be NaN) the result is the same, bit for
+    bit, as with the entries the table came with."""
+    from flexflow_tpu.ops.kernels.decode_attention import paged_append_attention
+
+    bs, cols, w = 16, 17, 1
+    q, k_cache, v_cache, tables, qpos, bounds, first = _walk_fixtures(77, _G4, jnp.float32, w, bs, cols, windowed)
+    poison = k_cache.shape[1]
+    k_cache, v_cache = (jnp.concatenate([c, jnp.full_like(c[:, :1], jnp.nan)], axis=1) for c in (k_cache, v_cache))
+    live = (np.max(np.asarray(qpos), axis=1) - first) // bs  # the last live column; -1: none
+    dead = np.arange(cols)[None, :] > live[:, None]
+    assert dead[0].all() and dead[1].sum() >= cols - 4 and not dead[2].any()
+    call = lambda t: np.asarray(paged_append_attention(
+        q, k_cache, v_cache, 0, jnp.asarray(t), qpos, interpret=True, kv_splits=splits, **bounds
+    ))
+    clean, poisoned = call(tables), call(np.where(dead, poison, tables).astype(np.int32))
+    assert np.all(np.isfinite(poisoned)) and np.array_equal(clean, poisoned)
+
+
+@pytest.mark.parametrize("bs,cols", [(16, 64), (64, 17), (64, 48), (64, 65), (64, 128)])
+def test_the_walk_ends_at_the_last_column_a_query_sees(bs, cols):
+    """``_live_columns``, the bound of a sequence's walk: the table
+    columns up to its largest position's (from the position of column 0
+    where the table starts behind a window), none for a sequence whose
+    queries are all padding; a padding query beside live ones counts for
+    nothing."""
+    from flexflow_tpu.ops.kernels.decode_attention import _live_columns
+
+    qpos = np.array([[-1, -1], [0, -1], [bs - 2, bs - 1], [bs - 1, bs], [5 * bs, -1], [cols * bs - 2, cols * bs - 1]], np.int32)
+    want = [0, 1, 1, 2, 6, cols]
+    assert list(np.asarray(_live_columns(jnp.asarray(qpos), None, bs, cols))) == want
+    first = np.array([0, 0, 0, 0, 3 * bs, 0], np.int32)
+    want[4] = 3
+    assert list(np.asarray(_live_columns(jnp.asarray(qpos), jnp.asarray(first), bs, cols))) == want
+    # positions far past a table that starts behind a window: the table's width bounds the walk
+    far = jnp.asarray(qpos + 40 * cols * bs * (qpos >= 0))
+    assert list(np.asarray(_live_columns(far, jnp.asarray(first), bs, cols))) == [0, cols, cols, cols, cols, cols]
+
+
+# (block, rows, query rows M, itemsize) of the three grouped cells' decode calls and what the rule gives them
+CELL_WALKS = {
+    "lfm2-8b-a1b.gen-batch": ((16, (4, 128), 32, 2), 32),  # 32 query heads over 8 K/V heads of 64, two a row
+    "mellum2-12b.code-gen": ((64, (4, 128), 32, 2), 8),  # 32 over 4 of 128 (its windowed table of 17 columns: 6)
+    "command-a-plus.long-doc": ((64, (8, 128), 128, 2), 4),  # 128 over 8 of 128: the VMEM budget halves 8
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_WALKS))
+def test_columns_per_step_at_the_cells_shapes(cell):
+    """The one rule for the columns a grid step folds, at the three
+    grouped cells' decode calls: what fills a step with
+    ``GROUPED_STEP_POSITIONS`` positions, inside the VMEM budget, and no
+    more columns than the table has."""
+    from flexflow_tpu.ops.kernels import decode_attention as kernels
+
+    (bs, rows, m, itemsize), want = CELL_WALKS[cell]
+    got = kernels.grouped_columns_per_step(bs, rows, m, itemsize)
+    assert got == want
+    assert kernels._grouped_vmem_bytes(*rows, bs, m, itemsize, got) <= kernels._VMEM_BUDGET_BYTES
+    assert (
+        2 * got * bs > kernels.GROUPED_STEP_POSITIONS or 2 * got > kernels.MAX_COLUMNS_PER_STEP
+        or kernels._grouped_vmem_bytes(*rows, bs, m, itemsize, 2 * got) > kernels._VMEM_BUDGET_BYTES
+    )
+    assert kernels.grouped_columns_per_step(bs, rows, m, itemsize, max_blocks=3) == 3
+    # a table is walked in as few steps as the most columns a step allow, and those steps are even
+    for cols in (17, 48, 64, 65, 128):
+        per_step = kernels.grouped_columns_per_step(bs, rows, m, itemsize, max_blocks=cols)
+        assert per_step <= got and -(-cols // per_step) == -(-cols // min(got, cols))
+        assert per_step * -(-cols // per_step) - cols < -(-cols // per_step)  # fewer dead columns than steps
+    if cell == "mellum2-12b.code-gen":
+        assert kernels.grouped_columns_per_step(bs, rows, m, itemsize, max_blocks=17) == 6  # 6 + 6 + 5, not 8 + 8 + 1
+    # a verify window of 5 holds five times the query rows: never more columns than the decode call
+    assert 1 <= kernels.grouped_columns_per_step(bs, rows, 5 * m, itemsize) <= got
+
+
+def test_walk_report_counts_the_steps_of_a_call():
+    from flexflow_tpu.ops.kernels.decode_attention import paged_grid, paged_walk
+
+    report = lambda columns, grid, walk: {"columns_per_step": columns, "grid_steps": grid, "walk_steps_at_most": walk}
+    # Mellum2's decode calls: 48 slots over 48 columns (8 a step) and over 17 (6 a step), a grid step a slot
+    assert paged_walk(4, 128, 64, 8, 2, 8, 48, 48) == report(8, 48, 48 * 6)
+    assert paged_walk(4, 128, 64, 8, 2, 8, 48, 17) == report(6, 48, 48 * 3)
+    # LFM2's: 64 slots over 64 columns of 16 positions, 32 a step; Command A+'s: 16 over 128 and 65, 4 a step
+    assert paged_walk(8, 64, 16, 4, 2, 4, 64, 64) == report(32, 64, 64 * 2)
+    assert paged_walk(8, 128, 64, 16, 2, 16, 16, 65) == report(4, 16, 16 * 17)
+    assert paged_walk(8, 128, 64, 16, 2, 16, 16, 128) == report(4, 16, 16 * 32)
+    # a group-1 call walks one column a grid step, every column of the table, as it did
+    assert paged_walk(16, 64, 16, 1, 4, 1, 8, 64) == report(1, 8 * 64, 8 * 64)
+    assert paged_walk(16, 64, 16, 1, 4, 1, 2, 64, kv_splits=8) == report(1, 2 * 8 * 8, 2 * 8 * 8)
+    # a split grouped call: a grid step a split, whose walk is over its share of the table
+    assert paged_walk(4, 128, 64, 8, 2, 8, 2, 48, kv_splits=4) == report(6, 2 * 4, 2 * 4 * 2)
+    assert paged_grid(1, 8, 64, 4) == (8, 4, 16) and paged_grid(8, 2, 33, 3) == (2, 3) and paged_grid(8, 2, 33) == (2,)
 
 
 def _kernel_bodies(fn, *args):
@@ -554,8 +755,15 @@ def test_grouped_call_scores_on_the_mxu(splits):
     assert call["name"] == ("paged_append_attention" if splits == 1 else "paged_append_attention_split")
     assert call["primitives"]["dot_general"] == 2 and "scan" not in call["primitives"]
     rows = 2 * 8 * 2  # window queries x cache rows x heads a row
-    assert call["scratch"] == [(rows, 1), (rows, 1), (rows, 128)]
-    assert call["grid"] == ((8, 64) if splits == 1 else (8, 4, 16))
+    # two buffers of a step's 16 columns of 16 positions for K and for V, their copies' semaphores, what a
+    # program hands the next, and the query rows' softmax state
+    assert call["scratch"] == [(2, 256, 8, 128)] * 2 + [(2, 2), (2,), (rows, 1), (rows, 1), (rows, 128)]
+    # a grid step a sequence (a split of one): the walk over the table's columns is the kernel's own
+    assert call["grid"] == ((8,) if splits == 1 else (8, 4))
+    # a copy of K and one of V a column of the step, in a loop over the step's live columns: waited for in one
+    # place and started in two (a program's own first step; the step after the one being folded, which at a
+    # program's last step is the next program's first)
+    assert call["primitives"]["dma_wait"] == 2 and call["primitives"]["dma_start"] == 2 * 2
     (module,) = _mosaic_modules(*_gpt2_call(1, splits, heads=32))
     assert module.count("tpu.matmul") == 2
 
@@ -570,7 +778,7 @@ def test_windowed_grouped_call_keeps_its_name():
     (call,) = _kernel_bodies(
         lambda q, k, v, t, p, f: paged_append_attention(q, k, v, 8, t, p, window=1024, first_positions=f), *args
     )
-    assert call["name"] == "paged_window_attention" and call["grid"] == (48, 17)
+    assert call["name"] == "paged_window_attention" and call["grid"] == (48,)
     assert call["primitives"]["dot_general"] == 2
 
 
@@ -586,6 +794,12 @@ def test_refusal_and_vmem_estimate_describe_the_body_that_runs():
     assert paged_kernel_refusal(4, 128, 64, 8, 2, group=8) is None  # Mellum2's decode call
     assert paged_kernel_refusal(8, 64, 16, 4, 2, group=4) is None  # LFM2's
     assert _vmem_bytes(4, 128, 64, 8, 2, group=8) != _vmem_bytes(4, 128, 64, 8, 2)
+    # at the walk the call takes: two buffers of K and of V of a step's columns and the step once more (3 x
+    # 2 MiB: 8 columns of 64 positions of rows padded to 8 x 128, as 32 columns of 16), the scores of its 2,048
+    # lines under 32 query rows four times over (1 MiB), Q / O / positions / state (112 KiB)
+    assert _vmem_bytes(4, 128, 64, 8, 2, group=8) == _vmem_bytes(8, 64, 16, 4, 2, group=4) == 3 * (2 << 20) + (1 << 20) + 114688
+    # a block of 8 K/V heads under 128 query rows: 4 columns a step (8 would be 20 MiB), 4 MiB of scores
+    assert _vmem_bytes(8, 128, 64, 16, 2, group=16) == 3 * (1 << 20) + (4 << 20) + 458752
     assert "MiB of VMEM" in paged_kernel_refusal(64, 128, 64, 32, 4, group=4)
 
 
@@ -619,16 +833,21 @@ def _toy_engine(which):
 
 @pytest.mark.parametrize("on_chip", [False, True], ids=["cpu_backend", "tpu_gate"])
 @pytest.mark.parametrize("which,kinds,group,body", [
-    ("gpt2", ["full"], 1, "vpu"),
-    ("lfm2", ["full"], 2, "mxu"),
-    ("mellum2", ["full", "window"], 2, "mxu"),
+    # (table columns a step of the walk, grid steps a call, the walk's steps at most) of a kind's decode call:
+    # two slots over tables of 8 columns of 8 positions (the window kind: 3), a group-1 call split by its own
+    # rule (two slots or fewer) or not, a grouped call a grid step a slot
+    ("gpt2", {"full": (1, 16, 16)}, 1, "vpu"),
+    ("lfm2", {"full": (8, 2, 2)}, 2, "mxu"),
+    ("mellum2", {"full": (8, 2, 2), "window": (3, 2, 2)}, 2, "mxu"),
 ])
 def test_stats_and_startup_line_name_the_body_of_each_attention_kind(which, kinds, group, body, on_chip, monkeypatch, caplog):
     """``/v2/stats`` ``kernels`` and the server's start-up line say, per
     attention kind of the loaded model, which body its paged decode call
     lowered to and at what group: the XLA composition on the CPU backend;
     through the TPU's gate the VPU body for GPT-2 and the MXU body for
-    the two grouped models, the group read off the shapes."""
+    the two grouped models, the group read off the shapes, and beside
+    them the walk that call runs over its block table: the columns a
+    step and the steps a call (nothing for the composition)."""
     import logging
 
     import flexflow_tpu.ops.attention as attention
@@ -639,7 +858,11 @@ def test_stats_and_startup_line_name_the_body_of_each_attention_kind(which, kind
         monkeypatch.setattr(attention, "on_tpu", lambda: True)
         engine.backend = "tpu"
         engine.attention_kernels = engine.paged_lowerings()
-    want = {kind: {"body": body if on_chip else "reference", "group": group} for kind in kinds}
+    keys = ("columns_per_step", "grid_steps", "walk_steps_at_most")
+    want = {
+        kind: {"body": body if on_chip else "reference", "group": group, **(dict(zip(keys, walk)) if on_chip else {})}
+        for kind, walk in kinds.items()
+    }
     model = GenerationModel(engine, name=which)
     assert model.scheduler.stats.snapshot()["kernels"] == want
     with caplog.at_level(logging.INFO, logger="flexflow_tpu.serving.generation"):
@@ -647,5 +870,6 @@ def test_stats_and_startup_line_name_the_body_of_each_attention_kind(which, kind
         model.stop(drain=False)
     (line,) = [r.getMessage() for r in caplog.records if "paged attention" in r.getMessage()]
     assert line.startswith(f"generation model '{which}' starts: paged attention ")
-    for kind in kinds:
-        assert f"{kind}: {want[kind]['body']} body at group {group}" in line
+    for kind, low in want.items():
+        walk = f", {low['columns_per_step']} columns a step over {low['grid_steps']} grid steps a call" if on_chip else ""
+        assert f"{kind}: {low['body']} body at group {group}{walk}" in line and ("columns a step" in line) == on_chip

@@ -476,6 +476,42 @@ def test_the_streamed_prefill_call_compiles_for_the_v5e_at_the_cells_buckets(seq
     assert compiled.memory_analysis().temp_size_in_bytes <= moved < 4 * heads * seq * seq / 8
 
 
+@pytest.mark.parametrize("heads, kv_heads, head_dim, block, slots, columns, window, w, splits", [
+    (32, 8, 64, 16, 64, 64, 0, 1, 1),          # gen-batch: LFM2's decode call, 32 columns of 16 positions a step
+    (32, 4, 128, 64, 48, 48, 0, 1, 1),         # code-gen: Mellum2's full layers, 8 columns a step
+    (32, 4, 128, 64, 48, 17, 1024, 1, 1),      # ... and its window layers: 17 columns in steps of 6
+    (32, 4, 128, 64, 48, 48, 0, 5, 1),         # ... a verify window of 5: 160 query rows
+    (128, 8, 128, 64, 16, 128, 0, 1, 1),       # long-doc: Command A+'s full layer at group 16, 4 columns a step
+    (128, 8, 128, 64, 16, 65, 4096, 1, 1),     # ... and its window layers: 65 columns
+    (32, 4, 128, 64, 2, 48, 0, 1, 4),          # two slots: the decode call's own rule splits the table
+])
+def test_the_grouped_paged_call_compiles_for_the_v5e_at_the_cells_shapes(
+    heads, kv_heads, head_dim, block, slots, columns, window, w, splits, one_v5e_chip
+):
+    """``paged_append_attention`` at the three grouped cells' calls
+    (bfloat16), compiled by the TPU's own compiler: Mosaic takes the walk
+    (copies of its own out of the whole cache, a step's columns as one
+    matrix, a loop as long as the context), ONE custom call under the
+    name the trace readers look for, and neither cache is copied,
+    sliced or converted on the way in (the temporaries are the query
+    rows' and the partials')."""
+    from flexflow_tpu.ops.kernels.decode_attention import cache_row_shape, paged_append_attention
+
+    sds = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_v5e_chip)  # noqa: E731
+    cache = sds(jnp.bfloat16, 3, slots * columns + 1, block, *cache_row_shape(kv_heads, head_dim))
+    args = (sds(jnp.bfloat16, slots, w, heads, head_dim), cache, cache, sds(jnp.int32, slots, columns),
+            sds(jnp.int32, slots, w), sds(jnp.int32, slots))
+
+    def call(q, k, v, tables, positions, first):
+        bounds = {"window": window, "first_positions": first} if window else {}
+        return paged_append_attention(q, k, v, 2, tables, positions, kv_splits=splits, **bounds)
+
+    text = (compiled := _compile_uncached(jax.jit(call), *args)).as_text()
+    name = ("paged_window_attention" if window else "paged_append_attention") + ("_split" if splits > 1 else "")
+    assert text.count("tpu_custom_call") == 1 and f"{name}" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * slots * w * heads * head_dim * max(splits, 2)
+
+
 @pytest.mark.parametrize("hidden, width, held, experts, k, heads", [(2304, 896, 64, 64, 8, 32), (2048, 768, 16, 256, 8, 64)])
 @pytest.mark.parametrize("rows", [1536, 2048])
 def test_the_grouped_expert_layer_compiles_for_the_v5e_at_the_cells_buckets(rows, hidden, width, held, experts, k, heads, one_v5e_chip):

@@ -273,7 +273,7 @@ def test_the_gate_sends_a_suffix_prefill_s_window_to_the_composition():
     assert da.latent_kernel_refusal(32, 640, 64, 2) is None  # the cell's decode call
     assert "query rows" in da.latent_kernel_refusal(2048 * 32, 640, 64, 2)
     cache = jax.ShapeDtypeStruct((20, 33, 64, 640), jnp.bfloat16)
-    assert attention.latent_call_lowering(32, cache, backend="cpu") == {"body": "reference", "group": 32}
+    assert attention.latent_call_lowering(32, cache, backend="cpu", batch=48, max_blocks=48) == {"body": "reference", "group": 32}
 
 
 @pytest.mark.parametrize("heads, dim", [(4, 192), (1, 576), (2, 160)])
