@@ -348,7 +348,8 @@ class InFlightDecode:
         # restamped by the scheduler when the PREVIOUS in-flight step
         # completes: with a one-deep pipeline this step only starts
         # executing then, so the execute span (and the watchdog's view
-        # of its age) is measured from here, not from dispatch
+        # of its age) is measured from here, not from dispatch. None
+        # (finish_decode sets it): from where the host parks
         self.t_started = t_disp
         self.traced = traced
         self.n_active = n_active
@@ -893,6 +894,27 @@ class GenerationEngine:
     def upload_stats(self) -> Dict[str, int]:
         """The ``uploads`` section of ``/v2/stats``."""
         return dict(self.uploads)
+
+    def register_stats(self, stats) -> None:
+        """Surface the engine on a ServingStats: its compute gauges
+        (useful FLOPs over execute seconds, retrace blame) and the
+        sections of ``/v2/stats`` it owns. What the layers that are not
+        attention-and-MLP count is absent for a configuration without
+        them. (``uploads`` is the tracing layer's to add: absent with
+        the scheduler's observability off.)"""
+        stats.add_gauge("mfu", self.mfu)
+        stats.add_gauge("model_tflops_total", lambda: self.total_flops() / 1e12)
+        stats.add_gauge("achieved_tflops", lambda: self.total_flops() / max(1e-9, self.total_device_time_s()) / 1e12)
+        stats.add_gauge("retraces_blamed", self.programs.total_retraces)
+        stats.add_section("sampling", self.sampling_stats)
+        stats.add_section("kernels", self.kernel_stats)
+        stats.add_section("prefill_attention", self.prefill_attention_stats)
+        if self.expert_counts:
+            stats.add_section("experts", self.expert_stats)
+        if self.state_config is not None:
+            stats.add_section("conv_state", self.conv_state_stats)
+        if self.window_config is not None or self.cache_config.latent:
+            stats.add_section("cache", self.cache_stats)
 
     def _register_strategy_predictions(self) -> None:
         """Put the chosen serving layout's predicted step times into the
@@ -2270,8 +2292,8 @@ class GenerationEngine:
         """After a decode program's call: the positions and counts it
         returned become the staged entries the next step's are compared
         with, beside the host's same sums (``advanced``). (The call
-        itself stays in ``decode`` / ``decode_async``: every Python
-        frame between them and the jit is one more frame in the location
+        itself stays in ``decode_async``: every Python
+        frame between it and the jit is one more frame in the location
         of every operation the program's first trace and lowering emit,
         and two of them cost a 24-layer program 4 s of set-up.)"""
         for name, host, dev in zip(CARRIED, advanced, (positions, counts)):
@@ -2332,46 +2354,30 @@ class GenerationEngine:
         the call ``last_finite[i]`` says whether slot i's logits were
         finite — the supervisor's per-slot NaN blame vector.
         ``seeds``/``counts`` replace the old host-built key array: the
-        per-slot sampling key derives in-jit (see :func:`derive_keys`)."""
-        try:  # whatever raises from the fault site to the program's end leaves no carried entry behind
-            masked = np.where(active, tokens, 0).astype(np.int32)
-            masked, bias = faults.inject(faults.GENERATION_DECODE_STEP, (masked, self._zero_bias))
-            if self.tp_degree > 1:
-                # sharded step: the cross-shard psum boundary can fail or
-                # wedge like any device work — chaos plans target it here
-                faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
-            self.step_counts["decode"] += 1
-            self._count_expert_form(self.max_batch_slots)
-            self._children = []
-            with phase("engine.decode.dispatch", cpu=self.cpu_stamps) as disp:
-                traces_before = self.trace_counts.get("decode", 0)
-                args, context_lens, advanced = self._decode_args(
-                    masked, positions, block_tables, active, temps, top_ks, seeds,
-                    counts, bias, mask,
-                )
-                with self._part("decode", "call"):
-                    out, ok, ck, cv, state, counts, *carried = self._decode_jit(self.params, *args)
-                self._carry(advanced, *carried)
-            self._count_dispatch(disp)
-            self._dispatched()
-            with phase("engine.decode.block") as block:
-                jax.block_until_ready((out, ok, ck, cv, state))  # device execution done
-        except BaseException:
-            self._drop_carried()
-            raise
-        with phase("engine.decode.readback") as read:
-            self.cache.update(ck, cv, **state)
-            self.expert_counts = counts
-            self.last_finite = np.asarray(ok)
-            result = np.asarray(out)  # result sync lands in the readback span
-        elapsed, execute_s = self._record_step_phases("decode", disp, block, read)
-        # success-only, paired with the time below (see prefill())
-        n_active, ctx_sum = int(active.sum()), int(context_lens.sum())
-        self._account_decode(
-            n_active, ctx_sum,
-            self.trace_counts.get("decode", 0) > traces_before,
-            elapsed, execute_s,
+        per-slot sampling key derives in-jit (see :func:`derive_keys`).
+        The blocking call of the one decode body: :meth:`decode_async`
+        and :meth:`finish_decode`."""
+        return self.finish_decode(
+            self.decode_async(tokens, positions, block_tables, active, temps, top_ks, seeds, counts, mask=mask)
         )
+
+    def finish_decode(self, step: InFlightDecode) -> np.ndarray:
+        """The back half of a BLOCKING decode step, straight after its
+        :meth:`decode_async`: the dispatched hook, then
+        :meth:`consume_decode`. Nothing was in flight before the step,
+        so its ``execute`` span is the park's (``t_started`` None:
+        ``consume_decode`` takes the ``block`` span's start), and the
+        whole anatomy is published here, the dispatch's spans too. (A
+        method of its own so that the scheduler's sequential step, which
+        is the decode call that traces, can call ``decode_async`` from
+        its own frame: ONE frame more between it and the jit read 3.0-3.6
+        s of set-up on the chip for a 24-layer program, PERF.md §6,
+        PR 44.)"""
+        self._dispatched()
+        step.t_started = None
+        result = self.consume_decode(step)
+        self.last_step_spans[:0] = [("dispatch", step.t0, step.t_disp), step.post]
+        self.last_step_children = step.children
         return result
 
     def _account_decode(self, n_active, ctx_sum, traced, elapsed, execute_s):
@@ -2424,8 +2430,9 @@ class GenerationEngine:
         mask: Optional[np.ndarray] = None,
         window: Optional[Dict[str, jax.Array]] = None,
     ) -> InFlightDecode:
-        """Dispatch one decode step WITHOUT blocking on it: the overlap
-        pipeline's front half. Returns an :class:`InFlightDecode` whose
+        """Dispatch one decode step WITHOUT blocking on it: the front
+        half of every decode step (the overlap pipeline's, and the
+        blocking :meth:`decode`'s). Returns an :class:`InFlightDecode` whose
         result :meth:`consume_decode` collects one scheduler iteration
         later — the async host copy of the sampled tokens starts here,
         at dispatch-return, so the eventual readback is a wait on an
@@ -2447,11 +2454,13 @@ class GenerationEngine:
             masked = np.where(active, tokens, 0).astype(np.int32)
         else:
             masked = None
-        try:  # as in decode(): a raised dispatch leaves no carried entry behind
+        try:  # whatever raises from the fault site to the program's call leaves no carried entry behind
             masked, bias = faults.inject(
                 faults.GENERATION_DECODE_STEP, (masked, self._zero_bias)
             )
             if self.tp_degree > 1:
+                # sharded step: the cross-shard psum boundary can fail or
+                # wedge like any device work — chaos plans target it here
                 faults.inject(faults.GENERATION_COLLECTIVE, ("decode", self.tp_degree))
             self.step_counts["decode"] += 1
             self._count_expert_form(self.max_batch_slots)
@@ -2504,7 +2513,7 @@ class GenerationEngine:
 
     def consume_decode(self, step: InFlightDecode) -> np.ndarray:
         """Block on an in-flight decode step and finish its accounting:
-        the overlap pipeline's back half. On failure the pre-step cache
+        the back half of every decode step. On failure the pre-step cache
         refs are restored (non-donating engines only) so the scheduler
         can re-run the step sequentially under the supervisor's normal
         retry/bisect machinery; a donating engine's failed step is
@@ -2531,6 +2540,13 @@ class GenerationEngine:
             result = np.asarray(step.out)  # async copy already landed
         t_exec = block.t1
         ph = self.phase_time_s["decode"]
+        if step.t_started is None:
+            # a blocking step's: the device's time is this park's, and what
+            # lay between the dispatch and it (post, the dispatched hook)
+            # is the dispatch's, so that the kind's phases stay contiguous
+            # and their sum the whole call
+            step.t_started = block.t0
+            ph["dispatch"] += block.t0 - step.t_disp
         ph["execute"] += t_exec - step.t_started
         ph["readback"] += read.t1 - t_exec
         # two-lane spans: "execute" starts at t_started (when the device

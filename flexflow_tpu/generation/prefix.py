@@ -285,6 +285,15 @@ class PrefixCache:
     def hit_ratio(self) -> float:
         return self.hits / self.lookups if self.lookups else 0.0
 
+    def register_gauges(self, stats) -> None:
+        """Prefix-cache telemetry (flexflow_serving_prefix_cache_*):
+        hit ratio, reuse volume, COW copies, host-tier swaps and
+        residency — counters ride as gauges like the cache_* family."""
+        stats.add_gauge("prefix_cache_hit_ratio", self.hit_ratio)
+        for name in ("blocks_reused_total", "tokens_reused_total", "cow_copies_total", "swaps_in_total",
+                     "swaps_out_total", "host_bytes", "resident_blocks", "offloaded_blocks"):
+            stats.add_gauge("prefix_cache_" + name, lambda name=name: getattr(self, name))
+
     def match(self, prompt: Sequence[int]) -> List[PrefixEntry]:
         """The longest cached run of full blocks along ``prompt``
         (resident and offloaded entries mixed), touched for LRU. Walks

@@ -504,29 +504,20 @@ class _Running:
 class _Frontier:
     """The overlap pipeline's in-flight frontier: AT MOST ONE
     outstanding decode step. Captures the dispatch-time slot states and
-    the host-side argument arrays (reused — bumped by one — for the
-    next dispatch, so steady state rebuilds nothing), plus the
+    the host-side argument arrays (``slots``: ``_collect_slots``'s after
+    the token array, reused — bumped by one — for the next dispatch, so
+    steady state rebuilds nothing), plus the
     heartbeat seq the watchdog/stall bookkeeping is keyed on. ``seq0``
     is the scheduler's heartbeat seq just BEFORE this dispatch: a stall
     flagged on any later seq belongs to this frontier chain and voids
     its (late) result. Loop-thread only."""
 
-    __slots__ = (
-        "handle", "states", "positions", "active", "temps", "top_ks",
-        "seeds", "counts", "tables", "sig", "hb_seq", "seq0",
-    )
+    __slots__ = ("handle", "states", "slots", "sig", "hb_seq", "seq0")
 
-    def __init__(self, handle, states, positions, active, temps, top_ks,
-                 seeds, counts, tables, sig, hb_seq, seq0):
+    def __init__(self, handle, states, slots, sig, hb_seq, seq0):
         self.handle = handle
         self.states = states
-        self.positions = positions
-        self.active = active
-        self.temps = temps
-        self.top_ks = top_ks
-        self.seeds = seeds
-        self.counts = counts
-        self.tables = tables
+        self.slots = slots
         self.sig = sig
         self.hb_seq = hb_seq
         self.seq0 = seq0
@@ -711,17 +702,7 @@ class ContinuousBatchingScheduler:
             engine.prefix_cache.observe = self.stats.observe
             self.stats.add_section("loop", self._loop_section)
             self.stats.add_section("uploads", engine.upload_stats)
-        self.stats.add_section("sampling", engine.sampling_stats)
-        self.stats.add_section("kernels", engine.kernel_stats)
-        self.stats.add_section("prefill_attention", engine.prefill_attention_stats)
-        # what the layers that are not attention-and-MLP count (cumulative,
-        # /v2/stats): absent for a configuration without them
-        if engine.expert_counts:
-            self.stats.add_section("experts", engine.expert_stats)
-        if engine.state_config is not None:
-            self.stats.add_section("conv_state", engine.conv_state_stats)
-        if engine.window_config is not None or engine.cache_config.latent:
-            self.stats.add_section("cache", engine.cache_stats)
+        engine.register_stats(self.stats)
         self.spec_stats = SpeculationStats()
         self.spec_stats.register_gauges(self.stats)
         # capacity & compute observability (obs/capacity.py, obs/slo.py):
@@ -766,67 +747,18 @@ class ContinuousBatchingScheduler:
                     1 for r in list(self._queue) if r.priority == p
                 ),
             )
-        # prefix-cache telemetry (flexflow_serving_prefix_cache_*):
-        # hit ratio, reuse volume, COW copies, host-tier swaps and
-        # residency — counters ride as gauges like the cache_* family
-        pc = engine.prefix_cache
-        self.stats.add_gauge("prefix_cache_hit_ratio", pc.hit_ratio)
-        self.stats.add_gauge(
-            "prefix_cache_blocks_reused_total", lambda: pc.blocks_reused_total
-        )
-        self.stats.add_gauge(
-            "prefix_cache_tokens_reused_total", lambda: pc.tokens_reused_total
-        )
-        self.stats.add_gauge(
-            "prefix_cache_cow_copies_total", lambda: pc.cow_copies_total
-        )
-        self.stats.add_gauge(
-            "prefix_cache_swaps_in_total", lambda: pc.swaps_in_total
-        )
-        self.stats.add_gauge(
-            "prefix_cache_swaps_out_total", lambda: pc.swaps_out_total
-        )
-        self.stats.add_gauge("prefix_cache_host_bytes", lambda: pc.host_bytes)
-        self.stats.add_gauge(
-            "prefix_cache_resident_blocks", lambda: pc.resident_blocks
-        )
-        self.stats.add_gauge(
-            "prefix_cache_offloaded_blocks", lambda: pc.offloaded_blocks
-        )
+        engine.prefix_cache.register_gauges(self.stats)
         self.goodput = GoodputStats()
         self.goodput.register_gauges(self.stats)
         self.slo = SLOMonitor(slo_objectives, clock=self.clock)
         self.slo.register_gauges(self.stats)
-        self.stats.add_gauge("mfu", self.engine.mfu)
-        self.stats.add_gauge(
-            "model_tflops_total", lambda: self.engine.total_flops() / 1e12
-        )
-        self.stats.add_gauge(
-            "achieved_tflops",
-            lambda: self.engine.total_flops()
-            / max(1e-9, self.engine.total_device_time_s()) / 1e12,
-        )
-        self.stats.add_gauge("retraces_blamed", self.engine.programs.total_retraces)
         # steady-state retrace blame rides the flight ring next to the
         # step that caused it ("decode retraced: batch 8 -> 9")
         self.engine.programs.on_retrace = self._note_retrace
         # cost-model truth (obs/truth.py): predicted-vs-measured step
         # times as perf_* gauges, drift alarms onto the flight ring,
         # full pairs on GET /v2/debug/predictions
-        self.stats.add_gauge(
-            "perf_prediction_pairs", lambda: self.engine.ledger.pairs_total
-        )
-        self.stats.add_gauge(
-            "perf_prediction_error_p50",
-            lambda: self.engine.ledger.error_summary()["abs_err_p50"],
-        )
-        self.stats.add_gauge(
-            "perf_prediction_error_max",
-            lambda: self.engine.ledger.error_summary()["abs_err_max"],
-        )
-        self.stats.add_gauge(
-            "perf_drift_alarms", lambda: self.engine.ledger.alarms_total
-        )
+        self.engine.ledger.register_gauges(self.stats)
         self.engine.ledger.on_alarm = self._note_drift
         # overlapped decode (ISSUE 13): steady-state decode runs as a
         # two-deep software pipeline — step N+1 dispatched (tokens
@@ -2225,16 +2157,9 @@ class ContinuousBatchingScheduler:
                 )
                 if len(state.blocks) >= need:
                     break
-                got = self.engine.allocator.allocate(1)
-                if got is None:
-                    # evict an unreferenced cached prefix (offloading it
-                    # to the host tier) before shrinking anyone's window
-                    # or preempting a live sequence; the swap-out device
-                    # read rides the heartbeat for the watchdog
-                    with self._stamped():
-                        reclaimed = self.engine.reclaim_cached(1)
-                    if reclaimed:
-                        got = self.engine.allocator.allocate(1)
+                # (an unreferenced cached prefix goes before anyone's
+                # window shrinks or a live sequence is preempted)
+                got, _ = self._take_block()
                 if got is not None:
                     state.blocks.extend(got)
                     continue
@@ -2249,6 +2174,34 @@ class ContinuousBatchingScheduler:
                     self._preempt_self(state)
                     break
 
+    def _take_block(self) -> Tuple[Optional[List[int]], int]:
+        """One block for a running sequence's growth, sequential
+        (``_grow``) or pipelined (``_try_pipeline``): from the free
+        list, else one unreferenced cached prefix is evicted (offloaded
+        to the host tier below its budget) and the block taken again.
+        Returns the block (None: nothing evictable is left) and the
+        blocks the eviction freed. Sound with a step in flight: a
+        victim has ``refs == 0``, so no running stream's table names it
+        and the in-flight step neither reads nor writes it; the step
+        that will write it is dispatched after, on that step's cache
+        outputs, so the device orders the two; and a swap-out read is
+        enqueued on those same outputs, behind it. That read is the one
+        part that can wedge, and it wedges behind the step in flight,
+        whose own heartbeat stamp stands in for it (``_stamped`` would
+        clear that stamp on exit and shift the sequence the step's
+        stall flags are scoped by). With no step in flight the reclaim
+        takes a stamp of its own for the watchdog."""
+        got, freed = self.engine.allocator.allocate(1), 0
+        if got is None:
+            if self._pipe is not None:
+                freed = self.engine.reclaim_cached(1)
+            else:
+                with self._stamped():
+                    freed = self.engine.reclaim_cached(1)
+            if freed:
+                got = self.engine.allocator.allocate(1)
+        return got, freed
+
     def _preempt_self(self, state: _Running) -> None:
         self.capacity.note_preempt(len(state.blocks))
         self.engine.stash_prefix(state)  # see _preempt_youngest
@@ -2262,14 +2215,18 @@ class ContinuousBatchingScheduler:
         with self._lock:
             self._queue.appendleft(req)
 
-    def _collect_slots(self, order):
+    def _collect_slots(self, order, covered=()):
         """Slot-indexed arrays every batched device step needs: the
         seed token (last emitted, not yet cached), its cache position,
         block tables, the live mask, and per-slot sampling params —
-        shared by the decode and verify assemblies so the two paths
-        cannot drift. ``seeds``/``counts`` feed the engine's in-jit
+        shared by the decode, verify and pipelined assemblies so the
+        paths cannot drift. ``seeds``/``counts`` feed the engine's in-jit
         sampling-key derivation (ISSUE 13): byte-identical keys to the
-        old host fold_in, with zero host key assembly on the hot path."""
+        old host fold_in, with zero host key assembly on the hot path.
+        ``covered``: the slots a step in flight covers. Each is one
+        token ahead of what the host has bookkept, in position and in
+        count (its seed token is that step's output, on the device: the
+        one here is stale and its caller passes the device's)."""
         b = self.engine.max_batch_slots
         last = np.zeros((b,), np.int32)
         start = np.zeros((b,), np.int32)
@@ -2282,14 +2239,15 @@ class ContinuousBatchingScheduler:
         for state in order:
             i = state.slot
             req = state.req
+            pend = 1 if i in covered else 0
             last[i] = req.generated[-1] if req.generated else req.prompt[-1]
-            start[i] = state.cached_len  # next cache position
+            start[i] = state.cached_len + pend  # next cache position
             tables[i, : len(state.blocks)] = state.blocks
             active[i] = True
             temps[i] = req.sampling.temperature
             top_ks[i] = req.sampling.top_k
             seeds[i] = req.sampling.seed & 0xFFFFFFFF
-            counts[i] = req.n_generated
+            counts[i] = req.n_generated + pend
         return last, start, tables, active, temps, top_ks, seeds, counts
 
     def _decode_mask(self, order):
@@ -2357,25 +2315,33 @@ class ContinuousBatchingScheduler:
         mask = self._decode_mask(order)
 
         def step():
-            return self.engine.decode(
+            # (engine.decode's two halves called from this frame: the
+            # first call traces, and a frame more is paid in set-up)
+            return self.engine.finish_decode(self.engine.decode_async(
                 tokens, positions, tables, active, temps, top_ks, seeds,
-                counts, mask,
-            )
+                counts, mask=mask,
+            ))
 
         def probe(subset):
             # blame-assignment probe: same step with only ``subset``
             # active; outputs discarded, cache writes idempotent (the
             # SAME mask as the real step, so bisection re-runs are
-            # deterministic for constrained slots too)
+            # deterministic for constrained slots too). The slots'
+            # convolution state is no idempotent write (a step shifts
+            # it): a probe that ran puts back what it found (its
+            # engine donates nothing, or no probe would run)
             act = np.zeros((b,), bool)
             for s in subset:
                 act[s.slot] = True
+            conv = self.engine.cache.state.get("conv")
             self._probe_call(
                 lambda: self.engine.decode(
                     tokens, positions, tables, act, temps, top_ks, seeds,
                     counts, mask,
                 )
             )
+            if conv is not None:
+                self.engine.cache.state["conv"] = conv
 
         return step, probe
 
@@ -2391,12 +2357,22 @@ class ContinuousBatchingScheduler:
             out = self.supervisor.run_step("decode", step, order, probe)
         self._step_walls["device"] = p_dev.seconds
         if out is None:
-            info["handled_failure"] = True
-            return True  # failure handled: quarantined or journal-replayed
+            info["handled_failure"] = True  # quarantined or journal-replayed
+        else:
+            self._sequential_tail(order, out)
+        return True
+
+    def _sequential_tail(self, order, out) -> bool:
+        """What follows a sequential decode step whose tokens the
+        supervisor returned (``_decode_once``, and the re-run of a
+        failed pipelined step): the engine's spans adopted, NaN blame,
+        the tokens scattered and counted. False where the blame took
+        the step (a handled failure: nothing was scattered)."""
+        info = self._step_info
         info["execute_s"] = self._engine_spans(decode_result=True)
         if self._quarantine_nan("decode", order):
             info["handled_failure"] = True
-            return True
+            return False
         with self._phase("sched.bookkeep"):
             n_live, _ = self._scatter_decode(order, out)
         info["emitted"] = n_live
@@ -2636,43 +2612,18 @@ class ContinuousBatchingScheduler:
         ``carried_hits_total``); a step of another composition uploads
         what differs."""
         with self._phase("sched.stage"):
-            b = self.engine.max_batch_slots
             sig = tuple((s.slot, s.req.id, len(s.blocks)) for s in live)
             covered = {s.slot for s in prev.states} if prev is not None else set()
+            tokens_host = tokens_dev = None
             if prev is not None and prev.sig == sig:
-                positions, active = prev.positions, prev.active
-                temps, top_ks = prev.temps, prev.top_ks
-                seeds, counts, tables = prev.seeds, prev.counts, prev.tables
+                slots = prev.slots
                 for s in live:  # same composition: everyone advances by one
-                    positions[s.slot] += 1
-                    counts[s.slot] += 1
+                    slots[0][s.slot] += 1  # positions
+                    slots[-1][s.slot] += 1  # counts
             else:
-                positions = np.zeros((b,), np.int32)
-                active = np.zeros((b,), bool)
-                temps = np.zeros((b,), np.float32)
-                top_ks = np.zeros((b,), np.int32)
-                seeds = np.zeros((b,), np.uint32)
-                counts = np.zeros((b,), np.int32)
-                tables = np.zeros((b, self.engine.max_blocks_per_seq), np.int32)
-                for s in live:
-                    i = s.slot
-                    pend = 1 if i in covered else 0
-                    positions[i] = s.cached_len + pend
-                    counts[i] = s.req.n_generated + pend
-                    active[i] = True
-                    temps[i] = s.req.sampling.temperature
-                    top_ks[i] = s.req.sampling.top_k
-                    seeds[i] = s.req.sampling.seed & 0xFFFFFFFF
-                    tables[i, : len(s.blocks)] = s.blocks
-            tokens_host = None
-            tokens_dev = prev.handle.out if prev is not None else None
-            if prev is None:
-                tokens_host = np.zeros((b,), np.int32)
-                for s in live:
-                    req = s.req
-                    tokens_host[s.slot] = (
-                        req.generated[-1] if req.generated else req.prompt[-1]
-                    )
+                tokens_host, *slots = self._collect_slots(live, covered)
+            if prev is not None:
+                tokens_host, tokens_dev = None, prev.handle.out
         hb_prev = self._heartbeat
         seq0 = prev.seq0 if prev is not None else self._hb_seq
         self._hb_seq += 1
@@ -2683,13 +2634,11 @@ class ContinuousBatchingScheduler:
             # back, and the block a position starts is taken, with the
             # predecessor in flight: scheduling work, in its span
             with self._phase("sched.schedule"):
+                positions, _tables, active = slots[:3]
                 window = self.engine.advance_windows(positions, active)
         self._heartbeat = (seq, self.clock())  # dispatch stamp
         try:
-            handle = self.engine.decode_async(
-                tokens_host, positions, tables, active, temps, top_ks,
-                seeds, counts, tokens_dev=tokens_dev, window=window,
-            )
+            handle = self.engine.decode_async(tokens_host, *slots, tokens_dev=tokens_dev, window=window)
         except Exception:
             self._heartbeat = hb_prev  # the step never went in flight
             self._hb_seq = seq  # seq stays burned; stall flags on it are void
@@ -2697,10 +2646,7 @@ class ContinuousBatchingScheduler:
         self._step_spans += [("dispatch", handle.t0, handle.t_disp), handle.post]
         self._step_children += handle.children
         self._add_wall("dispatch", handle.t_disp - handle.t0)
-        return _Frontier(
-            handle, list(live), positions, active, temps, top_ks, seeds,
-            counts, tables, sig, seq, seq0,
-        )
+        return _Frontier(handle, list(live), slots, sig, seq, seq0)
 
     def _pipeline_failure(self, e: BaseException, since_seq: int) -> None:
         """A pipelined dispatch or consume failed. Discard what is in
@@ -2725,37 +2671,8 @@ class ContinuousBatchingScheduler:
             return
         step, probe = self._decode_step_fns(order)
         out = self.supervisor.resume_step("decode", e, step, order, probe, since_seq)
-        if out is None:
-            return
-        self._step_info["execute_s"] = self._engine_spans(decode_result=True)
-        if self._quarantine_nan("decode", order):
-            return
-        n_live, _ = self._scatter_decode(order, out)
-        self.token_rate.record(n_live)
-        self._step_info["handled_failure"] = False
-        self._step_info["emitted"] = n_live
-
-    def _pipeline_reclaim(self, f: Optional["_Frontier"]) -> int:
-        """Evict one unreferenced cached prefix for the pipeline's block
-        growth; returns the blocks freed (0: nothing evictable is left).
-        Sound with ``f`` in flight: a victim has ``refs == 0``, so no
-        running stream's table names it and the in-flight step neither
-        reads nor writes it; the step that will write it is dispatched
-        after, on ``f``'s cache outputs, so the device orders the two;
-        and a swap-out read (host tier below its budget) is enqueued on
-        those same outputs, behind ``f``. That read is the one part that
-        can wedge, and it wedges behind ``f``: ``f``'s own heartbeat
-        stamp stands in for it (``_stamped`` would clear that stamp on
-        exit and shift the sequence ``f``'s stall flags are scoped by).
-        With no step in flight the reclaim takes a stamp of its own,
-        as in ``_grow``."""
-        if f is not None:
-            freed = self.engine.reclaim_cached(1)
-        else:
-            with self._stamped():
-                freed = self.engine.reclaim_cached(1)
-        self.pipe_reclaims += freed
-        return freed
+        if out is not None and self._sequential_tail(order, out):
+            self._step_info["handled_failure"] = False
 
     def pipeline_stats(self) -> Dict:
         """The ``pipeline`` section of ``/v2/stats``: monotone totals of
@@ -2812,74 +2729,56 @@ class ContinuousBatchingScheduler:
             covered = {s.slot for s in f.states} if f is not None else set()
             # slots live at the NEXT dispatch: budget-predicted finishes
             # are excluded (sequential would have freed them before this
-            # step); EOS cannot be predicted and is handled at consume
-            live = []
+            # step); EOS cannot be predicted and is handled at consume.
+            # Their block tables grow for the dispatch positions. An
+            # empty free list is the prefix cache's steady state, not
+            # pressure: the block comes from the cache's unreferenced
+            # entries, as in _grow, with the step in flight. Only a
+            # pool with nothing left to evict is short (and nobody
+            # grows after it): capping speculation and preempting
+            # mutate running slots, so that pressure drains and is
+            # handled sequentially
+            live, short = [], False
             for s in order:
                 pend = 1 if s.slot in covered else 0
                 if s.req.n_generated + pend >= s.req.max_new:
                     continue
                 live.append(s)
-            # grow block tables for the dispatch positions. An empty
-            # free list is the prefix cache's steady state, not
-            # pressure: take the block from the cache's unreferenced
-            # entries, as _grow does, with the step in flight. Only a
-            # pool with nothing left to evict is short: capping
-            # speculation and preempting mutate running slots, so that
-            # pressure drains and is handled sequentially
-            short = False
-            for s in live:
-                pend = 1 if s.slot in covered else 0
                 need = self.engine.cache_config.blocks_for(s.cached_len + pend + 1)
-                while len(s.blocks) < need:
-                    got = self.engine.allocator.allocate(1)
-                    if got is None and self._pipeline_reclaim(f):
-                        got = self.engine.allocator.allocate(1)
+                while not short and len(s.blocks) < need:
+                    got, freed = self._take_block()
+                    self.pipe_reclaims += freed
                     if got is None:
                         short = True
-                        break
-                    s.blocks.extend(got)
-                if short:
-                    break
-        if not live:
-            if f is None:
-                return None
-            # stream tail: nothing left to dispatch — consume only
-            info["kind"] = "decode"
-            self._pipe = None
-            try:
-                n = self._consume_and_finish(f)
-            except Exception as e:
-                self._pipeline_failure(e, f.seq0)
-                return True
-            if n is not None:
-                info["emitted"] = n
-            return True
-        if short:
-            if f is not None:
-                info["kind"] = "decode"
-                self._drain_frontier("pressure")
-                return True
+                    else:
+                        s.blocks.extend(got)
+        if f is None and (short or not live):
             return None
         info["kind"] = "decode"
-        try:
-            new_f = self._dispatch_pipeline(live, f)
-        except Exception as e:
-            # dispatch failed host-side; the in-flight predecessor is
-            # healthy — consume it first, then give the failed step the
-            # sequential recovery treatment
-            if f is not None:
-                self._pipe = None
-                try:
-                    self._consume_and_finish(f)
-                except Exception as e2:
-                    self._pipeline_failure(e2, f.seq0)
-                    return True
-            # the predecessor (if any) consumed cleanly and cleared its
-            # own stall flags; only trips from here on concern the re-run
-            self._pipeline_failure(e, self._hb_seq)
+        if short:
+            self._drain_frontier("pressure")
             return True
+        new_f = None  # (the stream tail: nothing left to dispatch — consume only)
+        if live:
+            try:
+                new_f = self._dispatch_pipeline(live, f)
+            except Exception as e:
+                # dispatch failed host-side; the in-flight predecessor is
+                # healthy — consume it first, then give the failed step the
+                # sequential recovery treatment
+                if f is not None:
+                    self._pipe = None
+                    try:
+                        self._consume_and_finish(f)
+                    except Exception as e2:
+                        self._pipeline_failure(e2, f.seq0)
+                        return True
+                # the predecessor (if any) consumed cleanly and cleared its
+                # own stall flags; only trips from here on concern the re-run
+                self._pipeline_failure(e, self._hb_seq)
+                return True
+            self.pipe_dispatches += 1
         self._pipe = new_f
-        self.pipe_dispatches += 1
         if f is None:
             info["emitted"] = 0  # warm-up: tokens arrive next iteration
             return True

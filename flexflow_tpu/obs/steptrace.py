@@ -13,7 +13,7 @@ questions:
    ``prefix_plan`` (radix match + table assembly), ``draft``
    (speculative proposal), ``stage`` (the pipeline's assembly of a
    step's arrays), ``dispatch`` (host arg prep + XLA dispatch),
-   ``post`` (a pipelined dispatch's device-to-host copies, cache swap
+   ``post`` (a decode dispatch's device-to-host copies, cache swap
    and handle), ``block`` (host parked in ``block_until_ready``),
    ``readback`` (device->host sync + numpy conversion), ``account``
    (the engine's FLOPs and truth-ledger pair for the step), ``bookkeep``

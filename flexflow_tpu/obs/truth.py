@@ -358,6 +358,18 @@ class PredictionLedger:
                 "entries": entries,
             }
 
+    def register_gauges(self, stats) -> None:
+        """The ``perf_*`` gauges of a ServingStats (a scrape's thread
+        reads them: the counters under the lock, as everything here)."""
+        def total(name: str) -> int:
+            with self._lock:
+                return getattr(self, name)
+
+        stats.add_gauge("perf_prediction_pairs", lambda: total("pairs_total"))
+        stats.add_gauge("perf_prediction_error_p50", lambda: self.error_summary()["abs_err_p50"])
+        stats.add_gauge("perf_prediction_error_max", lambda: self.error_summary()["abs_err_max"])
+        stats.add_gauge("perf_drift_alarms", lambda: total("alarms_total"))
+
     def error_summary(self) -> Dict:
         """Cheap cross-key aggregates for the ``perf_*`` gauges.
         Memoized on the ledger's mutation stamp: the error_p50 and

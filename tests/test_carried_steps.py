@@ -159,10 +159,8 @@ def test_a_probe_that_clears_a_live_slot_misses_and_uploads(engine):
         del engine.decode
     with pytest.raises(RuntimeError, match="poisoned-input crash"):
         handles[1].result(timeout=0)
-    if engine.state_config is None:
-        # (a probe over convolution layers shifts its slots' state a second time, on the parent as here: a probe is an
-        # idempotent replay for K/V alone, ROADMAP Reach B9; the uploads below are held there too)
-        assert [handles[0].result(timeout=0), handles[2].result(timeout=0)] == [ref[0], ref[2]]
+    # (over convolution layers too: a probe puts the slots' state back, which it shifts where K/V is rewritten)
+    assert [handles[0].result(timeout=0), handles[2].result(timeout=0)] == [ref[0], ref[2]]
     assert sched.recovery_stats.quarantined == 1 and engine.resets == 0
     cleared = [(carried, g) for n, carried, g in probes if n < 3 and g["carried_misses_total"]]
     assert cleared, probes
@@ -210,3 +208,63 @@ def test_a_reset_drops_the_carried_entries_and_the_replayed_stream_is_exact(engi
     handles, sched = serve(engine, overlap=True, plan=plan, recovery=NO_SLEEP)
     assert streams(handles) == ref
     assert engine.resets > before and sched.recovery_stats.recoveries >= 1
+
+
+def test_the_blocking_call_and_its_two_halves_are_one_step(engine):
+    """``decode(x)`` is ``consume_decode(decode_async(x))`` with the
+    dispatched hook between: from the same state the same tokens, blame
+    vector, cache contents and phases (contiguous in the blocking call:
+    their sum is the whole call), and a fault raised at either leaves
+    no carried entry behind."""
+    sched = ContinuousBatchingScheduler(engine, overlap=False)
+    handles = [sched.submit(list(p), GREEDY) for p in PROMPTS]
+    for _ in range(3):
+        sched.step()
+    x = sched._collect_slots(sorted(sched._running.values(), key=lambda s: s.slot))
+    cache = engine.cache
+    k, v, state, counts = cache.k, cache.v, dict(cache.state), engine.expert_counts
+
+    def run(call):
+        cache.update(k, v, **state)  # the engine donates nothing: the step's inputs are whole
+        engine.expert_counts = counts
+        engine._drop_carried()
+        phases = dict(engine.phase_time_s["decode"])
+        tokens = call()
+        assert all(name in engine._staged for name in CARRIED)
+        grew = {name: s - phases[name] for name, s in engine.phase_time_s["decode"].items()}
+        return tokens, engine.last_finite, [np.asarray(a) for a in (cache.k, cache.v, *cache.state.values())], grew
+
+    hooked = []
+    hook, engine.on_dispatched = engine.on_dispatched, lambda: hooked.append(engine.step_counts["decode"])
+    try:
+        before = engine.step_counts["decode"]
+        blocking = run(lambda: engine.decode(*x))
+        spans = {name: (t0, t1) for name, t0, t1 in engine.last_step_spans}
+        halves = run(lambda: engine.consume_decode(engine.decode_async(*x)))
+    finally:
+        engine.on_dispatched = hook
+    assert hooked == [before + 1]  # the blocking call's dispatch, and not the other's
+    for a, b in zip(blocking[:2], halves[:2]):
+        assert np.array_equal(a, b)
+    assert len(blocking[2]) == len(halves[2]) >= 2 and all(np.array_equal(a, b) for a, b in zip(blocking[2], halves[2]))
+    assert set(blocking[3]) == set(halves[3]) == {"dispatch", "execute", "readback"}
+    assert all(s > 0 for s in blocking[3].values()) and all(s > 0 for s in halves[3].values())
+    assert set(spans) == {"dispatch", "post", "block", "execute", "readback", "account"} and spans["block"] == spans["execute"]
+    assert sum(blocking[3].values()) == pytest.approx(spans["readback"][1] - spans["dispatch"][0])
+    assert [name for name, _, _ in engine.last_step_children] == []  # a handle's went with it
+    plan = FaultPlan(seed=0).on(faults.GENERATION_DECODE_STEP, mode="error", error=TransientDeviceError)
+    for call in (engine.decode, engine.decode_async):
+        run(lambda: engine.decode(*x))
+        plan.install()
+        try:
+            with pytest.raises(TransientDeviceError):
+                call(*x)
+        finally:
+            plan.remove()
+        assert not any(name in engine._staged for name in CARRIED)
+    # the streams go on from the state the step found, as if nothing had run
+    cache.update(k, v, **state)
+    engine.expert_counts = counts
+    while any(not h.done() for h in handles):
+        assert sched.step()
+    assert streams(handles) == streams(serve(engine, overlap=False)[0])

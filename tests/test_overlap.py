@@ -567,8 +567,11 @@ def test_full_pool_with_evictable_entries_stays_pipelined(decoder_params, temper
     drain for pressure, most decode steps pipelined, streams exact."""
     kw = dict(num_blocks=32, host_cache_bytes=HOST_FULL, temperature=temperature)
     off, _, _, _ = run_full_pool(decoder_params, overlap=False, **kw)
-    on, eng, sched, log = run_full_pool(decoder_params, overlap=True, **kw)
+    seen = []
+    on, eng, sched, log = run_full_pool(
+        decoder_params, overlap=True, each_step=lambda sched: staged_as_the_sequential_loop_would(sched, seen), **kw)
     assert on == off
+    assert len(set(seen)) >= 10 and len(seen) - len(set(seen)) >= 10  # compositions that changed, and steps that bumped in place
     # the index never ran dry: every reclaim came back with a block
     assert sched.preemptions == 0 and all(freed >= 1 for *_, freed in log)
     assert sched.pipe_drains["pressure"] == 0
@@ -662,6 +665,67 @@ def test_pipeline_section_of_stats_counts_the_loop_s_decisions(decoder_params):
     off = sched_off.stats.snapshot()["pipeline"]
     assert off["decode_steps_total"] == eng_off.step_counts["decode"] > 0
     assert off["pipelined_steps_total"] == off["reclaims_total"] == 0 == sum(off["drains_total"].values())
+
+
+# ------------------- one staging and one block growth for both loops (ISSUE 44)
+def staged_as_the_sequential_loop_would(sched, seen):
+    """(An ``each_step`` of ``run_full_pool``.) After an iteration that left a step in flight, the step before it
+    has been bookkept: the arrays the pipeline staged for the one in
+    flight (over that covered set, rebuilt or bumped in place) are what
+    the sequential staging gives now, one step later."""
+    f = sched._pipe
+    if f is None:
+        return
+    _last, *sequential = sched._collect_slots(f.states)
+    assert len(f.slots) == len(sequential) == 7
+    assert all(np.array_equal(a, b) and a.dtype == b.dtype for a, b in zip(sequential, f.slots))
+    seen.append(f.sig)
+
+
+def grow_from_a_full_pool(decoder_params, overlap):
+    """One stream grows by a block out of a pool whose free list is
+    empty and whose index holds ONE unreferenced prefix; returns the
+    block it took, the reclaim's heartbeat row, the pipeline's count and
+    the stream."""
+    eng = make_engine(decoder_params, num_blocks=16, slots=2, prefix_cache=True, host_cache_bytes=HOST_FULL)
+    sched = ContinuousBatchingScheduler(eng, overlap=overlap)
+    first = sched.submit(POOL_PROMPTS[0], SamplingParams(max_new_tokens=4))
+    while not first.done():
+        sched.step()
+    h = sched.submit([3, 1, 4, 1, 5], SamplingParams(max_new_tokens=12))
+    while not sched._running or (overlap and sched._pipe is None):
+        sched.step()
+    state, = sched._running.values()
+    assert len(state.blocks) == 1 and eng.prefix_cache.evictable_blocks >= 1
+    held = eng.allocator.allocate(eng.allocator.num_free)
+    while eng.prefix_cache.evictable_blocks > 1:
+        held += eng.allocator.allocate(eng.reclaim_cached(1))
+    log, reclaim = [], eng.reclaim_cached
+
+    def logged(n):
+        f, before = sched._pipe, sched._heartbeat
+        freed = reclaim(n)
+        log.append((f is not None, before is not None and (before[0], sched._heartbeat)))
+        return freed
+
+    eng.reclaim_cached = logged
+    while len(state.blocks) == 1:
+        sched.step()
+    block = state.blocks[1]
+    assert eng.prefix_cache.evictable_blocks == 0 and eng.allocator.num_free == 0
+    eng.allocator.free(held)
+    while not h.done():
+        sched.step()
+    return block, log, sched.pipe_reclaims, h.result(timeout=0)
+
+
+def test_both_loops_take_the_same_block_from_a_full_pool_each_under_its_own_stamp(decoder_params):
+    block, (row,), reclaims, stream = grow_from_a_full_pool(decoder_params, overlap=False)
+    assert row[0] is False and row[1] and row[1][1] is not None and reclaims == 0  # a stamp of its own, and not the pipeline's count
+    block_on, (row,), reclaims, stream_on = grow_from_a_full_pool(decoder_params, overlap=True)
+    # behind the step in flight, on ITS stamp, which stands after the reclaim as before it
+    assert row[0] is True and row[1] and row[1][1][0] == row[1][0] and reclaims == 1
+    assert block_on == block and stream_on == stream
 
 
 # ------------------------------------- the streams are woken late (ISSUE 38)

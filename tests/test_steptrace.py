@@ -218,7 +218,7 @@ def test_engine_device_time_split(engine):
         )
     # the engine published real spans for the last step
     spans = dict((n, (s0, s1)) for n, s0, s1 in engine.last_step_spans)
-    assert set(spans) == {"dispatch", "block", "execute", "readback", "account"}  # the last call was a decode step
+    assert set(spans) == {"dispatch", "post", "block", "execute", "readback", "account"}  # the last call was a decode step
     assert spans["readback"][1] <= spans["account"][0]
     assert spans["block"] == spans["execute"]
 
