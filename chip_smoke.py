@@ -154,6 +154,13 @@ GROUP16_CALLS = {
     "command_a_window": dict(heads=128, kv_heads=8, head_dim=128, block=64, slots=16, columns=65,
                              layers=3, contexts=(4700, 7100), window=4096),
 }
+# A block-diffusion cell's paged call (benchmark/workloads/sdar-30b-a3b-chat.block-gen.json): a block of 4
+# rows a sequence, each at its own position and all attending to the block's last one: 4 window queries x
+# group 8 x 4 K/V heads = 128 query rows a sequence.
+BLOCK_CALLS = {
+    "sdar_block": dict(heads=32, kv_heads=4, head_dim=128, block=64, slots=64, columns=32, layers=7,
+                       contexts=(300, 1800), window=0, rows=4),
+}
 STREAM_CALL = dict(heads=128, kv_heads=8, head_dim=128, seq=6144, length=5900, window=4096)
 # The older served cells' longest prefill calls, which stay under the bound and materialise their scores
 # (ops/attention.py STREAM_SCORE_BYTES): the streamed kernel's gate takes none of them (head_dim 64, or a
@@ -305,6 +312,47 @@ def grouped_kernels_check(calls=None, columns=None) -> dict:
     finally:
         if rule is not None:
             kernels.grouped_columns_per_step = rule
+    return out
+
+
+def block_kernel_check(calls=None) -> dict:
+    """The grouped paged call at a block's rows (``BLOCK_CALLS``: W = 4
+    window queries a sequence, every row's attend bound the block's last
+    position), compiled by Mosaic, against ``reference_paged_append_attention``
+    in float32 from the same bfloat16 operands: the largest difference
+    (bfloat16's roundings of the probabilities and of the result bound
+    it), an inactive sequence exact zeros, the gate's answer at the
+    cell's shapes, and ms a call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.ops.kernels import decode_attention as kernels
+
+    out = {}
+    for name, c in (calls or BLOCK_CALLS).items():
+        q1, k_cache, v_cache, layer, tables, ctx, _ = grouped_call(name, SEED, calls or BLOCK_CALLS)
+        w, group = c["rows"], c["heads"] // c["kv_heads"]
+        reason = kernels.paged_kernel_refusal(c["kv_heads"], c["head_dim"], c["block"], w * group, 2, group=group)
+        check(reason is None, f"{name}: the gate refuses the cell's own call: {reason}")
+        q = jax.random.normal(jax.random.key(SEED + 1), (c["slots"], w, c["heads"], c["head_dim"]), jnp.bfloat16)
+        # a block ends at a multiple of the block length; every row attends up to its last position
+        last = (jnp.maximum(ctx, w) // w) * w - 1
+        bound = jnp.where(ctx[:, None] > 0, jnp.broadcast_to(last[:, None], (c["slots"], w)), -1).astype(jnp.int32)
+        args = (q, k_cache, v_cache, tables, bound)
+        call = jax.jit(lambda q, k, v, t, p: kernels.paged_append_attention(q, k, v, layer, t, p))
+        ref = jax.jit(lambda q, k, v, t, p: kernels.reference_paged_append_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32), layer, t, p))
+        got, want = call(*args).astype(jnp.float32), ref(*args)
+        worst = float(jnp.max(jnp.abs(got - want)))
+        scale = float(jnp.max(jnp.abs(want)))
+        check(np.isfinite(worst) and worst <= 2.0 ** -6 * max(scale, 1.0), f"{name}: max err {worst} against values up to {scale}")
+        check(bool(jnp.all(got[0] == 0.0)), f"{name}: an inactive sequence must emit zeros")
+        walk = kernels.paged_walk(c["kv_heads"], c["head_dim"], c["block"], w * group, 2, group, c["slots"], c["columns"], 1)
+        out[name] = {"body": kernels.kernel_body(group), "group": group, "query_rows_a_sequence": w * c["heads"], **walk,
+                     "mean_context": float(np.mean(np.asarray(ctx))), "max_abs_err": worst, "largest_value": scale,
+                     "ms_a_call": _timed_ms(call, args)}
+        log(f"block paged kernel {name}: {out[name]}")
     return out
 
 
@@ -563,6 +611,8 @@ EXPERT_LAYERS = {
     "joyai_long": dict(hidden=2048, width=768, experts=256, held=16, k=8, router="sigmoid", rows=(4096, 6144)),
     # LongCat's routed branch: 16 held of 512 experts beside 256 identity experts (768 outputs), gates 6 p_j unrenormalised
     "longcat": dict(hidden=6144, width=2048, experts=512, zero=256, held=16, k=12, router="softmax", rows=(16, 64, 1024, 4096)),
+    # SDAR's layer, every expert held: a block step of 32 / 48 / 64 slots has 128 / 192 / 256 rows, a prefill 1,024
+    "sdar": dict(hidden=2048, width=768, experts=128, held=128, k=8, router="softmax", rows=(128, 192, 256, 1024)),
 }
 EXPERT_ROWS = (32, 64, 256, 512, 1024, 1536, 2048)
 
@@ -1677,6 +1727,9 @@ def main(argv=None) -> int:
                     help="the group-16 paged calls and the streamed prefill kernel alone, at the long-document cell's sizes")
     ap.add_argument("--grouped-kernels", action="store_true",
                     help="every grouped paged call of the cells alone (GROUPED_CALLS and GROUP16_CALLS), at the cells' contexts and at half the table")
+    ap.add_argument("--block-kernel", action="store_true",
+                    help="the grouped paged call at a block-diffusion cell's rows (W = 4, group 8: 128 query rows a sequence) alone; "
+                         "with --expert-product, both")
     ap.add_argument("--walk-sweep", action="store_true",
                     help="the grouped paged calls at 1 to 32 table columns a grid step, ms a call")
     ap.add_argument("--latent-prefill", action="store_true",
@@ -1723,6 +1776,10 @@ def main(argv=None) -> int:
     elif args.grouped_kernels:
         summary["tree"] = args.tree or "."
         summary["kernels"] = {"grouped": grouped_kernels_check({**GROUPED_CALLS, **GROUP16_CALLS})}
+    elif args.block_kernel:
+        summary["kernels"] = {"block": block_kernel_check()}
+        if args.expert_product is not None:
+            summary["experts"] = expert_product_check(args.expert_product)
     elif args.walk_sweep:
         summary["walk_sweep"] = walk_sweep()
     elif args.latent_prefill:

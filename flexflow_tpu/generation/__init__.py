@@ -49,7 +49,7 @@ HTTP (SSE) and gRPC.
 """
 from .cache import BlockAllocator, CacheConfig, KVCache
 from .decoder import DecoderParams, forward_full, init_decoder_params
-from .engine import GenerationEngine, SamplingParams
+from .engine import BlockDiffusion, GenerationEngine, SamplingParams
 from .prefix import PrefixCache, PrefixEntry
 from .sharding import ServingLayout
 from .recovery import (
@@ -76,6 +76,7 @@ from .speculative import (
 
 __all__ = [
     "BlockAllocator",
+    "BlockDiffusion",
     "CacheConfig",
     "ContinuousBatchingScheduler",
     "DecoderParams",

@@ -111,6 +111,15 @@ equal in exact arithmetic:
   (sum_j p_i(t, j) c(j)) W_UV_i``. ``W_UK`` / ``W_UV`` are the two halves
   of the stored ``W_UKV``, sliced where they are used.
 
+**Block diffusion** (``block_mask = B`` > 0). The mask is not causal:
+position ``i`` attends position ``j`` iff ``j // B <= i // B`` — every
+earlier block, and the whole of its own, later rows included — and the
+logits at position ``i`` are the distribution of the token AT ``i`` (a
+masked position predicts itself; nothing is shifted). ``forward_full``
+and ``prefill`` run under that mask; the step is :func:`block_step`, a
+:func:`verify_step` whose ``B`` rows a sequence are rotated at their
+own positions and all attend up to the block's last one.
+
 Four forwards over one params pytree, all through :func:`_layers`:
 
 * :func:`forward_full` — full-context causal forward, [B, S] -> logits
@@ -129,7 +138,9 @@ Four forwards over one params pytree, all through :func:`_layers`:
   the cache, [B, W] -> logits [B, W, V]: the speculative-verification
   forward (attention-only configurations) and the suffix prefill behind
   a prefix hit (any configuration: the window continues from the
-  convolution state its slots hold).
+  convolution state its slots hold). With ``attend_positions`` the
+  rows' attend bound is given apart from their own positions:
+  :func:`block_step`, a block-diffusion model's step, is that call.
 
 ``forward_full(tokens)[b, i] == decode logits after caching tokens[:i]``
 within fp32 tolerance — asserted by tests/test_generation.py and
@@ -219,6 +230,9 @@ class DecoderConfig(TransformerConfig):
     # (the cached row holds the scaled c)
     latent_q_scale: float = 1.0
     latent_kv_scale: float = 1.0
+    # block diffusion (module docstring): position i attends position j
+    # iff j // block_mask <= i // block_mask. 0: the causal mask
+    block_mask: int = 0
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.num_layers:
@@ -258,6 +272,11 @@ class DecoderConfig(TransformerConfig):
                 )
         if self.zero_experts < 0 or self.router_softmax_bias and self.router != "softmax":
             raise ValueError("zero_experts counts router outputs; router_softmax_bias is a softmax router's")
+        if self.block_mask < 0 or self.block_mask and set(self.layer_types) - {"attention"}:
+            raise ValueError(
+                f"block_mask {self.block_mask}: a block of positions that see each other is written down for "
+                f"attention layers that keep every position (layer types {sorted(set(self.layer_types))})"
+            )
 
     @property
     def kv_heads(self) -> int:
@@ -950,13 +969,18 @@ def prefill(
     cfg: Optional[TransformerConfig] = None,
     counts: Optional[List] = None,
     backend: str = "cpu",
+    head: bool = True,
 ):
     """Prefill forward: logits [B, S, V] plus every attention layer's
     K/V ([n_attn, B, S, Hkv, D] each, both kinds in layer order:
     ``cfg.kv_index`` says which array each belongs in; latent layers:
     their rows [n, B, S, RW] and a V of no width) for the engine to
     write into the cache and, for a configuration with convolution layers, a fourth
-    result: their padded ``z`` rows [n_conv, B, S + K - 1, E]."""
+    result: their padded ``z`` rows [n_conv, B, S + K - 1, E].
+    ``head`` False (a prefill that samples nothing: block diffusion's):
+    the first result is the last layer's output [B, S, E] and the head,
+    whose product over all S rows is the peak temporary of every other
+    prefill, is not run."""
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     b, s = tokens.shape
     lens = lengths if lengths is not None else jnp.full((b,), s, jnp.int32)
@@ -975,7 +999,9 @@ def prefill(
         ks.append(k)
         vs.append(v)
         with jax.named_scope(attention_scope(cfg, kind)), jax.named_scope("prefill_attention"):
-            return prefill_attention(q, k, v, lens, window=cfg.window if kind == "window" else 0, backend=backend)
+            return prefill_attention(
+                q, k, v, lens, window=cfg.window if kind == "window" else 0, backend=backend, block=cfg.block_mask
+            )
 
     def convolve(ci, z):
         with jax.named_scope("conv_state"):
@@ -986,7 +1012,7 @@ def prefill(
     x = _layers(cfg, params, x, positions, live, attend, convolve, counts)
     with jax.named_scope("head"):
         empty = jnp.zeros((0,), x.dtype)  # a configuration without attention layers
-        out = (_head(cfg, params, x), jnp.stack(ks) if ks else empty, jnp.stack(vs) if vs else empty)
+        out = (_head(cfg, params, x) if head else x, jnp.stack(ks) if ks else empty, jnp.stack(vs) if vs else empty)
     return out + (jnp.stack(zs),) if zs else out
 
 
@@ -1104,6 +1130,8 @@ def verify_step(
     conv_in: Optional[jax.Array] = None,
     counts: Optional[List] = None,
     window: Optional[Dict[str, jax.Array]] = None,
+    attend_positions: Optional[jax.Array] = None,
+    head: bool = True,
 ):
     """One chunked-append (speculative verification) step for every
     batch slot.
@@ -1129,11 +1157,18 @@ def verify_step(
     result then holds the layers' padded ``z`` rows [n_conv, B,
     W + K - 1, E], as :func:`prefill` returns them. ``window`` as in
     :func:`decode_step`, and the window layers' arrays the last result.
+
+    ``attend_positions`` ([B, W]; None: ``positions``): the last cache
+    position each row attends, where that is not its own (a block of
+    rows that see each other: :func:`block_step`; the row is still
+    rotated and written at ``positions``). ``head`` False: the first
+    result is the last layer's output [B, W, E], no logits.
     """
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     bs = cache_k.shape[2]
     state = {"k": cache_k, "v": cache_v}
     zs = []
+    attended = positions if attend_positions is None else attend_positions
 
     def slots(tables, pos):
         block, offset = jax.vmap(lambda bt, p: slot_mapping(bt, p, bs))(tables, pos)
@@ -1157,7 +1192,7 @@ def verify_step(
             with jax.named_scope("cache_write"):
                 state["k"] = write_rows(state["k"], at, block, offset, k.reshape(-1, k.shape[-1]))
             with jax.named_scope("attention.latent.absorb"):
-                return _absorbed(cfg, q, v, state["k"], at, block_tables, positions, backend)
+                return _absorbed(cfg, q, v, state["k"], at, block_tables, attended, backend)
         kk, vv, tables, blk, off, bounds = ("k", "v", block_tables, block, offset, {}) if kind != "window" else (
             "wk", "wv", window["tables"], wblock, woffset, {"window": cfg.window, "first_positions": window["first"]})
         with jax.named_scope("cache_write"):
@@ -1165,7 +1200,7 @@ def verify_step(
             state[vv] = write_rows(state[vv], at, blk, off, v.reshape(-1, *v.shape[2:]))
         with jax.named_scope(attention_scope(cfg, kind)):
             return append_attention_core(
-                q, state[kk], state[vv], at, tables, positions,
+                q, state[kk], state[vv], at, tables, attended,
                 backend=backend, mesh=mesh, **bounds,
             )
 
@@ -1179,7 +1214,52 @@ def verify_step(
         convolve if conv_in is not None else _no_conv, counts,
     )
     with jax.named_scope("head"):
-        out = (_head(cfg, params, x), state["k"], state["v"])
+        out = (_head(cfg, params, x) if head else x, state["k"], state["v"])
     if zs:
         out += (jnp.stack(zs),)
     return out if window is None else out + ({"k": state["wk"], "v": state["wv"]},)
+
+
+def block_positions(base, active, block: int):
+    """A block step's two position arrays from each slot's block base
+    ([B] int32) and its 0 / 1 ``active`` mask: the rows' own positions
+    ``base + 0 .. block - 1`` and their attend bound, the block's last
+    position for every row; -1 (padding) in an inactive slot."""
+    own = base[:, None] + jnp.arange(block, dtype=jnp.int32)[None, :]
+    live = active[:, None] > 0
+    return jnp.where(live, own, -1), jnp.where(live, jnp.broadcast_to(base[:, None] + block - 1, own.shape), -1)
+
+
+def block_step(
+    params: DecoderParams,
+    tokens: jax.Array,
+    fixed: jax.Array,
+    base: jax.Array,
+    active: jax.Array,
+    mask_token: int,
+    cache_k: jax.Array,
+    cache_v: jax.Array,
+    block_tables: jax.Array,
+    backend: str = "cpu",
+    mesh=None,
+    cfg: Optional[TransformerConfig] = None,
+    counts: Optional[List] = None,
+):
+    """One block-diffusion forward for every batch slot: the block of
+    ``B = cfg.block_mask`` positions from ``base`` ([slots]) on, rows
+    that are ``fixed`` ([slots, B] bool) embedded as their ``tokens``
+    and the others as ``mask_token``. The rows' K/V is written at the
+    block's positions (as :func:`verify_step` writes a window's: the
+    same function, given the attend bound ``base + B - 1`` for every
+    row), each row rotated at its own position and attending every
+    earlier block's kept K/V and all ``B`` rows of this one. Returns
+    (logits [slots, B, V], cache_k, cache_v): the logits at a row are
+    the distribution of the token AT that row. A forward over a block
+    whose rows are all fixed is its commit: what it writes is the K/V
+    later blocks read."""
+    cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
+    positions, attended = block_positions(base, active, cfg.block_mask)
+    return verify_step(
+        params, jnp.where(fixed, tokens, mask_token), positions, cache_k, cache_v, block_tables,
+        backend=backend, mesh=mesh, cfg=cfg, counts=counts, attend_positions=attended,
+    )

@@ -34,6 +34,17 @@ pipeline. Slot-constant args stage device-resident (``_stage``), and
 ``donate_cache`` aliases the decode/verify jits' KV-cache inputs to
 their outputs (in-place update; auto on accelerators).
 
+A BLOCK-DIFFUSION configuration (``DecoderConfig.block_mask`` = B with a
+:class:`BlockDiffusion` rule, given at construction) has ``block_step``
+in the decode program's place: ONE jitted program over every slot's
+block of B rows that see each other, with the generation rule (the
+candidates, their confidences, the rows fixed, commits) inside it after
+the head and the slots' blocks, flags, bases and forward counts carried
+on the device from step to step (:meth:`GenerationEngine.block_step`,
+a blocking call; ``scheduler._block_once`` emits what became final, in
+position order). Its prefill caches the prompt's whole blocks under the
+block mask and computes no logits.
+
 ISSUE 15 made the engine MESH-NATIVE: pass ``tp_degree`` /
 ``mesh_devices`` / ``mesh`` and the decoder weights + KV cache shard
 along the head axis over a ``"model"`` mesh axis
@@ -67,6 +78,7 @@ from .cache import (
 )
 from .decoder import (
     DecoderParams,
+    block_step,
     decode_step,
     decoder_config,
     prefill,
@@ -98,6 +110,60 @@ class PrefixPlan:
 EMPTY_PREFIX_PLAN = PrefixPlan([], None, 0, 0)
 # the staged entries that are a decode program's RESULTS (engine._staged)
 CARRIED = ("decode.positions", "decode.counts")
+# and a block step's: every slot's block (tokens, 0 / 1 flags of the rows
+# that are fixed), its base position and the denoising forwards it has had
+BLOCK_CARRIED = ("block.tokens", "block.fixed", "block.base", "block.forwards")
+REMASKING = ("low_confidence_static", "low_confidence_dynamic")
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """The generation rule of a block-diffusion model (a configuration
+    whose ``block_mask`` is ``block_length``), given to the engine at
+    construction: a served model has ONE block length (the cache's
+    positions and the prefill's mask depend on it).
+
+    Positions are laid out in blocks of ``block_length`` from 0. A block
+    starts as the prompt's remaining tokens (fixed) followed by the mask
+    token at every other row; a denoising forward runs the block's rows
+    and each masked row's candidate is its best token other than the
+    mask token, its confidence that token's softmax probability
+    (float32, whole vocabulary). ``low_confidence_static`` fixes the
+    ``block_length / denoising_steps`` masked rows of highest confidence
+    (ties to the lower position); ``low_confidence_dynamic`` every
+    masked row whose confidence passes ``threshold``, and the static
+    rule's rows if fewer pass. A fixed row stays fixed; when no row is
+    masked a commit forward runs the block over its final tokens, and
+    the K/V of THAT forward is what later blocks read.
+    ``denoising_steps``, ``remasking`` and ``threshold`` are defaults a
+    request may override (``SamplingParams``)."""
+
+    block_length: int = 4
+    denoising_steps: int = 4
+    remasking: str = "low_confidence_static"
+    threshold: float = 0.9
+    mask_token_id: int = 0
+
+    def __post_init__(self):
+        if self.block_length < 1 or self.mask_token_id < 0:
+            raise ValueError(f"block_length {self.block_length}, mask_token_id {self.mask_token_id}")
+        self.rows_per_forward(self.denoising_steps)
+        self.threshold_of(self.remasking, self.threshold)
+
+    def rows_per_forward(self, denoising_steps: Optional[int] = None) -> int:
+        """Rows the static rule fixes a denoising forward."""
+        steps = self.denoising_steps if denoising_steps is None else int(denoising_steps)
+        if steps < 1 or self.block_length % steps:
+            raise ValueError(f"{steps} denoising steps do not divide a block of {self.block_length}")
+        return self.block_length // steps
+
+    def threshold_of(self, remasking: Optional[str] = None, threshold: Optional[float] = None) -> float:
+        """The confidence a row has to pass to be fixed beyond the
+        static rule's rows: no confidence passes 2 (the static rule)."""
+        rule = self.remasking if remasking is None else remasking
+        if rule not in REMASKING:
+            raise ValueError(f"remasking {rule!r}: one of {REMASKING}")
+        return 2.0 if rule == "low_confidence_static" else float(self.threshold if threshold is None else threshold)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -117,6 +183,11 @@ class SamplingParams:
     top_k: int = 0
     eos_id: Optional[int] = None
     seed: int = 0
+    # a block-diffusion engine's defaults (:class:`BlockDiffusion`),
+    # overridden for this request; None: the engine's
+    denoising_steps: Optional[int] = None
+    remasking: Optional[str] = None
+    threshold: Optional[float] = None
 
 
 def default_buckets(max_seq_len: int, start: int = 16) -> Tuple[int, ...]:
@@ -219,7 +290,29 @@ def unsupported_paths(kind: str, dcfg) -> Dict[str, str]:
     (``GenerationEngine.unsupported``; ROADMAP "What the system cannot
     run yet")."""
     w = dcfg.window
+    b = dcfg.block_mask
     return {
+        "diffusion": {
+            "speculation": (
+                f"speculative verification (engine.verify) is refused for a block-diffusion configuration (blocks of "
+                f"{b}): a step already scores a whole block of positions that see each other, and a drafted window "
+                f"is a causal one"
+            ),
+            "constrained_decoding": (
+                f"constrained decoding is refused for a block-diffusion configuration (blocks of {b}): a grammar "
+                f"mask is a left-to-right automaton, and a block's rows are fixed out of order"
+            ),
+            "kv_handoff": (
+                f"the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a block-diffusion "
+                f"configuration (blocks of {b}): a prefill yields no first token to hand over, and the decode side "
+                f"would need the block in flight beside the committed K/V"
+            ),
+            "tensor_parallel": (
+                f"tp_degree > 1 is refused for a block-diffusion configuration (blocks of {b}): the head-sharded "
+                f"paged kernel takes a window's rows at their own positions, not a block's shared attend bound, and "
+                f"the serving layout has no placement for the expert weights"
+            ),
+        },
         "conv": {
             "speculation": (
                 "speculative verification (engine.verify) is refused for a configuration "
@@ -387,6 +480,7 @@ class GenerationEngine:
         tp_degree: Optional[int] = None,
         mesh_devices: Optional[int] = None,
         expected_prefix_sharing: float = 0.0,
+        diffusion: Optional[BlockDiffusion] = None,
     ):
         self.cfg = cfg
         # the block's choices as data (decoder.py): a plain
@@ -415,6 +509,19 @@ class GenerationEngine:
             self.unsupported.update(unsupported_paths("parallel", self.dcfg))
         if self.dcfg.shortcut_experts:
             self.unsupported.update(unsupported_paths("shortcut", self.dcfg))
+        # the generation rule of a block-diffusion model (its steps are
+        # block steps, and no decode step ever runs); None: one token a
+        # sequence a step under the causal mask
+        self.diffusion = diffusion
+        if (diffusion.block_length if diffusion else 0) != self.dcfg.block_mask:
+            raise ValueError(
+                f"a configuration with block_mask {self.dcfg.block_mask} is generated by block diffusion over blocks "
+                f"of that length, and no other is (diffusion={diffusion})"
+            )
+        if diffusion is not None:
+            if diffusion.mask_token_id >= cfg.vocab_size:
+                raise ValueError(f"mask_token_id {diffusion.mask_token_id} outside the vocabulary of {cfg.vocab_size}")
+            self.unsupported.update(unsupported_paths("diffusion", self.dcfg))
         if wants_tp and "tensor_parallel" in self.unsupported:
             raise NotImplementedError(self.unsupported["tensor_parallel"])
         # ------------------------------------------------- serving mesh
@@ -589,6 +696,22 @@ class GenerationEngine:
         self.state_snapshots_total = 0
         self.allocator = BlockAllocator(cache_config)
         self.max_blocks_per_seq = cache_config.blocks_for(self.max_seq_len)
+        if diffusion is not None:
+            # a cache block then ends where a diffusion block does, so a whole cache block's K/V depends on
+            # nothing behind it and a prefix hit of whole cache blocks is always valid; one that is no
+            # multiple would cut diffusion blocks in two, and is refused here
+            off = [n for n in (cache_config.block_size, self.max_seq_len, *self.buckets) if n % diffusion.block_length]
+            if off:
+                raise ValueError(
+                    f"block diffusion over blocks of {diffusion.block_length}: the cache's block size, max_seq_len "
+                    f"and every prompt bucket have to be multiples of it ({off} are not)"
+                )
+            # cumulative, /v2/stats "diffusion" (diffusion_stats)
+            self.diffusion_counts: Dict[str, int] = {
+                "slot_forwards_total": 0, "commit_forwards_total": 0, "tokens_fixed_total": 0, "blocks_committed_total": 0,
+            }
+            # denoising forwards of a slot by the tokens they fixed (0 .. block_length)
+            self.fixed_histogram = np.zeros((diffusion.block_length + 1,), np.int64)
         if self.buckets[-1] > self.max_seq_len:
             raise ValueError(
                 f"bucket {self.buckets[-1]} exceeds max_seq_len {self.max_seq_len}"
@@ -635,6 +758,8 @@ class GenerationEngine:
         # host-call counters: engine steps actually issued (the divisor of
         # benchmark/layer_metrics/decode_step_ms.py and batch_occupancy.py)
         self.step_counts: Dict[str, int] = {"prefill": 0, "decode": 0, "verify": 0}
+        if diffusion is not None:
+            self.step_counts["block_step"] = 0
         # per-kind step-phase seconds (the device_time_s split, ISSUE
         # 12): dispatch = host arg prep + XLA dispatch (jit call entry
         # to return), execute = dispatch-return to block_until_ready
@@ -646,7 +771,7 @@ class GenerationEngine:
         # documented in README "Step anatomy").
         self.phase_time_s: Dict[str, Dict[str, float]] = {
             k: {"dispatch": 0.0, "execute": 0.0, "readback": 0.0}
-            for k in ("prefill", "decode", "verify")
+            for k in ("prefill", "decode", "verify") + (("block_step",) if diffusion is not None else ())
         }
         # spans of the most recent engine step (obs/steptrace.py):
         # (phase, t0, t1) perf_counter stamps, overwritten per call —
@@ -705,6 +830,8 @@ class GenerationEngine:
         # permanently-wrong prediction must not spam the flight ring
         self._roofline_alarm = jax.default_backend() != "cpu"
         self.flops_by_kind: Dict[str, float] = {"prefill": 0.0, "decode": 0.0, "verify": 0.0}
+        if diffusion is not None:
+            self.flops_by_kind["block_step"] = 0.0
         # jit program registry: every traced program's static signature,
         # trace count, and compile wall time; retraces carry blame
         # strings (GET /v2/debug/programs)
@@ -797,6 +924,8 @@ class GenerationEngine:
         self._verify_jit = jax.jit(
             self._verify_impl, donate_argnums=ver_donate, **ver_sh
         )
+        # a block-diffusion engine's step (tp_degree > 1 is refused for it: no shardings)
+        self._block_jit = jax.jit(self._block_impl, donate_argnums=(5, 6) if self.donate else ())
         # cross-request prefix caching (generation/prefix.py): radix
         # index + refcounted COW blocks + host-RAM offload tier. The
         # block-level device programs below are admission-time only
@@ -915,6 +1044,8 @@ class GenerationEngine:
             stats.add_section("conv_state", self.conv_state_stats)
         if self.window_config is not None or self.cache_config.latent:
             stats.add_section("cache", self.cache_stats)
+        if self.diffusion is not None:
+            stats.add_section("diffusion", self.diffusion_stats)
 
     def _register_strategy_predictions(self) -> None:
         """Put the chosen serving layout's predicted step times into the
@@ -1044,7 +1175,8 @@ class GenerationEngine:
         })
         state, counts, rows = state or {}, counts or {}, []
         logits, ks, vs, *zs = prefill(
-            params, tokens, jnp.full((1,), length, jnp.int32), cfg=self.dcfg, counts=rows, backend=self.backend
+            params, tokens, jnp.full((1,), length, jnp.int32), cfg=self.dcfg, counts=rows, backend=self.backend,
+            head=self.diffusion is None,
         )
         if self.state_config is not None:
             state = self._write_state(state, zs[0], slot, length, block_table, 0)
@@ -1075,6 +1207,8 @@ class GenerationEngine:
                 cache_v = write_rows(cache_v, at, block, offset, vs[li, 0])
         if self.window_config is not None:
             state = dict(state, wk=wk, wv=wv)
+        if self.diffusion is not None:
+            return self._no_token(logits[0, length - 1]) + (cache_k, cache_v, state, counts)
         with jax.named_scope("sample"):
             last = logits[0, length - 1]
             ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
@@ -1083,6 +1217,15 @@ class GenerationEngine:
             last = last + mask
             token = _sample(last[None], temp[None], top_k[None], key[None])[0]
         return token, ok, cache_k, cache_v, state, counts
+
+    @staticmethod
+    def _no_token(last):
+        """What a block-diffusion engine's prefill returns where another
+        samples a token: the prompt's whole blocks are cached and no
+        logits are computed at all (the prompt's last ``P mod B`` tokens
+        enter the first block as rows already fixed), so the blame is
+        the finiteness of the last real row of the last layer's output."""
+        return jnp.int32(0), jnp.all(jnp.isfinite(last.astype(jnp.float32)))
 
     def _decode_impl(
         self, params, tokens, positions, cache_k, cache_v, block_tables, active, temps, top_ks, bias, seeds, counts, mask,
@@ -1182,6 +1325,76 @@ class GenerationEngine:
         )
         return out, jnp.where(n_draft >= 0, n_emitted, 0), ok, cache_k, cache_v
 
+    def _block_impl(
+        self, params, tokens, fixed, base, forwards, cache_k, cache_v, block_tables, active, temps, top_ks, seeds,
+        n_fix, threshold, bias, expert_counts=None,
+    ):
+        """One block-diffusion step: a forward over every slot's block
+        and, after the head, the generation rule (:class:`BlockDiffusion`).
+
+        ``tokens`` [slots, B], ``fixed`` [slots, B] (0 / 1), ``base``
+        [slots] the block's first position and ``forwards`` [slots] the
+        denoising forwards the block has had are the slots' state, kept
+        on the device from forward to forward: the last four results
+        are that state after this forward, the next step's arguments if
+        its composition is this one's (as a decode step's positions and
+        counts are). ``n_fix`` [slots]: the rows the static rule fixes a
+        forward; ``threshold`` [slots]: the confidence past which the
+        dynamic rule fixes a row (2: the static rule). A slot none of
+        whose rows was masked on entry ran its COMMIT forward: its base
+        advances by B and its block resets to the next one's start (all
+        masked). Returns the block's tokens after this forward [slots,
+        B], the rows it fixed [slots, B] bool, the commits [slots] bool,
+        the finiteness blame [slots], the cache, the expert counters and
+        the next state."""
+        self.trace_counts["block_step"] = self.trace_counts.get("block_step", 0) + 1
+        self.programs.note_trace("block_step", {
+            "params": params, "tokens": tokens, "fixed": fixed, "base": base, "forwards": forwards, "cache_k": cache_k,
+            "block_tables": block_tables, "active": active, "temps": temps, "top_ks": top_ks, "seeds": seeds,
+            "n_fix": n_fix, "threshold": threshold, "bias": bias,
+        })
+        d = self.diffusion
+        slots, b = tokens.shape
+        live, was_fixed = active > 0, fixed > 0
+        commit = jnp.logical_and(live, jnp.all(was_fixed, axis=1))
+        expert_counts, rows = expert_counts or {}, []
+        logits, cache_k, cache_v = block_step(
+            params, tokens, was_fixed, base, active, d.mask_token_id, cache_k, cache_v, block_tables,
+            backend=self.backend, mesh=self._kernel_mesh, cfg=self.dcfg, counts=rows,
+        )
+        expert_counts = self._count(expert_counts, rows, 0)
+        with jax.named_scope("sample"):
+            logits = logits + bias[:, None, None]
+            ok = jnp.all(jnp.logical_or(jnp.isfinite(logits), ~live[:, None, None]), axis=(1, 2))
+            # a row's candidate: its best token other than the mask token (never a candidate),
+            # or with a temperature a draw; its confidence: that token's probability over the
+            # whole vocabulary, float32
+            candidates = jnp.where(jnp.arange(logits.shape[-1]) == d.mask_token_id, NEG_INF, logits)
+            own = base[:, None] + jnp.arange(b, dtype=jnp.int32)[None, :]
+            keys = jax.vmap(jax.vmap(
+                lambda s, p, f: jax.random.fold_in(jax.random.fold_in(jax.random.key(s), p), f), in_axes=(None, 0, None)
+            ))(seeds, own, forwards)
+            picked = _sample(
+                candidates.reshape(slots * b, -1), jnp.repeat(temps, b), jnp.repeat(top_ks, b), keys.reshape(-1)
+            ).reshape(slots, b)
+            at_pick = jnp.take_along_axis(logits, picked[..., None], axis=-1)[..., 0]
+            confidence = jnp.exp(at_pick - jax.scipy.special.logsumexp(logits, axis=-1))
+            masked = jnp.logical_and(live[:, None], ~was_fixed)
+            # the n_fix masked rows of highest confidence, ties to the lower position
+            order = jnp.argsort(-jnp.where(masked, confidence, -1.0), axis=1, stable=True)
+            top = jnp.argsort(order, axis=1, stable=True) < n_fix[:, None]
+            passes = jnp.logical_and(masked, confidence > threshold[:, None])
+            enough = jnp.sum(passes, axis=1) >= n_fix
+            chosen = jnp.logical_and(masked, jnp.where(enough[:, None], passes, top))
+            out = jnp.where(chosen, picked, tokens)
+        # the state after this forward: a committed slot starts its next block
+        keep = lambda new, old: jnp.where(live.reshape((-1,) + (1,) * (old.ndim - 1)), new, old)  # noqa: E731
+        next_tokens = keep(jnp.where(commit[:, None], 0, out), tokens)
+        next_fixed = keep(jnp.where(commit[:, None], 0, jnp.logical_or(was_fixed, chosen).astype(jnp.int32)), fixed)
+        next_base = keep(base + b * commit.astype(jnp.int32), base)
+        next_forwards = keep(jnp.where(commit, 0, forwards + 1), forwards)
+        return out, chosen, commit, ok, cache_k, cache_v, expert_counts, next_tokens, next_fixed, next_base, next_forwards
+
     def _restore_state_impl(self, state, slot, block):
         """A prefix hit's restore: the slot's convolution state becomes
         the snapshot stored with the last matched block, from which the
@@ -1224,10 +1437,16 @@ class GenerationEngine:
         if self.window_config is not None:
             window = {"k": state["wk"], "v": state["wv"],
                       "tables": wtable["tables"][None], "first": wtable["first"][None]}
+        attended = None
+        if self.diffusion is not None:
+            # under the block mask a row attends up to its block's end (the suffix is whole blocks:
+            # a reused prefix ends on a cache block's boundary, which is a diffusion block's)
+            b = self.diffusion.block_length
+            attended = jnp.where(positions >= 0, (positions // b + 1) * b - 1, -1)
         logits, cache_k, cache_v, *zs = verify_step(
             params, tokens, positions, cache_k, cache_v, block_table[None],
             backend=self.backend, mesh=self._kernel_mesh, cfg=self.dcfg,
-            conv_in=conv_in, counts=rows, window=window,
+            conv_in=conv_in, counts=rows, window=window, attend_positions=attended, head=self.diffusion is None,
         )
         if self.window_config is not None:
             state = dict(state, wk=zs[-1]["k"], wv=zs[-1]["v"])
@@ -1238,6 +1457,8 @@ class GenerationEngine:
                 state, zs[0], slot, n_real, block_table, start // self.cache_config.block_size
             )
         counts = self._count(counts, rows, 1)
+        if self.diffusion is not None:
+            return self._no_token(logits[0, n_real - 1]) + (cache_k, cache_v, state, counts)
         last = logits[0, n_real - 1]
         ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
         last = last + mask  # grammar mask: [V], finite (see _prefill_impl)
@@ -1785,10 +2006,14 @@ class GenerationEngine:
         reuse = min(len(run) * bs, len(prompt) - 1)
         n_shared = reuse // bs
         cow = run[n_shared] if (reuse % bs and len(run) > n_shared) else None
-        if self.state_config is not None:
+        if self.state_config is not None or self.diffusion is not None:
             # a convolution state is stored at a block's END and nowhere
             # else: reuse stops on the last whole block (a fully covered
-            # prompt recomputes its last block instead of copying it)
+            # prompt recomputes its last block instead of copying it).
+            # Block diffusion: a hit that does not end on a multiple of
+            # the block length is cut back to one, and a whole cache
+            # block always ends on one (the constructor holds the cache's
+            # block size to a multiple)
             reuse, cow = n_shared * bs, None
             if not reuse:
                 return EMPTY_PREFIX_PLAN
@@ -2210,7 +2435,7 @@ class GenerationEngine:
         """Forget the staged entries a decode program returned: that
         program may have failed, and what a failed program returned is
         poisoned (an uploaded entry is not, and stays)."""
-        for name in CARRIED:
+        for name in CARRIED + BLOCK_CARRIED:
             self._staged.pop(name, None)
 
     def _decode_args(self, tokens, positions, block_tables, active, temps, top_ks, seeds, counts, bias, mask=None,
@@ -2684,6 +2909,133 @@ class GenerationEngine:
             )
         return result
 
+    def block_step(
+        self,
+        tokens: np.ndarray,
+        fixed: np.ndarray,
+        base: np.ndarray,
+        forwards: np.ndarray,
+        block_tables: np.ndarray,
+        active: np.ndarray,
+        temps: np.ndarray,
+        top_ks: np.ndarray,
+        seeds: np.ndarray,
+        n_fix: np.ndarray,
+        threshold: np.ndarray,
+    ) -> Dict[str, np.ndarray]:
+        """One block-diffusion step across all slots (:meth:`_block_impl`;
+        a blocking call, as :meth:`verify` is: the scheduler's loop has
+        no step in flight while it bookkeeps this one's result).
+
+        ``tokens`` / ``fixed`` [slots, B], ``base`` / ``forwards``
+        [slots]: the slots' blocks as the host holds them. They are
+        staged as a decode step's positions and counts are: the entries
+        are what the step before RETURNED, beside the host's same
+        arrays, so a step whose composition is that step's uploads
+        nothing. Returns ``{"tokens", "chosen", "commit"}``, this
+        forward's result: the block's tokens after it, the rows it
+        fixed, the slots that ran their commit."""
+        d = self.diffusion
+        if d is None:
+            raise NotImplementedError("block_step is a block-diffusion engine's step (diffusion=None)")
+        tokens = np.where(active[:, None], tokens, 0).astype(np.int32)
+        tokens, bias = faults.inject(faults.GENERATION_DECODE_STEP, (tokens, self._zero_bias))
+        self.step_counts["block_step"] += 1
+        self._count_expert_form(self.max_batch_slots * d.block_length)
+        self._children = []
+        try:  # whatever raises up to the readback leaves no carried entry behind
+            with phase("engine.block_step.dispatch") as disp:
+                traces_before = self.trace_counts.get("block_step", 0)
+                with self._part("block_step", "args"):
+                    act = active.astype(np.int32)
+                    state = (tokens, np.where(active[:, None], fixed, 0).astype(np.int32),
+                             np.where(active, base, 0).astype(np.int32), np.where(active, forwards, 0).astype(np.int32))
+                    carried = [self._lookup(name, host) for name, host in zip(BLOCK_CARRIED, state)]
+                    missed = any(isinstance(x, tuple) for x in carried)
+                    self.uploads["carried_misses_total" if missed else "carried_hits_total"] += 1
+                    self.sampling_steps[sampling_branch(temps, top_ks)] += 1
+                    staged = [
+                        self._lookup("block.tables", np.where(active[:, None], block_tables, 0).astype(np.int32)),
+                        self._lookup("block.active", act),
+                        self._lookup("block.temps", temps.astype(np.float32)),
+                        self._lookup("block.top_ks", top_ks.astype(np.int32)),
+                        self._lookup("block.seeds", seeds.astype(np.uint32)),
+                        self._lookup("block.n_fix", n_fix.astype(np.int32)),
+                        self._lookup("block.threshold", threshold.astype(np.float32)),
+                    ]
+                with self._part("block_step", "upload"):
+                    dev_state = [self._upload(x) for x in carried]
+                    tables, *rest = (self._upload(x) for x in staged)
+                    args = (*dev_state, self.cache.k, self.cache.v, tables, *rest, self._bias_arg(bias), self.expert_counts)
+                with self._part("block_step", "call"):
+                    out, chosen, commit, ok, ck, cv, counts, *next_dev = self._block_jit(self.params, *args)
+                for result in (out, chosen, commit, ok):
+                    # the copies start now: the readback finds the bytes on the host (four small
+                    # transfers one after another read 1.6 ms a step on the chip: PERF.md §6, PR 45)
+                    result.copy_to_host_async()
+            self._dispatched()
+            with phase("engine.block_step.block") as block:
+                jax.block_until_ready((out, chosen, commit, ok, ck, cv))
+            with phase("engine.block_step.readback") as read:
+                self.cache.update(ck, cv)
+                self.expert_counts = counts
+                self.last_finite = np.asarray(ok)
+                out, chosen, commit = np.asarray(out), np.asarray(chosen), np.asarray(commit)
+        except BaseException:
+            self._drop_carried()
+            raise
+        elapsed, execute_s = self._record_step_phases("block_step", disp, block, read)
+        with phase("engine.block_step.account", into=self.last_step_spans):
+            # the host's side of the state the device now holds (the program's last four results)
+            nxt = (
+                np.where(commit[:, None], 0, out).astype(np.int32),
+                np.where(commit[:, None], 0, state[1] | chosen).astype(np.int32),
+                state[2] + d.block_length * commit, np.where(commit, 0, state[3] + act),
+            )
+            for name, host, dev in zip(BLOCK_CARRIED, nxt, next_dev):
+                self._staged[name] = (host.astype(np.int32), dev)
+            n_active, n_commit = int(act.sum()), int(commit.sum())
+            c = self.diffusion_counts
+            c["slot_forwards_total"] += n_active
+            c["commit_forwards_total"] += n_commit
+            c["blocks_committed_total"] += n_commit
+            c["tokens_fixed_total"] += int(chosen.sum())
+            self.fixed_histogram += np.bincount(
+                chosen.sum(axis=1)[active & ~commit], minlength=d.block_length + 1
+            )
+            # success-only, paired with the time below (see prefill()): every live row attends its block's end
+            ctx_sum = int(((state[2] + d.block_length) * act).sum())
+            self.flops_by_kind["block_step"] += self.flops_model.block_flops(n_active, ctx_sum, d.block_length)
+            if self.trace_counts.get("block_step", 0) > traces_before:
+                self.programs.set_compile_time("block_step", elapsed)
+            else:
+                b = self.max_batch_slots  # EXECUTED work: every slot's rows compute
+                self.ledger.observe(
+                    "block_step",
+                    self.flops_model.roofline_s(
+                        self.flops_model.block_flops(b, ctx_sum, d.block_length),
+                        self.flops_model.block_bytes(b, ctx_sum, d.block_length),
+                    ),
+                    execute_s, label=f"block_step ({self.flops_model.chip.name})",
+                    provenance="serving roofline (ServingFlops x chip peak)", alarm=self._roofline_alarm,
+                )
+        return {"tokens": out, "chosen": chosen, "commit": commit}
+
+    def diffusion_stats(self) -> Dict:
+        """The ``diffusion`` section of ``/v2/stats`` (a block-diffusion
+        engine's): forwards by slot (a step is one forward of every live
+        slot), those that were commits, the tokens fixed, the blocks
+        committed, the denoising forwards by the tokens they fixed
+        (``fixed_per_forward_histogram[k]``, k = 0 .. block_length), and
+        the rule's defaults."""
+        d = self.diffusion
+        return {
+            **self.diffusion_counts,
+            "fixed_per_forward_histogram": [int(n) for n in self.fixed_histogram],
+            "block_length": d.block_length, "denoising_steps": d.denoising_steps, "remasking": d.remasking,
+            "threshold": d.threshold, "mask_token_id": d.mask_token_id,
+        }
+
     def generate(
         self,
         prompts: Sequence[Sequence[int]],
@@ -2764,6 +3116,9 @@ class GenerationEngine:
         (its rows are the slots) and every ``prefill[N]`` bucket (a
         suffix prefill of ``N`` rows takes the same form)."""
         programs = {"decode": self.max_batch_slots, **{f"prefill[{b}]": b for b in self.buckets}}
+        if self.diffusion is not None:  # (its step program, in the decode step's place)
+            programs = {"block_step": self.max_batch_slots * self.diffusion.block_length, **programs}
+            del programs["decode"]
         return {name: self.expert_form(rows) for name, rows in programs.items()}
 
     def paged_lowerings(self) -> Dict[str, Dict]:
@@ -2780,6 +3135,10 @@ class GenerationEngine:
         kinds = {"full": (self.cache.k, shape)}
         if self.window_config is not None:
             kinds["window"] = (self.cache.state["wk"], {**shape, "max_blocks": self.window_columns})
+        if self.diffusion is not None:
+            # the one paged call such an engine's steps make: a block's rows, W = block_length
+            # window queries a sequence (x the group), under the name of its step program
+            kinds = {"block_step": (self.cache.k, {**shape, "window": self.diffusion.block_length})}
         return {
             kind: paged_call_lowering(
                 self.dcfg.num_heads, self.dcfg.dim_per_head, arrays,
@@ -2813,7 +3172,7 @@ class GenerationEngine:
             )
             self._prefill_lowerings[bucket] = prefill_call_lowering(
                 (1, bucket, d.num_heads, width), (1, bucket, heads, width), d.dtype.size_bytes, backend=self.backend,
-                v_shape=(1, bucket, heads, value),
+                v_shape=(1, bucket, heads, value), block=d.block_mask,
             )
         return self._prefill_lowerings[bucket]
 

@@ -342,6 +342,14 @@ class Request:
         # scheduler can actually give this sequence
         self.max_new = sampling.max_new_tokens
         self.generated: List[int] = []  # tokens generated so far (total)
+        # block diffusion: for every emitted token, the index within its
+        # block of the denoising forward that fixed it (the trace and the
+        # non-streamed response carry it); and, across a preemption, the
+        # block that was in flight (base, _Block): its rows that were
+        # fixed out of order stay fixed, so the resumed stream is the
+        # unpreempted one
+        self.fixed_at: List[int] = []
+        self.block_resume = None
         self.cancelled = False
         self.preemptions = 0
         self.replays = 0  # journal-replay recoveries this stream rode out
@@ -478,12 +486,29 @@ class Request:
         return eos is not None and bool(self.generated) and self.generated[-1] == eos
 
 
+class _Block:
+    """A running sequence's block in flight (block diffusion): the
+    block's tokens, which rows are fixed, the denoising forwards it has
+    had, and for every row the forward that fixed it (-1: a row that
+    entered fixed, from the prompt or a replayed reply)."""
+
+    __slots__ = ("tokens", "fixed", "forwards", "fixed_at")
+
+    def __init__(self, length: int, given: Sequence[int] = ()):
+        self.tokens = np.zeros((length,), np.int32)
+        self.fixed = np.zeros((length,), bool)
+        self.tokens[: len(given)] = given
+        self.fixed[: len(given)] = True
+        self.forwards = 0
+        self.fixed_at = [-1] * length
+
+
 class _Running:
     """Slot-resident state for an admitted request."""
 
     __slots__ = (
         "req", "slot", "blocks", "cached_len", "admitted_seq", "step_k",
-        "shared_idx", "shared_entries",
+        "shared_idx", "shared_entries", "blk", "rule",
     )
 
     def __init__(self, req: Request, slot: int, blocks: List[int], cached_len: int, admitted_seq: int,
@@ -499,6 +524,10 @@ class _Running:
         # index — never by this sequence) and the held entries
         self.shared_idx = shared_idx if shared_idx is not None else set()
         self.shared_entries = shared_entries if shared_entries is not None else []
+        # block diffusion: the block in flight, whose first position is
+        # ``cached_len`` (the positions below it are committed)
+        self.blk: Optional[_Block] = None
+        self.rule = (0, 2.0)  # (rows the static rule fixes a forward, the confidence the dynamic one asks)
 
 
 class _Frontier:
@@ -773,6 +802,11 @@ class ContinuousBatchingScheduler:
         # like _running; the heartbeat hand-off to the watchdog thread
         # stays the documented GIL-atomic tuple swap.
         self.overlap = True if overlap is None else bool(overlap)
+        # a block-diffusion engine's rule (engine.BlockDiffusion): its
+        # steps are block steps (``_block_once``), sequential ones, and
+        # no decode step is ever dispatched, pipelined or not
+        self.diffusion = engine.diffusion
+        self._step_positions = self.diffusion.block_length if self.diffusion else 1
         self._pipe: Optional[_Frontier] = None
         # the handles that hold decode tokens bookkept and not yet on
         # their streams' queues (GenerationHandle._emit_later). A put
@@ -874,6 +908,12 @@ class ContinuousBatchingScheduler:
             > self.engine.allocator.num_total
         ):
             raise ValueError("prompt exceeds total cache capacity; can never be admitted")
+        if grammar is not None:
+            self.engine._refuse("constrained_decoding")
+        if self.diffusion is not None:
+            # a request's overrides of the rule's defaults: checked here, by the rule itself
+            self.diffusion.rows_per_forward(sampling.denoising_steps)
+            self.diffusion.threshold_of(sampling.remasking, sampling.threshold)
         if grammar is not None and grammar.vocab_size != self.engine.cfg.vocab_size:
             raise ValueError(
                 f"grammar compiled against vocab {grammar.vocab_size}, "
@@ -939,6 +979,8 @@ class ContinuousBatchingScheduler:
                 )
                 req.trace_ring = self.trace_ring
                 req.slo_sink = self._slo_record
+                if self.diffusion is not None:
+                    req.trace.fixed_at = req.fixed_at  # (the one list: the trace shows it as it grows)
                 req.trace.mark_accept(
                     prompt_len=len(prompt),
                     deadline_s=deadline_s,
@@ -1655,6 +1697,7 @@ class ContinuousBatchingScheduler:
         self.engine.stash_prefix(victim)
         self._release(victim)
         req = victim.req
+        req.block_resume = (victim.cached_len, victim.blk) if victim.blk is not None else None
         req.prompt = req.original_prompt + list(req.generated)
         req.mask_state = None  # rebuilt from `generated` at re-admission
         req.preemptions += 1
@@ -1724,11 +1767,17 @@ class ContinuousBatchingScheduler:
         # Radix planning is a first-class anatomy phase (prefix_plan):
         # PR 11 made it a real admission cost the waterfall must not
         # hide inside "admit".
+        # what the admission's prefill caches: the prompt or, under block
+        # diffusion, its whole blocks (the remaining ``P mod B`` tokens
+        # enter the first block as rows already fixed)
+        head = req.prompt
+        if self.diffusion is not None:
+            head = req.prompt[: len(req.prompt) // self._step_positions * self._step_positions]
         with self._phase("sched.prefix_plan", request=req.id) as p_plan:
-            plan = self.engine.prefix_plan(req.prompt)
+            plan = self.engine.prefix_plan(head)
         with self._phase("sched.admit", request=req.id):
             need = (
-                self.engine.cache_config.blocks_for(len(req.prompt) + 1)
+                self.engine.cache_config.blocks_for(len(head) + 1)
                 - plan.n_resident
             )
             blocks = self.engine.allocator.allocate(need)
@@ -1776,7 +1825,7 @@ class ContinuousBatchingScheduler:
         # heartbeat covers them like any other step
         with self._phase("sched.prefix_plan", request=req.id) as p_prep:
             with self._stamped():
-                prep = self.engine.prepare_prefix(req.prompt, plan, blocks, slot=slot)
+                prep = self.engine.prepare_prefix(head, plan, blocks, slot=slot)
         with self._phase("sched.admit", request=req.id):
             if prep is None:
                 # a mid-assembly swap-in fallback could not replace the
@@ -1805,12 +1854,13 @@ class ContinuousBatchingScheduler:
                 # a dispatched fold_in; the same key on every retry
                 key = req.sample_key()
             with phase("sched.device_step", request=req.id) as p_dev:
+                # (block diffusion: a prompt shorter than a block has nothing to cache)
                 token = self._device(
                     lambda: self.engine.prefill_one(
-                        req.prompt, table, req.sampling, key,
+                        head, table, req.sampling, key,
                         prefix_len=prefix_len, mask=pf_mask, slot=slot,
                     )
-                )
+                ) if head else None
         except Exception as e:
             self._admitting = None
             self._admitting_blocks = None
@@ -1840,9 +1890,9 @@ class ContinuousBatchingScheduler:
             return True  # did work (and must not spin on the same head)
         # the prefill's dispatch/block/execute/readback spans join the
         # iteration's anatomy timeline with their real offsets
-        execute_s = self._engine_spans()
+        execute_s = self._engine_spans() if head else 0.0
         with self._phase("sched.admit", request=req.id):
-            if not bool(self.engine.last_finite[0]):
+            if head and not bool(self.engine.last_finite[0]):
                 # poisoned prompt: the prefill's logits went non-finite, and
                 # a single-sequence step needs no bisection to assign blame
                 self._admitting = None
@@ -1867,13 +1917,17 @@ class ContinuousBatchingScheduler:
             # shared content another request could reuse (reuse telemetry
             # also counts here, so failed admissions never inflate it)
             self.engine.register_prefix(
-                req.prompt, table, shared_idx, entries, prefix_len=prefix_len, slot=slot
+                head, table, shared_idx, entries, prefix_len=prefix_len, slot=slot
             )
             state = _Running(
-                req, slot, table, cached_len=len(req.prompt),
+                req, slot, table, cached_len=len(head),
                 admitted_seq=next(self._admitted_seq),
                 shared_idx=shared_idx, shared_entries=entries,
             )
+            if self.diffusion is not None:
+                state.blk = self._first_block(req, len(head))
+                state.rule = (self.diffusion.rows_per_forward(req.sampling.denoising_steps),
+                              self.diffusion.threshold_of(req.sampling.remasking, req.sampling.threshold))
             self._note_admission()
             self._running[slot] = state
             # clear only AFTER slot registration: cache_report reads
@@ -1907,13 +1961,14 @@ class ContinuousBatchingScheduler:
                     "queue_time", max(0.0, popped_at - req.submitted_at),
                     exemplar=req.journey.journey_id,
                 )
-            self._emit_token(state, token)
-            req.trace.note_tokens(1, "prefill")
+            if self.diffusion is None:
+                self._emit_token(state, token)
+                req.trace.note_tokens(1, "prefill")
             req.journey.hop(
                 "prefill", prompt_len=len(req.prompt),
                 prefix_reused=prefix_len, replica=self.fault_scope,
             )
-            if self.obs_enabled and was_first:
+            if self.obs_enabled and was_first and self.diffusion is None:  # (a diffusion prefill yields no token: _block_once)
                 # gated like tpot (trace-derived in _finish) so disabling
                 # observability drops all three SLO windows together, not
                 # a confusing two of three
@@ -1932,7 +1987,8 @@ class ContinuousBatchingScheduler:
                 blocks_free=self.engine.allocator.num_free,
                 prefix_reused=prefix_len,
             )
-            self.token_rate.record(1)
+            if self.diffusion is None:
+                self.token_rate.record(1)
             if req.finished():
                 self._finish(state)
             elif self.handoff_sink is not None:
@@ -2152,8 +2208,9 @@ class ContinuousBatchingScheduler:
             if self._running.get(state.slot) is not state:
                 continue  # preempted earlier in this sweep
             while True:
+                # (a block step writes a whole block of positions ahead)
                 need = self.engine.cache_config.blocks_for(
-                    state.cached_len + state.step_k + 1
+                    state.cached_len + state.step_k + self._step_positions
                 )
                 if len(state.blocks) >= need:
                     break
@@ -2207,6 +2264,7 @@ class ContinuousBatchingScheduler:
         self.engine.stash_prefix(state)  # see _preempt_youngest
         self._release(state)
         req = state.req
+        req.block_resume = (state.cached_len, state.blk) if state.blk is not None else None
         req.prompt = req.original_prompt + list(req.generated)
         req.mask_state = None  # rebuilt from `generated` at re-admission
         req.preemptions += 1
@@ -2967,6 +3025,122 @@ class ContinuousBatchingScheduler:
         self.token_rate.record(n_live_tokens)
         return True
 
+    # ------------------------------------------------------- block diffusion
+    def _first_block(self, req: Request, base: int) -> _Block:
+        """The block an admission starts with, at position ``base`` (the
+        prefill cached everything below it): what a preemption kept of
+        the block that was in flight there, else the remaining tokens
+        of ``req.prompt`` (the prompt's last ``P mod B``, and after a
+        replay the reply's emitted tokens too) as rows already fixed
+        before mask tokens."""
+        kept, req.block_resume = req.block_resume, None
+        if kept is not None and kept[0] == base:
+            return kept[1]
+        # (a kept block below ``base``: all its rows had left, so the prefill was its commit)
+        return _Block(self._step_positions, req.prompt[base:])
+
+    def _collect_blocks(self, order):
+        """Slot-indexed arrays of a block step: every live slot's block
+        (tokens, fixed rows), its base and forwards, its table, and the
+        request's sampling parameters and rule."""
+        b, width = self.engine.max_batch_slots, self._step_positions
+        tokens, fixed = np.zeros((b, width), np.int32), np.zeros((b, width), bool)
+        base, forwards = np.zeros((b,), np.int32), np.zeros((b,), np.int32)
+        tables = np.zeros((b, self.engine.max_blocks_per_seq), np.int32)
+        active = np.zeros((b,), bool)
+        temps, top_ks, seeds = np.zeros((b,), np.float32), np.zeros((b,), np.int32), np.zeros((b,), np.uint32)
+        n_fix, threshold = np.zeros((b,), np.int32), np.full((b,), 2.0, np.float32)
+        for state in order:
+            i, sp, blk = state.slot, state.req.sampling, state.blk
+            tokens[i], fixed[i], base[i], forwards[i] = blk.tokens, blk.fixed, state.cached_len, blk.forwards
+            tables[i, : len(state.blocks)] = state.blocks
+            active[i] = True
+            temps[i], top_ks[i], seeds[i] = sp.temperature, sp.top_k, sp.seed & 0xFFFFFFFF
+            n_fix[i], threshold[i] = state.rule
+        return tokens, fixed, base, forwards, tables, active, temps, top_ks, seeds, n_fix, threshold
+
+    def _block_once(self) -> bool:
+        """One block-diffusion step across all running slots (a
+        sequential step, as ``_verify_once`` is): a forward of every
+        slot's block, then each slot's newly final tokens emitted IN
+        POSITION ORDER: a token leaves when it and every earlier
+        position of the reply are fixed, several a step or none. A slot
+        whose forward was its block's commit moves on to the next block;
+        a request whose budget is spent ends with its last block's
+        commit (the plain loop runs every block whole and drops the
+        tokens past the budget), one that emits its end-of-sequence
+        token ends there, inside the block."""
+        if not self._running:
+            return False
+        info = self._step_info
+        info["kind"] = "block_step"
+        with self._phase("sched.schedule"):
+            order = sorted(self._running.values(), key=lambda s: s.slot)
+            (tokens, fixed, base, forwards, tables, active, *params) = self._collect_blocks(order)
+
+        def step():
+            return self.engine.block_step(tokens, fixed, base, forwards, tables, active, *params)
+
+        def probe(subset):
+            # blame-assignment probe: the same step with only ``subset`` active; its
+            # results are discarded and its writes are the block's provisional rows
+            act = np.zeros_like(active)
+            for s in subset:
+                act[s.slot] = True
+            self._probe_call(lambda: self.engine.block_step(tokens, fixed, base, forwards, tables, act, *params))
+
+        with phase("sched.device_step") as p_dev:
+            result = self.supervisor.run_step("block_step", step, order, probe)
+        self._step_walls["device"] = p_dev.seconds
+        if result is None:
+            info["handled_failure"] = True
+            return True  # failure handled: quarantined or journal-replayed
+        info["execute_s"] = self._engine_spans(decode_result=True)
+        if self._quarantine_nan("block_step", order):
+            info["handled_failure"] = True
+            return True
+        n_emitted = 0
+        with self._phase("sched.bookkeep"):
+            now = self.clock()
+            for state in order:
+                if self._running.get(state.slot) is not state:
+                    continue  # preempted/expired between collect and scatter
+                req, i, blk = state.req, state.slot, state.blk
+                if req.handle.done():
+                    continue  # watchdog-reaped mid-step; _expire releases it
+                if result["commit"][i]:
+                    # the block's K/V is now what later blocks read
+                    state.cached_len += self._step_positions
+                    state.blk = _Block(self._step_positions)
+                    if req.finished():
+                        self._finish(state)
+                    continue
+                for j in np.nonzero(result["chosen"][i])[0]:
+                    blk.tokens[j], blk.fixed[j], blk.fixed_at[j] = result["tokens"][i, j], True, blk.forwards
+                blk.forwards += 1
+                # in order: the reply's next token is row (prompt + emitted - base) of the block
+                first, emitted = req.n_generated == 0, 0
+                while req.n_generated < req.max_new:
+                    j = len(req.original_prompt) + req.n_generated - state.cached_len
+                    if j >= self._step_positions or not blk.fixed[j]:
+                        break
+                    req.fixed_at.append(blk.fixed_at[j])
+                    self._emit_token(state, int(blk.tokens[j]), later=True)
+                    emitted += 1
+                    if req.generated[-1] == req.sampling.eos_id:
+                        break
+                if not emitted:
+                    continue
+                n_emitted += emitted
+                req.trace.note_tokens(emitted, "block_step")
+                if self.obs_enabled and first and req.preemptions == 0 and req.replays == 0:
+                    self.stats.observe("ttft", max(0.0, now - req.submitted_at), exemplar=req.journey.journey_id)
+                if req.generated[-1] == req.sampling.eos_id:
+                    self._finish(state)  # an end inside a block: with what has left
+        info["emitted"] = n_emitted
+        self.token_rate.record(n_emitted)
+        return True
+
     def _phase(self, name: str, **args) -> phase:
         """Open one host span of THIS iteration (obs/steptrace.phase:
         an ``ff.<name>`` event on the profiler's clock and perf_counter
@@ -3065,7 +3239,7 @@ class ContinuousBatchingScheduler:
         # overlapped decode: steady-state iterations pipeline
         # dispatch/consume; any non-steady event drains the frontier
         # and falls through to the sequential body
-        r = self._try_pipeline() if self.overlap else None
+        r = self._try_pipeline() if self.overlap and self.diffusion is None else None
         if r is not None:
             did, kind = r, "decode"
         else:
@@ -3083,7 +3257,10 @@ class ContinuousBatchingScheduler:
             if admitted:
                 info["admitted"] = admitted
             speculating = any(s.step_k > 0 for s in self._running.values())
-            stepped = self._verify_once() if speculating else self._decode_once()
+            if self.diffusion is not None:
+                stepped = self._block_once()
+            else:
+                stepped = self._verify_once() if speculating else self._decode_once()
             did, kind = stepped or admitted > 0, "admit"
         if did:
             # before housekeep, whose overload tick may shed requests:

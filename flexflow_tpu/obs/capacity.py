@@ -441,7 +441,22 @@ class ServingFlops:
         return (n_tokens * self.per_token_flops + self.per_ctx_flops * context_sum
                 + self.window_ctx_flops * self.windowed(n_tokens, context_sum))
 
+    def block_flops(self, n_slots: int, context_sum: int, block: int) -> float:
+        """One block-diffusion forward: ``n_slots`` live slots of
+        ``block`` rows each, every row attending its slot's context
+        (``context_sum``: the slots' contexts added up, each its block's
+        last position + 1): a verify step's arithmetic at ``block`` rows
+        a slot."""
+        return self.verify_flops(n_slots * block, context_sum * block)
+
     # ------------------------------------------ predicted step time (truth)
+    def block_bytes(self, n_slots: int, context_sum: int, block: int) -> float:
+        """HBM bytes of one block-diffusion forward: weights once, a
+        slot's K/V read once for all its ``block`` rows, the block's
+        rows written (at every forward: the K/V is provisional until the
+        commit)."""
+        return self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_slots * block)
+
     def prefill_bytes(self, prompt_len: int) -> float:
         n = max(0, prompt_len)
         return self.param_bytes + (self.kv_bytes_per_pos + self.window_kv_bytes_per_pos) * n

@@ -55,7 +55,7 @@ class RequestTrace:
         "t_accept", "t_admit", "t_first_token", "t_last_token", "t_finish",
         "n_generated", "outcome", "error", "preemptions", "replays",
         "spec_windows", "spec_proposed", "spec_accepted", "transport",
-        "progress_every", "_steps_since_progress", "journey_id",
+        "progress_every", "_steps_since_progress", "journey_id", "fixed_at",
     )
 
     def __init__(
@@ -92,6 +92,10 @@ class RequestTrace:
         # the join key between a replica-local trace and the stitched
         # cross-replica journey (obs/journey.py)
         self.journey_id: Optional[str] = None
+        # block diffusion: for every emitted token, the denoising forward
+        # of its block that fixed it (the request's own list, set by the
+        # scheduler of such an engine; None elsewhere)
+        self.fixed_at: Optional[List[int]] = None
         self.progress_every = max(1, progress_every)
         self._steps_since_progress = 0
 
@@ -238,6 +242,7 @@ class RequestTrace:
                 "accepted": self.spec_accepted,
             },
             "events": events,
+            **({} if self.fixed_at is None else {"fixed_at": list(self.fixed_at)}),
         }
 
 

@@ -462,7 +462,9 @@ def latent_attention_core(
 STREAM_SCORE_BYTES = 1 << 30
 
 
-def prefill_call_lowering(q_shape, k_shape, itemsize: int, backend: str = "tpu", v_shape=None) -> Dict[str, Optional[str]]:
+def prefill_call_lowering(
+    q_shape, k_shape, itemsize: int, backend: str = "tpu", v_shape=None, block: int = 0
+) -> Dict[str, Optional[str]]:
     """What a prefill's attention call of these shapes lowers to, as
     :func:`prefill_attention` will decide it: ``{"form", "kernel",
     "refused"}``. ``form`` is ``"materialised"`` (:func:`masked_attention`,
@@ -473,31 +475,55 @@ def prefill_call_lowering(q_shape, k_shape, itemsize: int, backend: str = "tpu",
     ``k_shape``'s where none is given) or ``"xla_chunks"`` (the same
     arithmetic as a scan over chunks of query rows: the CPU backend, and
     a shape the kernel's gate refuses, whose reason is ``refused``). No
-    call falls from the streamed form to materialised scores."""
+    call falls from the streamed form to materialised scores. ``block``
+    > 0 (a block mask, :func:`block_seen`): the Pallas call's diagonal
+    tiles are causal, so it refuses such a call by name and the XLA
+    chunks serve it."""
     b, s, h, _ = q_shape
     if 4 * b * h * s * k_shape[1] <= STREAM_SCORE_BYTES:
         return {"form": "materialised", "kernel": "masked_attention", "refused": None}
     refused = None
     if backend == "tpu" and on_tpu():
         refused = prefill_stream_refusal(tuple(q_shape), tuple(k_shape), itemsize, v_shape)
+        if refused is None and block:
+            refused = f"a block mask of {block} positions (the kernel's diagonal tiles are causal)"
         if refused is None:
             return {"form": "streamed", "kernel": "prefill_stream_attention", "refused": None}
         _note_refusal("prefill_stream_attention", refused)
     return {"form": "streamed", "kernel": "xla_chunks", "refused": refused}
 
 
-def prefill_attention(q, k, v, lengths, window: int = 0, backend: str = "cpu"):
+def prefill_attention(q, k, v, lengths, window: int = 0, backend: str = "cpu", block: int = 0):
     """A prefill's causal attention over [B, S, H, D] (grouped K/V, a
     valid length a sequence, ``window`` > 0 for a sliding-window layer;
     ``v``'s width may differ from the scores', whose scale is of ``q``'s
     width: a latent layer's expanded form), in the lowering
-    :func:`prefill_call_lowering` names for the three shapes."""
-    low = prefill_call_lowering(q.shape, k.shape, q.dtype.itemsize, backend, v_shape=v.shape)
+    :func:`prefill_call_lowering` names for the three shapes. ``block``
+    > 0: the block mask instead of the causal one (:func:`block_seen`:
+    a query sees its whole block of ``block`` positions, later rows
+    included, and every block before it)."""
+    low = prefill_call_lowering(q.shape, k.shape, q.dtype.itemsize, backend, v_shape=v.shape, block=block)
     if low["form"] == "materialised":
-        return masked_attention(q, k, v, lengths, causal=True, window=window)
+        return masked_attention(q, k, v, lengths, causal=True, window=window, block=block)
     if low["kernel"] == "prefill_stream_attention":
         return prefill_stream_attention(q, k, v, lengths, window=window)
-    return reference_prefill_stream_attention(q, k, v, lengths, window=window)
+    return reference_prefill_stream_attention(q, k, v, lengths, window=window, block=block)
+
+
+def block_seen(q_pos, k_pos, block: int):
+    """[Sq, Sk] bool, the block mask: key ``j`` is admitted for query
+    ``i`` iff ``j // block <= i // block``, i.e. ``j < (i // block + 1)
+    * block`` (block diffusion: full history, and the whole of the
+    query's own block)."""
+    return k_pos[None, :] < (q_pos[:, None] // block + 1) * block
+
+
+def _seen(sq: int, sk: int, block: int):
+    """[Sq, Sk] bool: the causal mask (queries the last ``sq`` of ``sk``
+    positions), or with ``block`` the block mask over the same positions."""
+    if block:
+        return block_seen(jnp.arange(sq) + (sk - sq), jnp.arange(sk), block)
+    return jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
 
 
 def _behind_window(sq: int, sk: int, window: int):
@@ -506,26 +532,25 @@ def _behind_window(sq: int, sk: int, window: int):
     return jnp.triu(jnp.ones((sq, sk), bool), k=sk - sq - window + 1)
 
 
-def masked_attention(q, k, v, lengths, causal=True, scale=None, window=0):
+def masked_attention(q, k, v, lengths, causal=True, scale=None, window=0, block=0):
     """Causal attention over [B, S, H, D] (v's width may differ from q's
     and k's: a latent layer scores at 192 and weighs values of 128) with
     a per-sequence valid length: key positions >= lengths[b] are masked. The prefill side of
     the decode split — bucketed (padded) prompts attend only over their
     real tokens, so prefill logits match the unpadded forward.
     ``window`` > 0 (a sliding-window layer): a query attends only the
-    ``window`` positions up to its own."""
+    ``window`` positions up to its own. ``block`` > 0: the causal mask
+    is the block mask (:func:`block_seen`)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if q.shape[2] != k.shape[2]:
-        return _grouped_masked_attention(q, k, v, lengths, causal, scale, window)
+        return _grouped_masked_attention(q, k, v, lengths, causal, scale, window, block)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
     sq, sk = logits.shape[-2], logits.shape[-1]
     mask = jnp.arange(sk)[None, :] < lengths[:, None]  # [B, Sk]
     mask = mask[:, None, None, :]
     if causal:
-        mask = jnp.logical_and(
-            mask, jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)[None, None]
-        )
+        mask = jnp.logical_and(mask, _seen(sq, sk, block)[None, None])
     if window:
         mask = jnp.logical_and(mask, _behind_window(sq, sk, window)[None, None])
     logits = jnp.where(mask, logits, -jnp.inf)
@@ -536,7 +561,7 @@ def masked_attention(q, k, v, lengths, causal=True, scale=None, window=0):
     return jnp.einsum("bhqk,bkhd->bqhd", (p / l).astype(v.dtype), v)
 
 
-def _grouped_masked_attention(q, k, v, lengths, causal, scale, window=0):
+def _grouped_masked_attention(q, k, v, lengths, causal, scale, window=0, block=0):
     """:func:`masked_attention` with grouped queries: q [B, S, H, D] over
     k/v [B, S, Hkv, D], query head ``i`` reading K/V head ``i // (H //
     Hkv)``. K and V are never repeated: the group is an axis of q."""
@@ -546,7 +571,7 @@ def _grouped_masked_attention(q, k, v, lengths, causal, scale, window=0):
     logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32) * scale
     mask = (jnp.arange(sk)[None, :] < lengths[:, None])[:, None, None, None, :]
     if causal:
-        mask = jnp.logical_and(mask, jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)[None, None, None])
+        mask = jnp.logical_and(mask, _seen(sq, sk, block)[None, None, None])
     if window:
         mask = jnp.logical_and(mask, _behind_window(sq, sk, window)[None, None, None])
     logits = jnp.where(mask, logits, -jnp.inf)

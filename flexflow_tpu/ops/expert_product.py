@@ -133,7 +133,21 @@ def expert_form(rows: int, held: int, k: int, outputs: Optional[int] = None) -> 
     At 16-64 rows a quarter of a pair a row lands: 4-16 pairs on 4-10 of
     the 16 held experts, whose weights are all the loop reads; at 4,096
     rows the dense form multiplies 65,536 row-expert products for the
-    ~1,024 that landed."""
+    ~1,024 that landed.
+
+    Every expert of 128 held, ``k`` 8, 2,048 x 768 (SDAR's layer: a block
+    step of 32 / 48 / 64 slots has 128 / 192 / 256 rows, the first step
+    program whose dense arithmetic is not far under its weights' reads:
+    1.21 GB a layer, 1.48 ms at 819 GB/s), ms dense / grouped
+    (``chip_smoke.py --expert-product sdar``, my chip run, PR 45)::
+
+        rows     128           192           256           1024
+               1.66 / 1.77   1.66 / 1.85   1.96 / 1.90   6.68 / 2.67
+
+    Up to 192 rows the reads set the dense form's time and the grouped
+    one moves pairs on top; at 256 the two are within 3 %: the rule stays
+    where it was (dense under 1,024 rows), and a block step keeps the
+    dense form at every slot count of the sweep."""
     outputs = outputs or held
     if sparse(held, k, outputs):
         return "grouped"

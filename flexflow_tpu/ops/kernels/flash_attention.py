@@ -577,14 +577,16 @@ def stream_chunk(batch: int, heads: int, span: int) -> int:
 
 def reference_prefill_stream_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, lengths: jax.Array, window: int = 0,
-    scale: Optional[float] = None, chunk: Optional[int] = None,
+    scale: Optional[float] = None, chunk: Optional[int] = None, block: int = 0,
 ) -> jax.Array:
     """:func:`prefill_stream_attention`'s arithmetic as an XLA
     composition: a scan over chunks of query rows, each scored against
     the ``chunk + window - 1`` keys its rows can reach (a full layer's:
     the sequence), masked and weighed as ``masked_attention`` does it
     (``v``'s width may differ from the scores': a latent layer's expanded
-    form). The largest temporary is one chunk's scores."""
+    form). The largest temporary is one chunk's scores. ``block`` > 0:
+    a query sees key ``j`` iff ``j < (i // block + 1) * block`` (the
+    block mask: its whole block, later rows included) and not ``j <= i``."""
     b, s, h, d = q.shape
     dv = v.shape[-1]
     hk = k.shape[2]
@@ -604,7 +606,7 @@ def reference_prefill_stream_attention(
         vc = jax.lax.dynamic_slice_in_dim(v, start, span, axis=1)
         k_pos = start + jnp.arange(span)
         logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg[:, c], kc, preferred_element_type=jnp.float32) * scale
-        seen = k_pos[None, :] <= q_pos[:, None]
+        seen = k_pos[None, :] < (q_pos[:, None] // block + 1) * block if block else k_pos[None, :] <= q_pos[:, None]
         if window:
             seen = jnp.logical_and(seen, k_pos[None, :] > q_pos[:, None] - window)
         mask = jnp.logical_and(seen[None], (k_pos[None, :] < lengths[:, None])[:, None, :])[:, None, None]

@@ -268,12 +268,19 @@ class GenerationModel:
         fields / gRPC parameters map), ignoring unknown keys."""
         defaults = SamplingParams()
         eos = params.get("eos_id")
+        # a block-diffusion model's rule for this request (the body's
+        # "parameters"; absent: the engine's defaults)
+        rule = params.get("parameters") if isinstance(params.get("parameters"), dict) else {}
+        steps, threshold = rule.get("denoising_steps"), rule.get("threshold")
         return SamplingParams(
             max_new_tokens=int(params.get("max_new_tokens", defaults.max_new_tokens)),
             temperature=float(params.get("temperature", defaults.temperature)),
             top_k=int(params.get("top_k", defaults.top_k)),
             eos_id=None if eos is None else int(eos),
             seed=int(params.get("seed", defaults.seed)),
+            denoising_steps=None if steps is None else int(steps),
+            remasking=rule.get("remasking"),
+            threshold=None if threshold is None else float(threshold),
         )
 
     @staticmethod

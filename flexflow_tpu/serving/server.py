@@ -800,7 +800,9 @@ class InferenceServer:
             def _generate(self, name: str):
                 """POST /v2/models/{name}/generate — body: {"prompt":
                 [ids], "max_new_tokens", "temperature", "top_k",
-                "eos_id", "seed", "stream", "parameters": {"timeout_ms"},
+                "eos_id", "seed", "stream", "parameters": {"timeout_ms",
+                "denoising_steps", "remasking", "threshold" (a
+                block-diffusion model's rule, this request's)},
                 "speculation": {"enabled", "k", "method", "max_ngram",
                 "min_ngram", "adaptive"}, "response_format": {"type":
                 "json_schema"|"regex", ...}}. The speculation block
@@ -894,6 +896,8 @@ class InferenceServer:
                         return self._json(500, error_payload(e))
                     body = {"model_name": name, "tokens": tokens,
                             "num_generated": len(tokens)}
+                    if handle._request.fixed_at:  # a block-diffusion model's
+                        body["fixed_at"] = list(handle._request.fixed_at[: len(tokens)])
                     if journey is not None:
                         body["journey_id"] = journey.journey_id
                         return self._json(
