@@ -40,10 +40,15 @@ in the decode program's place: ONE jitted program over every slot's
 block of B rows that see each other, with the generation rule (the
 candidates, their confidences, the rows fixed, commits) inside it after
 the head and the slots' blocks, flags, bases and forward counts carried
-on the device from step to step (:meth:`GenerationEngine.block_step`,
-a blocking call; ``scheduler._block_once`` emits what became final, in
-position order). Its prefill caches the prompt's whole blocks under the
-block mask and computes no logits.
+on the device from step to step. The step is split like the decode
+step: :meth:`GenerationEngine.block_async` dispatches without blocking
+(given an unconsumed predecessor it takes that step's device results as
+its state, whatever the host has bookkept) and
+:meth:`GenerationEngine.consume_block` collects a result one scheduler
+iteration later; :meth:`GenerationEngine.block_step` is the pair back to
+back (``scheduler._scatter_block`` emits what became final, in position
+order). Its prefill caches the prompt's whole blocks under the block
+mask and computes no logits.
 
 ISSUE 15 made the engine MESH-NATIVE: pass ``tp_degree`` /
 ``mesh_devices`` / ``mesh`` and the decoder weights + KV cache shard
@@ -454,6 +459,32 @@ class InFlightDecode:
         # scheduler's anatomy; set by decode_async once both have closed
         self.post: Tuple[str, float, float] = ("post", t_disp, t_disp)
         self.children: List[Tuple[str, float, float]] = []
+
+
+class InFlightBlock(InFlightDecode):
+    """One dispatched-but-unconsumed block step: an
+    :class:`InFlightDecode` (``out`` the blocks' tokens after the
+    forward, what a rollback needs, the stamps) with the rule's other
+    results (``chosen``, ``commit``), the state the program returned for
+    the next forward (``next_dev``, what a successor dispatched before
+    this step is consumed takes as its arguments) and the host's side of
+    the state the forward RAN on: ``pre`` where the host staged it,
+    else ``prev``, the predecessor whose ``nxt`` (its results applied to
+    its own state, computed when it is consumed, which is always first)
+    is that state. ``active`` [slots] int32: the slots the forward ran.
+    Created by :meth:`GenerationEngine.block_async`, consumed exactly
+    once by :meth:`GenerationEngine.consume_block`. Loop-thread only."""
+
+    __slots__ = ("chosen", "commit", "next_dev", "pre", "prev", "nxt", "active")
+
+    def __init__(self, results, next_dev, pre, prev, active, **step):
+        out, self.chosen, self.commit, ok = results
+        super().__init__(out, ok, n_active=0, ctx_sum=0, **step)  # (counted at the consume, over the slots still running)
+        self.next_dev = next_dev
+        self.pre = pre
+        self.prev = prev
+        self.nxt = None
+        self.active = active
 
 
 class GenerationEngine:
@@ -2763,8 +2794,18 @@ class GenerationEngine:
         with phase("engine.decode.readback") as read:
             self.last_finite = np.asarray(step.ok)
             result = np.asarray(step.out)  # async copy already landed
+        elapsed, execute_s = self._record_lanes("decode", step, block, read)
+        self._account_decode(step.n_active, step.ctx_sum, step.traced, elapsed=elapsed, execute_s=execute_s)
+        return result
+
+    def _record_lanes(self, kind: str, step: InFlightDecode, block: phase, read: phase) -> Tuple[float, float]:
+        """Account a consumed step (a decode or a block step) from its
+        handle's stamps and the consume's two host spans, and publish
+        them for the scheduler's anatomy: what :meth:`_record_step_phases`
+        is to a call that dispatches and waits in one. Returns
+        (total_elapsed_s, execute_s)."""
         t_exec = block.t1
-        ph = self.phase_time_s["decode"]
+        ph = self.phase_time_s[kind]
         if step.t_started is None:
             # a blocking step's: the device's time is this park's, and what
             # lay between the dispatch and it (post, the dispatched hook)
@@ -2782,12 +2823,7 @@ class GenerationEngine:
             block.span, ("execute", step.t_started, t_exec), read.span,
         ]
         self.last_step_children = []  # the dispatch and its parts went to the scheduler with the handle
-        self._account_decode(
-            step.n_active, step.ctx_sum, step.traced,
-            elapsed=read.t1 - step.t0,
-            execute_s=t_exec - step.t_started,
-        )
-        return result
+        return read.t1 - step.t0, t_exec - step.t_started
 
     def _bias_arg(self, bias) -> jax.Array:
         """Device-side logit bias: the cached zeros unless a fault plan
@@ -2923,9 +2959,10 @@ class GenerationEngine:
         n_fix: np.ndarray,
         threshold: np.ndarray,
     ) -> Dict[str, np.ndarray]:
-        """One block-diffusion step across all slots (:meth:`_block_impl`;
-        a blocking call, as :meth:`verify` is: the scheduler's loop has
-        no step in flight while it bookkeeps this one's result).
+        """One block-diffusion step across all slots (:meth:`_block_impl`):
+        the blocking call of the one block-step body, :meth:`block_async`
+        and :meth:`finish_block` (as :meth:`decode` is of the decode
+        step's).
 
         ``tokens`` / ``fixed`` [slots, B], ``base`` / ``forwards``
         [slots]: the slots' blocks as the host holds them. They are
@@ -2935,23 +2972,74 @@ class GenerationEngine:
         nothing. Returns ``{"tokens", "chosen", "commit"}``, this
         forward's result: the block's tokens after it, the rows it
         fixed, the slots that ran their commit."""
+        return self.finish_block(self.block_async(
+            tokens, fixed, base, forwards, block_tables, active, temps, top_ks, seeds, n_fix, threshold,
+        ))
+
+    def finish_block(self, step: InFlightBlock) -> Dict[str, np.ndarray]:
+        """The back half of a BLOCKING block step, straight after its
+        :meth:`block_async`: :meth:`finish_decode` for a block step (a
+        method of its own for the same reason: the scheduler's
+        sequential step calls ``block_async`` from its own frame)."""
+        self._dispatched()
+        step.t_started = None
+        result = self.consume_block(step)
+        self.last_step_spans[:0] = [("dispatch", step.t0, step.t_disp)]
+        self.last_step_children = step.children
+        return result
+
+    def block_async(
+        self,
+        tokens: Optional[np.ndarray],
+        fixed: Optional[np.ndarray],
+        base: Optional[np.ndarray],
+        forwards: Optional[np.ndarray],
+        block_tables: np.ndarray,
+        active: np.ndarray,
+        temps: np.ndarray,
+        top_ks: np.ndarray,
+        seeds: np.ndarray,
+        n_fix: np.ndarray,
+        threshold: np.ndarray,
+        prev: Optional[InFlightBlock] = None,
+    ) -> InFlightBlock:
+        """Dispatch one block step WITHOUT blocking on it: the front
+        half of every block step (the overlap pipeline's, and the
+        blocking :meth:`block_step`'s). Returns an :class:`InFlightBlock`
+        whose result :meth:`consume_block` collects one scheduler
+        iteration later; the four device-to-host copies of the result
+        start here.
+
+        ``prev``: the unconsumed step this one follows. Its program's
+        last four results (``next_dev``) ARE this step's blocks, flags,
+        bases and forward counts, and they go in as the device arrays
+        they are, whatever ``active`` is now (a slot left out keeps its
+        rows: ``_block_impl``'s ``keep``): no host array is compared and
+        none of the state is uploaded, so the four state arguments are
+        not read (None). The host cannot know them: what ``prev`` fixed
+        is on the device alone until it is consumed. Tables, ``active``
+        and the sampling vectors are staged as ever."""
         d = self.diffusion
         if d is None:
             raise NotImplementedError("block_step is a block-diffusion engine's step (diffusion=None)")
-        tokens = np.where(active[:, None], tokens, 0).astype(np.int32)
-        tokens, bias = faults.inject(faults.GENERATION_DECODE_STEP, (tokens, self._zero_bias))
-        self.step_counts["block_step"] += 1
-        self._count_expert_form(self.max_batch_slots * d.block_length)
-        self._children = []
-        try:  # whatever raises up to the readback leaves no carried entry behind
+        if prev is None:
+            tokens = np.where(active[:, None], tokens, 0).astype(np.int32)
+        try:  # whatever raises from the fault site to the program's call leaves no carried entry behind
+            tokens, bias = faults.inject(faults.GENERATION_DECODE_STEP, (tokens, self._zero_bias))
+            self.step_counts["block_step"] += 1
+            self._count_expert_form(self.max_batch_slots * d.block_length)
+            children = self._children = []
             with phase("engine.block_step.dispatch") as disp:
                 traces_before = self.trace_counts.get("block_step", 0)
                 with self._part("block_step", "args"):
                     act = active.astype(np.int32)
-                    state = (tokens, np.where(active[:, None], fixed, 0).astype(np.int32),
-                             np.where(active, base, 0).astype(np.int32), np.where(active, forwards, 0).astype(np.int32))
-                    carried = [self._lookup(name, host) for name, host in zip(BLOCK_CARRIED, state)]
-                    missed = any(isinstance(x, tuple) for x in carried)
+                    if prev is None:
+                        pre = (tokens, np.where(active[:, None], fixed, 0).astype(np.int32),
+                               np.where(active, base, 0).astype(np.int32), np.where(active, forwards, 0).astype(np.int32))
+                        carried = [self._lookup(name, host) for name, host in zip(BLOCK_CARRIED, pre)]
+                        missed = any(isinstance(x, tuple) for x in carried)
+                    else:
+                        pre, carried, missed = None, prev.next_dev, False
                     self.uploads["carried_misses_total" if missed else "carried_hits_total"] += 1
                     self.sampling_steps[sampling_branch(temps, top_ks)] += 1
                     staged = [
@@ -2967,46 +3055,86 @@ class GenerationEngine:
                     dev_state = [self._upload(x) for x in carried]
                     tables, *rest = (self._upload(x) for x in staged)
                     args = (*dev_state, self.cache.k, self.cache.v, tables, *rest, self._bias_arg(bias), self.expert_counts)
+                prev_k, prev_v = (None, None) if self.donate else (self.cache.k, self.cache.v)
                 with self._part("block_step", "call"):
-                    out, chosen, commit, ok, ck, cv, counts, *next_dev = self._block_jit(self.params, *args)
-                for result in (out, chosen, commit, ok):
+                    *results, ck, cv, counts, next_tokens, next_fixed, next_base, next_forwards = self._block_jit(
+                        self.params, *args
+                    )
+                for result in results:
                     # the copies start now: the readback finds the bytes on the host (four small
                     # transfers one after another read 1.6 ms a step on the chip: PERF.md §6, PR 45)
                     result.copy_to_host_async()
-            self._dispatched()
-            with phase("engine.block_step.block") as block:
-                jax.block_until_ready((out, chosen, commit, ok, ck, cv))
-            with phase("engine.block_step.readback") as read:
                 self.cache.update(ck, cv)
-                self.expert_counts = counts
-                self.last_finite = np.asarray(ok)
-                out, chosen, commit = np.asarray(out), np.asarray(chosen), np.asarray(commit)
+                prev_counts, self.expert_counts = self.expert_counts, counts
         except BaseException:
             self._drop_carried()
             raise
-        elapsed, execute_s = self._record_step_phases("block_step", disp, block, read)
+        self.phase_time_s["block_step"]["dispatch"] += disp.seconds
+        step = InFlightBlock(
+            results, (next_tokens, next_fixed, next_base, next_forwards), pre, prev, act,
+            prev_k=prev_k, prev_v=prev_v, ck=ck, cv=cv, t0=disp.t0, t_disp=disp.t1,
+            traced=self.trace_counts.get("block_step", 0) > traces_before, prev_counts=prev_counts,
+        )
+        step.children = children
+        return step
+
+    def consume_block(self, step: InFlightBlock, running: Optional[np.ndarray] = None) -> Dict[str, np.ndarray]:
+        """Block on an in-flight block step and finish its accounting:
+        the back half of every block step. A failed step's cache is
+        rolled back as a decode step's is (:meth:`consume_decode`).
+
+        ``running`` [slots] bool: the slots of the step whose requests
+        are still running now (None: all it ran). A step dispatched
+        behind an unconsumed one ran every slot that one ran, and a
+        request may have ENDED on what that one fixed (its
+        end-of-sequence token): its rows in this step are work a
+        sequential loop never does, so the rule's counters
+        (``diffusion_counts``, the histogram, the useful FLOPs) leave
+        them out and stay what the sequential loop's are. The state
+        carried to the next step is the whole program's, whoever
+        counts."""
+        if step.consumed:
+            raise RuntimeError("InFlightBlock consumed twice")
+        step.consumed = True
+        d = self.diffusion
+        with phase("engine.block_step.block") as block:
+            try:
+                jax.block_until_ready((step.out, step.chosen, step.commit, step.ok))
+            except Exception:
+                self._drop_carried()  # a donating engine's too, which rolls nothing back
+                if step.prev_k is not None:
+                    self.rollback_decode(step)  # (see consume_decode)
+                raise
+        with phase("engine.block_step.readback") as read:
+            self.last_finite = np.asarray(step.ok)
+            out, chosen, commit = np.asarray(step.out), np.asarray(step.chosen), np.asarray(step.commit)
+        elapsed, execute_s = self._record_lanes("block_step", step, block, read)
         with phase("engine.block_step.account", into=self.last_step_spans):
-            # the host's side of the state the device now holds (the program's last four results)
-            nxt = (
+            # the host's side of the state the device now holds (the program's last four results), from
+            # the state the forward ran on: what the host staged, or what the step before it left
+            pre, act = step.pre if step.prev is None else step.prev.nxt, step.active
+            step.prev = None  # (a chain of consumed steps is not kept alive through it)
+            step.nxt = (
                 np.where(commit[:, None], 0, out).astype(np.int32),
-                np.where(commit[:, None], 0, state[1] | chosen).astype(np.int32),
-                state[2] + d.block_length * commit, np.where(commit, 0, state[3] + act),
+                np.where(commit[:, None], 0, pre[1] | chosen).astype(np.int32),
+                (pre[2] + d.block_length * commit).astype(np.int32), np.where(commit, 0, pre[3] + act).astype(np.int32),
             )
-            for name, host, dev in zip(BLOCK_CARRIED, nxt, next_dev):
-                self._staged[name] = (host.astype(np.int32), dev)
-            n_active, n_commit = int(act.sum()), int(commit.sum())
+            for name, host, dev in zip(BLOCK_CARRIED, step.nxt, step.next_dev):
+                self._staged[name] = (host, dev)
+            counted = act > 0 if running is None else np.logical_and(act > 0, running)
+            n_active, n_commit = int(counted.sum()), int(commit[counted].sum())
             c = self.diffusion_counts
             c["slot_forwards_total"] += n_active
             c["commit_forwards_total"] += n_commit
             c["blocks_committed_total"] += n_commit
-            c["tokens_fixed_total"] += int(chosen.sum())
+            c["tokens_fixed_total"] += int(chosen[counted].sum())
             self.fixed_histogram += np.bincount(
-                chosen.sum(axis=1)[active & ~commit], minlength=d.block_length + 1
+                chosen.sum(axis=1)[counted & ~commit], minlength=d.block_length + 1
             )
             # success-only, paired with the time below (see prefill()): every live row attends its block's end
-            ctx_sum = int(((state[2] + d.block_length) * act).sum())
+            ctx_sum = int(((pre[2] + d.block_length) * counted).sum())
             self.flops_by_kind["block_step"] += self.flops_model.block_flops(n_active, ctx_sum, d.block_length)
-            if self.trace_counts.get("block_step", 0) > traces_before:
+            if step.traced:
                 self.programs.set_compile_time("block_step", elapsed)
             else:
                 b = self.max_batch_slots  # EXECUTED work: every slot's rows compute

@@ -70,7 +70,14 @@ so chaos tests run on virtual time. Fault sites: ``generation.prefill``,
   semantics — token streams are byte-identical with overlap on/off
   (tests/test_overlap.py). The speculative verify path stays
   sequential by design: drafting needs step N's committed tokens on
-  the host, so there is no overlap window. An EMPTY FREE LIST is not
+  the host, so there is no overlap window. A BLOCK-DIFFUSION engine's
+  block step rides the same loop (ISSUE 46; an engine has one kind of
+  step, read once from ``engine.diffusion``): forward N+1 goes out on
+  the state forward N's program left on the device while the host
+  emits what N-1 fixed; what the host must know for the dispatch (is N
+  a slot's commit, so where N+1's block starts and whether a spent
+  budget ends the request there) it knows one consume earlier, and
+  what N fixed it needs only to emit tokens. An EMPTY FREE LIST is not
   pressure (ISSUE 30): with the prefix cache on the pool is full by
   design, so the pipeline's block growth takes its block from the
   cache's unreferenced entries (``engine.reclaim_cached``, as ``_grow``
@@ -480,8 +487,11 @@ class Request:
             self.spec_k = min(cfg.k, self.spec_k + 1)
 
     def finished(self) -> bool:
-        if self.n_generated >= self.max_new:
-            return True
+        return self.n_generated >= self.max_new or self.ended_on_eos()
+
+    def ended_on_eos(self) -> bool:
+        """The end of a request that needs no further step under block
+        diffusion, where a spent budget still awaits its block's commit."""
         eos = self.sampling.eos_id
         return eos is not None and bool(self.generated) and self.generated[-1] == eos
 
@@ -532,10 +542,11 @@ class _Running:
 
 class _Frontier:
     """The overlap pipeline's in-flight frontier: AT MOST ONE
-    outstanding decode step. Captures the dispatch-time slot states and
-    the host-side argument arrays (``slots``: ``_collect_slots``'s after
-    the token array, reused — bumped by one — for the next dispatch, so
-    steady state rebuilds nothing), plus the
+    outstanding step (a decode step, or a block-diffusion engine's block
+    step). Captures the dispatch-time slot states and, for a decode
+    step, the host-side argument arrays (``slots``: ``_collect_slots``'s
+    after the token array, reused — bumped by one — for the next
+    dispatch, so steady state rebuilds nothing), plus the
     heartbeat seq the watchdog/stall bookkeeping is keyed on. ``seq0``
     is the scheduler's heartbeat seq just BEFORE this dispatch: a stall
     flagged on any later seq belongs to this frontier chain and voids
@@ -803,10 +814,27 @@ class ContinuousBatchingScheduler:
         # stays the documented GIL-atomic tuple swap.
         self.overlap = True if overlap is None else bool(overlap)
         # a block-diffusion engine's rule (engine.BlockDiffusion): its
-        # steps are block steps (``_block_once``), sequential ones, and
-        # no decode step is ever dispatched, pipelined or not
+        # steps are block steps and no decode step is ever dispatched.
+        # An engine has ONE kind of step, and the loop (the sequential
+        # body, the overlap pipeline, its drains and its failure ladder)
+        # is one: what the kind supplies is bound here, once, so that an
+        # iteration of either kind runs no test of the other's. Who is
+        # live at the next dispatch and the blocks they need
+        # (``_plan``), the dispatch (``_dispatch``), the consume and the
+        # scatter of its result, the sequential step with its probe,
+        # and which end of a request needs no further step (``_ended``:
+        # a block-diffusion request whose budget is spent still awaits
+        # its block's commit)
         self.diffusion = engine.diffusion
         self._step_positions = self.diffusion.block_length if self.diffusion else 1
+        if self.diffusion is None:
+            self._kind, self._ended = "decode", Request.finished
+            self._plan, self._dispatch = self._plan_decode, self._dispatch_pipeline
+            self._consume, self._scatter, self._step_fns = self._consume_decode, self._scatter_decode, self._decode_step_fns
+        else:
+            self._kind, self._ended = "block_step", Request.ended_on_eos
+            self._plan, self._dispatch = self._plan_block, self._dispatch_block
+            self._consume, self._scatter, self._step_fns = self._consume_block, self._scatter_block, self._block_step_fns
         self._pipe: Optional[_Frontier] = None
         # the handles that hold decode tokens bookkept and not yet on
         # their streams' queues (GenerationHandle._emit_later). A put
@@ -1968,7 +1996,7 @@ class ContinuousBatchingScheduler:
                 "prefill", prompt_len=len(req.prompt),
                 prefix_reused=prefix_len, replica=self.fault_scope,
             )
-            if self.obs_enabled and was_first and self.diffusion is None:  # (a diffusion prefill yields no token: _block_once)
+            if self.obs_enabled and was_first and self.diffusion is None:  # (a diffusion prefill yields no token: _scatter_block)
                 # gated like tpot (trace-derived in _finish) so disabling
                 # observability drops all three SLO windows together, not
                 # a confusing two of three
@@ -2403,16 +2431,18 @@ class ContinuousBatchingScheduler:
 
         return step, probe
 
-    def _decode_once(self) -> bool:
+    def _step_once(self) -> bool:
+        """One sequential step of the engine's kind (a decode step, or
+        a block-diffusion engine's block step) across all running slots."""
         if not self._running:
             return False
         with self._phase("sched.schedule"):
             order = sorted(self._running.values(), key=lambda s: s.slot)
-            step, probe = self._decode_step_fns(order)
+            step, probe = self._step_fns(order)
         info = self._step_info
-        info["kind"] = "decode"
+        info["kind"] = self._kind
         with phase("sched.device_step") as p_dev:
-            out = self.supervisor.run_step("decode", step, order, probe)
+            out = self.supervisor.run_step(self._kind, step, order, probe)
         self._step_walls["device"] = p_dev.seconds
         if out is None:
             info["handled_failure"] = True  # quarantined or journal-replayed
@@ -2421,18 +2451,18 @@ class ContinuousBatchingScheduler:
         return True
 
     def _sequential_tail(self, order, out) -> bool:
-        """What follows a sequential decode step whose tokens the
-        supervisor returned (``_decode_once``, and the re-run of a
-        failed pipelined step): the engine's spans adopted, NaN blame,
-        the tokens scattered and counted. False where the blame took
-        the step (a handled failure: nothing was scattered)."""
+        """What follows a sequential step whose result the supervisor
+        returned (``_step_once``, and the re-run of a failed pipelined
+        step): the engine's spans adopted, NaN blame, the tokens
+        scattered and counted. False where the blame took the step (a
+        handled failure: nothing was scattered)."""
         info = self._step_info
         info["execute_s"] = self._engine_spans(decode_result=True)
-        if self._quarantine_nan("decode", order):
+        if self._quarantine_nan(self._kind, order):
             info["handled_failure"] = True
             return False
         with self._phase("sched.bookkeep"):
-            n_live, _ = self._scatter_decode(order, out)
+            n_live, _ = self._scatter(order, out)
         info["emitted"] = n_live
         self.token_rate.record(n_live)
         return True
@@ -2493,7 +2523,7 @@ class ContinuousBatchingScheduler:
             if (
                 req.handle.done()
                 or req.cancelled
-                or req.finished()
+                or self._ended(req)
                 or (req.deadline is not None and now >= req.deadline)
                 or req.drafter is not None
                 # constrained slots are non-steady by construction: the
@@ -2577,8 +2607,11 @@ class ContinuousBatchingScheduler:
         except Exception as e:
             self._pipeline_failure(e, f.seq0)
 
+    def _consume_decode(self, f: "_Frontier"):
+        return self.engine.consume_decode(f.handle)
+
     def _consume_and_finish(self, f: "_Frontier"):
-        """Consume one in-flight decode step: blocked (double-buffered)
+        """Consume one in-flight step: blocked (double-buffered)
         readback, watchdog/stall arbitration, NaN blame, token scatter
         — then, if any stream finished, drain the successor frontier
         before releasing its blocks. Returns tokens emitted, or None
@@ -2586,9 +2619,9 @@ class ContinuousBatchingScheduler:
         blame). Device errors propagate to the caller's
         pipeline-failure handling."""
         self._emit_late()
-        faults.inject(faults.GENERATION_ASYNC_READBACK, ("decode", len(f.states)))
+        faults.inject(faults.GENERATION_ASYNC_READBACK, (self._kind, len(f.states)))
         with phase("sched.device_step") as p_dev:
-            out = self.engine.consume_decode(f.handle)
+            out = self._consume(f)
         self._add_wall("device", p_dev.seconds)
         # completion stamp (satellite: dispatch AND completion): the
         # successor — if any — only starts device work now, so its
@@ -2615,8 +2648,8 @@ class ContinuousBatchingScheduler:
             self._step_info["handled_failure"] = True
             self._discard_frontier()
             self.supervisor._restart_and_replay(
-                StalledStepError("decode step exceeded the watchdog stall timeout"),
-                "decode",
+                StalledStepError(f"{self._kind} step exceeded the watchdog stall timeout"),
+                self._kind,
             )
             return None
         ok = self.engine.last_finite
@@ -2627,11 +2660,11 @@ class ContinuousBatchingScheduler:
             # blame rules — partial blame quarantines and keeps the
             # survivors' tokens from THIS step, whole-batch restarts
             self._discard_frontier()
-            if self._quarantine_nan("decode", f.states):
+            if self._quarantine_nan(self._kind, f.states):
                 self._step_info["handled_failure"] = True
                 return None
         with self._phase("sched.bookkeep"):
-            n_live, finish = self._scatter_decode(f.states, out, defer_finish=True)
+            n_live, finish = self._scatter(f.states, out, defer_finish=True)
             self.token_rate.record(n_live)
         # the consumed step's handle goes HERE, in a span, and not
         # wherever the frame that holds the frontier returns. No free
@@ -2719,24 +2752,27 @@ class ContinuousBatchingScheduler:
         self._discard_frontier()
         self._step_info["handled_failure"] = True
         if self.engine.donate:
-            self.supervisor._restart_and_replay(e, "decode")
+            self.supervisor._restart_and_replay(e, self._kind)
             return
         order = [
             s for s in sorted(self._running.values(), key=lambda s: s.slot)
-            if not s.req.handle.done() and not s.req.finished()
+            if not s.req.handle.done() and not self._ended(s.req)
         ]
         if not order:
             return
-        step, probe = self._decode_step_fns(order)
-        out = self.supervisor.resume_step("decode", e, step, order, probe, since_seq)
+        step, probe = self._step_fns(order)
+        out = self.supervisor.resume_step(self._kind, e, step, order, probe, since_seq)
         if out is not None and self._sequential_tail(order, out):
             self._step_info["handled_failure"] = False
 
     def pipeline_stats(self) -> Dict:
         """The ``pipeline`` section of ``/v2/stats``: monotone totals of
         the overlap loop's decisions. ``decode_steps_total`` is every
-        decode step dispatched (``engine.step_counts``), of which
-        ``pipelined_steps_total`` went through ``_dispatch_pipeline``;
+        decode step dispatched (``engine.step_counts``) and
+        ``block_steps_total`` every block step (an engine has one kind:
+        the other reads 0), of which ``pipelined_steps_total`` went
+        through the pipeline's dispatch (``_dispatch_pipeline``,
+        ``_dispatch_block``: sent out beside the step before them);
         ``reclaims_total`` the blocks the pipeline's growth took from
         the prefix cache; ``drains_total`` the frontier drains by reason
         (``_drain_frontier``'s callers); ``emits_deferred_total`` the
@@ -2751,6 +2787,7 @@ class ContinuousBatchingScheduler:
         meets a dict that changes size."""
         return {
             "decode_steps_total": self.engine.step_counts["decode"],
+            "block_steps_total": self.engine.step_counts.get("block_step", 0),
             "pipelined_steps_total": self.pipe_dispatches,
             "reclaims_total": self.pipe_reclaims,
             "drains_total": dict(self.pipe_drains),
@@ -2759,8 +2796,70 @@ class ContinuousBatchingScheduler:
             "release_wait_total_s": self.release_wait_s,
         }
 
+    def _plan_decode(self, order, covered):
+        """The slots live at the NEXT decode dispatch, and whether the
+        pool fell short of their growth. Budget-predicted finishes
+        are excluded (sequential would have freed them before this
+        step); EOS cannot be predicted and is handled at consume.
+        Their block tables grow for the dispatch positions. An
+        empty free list is the prefix cache's steady state, not
+        pressure: the block comes from the cache's unreferenced
+        entries, as in _grow, with the step in flight. Only a
+        pool with nothing left to evict is short (and nobody
+        grows after it): capping speculation and preempting
+        mutate running slots, so that pressure drains and is
+        handled sequentially. ``covered``: the slots of the step in
+        flight, each one token ahead of what the host has bookkept."""
+        live, short, blocks_for = [], False, self.engine.cache_config.blocks_for
+        for s in order:
+            pend = 1 if s.slot in covered else 0
+            if s.req.n_generated + pend >= s.req.max_new:
+                continue
+            live.append(s)
+            need = blocks_for(s.cached_len + pend + 1)
+            if len(s.blocks) < need:
+                short = self._grow_to(s, need, short)
+        return live, short
+
+    def _plan_block(self, order, covered):
+        """``_plan_decode`` for a block step. What the step in flight
+        FIXED is not known, and is not needed: that forward is a slot's
+        commit iff every row of its block was fixed on entry to it,
+        which is the host's ``blk.fixed`` since the consume before. A
+        slot whose commit is in flight starts its next block at the
+        next dispatch (a whole block of positions further on) or, its
+        budget spent, ends with that commit and is left out (every
+        token of the block left when its last row was fixed, so the
+        count is final); an end-of-sequence token cannot be predicted
+        and is handled at consume."""
+        live, short, b, blocks_for = [], False, self._step_positions, self.engine.cache_config.blocks_for
+        for s in order:
+            committing = s.slot in covered and bool(s.blk.fixed.all())
+            if committing and s.req.n_generated >= s.req.max_new:
+                continue
+            live.append(s)
+            need = blocks_for(s.cached_len + b * committing + b)
+            if len(s.blocks) < need:
+                short = self._grow_to(s, need, short)
+        return live, short
+
+    def _grow_to(self, s: _Running, need: int, short: bool) -> bool:
+        """The pipeline's block growth for one slot, to ``need`` blocks
+        (``_take_block`` says why it is sound with a step in flight).
+        Returns ``short``: nobody grows once the pool had nothing left
+        to evict."""
+        while not short and len(s.blocks) < need:
+            got, freed = self._take_block()
+            self.pipe_reclaims += freed
+            if got is None:
+                short = True
+            else:
+                s.blocks.extend(got)
+        return short
+
     def _try_pipeline(self) -> Optional[bool]:
-        """One overlapped-decode iteration. Returns None when the
+        """One overlapped iteration (decode steps, or a block-diffusion
+        engine's block steps). Returns None when the
         iteration must run sequentially instead (the frontier is
         guaranteed drained by then); True when pipelined work happened.
         Steady state: dispatch step N+1 (token carry from step N's
@@ -2784,42 +2883,17 @@ class ContinuousBatchingScheduler:
         info = self._step_info
         f = self._pipe
         with self._phase("sched.schedule"):
-            covered = {s.slot for s in f.states} if f is not None else set()
-            # slots live at the NEXT dispatch: budget-predicted finishes
-            # are excluded (sequential would have freed them before this
-            # step); EOS cannot be predicted and is handled at consume.
-            # Their block tables grow for the dispatch positions. An
-            # empty free list is the prefix cache's steady state, not
-            # pressure: the block comes from the cache's unreferenced
-            # entries, as in _grow, with the step in flight. Only a
-            # pool with nothing left to evict is short (and nobody
-            # grows after it): capping speculation and preempting
-            # mutate running slots, so that pressure drains and is
-            # handled sequentially
-            live, short = [], False
-            for s in order:
-                pend = 1 if s.slot in covered else 0
-                if s.req.n_generated + pend >= s.req.max_new:
-                    continue
-                live.append(s)
-                need = self.engine.cache_config.blocks_for(s.cached_len + pend + 1)
-                while not short and len(s.blocks) < need:
-                    got, freed = self._take_block()
-                    self.pipe_reclaims += freed
-                    if got is None:
-                        short = True
-                    else:
-                        s.blocks.extend(got)
+            live, short = self._plan(order, {s.slot for s in f.states} if f is not None else set())
         if f is None and (short or not live):
             return None
-        info["kind"] = "decode"
+        info["kind"] = self._kind
         if short:
             self._drain_frontier("pressure")
             return True
         new_f = None  # (the stream tail: nothing left to dispatch — consume only)
         if live:
             try:
-                new_f = self._dispatch_pipeline(live, f)
+                new_f = self._dispatch(live, f)
             except Exception as e:
                 # dispatch failed host-side; the in-flight predecessor is
                 # healthy — consume it first, then give the failed step the
@@ -3039,47 +3113,41 @@ class ContinuousBatchingScheduler:
         # (a kept block below ``base``: all its rows had left, so the prefill was its commit)
         return _Block(self._step_positions, req.prompt[base:])
 
-    def _collect_blocks(self, order):
+    def _collect_blocks(self, order, chained: bool = False):
         """Slot-indexed arrays of a block step: every live slot's block
         (tokens, fixed rows), its base and forwards, its table, and the
-        request's sampling parameters and rule."""
+        request's sampling parameters and rule. ``chained``: for a step
+        that follows one still in flight, whose blocks are on the device
+        alone (``engine.block_async``'s ``prev``): None in their place."""
         b, width = self.engine.max_batch_slots, self._step_positions
-        tokens, fixed = np.zeros((b, width), np.int32), np.zeros((b, width), bool)
-        base, forwards = np.zeros((b,), np.int32), np.zeros((b,), np.int32)
         tables = np.zeros((b, self.engine.max_blocks_per_seq), np.int32)
         active = np.zeros((b,), bool)
         temps, top_ks, seeds = np.zeros((b,), np.float32), np.zeros((b,), np.int32), np.zeros((b,), np.uint32)
         n_fix, threshold = np.zeros((b,), np.int32), np.full((b,), 2.0, np.float32)
+        tokens = fixed = base = forwards = None
+        if not chained:
+            tokens, fixed = np.zeros((b, width), np.int32), np.zeros((b, width), bool)
+            base, forwards = np.zeros((b,), np.int32), np.zeros((b,), np.int32)
         for state in order:
             i, sp, blk = state.slot, state.req.sampling, state.blk
-            tokens[i], fixed[i], base[i], forwards[i] = blk.tokens, blk.fixed, state.cached_len, blk.forwards
+            if not chained:
+                tokens[i], fixed[i], base[i], forwards[i] = blk.tokens, blk.fixed, state.cached_len, blk.forwards
             tables[i, : len(state.blocks)] = state.blocks
             active[i] = True
             temps[i], top_ks[i], seeds[i] = sp.temperature, sp.top_k, sp.seed & 0xFFFFFFFF
             n_fix[i], threshold[i] = state.rule
         return tokens, fixed, base, forwards, tables, active, temps, top_ks, seeds, n_fix, threshold
 
-    def _block_once(self) -> bool:
-        """One block-diffusion step across all running slots (a
-        sequential step, as ``_verify_once`` is): a forward of every
-        slot's block, then each slot's newly final tokens emitted IN
-        POSITION ORDER: a token leaves when it and every earlier
-        position of the reply are fixed, several a step or none. A slot
-        whose forward was its block's commit moves on to the next block;
-        a request whose budget is spent ends with its last block's
-        commit (the plain loop runs every block whole and drops the
-        tokens past the budget), one that emits its end-of-sequence
-        token ends there, inside the block."""
-        if not self._running:
-            return False
-        info = self._step_info
-        info["kind"] = "block_step"
-        with self._phase("sched.schedule"):
-            order = sorted(self._running.values(), key=lambda s: s.slot)
-            (tokens, fixed, base, forwards, tables, active, *params) = self._collect_blocks(order)
+    def _block_step_fns(self, order):
+        """``_decode_step_fns`` for a block step: the sequential step
+        and its bisection probe over ``order``, from one collection."""
+        tokens, fixed, base, forwards, tables, active, *params = self._collect_blocks(order)
 
         def step():
-            return self.engine.block_step(tokens, fixed, base, forwards, tables, active, *params)
+            # (engine.block_step's two halves called from this frame, as the decode step's are)
+            return self.engine.finish_block(
+                self.engine.block_async(tokens, fixed, base, forwards, tables, active, *params)
+            )
 
         def probe(subset):
             # blame-assignment probe: the same step with only ``subset`` active; its
@@ -3089,57 +3157,98 @@ class ContinuousBatchingScheduler:
                 act[s.slot] = True
             self._probe_call(lambda: self.engine.block_step(tokens, fixed, base, forwards, tables, act, *params))
 
-        with phase("sched.device_step") as p_dev:
-            result = self.supervisor.run_step("block_step", step, order, probe)
-        self._step_walls["device"] = p_dev.seconds
-        if result is None:
-            info["handled_failure"] = True
-            return True  # failure handled: quarantined or journal-replayed
-        info["execute_s"] = self._engine_spans(decode_result=True)
-        if self._quarantine_nan("block_step", order):
-            info["handled_failure"] = True
-            return True
-        n_emitted = 0
-        with self._phase("sched.bookkeep"):
-            now = self.clock()
-            for state in order:
-                if self._running.get(state.slot) is not state:
-                    continue  # preempted/expired between collect and scatter
-                req, i, blk = state.req, state.slot, state.blk
-                if req.handle.done():
-                    continue  # watchdog-reaped mid-step; _expire releases it
-                if result["commit"][i]:
-                    # the block's K/V is now what later blocks read
-                    state.cached_len += self._step_positions
-                    state.blk = _Block(self._step_positions)
-                    if req.finished():
-                        self._finish(state)
-                    continue
-                for j in np.nonzero(result["chosen"][i])[0]:
-                    blk.tokens[j], blk.fixed[j], blk.fixed_at[j] = result["tokens"][i, j], True, blk.forwards
-                blk.forwards += 1
-                # in order: the reply's next token is row (prompt + emitted - base) of the block
-                first, emitted = req.n_generated == 0, 0
-                while req.n_generated < req.max_new:
-                    j = len(req.original_prompt) + req.n_generated - state.cached_len
-                    if j >= self._step_positions or not blk.fixed[j]:
-                        break
-                    req.fixed_at.append(blk.fixed_at[j])
-                    self._emit_token(state, int(blk.tokens[j]), later=True)
-                    emitted += 1
-                    if req.generated[-1] == req.sampling.eos_id:
-                        break
-                if not emitted:
-                    continue
-                n_emitted += emitted
-                req.trace.note_tokens(emitted, "block_step")
-                if self.obs_enabled and first and req.preemptions == 0 and req.replays == 0:
-                    self.stats.observe("ttft", max(0.0, now - req.submitted_at), exemplar=req.journey.journey_id)
+        return step, probe
+
+    def _dispatch_block(self, live, prev: Optional["_Frontier"]) -> "_Frontier":
+        """``_dispatch_pipeline`` for a block step. With an unconsumed
+        predecessor the slots' blocks, flags, bases and forward counts
+        are its program's device results, handed on as they are (the
+        host holds those of the step BEFORE it): nothing of the state
+        is compared or uploaded, whatever the live set; a table that
+        grew, or a slot left out, uploads what differs."""
+        with self._phase("sched.stage"):
+            args = self._collect_blocks(live, chained=prev is not None)
+        hb_prev = self._heartbeat
+        seq0 = prev.seq0 if prev is not None else self._hb_seq
+        self._hb_seq += 1
+        seq = self._hb_seq
+        self._heartbeat = (seq, self.clock())  # dispatch stamp
+        try:
+            handle = self.engine.block_async(*args, prev=prev.handle if prev is not None else None)
+        except Exception:
+            self._heartbeat = hb_prev  # the step never went in flight
+            self._hb_seq = seq  # seq stays burned; stall flags on it are void
+            raise
+        self._step_spans.append(("dispatch", handle.t0, handle.t_disp))
+        self._step_children += handle.children
+        self._add_wall("dispatch", handle.t_disp - handle.t0)
+        return _Frontier(handle, list(live), None, None, seq, seq0)
+
+    def _consume_block(self, f: "_Frontier"):
+        """Consume a block step, telling the engine which of its slots
+        still run: a request that ended on its end-of-sequence token at
+        the consume before (its release waits for this step, which was
+        dispatched with it) is work a sequential loop never does, and
+        the rule's counters leave it out."""
+        running = np.zeros((self.engine.max_batch_slots,), bool)
+        for s in f.states:
+            running[s.slot] = self._running.get(s.slot) is s and not s.req.ended_on_eos()
+        return self.engine.consume_block(f.handle, running)
+
+    def _scatter_block(self, order, result, defer_finish: bool = False):
+        """Scatter one block step's result back onto the slot states
+        (``_scatter_decode`` for a block step: the same callers, the
+        same answer, the same ``defer_finish``): each slot's newly final
+        tokens emitted IN POSITION ORDER: a token leaves when it and
+        every earlier position of the reply are fixed, several a step or
+        none. A slot whose forward was its block's commit moves on to
+        the next block; a request whose budget is spent ends with its
+        last block's commit (the plain loop runs every block whole and
+        drops the tokens past the budget), one that emits its
+        end-of-sequence token ends there, inside the block: the rows a
+        step already in flight ran for it are skipped."""
+        n_emitted, finish, now = 0, [], self.clock()
+        for state in order:
+            if self._running.get(state.slot) is not state:
+                continue  # preempted/expired between collect and scatter
+            req, i, blk = state.req, state.slot, state.blk
+            if req.handle.done():
+                continue  # watchdog-reaped mid-step; _expire releases it
+            if req.ended_on_eos():
+                continue  # ended at a previous pipeline consume
+            if result["commit"][i]:
+                # the block's K/V is now what later blocks read
+                state.cached_len += self._step_positions
+                state.blk = _Block(self._step_positions)
+                if req.finished():
+                    finish.append(state)
+                continue
+            for j in np.nonzero(result["chosen"][i])[0]:
+                blk.tokens[j], blk.fixed[j], blk.fixed_at[j] = result["tokens"][i, j], True, blk.forwards
+            blk.forwards += 1
+            # in order: the reply's next token is row (prompt + emitted - base) of the block
+            first, emitted = req.n_generated == 0, 0
+            while req.n_generated < req.max_new:
+                j = len(req.original_prompt) + req.n_generated - state.cached_len
+                if j >= self._step_positions or not blk.fixed[j]:
+                    break
+                req.fixed_at.append(blk.fixed_at[j])
+                self._emit_token(state, int(blk.tokens[j]), later=True)
+                emitted += 1
                 if req.generated[-1] == req.sampling.eos_id:
-                    self._finish(state)  # an end inside a block: with what has left
-        info["emitted"] = n_emitted
-        self.token_rate.record(n_emitted)
-        return True
+                    break
+            if not emitted:
+                continue
+            n_emitted += emitted
+            req.trace.note_tokens(emitted, "block_step")
+            if self.obs_enabled and first and req.preemptions == 0 and req.replays == 0:
+                self.stats.observe("ttft", max(0.0, now - req.submitted_at), exemplar=req.journey.journey_id)
+            if req.generated[-1] == req.sampling.eos_id:
+                finish.append(state)  # an end inside a block: with what has left
+        if not defer_finish:
+            for state in finish:
+                self._finish(state)
+        return n_emitted, finish
 
     def _phase(self, name: str, **args) -> phase:
         """Open one host span of THIS iteration (obs/steptrace.phase:
@@ -3239,9 +3348,9 @@ class ContinuousBatchingScheduler:
         # overlapped decode: steady-state iterations pipeline
         # dispatch/consume; any non-steady event drains the frontier
         # and falls through to the sequential body
-        r = self._try_pipeline() if self.overlap and self.diffusion is None else None
+        r = self._try_pipeline() if self.overlap else None
         if r is not None:
-            did, kind = r, "decode"
+            did, kind = r, self._kind
         else:
             with self._phase("sched.schedule"):
                 self._expire()
@@ -3257,10 +3366,7 @@ class ContinuousBatchingScheduler:
             if admitted:
                 info["admitted"] = admitted
             speculating = any(s.step_k > 0 for s in self._running.values())
-            if self.diffusion is not None:
-                stepped = self._block_once()
-            else:
-                stepped = self._verify_once() if speculating else self._decode_once()
+            stepped = self._verify_once() if speculating else self._step_once()
             did, kind = stepped or admitted > 0, "admit"
         if did:
             # before housekeep, whose overload tick may shed requests:

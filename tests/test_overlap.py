@@ -651,8 +651,9 @@ def test_pipeline_section_of_stats_counts_the_loop_s_decisions(decoder_params):
     assert all(a <= b for p, q in zip(snaps, snaps[1:]) for a, b in zip(flat(p), flat(q)))
     assert all(0 <= p["pipelined_steps_total"] <= p["decode_steps_total"] for p in snaps)
     last = sched.stats.snapshot()["pipeline"]
-    assert set(last) == {"decode_steps_total", "pipelined_steps_total", "reclaims_total", "drains_total",
+    assert set(last) == {"decode_steps_total", "block_steps_total", "pipelined_steps_total", "reclaims_total", "drains_total",
                          "emits_deferred_total", "emits_pending", "release_wait_total_s"}
+    assert last["block_steps_total"] == 0  # (a block-diffusion engine's: tests/test_sdar.py)
     assert set(last["drains_total"]) == {"nonsteady", "finish", "pressure", "idle"}
     assert sum(last["drains_total"].values()) == drained[0] > 0
     assert last["drains_total"]["pressure"] >= 1 and last["drains_total"]["finish"] >= 1

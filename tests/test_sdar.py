@@ -46,9 +46,9 @@ def sharp(params):
     return dict(params, lm_head=params["lm_head"] * 60.0)
 
 
-def make_engine(params, config, slots=3, steps=2, **rule):
+def make_engine(params, config, slots=3, steps=2, cache_config=None, **rule):
     return GenerationEngine(
-        params, sdar.engine_config(config, 64), max_batch_slots=slots, block_size=8, max_seq_len=64,
+        params, sdar.engine_config(config, 64), cache_config, max_batch_slots=slots, block_size=8, max_seq_len=64,
         prompt_buckets=[16, 32], diffusion=sdar.diffusion_rule(config, denoising_steps=steps, **rule),
     )
 
@@ -58,8 +58,8 @@ def prompts_of(seed, lengths, vocab=512):
     return [[int(t) for t in rs.randint(0, vocab - 1, size=n)] for n in lengths]
 
 
-def serve(engine, prompts, budgets, **sampling):
-    sched = ContinuousBatchingScheduler(engine)
+def serve(engine, prompts, budgets, overlap=None, **sampling):
+    sched = ContinuousBatchingScheduler(engine, overlap=overlap)
     handles = [sched.submit(p, SamplingParams(max_new_tokens=n, **sampling)) for p, n in zip(prompts, budgets)]
     for _ in range(2000):
         if all(h.done() for h in handles) or not sched.step():
@@ -201,19 +201,25 @@ def test_the_streamed_kernel_refuses_a_block_mask_by_name(monkeypatch):
 
 
 # ------------------------------------------------------- the served path
+OVERLAP = pytest.mark.parametrize("overlap", [True, False], ids=["pipelined", "sequential"])
+
+
+@OVERLAP
 @pytest.mark.parametrize("steps", [1, 2, 4])
-def test_served_tokens_and_fixed_at_are_the_plain_loops(params, config, steps):
+def test_served_tokens_and_fixed_at_are_the_plain_loops(params, config, steps, overlap):
     """Prompt remainders ``P mod 4`` in 0..3, budgets that end inside a
     block, more requests than slots (so slots sit at different phases of
     their blocks in one step): tokens and ``fixed_at`` equal
-    ``reference.generate``; the mask token is never emitted."""
+    ``reference.generate``; the mask token is never emitted. With the
+    step in the overlap pipeline and without: the same bytes."""
     engine = make_engine(params, config, steps=steps)
     lengths, budgets = (8, 9, 10, 11, 3), (9, 8, 6, 12, 5)
     prompts = prompts_of(steps, lengths)
     seen = []
-    real = engine.block_step
-    engine.block_step = lambda *a: seen.append(real(*a)) or seen[-1]
-    handles, _ = serve(engine, prompts, budgets)
+    real = engine.consume_block  # (every block step's result comes through it, pipelined or not)
+    engine.consume_block = lambda *a: seen.append(real(*a)) or seen[-1]
+    handles, sched = serve(engine, prompts, budgets, overlap=overlap)
+    assert (sched.pipe_dispatches > 0) == overlap
     for p, n, h in zip(prompts, budgets, handles):
         tokens, fixed_at = sdar.generate(params, config, p, n, steps=steps)
         assert h.result(0) == tokens and h._request.fixed_at == fixed_at
@@ -242,19 +248,21 @@ def test_slot_forwards_are_blocks_times_steps_plus_one(params, config):
     assert c["fixed_per_forward_histogram"] == [0, 0, 12, 0, 0]
 
 
-def test_the_dynamic_rule_takes_fewer_forwards_and_is_the_plain_loops(sharp, config):
+@OVERLAP
+def test_the_dynamic_rule_takes_fewer_forwards_and_is_the_plain_loops(sharp, config, overlap):
     static = make_engine(sharp, config, steps=4)
     dynamic = make_engine(sharp, config, steps=4, remasking="low_confidence_dynamic", threshold=0.5)
     prompts, budgets = prompts_of(5, (9, 12, 6)), (11, 8, 13)
-    serve(static, prompts, budgets)
-    handles, _ = serve(dynamic, prompts, budgets)
+    serve(static, prompts, budgets, overlap=overlap)
+    handles, sched = serve(dynamic, prompts, budgets, overlap=overlap)
+    assert (sched.pipe_dispatches > 0) == overlap
     for p, n, h in zip(prompts, budgets, handles):
         tokens, fixed_at = sdar.generate(sharp, config, p, n, steps=4, remasking="low_confidence_dynamic", threshold=0.5)
         assert h.result(0) == tokens and h._request.fixed_at == fixed_at
     assert dynamic.diffusion_stats()["slot_forwards_total"] < static.diffusion_stats()["slot_forwards_total"]
     assert sum(dynamic.diffusion_stats()["fixed_per_forward_histogram"][2:]) > 0  # several rows passed at once
     # a request's own rule over the engine's default
-    handles, _ = serve(static, prompts[:1], budgets[:1], remasking="low_confidence_dynamic", threshold=0.5)
+    handles, _ = serve(static, prompts[:1], budgets[:1], overlap=overlap, remasking="low_confidence_dynamic", threshold=0.5)
     assert handles[0].result(0) == sdar.generate(sharp, config, prompts[0], budgets[0], steps=4,
                                                  remasking="low_confidence_dynamic", threshold=0.5)[0]
 
@@ -289,30 +297,39 @@ def test_an_end_inside_a_block_ends_the_request_with_what_has_left(params, confi
     assert handles[0].result(0) == full[: at + 1] == sdar.generate(params, config, prompt, 12, steps=2, eos_id=eos)[0]
 
 
-def test_cancel_and_preemption_inside_a_block(params, config):
+@OVERLAP
+def test_cancel_and_preemption_inside_a_block(params, config, overlap):
     """A request preempted with a row fixed out of order resumes with it
     (and gives the unpreempted stream, ``fixed_at`` too); one cancelled
-    there ends with an error after a prefix of its stream."""
+    there ends with an error after a prefix of its stream. With a step
+    in flight both drain it first."""
     prompts, budgets = prompts_of(10, (9, 14)), (14, 10)
     want = [sdar.generate(params, config, p, n, steps=4) for p, n in zip(prompts, budgets)]
     engine = make_engine(params, config, steps=4)
-    sched = ContinuousBatchingScheduler(engine)
+    sched = ContinuousBatchingScheduler(engine, overlap=overlap)
     handles = [sched.submit(p, SamplingParams(max_new_tokens=n)) for p, n in zip(prompts, budgets)]
-    preempted = 0
+
+    def inside():
+        return [s for s in sched._running.values() if 0 < s.blk.fixed.sum() < B and s.blk.forwards > 0]
+
+    preempted = in_flight = 0
     for _ in range(400):
-        inside = [s for s in sched._running.values() if 0 < s.blk.fixed.sum() < B and s.blk.forwards > 0]
-        if inside and preempted < 3:
-            assert sched._preempt_youngest()
-            preempted += 1
+        if inside() and preempted < 3 and (sched._pipe is not None) == overlap:
+            in_flight += sched._pipe is not None
+            sched._drain_frontier("pressure")  # (what the loop does itself before it preempts: never with a step in flight)
+            if inside():
+                assert sched._preempt_youngest()
+                preempted += 1
         if all(h.done() for h in handles) or not sched.step():
             break
-    assert preempted == 3 and sum(h._request.preemptions for h in handles) == 3
+    assert preempted == 3 and sum(h._request.preemptions for h in handles) == 3 and in_flight == 3 * overlap
     assert [(h.result(0), h._request.fixed_at) for h in handles] == want
     # cancel
-    sched = ContinuousBatchingScheduler(make_engine(params, config, steps=4))
+    sched = ContinuousBatchingScheduler(make_engine(params, config, steps=4), overlap=overlap)
     h = sched.submit(prompts[0], SamplingParams(max_new_tokens=14))
     while h._request.n_generated < 3:
         sched.step()
+    assert (sched._pipe is not None) == overlap
     h.cancel()
     sched.step()
     with pytest.raises(ShuttingDownError):
@@ -321,18 +338,30 @@ def test_cancel_and_preemption_inside_a_block(params, config):
     assert got == want[0][0][: len(got)] and not sched._running and len(sched._free_slots) == 3
 
 
-def test_a_journal_replay_restarts_the_block_from_what_had_left(params, config):
+@pytest.mark.parametrize("site,nth", [("generation.decode_step", (4, 5)), ("generation.async_readback", (3,))])
+@OVERLAP
+def test_a_journal_replay_restarts_the_block_from_what_had_left(params, config, overlap, site, nth):
     """A crash mid-stream: the engine is reset and every stream replayed
     from its journal; the block in flight restarts from the emitted
-    tokens as fixed rows, and the streams complete at their budgets."""
+    tokens as fixed rows, and the streams complete at their budgets.
+    Pipelined, the crash meets a step in flight: at the dispatch of its
+    successor (the step in flight is consumed first) or at its own
+    consume (its successor is discarded)."""
     from flexflow_tpu.generation import RecoveryPolicy
-    from flexflow_tpu.runtime.faults import FaultPlan
+    from flexflow_tpu.runtime.faults import FaultInjected, FaultPlan
 
+    if site == "generation.async_readback" and not overlap:
+        pytest.skip("the sequential loop has no consume apart from its dispatch")
     prompts, budgets = prompts_of(11, (9, 6)), (10, 9)
     engine = make_engine(params, config)
-    sched = ContinuousBatchingScheduler(engine, recovery=RecoveryPolicy(sleep=lambda _s: None))
+    sched = ContinuousBatchingScheduler(engine, overlap=overlap, recovery=RecoveryPolicy(sleep=lambda _s: None))
     plan = FaultPlan(seed=0)
-    plan.on("generation.decode_step", mode="error", error=RuntimeError("device crash"), nth=(4, 5))
+    if site == "generation.async_readback":
+        # the consume is lost and the step's sequential re-run crashes twice: an engine-level fault
+        plan.on(site, mode="error", error=FaultInjected("readback lost"), nth=nth)
+        plan.on("generation.decode_step", mode="error", error=RuntimeError("device crash"), nth=(5, 6))
+    else:
+        plan.on(site, mode="error", error=RuntimeError("device crash"), nth=nth)
     with plan.active():
         handles = [sched.submit(p, SamplingParams(max_new_tokens=n)) for p, n in zip(prompts, budgets)]
         for _ in range(400):
@@ -358,6 +387,107 @@ def test_the_durable_journal_takes_a_step_of_several_tokens(params, config, tmp_
         sched.step()
     state, done = durable.lookup(h._request.durable_id)
     assert state == "done" and list(done["tokens"]) == h.result(0) == sdar.generate(params, config, prompt, 8, steps=1)[0]
+
+
+# ------------------------------------------- the block step in the overlap pipeline
+def counters(engine):
+    c = engine.diffusion_stats()
+    return {k: c[k] for k in ("slot_forwards_total", "commit_forwards_total", "blocks_committed_total",
+                              "tokens_fixed_total", "fixed_per_forward_histogram")}
+
+
+@pytest.mark.parametrize("rule", ["static", "dynamic"])
+def test_an_end_of_sequence_token_with_a_successor_in_flight_is_counted_as_the_sequential_loop_counts(sharp, config, rule):
+    """The successor was dispatched with the slot that then ended on
+    its end-of-sequence token: that slot's rows in it are neither
+    emitted nor counted, so streams, ``fixed_at`` AND the rule's
+    counters are the sequential loop's."""
+    kw = dict(steps=4, remasking="low_confidence_dynamic", threshold=0.5) if rule == "dynamic" else dict(steps=2)
+    prompts, budgets = prompts_of(16, (9, 12, 6)), (13, 11, 12)
+    full = serve(make_engine(sharp, config, **kw), prompts, budgets, overlap=False)[0][0].result(0)
+    eos = full[5]  # inside the first request's second block; the others run to their budgets or meet it too
+    got = {}
+    for overlap in (False, True):
+        engine = make_engine(sharp, config, **kw)
+        handles, sched = serve(engine, prompts, budgets, overlap=overlap, eos_id=eos)
+        got[overlap] = ([(h.result(0), h._request.fixed_at) for h in handles], counters(engine))
+        if overlap:
+            assert sched.pipe_drains["finish"] >= 1 and sched.pipe_dispatches > sched.pipe_drains["finish"]
+    assert got[True] == got[False]
+    assert got[True][0][0][0] == full[: full.index(eos) + 1] and len(full) > full.index(eos) + 1
+    # the forwards the engine RAN are more: the successor's rows for the ended slot
+    assert engine.step_counts["block_step"] >= 1
+
+
+def test_a_spent_budget_leaves_the_slot_out_of_the_successor_and_drains_nothing_before_its_commit(params, config):
+    """A request whose budget is spent still awaits its block's commit:
+    no drain on the steps before it (as ``finished()`` would ask on
+    every request's last block), and with that commit in flight the
+    next dispatch leaves the slot out."""
+    engine = make_engine(params, config, steps=4)
+    prompts, budgets = prompts_of(17, (8, 12)), (5, 14)  # the first ends a row into its second block
+    sched = ContinuousBatchingScheduler(engine)
+    handles = [sched.submit(p, SamplingParams(max_new_tokens=n)) for p, n in zip(prompts, budgets)]
+    left_out, awaiting, real = [], 0, sched._dispatch
+    sched._dispatch = lambda live, prev: left_out.extend(
+        (s.req, prev is not None) for s in sched._running.values() if s not in live) or real(live, prev)
+    for _ in range(400):
+        if all(h.done() for h in handles) or not sched.step():
+            break
+        spent = [s for s in sched._running.values() if s.req.finished()]
+        awaiting += bool(spent)
+        if spent:
+            assert sched._pipe is not None and sched.pipe_drains["nonsteady"] == 0
+    assert awaiting >= 3, "the budget-spent request never waited for its block's last rows and commit"
+    assert [r for r, _ in left_out] == [handles[0]._request]  # (the second's commit is consumed with nothing left to dispatch)
+    assert all(in_flight and r.n_generated == r.max_new for r, in_flight in left_out)
+    assert sched.pipe_drains == {"nonsteady": 0, "finish": 1, "pressure": 0, "idle": 0}  # (the last end has no successor)
+    for p, n, h in zip(prompts, budgets, handles):
+        assert (h.result(0), h._request.fixed_at) == sdar.generate(params, config, p, n, steps=4)
+    c = engine.diffusion_stats()
+    assert c["blocks_committed_total"] == c["commit_forwards_total"] == sum(
+        -(-(len(p) + n) // B) - len(p) // B for p, n in zip(prompts, budgets))
+
+
+def test_pool_pressure_drains_the_step_in_flight_and_preempts(params, config):
+    """A pool with nothing left to evict: the pipeline drains for
+    ``pressure`` and the sequential body preempts; the streams are the
+    plain loop's."""
+    import dataclasses
+
+    prompts, budgets = prompts_of(18, (9, 14, 11)), (22, 18, 20)
+    roomy = make_engine(params, config, steps=2)
+    tight = dataclasses.replace(roomy.cache_config, num_blocks=9)  # three sequences of up to 4 blocks, and the scratch block
+    got = {}
+    for overlap in (False, True):
+        engine = make_engine(params, config, steps=2, cache_config=tight)
+        handles, sched = serve(engine, prompts, budgets, overlap=overlap)
+        got[overlap] = [(h.result(0), h._request.fixed_at) for h in handles]
+        assert sched.preemptions >= 1
+        assert (sched.pipe_drains["pressure"] >= 1) == overlap
+    assert got[True] == got[False] == [sdar.generate(params, config, p, n, steps=2) for p, n in zip(prompts, budgets)]
+
+
+def test_the_pipeline_section_counts_block_steps(params, config):
+    engine = make_engine(params, config)
+    prompts, budgets = prompts_of(19, (8, 9, 10, 11, 3)), (9, 8, 6, 12, 5)  # five requests over three slots
+    sched = ContinuousBatchingScheduler(engine)
+    handles = [sched.submit(p, SamplingParams(max_new_tokens=n)) for p, n in zip(prompts[:2], budgets[:2])]
+    while sched._pipe is None:
+        sched.step()
+    # a slot is free and a step in flight: the admissions drain it first
+    handles += [sched.submit(p, SamplingParams(max_new_tokens=n)) for p, n in zip(prompts[2:], budgets[2:])]
+    while not all(h.done() for h in handles):
+        assert sched.step()
+    p = sched.pipeline_stats()
+    assert p["block_steps_total"] == engine.step_counts["block_step"] > 0 and p["decode_steps_total"] == 0
+    assert 0 < p["pipelined_steps_total"] < p["block_steps_total"]
+    assert p["drains_total"]["finish"] >= 1 and p["drains_total"]["nonsteady"] == 1 and p["emits_pending"] == 0
+    assert sched.stats.snapshot()["pipeline"] == p
+    assert [h.result(0) for h in handles] == [sdar.generate(params, config, q, n, steps=2)[0] for q, n in zip(prompts, budgets)]
+    _, off = serve(make_engine(params, config), prompts, budgets, overlap=False)
+    q = off.pipeline_stats()
+    assert q["pipelined_steps_total"] == 0 and not any(q["drains_total"].values()) and q["block_steps_total"] > 0
 
 
 # ------------------------------------------------------------ refusals
@@ -457,6 +587,19 @@ def test_a_steady_composition_uploads_nothing(params, config):
     assert engine.uploads["carried_hits_total"] == before["carried_hits_total"] + 5
     assert engine.uploads["carried_misses_total"] == before["carried_misses_total"]
     assert engine.uploads["uploads_total"] - before["uploads_total"] <= 2
+    # a pipelined steady step takes its state from the predecessor's device outputs: the same arrays, and
+    # no host array is compared for them (7 staged look-ups a step: the table, active and the five vectors)
+    calls, real = [], engine._block_jit
+    engine._block_jit = lambda params, *a: calls.append((a[:4], real(params, *a))) or calls[-1][1]
+    before = dict(engine.uploads)
+    for _ in range(3):
+        sched.step()
+    assert sched.pipe_dispatches >= 8 and len(calls) == 3
+    for (state, _), (_, results) in zip(calls[1:], calls):
+        assert all(x is y for x, y in zip(state, results[-4:]))
+    looked = sum(engine.uploads[k] - before[k] for k in ("staged_hits_total", "staged_misses_total"))
+    assert looked == 3 * 7
+    engine._block_jit = real
     while not all(h.done() for h in handles):
         sched.step()
 
