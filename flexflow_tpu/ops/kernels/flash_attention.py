@@ -13,12 +13,25 @@ match ops/attention.py.
 
 Backward follows the FlashAttention-2 decomposition: residuals are the
 output O and the per-row logsumexp L; dQ is computed by a kernel gridded
-over Q blocks, dK/dV by a kernel gridded over KV blocks.
+over Q blocks, dK/dV by a kernel gridded over KV blocks, which scores
+K Q^T (the scores transposed) so that dV = P^T dO and dK = dS^T Q are
+plain products of it.
+
+What each product runs in (all three kernels): q, k, v and dO reach the
+MXU as they were loaded, P and dS are cast to that type as the operand
+of their product (P V, P^T dO, dS K, dS^T Q), and every product
+accumulates in float32; the scores, the softmax scale, the mask, the
+running max and sum, ``lse``, ``delta``, ``exp`` and the accumulators
+are float32. So bfloat16 operands give nine bfloat16 products a layer
+and float32 operands nine float32 ones: the type is the input's, there
+is no switch. (On the v5e under libtpu 0.0.34 Mosaic rounds a float32
+product's operands to bfloat16 anyway, one pass: see PERF.md, PR 47.)
 """
 from __future__ import annotations
 
 import functools
 import math
+import os as _os
 from typing import Optional, Tuple
 
 import jax
@@ -28,19 +41,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-
-# Sequence block sizes. 128 matches the MXU systolic dimension; 256x256
-# blocks mean fewer grid invocations and online-softmax rescale passes
-# per output row, and 512x512 is left out because it did not finish
-# compiling in 20 minutes when it was last tried. Default policy: the
-# largest block in _BLOCK_CANDIDATES that divides the sequence, so long
-# sequences get 256 and seq 128 keeps 128.
-# Env-overridable (FF_FLASH_BLOCK_Q/K) for sweeps across clean child
-# processes; read once at import; malformed values fall back to the
-# adaptive policy rather than breaking every import of the package.
-import os as _os
-
-_BLOCK_CANDIDATES = (256, 128)
+# Sequence block sizes, from the chip (v5e; `chip_smoke.py --flash-sweep`, PR 47): the three kernels inside one
+# program (a layer's attention and its gradient) at [16, 512, 16, 64] bfloat16, not causal, ms a call on the
+# device's clock as fwd / bwd_dq / bwd_dkv:
+#                 block_k 128             block_k 256             block_k 512
+#   block_q 128   1.667 / 1.395 / 1.397   0.998 / 0.888 / 0.906   0.656 / 0.669 / 0.722
+#   block_q 256   1.099 / 0.869 / 1.105   0.658 / 0.600 / 0.752   0.527 / 0.508 / 0.616
+#   block_q 512   0.846 / 0.600 / 0.589   0.538 / 0.449 / 0.442   0.327 / 0.361 / 0.393
+# Mosaic compiles any of them in 0.13-0.31 s a kernel (the program: 3.7-4.3 s at every pair). The largest pair
+# wins in every kernel, and of the two the block a kernel LOOPS over counts more (keys in fwd and bwd_dq, queries
+# in bwd_dkv); 512 is this sequence whole, in one trip. Policy: the largest of _BLOCK_CANDIDATES dividing the
+# sequence, for queries and for keys, in all three kernels. FF_FLASH_BLOCK_Q/K override it for a sweep across
+# clean child processes: read once at import; a malformed value falls back to the policy, not a failed import.
+_BLOCK_CANDIDATES = (512, 256, 128)
 
 
 def _env_block(name: str) -> Optional[int]:
@@ -63,9 +76,8 @@ def pick_block(seq: int, env: Optional[int]) -> int:
     to the sequence (an override that does not divide the sequence is an
     error said aloud: the kernel's gate would refuse the call and the
     dense path run in its place), else the largest default candidate
-    dividing it,
-    else the largest power-of-two divisor (a non-dividing block would
-    leave sq // bq grid steps covering only a prefix of the rows)."""
+    dividing it, else the largest power-of-two divisor (a non-dividing
+    block would leave sq // bq grid steps covering only a prefix of the rows)."""
     if env is not None:
         block = min(env, seq)
         if seq % block:
@@ -102,13 +114,22 @@ def supports_shapes(q_shape: Tuple[int, ...], k_shape: Tuple[int, ...]) -> bool:
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+_NT = (((1,), (1,)), ((), ()))  # [m, d] x [n, d] -> [m, n]: the contraction over both operands' last dimension
+_NN = (((1,), (0,)), ((), ()))  # [m, n] x [n, d] -> [m, d]
+
+
+def _mxu(a, b, dims):
+    """A product on the MXU in the operands' type, accumulated in
+    float32: bfloat16 operands are multiplied as bfloat16, float32 ones
+    as float32 (in as many passes as the compiler makes of that)."""
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, sk):
     # q_ref: [bq, d]; k_ref/v_ref: [sk, d] (whole key sequence for this head)
     bq, d = q_ref.shape
     iq = pl.program_id(2)
-    q = q_ref[:].astype(jnp.float32) * scale
+    q = q_ref[:]
     m = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((bq, 1), jnp.float32)
     acc = jnp.zeros((bq, d), jnp.float32)
@@ -118,11 +139,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, 
 
     def body(j, carry):
         m, l, acc = carry
-        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bk]
+        k = k_ref[pl.ds(j * block_k, block_k), :]
+        v = v_ref[pl.ds(j * block_k, block_k), :]
+        s = _mxu(q, k, _NT) * scale  # [bq, bk]
         if causal:
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
@@ -130,16 +149,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal, block_k, 
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        acc_new = acc * corr + _mxu(p.astype(v.dtype), v, _NN)
         return m_new, l_new, acc_new
 
-    if causal:
-        # skip key blocks entirely above the diagonal
-        nk_eff = jnp.minimum(nk, (iq + 1) * bq // block_k + 1)
-    else:
-        nk_eff = nk
+    # causal: skip key blocks entirely above the diagonal
+    nk_eff = jnp.minimum(nk, (iq + 1) * bq // block_k + 1) if causal else nk
     m, l, acc = jax.lax.fori_loop(0, nk_eff, body, (m, l, acc))
     l = jnp.maximum(l, 1e-30)
     o_ref[:] = (acc / l).astype(o_ref.dtype)
@@ -150,14 +164,9 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     # q,k,v: [B, H, S, D]
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
-    if sq % bq or sk % bk:
-        # a non-dividing block would silently compute only the first
-        # (sq // bq) * bq query rows — fail loudly instead
-        raise ValueError(
-            f"sequence lengths ({sq}, {sk}) not divisible by blocks ({bq}, {bk})"
-        )
+    bq, bk = min(block_q, sq), min(block_k, sk)
+    if sq % bq or sk % bk:  # a non-dividing block would silently compute only the first (sq // bq) * bq query rows
+        raise ValueError(f"sequence lengths ({sq}, {sk}) not divisible by blocks ({bq}, {bk})")
     grid = (b, h, sq // bq)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, block_k=bk, sk=sk)
     o, lse = pl.pallas_call(
@@ -190,8 +199,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, scale, causal, block_k, sk):
     bq, d = q_ref.shape
     iq = pl.program_id(2)
-    q = q_ref[:].astype(jnp.float32) * scale
-    do = do_ref[:].astype(jnp.float32)
+    q = q_ref[:]
+    do = do_ref[:]
     lse = lse_ref[:]  # [bq, 1]
     delta = delta_ref[:]
     dq = jnp.zeros((bq, d), jnp.float32)
@@ -199,60 +208,51 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *, s
     q_pos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
 
     def body(j, dq):
-        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        k = k_ref[pl.ds(j * block_k, block_k), :]
+        v = v_ref[pl.ds(j * block_k, block_k), :]
+        s = _mxu(q, k, _NT) * scale
         if causal:
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         p = jnp.exp(s - lse)  # [bq, bk]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ds = p * (_mxu(do, v, _NT) - delta)
+        return dq + _mxu(ds.astype(k.dtype), k, _NN)
 
-    if causal:
-        nk_eff = jnp.minimum(nk, (iq + 1) * bq // block_k + 1)
-    else:
-        nk_eff = nk
+    nk_eff = jnp.minimum(nk, (iq + 1) * bq // block_k + 1) if causal else nk
     dq = jax.lax.fori_loop(0, nk_eff, body, dq)
     dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, *, scale, causal, block_q, sq):
+    # lse_ref / delta_ref: [1, sq] (a row: the query positions on the lanes)
     bk, d = k_ref.shape
     jk = pl.program_id(2)
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
+    k = k_ref[:]
+    v = v_ref[:]
     dk = jnp.zeros((bk, d), jnp.float32)
     dv = jnp.zeros((bk, d), jnp.float32)
     nq = sq // block_q
-    k_pos = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+    k_pos = jk * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 0)
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32) * scale
-        do = do_ref[pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[pl.ds(i * block_q, block_q), :]  # [bq, 1]
-        delta = delta_ref[pl.ds(i * block_q, block_q), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q = q_ref[pl.ds(i * block_q, block_q), :]
+        do = do_ref[pl.ds(i * block_q, block_q), :]
+        lse = lse_ref[:, pl.ds(i * block_q, block_q)]  # [1, bq]
+        delta = delta_ref[:, pl.ds(i * block_q, block_q)]
+        s = _mxu(k, q, _NT) * scale  # [bk, bq]: the scores transposed
         if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
+            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (bk, block_q), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)  # [bq, bk]
-        dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse)
+        dv = dv + _mxu(p.astype(do.dtype), do, _NN)
+        ds = p * (_mxu(v, do, _NT) - delta)
+        dk = dk + _mxu(ds.astype(q.dtype), q, _NN)
         return dk, dv
 
-    if causal:
-        # query blocks strictly below this key block see nothing
-        start = jk * bk // block_q
-    else:
-        start = 0
+    start = jk * bk // block_q if causal else 0  # causal: query blocks strictly below this key block see nothing
     dk, dv = jax.lax.fori_loop(start, nq, body, (dk, dv))
-    # q entered the loop pre-scaled, so dk = scale * dS^T Q already
-    dk_ref[:] = dk.astype(dk_ref.dtype)
+    dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[:] = dv.astype(dv_ref.dtype)
 
 
@@ -261,8 +261,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
     do = g
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    bq = min(block_q, sq)
-    bk = min(block_k, sk)
+    bq, bk = min(block_q, sq), min(block_k, sk)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True)  # [B,H,Sq,1]
 
     dq = pl.pallas_call(
@@ -282,6 +281,8 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
         name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)
 
+    # the same two vectors with the query positions on the lanes, as the transposed scores meet them
+    lse_row, delta_row = lse.reshape(b, h, 1, sq), delta.reshape(b, h, 1, sq)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal, block_q=bq, sq=sq),
         grid=(b, h, sk // bk),
@@ -290,8 +291,8 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
             pl.BlockSpec((None, None, bk, d), lambda ib, ih, jk: (ib, ih, jk, 0)),
             pl.BlockSpec((None, None, bk, d), lambda ib, ih, jk: (ib, ih, jk, 0)),
             pl.BlockSpec((None, None, sq, d), lambda ib, ih, jk: (ib, ih, 0, 0)),
-            pl.BlockSpec((None, None, sq, 1), lambda ib, ih, jk: (ib, ih, 0, 0)),
-            pl.BlockSpec((None, None, sq, 1), lambda ib, ih, jk: (ib, ih, 0, 0)),
+            pl.BlockSpec((None, None, 1, sq), lambda ib, ih, jk: (ib, ih, 0, 0)),
+            pl.BlockSpec((None, None, 1, sq), lambda ib, ih, jk: (ib, ih, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((None, None, bk, d), lambda ib, ih, jk: (ib, ih, jk, 0)),
@@ -303,7 +304,7 @@ def _flash_bwd(res, g, scale, causal, block_q, block_k, interpret):
         ],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse_row, delta_row)
     return dq, dk, dv
 
 
@@ -353,10 +354,7 @@ def flash_attention(
         block_k = pick_block(k.shape[1], ENV_BLOCK_K)
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    # [B, S, H, D] -> [B, H, S, D]
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))  # [B, S, H, D] -> [B, H, S, D]
     o = _flash_bhsd(qt, kt, vt, float(scale), bool(causal), int(block_q), int(block_k), bool(interpret))
     return jnp.swapaxes(o, 1, 2)
 
@@ -394,6 +392,8 @@ def flash_attention_sharded(
     return fn(q, k, v)
 
 
+# (The lines below keep their numbers from commit to commit where they can: a Mosaic module carries its source
+# locations into the compile cache's key, so shifting them costs every streamed-prefill program one cold compile.)
 # ---------------------------------------------------------------------------
 # the serving prefill: streamed, forward only
 # ---------------------------------------------------------------------------

@@ -48,6 +48,126 @@ def test_flash_attention_gradients_match(causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
 
 
+def _rooms(q, k, v, w, causal, unit):
+    """What the kernels' roundings leave around float32 attention and its
+    gradients on the same values, element by element (first order in
+    ``unit``, one operand rounding; sums of magnitudes, so no cancellation
+    is counted on). The kernels round: the probabilities as the operand
+    of ``P V`` and ``P^T dO``, ``dS`` as the operand of ``dS K`` and
+    ``dS^T Q``, and each result once; ``delta = sum(dO * O)`` is taken from
+    the ROUNDED result. Everything else (scores, softmax state, ``dP``,
+    accumulation) is float32."""
+    q, k, v, w = (np.asarray(x, np.float64).transpose(0, 2, 1, 3) for x in (q, k, v, w))  # [B, H, S, D]
+    scale = q.shape[-1] ** -0.5
+    s = np.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    if causal:
+        s = np.where(np.tril(np.ones(s.shape[-2:], bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    o = p @ v
+    ds = p * (w @ v.transpose(0, 1, 3, 2) - (w * o).sum(-1, keepdims=True))
+    delta_room = unit * (np.abs(w) * np.abs(o)).sum(-1, keepdims=True)
+    ds_room = unit * np.abs(ds) + p * delta_room
+    pt, ds_t = p.transpose(0, 1, 3, 2), ds.transpose(0, 1, 3, 2)
+    rooms = {
+        "fwd": unit * (p @ np.abs(v) + np.abs(o)),
+        "dq": scale * (ds_room @ np.abs(k)) + unit * np.abs(scale * ds @ k),
+        "dk": scale * (ds_room.transpose(0, 1, 3, 2) @ np.abs(q)) + unit * np.abs(scale * ds_t @ q),
+        "dv": unit * (pt @ np.abs(w) + np.abs(pt @ w)),
+    }
+    return {name: room.transpose(0, 2, 1, 3) for name, room in rooms.items()}
+
+
+@functools.lru_cache(maxsize=None)
+def _flash_against_float32(dtype, causal, blocks):
+    """(kernel's result and gradients, float32 reference's, rooms) at
+    operands of ``dtype`` holding values that bfloat16 holds too."""
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(B=1, S=256, H=2, seed=3))
+    w = jnp.asarray(np.random.RandomState(4).randn(*q.shape), jnp.bfloat16)  # dO: the same in every type
+
+    def loss(fn, *operands):
+        return jnp.sum(fn(*operands, causal=causal).astype(jnp.float32) * w.astype(jnp.float32))
+
+    flash = functools.partial(flash_attention, block_q=blocks[0], block_k=blocks[1], interpret=True)
+    typed = tuple(x.astype(dtype) for x in (q, k, v))
+    exact = tuple(x.astype(jnp.float32) for x in (q, k, v))
+    got = (flash(*typed, causal=causal),) + jax.grad(functools.partial(loss, flash), (0, 1, 2))(*typed)
+    want = (reference_attention(*exact, causal=causal),) + jax.grad(functools.partial(loss, reference_attention), (0, 1, 2))(*exact)
+    # a rounding of the operands' type (bfloat16: 2^-8 of the value), and float32's own over a sum of S terms
+    unit = float(jnp.finfo(dtype).eps) / 2 + q.shape[1] * 2.0 ** -24
+    names = ("fwd", "dq", "dk", "dv")
+    return dict(zip(names, got)), dict(zip(names, want)), _rooms(q, k, v, w, causal, unit)
+
+
+@pytest.mark.parametrize("what", ["fwd", "dq", "dk", "dv"])
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 128), (256, 256)], ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_kernels_lie_within_their_operands_roundings(dtype, causal, blocks, what):
+    """The three kernels multiply in the operands' type and accumulate in
+    float32: bfloat16 operands put the result and each gradient within
+    :func:`_rooms` of float32 attention on the same values (a rounding
+    is 2^-8 of the value), float32 operands within float32's own. Several
+    loop trips with the running softmax's rescale, a block pair that is
+    not square, and the whole sequence in one trip."""
+    got, want, rooms = _flash_against_float32(dtype, causal, blocks)
+    assert got[what].dtype == jnp.dtype(dtype)
+    excess = np.abs(np.asarray(got[what], np.float64) - np.asarray(want[what], np.float64)) - rooms[what]
+    assert excess.max() <= 0.0, f"{what}: {excess.max()} past its room of {rooms[what].flat[excess.argmax()]}"
+
+
+@pytest.mark.parametrize("dtype,element", [("bfloat16", "bf16"), ("float32", "f32")])
+def test_flash_kernels_hand_the_mxu_the_operands_type(dtype, element):
+    """What reaches the MXU at the training cells' shape ([16, 512, 16,
+    64]), lowered for a TPU: three Mosaic modules under the names the
+    trace readers look for (``benchmark/kernel_model.py::FLASH_KERNELS``),
+    2 / 3 / 4 ``tpu.matmul`` (all of them plain: no operand is
+    transposed on the way), every operand vector of the operands' own
+    type and every accumulator float32. An ``.astype(jnp.float32)`` on
+    an operand of a product in a body turns a ``bf16`` here into ``f32``."""
+    import re
+
+    from benchmark import kernel_model
+
+    products = {name: matmuls for name, (matmuls, _) in kernel_model._FLASH.items()}  # what the roofline's reader counts
+    assert products == {"flash_attention_fwd": 2, "flash_attention_bwd_dq": 3, "flash_attention_bwd_dkv": 4}
+    t = jax.ShapeDtypeStruct((16, 512, 16, 64), jnp.dtype(dtype))
+    grads = jax.grad(lambda q, k, v: jnp.sum(flash_attention(q, k, v).astype(jnp.float32)), (0, 1, 2))
+    modules = _mosaic_modules(grads, t, t, t)
+    assert [re.match(r"module @(\w+)", m).group(1) for m in modules] == list(kernel_model.FLASH_KERNELS)
+    for (name, count), module in zip(products.items(), modules):
+        matmuls = re.findall(r"tpu\.matmul.*?: \(vector<\w+x(\w+)>, vector<\w+x(\w+)>, vector<\w+x(\w+)>\) -> vector<\w+x(\w+)>", module)
+        assert len(matmuls) == module.count("tpu.matmul") == count, name
+        assert set(matmuls) == {(element, element, "f32", "f32")}, (name, matmuls)
+        assert "transpose_lhs = true" not in module and "tpu.transpose" not in module and "vector.transpose" not in module, name
+
+
+STREAMED_PREFILL_MODULES = {
+    # cell: (q, k, v shapes of its longest prefill's call, window, the module's hash at 7a94bda, PR 46)
+    "command-a-plus.long-doc": ((1, 6144, 128, 128), (1, 6144, 8, 128), (1, 6144, 8, 128), 4096, "3edc5ecbd9f168ce"),
+    "longcat-flash-chat.agent-turns": ((1, 4096, 64, 192), (1, 4096, 64, 192), (1, 4096, 64, 128), 0, "ab1d8c446c22c485"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(STREAMED_PREFILL_MODULES))
+def test_streamed_prefill_call_lowers_to_the_parents_mosaic_module(cell):
+    """The serving prefill's kernel shares the training kernels' file
+    and none of their code: its Mosaic module at the two cells' calls,
+    printed without source locations, hashes to what it hashed to before
+    PR 47 rewrote the training bodies above it."""
+    import hashlib
+
+    from flexflow_tpu.ops.kernels.flash_attention import prefill_stream_attention
+
+    q, k, v, window, digest = STREAMED_PREFILL_MODULES[cell]
+    shape = lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    (module,) = _mosaic_modules(
+        lambda q, k, v, lens: prefill_stream_attention(q, k, v, lens, window=window),
+        shape(q), shape(k), shape(v), jax.ShapeDtypeStruct((1,), jnp.int32),
+    )
+    assert hashlib.sha256(module.encode()).hexdigest()[:16] == digest
+
+
 def test_supports_shapes():
     assert supports_shapes((2, 256, 4, 64), (2, 256, 4, 64))
     assert not supports_shapes((2, 100, 4, 64), (2, 100, 4, 64))  # ragged seq
@@ -173,10 +293,12 @@ def test_flash_env_block_rejects_nonpositive(monkeypatch):
 
 
 def test_flash_adaptive_block_policy(monkeypatch):
-    """Round-5 on-chip sweep: 256 blocks beat 128 by 1.49x at seq 512,
-    so the default picks the largest candidate dividing the sequence —
-    while seq not divisible by 256 (e.g. 384) must keep flash via 128
-    instead of silently falling back to dense."""
+    """The chip's sweep of PR 47 (the table in ops/kernels/flash_attention.py):
+    the largest block wins in all three kernels, 512 (at seq 512 the whole
+    sequence in one loop trip) over 256 over 128, so the default picks the
+    largest candidate dividing the sequence — while seq not divisible by
+    256 (e.g. 384) must keep flash via 128 instead of silently falling
+    back to dense."""
     from flexflow_tpu.ops.kernels import flash_attention as fa
     from flexflow_tpu.ops.kernels.flash_attention import (
         effective_blocks,
@@ -188,13 +310,14 @@ def test_flash_adaptive_block_policy(monkeypatch):
     monkeypatch.setattr(fa, "ENV_BLOCK_Q", None)
     monkeypatch.setattr(fa, "ENV_BLOCK_K", None)
 
-    assert pick_block(512, None) == 256
+    assert pick_block(512, None) == 512 and pick_block(1024, None) == 512
+    assert pick_block(256, None) == 256 and pick_block(768, None) == 256
     assert pick_block(128, None) == 128
     assert pick_block(384, None) == 128  # 384 % 256 != 0
     assert pick_block(64, None) == 64  # clamp below smallest candidate
     assert pick_block(512, 128) == 128  # env override wins
     assert pick_block(64, 512) == 64  # override still clamped to seq
-    assert effective_blocks(512, 512) == (256, 256)
+    assert effective_blocks(512, 512) == (512, 512) and effective_blocks(256, 1024) == (256, 512)
     for seq in (128, 256, 384, 512, 1024):
         assert supports_shapes((2, seq, 4, 64), (2, seq, 4, 64)), seq
 

@@ -291,6 +291,6 @@ def test_the_accepted_served_cells_keep_their_prefix_reuse(cell):
 
 
 def test_pick_block_refuses_an_override_that_does_not_divide():
-    assert fa.pick_block(512, None) == 256 and fa.pick_block(512, 128) == 128 and fa.pick_block(100, 256) == 100
+    assert fa.pick_block(512, None) == 512 and fa.pick_block(512, 128) == 128 and fa.pick_block(100, 256) == 100
     with pytest.raises(ValueError, match="does not divide the sequence length 512"):
         fa.pick_block(512, 96)
