@@ -99,6 +99,64 @@ def check(ok: bool, what: str) -> None:
         raise RuntimeError(f"chip_smoke check failed: {what}")
 
 
+def ssm_slots_check(slots_pair=(64, 192), new_tokens: int = 12, config: dict = None) -> dict:
+    """An engine with state-space layers at the Nemotron cell's sizes
+    serving the SAME prompts (as many as the smaller count has slots) at
+    two slot counts: the streams, and what every state-space layer stores
+    for each of them after its last step (a fresh scheduler seats request
+    i in slot i at both counts). The two programs differ in shape alone,
+    so a stream leaves the other count's only where a near-tie of these
+    seeded weights' logits falls the other way (a few in a hundred
+    tokens), and for the streams that stay TOGETHER the first layer's
+    stored parts differ by roundings that fell the other way (1e-4 of
+    their norm) and deeper ones by the bfloat16 activations' noise. What a compiled decode program does to a state it
+    updates in place under memory pressure shows in no kernel check and
+    on no CPU (from 176 slots on the convolution's rows were
+    rematerialised after their update and left shifted twice: PERF.md
+    section 6, PR 48): it shows here, as tenths."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import spec
+    from benchmark.reference import nemotron_h
+    from flexflow_tpu.generation import GenerationEngine
+    from flexflow_tpu.generation.engine import SamplingParams
+
+    cell = spec.load_cell("nemotron-3-super-120b-a12b.reason-gen")
+    config, d = config or cell.config, cell.workload["deployment"]
+    cfg = nemotron_h.engine_config(config, int(d["max_seq_len"]))
+    params = nemotron_h.init_params(SEED + 11, config)
+    if cfg.dtype.jnp != jnp.bfloat16:
+        params = nemotron_h.cast_params(params, cfg.dtype.jnp)
+    rs = np.random.RandomState(SEED + 5)
+    n, buckets = min(slots_pair), sorted(d["prompt_buckets"])
+    prompts = [rs.randint(0, cfg.vocab_size, rs.randint(buckets[0] // 2, buckets[0])).tolist() for _ in range(n)]
+    served = {}
+    for slots in slots_pair:
+        t0 = time.monotonic()
+        eng = GenerationEngine(params, cfg, max_batch_slots=slots, block_size=int(d["block_size"]), prompt_buckets=buckets,
+                               max_seq_len=int(d["max_seq_len"]))
+        streams = [list(map(int, s)) for s in eng.generate(prompts, SamplingParams(max_new_tokens=new_tokens))]
+        # on the host: at the larger count the chip has no room for a second state (the cell peaks at 90 % of it)
+        served[slots] = (streams, {name: np.asarray(part[:, :n].astype(jnp.float32)) for name, part in eng.cache.state.items()})
+        eng.cache.k = eng.cache.v = None
+        eng.cache.state = {}
+        del eng
+        log(f"ssm engine at {slots} slots: {n} prompts x {new_tokens} tokens in {time.monotonic() - t0:.0f}s")
+    (a_streams, a_state), (b_streams, b_state) = (served[s] for s in slots_pair)
+    together = np.flatnonzero([a == b for a, b in zip(a_streams, b_streams)])  # (another last token is another state)
+    left_at = sorted(next(j for j in range(new_tokens) if a[j] != b[j]) for a, b in zip(a_streams, b_streams) if a != b)
+    apart = {name: [float(np.linalg.norm((a_state[name][l, together] - b_state[name][l, together]).ravel())
+                          / np.linalg.norm(a_state[name][l, together].ravel())) for l in range(a_state[name].shape[0])] for name in a_state}
+    out = {"slots": list(slots_pair), "prompts": n, "tokens_a_stream": new_tokens, "streams_together": int(len(together)),
+           "the_others_left_at_token": left_at, "stored_state_apart_by_layer_of_those_together": apart}
+    log(f"ssm engine at {slots_pair[0]} against {slots_pair[1]} slots: {out}")
+    check(2 * len(together) >= n, f"{n - len(together)} of {n} streams differ between {slots_pair} slots")
+    for name, by_layer in apart.items():
+        check(by_layer[0] <= 2e-3, f"the first state-space layer's stored {name} lies {by_layer[0]} of its norm apart between {slots_pair} slots")
+    return out
+
+
 # ----------------------------------------------------------------- set-up
 
 
@@ -706,6 +764,58 @@ def latent_kernel_check(c=None) -> dict:
     return out
 
 
+def ssm_kernel_check(slots_list=(64, 128, 192)) -> dict:
+    """The state-space decode update (``ops/ssm.py::update``) at the
+    Nemotron cell's sizes (5 layers of state [slots, 64, 128, 128]
+    float32): the Pallas call ``ssm_state_update`` against its XLA
+    composition (results and the state, which both update in place on a
+    donated array), ms a call of each on the host's clock over 20 calls
+    of ONE layer, and GB/s of state moved (read + written)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops import ssm
+
+    h, p, g, n, layers = 128, 64, 8, 128, 5
+    out = {}
+    for slots in slots_list:
+        keys = jax.random.split(jax.random.key(SEED + slots), 6)
+        shape = (layers, slots) + ssm.state_shape(h, p, g, n)
+        x = jax.random.normal(keys[0], (slots, h, p), jnp.bfloat16)
+        dt = jax.nn.softplus(jax.random.normal(keys[1], (slots, h)) - 4.0)
+        a = -jnp.arange(1, h + 1, dtype=jnp.float32)
+        b, c = (jax.random.normal(k, (slots, g, n), jnp.bfloat16) for k in keys[2:4])
+        calls = {
+            "kernel": jax.jit(lambda st, *r: ssm.update(st, 2, *r, backend="tpu"), donate_argnums=(0,)),
+            "xla_composition": jax.jit(lambda st, *r: ssm.update_reference(st, 2, *r), donate_argnums=(0,)),
+        }
+        got, ms = {}, {}
+        for name, call in calls.items():
+            state = jax.random.normal(keys[4], shape, jnp.float32)
+            y, state = jax.block_until_ready(call(state, x, dt, a, b, c))
+            got[name] = (y, state[2], state[1])
+            t0 = time.perf_counter()
+            for _ in range(20):
+                y, state = call(state, x, dt, a, b, c)
+            jax.block_until_ready(state)
+            ms[name] = round((time.perf_counter() - t0) / 20 * 1e3, 4)
+            del state
+        err_y = float(jnp.max(jnp.abs(got["kernel"][0] - got["xla_composition"][0])))
+        err_s = float(jnp.max(jnp.abs(got["kernel"][1] - got["xla_composition"][1])))
+        untouched = bool(jnp.all(got["kernel"][2] == got["xla_composition"][2]))
+        scale = float(jnp.max(jnp.abs(got["xla_composition"][0])))
+        check(err_y <= 1e-4 * scale + 1e-5 and err_s <= 1e-5, f"ssm update at {slots} slots: y differs by {err_y} (of {scale}), the state by {err_s}")
+        check(untouched, "ssm update: another layer's state moved")
+        text = calls["kernel"].lower(jax.ShapeDtypeStruct(shape, jnp.float32), x, dt, a, b, c).compile().as_text()
+        check("ssm_state_update" in text, "the update's Mosaic custom call is not in its program")
+        moved = 2 * 4 * slots * h * p * n
+        out[str(slots)] = {"ms_a_call": ms, "max_abs_err_y": err_y, "max_abs_err_state": err_s, "state_moved_gb": round(moved / 1e9, 4),
+                           "gb_per_s": {k: round(moved / 1e9 / (v / 1e3), 1) for k, v in ms.items()}}
+        log(f"ssm state update at {slots} slots: {out[str(slots)]}")
+        del got
+    return out
+
+
 # One expert layer of each expert configuration as its cell holds it
 # (benchmark/configs/*.json): the routed sum's two lowerings are timed over
 # these, and `expert_form`'s constants come from the table this prints.
@@ -720,6 +830,10 @@ EXPERT_LAYERS = {
     "longcat": dict(hidden=6144, width=2048, experts=512, zero=256, held=16, k=12, router="softmax", rows=(16, 64, 1024, 4096)),
     # SDAR's layer, every expert held: a block step of 32 / 48 / 64 slots has 128 / 192 / 256 rows, a prefill 1,024
     "sdar": dict(hidden=2048, width=768, experts=128, held=128, k=8, router="softmax", rows=(128, 192, 256, 1024)),
+    # Nemotron-3-Super's routed experts as they lie in the latent: ungated relu^2, 1,024 -> 2,688 -> 1,024, 128 held of 512,
+    # k 22 (5.5 of a row's picks land here); a decode step of 64 / 128 / 192 slots, a prefill of 512 / 1,024 rows. (The
+    # router here reads the 1,024-wide rows too; the model's reads the 4,096-wide ones: 2 M more weights in both forms)
+    "nemotron": dict(hidden=1024, width=2688, experts=512, held=128, k=22, router="sigmoid", ungated=True, rows=(64, 128, 192, 512, 1024)),
 }
 EXPERT_ROWS = (32, 64, 256, 512, 1024, 1536, 2048)
 
@@ -760,13 +874,15 @@ def expert_product_check(names=()) -> dict:
         cfg = decoder.DecoderConfig(
             num_layers=1, hidden_size=c["hidden"], num_heads=16, ff_size=c["width"], seq_length=64, vocab_size=128,
             num_dense_layers=0, num_experts=c["experts"], experts_per_token=c["k"], moe_ff_size=c["width"],
-            router=c["router"], experts_held=held or (),
+            router=c["router"], experts_held=held or (), expert_activation="relu2" if c.get("ungated") else "swiglu",
             **(dict(zero_experts=c["zero"], router_softmax_bias=True, router_renormalise=False, routed_scaling_factor=6.0)
                if c.get("zero") else {}))
         outputs = cfg.router_outputs
         shapes = dict(router=(c["hidden"], outputs), router_bias=(outputs,),
                       ew1=(c["held"], c["hidden"], c["width"]), ew3=(c["held"], c["hidden"], c["width"]),
                       ew2=(c["held"], c["width"], c["hidden"]))
+        if c.get("ungated"):  # W2 relu(W1 v)^2: no third matrix
+            del shapes["ew3"]
         keys = dict(zip(shapes, jax.random.split(jax.random.key(SEED), len(shapes))))
         layer = {k: jax.random.normal(keys[k], s, jnp.float32) * (s[-2] if len(s) > 1 else 2500.0) ** -0.5
                  for k, s in shapes.items()}
@@ -785,7 +901,7 @@ def expert_product_check(names=()) -> dict:
             gates, chosen = decoder.route(cfg, layer, v)
             identity = jnp.sum(gates[:, cfg.num_experts:], axis=1) if cfg.zero_experts else None
             return expert_product.grouped_expert_sum(
-                v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, product=ragged, identity=identity)
+                v, gates, chosen, layer["ew1"], layer.get("ew3"), layer["ew2"], held=held, product=ragged, identity=identity)
 
         def composition(layer, v):
             gates, _ = decoder.route(cfg, layer, v)
@@ -797,6 +913,13 @@ def expert_product_check(names=()) -> dict:
                 w1, w3, w2, g = w
                 up, gate_up = (jnp.dot(x, m.astype(jnp.float32), precision=hi) for m in (w1, w3))
                 return acc + jnp.dot(jax.nn.silu(up) * gate_up * g[:, None], w2.astype(jnp.float32), precision=hi), None
+
+            def one_ungated(acc, w):
+                w1, w2, g = w
+                up = jnp.dot(x, w1.astype(jnp.float32), precision=hi)
+                return acc + jnp.dot(jnp.square(jax.nn.relu(up)) * g[:, None], w2.astype(jnp.float32), precision=hi), None
+            if "ew3" not in layer:
+                return jax.lax.scan(one_ungated, start, (layer["ew1"], layer["ew2"], mine.T))[0]
             return jax.lax.scan(one, start, (layer["ew1"], layer["ew3"], layer["ew2"], mine.T))[0]
 
         forms = {"dense": traced_as("dense"), "grouped": traced_as("grouped"), "grouped_ragged_dot": jax.jit(other_candidate)}
@@ -1805,6 +1928,11 @@ def main(argv=None) -> int:
                     help="the latent paged kernel alone, at the sizes of the latent cell whose layers have HEADS heads")
     ap.add_argument("--expert-product", nargs="*", default=None, metavar="LAYER", choices=sorted(EXPERT_LAYERS),
                     help="the routed experts' sum alone: dense against grouped, one layer of each expert cell (or of those named)")
+    ap.add_argument("--ssm-kernel", action="store_true",
+                    help="the state-space decode update alone at the Nemotron cell's sizes: the Pallas call against its XLA "
+                         "composition; with --expert-product, both")
+    ap.add_argument("--ssm-slots", action="store_true",
+                    help="an engine at the Nemotron cell's sizes serving the same prompts at 64 and at 192 slots: the streams and the stored state")
     ap.add_argument("--group16", action="store_true",
                     help="the group-16 paged calls and the streamed prefill kernel alone, at the long-document cell's sizes")
     ap.add_argument("--grouped-kernels", action="store_true",
@@ -1856,6 +1984,12 @@ def main(argv=None) -> int:
         summary["four_chips"] = four_chip_phase(rs)
     elif args.latent_kernel or args.latent:
         summary["kernels"] = {"latent": latent_kernel_check(LATENT_CALLS[args.latent or 32])}
+    elif args.ssm_slots:
+        summary["engine"] = {"ssm_slots": ssm_slots_check()}
+    elif args.ssm_kernel:
+        summary["kernels"] = {"ssm_update": ssm_kernel_check()}
+        if args.expert_product is not None:
+            summary["experts"] = expert_product_check(args.expert_product)
     elif args.group16:
         summary["kernels"] = {"grouped": grouped_kernels_check(GROUP16_CALLS), "prefill_stream": stream_kernel_check(),
                               "materialised_prefill": materialised_calls_check(), "latent_prefill_stream": latent_stream_check()}
@@ -1909,6 +2043,7 @@ def main(argv=None) -> int:
     out_dir = REPO / "chiprun_out"
     out_dir.mkdir(exist_ok=True)
     name = ("chip_smoke_four_chips.json" if args.four_chips else "chip_smoke_latent.json" if args.latent_kernel or args.latent
+            else "chip_smoke_ssm_slots.json" if args.ssm_slots else "chip_smoke_ssm.json" if args.ssm_kernel
             else "chip_smoke_group16.json" if args.group16
             else "chip_smoke_grouped" + ("_" + pathlib.Path(args.tree).name.strip(".") if args.tree else "") + ".json" if args.grouped_kernels
             else "chip_smoke_walk_sweep.json" if args.walk_sweep else "chip_smoke_latent_prefill.json" if args.latent_prefill

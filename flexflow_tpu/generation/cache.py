@@ -95,6 +95,14 @@ three conditions above hold for it as they do for K/V: default layout
 row-major, donated, one scatter on the whole operand, the kernel taking
 the whole array and a static layer index.
 
+**State of named parts, per slot only.** A state-space layer
+(generation/decoder.py ``ssm``) keeps two arrays of different shape and
+type a sequence (:class:`SlotStateConfig`), megabytes of them: they live
+in ``KVCache.state`` under their names, ``[n_layers, slots, *shape]``,
+carried and donated with K/V by the decode step, written for one slot by
+a prefill's hand-over, and never snapshotted a block: an engine with such
+layers keeps no prefix index.
+
 Block 0 is reserved as a **scratch block**: padded prompt positions and
 inactive decode slots scatter their (meaningless) K/V there, so the
 jitted steps never need dynamic shapes or masked scatters to avoid
@@ -104,6 +112,7 @@ block 0.
 from __future__ import annotations
 
 import dataclasses
+import math
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -386,14 +395,53 @@ class StateConfig:
         return (self.slots + num_blocks) * self.bytes_per_sequence
 
 
+@dataclasses.dataclass(frozen=True)
+class SlotStateConfig:
+    """Per-sequence state of NAMED PARTS, kept per batch slot and nowhere
+    else: ``num_layers`` layers each keep, for each of ``slots`` slots,
+    the arrays ``parts`` names: ``(name, shape a layer a slot, dtype)``.
+    A state-space layer keeps two of different shape and type (the last
+    rows of its convolution's input, bfloat16, and its recurrent state,
+    float32: ops/ssm.py) and megabytes of them a sequence, where a
+    convolution layer keeps two rows: no snapshot a cached block exists
+    (it would be a sequence's whole state a block), and an engine with
+    such layers keeps no prefix index. A prefill hands a slot its parts
+    at the sequence's own length; a decode step carries all of them."""
+
+    num_layers: int
+    slots: int
+    parts: Tuple[Tuple[str, Tuple[int, ...], DataType], ...]
+
+    @property
+    def names(self) -> Tuple[str, ...]:
+        return tuple(name for name, _, _ in self.parts)
+
+    def part_bytes(self, name: str) -> int:
+        """One slot's bytes of part ``name`` over all layers."""
+        shape, dtype = next((s, d) for n, s, d in self.parts if n == name)
+        return self.num_layers * math.prod(shape) * dtype.size_bytes
+
+    @property
+    def bytes_per_sequence(self) -> int:
+        return sum(self.part_bytes(name) for name in self.names)
+
+    @property
+    def total_bytes(self) -> int:
+        return self.slots * self.bytes_per_sequence
+
+    def zeros(self) -> Dict[str, jax.Array]:
+        return {n: jnp.zeros((self.num_layers, self.slots, *shape), d.jnp) for n, shape, d in self.parts}
+
+
 class KVCache:
     """Device storage: ``k``/``v`` of shape [L, num_blocks, block_size,
     R, LW] (``CacheConfig.row_shape``; [..., H, D] after a row-major
     reshape) and ``state``: with a :class:`StateConfig` the arrays
     ``{"conv": [n, slots, rows, width], "snap": [n, num_blocks, rows,
     width]}``, with a ``window_config`` the window layers' ``{"wk",
-    "wv"}`` (empty with neither: an empty pytree adds nothing to a
-    program). Functional updates — jitted steps take the arrays and
+    "wv"}``, with a ``slot_state`` (:class:`SlotStateConfig`) its named
+    parts ``[n, slots, *shape]`` (empty with none of them: an empty
+    pytree adds nothing to a program). Functional updates — jitted steps take the arrays and
     return replacements; this object just holds the current ones.
 
     ``sharding`` (a NamedSharding over the serving mesh, rows — that is,
@@ -405,8 +453,10 @@ class KVCache:
 
     def __init__(self, config: CacheConfig, k: jax.Array, v: jax.Array,
                  sharding=None, state_config: Optional[StateConfig] = None,
-                 window_config: Optional[CacheConfig] = None):
+                 window_config: Optional[CacheConfig] = None,
+                 slot_state: Optional[SlotStateConfig] = None):
         self.config = config
+        self.slot_state = slot_state
         self.k = k
         self.v = v
         self.sharding = sharding
@@ -425,6 +475,8 @@ class KVCache:
         if self.window_config is not None:
             # the window layers' K and V, a pool of their own (module docstring)
             state.update(wk=self._zeros(self.window_config, None), wv=self._zeros(self.window_config, None))
+        if self.slot_state is not None:
+            state.update(self.slot_state.zeros())
         return state
 
     @staticmethod
@@ -446,7 +498,8 @@ class KVCache:
     @classmethod
     def create(cls, config: CacheConfig, sharding=None,
                state_config: Optional[StateConfig] = None,
-               window_config: Optional[CacheConfig] = None) -> "KVCache":
+               window_config: Optional[CacheConfig] = None,
+               slot_state: Optional[SlotStateConfig] = None) -> "KVCache":
         return cls(
             config,
             cls._zeros(config, sharding),
@@ -454,6 +507,7 @@ class KVCache:
             sharding=sharding,
             state_config=state_config,
             window_config=window_config,
+            slot_state=slot_state,
         )
 
     def update(self, k: jax.Array, v: jax.Array, **state: jax.Array) -> None:
@@ -470,6 +524,9 @@ class KVCache:
         have written."""
         self.k = self._zeros(self.config, self.sharding)
         self.v = self._zeros(self.config, self.sharding, value=True)
+        # (the old state goes first: state-space layers' is gigabytes, and a zeroed second copy beside it
+        # is what ran 192 slots of the Nemotron cell out of memory at the reset behind `warm`, PR 48)
+        self.state = {}
         self.state = self._state_zeros()
 
 

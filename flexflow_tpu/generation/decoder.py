@@ -13,7 +13,9 @@ setting of every one of them):
   FFN(norm2(h))`` — or ``parallel``: ONE norm a layer, which the
   operator and the feed-forward both read, and both add into the
   residual: ``n = norm1(x)``, ``y = x + Op(n) + FFN(n)`` (such a layer
-  has no second norm);
+  has no second norm); or ``single``: ONE norm and ONE branch a layer,
+  ``y = x + Mixer(norm1(x))``, the mixer the layer's operator or, for the
+  layer type ``ffn``, its feed-forward alone (no layer has both);
 * positions: ``learned`` (an absolute table added at the embedding) or
   ``rotary`` (on q and k inside attention, nothing at the embedding:
   rotate-half, or with ``rope_interleave`` over the pairs ``(2i, 2i+1)``;
@@ -31,6 +33,11 @@ setting of every one of them):
   frequencies and the factor on cos and sin) — ``latent``, attention
   whose K and V come from ONE low-rank row a token shared by all heads
   (below) — or ``conv``, a gated short convolution:
+  (or ``ssm``, a Mamba-2 state-space mixer: :func:`_ssm` has its
+  equations; its per-sequence state is the convolution's last ``K - 1``
+  input rows and a float32 recurrent state ``[H, P, N]``, kept per batch
+  slot and nowhere else: ops/ssm.py, generation/cache.py) — the gated
+  short convolution is
   ``[B, C, X] = split3(W_in u)``, ``z_t = B_t * X_t``, ``c_t = sum_j
   w[:, j] * z_{t-K+1+j}`` (depthwise, causal, kernel ``K``, zeros before
   the sequence), ``out = W_out (C_t * c_t)``. Its state after position
@@ -44,6 +51,10 @@ setting of every one of them):
   token goes through (one SwiGLU of their summed width, added to the
   routed sum; ``shared_experts: "average"`` scales it by ``1 /
   num_shared_experts``: the mean of their outputs);
+  ``expert_activation: "relu2"``: the experts and the shared expert are
+  UNGATED, ``W2 relu(W1 v)^2`` (two matrices); ``moe_latent_size`` > 0:
+  the routed experts read ``W_down v`` and ``W_up`` takes their gated sum
+  back to the hidden size (the router and the shared expert read ``v``);
   ``router_selection_bias=False`` is a sigmoid router whose choice is
   ``top_k(s)`` and whose gates are ``s_i / sum_{j in I} s_j``.
   ``experts_held`` names the routed experts whose weights
@@ -161,6 +172,7 @@ from ..models.transformer import TransformerConfig
 from ..ops.attention import (
     append_attention_core, decode_attention_core, latent_attention_core, prefill_attention,
 )
+from ..ops import ssm as ssm_ops
 from ..ops.expert_product import expert_lowering, grouped_expert_sum
 from ..ops.kernels.decode_attention import latent_row_width
 from .cache import slot_mapping
@@ -177,13 +189,17 @@ class DecoderConfig(TransformerConfig):
 
     norm: str = "layernorm"  # | "layernorm_nobias" | "rmsnorm"
     norm_eps: float = 1e-5
-    block: str = "sequential"  # | "parallel": one norm a layer, operator and feed-forward both read it
+    # | "parallel": one norm a layer, operator and feed-forward both read it
+    # | "single": one norm and ONE branch a layer, the operator or (layer type "ffn") the feed-forward
+    block: str = "sequential"
     positions: str = "learned"  # | "rotary"
     rope_theta: float = 10000.0
     qk_norm: bool = False
     num_kv_heads: int = 0  # 0: as many as query heads
     head_dim: int = 0  # 0: hidden_size // num_heads
-    layer_types: Tuple[str, ...] = ()  # per layer "attention" | "window" | "latent" | "conv"; (): all attention
+    # per layer "attention" | "window" | "latent" | "conv" | "ssm", or in a "single" block "ffn" (a layer that
+    # is its feed-forward alone); (): all attention
+    layer_types: Tuple[str, ...] = ()
     window: int = 0  # positions a "window" layer's query attends, its own included
     # rotary parameters by attention kind ("attention" / "window"); a kind
     # without an entry has plain `rope_theta`. Keys: "theta" and, for YaRN,
@@ -233,21 +249,49 @@ class DecoderConfig(TransformerConfig):
     # block diffusion (module docstring): position i attends position j
     # iff j // block_mask <= i // block_mask. 0: the causal mask
     block_mask: int = 0
+    # an "ssm" layer (module docstring): heads H of width P (d_inner = H x P), G groups that share B
+    # and C, the state's width N, the depthwise convolution's kernel and the prefill's chunk
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state_size: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    # what init_decoder_params draws an ssm layer's steps from: log-uniform in [min, max], floored (the
+    # published Mamba-2 initialisation's time_step_min / time_step_max / time_step_floor)
+    ssm_dt_range: Tuple[float, float, float] = (1e-3, 1e-1, 1e-4)
+    # the experts' (and the shared expert's) form: "swiglu", or "relu2": ungated, W2 relu(W1 v)^2
+    expert_activation: str = "swiglu"
+    # > 0: the routed experts live in a latent of this width: u = W_down h goes through them
+    # (their matrices are [latent, moe_ff_size] and back) and W_up takes their gated sum to the
+    # hidden size; the router and the shared expert read h itself
+    moe_latent_size: int = 0
+    shared_ff_size: int = 0  # the shared expert's width; 0: num_shared_experts x moe_ff_size
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.num_layers:
             raise ValueError(f"{len(self.layer_types)} layer_types for {self.num_layers} layers")
         for kind in self.layer_types:
-            if kind not in ("attention", "window", "latent", "conv"):
-                raise ValueError(f"layer type {kind!r}: 'attention', 'window', 'latent' or 'conv'")
+            if kind not in ("attention", "window", "latent", "conv", "ssm", "ffn"):
+                raise ValueError(f"layer type {kind!r}: 'attention', 'window', 'latent', 'conv', 'ssm' or 'ffn'")
         if "window" in self.layer_types and self.window < 1:
             raise ValueError("a 'window' layer needs window >= 1")
         if self.router not in ("sigmoid", "softmax"):
             raise ValueError(f"router {self.router!r}: 'sigmoid' or 'softmax'")
         if self.norm not in ("layernorm", "layernorm_nobias", "rmsnorm"):
             raise ValueError(f"norm {self.norm!r}: 'layernorm', 'layernorm_nobias' or 'rmsnorm'")
-        if self.block not in ("sequential", "parallel"):
-            raise ValueError(f"block {self.block!r}: 'sequential' or 'parallel'")
+        if self.block not in ("sequential", "parallel", "single"):
+            raise ValueError(f"block {self.block!r}: 'sequential', 'parallel' or 'single'")
+        if ("ffn" in self.layer_types) != (self.block == "single") or self.block == "single" and self.shortcut_experts:
+            raise ValueError("a layer that is its feed-forward alone ('ffn') is a 'single' block's, and such a block has one")
+        if "ssm" in self.layer_types:
+            h, p, g, n = self.ssm_heads, self.ssm_head_dim, self.ssm_groups, self.ssm_state_size
+            if min(h, p, g, n) < 1 or h % g or self.ssm_conv_kernel < 2 or self.ssm_chunk < 1:
+                raise ValueError(f"an 'ssm' layer needs its heads, head width, groups (dividing the heads) and state width, got {(h, p, g, n)}")
+            if self.conv_layers or self.window_layers or self.latent_layers:
+                raise ValueError("ssm layers beside convolution, window or latent layers: one kind of state beside paged K/V")
+        if self.expert_activation not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_activation {self.expert_activation!r}: 'swiglu' or 'relu2'")
         if self.block == "parallel" and (self.stateful or self.latent_layers):
             raise ValueError("a parallel block is written down for attention and window layers")
         if self.shared_experts not in ("sum", "average"):
@@ -290,12 +334,14 @@ class DecoderConfig(TransformerConfig):
         return self.layer_types[layer] if self.layer_types else "attention"
 
     def ffn_kind(self, layer: int) -> str:
+        if self.block == "single" and self.operator(layer) != "ffn":
+            return "none"  # the layer is its operator alone
         return "experts" if 0 <= self.num_dense_layers <= layer else self.ffn
 
     @property
     def attention_layers(self) -> Tuple[int, ...]:
         """Layers with K/V, of either kind, in layer order."""
-        return tuple(l for l in range(self.num_layers) if self.operator(l) != "conv")
+        return tuple(l for l in range(self.num_layers) if self.operator(l) not in ("conv", "ssm", "ffn"))
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -336,6 +382,20 @@ class DecoderConfig(TransformerConfig):
     def conv_layers(self) -> Tuple[int, ...]:
         return tuple(l for l in range(self.num_layers) if self.operator(l) == "conv")
 
+    @property
+    def ssm_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "ssm")
+
+    @property
+    def ssm_inner(self) -> int:
+        """An ssm layer's inner width, ``H x P``."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """What an ssm layer's convolution runs over: ``[x, B, C]``."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
+
     def shortcut(self, layer: int) -> bool:
         """``layer`` carries a shortcut expert branch (beside its dense
         feed-forward)."""
@@ -359,7 +419,7 @@ class DecoderConfig(TransformerConfig):
     @property
     def stateful(self) -> bool:
         """Some layer keeps a state that is not paged K/V."""
-        return bool(self.conv_layers)
+        return bool(self.conv_layers or self.ssm_layers)
 
 
 def decoder_config(cfg: TransformerConfig) -> DecoderConfig:
@@ -404,7 +464,7 @@ def init_decoder_params(
     f, v = cfg.ff_size, cfg.vocab_size
     p = max_positions or cfg.seq_length
     # (a configuration without latent layers or shared experts draws the keys it always drew)
-    per_layer = 14 if cfg.latent_layers or cfg.num_shared_experts or cfg.shortcut_experts else 10
+    per_layer = 14 if cfg.latent_layers or cfg.num_shared_experts or cfg.shortcut_experts or cfg.block == "single" else 10
     keys = iter(jax.random.split(rng, 4 + per_layer * cfg.num_layers))
     ones, zeros = jnp.ones((e,), dt), jnp.zeros((e,), dt)
     params: DecoderParams = {"tok_embed": _glorot(next(keys), (v, e), dt)}
@@ -429,6 +489,10 @@ def init_decoder_params(
                 w_dkv=_glorot(next(keys), (e, rkv + dr), dt), kv_lora_g=jnp.ones((rkv,), dt),
                 w_ukv=_glorot(next(keys), (rkv, h, dn + dv), dt), wo=_glorot(next(keys), (h, dv, e), dt),
             )
+        elif cfg.operator(li) == "ssm":
+            layer.update(_init_ssm(cfg, keys, dt))
+        elif cfg.operator(li) == "ffn":
+            pass  # the layer is its feed-forward alone
         elif cfg.operator(li) != "conv":
             layer.update(
                 wq=_glorot(next(keys), (e, h, d), dt), wk=_glorot(next(keys), (e, hk, d), dt),
@@ -465,18 +529,40 @@ def init_decoder_params(
             elif cfg.router_softmax_bias:  # a tenth of a uniform pick's probability: it moves near-ties
                 layer.update(router_bias=0.1 / n * jax.random.normal(next(keys), (n,), jnp.float32))
             n = cfg.held_experts  # the router scores every expert; the weights are the held ones'
-            layer.update(
-                ew1=_glorot(next(keys), (n, e, fe), dt), ew3=_glorot(next(keys), (n, e, fe), dt),
-                ew2=_glorot(next(keys), (n, fe, e), dt),
-            )
+            gated = cfg.expert_activation == "swiglu"  # an ungated expert has no third matrix
+            ew = cfg.moe_latent_size or e  # the width the routed experts read and write
+            if cfg.moe_latent_size:
+                layer.update(lat_down=_glorot(next(keys), (e, ew), dt), lat_up=_glorot(next(keys), (ew, e), dt))
+            layer.update(ew1=_glorot(next(keys), (n, ew, fe), dt))
+            if gated:
+                layer.update(ew3=_glorot(next(keys), (n, ew, fe), dt))
+            layer.update(ew2=_glorot(next(keys), (n, fe, ew), dt))
             if cfg.num_shared_experts:
-                fs = cfg.num_shared_experts * fe
-                layer.update(
-                    sw1=_glorot(next(keys), (e, fs), dt), sw3=_glorot(next(keys), (e, fs), dt),
-                    sw2=_glorot(next(keys), (fs, e), dt),
-                )
+                fs = cfg.shared_ff_size or cfg.num_shared_experts * fe
+                layer.update(sw1=_glorot(next(keys), (e, fs), dt))
+                if gated:
+                    layer.update(sw3=_glorot(next(keys), (e, fs), dt))
+                layer.update(sw2=_glorot(next(keys), (fs, e), dt))
         params["layers"].append(layer)
     return params
+
+
+def _init_ssm(cfg: DecoderConfig, keys, dt) -> Dict[str, Any]:
+    """An ssm layer's weights: the projections Glorot, the rates and
+    steps as the published Mamba-2 initialisation draws them (``A_log =
+    log(1..H)``, ``dt`` log-uniform in ``cfg.ssm_dt_range`` through the
+    inverse softplus, ``D = 1``), so that the decays are a trained model's
+    in scale. The three vectors stay float32, as the router does."""
+    e, h, di, cw = cfg.hidden_size, cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_width
+    lo, hi, floor = cfg.ssm_dt_range
+    step = jnp.exp(jax.random.uniform(next(keys), (h,), jnp.float32) * (math.log(hi) - math.log(lo)) + math.log(lo))
+    step = jnp.maximum(step, floor)
+    return dict(
+        ssm_in=_glorot(next(keys), (e, di + cw + h), dt), ssm_conv_w=_glorot(next(keys), (cw, cfg.ssm_conv_kernel), dt),
+        ssm_conv_b=jnp.zeros((cw,), dt), ssm_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+        ssm_a_log=jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)), ssm_d=jnp.ones((h,), jnp.float32),
+        ssm_norm_g=jnp.ones((di,), dt), ssm_out=_glorot(next(keys), (di, e), dt),
+    )
 
 
 # ------------------------------------------------------------------ pieces
@@ -708,7 +794,8 @@ def route(cfg: DecoderConfig, layer, v):
 
 def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = None, live=None, routed=None):
     """The routed feed-forward of rows ``v`` [T, E]: ``sum_{i in I} g_i
-    W2_i (silu(W1_i v) * W3_i v)``, exactly (no capacity, no dropped
+    W2_i (silu(W1_i v) * W3_i v)`` (``expert_activation: "relu2"``: ``W2_i
+    relu(W1_i v)^2``, no third matrix), exactly (no capacity, no dropped
     token), and the gates it used ([T, N], for the counters).
 
     ``held`` names the experts whose weights this call has, in the order
@@ -739,7 +826,7 @@ def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = Non
     identity = jnp.sum(gates[:, cfg.num_experts:], axis=1) if cfg.zero_experts else None
     if expert_lowering(v.shape[0], layer["ew1"].shape[0], cfg.experts_per_token, gates.shape[1]) == "grouped":
         out = grouped_expert_sum(
-            v, gates, chosen, layer["ew1"], layer["ew3"], layer["ew2"], held=held, live=live, identity=identity,
+            v, gates, chosen, layer["ew1"], layer.get("ew3"), layer["ew2"], held=held, live=live, identity=identity,
         )
         return out, gates
     if held is not None:
@@ -750,8 +837,11 @@ def expert_ffn(cfg: DecoderConfig, layer, v, held: Optional[Sequence[int]] = Non
     # are read once either way, and at a decode step's or a short
     # bucket's rows the 8 x multiply-adds hide behind those reads
     up = jnp.einsum("te,nef->ntf", v, layer["ew1"], preferred_element_type=jnp.float32)
-    gate_up = jnp.einsum("te,nef->ntf", v, layer["ew3"], preferred_element_type=jnp.float32)
-    hidden = (jax.nn.silu(up) * gate_up * mine.T[:, :, None]).astype(v.dtype)
+    if cfg.expert_activation == "relu2":  # ungated: W2 relu(W1 v)^2
+        hidden = (jnp.square(jax.nn.relu(up)) * mine.T[:, :, None]).astype(v.dtype)
+    else:
+        gate_up = jnp.einsum("te,nef->ntf", v, layer["ew3"], preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(up) * gate_up * mine.T[:, :, None]).astype(v.dtype)
     out = jnp.einsum("ntf,nfe->te", hidden, layer["ew2"], preferred_element_type=jnp.float32)
     if identity is not None:
         with jax.named_scope("experts.zero"):
@@ -765,6 +855,14 @@ def _swiglu(h, w1, w3, w2):
     up = jnp.einsum("...e,ef->...f", h, w1, preferred_element_type=jnp.float32)
     gate_up = jnp.einsum("...e,ef->...f", h, w3, preferred_element_type=jnp.float32)
     return _mm("...f,fe->...e", (jax.nn.silu(up) * gate_up).astype(h.dtype), w2)
+
+
+def _relu2(h, w1, w2):
+    """``W2 relu(W1 h)^2``, the ungated feed-forward: the product in
+    float32, its squared rectification handed on in the activations'
+    type."""
+    up = jnp.einsum("...e,ef->...f", h, w1, preferred_element_type=jnp.float32)
+    return _mm("...f,fe->...e", jnp.square(jax.nn.relu(up)).astype(h.dtype), w2)
 
 
 def _count_row(cfg: DecoderConfig, gates, live):
@@ -819,11 +917,24 @@ def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List], no
         with jax.named_scope("router"):
             h = _norm(cfg, x, layer, "ln2") if normed is None else normed
             rows = h.reshape(-1, h.shape[-1])
+        routed, inner = None, rows
+        if cfg.moe_latent_size:
+            # the router reads h; the routed experts read, and write, its latent projection
+            with jax.named_scope("router"):
+                routed = route(cfg, layer, rows)
+            with jax.named_scope("experts.latent"):
+                inner = _mm("te,el->tl", rows, layer["lat_down"])
         with jax.named_scope("experts"):
-            out, gates = expert_ffn(cfg, layer, rows, held=cfg.experts_held or None, live=live.reshape(-1))
+            out, gates = expert_ffn(cfg, layer, inner, held=cfg.experts_held or None, live=live.reshape(-1), routed=routed)
+        if cfg.moe_latent_size:
+            with jax.named_scope("experts.latent"):
+                out = _mm("tl,le->te", out, layer["lat_up"])
         if cfg.num_shared_experts:
             with jax.named_scope("shared_expert" if cfg.num_shared_experts == 1 else "shared_experts"):
-                shared = _swiglu(rows, layer["sw1"], layer["sw3"], layer["sw2"])
+                if cfg.expert_activation == "relu2":
+                    shared = _relu2(rows, layer["sw1"], layer["sw2"])
+                else:
+                    shared = _swiglu(rows, layer["sw1"], layer["sw3"], layer["sw2"])
                 if cfg.shared_experts == "average":
                     # one SwiGLU of the summed width IS the sum of the experts' outputs
                     shared = (shared.astype(jnp.float32) / cfg.num_shared_experts).astype(shared.dtype)
@@ -842,6 +953,44 @@ def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List], no
         return x + out + layer["ff2_b"] if normed is None else out + layer["ff2_b"]
 
 
+def _ssm(cfg: DecoderConfig, layer, h, live, si: int, window: Callable, scan: Callable):
+    """A Mamba-2 layer's mixer of its normed input ``h`` ([B, S, E], or
+    [B, E] for a decode step's one position): ``[z, xBC, dt] = W_in h``;
+    ``xBC <- silu(conv1d_causal(xBC) + b)`` (depthwise, kernel K, in
+    float32), split into ``x`` [H, P], ``B`` and ``C`` [G, N]; ``dt <-
+    softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the recurrence
+    (ops/ssm.py) through ``scan``; ``y <- (y + D x) * silu(z)``,
+    RMS-normalised over each of the G groups apart, times a weight; ``out
+    = W_out y``. Rows that are not ``live`` get ``dt = 0`` and ``x = 0``:
+    the state passes them unchanged. ``window`` hands the convolution
+    the rows before the sequence (:func:`conv_window`) and takes what it
+    leaves; ``dt``, the decay, the recurrence, the skip, the gate and the
+    norm are float32."""
+    one = h.ndim == 2
+    if one:
+        h, live = h[:, None], live[:, None]
+    di, gn, hh, p = cfg.ssm_inner, cfg.ssm_groups * cfg.ssm_state_size, cfg.ssm_heads, cfg.ssm_head_dim
+    proj = _mm("...e,ef->...f", h, layer["ssm_in"])
+    z, xbc, dt = proj[..., :di], proj[..., di : di + cfg.ssm_conv_width], proj[..., di + cfg.ssm_conv_width :]
+    xpad = window(si, xbc)  # [B, S + K - 1, width]
+    with jax.named_scope("ssm.conv"):
+        w, t = layer["ssm_conv_w"].astype(jnp.float32), xbc.shape[1]
+        xf = xpad.astype(jnp.float32)
+        mixed = sum(w[:, j] * xf[:, j : j + t] for j in range(w.shape[1])) + layer["ssm_conv_b"].astype(jnp.float32)
+        xbc = jax.nn.silu(mixed).astype(h.dtype)
+    lead = xbc.shape[:2]
+    x = jnp.where(live[..., None], xbc[..., :di], 0).reshape(*lead, hh, p)
+    b = xbc[..., di : di + gn].reshape(*lead, cfg.ssm_groups, cfg.ssm_state_size)
+    c = xbc[..., di + gn :].reshape(*lead, cfg.ssm_groups, cfg.ssm_state_size)
+    dt = jnp.where(live[..., None], jax.nn.softplus(dt.astype(jnp.float32) + layer["ssm_dt_bias"]), 0.0)
+    y = scan(si, x, dt, -jnp.exp(layer["ssm_a_log"]), b, c)  # [B, S, H, P] float32
+    y = y + layer["ssm_d"][:, None] * x.astype(jnp.float32)
+    y = y.reshape(*lead, di) * jax.nn.silu(z.astype(jnp.float32))
+    y = _rms(y.reshape(*lead, cfg.ssm_groups, -1), 1.0, cfg.norm_eps).reshape(*lead, di) * layer["ssm_norm_g"].astype(jnp.float32)
+    out = _mm("...f,fe->...e", y.astype(h.dtype), layer["ssm_out"])
+    return out[:, 0] if one else out
+
+
 def _layers(
     cfg: DecoderConfig,
     params: DecoderParams,
@@ -851,6 +1000,7 @@ def _layers(
     attend: Callable,
     convolve: Callable,
     counts: Optional[List] = None,
+    recur: Optional[Tuple[Callable, Callable]] = None,
 ):
     """THE block definition: every layer of ``params`` applied to ``x``
     ([..., E]; ``positions`` and ``live`` over its leading axes). What
@@ -860,7 +1010,11 @@ def _layers(
     * ``attend(ai, q, k, v)`` -> the attention context of the ``ai``-th
       attention layer for its projected (normed, rotated) q, k, v;
     * ``convolve(ci, z)`` -> the padded rows (:func:`conv_window`) of the
-      ``ci``-th convolution layer behind its gated input ``z``.
+      ``ci``-th convolution layer behind its gated input ``z``;
+    * ``recur = (window, scan)`` (a configuration with ssm layers):
+      ``window(si, xbc)`` -> the padded rows of the ``si``-th ssm layer's
+      convolution behind its projected ``[x, B, C]``, and ``scan(si, x,
+      dt, a, b, c)`` -> the recurrence's ``y`` (:func:`_ssm`).
 
     Scope names land in the instructions' op_name, so a device trace can
     be grouped by them: ``layer<i>/attention | cache_write | conv |
@@ -873,10 +1027,28 @@ def _layers(
     and ``attention.full`` (:func:`attention_scope`); a latent layer is
     ``attention.latent``, its attention proper ``attention.latent.expand``
     or ``attention.latent.absorb`` by the form the forward runs."""
-    ai = ci = 0
+    ai = ci = si = 0
     for li, layer in enumerate(params["layers"]):
         with jax.named_scope(f"layer{li}"):
             kind = cfg.operator(li)
+            if cfg.block == "single":
+                # ONE norm and ONE branch: the state-space mixer, attention or the feed-forward
+                if kind == "ssm":
+                    with jax.named_scope("ssm"):
+                        x = x + _ssm(cfg, layer, _norm(cfg, x, layer, "ln1"), live, si, *recur)
+                    si += 1
+                elif kind == "ffn":
+                    with jax.named_scope("router"):
+                        n = _norm(cfg, x, layer, "ln1")
+                    x = x + _ffn(cfg, li, layer, x, live, counts, normed=n)
+                else:
+                    with jax.named_scope("attention"):
+                        q, k, v = _qkv(cfg, layer, _norm(cfg, x, layer, "ln1"), positions, kind)
+                    ctx = attend(ai, q, k, v)
+                    with jax.named_scope("attention"):
+                        x = x + _mm("...hd,hde->...e", ctx, layer["wo"])
+                    ai += 1
+                continue
             if kind == "latent":
                 # the callback takes the position's cache row for k and the
                 # layer's up-projection for v: it expands or absorbs
@@ -976,7 +1148,10 @@ def prefill(
     ``cfg.kv_index`` says which array each belongs in; latent layers:
     their rows [n, B, S, RW] and a V of no width) for the engine to
     write into the cache and, for a configuration with convolution layers, a fourth
-    result: their padded ``z`` rows [n_conv, B, S + K - 1, E].
+    result: their padded ``z`` rows [n_conv, B, S + K - 1, E]; for one
+    with ssm layers the fourth result is ``{"xbc": [n_ssm, B, S + K - 1,
+    width], "state": [n_ssm, B, H, P, N]}``: their convolutions' padded
+    input rows, and each sequence's state after its own length.
     ``head`` False (a prefill that samples nothing: block diffusion's):
     the first result is the last layer's output [B, S, E] and the head,
     whose product over all S rows is the peak temporary of every other
@@ -988,6 +1163,18 @@ def prefill(
     with jax.named_scope("embed"):
         x = _embed(cfg, params, tokens, positions)
     ks, vs, zs = [], [], []
+    xs, finals = [], []  # the ssm layers' padded [x, B, C] rows, and their states after each sequence's length
+
+    def window(si, xbc):
+        with jax.named_scope("ssm.conv"):
+            xs.append(conv_window(jnp.zeros((b, cfg.ssm_conv_kernel - 1, xbc.shape[-1]), xbc.dtype), xbc))
+        return xs[-1]
+
+    def scan(si, x_, dt, a, b_, c_):
+        with jax.named_scope("ssm.scan"):
+            y, final = ssm_ops.chunk_scan(x_, dt, a, b_, c_, cfg.ssm_chunk)
+        finals.append(final)
+        return y
 
     def attend(ai, q, k, v):
         kind = cfg.kv_index[ai][0]
@@ -1009,10 +1196,13 @@ def prefill(
         return zs[-1]
 
     live = positions < lens[:, None]
-    x = _layers(cfg, params, x, positions, live, attend, convolve, counts)
+    x = _layers(cfg, params, x, positions, live, attend, convolve, counts, recur=(window, scan))
     with jax.named_scope("head"):
         empty = jnp.zeros((0,), x.dtype)  # a configuration without attention layers
         out = (_head(cfg, params, x) if head else x, jnp.stack(ks) if ks else empty, jnp.stack(vs) if vs else empty)
+    if xs:
+        # (rows behind a sequence's length have dt = 0: `state` is the state AT its length)
+        return out + ({"xbc": jnp.stack(xs), "state": jnp.stack(finals)},)
     return out + (jnp.stack(zs),) if zs else out
 
 
@@ -1043,6 +1233,7 @@ def decode_step(
     conv: Optional[jax.Array] = None,
     counts: Optional[List] = None,
     window: Optional[Dict[str, jax.Array]] = None,
+    ssm: Optional[Dict[str, jax.Array]] = None,
 ):
     """One decode step for every batch slot.
 
@@ -1066,10 +1257,21 @@ def decode_step(
     (generation/cache.py); ``cache_k`` / ``cache_v`` / ``block_tables``
     are then the full layers' alone. The last result is then ``{"k",
     "v"}``, the window layers' arrays with the token's rows written.
+
+    ``ssm`` (a configuration with ssm layers): ``{"ssm_conv": [n_ssm, B, K
+    - 1, width], "ssm": [n_ssm, B, H / pack, N, lanes]}``, every slot's
+    convolution rows and stored recurrent state (ops/ssm.py) under the
+    names the cache keeps them by. The fourth
+    result is then the same dict after this token: the live slots' rows
+    shifted, their state updated in ONE pass a layer (on a TPU the Pallas
+    call ``ssm_state_update``, in place when the caller donates); a slot
+    that is not live keeps both, bit for bit.
     """
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     bs = cache_k.shape[2]
     state = {"k": cache_k, "v": cache_v, "conv": conv}
+    if ssm is not None:
+        state["ssm"] = ssm["ssm"]
     live = context_lens > 0
     with jax.named_scope("embed"):
         x = _embed(cfg, params, tokens, positions)  # [B, E]
@@ -1109,11 +1311,33 @@ def decode_step(
             state["conv"] = state["conv"].at[ci].set(new.astype(old.dtype))
         return zpad
 
-    x = _layers(cfg, params, x, positions, live, attend, convolve if conv is not None else _no_conv, counts)
+    conv_rows = []  # every ssm layer's convolution rows after this token
+
+    def ssm_window(si, xbc):
+        with jax.named_scope("ssm.conv"):
+            old = ssm["ssm_conv"][si]
+            xpad = conv_window(old, xbc)
+            conv_rows.append(jnp.where(live[:, None, None], xpad[:, 1:], old).astype(old.dtype))
+        return xpad
+
+    def ssm_scan(si, x_, dt, a, b_, c_):
+        with jax.named_scope("ssm.update"):
+            y, state["ssm"] = ssm_ops.update(state["ssm"], si, x_[:, 0], dt[:, 0], a, b_[:, 0], c_[:, 0], backend=backend)
+        return y[:, None]
+
+    x = _layers(
+        cfg, params, x, positions, live, attend, convolve if conv is not None else _no_conv, counts,
+        recur=(ssm_window, ssm_scan) if ssm is not None else None,
+    )
     with jax.named_scope("head"):
         out = (_head(cfg, params, x), state["k"], state["v"])
     if conv is not None:
         out += (state["conv"],)
+    if ssm is not None:
+        # the rows are written ONCE, from the array the step was given: updated layer by layer in place
+        # (`.at[si].set`), the TPU compiler rematerialised a layer's old rows AFTER their update under
+        # memory pressure, and from 176 slots on every sequence's rows were shifted twice a step (PR 48)
+        out += ({"ssm_conv": jnp.stack(conv_rows), "ssm": state["ssm"]},)
     return out if window is None else out + ({"k": state["wk"], "v": state["wv"]},)
 
 
@@ -1165,6 +1389,11 @@ def verify_step(
     result is the last layer's output [B, W, E], no logits.
     """
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
+    if cfg.ssm_layers:
+        raise NotImplementedError(
+            "an append window over ssm layers (speculative verification, a prefix hit's suffix prefill) is not "
+            "written down: each row's recurrent state would have to be kept to choose one at the accepted length"
+        )
     bs = cache_k.shape[2]
     state = {"k": cache_k, "v": cache_v}
     zs = []

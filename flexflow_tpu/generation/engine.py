@@ -70,16 +70,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.types import DataType
 from ..device import on_tpu
 from ..models.transformer import TransformerConfig
 from ..obs.capacity import ProgramRegistry, ServingFlops
 from ..obs.steptrace import phase
 from ..obs.truth import PredictionLedger
 from ..ops.attention import STREAM_SCORE_BYTES, latent_call_lowering, paged_call_lowering, prefill_call_lowering
+from ..ops import ssm as ssm_ops
 from ..ops.expert_product import expert_lowering
 from ..runtime import faults
 from .cache import (
-    BlockAllocator, CacheConfig, KVCache, StateConfig, WindowTable, pools_from_budget, slot_mapping,
+    BlockAllocator, CacheConfig, KVCache, SlotStateConfig, StateConfig, WindowTable, pools_from_budget, slot_mapping,
 )
 from .decoder import (
     DecoderParams,
@@ -318,6 +320,28 @@ def unsupported_paths(kind: str, dcfg) -> Dict[str, str]:
                 f"the serving layout has no placement for the expert weights"
             ),
         },
+        "ssm": {
+            "speculation": (
+                "speculative verification (engine.verify) is refused for a configuration with state-space "
+                "layers: every window row's recurrent state would have to be kept, megabytes a row, to choose "
+                "one at the accepted length"
+            ),
+            "kv_handoff": (
+                "the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a configuration "
+                "with state-space layers: a payload carries K/V blocks and no recurrent state, so the decode "
+                "side could not continue from it"
+            ),
+            "tensor_parallel": (
+                "tp_degree > 1 is refused for a configuration with state-space layers: the serving layout "
+                "shards attention heads and the FFN, and has no placement for the state-space mixer, its "
+                "per-slot state or the expert weights"
+            ),
+            "prefix_reuse": (
+                "prefix reuse is off: a state-space layer's state exists per slot and nowhere else (a "
+                "snapshot a cached block would be a sequence's whole state a block), so no cached prefix "
+                "could be resumed; every prompt is prefilled whole"
+            ),
+        },
         "conv": {
             "speculation": (
                 "speculative verification (engine.verify) is refused for a configuration "
@@ -533,7 +557,7 @@ class GenerationEngine:
                 "a configuration with both convolution and sliding-window layers is refused: a "
                 "cached block would carry a convolution snapshot and a window half at once"
             )
-        for kind in ("conv", "window", "latent"):
+        for kind in ("conv", "window", "latent", "ssm"):
             if kind in self.dcfg.layer_types:
                 self.unsupported.update(unsupported_paths(kind, self.dcfg))
         if self.dcfg.block == "parallel":
@@ -630,6 +654,19 @@ class GenerationEngine:
                     f"the window layers' pool holds {self.window_config.num_blocks} blocks; {max_batch_slots} "
                     f"slots need {need} (a live sequence keeps up to {self.window_columns}, and the release counts on it)"
                 )
+        # the third kind (cache.py SlotStateConfig): what the state-space
+        # layers keep per sequence, per slot ONLY: the last rows of the
+        # convolution's input and the recurrent state as ops/ssm.py stores it
+        self.slot_state: Optional[SlotStateConfig] = None
+        if self.dcfg.ssm_layers:
+            d = self.dcfg
+            self.slot_state = SlotStateConfig(
+                num_layers=len(d.ssm_layers), slots=max_batch_slots,
+                parts=(
+                    ("ssm_conv", (d.ssm_conv_kernel - 1, d.ssm_conv_width), cfg.dtype),
+                    ("ssm", ssm_ops.state_shape(d.ssm_heads, d.ssm_head_dim, d.ssm_groups, d.ssm_state_size), DataType.FLOAT),
+                ),
+            )
         if cache_config is None:
             # K/V is paged for the ATTENTION layers alone, at the K/V
             # heads' width, in the type the configuration serves in
@@ -645,6 +682,15 @@ class GenerationEngine:
             if self.dcfg.latent_layers:
                 # one row a position, all heads': cache.py "Latent rows"
                 kv.update(num_heads=1, head_dim=self.dcfg.latent_width, latent=True)
+            if cache_budget_bytes is not None and self.slot_state is not None:
+                # the slots' state comes out of the same budget, before any block
+                held = self.slot_state.total_bytes
+                if held >= cache_budget_bytes:
+                    raise ValueError(
+                        f"cache budget {cache_budget_bytes}B is under the {held}B that {max_batch_slots} slots' "
+                        f"state-space state takes before any K/V block"
+                    )
+                cache_budget_bytes -= held
             if cache_budget_bytes is not None:
                 # per-device HBM budget: the head-sharded cache holds
                 # H/tp heads of every block per chip, so the same chip
@@ -668,7 +714,7 @@ class GenerationEngine:
         # the second kind of state (cache.py): what the convolution
         # layers keep per sequence, per slot and per cached block
         self.state_config: Optional[StateConfig] = None
-        if self.dcfg.stateful:
+        if self.dcfg.conv_layers:
             self.state_config = StateConfig(
                 num_layers=len(self.dcfg.conv_layers),
                 rows=self.dcfg.conv_kernel - 1,
@@ -681,7 +727,9 @@ class GenerationEngine:
             sharding=self.layout.cache_sharding if self.layout else None,
             state_config=self.state_config,
             window_config=self.window_config,
+            slot_state=self.slot_state,
         )
+        self._ssm_slots_live = 0  # the slots the last decode step ran live (`cache.ssm`)
         # the live sequences' tables of the window pool, by batch slot,
         # and what the release has given back (the `cache` section of
         # /v2/stats: cache_stats)
@@ -972,7 +1020,9 @@ class GenerationEngine:
         # the refusal is named; every other configuration's hits are
         # served as they always were
         suffix_scores = 4 * self.dcfg.num_heads * self.buckets[0] * self.max_seq_len
-        if prefix_cache and suffix_scores > STREAM_SCORE_BYTES:
+        if self.slot_state is not None:
+            prefix_cache = False  # (named in `unsupported` above: unsupported_paths("ssm"))
+        elif prefix_cache and suffix_scores > STREAM_SCORE_BYTES:
             prefix_cache = False
             self.unsupported["prefix_reuse"] = (
                 f"prefix reuse is off: a hit's suffix prefill[{self.buckets[0]}] (the smallest bucket) would hold "
@@ -1001,6 +1051,10 @@ class GenerationEngine:
         # (the admission-time programs donate nothing, state included: a
         # failed prefill leaves every array as it was, for the retry)
         self._restore_state_jit = jax.jit(self._restore_state_impl)
+        # a prefill's hand-over of a slot's named parts (cache.py SlotStateConfig): the one admission-time
+        # program that donates, and only the slots' state: it runs after the prefill has succeeded, so a
+        # failed prefill still leaves every array as it was
+        self._install_state_jit = jax.jit(self._install_state_impl, donate_argnums=(0,) if self.donate else ())
         self._copy_block_jit = jax.jit(self._copy_block_impl, **blk_sh)
         self._read_block_jit = jax.jit(self._read_block_impl, **rd_sh)
         self._write_block_jit = jax.jit(self._write_block_impl, **blk_sh)
@@ -1073,7 +1127,7 @@ class GenerationEngine:
             stats.add_section("experts", self.expert_stats)
         if self.state_config is not None:
             stats.add_section("conv_state", self.conv_state_stats)
-        if self.window_config is not None or self.cache_config.latent:
+        if self.window_config is not None or self.cache_config.latent or self.slot_state is not None:
             stats.add_section("cache", self.cache_stats)
         if self.diffusion is not None:
             stats.add_section("diffusion", self.diffusion_stats)
@@ -1184,6 +1238,35 @@ class GenerationEngine:
                 snap = snap.at[:, dst].set(at_end.astype(snap.dtype))
         return {"conv": conv, "snap": snap}
 
+    def _slot_parts(self, left, n_tokens):
+        """What a prefill hands a slot of its state-space layers'
+        results (``left``: decoder.py ``prefill``'s fourth result, one
+        sequence): the convolution's input rows behind the sequence's
+        ``n_tokens`` (:func:`state_at`) and the recurrent state after
+        them (rows past the length have ``dt = 0``), in the stored
+        layout: ``{name: [n_ssm, *shape]}``, for :meth:`_install_state`."""
+        k = self.dcfg.ssm_conv_kernel
+        with jax.named_scope("ssm.handover"):
+            rows = jax.vmap(lambda z: state_at(z, n_tokens[None], k)[0])(left["xbc"])  # [n_ssm, K-1, width]
+            return {"ssm_conv": rows, "ssm": ssm_ops.pack_state(left["state"][:, 0], self.dcfg.ssm_groups)}
+
+    def _install_state_impl(self, state, slot, parts):
+        """A slot's parts into the slots' arrays (donated: in place)."""
+        self.trace_counts["state_install"] = self.trace_counts.get("state_install", 0) + 1
+        return {
+            name: jax.lax.dynamic_update_slice_in_dim(state[name], parts[name][:, None].astype(state[name].dtype), slot, axis=1)
+            for name in state
+        }
+
+    def _install_state(self, slot: int, parts: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+        """The hand-over behind a successful prefill: ``slot`` takes the
+        sequence's state, whole (whatever the slot's last tenant left is
+        overwritten). Returns the slots' arrays for ``cache.update``."""
+        with phase("cache.state_install", slot=slot):
+            return self._install_state_jit(
+                {name: self.cache.state[name] for name in self.slot_state.names}, jnp.int32(slot), parts
+            )
+
     def _count(self, counts, rows, kind: int):
         """The step's per-expert token counts (``rows``: one [N] row per
         expert layer, decoder.py) added into the carried counters."""
@@ -1211,6 +1294,8 @@ class GenerationEngine:
         )
         if self.state_config is not None:
             state = self._write_state(state, zs[0], slot, length, block_table, 0)
+        if self.slot_state is not None:
+            state = self._slot_parts(zs[0], length)
         counts = self._count(counts, rows, 1)
         positions = jnp.arange(s, dtype=jnp.int32)
         block, offset = slot_mapping(block_table, positions, cache_k.shape[2])
@@ -1289,7 +1374,10 @@ class GenerationEngine:
             params, tokens, positions, cache_k, cache_v, block_tables,
             context_lens, backend=self.backend, mesh=self._kernel_mesh,
             cfg=self.dcfg, conv=state.get("conv"), counts=rows, window=window,
+            ssm={name: state[name] for name in self.slot_state.names} if self.slot_state is not None else None,
         )
+        if self.slot_state is not None:
+            state = conv[0]
         if self.state_config is not None:
             # a decode step carries the slots' state alone: the blocks'
             # snapshots are the prefill programs' to write
@@ -1707,6 +1795,8 @@ class GenerationEngine:
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
+            if self.slot_state is not None:
+                state = self._install_state(slot, state)
             self.cache.update(ck, cv, **state)
             self.expert_counts = counts
             self.last_finite = np.asarray(ok).reshape(1)
@@ -1831,7 +1921,8 @@ class GenerationEngine:
         and, where the configuration has window layers, the slot's table
         of the window pool at ``window_columns`` columns."""
         args = (
-            self.cache.state,
+            # (a state-space configuration's prefill RETURNS the slot's parts and takes none: _install_state)
+            self.cache.state if self.slot_state is None else {},
             jnp.int32(slot) if self.state_config is not None else None,
             self.expert_counts,
         )
@@ -1960,6 +2051,16 @@ class GenerationEngine:
         cc, wc = self.cache_config, self.window_config
         if cc.latent:
             return {"latent": self.latent_stats()}
+        if self.slot_state is not None:
+            ss, live = self.slot_state, self._ssm_slots_live
+            return {
+                "ssm": {"layers": ss.num_layers, "slots": ss.slots, "bytes_per_slot": ss.bytes_per_sequence,
+                        "state_bytes_per_slot": ss.part_bytes("ssm"), "conv_bytes_per_slot": ss.part_bytes("ssm_conv"),
+                        "slots_live": live, "bytes_held": ss.total_bytes, "live_bytes": live * ss.bytes_per_sequence},
+                "full": {"layers": cc.num_layers, "blocks_total": self.allocator.num_total,
+                         "blocks_used": self.allocator.num_total - self.allocator.num_free,
+                         "bytes_per_block": cc.bytes_per_block},
+            }
         full, window = self._live_blocks
         return {
             "full": {"layers": cc.num_layers, "blocks_total": self.allocator.num_total,
@@ -2649,6 +2750,8 @@ class GenerationEngine:
             if self._n_latent:
                 self.latent_tokens_held = ctx_sum
                 self.latent_calls["absorbed"] += self._n_latent
+            if self.slot_state is not None:
+                self._ssm_slots_live = n_active
             if traced:
                 self.programs.set_compile_time("decode", elapsed)
             else:
@@ -2730,9 +2833,9 @@ class GenerationEngine:
                 prev_k, prev_v, prev_conv = (None, None, None) if self.donate else (
                     self.cache.k, self.cache.v, self.cache.state.get("conv")
                 )
-                prev_window = None
-                if self.window_config is not None and not self.donate:
-                    prev_window = {k: self.cache.state[k] for k in ("wk", "wv")}
+                # (the window pool's arrays or the slots' named parts: a configuration has one of them at most)
+                named = ("wk", "wv") if self.window_config is not None else self.slot_state.names if self.slot_state is not None else ()
+                prev_window = {k: self.cache.state[k] for k in named} if named and not self.donate else None
                 with self._part("decode", "call"):
                     out, ok, ck, cv, state, counts, *carried = self._decode_jit(self.params, *args)
                 self._carry(advanced, *carried)

@@ -334,6 +334,8 @@ class ServingFlops:
         self.window = 0
         self.window_ctx_flops = 0
         self.window_kv_bytes_per_pos = 0
+        # state-space layers (from_config): a live sequence's recurrent state over all of them, in bytes
+        self.state_bytes_per_seq = 0
 
     @classmethod
     def from_config(cls, cfg, dtype: DataType = DataType.FLOAT, chip=None) -> "ServingFlops":
@@ -351,8 +353,13 @@ class ServingFlops:
         position ``latent_width`` multiply-adds for the score and
         ``kv_lora_rank`` for the value), and ONE row a position in the
         cache, at its stored width; the shared experts every token goes
-        through; of the routed experts the bytes of those held here. A
-        shortcut expert branch (``shortcut_experts``): beside the layer's
+        through; of the routed experts the bytes of those held here. An
+        ssm layer: its projections and convolution, 5 flops a state value
+        a token for the recurrence, and the float32 state a decode step
+        reads and writes for every live sequence (``state_bytes_per_seq``);
+        ungated experts or experts in a latent: two matrices an expert at
+        the latent's width, the picks that land on a held expert, the two
+        latent projections. A shortcut expert branch (``shortcut_experts``): beside the layer's
         dense feed-forward, the router, the picks that land on a held
         expert and the identity experts' picks at ``2 E`` flops each."""
         model = cls(
@@ -367,7 +374,7 @@ class ServingFlops:
             return model
         e, v = cfg.hidden_size, cfg.vocab_size
         q, kv = cfg.num_heads * cfg.dim_per_head, cfg.kv_heads * cfg.dim_per_head
-        flops, params, n_attn, n_window = 2 * e * v, v * e * (1 if cfg.tied_head else 2), 0, 0
+        flops, params, n_attn, n_window, n_ssm = 2 * e * v, v * e * (1 if cfg.tied_head else 2), 0, 0, 0
         n_latent = len(cfg.latent_layers)
         for l in range(cfg.num_layers):
             if cfg.operator(l) == "latent":
@@ -380,10 +387,28 @@ class ServingFlops:
             elif cfg.operator(l) == "window":
                 op = 2 * e * q + 2 * e * kv
                 n_window += 1
+            elif cfg.operator(l) == "ssm":
+                # the two projections and the convolution; the recurrence is counted apart (ssm_token_flops)
+                op = e * (cfg.ssm_inner + cfg.ssm_conv_width + cfg.ssm_heads) + cfg.ssm_conv_kernel * cfg.ssm_conv_width + cfg.ssm_inner * e
+                n_ssm += 1
+            elif cfg.operator(l) == "ffn":
+                op = 0  # the layer is its feed-forward alone
             else:
                 op = 4 * e * e + cfg.conv_kernel * e
             kind = cfg.ffn_kind(l)
-            if kind == "experts":
+            if kind == "none":
+                ffn = ffn_params = 0
+            elif kind == "experts" and (cfg.moe_latent_size or cfg.expert_activation != "swiglu" or cfg.shared_ff_size):
+                # ungated experts (two matrices) and/or experts in a latent: the two latent projections, of a
+                # token's k picks those that land on a held expert, the shared expert on the hidden size itself
+                mats = 3 if cfg.expert_activation == "swiglu" else 2
+                ew = cfg.moe_latent_size or e
+                per_expert = mats * ew * cfg.moe_ff_size
+                shared = mats * e * (cfg.shared_ff_size or cfg.num_shared_experts * cfg.moe_ff_size) if cfg.num_shared_experts else 0
+                outside = (2 * e * ew if cfg.moe_latent_size else 0) + shared + e * cfg.num_experts
+                landed = cfg.experts_per_token * cfg.held_experts / cfg.num_experts
+                ffn, ffn_params = int(landed * per_expert) + outside, cfg.held_experts * per_expert + outside
+            elif kind == "experts":
                 per_expert = 3 * e * cfg.moe_ff_size
                 ffn, ffn_params = (cfg.experts_per_token + cfg.num_shared_experts) * per_expert + e * cfg.num_experts, (
                     (cfg.held_experts + cfg.num_shared_experts) * per_expert + e * cfg.num_experts)
@@ -409,6 +434,12 @@ class ServingFlops:
 
             model.per_ctx_flops = n_latent * 2 * cfg.num_heads * (cfg.latent_width + cfg.kv_lora_rank)
             model.kv_bytes_per_pos = n_latent * latent_row_width(cfg.latent_width) * model.dtype_bytes
+        if n_ssm:
+            # a token's recurrence in every ssm layer (decay, rank-1 update, the product with C: 5 flops a
+            # state value) and a live sequence's float32 state, which a decode step reads AND writes
+            values = cfg.ssm_inner * cfg.ssm_state_size
+            model.per_token_flops += n_ssm * 5 * values
+            model.state_bytes_per_seq = n_ssm * values * 4
         model.window = getattr(cfg, "window", 0) if n_window else 0
         model.window_ctx_flops = n_window * 4 * q
         model.window_kv_bytes_per_pos = 2 * n_window * kv * model.dtype_bytes
@@ -465,7 +496,8 @@ class ServingFlops:
         """HBM bytes for one decode step: weights once, KV read per live
         context position, KV write per active token."""
         return (self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_active)
-                + self.window_kv_bytes_per_pos * (self.windowed(n_active, context_sum) + n_active))
+                + self.window_kv_bytes_per_pos * (self.windowed(n_active, context_sum) + n_active)
+                + 2 * self.state_bytes_per_seq * n_active)
 
     def verify_bytes(self, n_tokens: int, context_sum: int) -> float:
         return (self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_tokens)

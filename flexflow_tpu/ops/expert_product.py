@@ -147,7 +147,28 @@ def expert_form(rows: int, held: int, k: int, outputs: Optional[int] = None) -> 
     Up to 192 rows the reads set the dense form's time and the grouped
     one moves pairs on top; at 256 the two are within 3 %: the rule stays
     where it was (dense under 1,024 rows), and a block step keeps the
-    dense form at every slot count of the sweep."""
+    dense form at every slot count of the sweep.
+
+    128 held of 512, ``k`` 22 (5.5 of a row's picks land here), UNGATED
+    experts (two matrices) of 1,024 x 2,688 in a latent (Nemotron-3-Super's
+    layer: a decode step of 64 / 128 / 192 slots; a prefill of 512 / 1,024
+    rows; the held weights are 1.41 GB a layer, 1.72 ms at 819 GB/s), ms
+    dense / grouped (``chip_smoke.py --expert-product nemotron``, my chip
+    run, PR 48)::
+
+        rows     64            128           192           512           1024
+               2.21 / 2.02   1.97 / 2.36   2.02 / 2.42   6.32 / 3.07   10.32 / 4.28
+
+    At a decode step's rows the reads set both forms' time and the dense
+    one is ahead from 128: the rule keeps it there. At a prefill's rows
+    the dense form multiplies ``rows x 128`` row-expert products for the
+    ``5.5 rows`` that landed and reads 2.1 / 2.4 x the grouped form's
+    time; the rule still keeps dense (``rows x held`` 131,072 at 1,024
+    rows is under ``8192 k`` = 180,224: its third clause asks ``k``, not
+    what lands), and leaves 3.3 / 6.0 ms a layer there. Moving it moves
+    ``prefill[512]`` / ``prefill[1024]`` of one cell and no other's
+    bucket only if the clause is written for it; that is a ``perf_opt``
+    issue's, with its claim (ROADMAP Reach B5)."""
     outputs = outputs or held
     if sparse(held, k, outputs):
         return "grouped"
@@ -211,7 +232,8 @@ def grouped_expert_sum(
     """The routed sum of rows ``v`` [T, E] in the activations' type, from
     ``route``'s ``gates`` [T, N] and ``chosen`` [T, k] and the stacked
     weights of the held experts (``held`` names them in stacking order;
-    None: the first of the N outputs, as many as are stacked). ``live``
+    None: the first of the N outputs, as many as are stacked; ``w3`` None:
+    ungated experts, ``W2 relu(W1 v)^2``). ``live``
     [T] bool: rows that are not get zeros and cost nothing. ``product``:
     the grouped product (:func:`grouped_matmul`). ``identity`` [T]
     float32: what each row's identity experts weigh it by; ``identity x
@@ -269,8 +291,10 @@ def grouped_expert_sum(
         ``part_sizes`` of them from place 0."""
         xs = v.reshape(t, -1, lanes)[part[:, 0].astype(jnp.int32)].reshape(part.shape[0], e)
         up = product(xs, w1, part_sizes)
-        gate_up = product(xs, w3, part_sizes)
-        hidden = (jax.nn.silu(up) * gate_up * part[:, 1:]).astype(v.dtype)
+        if w3 is None:  # an ungated expert: W2 relu(W1 v)^2
+            hidden = (jnp.square(jax.nn.relu(up)) * part[:, 1:]).astype(v.dtype)
+        else:
+            hidden = (jax.nn.silu(up) * product(xs, w3, part_sizes) * part[:, 1:]).astype(v.dtype)
         return product(hidden, w2, part_sizes)
 
     if loop:

@@ -1,0 +1,233 @@
+"""The cell ``nemotron-3-super-120b-a12b.reason-gen`` (PR 48), as
+``test_sdar_cell.py`` holds PR 45's: the configuration is the catalog's row
+cut in depth, experts held and vocabulary alone; the cell's files say what
+the issue named, key for key; ``nemotron_model.py``'s arithmetic is
+hand-worked and no share passes 100 %; every new reader on a hand-made run,
+and nothing raised where a program lacks what this PR adds; and ONE run of
+the whole command at rehearsal size on the CPU. Membership in
+``BENCHMARK.json``'s lists is asserted, never a list's END or its length:
+the next PR appends."""
+import json
+import pathlib
+import subprocess
+import sys
+
+import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from benchmark import kernel_model, layer_metrics, nemotron_model  # noqa: E402
+
+CELL = "nemotron-3-super-120b-a12b.reason-gen"
+NAME = "nemotron-3-super-120b-a12b"
+WORKLOAD = json.loads((ROOT / f"benchmark/workloads/{CELL}.json").read_text())
+CONFIG = json.loads((ROOT / f"benchmark/configs/{NAME}.json").read_text())
+TRAFFIC = json.loads((ROOT / "benchmark/traffic/reason-gen.json").read_text())
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CATALOG = pathlib.Path("/opt/skills/guides/model-configs/architectures.jsonl")
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+MINE = ("ssm_state_update_roofline.served", "hybrid_decode_roofline.served", "ssm_step_share.served", "ssm_prefill_ms_per_ktoken.served")
+MODEL = {  # the cell's sizes, as drivers/serve_nemotron.py::model_sizes gives them
+    "num_layers": 11, "ssm_layers": 5, "attention_layers": 1, "expert_layers": 5, "hidden_size": 4096, "num_heads": 32, "kv_heads": 2,
+    "head_dim": 128, "ssm_heads": 128, "ssm_head_dim": 64, "ssm_groups": 8, "ssm_state_size": 128, "ssm_conv_kernel": 4,
+    "moe_ff_size": 2688, "moe_latent_size": 1024, "shared_ff_size": 5376, "num_experts": 512, "experts_held": 128,
+    "experts_per_token": 22, "vocab_size": 32768, "block_size": 64, "cache_itemsize": 2, "weight_itemsize": 2,
+}
+
+
+def test_the_configuration_is_the_catalog_s_row_cut_in_depth_experts_held_and_vocabulary_alone():
+    if not CATALOG.exists():
+        pytest.skip("the catalog is not on this machine")
+    row = next(r for r in map(json.loads, CATALOG.read_text().splitlines()) if r["name"] == "NVIDIA-Nemotron-3-Super-120B-A12B-BF16")
+    assert CONFIG["source"] == row["source_url"] and CONFIG["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    differing = sorted(k for k, v in row["config"].items() if CONFIG.get(k) != v)
+    assert differing == sorted(CONFIG["reduced"])
+    assert CONFIG["published"] == {k: row["config"][k] for k in CONFIG["reduced"]} == {"num_hidden_layers": 88, "n_routed_experts": 512, "vocab_size": 131072}
+    assert (CONFIG["num_hidden_layers"], CONFIG["n_routed_experts"], CONFIG["vocab_size"]) == (11, 128, 32768)
+    pattern = CONFIG["hybrid_override_pattern"]
+    assert pattern[:11] == "MEMEMEM*EME" and (pattern.count("M"), pattern.count("E"), pattern.count("*")) == (40, 40, 8)
+    assert (pattern[:11].count("M"), pattern[:11].count("E"), pattern[:11].count("*")) == (5, 5, 1)  # 40 : 40 : 8 exactly
+    # the guide's floors: a whole period, 8 routed experts a layer, an eighth of the vocabulary
+    assert CONFIG["n_routed_experts"] >= 8 and 8 * CONFIG["vocab_size"] >= 131072
+    assert CONFIG["expert_share"]["chips"] * CONFIG["n_routed_experts"] == 512 and CONFIG["vocab_share"]["chips"] * CONFIG["vocab_size"] == 131072
+    assert {"state_dtype", "router_dtype", "positions", "ssm_init", "gate_epsilon"} <= set(CONFIG["assumed"])
+    assert "num_nextn_predict_layers" in CONFIG["not_served"] and CONFIG["serving_dtype"] == "bfloat16"
+
+
+def test_the_cell_is_the_one_the_issue_named_key_for_key():
+    d, p = WORKLOAD["deployment"], TRAFFIC["params"]
+    assert (WORKLOAD["config"], WORKLOAD["traffic"], WORKLOAD["chips"], WORKLOAD["driver"]) == (NAME, "reason-gen", 1, "serve_nemotron")
+    assert (d["block_size"], d["max_seq_len"], d["prompt_buckets"]) == (64, 2048, [512, 1024])
+    assert all(b % CONFIG["chunk_size"] == 0 for b in d["prompt_buckets"])  # multiples of the prefill's chunk
+    assert d["slots"] in (64, 128, 192) and WORKLOAD["traffic_params"]["clients"] == 2 * d["slots"]
+    assert set(d["slot_sweep"]["on_shared_seeds"]["served_tokens_per_s"]) == {"64", "128", "192"} and len(d["slot_sweep"]["on_shared_seeds"]["seeds"]) == 4
+    again = d["slot_sweep"]["retaken_on_the_final_program"]  # (the two counts the rule decides between, one call, four fresh seeds)
+    assert set(again["served_tokens_per_s"]) == {"128", "192"} and len(again["seeds"]) == 4 and not set(again["seeds"]) & set(d["slot_sweep"]["on_shared_seeds"]["seeds"])
+    # the repo's slot rule, the smallest count within 10 % of the best, over the counts that are candidates: a count at
+    # which a run of the cell did not run to its end is struck (192: the check's round), as one that served wrong tokens was
+    struck = d["slot_sweep"]["struck"]
+    assert set(struck) == {"192"} and all(len(why) > 100 for why in struck.values())
+    for sweep in (d["slot_sweep"]["on_shared_seeds"], again):
+        medians = {int(s): m for s, m in sweep["median"].items() if s not in struck}
+        assert d["slots"] == min(s for s, m in medians.items() if m >= 0.9 * max(medians.values()))
+    assert "max_queue" not in d  # every server option at its default: 2 x 128 clients fit the scheduler's queue of 256
+    assert TRAFFIC["generator"] == "closed_clients" and p["clients"] is None and p["max_rate_per_s"] is None
+    assert p["prompt"] == {"dist": "uniform", "min": 256, "max": 1024}
+    assert p["output"] == {"dist": "uniform", "min": 512, "max": 1024, "stratified_block": 32}
+    assert p["prompt"]["max"] + p["output"]["max"] <= d["max_seq_len"]
+    assert (WORKLOAD["lead_in_s"], WORKLOAD["drain_s"], WORKLOAD["reference_sample"]) == (40.0, 90.0, 16)
+    for limit in ("gap_ratio_limit", "request_excess_limit", "state_error_limit", "router_shift_least"):
+        assert WORKLOAD[limit] > 0 and limit in WORKLOAD["tolerances"], limit  # every tolerance with its reason
+    assert WORKLOAD["probe_sample"] <= WORKLOAD["reference_sample"] and WORKLOAD["probe_sample"] <= d["slots"] and WORKLOAD["probe_steps"] >= 128
+    controls = {k: c for k, c in WORKLOAD["controls"].items() if k != "what"}
+    assert set(controls) == {"bfloat16_state", "bfloat16_router", "state_skipped"}
+    # the issue asked that all three fail, each by one of the cell's limits: a skipped update by BOTH limits on the
+    # served tokens and by the stored state; the two roundings, which read inside the program's own band of served
+    # tokens, each by the number the probe brings for it, with room on both sides
+    assert not any(c["comes_out_correct"] for c in controls.values()) and all(len(c["why"]) > 100 for c in controls.values())
+    skipped, program = controls["state_skipped"], WORKLOAD["tolerances"]["program_readings"]
+    assert min(skipped["gap_ratio"]) > WORKLOAD["gap_ratio_limit"] and min(skipped["worst_request_excess"]) > WORKLOAD["request_excess_limit"]
+    assert max(program["gap_ratio"]) < WORKLOAD["gap_ratio_limit"] and max(program["worst_request_excess"]) < WORKLOAD["request_excess_limit"]
+    assert 3 * max(program["state_error"]) < WORKLOAD["state_error_limit"] < min(controls["bfloat16_state"]["state_error"]) / 3
+    assert min(skipped["state_error"]) > WORKLOAD["state_error_limit"] and len(program["state_error"]) >= 5
+    # (by (request, head) and not pooled: pooled, the program's largest reading and the control's least lie a factor of 5 apart)
+    assert 10 * max(program["state_error_pooled"]) > min(controls["bfloat16_state"]["state_error_pooled"])
+    assert max(controls["bfloat16_router"]["router_shift_over_stated"]) < WORKLOAD["router_shift_least"] < min(program["router_shift_over_stated"]) / 1.3
+    for name in ("bfloat16_state", "bfloat16_router"):  # (and neither is told from the program by a served token)
+        assert max(controls[name]["gap_ratio"]) <= WORKLOAD["gap_ratio_limit"], name
+    assert len(WORKLOAD["spread_of_six"]["served_tokens_per_s"]) == 6 and WORKLOAD["memory_peak_bytes"] > 0.25 * 16.9e9
+
+
+def _verdict(probed_program, gap=0.01):
+    """``serve_nemotron.verdict`` on a hand-made sample of 2 requests x 5
+    judged tokens: the stated arithmetic's choices lie 0.01 logits under
+    the reference's best, the arm's ``gap``; ``probed_program`` the probe's
+    readings of it, by layer."""
+    import numpy as np
+
+    from benchmark.drivers import serve_nemotron
+
+    judged = lambda g: {"gap": np.full((10,), g), "margin": np.full((10,), 0.1)}  # noqa: E731
+    probed = {"state_error": [5e-5, 4e-3], "pick_error": [5e-3, 6e-3], "router_shift": [3.1e-3, 9e-3], "router_shift_stated": [3.3e-3, 9e-3]}
+    return serve_nemotron.verdict(judged(gap), judged(0.01), np.ones((2, 5), bool), WORKLOAD, dict(probed, **probed_program))
+
+
+def test_the_comparison_holds_the_tokens_the_stored_state_and_the_router_s_sight_of_its_weights():
+    read, failures = _verdict({})
+    assert failures == [] and read["gap_ratio"] == pytest.approx(1.0) and (read["state_error"], read["router_shift"]) == (5e-5, 3.1e-3)
+    # each of the four limits alone: tokens further off, a state that holds bfloat16 (its first layer lies 2^-9 of a
+    # value and more from the float32 one), a router whose product never sees the weights below bfloat16
+    assert [f.split()[0] for f in _verdict({}, gap=0.05)[1]] == ["gap_ratio", "worst_request_excess"]
+    assert [f.split()[0] for f in _verdict({"state_error": [3e-3, 4e-3]})[1]] == ["state_error"]
+    assert [f.split()[0] for f in _verdict({"router_shift": [0.0, 0.0]})[1]] == ["router_shift"]
+    assert _verdict({"state_error": [5e-5, 1.0], "router_shift": [3.1e-3, 0.0]})[1] == []  # (the first layer of each kind is what is held)
+
+
+def test_benchmark_json_holds_the_configuration_the_cell_and_four_metrics_that_list_it():
+    config = next(c for c in BENCH["configs"] if c["name"] == NAME)
+    assert config["file"] == f"benchmark/configs/{NAME}.json" and config["reduced"] == CONFIG["reduced"] and config["source"] == CONFIG["source"]
+    entry = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+    assert entry == {"name": CELL, "config": NAME, "traffic": "reason-gen", "chips": 1, "why": entry["why"]}
+    assert all(len(e["why"]) <= 200 for e in (entry, config))
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
+    by_name = {m["name"]: m for m in BENCH["per_layer"]}
+    for name, layer, source in zip(MINE, ("kernels", "kernels", "device", "engine"), ("device_trace", "device_trace", "device_trace", "program_span")):
+        m = by_name[name]
+        assert (m["workloads"], m["moves"], m["layer"], m["source"]) == ([CELL], "served_tokens_per_s", layer, source), name
+        assert (ROOT / "benchmark/layer_metrics" / f"{name.split('.')[0]}.py").exists(), name
+    assert by_name[MINE[0]]["unit"] == by_name[MINE[1]]["unit"] == by_name[MINE[2]]["unit"] == "%"
+    served = next(m for m in BENCH["end_to_end"] if m["name"] == "served_tokens_per_s")
+    assert CELL in served["workloads"] and served["bound"] == 0.1
+    listed = {m["name"] for m in BENCH["per_layer"] if CELL in m.get("workloads", [])}
+    assert listed == set(MINE) | {
+        "batch_occupancy.served", "cache_blocks_used_peak", "decode_step_ms.served", "prefill_time_share.served", "device_idle_share.served",
+        "host_dispatch_share.served", "host_readback_share.served", "host_sched_share.served", "host_unspanned_share.served",
+        "host_release_share.served", "pipelined_step_share.served", "expert_load_imbalance.served", "expert_tokens_per_call.served"}
+
+
+def test_the_update_call_and_a_decode_step_by_hand():
+    assert nemotron_model.state_values(MODEL) == 128 * 64 * 128
+    ops, nbytes = nemotron_model.update_call(MODEL, 128)
+    assert ops == 5 * 128 * 1048576 and nbytes == 4 * 128 * (2 * 1048576 + 3 * 8192 + 2 * 1024)
+    least, bound = kernel_model.least_seconds(ops, nbytes, PEAKS)
+    assert bound == "memory" and 1.30e-3 < least < 1.34e-3  # 1.07 GB of state each way at 819 GB/s
+    w = nemotron_model.weights(MODEL)
+    assert w["expert"] == 2 * 1024 * 2688 and w["ssm"] == 4096 * 18560 + 10240 * 5 + 8192 * 4096 + 8192 + 4096
+    # at 128 rows a held expert is missed by every row with probability (1 - 22/512)^128 = 0.36 %
+    assert nemotron_model.experts_touched(MODEL, 128) == pytest.approx(128 * (1 - (490 / 512) ** 128)) and nemotron_model.experts_touched(MODEL, 128) > 127.5
+    ops, nbytes = nemotron_model.decode_step(MODEL, 128, 128 * 1000)
+    state = 5 * 128 * (2 * 4 * 1048576 + 2 * 3 * 10240 * 2)
+    assert 5.4e9 < state < 5.5e9 and nbytes > state + 2 * 5 * 127.5 * w["expert"]
+    assert 13.5e9 < nbytes < 15.5e9  # the issue's reckoning: ~14.4 GB a step at 128 slots
+    least, bound = kernel_model.least_seconds(ops, nbytes, PEAKS)
+    assert bound == "memory" and 0.0165 < least < 0.019
+    ops, nbytes = nemotron_model.prefill(MODEL, 1024)
+    assert kernel_model.least_seconds(ops, nbytes, PEAKS)[1] == "memory" and nbytes > 9.0e9
+
+
+def _ctx():
+    snap = lambda k: {"cache": {"ssm": {"bytes_per_slot": 21278720, "slots_live": 128}}, "prefill_attention": {"tokens_total": 20000 * k}}  # noqa: E731
+    engine = lambda k: {"step_counts": {"decode": 2000 * k, "prefill": 30 * k},  # noqa: E731
+                        "phase_time_s": {"decode": {"dispatch": 0.5 * k, "execute": 40.0 * k, "readback": 0.5 * k},
+                                         "prefill": {"dispatch": 0.2 * k, "execute": 1.7 * k, "readback": 0.1 * k}}}
+    return {
+        "stats_open": snap(1), "stats_close": snap(2), "engine_open": engine(1), "engine_close": engine(2),
+        "window": (100.0, 150.0), "trace_abs": (147.0, 150.0), "model": dict(MODEL), "peaks": PEAKS, "slots": 128,
+        "records": [{"prompt_len": 500, "token_times": [146.0] + [147.0 + 0.02 * i for i in range(120)]},
+                    {"prompt_len": 900, "token_times": [148.0] + [148.1 + 0.02 * i for i in range(80)]}],
+        "trace": {"programs": {"jit__decode_impl": 2.7, "jit__prefill_impl": 0.2}, "busy_s": 2.9, "kernel_s": {}, "kernel_calls": {}},
+        "ssm_kernels": {"kernel_s": {"ssm_state_update": 1.2}, "kernel_calls": {"ssm_state_update": 600}},
+    }
+
+
+def test_the_four_new_readers_on_a_hand_made_run():
+    ctx = _ctx()
+    ops, nbytes = nemotron_model.update_call(MODEL, 128)
+    got = layer_metrics.read("ssm_state_update_roofline.served", ctx)
+    assert got == pytest.approx(100 * kernel_model.least_seconds(600 * ops, 600 * nbytes, PEAKS)[0] / 1.2) and 0 < got < 100
+    assert layer_metrics.read("ssm_step_share.served", ctx) == pytest.approx(100 * 1.2 / 2.9)
+    assert layer_metrics.read("ssm_prefill_ms_per_ktoken.served", ctx) == pytest.approx(2000.0 / 20)
+    # traced: 600 update calls over 5 layers = 120 steps; 200 decode rows in the traced part = 1.67 rows a step
+    contexts = [500 + i for i in range(1, 121)] + [900 + i for i in range(1, 81)]
+    ops, nbytes = nemotron_model.decode_step(MODEL, 200 / 120, sum(contexts) / 120)
+    got = layer_metrics.read("hybrid_decode_roofline.served", ctx)
+    assert got == pytest.approx(100 * kernel_model.least_seconds(120 * ops, 120 * nbytes, PEAKS)[0] / 2.7) and 0 < got < 100
+
+
+@pytest.mark.parametrize("name", MINE)
+def test_a_program_without_ssm_calls_leaves_the_new_metrics_out(name):
+    """On the parent there is no ``cache.ssm`` section, no update kernel in
+    the trace and no state-space sizes in the model's: nothing to read, and
+    nothing raised."""
+    assert layer_metrics.read(name, {}) is None
+    parent = _ctx()
+    for key in ("stats_open", "stats_close"):
+        del parent[key]["cache"]["ssm"]
+    del parent["ssm_kernels"], parent["model"]["ssm_layers"]
+    assert layer_metrics.read(name, parent) is None
+    silent = dict(_ctx(), ssm_kernels={"kernel_s": {"ssm_state_update": 0.0}, "kernel_calls": {"ssm_state_update": 0}},
+                  stats_close=_ctx()["stats_open"], engine_close=_ctx()["engine_open"])
+    assert layer_metrics.read(name, silent) is None
+
+
+def test_the_whole_command_runs_the_cell_at_rehearsal_size():
+    """``run.py --rehearse --trace 1``: tiny widths on the CPU backend, the
+    whole control flow (weights from the seed, warm-up, HTTP, the closed
+    loop, the judged requests scored by the reference), no result line."""
+    out = subprocess.run(
+        [sys.executable, str(ROOT / "benchmark/run.py"), "--workload", CELL, "--seed", "3400000123", "--seconds", "3",
+         "--trace", "1", "--rehearse"], capture_output=True, text=True, timeout=600, cwd=str(ROOT),
+    )
+    assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-3000:]
+    assert "rehearsal done: correct=True" in out.stdout and "failed=0" in out.stdout
+    found = out.stdout.split("readers that found something: ")[1].splitlines()[0]
+    for name in ("ssm_prefill_ms_per_ktoken.served", "decode_step_ms.served", "batch_occupancy.served", "pipelined_step_share.served",
+                 "expert_load_imbalance.served", "expert_tokens_per_call.served", "cache_blocks_used_peak", "prefill_time_share.served",
+                 "host_dispatch_share.served", "host_release_share.served"):
+        assert name in found, found
+    # (the three shares read a device trace: the chip's)
+    assert "reference: gap_ratio 0.0000" in out.stdout  # float32 on the CPU is the reference's own arithmetic
+    assert "block single (3 ssm: 8 heads of 16, 2 groups, state 16; 1 attention" in out.stdout
+    assert "refused ['kv_handoff', 'prefix_reuse', 'speculation', 'tensor_parallel']" in out.stdout and "cache.ssm: {'layers': 3" in out.stdout

@@ -118,6 +118,10 @@ _as_pr_31_left_it = _as_it_was_after("mellum2-12b.code-gen", "mellum2-12b", "win
 # PR 37 pins its ten as the list's tail (PR 38 appended two)
 _as_pr_34_left_it = _per_layer_through("latent_cache_share.served")
 _as_pr_37_left_it = _per_layer_through("trace_record_share.served")
+# PR 41 and PR 45 each pin their configuration, their cell and their metrics as the lists' tails and the
+# lists' lengths (PR 45 appended after PR 41, PR 48 after both)
+_as_pr_41_left_it = _as_it_was_after("longcat-flash-chat.agent-turns", "longcat-flash-chat", "latent_prefill_ms_per_ktoken.served")
+_as_pr_45_left_it = _as_it_was_after("sdar-30b-a3b-chat.block-gen", "sdar-30b-a3b-chat", "block_prefill_ms_per_ktoken.served")
 
 
 def _seeing(item, view):
@@ -143,11 +147,14 @@ def _seeing(item, view):
 # `benchmark` one edits them: for the next `benchmark` issue, unpin the
 # tail assertions and take this out)
 _PINNED_TAILS = {
-    ("test_pipelined_step_share.py", "test_benchmark_json_asks_for_it_in_the_three_serving_cells"): _as_pr_30_left_it,
+    ("test_pipelined_step_share.py", "test_benchmark_json_asks_for_it_in_the_three_serving_cells"): lambda b: _as_pr_30_left_it(_as_pr_41_left_it(b)),
     ("test_lfm2_cell.py", "test_every_new_metric_lists_the_cell_and_is_read_there"): _as_pr_27_left_it,
     ("test_mellum2_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_metrics_that_list_it"): _as_pr_31_left_it,
     ("test_joyai_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_three_metrics_that_list_it"): _as_pr_34_left_it,
-    ("test_thread_account.py", "test_benchmark_json_asks_for_them_in_the_five_serving_cells"): _as_pr_37_left_it,
+    ("test_thread_account.py", "test_benchmark_json_asks_for_them_in_the_five_serving_cells"): lambda b: _as_pr_37_left_it(_as_pr_41_left_it(b)),
+    ("test_host_release_share.py", "test_benchmark_json_asks_for_it_in_the_five_serving_cells"): _as_pr_41_left_it,
+    ("test_longcat_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_five_metrics_that_list_it"): _as_pr_41_left_it,
+    ("test_sdar_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_six_metrics_that_list_it"): _as_pr_45_left_it,
 }
 
 
