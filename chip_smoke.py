@@ -314,6 +314,30 @@ def _timed_ms(call, args, reps=20) -> float:
     return round((time.perf_counter() - t0) / reps * 1e3, 4)
 
 
+def _device_trace(call, reps: int, tag: str):
+    """``reps`` calls of ``call()`` under the profiler: the trace as
+    ``benchmark/trace_reduce.py`` reads it (times on the device's clock)."""
+    import shutil
+
+    import jax
+
+    from benchmark import trace_reduce
+
+    trace_dir = REPO / ".bench_trace" / tag
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    for _ in range(reps):
+        r = call()
+    jax.block_until_ready(r)
+    jax.profiler.stop_trace()
+    (xplane,) = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
+    trace = trace_reduce.read_xplane(str(xplane))
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return trace
+
+
 def grouped_kernels_check(calls=None, columns=None) -> dict:
     """The grouped-query decode calls of ``calls`` (``GROUPED_CALLS``), compiled by
     Mosaic, against :func:`stated_paged_attention`: every element inside
@@ -500,8 +524,6 @@ def flash_kernels_check(block_pairs=(None,)) -> dict:
     seconds it took to lower and compile, at each of ``block_pairs``
     (``None``: the pair the tree's own policy takes). Through
     ``_flash_bhsd``, which a parent commit has under the same name."""
-    import shutil
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -515,7 +537,6 @@ def flash_kernels_check(block_pairs=(None,)) -> dict:
     scale = c["head_dim"] ** -0.5
     kernels = kernel_model.FLASH_KERNELS
     out = {"call": c, "policy_blocks": list(fa.effective_blocks(c["seq"], c["seq"]))}
-    trace_dir = REPO / ".bench_trace" / "chip_smoke_flash"
     reps = 20
     for pair in block_pairs:
         bq, bk = pair or out["policy_blocks"]
@@ -526,22 +547,69 @@ def flash_kernels_check(block_pairs=(None,)) -> dict:
         t0 = time.perf_counter()
         call = jax.jit(jax.grad(layer, (0, 1, 2))).lower(q, k, v).compile()
         row = {"compile_s": round(time.perf_counter() - t0, 2), "program_ms": _timed_ms(call, (q, k, v), reps=reps)}
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
-        for _ in range(reps):
-            r = call(q, k, v)
-        jax.block_until_ready(r)
-        jax.profiler.stop_trace()
-        (xplane,) = sorted(trace_dir.glob("plugins/profile/*/*.xplane.pb"))
-        reduced = trace_reduce.reduce_trace(trace_reduce.read_xplane(str(xplane)), kernels)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        reduced = trace_reduce.reduce_trace(_device_trace(lambda: call(q, k, v), reps, "chip_smoke_flash"), kernels)
         check(all(reduced["kernel_calls"][name] == reps for name in kernels), f"the trace shows {reduced['kernel_calls']}, not {reps} calls of each")
         row.update({name: round(reduced["kernel_s"][name] / reps * 1e3, 4) for name in kernels})
         row["three_ms"] = round(sum(row[name] for name in kernels), 4)
         out[f"{bq}x{bk}"] = row
         log(f"flash kernels at blocks {bq} x {bk}, ms a call on the device's clock: {row}")
+    return out
+
+
+LOSS_CHAIN_CALL = dict(rows=16 * 512, classes=30522)  # a chip's logits in both bert-large cells, bfloat16, a label a row
+
+
+def loss_chain_check() -> dict:
+    """The trainer's vocabulary loss chain alone at :data:`LOSS_CHAIN_CALL`,
+    value and gradient in one program, composed
+    (``sparse_categorical_crossentropy(softmax(logits))`` under autodiff)
+    against fused (``losses.softmax_crossentropy``): milliseconds a call
+    on the device's clock (a profiler trace reduced as the benchmark
+    reduces a cell's) and on the host's, what share of HBM's peak the
+    LEAST traffic of the chain would be at that time (two reads of the
+    bfloat16 logits, one write of their gradient), and the errors of the
+    value and the gradient against the composed chain on the same logits
+    in float32. Alone, the logits and their gradient cross the program's
+    boundary in the default layout; a train step picks its own."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import trace_reduce
+    from flexflow_tpu.runtime import losses
+
+    c = LOSS_CHAIN_CALL
+    rs = np.random.RandomState(SEED)
+    logits = jnp.asarray(rs.randn(c["rows"], c["classes"]) * 2.0, jnp.bfloat16)
+    labels = jnp.asarray(rs.randint(0, c["classes"], c["rows"]), jnp.int32)
+    forms = {
+        "composed": lambda x, y: losses.sparse_categorical_crossentropy(jax.nn.softmax(x, axis=-1), y),
+        "fused": lambda x, y: losses.softmax_crossentropy(x, y, True),
+    }
+    ref_value, ref_grad = jax.jit(jax.value_and_grad(forms["composed"]))(logits.astype(jnp.float32), labels)
+    least_bytes = 3 * logits.size * logits.dtype.itemsize
+    out = {"call": c, "least_bytes": least_bytes}
+    reps = 20
+    for form, fn in forms.items():
+        t0 = time.perf_counter()
+        call = jax.jit(jax.value_and_grad(fn)).lower(logits, labels).compile()
+        row = {"compile_s": round(time.perf_counter() - t0, 2), "host_ms": _timed_ms(call, (logits, labels), reps=reps)}
+        reduced = trace_reduce.reduce_trace(_device_trace(lambda: call(logits, labels), reps, "chip_smoke_loss_chain"), ())
+        value, grad = call(logits, labels)
+        row["device_ms"] = round(reduced["busy_s"] / reps * 1e3, 4)
+        row["device_ops_ms"] = {k: round(v / reps * 1e3, 4) for k, v in reduced["device_ops"][:6]}
+        row["least_traffic_gb_per_s"] = round(least_bytes / (row["device_ms"] * 1e-3) / 1e9, 1)
+        row["temp_bytes"] = call.memory_analysis().temp_size_in_bytes
+        row["value_rel_err"] = float(abs(value - ref_value) / abs(ref_value))
+        row["grad_dtype"] = str(grad.dtype)
+        row["grad_err_over_max"] = float(jnp.max(jnp.abs(grad.astype(jnp.float32) - ref_grad)) / jnp.max(jnp.abs(ref_grad)))
+        out[form] = row
+        log(f"loss chain {form} at {c}: {row}")
+    # the fused value is float32 from the logits; the composed one rounds the probabilities to bfloat16 first
+    check(out["fused"]["value_rel_err"] <= out["composed"]["value_rel_err"] + 1e-7,
+          f"the fused value departs further from float32 than the composed one: {out['fused']['value_rel_err']} > {out['composed']['value_rel_err']}")
+    check(out["fused"]["grad_err_over_max"] <= 2.0 ** -8, f"the fused gradient is {out['fused']['grad_err_over_max']} of its largest entry from float32")
+    check(out["fused"]["grad_dtype"] == "bfloat16", f"the fused gradient is {out['fused']['grad_dtype']}")
     return out
 
 
@@ -1771,6 +1839,71 @@ def train_phase(rs, label: str, config_kwargs: dict, strategy_fn=None,
     return out, model
 
 
+TRAIN_OPS_CELL = "bert-large.mlm-s512"
+
+
+def train_ops_probe(label: str = "", steps: int = 8, top: int = 40) -> dict:
+    """The train step of :data:`TRAIN_OPS_CELL` (the cell's own model,
+    batch and optimizer, a fixed random batch) under the profiler for
+    ``steps`` steps: ms a step on the device's clock of every
+    INSTRUCTION by its own name, joined to the layer it came from (the
+    ``op_name`` the compiled HLO carries), beside the families
+    ``breakdown.device_ops`` of a cell's traced run sums them into. A
+    fusion is named by its LAST op: ``subtract_convert_fusion`` is every
+    weight-gradient product with its Adam update behind it, and read as
+    "the loss" it cost an issue its premise (PERF.md, PR 49). The
+    compiled HLO goes beside the JSON (``label`` in both names)."""
+    import gzip
+    import re
+
+    import jax
+    import numpy as np
+
+    from benchmark import spec, trace_reduce
+    from benchmark.drivers import train as driver
+    from flexflow_tpu import AdamOptimizer, LossType
+
+    cell = spec.load_cell(TRAIN_OPS_CELL)
+    batch, seq = int(cell.workload["deployment"]["per_chip_batch"]) * cell.chips, int(cell.traffic["params"]["seq"])
+    model, _ = driver.build_model(cell, SEED, batch, seq)
+    model.compile(optimizer=AdamOptimizer(alpha=float(cell.config["optimizer"]["alpha"])),
+                  loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY)
+    ex = model.executor
+    rs = np.random.RandomState(SEED)
+    x, y = (rs.randint(0, cell.config["vocab_size"], (batch, seq)).astype(np.int32) for _ in range(2))
+    keys = iter(jax.random.split(jax.random.key(SEED), 4 * steps))
+    step = lambda: ex.train_batch([x], y, next(keys))["loss"]  # noqa: E731
+    losses = [float(step()) for _ in range(steps)]
+    check(all(np.isfinite(l) for l in losses) and losses[-1] < losses[0], f"the loss did not fall: {losses}")
+    host_ms = _timed_ms(step, (), reps=steps)
+    trace = _device_trace(step, steps, "chip_smoke_train_ops")
+    hlo = ex._train_step.lower(ex.params, ex.opt_state, ex.state, ex._shard_inputs([x]), y, next(keys)).compile().as_text()
+    layer_of = {m.group(1): re.sub(r"^jit\(\w+\)/", "", m.group(2))
+                for m in re.finditer(r'^\s+(?:ROOT )?%([\w.\-]+) = .*?op_name="([^"]*)"', hlo, flags=re.M)}
+    by_name, by_family = {}, {}
+    for dev in trace.devices:
+        for (event, _, _), own in zip(dev.ops, trace_reduce.self_times(dev.ops)):
+            name = trace_reduce.op_name(event)
+            by_name[name] = by_name.get(name, 0.0) + own
+            family = trace_reduce.op_family(name)
+            by_family[family] = by_family.get(family, 0.0) + own
+    ms = lambda ns: round(ns * 1e-6 / steps / len(trace.devices), 4)  # noqa: E731
+    ranked = lambda d: sorted(d.items(), key=lambda kv: -kv[1])  # noqa: E731
+    out = {
+        "cell": TRAIN_OPS_CELL, "loss_form": getattr(ex, "loss_form", None), "losses": losses, "host_step_ms": host_ms,
+        "busy_ms_a_step": ms(sum(by_name.values())),
+        "families_ms": {k: ms(v) for k, v in ranked(by_family)[:top]},
+        "instructions_ms": [[k, ms(v), layer_of.get(k, "")] for k, v in ranked(by_name)[: 4 * top]],
+    }
+    log(f"train ops of {TRAIN_OPS_CELL} ({out['loss_form']}): host step {host_ms} ms, busy {out['busy_ms_a_step']} ms a step; "
+        f"families {dict(list(out['families_ms'].items())[:10])}")
+    log(f"largest instructions: {[row for row in out['instructions_ms'] if not row[0].startswith('flash_attention')][:12]}")
+    (REPO / "chiprun_out").mkdir(exist_ok=True)
+    with gzip.open(REPO / "chiprun_out" / f"chip_smoke_train_ops{label}.hlo.txt.gz", "wt") as f:
+        f.write(hlo)
+    return out
+
+
 def calibration_hit(kind: str) -> str:
     """The search's op-cost lookup for this chip must hit a table inside
     the checkout: nothing under $HOME decides a strategy."""
@@ -1948,6 +2081,10 @@ def main(argv=None) -> int:
                     help="the three training flash kernels alone at the bert-large cells' call: error and ms a call")
     ap.add_argument("--flash-sweep", action="store_true",
                     help="the same three kernels at every block pair of {128, 256, 512}: ms a call and compile seconds")
+    ap.add_argument("--loss-chain", action="store_true",
+                    help="the trainer's softmax + cross-entropy chain alone at the bert-large cells' logits: composed against fused, ms a call and error")
+    ap.add_argument("--train-ops", action="store_true",
+                    help="the bert-large one-chip cell's train step under the profiler: ms a step of every instruction by name and by the layer it came from")
     ap.add_argument("--release-probe", action="store_true",
                     help="the drop of a finished step's device arrays, timed beside a program in flight and beside woken stream threads")
     ap.add_argument("--dispatch-probe", action="store_true",
@@ -2012,6 +2149,11 @@ def main(argv=None) -> int:
             summary["kernels"] = {"flash": flash_kernels_check([(bq, bk) for bq in (128, 256, 512) for bk in (128, 256, 512)])}
         else:
             summary["kernels"] = {"flash": flash_kernels_check(), "flash_errors": flash_public_check(rs)}
+    elif args.loss_chain:
+        summary["loss_chain"] = loss_chain_check()
+    elif args.train_ops:
+        summary["tree"] = args.tree or "."
+        summary["train_ops"] = train_ops_probe("_" + pathlib.Path(args.tree).name.strip(".") if args.tree else "")
     elif args.release_probe:
         summary["release_probe"] = release_probe()
         (REPO / "chiprun_out" / "pr38").mkdir(parents=True, exist_ok=True)
@@ -2050,6 +2192,8 @@ def main(argv=None) -> int:
             else "chip_smoke_flash_sweep.json" if args.flash_sweep
             else "chip_smoke_flash" + ("_" + pathlib.Path(args.tree).name.strip(".") if args.tree else "") + ".json" if args.flash_kernels
             else "chip_smoke_experts.json" if args.expert_product is not None
+            else "chip_smoke_loss_chain.json" if args.loss_chain
+            else "chip_smoke_train_ops" + ("_" + pathlib.Path(args.tree).name.strip(".") if args.tree else "") + ".json" if args.train_ops
             else "chip_smoke_release.json" if args.release_probe else "chip_smoke_dispatch.json" if args.dispatch_probe
             else "chip_smoke.json")
     (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")

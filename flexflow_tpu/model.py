@@ -612,6 +612,10 @@ class FFModel:
             grad_accum_steps=self.config.grad_accum_steps,
         )
         self.executor.initialize(jax.random.key(self._seed))
+        print(
+            f"compiled: mesh {dict(zip(self.mesh.axis_names, self.mesh.devices.shape))}, "
+            f"loss {loss_type.value if loss_type else None} ({self.executor.loss_form})"
+        )
         return self
 
     def _default_output(self) -> Tensor:

@@ -534,8 +534,10 @@ class ProgramEntry:
 
 def _summarize(x) -> str:
     """Compact signature for one traced argument: ``dtype[shape]`` for
-    arrays, a leaf-count/element-count digest for pytrees, ``repr`` for
-    static scalars."""
+    arrays, a leaf-count/element-count digest for pytrees, a string as
+    it is, ``repr`` for other static scalars."""
+    if isinstance(x, str):
+        return x
     shape = getattr(x, "shape", None)
     if shape is not None:
         dt = getattr(x, "dtype", "?")
@@ -635,11 +637,13 @@ class ProgramRegistry:
             if entry is not None:
                 entry.compile_s = seconds
 
-    def instrument(self, name: str, fn: Callable) -> Callable:
+    def instrument(self, name: str, fn: Callable, **static: str) -> Callable:
         """Wrap ``fn`` for ``jax.jit`` so every trace self-registers
         (the wrapper body runs at trace time only — zero steady-state
         cost). Used for the executor's train/eval programs, where
-        arguments are anonymous pytrees. The wrapper takes the
+        arguments are anonymous pytrees. ``static`` names what the
+        program was built as beside its arguments (the train step's
+        ``loss_form``) and rides in its signature. The wrapper takes the
         program's own name (``executor[3].train_window[16]`` ->
         ``train_window_16``), which is what jit names the XLA module
         by: a device trace then shows ``jit_train_step``, not one
@@ -648,6 +652,7 @@ class ProgramRegistry:
         def traced(*args, **kwargs):
             sig = {f"arg{i}": a for i, a in enumerate(args)}
             sig.update(kwargs)
+            sig.update(static)
             self.note_trace(name, sig)
             return fn(*args, **kwargs)
 

@@ -160,6 +160,10 @@ class CompiledExecutor:
     # optimizer update — large effective batches without the activation
     # memory (beyond-parity; no reference analog)
     grad_accum_steps: int = 1
+    # which chain the train step's loss runs, decided from the graph in
+    # _build_steps: "fused_softmax_ce" (the loss taken from the last
+    # softmax's input) or "composed" (the loss of the model's output)
+    loss_form: str = "composed"
 
     params: Any = None
     opt_state: Any = None
@@ -341,14 +345,16 @@ class CompiledExecutor:
         return _put_global(arr, NamedSharding(self.mesh, to_partition_spec(spec)), full=True)
 
     # ----------------------------------------------------------- forward
-    def _forward_impl(self, params, state, inputs: Sequence[jax.Array], rng, training: bool):
+    def _forward_impl(self, params, state, inputs: Sequence[jax.Array], rng, training: bool,
+                      also: Sequence[Tuple[int, int]] = ()):
         """Interpret the PCG in topological order (the reference's
         FFModel::forward op loop, model.cc:2423 — but traced, not
-        dispatched per iteration)."""
+        dispatched per iteration). ``also`` names further values
+        (node guid, output idx) to return behind the model's outputs."""
         if self._pipeline_plan is not None:
-            return self._forward_pipelined(params, state, inputs, rng, training)
+            return self._forward_pipelined(params, state, inputs, rng, training, also)
         if self._remat_plan is not None and training:
-            return self._forward_remat(params, state, inputs, rng)
+            return self._forward_remat(params, state, inputs, rng, also)
         values: Dict[Tuple[int, int], jax.Array] = {}
         ctx = LowerCtx(
             training=training,
@@ -376,7 +382,7 @@ class CompiledExecutor:
             for i, o in enumerate(outs):
                 values[(node.guid, i)] = self._constrain_output(node.guid, i, o)
         new_state = _apply_state_updates(state, ctx.state_updates, self.graph)
-        outputs = [values[(g, i)] for g, i in self.outputs]
+        outputs = [values[k] for k in (*self.outputs, *also)]
         return outputs, new_state, ctx.aux_losses
 
     def _interpret_nodes(self, nodes, values, params, state, rng, training, constrain=True):
@@ -404,7 +410,7 @@ class CompiledExecutor:
                 )
         return ctx
 
-    def _forward_pipelined(self, params, state, inputs, rng, training):
+    def _forward_pipelined(self, params, state, inputs, rng, training, also=()):
         """GPipe execution of the repeated block stack (reference has no
         pipeline implementation — OP_PIPELINE is a placeholder,
         ffconst.h:160; this is the TPU-native schedule from
@@ -628,10 +634,10 @@ class CompiledExecutor:
         updates = dict(pre_ctx.state_updates)
         updates.update(post_ctx.state_updates)
         new_state = _apply_state_updates(state, updates, self.graph)
-        outputs = [values[(g, i)] for g, i in self.outputs]
+        outputs = [values[k] for k in (*self.outputs, *also)]
         return outputs, new_state, aux
 
-    def _forward_remat(self, params, state, inputs, rng):
+    def _forward_remat(self, params, state, inputs, rng, also=()):
         """Plain interpretation with each repeated block wrapped in
         jax.checkpoint: the backward pass recomputes block activations
         instead of keeping them live — the TPU-native HBM/FLOPs trade
@@ -697,7 +703,7 @@ class CompiledExecutor:
         aux.extend(post_ctx.aux_losses)
         updates.update(post_ctx.state_updates)
         new_state = _apply_state_updates(state, updates, self.graph)
-        outputs = [values[(g, i)] for g, i in self.outputs]
+        outputs = [values[k] for k in (*self.outputs, *also)]
         return outputs, new_state, aux
 
     def _constrain_output(self, guid: int, idx: int, x: jax.Array) -> jax.Array:
@@ -720,9 +726,38 @@ class CompiledExecutor:
         return jax.lax.with_sharding_constraint(x, NamedSharding(self.mesh, to_partition_spec(spec)))
 
     # -------------------------------------------------------------- steps
+    def _softmax_loss_logits(self) -> Optional[Tuple[int, int]]:
+        """The value (node guid, output idx) that a cross-entropy loss
+        may be taken from in place of the model's last output: the input
+        of the node that produces it, when that node is a softmax over
+        the last axis whose output nothing else reads. None otherwise."""
+        if self.loss_type not in (
+            LossType.SPARSE_CATEGORICAL_CROSSENTROPY, LossType.CATEGORICAL_CROSSENTROPY
+        ):
+            return None
+        guid, _ = self.outputs[-1]
+        node = self.graph.nodes[guid]
+        if node.op_type != OpType.SOFTMAX or self.graph.out_edges(node):
+            return None
+        rank = len(infer_all_specs(self.graph)[guid][0].shape)
+        if node.params.axis not in (-1, rank - 1):
+            return None
+        # under a block plan only the nodes behind the blocks leave
+        # their inputs among the top-level values
+        if self._pipeline_plan is not None and node not in self._pipeline_plan.post:
+            return None
+        if self._remat_plan is not None and node not in self._remat_plan[2]:
+            return None
+        (edge,) = self.graph.in_edges(node)
+        return edge.src, edge.src_idx
+
     def _build_steps(self):
         loss_fn = losses.get_loss_fn(self.loss_type) if self.loss_type else None
         metric_types = self.metric_types
+        logits_at = self._softmax_loss_logits()
+        loss_logits = () if logits_at is None else (logits_at,)
+        self.loss_form = "fused_softmax_ce" if loss_logits else "composed"
+        sparse_labels = self.loss_type == LossType.SPARSE_CATEGORICAL_CROSSENTROPY
 
         def forward(params, state, inputs, rng):
             outs, _, _ = self._forward_impl(params, state, inputs, rng, training=False)
@@ -734,10 +769,18 @@ class CompiledExecutor:
 
         def train_step(params, opt_state, state, inputs, label, rng):
             def objective(p, st, ins, lab, r):
-                outs, new_state, aux = self._forward_impl(p, st, ins, r, training=True)
-                final = outs[-1]
+                outs, new_state, aux = self._forward_impl(
+                    p, st, ins, r, training=True, also=loss_logits
+                )
+                final = outs[len(self.outputs) - 1]
                 with jax.named_scope("loss"):
-                    loss = loss_fn(final, lab)
+                    # a graph that ends in a softmax only the loss reads:
+                    # the loss takes its INPUT, and the model's output
+                    # stays the probabilities for the metrics
+                    if loss_logits:
+                        loss = losses.softmax_crossentropy(outs[-1], lab, sparse_labels)
+                    else:
+                        loss = loss_fn(final, lab)
                 # aux is a Python LIST of scalar aux losses — pytree
                 # structure iteration at trace time, not a traced array
                 for a in aux:  # flexlint: disable=jit-discipline
@@ -853,7 +896,9 @@ class CompiledExecutor:
         if self.optimizer is not None:
             self._train_step_fn = train_step
             self._train_step = jax.jit(
-                GLOBAL_PROGRAMS.instrument(f"{self._prog_ns}.train_step", train_step),
+                GLOBAL_PROGRAMS.instrument(
+                    f"{self._prog_ns}.train_step", train_step, loss_form=self.loss_form
+                ),
                 donate_argnums=(0, 1, 2),
             )
             self._multi_step_cache = {}
@@ -1015,7 +1060,8 @@ class CompiledExecutor:
         name = (f"{self._prog_ns}.train_window[{w}]" if per_step_xs
                 else f"{self._prog_ns}.train_repeat[{w}]")
         jitted = jax.jit(
-            GLOBAL_PROGRAMS.instrument(name, program), donate_argnums=(0, 1, 2)
+            GLOBAL_PROGRAMS.instrument(name, program, loss_form=self.loss_form),
+            donate_argnums=(0, 1, 2),
         )
         cache[w] = jitted
         return jitted

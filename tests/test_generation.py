@@ -553,6 +553,56 @@ def test_the_grouped_expert_layer_compiles_for_the_v5e_at_the_cells_buckets(rows
         assert temporaries < _compile_uncached(jax.jit(dense), *args).memory_analysis().temp_size_in_bytes
 
 
+@pytest.mark.parametrize("tail", [False, True], ids=["fused", "composed"])
+def test_the_train_steps_vocabulary_chain_on_the_v5e(tail, one_v5e_chip):
+    """The trainer's step over a small ``build_transformer`` in bfloat16
+    (this file holds every compile for a described chip), compiled by the
+    TPU's own compiler: with the loss taken from the softmax's input
+    (``loss_form`` ``fused_softmax_ce``) the program scatters nothing
+    into the logits' shape and its entry computation holds NO float32
+    ``[tokens, vocabulary]`` result: the log-sum-exp and the gradient
+    stay inside fusions. The composed chain (forced by an identity that
+    reads the softmax) scatters one value a row into such an array and
+    writes the float32 log-probabilities out. Nothing runs."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from flexflow_tpu import AdamOptimizer, DataType, FFConfig, LossType
+    from flexflow_tpu.models import build_transformer
+    from flexflow_tpu.parallel.strategy import data_parallel_strategy
+
+    b, s, v = 4, 128, 250
+    cfg = TransformerConfig(num_layers=1, hidden_size=128, num_heads=2, ff_size=256, seq_length=s, vocab_size=v,
+                            dtype=DataType.BFLOAT16)
+    model = build_transformer(FFConfig(batch_size=b), cfg)
+    if tail:
+        model.identity(model.get_output(), name="tail")
+    model.compile(optimizer=AdamOptimizer(alpha=1e-4), loss_type=LossType.SPARSE_CATEGORICAL_CROSSENTROPY,
+                  strategy=data_parallel_strategy(model.graph, 1))
+    ex = model.executor
+    assert ex.loss_form == ("composed" if tail else "fused_softmax_ce")
+    (chip,) = one_v5e_chip.device_set
+    ex.mesh, ex.backend = Mesh(np.array([chip]).reshape(model.mesh.devices.shape), model.mesh.axis_names), "tpu"
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(ex.mesh, PartitionSpec()))  # noqa: E731
+    params, opt, state = (jax.tree.map(on_chip, t) for t in (ex.params, ex.opt_state, ex.state))
+    tokens = on_chip(jax.ShapeDtypeStruct((b, s), jnp.int32))
+    lowered = jax.jit(ex._train_step_fn).lower(params, opt, state, (tokens,), tokens, on_chip(jax.eval_shape(lambda: jax.random.key(0))))
+    scattered = re.findall(r"stablehlo\.scatter.*?-> tensor<([^>]*)>", lowered.as_text(), flags=re.S)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = lowered.compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+    entry = text[text.index("\nENTRY "):]
+    float32_logits = re.findall(rf"^\s+(?:ROOT )?%?[\w.\-]+ = f32\[{b},{s},{v}\]", entry[: entry.index("\n}")], flags=re.M)
+    if tail:
+        assert f"{b}x{s}x{v}xf32" in scattered and float32_logits
+    else:
+        assert f"{b}x{s}x{v}xf32" not in scattered and float32_logits == []
+
+
 def test_packed_cache_through_every_engine_program():
     """An engine whose heads share cache rows (four heads of 32 in one
     128-lane row) against the stateless forward: prefill, decode, a
