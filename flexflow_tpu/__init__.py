@@ -71,3 +71,10 @@ def __getattr__(name):
 
 def __dir__():
     return sorted(set(list(globals()) + __all__))
+
+
+# ``ff.startup.import``: the process's start to here (the interpreter,
+# JAX's import where it came first, this package's)
+from .obs.steptrace import GLOBAL_STARTUP as _startup  # noqa: E402
+
+_startup.mark_import()

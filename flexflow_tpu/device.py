@@ -13,6 +13,8 @@ import pathlib
 
 import jax
 
+from .obs.steptrace import GLOBAL_STARTUP
+
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 # fixed for a checkout: a directory that moves between runs never hits
 _DEFAULT_COMPILE_CACHE = pathlib.Path(__file__).resolve().parent.parent / ".jax_cache"
@@ -25,8 +27,11 @@ def on_tpu() -> bool:
 def require_tpu() -> jax.Device:
     """The first device, which must be a TPU. JAX falls back to the CPU
     with a warning when libtpu finds no chip, so the platform is checked
-    rather than inferred from ``JAX_PLATFORMS`` being unset."""
-    dev = jax.devices()[0]
+    rather than inferred from ``JAX_PLATFORMS`` being unset. A
+    launcher's first call is the process's first listing of devices, so
+    the TPU runtime's own start is the span ``ff.startup.backend``."""
+    with GLOBAL_STARTUP.span("backend"):
+        dev = jax.devices()[0]
     if dev.platform != "tpu":
         raise RuntimeError(
             f"no TPU: JAX's first device is platform={dev.platform!r} "

@@ -74,7 +74,7 @@ from ..core.types import DataType
 from ..device import on_tpu
 from ..models.transformer import TransformerConfig
 from ..obs.capacity import ProgramRegistry, ServingFlops
-from ..obs.steptrace import phase
+from ..obs.steptrace import GLOBAL_STARTUP, phase
 from ..obs.truth import PredictionLedger
 from ..ops.attention import STREAM_SCORE_BYTES, latent_call_lowering, paged_call_lowering, prefill_call_lowering
 from ..ops import ssm as ssm_ops
@@ -516,6 +516,8 @@ class GenerationEngine:
     continuous-batching scheduler drives it; ``generate`` is a
     convenience wrapper that spins up a private scheduler."""
 
+    # pools, staging, tables, the kernels chosen: ff.startup.engine_build
+    @GLOBAL_STARTUP.spanned("engine_build")
     def __init__(
         self,
         params: DecoderParams,

@@ -121,7 +121,7 @@ from ..obs import (
     TraceRing,
     next_request_id,
 )
-from ..obs.steptrace import DEVICE_PHASES, phase
+from ..obs.steptrace import DEVICE_PHASES, GLOBAL_STARTUP, phase
 from ..runtime import faults
 from ..serving.overload import OverloadConfig, OverloadController, Priority
 from ..serving.resilience import (
@@ -742,6 +742,8 @@ class ContinuousBatchingScheduler:
             engine.prefix_cache.observe = self.stats.observe
             self.stats.add_section("loop", self._loop_section)
             self.stats.add_section("uploads", engine.upload_stats)
+            # where the process's seconds went before it served
+            self.stats.add_section("startup", GLOBAL_STARTUP.snapshot)
         engine.register_stats(self.stats)
         self.spec_stats = SpeculationStats()
         self.spec_stats.register_gauges(self.stats)

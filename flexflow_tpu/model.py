@@ -30,6 +30,7 @@ from .core.types import (
     OpType,
     PoolType,
 )
+from .obs.steptrace import GLOBAL_STARTUP
 from .ops import io_ops, linear as linear_mod, conv as conv_mod
 from .ops.attention import MultiHeadAttentionParams
 from .ops.batch_matmul import BatchMatmulParams
@@ -567,7 +568,8 @@ class FFModel:
         else:
             from .search.unity import unity_optimize
 
-            self.strategy, self._search_result = unity_optimize(self.graph, self.config)
+            with GLOBAL_STARTUP.span("search"):
+                self.strategy, self._search_result = unity_optimize(self.graph, self.config)
             # adopt the rewritten PCG (reference: convert_graph_to_operators
             # model.cc:2856-2858); compute-node guids survive rewrites, so
             # frontend Tensor handles remain valid
@@ -596,25 +598,32 @@ class FFModel:
         if self.config.export_strategy_computation_graph_file:
             with open(self.config.export_strategy_computation_graph_file, "w") as f:
                 f.write(self.graph.to_dot())
-        self.mesh = build_mesh(self.strategy.axis_sizes)
-        self.executor = CompiledExecutor(
-            graph=self.graph,
-            strategy=self.strategy,
-            mesh=self.mesh,
-            loss_type=loss_type,
-            metric_types=tuple(metrics),
-            optimizer=optimizer if comp_mode == CompMode.TRAINING else None,
-            outputs=[(t.node.guid, t.idx) for t in self._outputs],
-            backend=jax.default_backend(),
-            comp_mode=comp_mode,
-            remat_blocks=self.config.remat_blocks,
-            zero_optimizer=self.config.zero_optimizer,
-            grad_accum_steps=self.config.grad_accum_steps,
-        )
-        self.executor.initialize(jax.random.key(self._seed))
+        with GLOBAL_STARTUP.span("mesh"):
+            self.mesh = build_mesh(self.strategy.axis_sizes)
+        with GLOBAL_STARTUP.span("executor"):
+            self.executor = CompiledExecutor(
+                graph=self.graph,
+                strategy=self.strategy,
+                mesh=self.mesh,
+                loss_type=loss_type,
+                metric_types=tuple(metrics),
+                optimizer=optimizer if comp_mode == CompMode.TRAINING else None,
+                outputs=[(t.node.guid, t.idx) for t in self._outputs],
+                backend=jax.default_backend(),
+                comp_mode=comp_mode,
+                remat_blocks=self.config.remat_blocks,
+                zero_optimizer=self.config.zero_optimizer,
+                grad_accum_steps=self.config.grad_accum_steps,
+            )
+        # host seconds: the initialisers' programs compile (or load) and
+        # are dispatched here; the device may still be filling the
+        # parameters when the span closes
+        with GLOBAL_STARTUP.span("param_init"):
+            self.executor.initialize(jax.random.key(self._seed))
         print(
             f"compiled: mesh {dict(zip(self.mesh.axis_names, self.mesh.devices.shape))}, "
-            f"loss {loss_type.value if loss_type else None} ({self.executor.loss_form})"
+            f"loss {loss_type.value if loss_type else None} ({self.executor.loss_form}); "
+            f"start-up seconds so far: {GLOBAL_STARTUP.summary()}"
         )
         return self
 

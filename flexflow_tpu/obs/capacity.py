@@ -31,7 +31,13 @@ this module answers the *resource* dimension with three pieces:
 * :class:`ProgramRegistry` — every traced jit program (engine prefill
   buckets, decode, verify, plus the executor's train/eval programs via
   :data:`GLOBAL_PROGRAMS`) with its static argument signature, trace
-  count, and compile wall time. A steady-state retrace diffs the new
+  count, and the wall of its first call split by JAX's own compile
+  events into trace, lowering, backend compile or cache load, and the
+  rest (argument preparation and the first run). The same records are
+  the ``programs`` of the process's start-up account
+  (:data:`~flexflow_tpu.obs.steptrace.GLOBAL_STARTUP`), where programs
+  no registry owns appear under their function's name. A steady-state
+  retrace diffs the new
   abstract arguments against the registered signature and produces a
   human-readable *blame* string ("decode retraced: tokens int32[4] ->
   int32[5]") — attached to the flight recorder and served on
@@ -40,7 +46,9 @@ this module answers the *resource* dimension with three pieces:
 
 Everything here is host-side Python arithmetic: no device calls, no
 extra dispatches, and the per-step cost is a handful of integer adds
-(no reader measures it alone: every cell runs with it on).
+(no reader measures it alone: every cell runs with it on). The compile
+listeners fire on JAX's compile events only, ``note_trace`` at trace
+time only: a warm step runs neither.
 """
 from __future__ import annotations
 
@@ -51,8 +59,11 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence
 
+import jax.monitoring
+
 from ..core.types import DataType
 from ..parallel.machine import TPUChipSpec
+from .steptrace import GLOBAL_STARTUP, PROGRAM_PARTS, both_hit
 
 # --------------------------------------------------------------------------
 # KV-cache block telemetry
@@ -525,11 +536,138 @@ class ServingFlops:
 
 @dataclasses.dataclass
 class ProgramEntry:
+    """``compile_s`` is the lump: the wall of the host call that traced
+    the program (``set_compile_time``). ``cycle`` is the newest record
+    of its trace -> lowering -> compile or cache load from JAX's events
+    (``trace_s``, ``lower_s``, ``compile_s``: the backend's, ``cache_load_s``,
+    ``cache_hit``, and ``run_s`` = the lump less those four), shared
+    with the start-up account; None until the program has compiled."""
+
     name: str
     signature: Dict[str, str]
     traces: int = 1
     compile_s: Optional[float] = None
     last_blame: Optional[str] = None
+    cycle: Optional[Dict] = None
+    retrace: Optional[Dict] = None  # the newest record in ``retraces``, until its call's wall is stamped
+
+    def split(self) -> Dict:
+        """The cycle's parts under the names a registry reports them
+        by (``compile_s`` stays the lump there)."""
+        c = self.cycle or {}
+        return {
+            "trace_s": c.get("trace_s"), "lower_s": c.get("lower_s"), "compile_s_backend": c.get("compile_s"),
+            "cache_load_s": c.get("cache_load_s"), "cache_hit": c.get("cache_hit"), "run_s": c.get("run_s"),
+        }
+
+
+# --- JAX's compile events, by thread -------------------------------------
+# JAX times three sections of a program's first call and says so through
+# jax.monitoring: a scalar event when a section opens and a duration
+# event when it closes, on the thread that does the work. The trace of a
+# jit that calls jits (or runs an eager op, which compiles) opens
+# sections INSIDE its own: only a section at depth one is a program's,
+# and of those inside it only the backend's seconds are taken out of it
+# (a compile is a compile, wherever it ran).
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"  # compile, or the persistent cache's load
+_SECTIONS = (_TRACE, _LOWER, _BACKEND)
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "requests",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",  # fired when the compiled program is written
+}
+
+
+class _CompilingThread(threading.local):
+    depth = 0  # sections open on this thread
+    noted = None  # (registry, name): the outermost note_trace inside the open top-level trace
+    owner = None  # ... of the cycle being built
+    cycle = None  # the record of the program this thread is compiling
+    module = ""  # the open backend section's program (a backend section holds no other)
+    hit = None  # what the cache said inside it: None (not asked), False, True
+    inner = (0.0, 0.0, None)  # compile_s, cache_load_s, hit of the backend sections inside the open top-level one
+
+
+_COMPILING = _CompilingThread()
+
+
+def _close_cycle(t: _CompilingThread) -> None:
+    rec, owner = t.cycle, t.owner
+    t.cycle = t.owner = None
+    if rec is None:
+        return
+    if rec["end_s"] is None:  # no compile followed (an executable the process already held)
+        rec["end_s"] = rec["at_s"] + sum(rec[k] for k in PROGRAM_PARTS)
+    if owner is not None:
+        rec["name"] = owner[1]
+        owner[0]._credit(owner[1], rec)
+    GLOBAL_STARTUP.add_program(rec)
+
+
+def _on_section_open(event: str, _value: float, **kw) -> None:
+    if event not in _SECTIONS:
+        return
+    t = _COMPILING
+    t.depth += 1
+    if t.depth == 1:
+        t.inner = (0.0, 0.0, None)
+        if event == _TRACE:
+            t.noted = None
+    if event == _BACKEND:
+        t.module, t.hit = str(kw.get("fun_name", "")), None
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    kind = _CACHE_EVENTS.get(event)
+    if kind is None:
+        return
+    t = _COMPILING
+    GLOBAL_STARTUP.note_cache(kind, t.module)
+    if kind != "misses":
+        t.hit = kind == "hits"
+
+
+def _on_section_close(event: str, seconds: float, **kw) -> None:
+    if event not in _SECTIONS:
+        return
+    t = _COMPILING
+    t.depth = max(0, t.depth - 1)
+    compile_s, load_s, hit = t.inner
+    if t.depth:
+        if event == _BACKEND:  # inside another program's section
+            t.inner = (compile_s + (0.0 if t.hit else seconds), load_s + (seconds if t.hit else 0.0), both_hit(hit, t.hit))
+            t.module = ""
+        return
+    now = GLOBAL_STARTUP.now()
+    if event == _TRACE:
+        _close_cycle(t)  # one still open: nothing compiled after its trace
+    if t.cycle is None:
+        t.owner, t.noted = t.noted, None
+        t.cycle = {
+            "name": str(kw.get("fun_name", "?")).removeprefix("jit_"), "at_s": now - seconds, "end_s": None,
+            **{k: 0.0 for k in PROGRAM_PARTS}, "cache_hit": None, "lump_s": None, "run_s": None,
+        }
+    rec = t.cycle
+    if event == _BACKEND:
+        # a hit's whole section (the key's hashing, the read, the load)
+        # is the cache's; anything else compiled
+        rec["cache_load_s" if t.hit else "compile_s"] += seconds
+        rec["cache_hit"], rec["end_s"] = both_hit(rec["cache_hit"], t.hit), now
+        t.module = ""
+        _close_cycle(t)
+    else:
+        rec["trace_s" if event == _TRACE else "lower_s"] += seconds - compile_s - load_s
+        rec["compile_s"] += compile_s
+        rec["cache_load_s"] += load_s
+        rec["cache_hit"] = both_hit(rec["cache_hit"], hit)
+
+
+# once a process (this module is imported once)
+jax.monitoring.register_scalar_listener(_on_section_open)
+jax.monitoring.register_event_listener(_on_cache_event)
+jax.monitoring.register_event_duration_secs_listener(_on_section_close)
 
 
 def _summarize(x) -> str:
@@ -586,6 +724,9 @@ class ProgramRegistry:
         self._lock = threading.Lock()
         self.entries: Dict[str, ProgramEntry] = {}  # guarded-by: _lock
         self.retraces: deque = deque(maxlen=max_retraces)  # guarded-by: _lock
+        # programs that compiled and whose call's wall is not stamped yet
+        # (written under the lock; a caller tests membership without it)
+        self.unstamped: set = set()
         self.on_retrace: Optional[Callable[[str, str], None]] = None
 
     def note_trace(self, name: str, args: Dict[str, object]) -> Optional[str]:
@@ -596,6 +737,7 @@ class ProgramRegistry:
             entry = self.entries.get(name)
             if entry is None:
                 self.entries[name] = ProgramEntry(name=name, signature=sig)
+                self._noted(name)
                 return None
             entry.traces += 1
             diffs = []
@@ -615,13 +757,15 @@ class ProgramRegistry:
                 )
             entry.signature = sig
             entry.last_blame = blame
-            self.retraces.append({
+            entry.retrace = {
                 "t": self._clock(),
                 "program": name,
                 "blame": blame,
                 "traces": entry.traces,
-            })
+            }
+            self.retraces.append(entry.retrace)
             cb = self.on_retrace
+        self._noted(name)
         if cb is not None:
             try:
                 cb(name, blame)
@@ -629,13 +773,53 @@ class ProgramRegistry:
                 pass  # observability must never break tracing
         return blame
 
-    def set_compile_time(self, name: str, seconds: float) -> None:
-        """Stamp the wall time of the host call that triggered the
-        program's (re)trace — trace + lower + compile + first run."""
+    def _noted(self, name: str) -> None:
+        """This thread is inside ``name``'s traced body: the compile
+        events that follow on it are this program's. The OUTERMOST
+        program of a trace keeps them (a jit that calls an instrumented
+        jit holds the inner one's trace in its own)."""
+        t = _COMPILING
+        if t.depth and t.noted is None:
+            t.noted = (self, name)
+
+    def _credit(self, name: str, cycle: Dict) -> None:
+        """``cycle`` is what JAX's events said of ``name``'s compile; a
+        retrace's record carries the same split beside its blame."""
         with self._lock:
             entry = self.entries.get(name)
-            if entry is not None:
-                entry.compile_s = seconds
+            if entry is None:
+                return
+            entry.cycle = cycle
+            self.unstamped.add(name)
+            if entry.retrace is not None:
+                entry.retrace.update(entry.split())
+
+    def set_compile_time(self, name: str, seconds: float) -> None:
+        """Stamp the wall time of the host call that triggered the
+        program's (re)trace: the lump, ``compile_s``. Its named parts
+        are the cycle's ``trace_s + lower_s + compile_s + cache_load_s``
+        (JAX's events, on this thread, since ``note_trace``); what is
+        left, ``run_s``, is the call's argument preparation, its first
+        run and its readback. Ends the attribution: an event that fires
+        on this thread afterwards is another program's."""
+        t = _COMPILING
+        if t.owner == (self, name):
+            _close_cycle(t)
+        t.noted = None
+        with self._lock:
+            entry = self.entries.get(name)
+            if entry is None:
+                return
+            entry.compile_s = seconds
+            self.unstamped.discard(name)
+            cycle = entry.cycle
+            if cycle is not None and cycle["lump_s"] is None:
+                cycle["lump_s"] = seconds
+                cycle["run_s"] = seconds - sum(cycle[k] for k in PROGRAM_PARTS)
+                cycle["end_s"] = max(cycle["end_s"], GLOBAL_STARTUP.now())
+            if entry.retrace is not None:
+                entry.retrace.update(entry.split(), compile_s=seconds)
+                entry.retrace = None
 
     def instrument(self, name: str, fn: Callable, **static: str) -> Callable:
         """Wrap ``fn`` for ``jax.jit`` so every trace self-registers
@@ -672,6 +856,7 @@ class ProgramRegistry:
             for name in [n for n in self.entries
                          if n == prefix or n.startswith(dot)]:
                 del self.entries[name]
+                self.unstamped.discard(name)
             kept = [r for r in self.retraces
                     if not (r["program"] == prefix or r["program"].startswith(dot))]
             self.retraces.clear()
@@ -684,6 +869,7 @@ class ProgramRegistry:
                     "name": e.name,
                     "traces": e.traces,
                     "compile_s": e.compile_s,
+                    **e.split(),
                     "signature": dict(e.signature),
                     "last_blame": e.last_blame,
                 }
@@ -692,7 +878,7 @@ class ProgramRegistry:
 
     def recent_retraces(self) -> List[Dict]:
         with self._lock:
-            return list(self.retraces)
+            return [dict(r) for r in self.retraces]
 
     def trace_count(self, name: str) -> int:
         """Traces recorded for one program (0 if never traced) — callers

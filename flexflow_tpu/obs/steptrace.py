@@ -58,6 +58,15 @@ from. The scheduler, the engine, the prefix cache, the HTTP handler,
 the executor and the data loader all open their spans here; the span
 catalogue is in README "Step anatomy".
 
+3. **Where do the seconds before the first step go?** Start-up has the
+   same kind of account, once a process: :data:`GLOBAL_STARTUP`
+   (:class:`StartupAccount`) keeps every closed ``ff.startup.*`` span
+   (``import``, ``backend``, ``search`` with its children, ``mesh``,
+   ``executor``, ``param_init``, ``engine_build``) as an offset from
+   the process's start, and every jit program's first call split by
+   JAX's own compile events (``obs/capacity.py`` feeds those). It is
+   the ``startup`` section of ``/v2/stats``.
+
 Clock discipline (the PR 6 dual-clock decision): span stamps are
 ``time.perf_counter`` values — physical profiling data even in
 virtual-clock tests. :class:`phase` is the one place they are read,
@@ -82,12 +91,15 @@ method a cheap no-op (mirrors ``observability=False``).
 """
 from __future__ import annotations
 
+import functools
 import math
+import os
 import threading
+import time
 from bisect import bisect_left
 from collections import deque
 from time import perf_counter, thread_time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from jax.profiler import TraceAnnotation
 
@@ -515,3 +527,243 @@ class StepAnatomy:
             "step_anatomy_steps_observed",
             lambda: self.steps_observed() if self.enabled else None,
         )
+
+
+# --------------------------------------------------------------------------
+# The start-up account
+# --------------------------------------------------------------------------
+
+# seconds of a program's first call that JAX's compile events name
+PROGRAM_PARTS = ("trace_s", "lower_s", "compile_s", "cache_load_s")
+
+
+def _process_start() -> Tuple[float, str]:
+    """The process's start as a ``perf_counter`` stamp, and what that
+    origin is. ``/proc/self/stat`` counts the start in clock ticks
+    since boot, which is ``time.monotonic``'s zero on Linux, and
+    ``perf_counter`` reads the same CLOCK_MONOTONIC there: the two are
+    read together once and the difference (zero on Linux) carried over.
+    The rule is ``benchmark/run.py::process_start_monotonic``'s, so the
+    program's zero and the harness's are one instant; where ``/proc``
+    has nothing believable the origin is this module's import."""
+    now = perf_counter()
+    mono = time.monotonic()  # flexlint: disable=clock-discipline
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        start = ticks / os.sysconf("SC_CLK_TCK")
+        if 0.0 <= mono - start < 60.0:
+            return start + (now - mono), "process_start"
+    except (OSError, ValueError, IndexError):
+        pass
+    return now, "import"
+
+
+def both_hit(so_far: Optional[bool], new: Optional[bool]) -> Optional[bool]:
+    """A program hit the persistent cache if every compile it asked the
+    cache for did (None: it never asked)."""
+    if so_far is None or new is None:
+        return new if so_far is None else so_far
+    return so_far and new
+
+
+def _union_s(intervals: List[Tuple[float, float]]) -> float:
+    covered, edge = 0.0, -math.inf
+    for a, b in sorted(intervals):
+        a = max(a, edge)
+        if b > a:
+            covered += b - a
+            edge = b
+    return covered
+
+
+class _OpenSpan:
+    """What ``phase(into=)`` appends a start-up span's stamps to."""
+
+    __slots__ = ("account", "name", "parent", "args")
+
+    def __init__(self, account: "StartupAccount", name: str, parent: Optional[str], args: Dict):
+        self.account, self.name, self.parent, self.args = account, name, parent, args
+
+    def append(self, span: Span) -> None:
+        stack = self.account._stack()
+        if self in stack:  # an exception may have skipped a child's exit
+            del stack[stack.index(self):]
+        self.account.note_span(self.name, span[1], span[2], self.parent, self.args)
+
+
+class StartupAccount:
+    """Where a process's seconds go before its first warm step.
+
+    *Spans.* ``with account.span("search.calibrate"):`` is
+    ``phase("startup.search.calibrate")`` (hence
+    ``ff.startup.search.calibrate`` in a profiler trace) whose stamps
+    come here on exit: kept as ``(name, parent, start_offset_s, seconds,
+    args)`` with the offset from the account's origin (the process's
+    start), in a list bounded at ``max_spans`` (what no longer fits
+    still counts in the totals). The parent is the span open on the
+    same thread when this one was opened; a parent's ``self_s`` is its
+    seconds less its children's.
+
+    *Programs.* One record a jit program's trace -> lowering -> compile
+    or cache load, made by ``obs/capacity.py`` from JAX's own events
+    (see there): ``name``, ``at_s`` / ``end_s`` (offsets), the
+    :data:`PROGRAM_PARTS`, ``cache_hit``, and ``lump_s`` / ``run_s``
+    once :meth:`ProgramRegistry.set_compile_time` has stamped the wall
+    of the call that traced it. The cache's answers are kept beside
+    them with the module each was about.
+
+    Written a few dozen to a few hundred times a process, at span exits
+    and compile events only; nothing here runs on a warm step."""
+
+    def __init__(self, origin: Optional[float] = None, max_spans: int = 512, max_programs: int = 2048):
+        if origin is None:
+            self._origin, self.origin = _process_start()
+        else:
+            self._origin, self.origin = float(origin), "given"
+        self.max_spans, self.max_programs = max_spans, max_programs
+        self._lock = threading.Lock()
+        self._spans: List[Tuple] = []  # guarded-by: _lock
+        self._overflow: Dict[str, List[float]] = {}  # guarded-by: _lock; name -> [count, total_s, children_s]
+        self._programs: List[Dict] = []  # guarded-by: _lock
+        self.programs_dropped = 0  # guarded-by: _lock
+        self._cache: List[Tuple[float, str, str]] = []  # guarded-by: _lock; (at_s, kind, module)
+        self._open = threading.local()
+
+    # ------------------------------------------------------------- clock
+    def now(self) -> float:
+        """Seconds since the origin."""
+        return perf_counter() - self._origin
+
+    def _stack(self) -> List[_OpenSpan]:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    # ------------------------------------------------------------- spans
+    def span(self, name: str, **args) -> phase:
+        """Open ``ff.startup.<name>``; use as ``with account.span(...)``."""
+        stack = self._stack()
+        sink = _OpenSpan(self, name, stack[-1].name if stack else None, dict(args))
+        stack.append(sink)
+        return phase("startup." + name, into=sink, **args)
+
+    def annotate(self, **args) -> None:
+        """Arguments known only inside a span (which table resolved the
+        calibration, how many graphs the search costed) onto the span
+        this thread has open; nothing where none is."""
+        stack = self._stack()
+        if stack:
+            stack[-1].args.update(args)
+
+    def spanned(self, name: str) -> Callable:
+        """Decorator: the whole call is one ``span(name)``."""
+        def wrap(fn):
+            @functools.wraps(fn)
+            def run(*a, **kw):
+                with self.span(name):
+                    return fn(*a, **kw)
+            return run
+        return wrap
+
+    def note_span(self, name: str, t0: float, t1: float, parent: Optional[str] = None, args: Optional[Dict] = None) -> None:
+        """One closed span from its ``perf_counter`` stamps."""
+        rec = (name, parent, t0 - self._origin, max(0.0, t1 - t0), args or {})
+        with self._lock:
+            if len(self._spans) < self.max_spans:
+                self._spans.append(rec)
+            else:
+                self._fold(self._overflow, rec)
+
+    def mark_import(self) -> None:
+        """``startup.import``: the origin to now, once (the package's
+        ``__init__`` calls this on its last line)."""
+        with self._lock:
+            if any(s[0] == "import" for s in self._spans):
+                return
+        self.note_span("import", self._origin, perf_counter())
+
+    @staticmethod
+    def _fold(totals: Dict[str, List[float]], rec: Tuple) -> None:
+        name, parent, _, seconds, _ = rec
+        mine = totals.setdefault(name, [0, 0.0, 0.0])
+        mine[0] += 1
+        mine[1] += seconds
+        if parent is not None:
+            totals.setdefault(parent, [0, 0.0, 0.0])[2] += seconds
+
+    # ---------------------------------------------------------- programs
+    def add_program(self, record: Dict) -> None:
+        with self._lock:
+            if len(self._programs) < self.max_programs:
+                self._programs.append(record)
+            else:
+                self.programs_dropped += 1
+
+    def note_cache(self, kind: str, module: str) -> None:
+        """The persistent cache was asked (``requests``) or answered
+        (``hits`` / ``misses``) about ``module``."""
+        at = self.now()
+        with self._lock:
+            if len(self._cache) < 4 * self.max_programs:
+                self._cache.append((at, kind, module))
+
+    # ---------------------------------------------------------- reporting
+    def snapshot(self, until_s: Optional[float] = None) -> Dict:
+        """The ``startup`` section of ``/v2/stats``. ``until_s`` keeps
+        what had ENDED (a span) or begun (a program, a cache answer) by
+        that offset: a reader in the trainer's process cuts at the
+        window's opening, so what ran after it is out."""
+        cut = math.inf if until_s is None else float(until_s)
+        with self._lock:
+            spans = [s for s in self._spans if s[2] + s[3] <= cut]
+            totals = {k: list(v) for k, v in self._overflow.items()}
+            programs = [dict(p) for p in self._programs if p["at_s"] < cut]
+            cache = [c for c in self._cache if c[0] < cut]
+            dropped = self.programs_dropped
+        for rec in spans:
+            self._fold(totals, rec)
+        by_name: Dict[str, Dict] = {}
+        intervals = [(s[2], s[2] + s[3]) for s in spans if s[1] is None]
+        for p in programs:
+            intervals.append((p["at_s"], min(p["end_s"], cut)))
+            agg = by_name.get(p["name"])
+            if agg is None:
+                agg = by_name[p["name"]] = {
+                    "calls": 0, **{k: 0.0 for k in PROGRAM_PARTS}, "cache_hit": None, "run_s": None, "at_s": p["at_s"],
+                }
+            agg["calls"] += 1
+            for k in PROGRAM_PARTS:
+                agg[k] += p[k]
+            agg["cache_hit"] = both_hit(agg["cache_hit"], p["cache_hit"])
+            if p["run_s"] is not None:
+                agg["run_s"] = (agg["run_s"] or 0.0) + p["run_s"]
+        counts = {kind: sum(1 for c in cache if c[1] == kind) for kind in ("requests", "hits", "misses")}
+        counts["missed"] = [c[2] for c in cache if c[1] == "misses"][:64]
+        return {
+            "origin": self.origin,
+            "now_s": self.now(),
+            "phases": {
+                name: {"count": int(n), "total_s": total, "self_s": total - children}
+                for name, (n, total, children) in sorted(totals.items())
+            },
+            "programs": dict(sorted(by_name.items())),
+            "programs_dropped": dropped,
+            "cache": counts,
+            "spanned_s": _union_s(intervals),
+            "spans": [list(s) for s in spans],
+        }
+
+    def summary(self) -> str:
+        """The phases' seconds and the programs' parts on one line, for
+        the line ``FFModel.compile`` prints."""
+        snap = self.snapshot()
+        sums = {k: sum(p[k] for p in snap["programs"].values()) for k in PROGRAM_PARTS}
+        return (", ".join(f"{name} {p['total_s']:.2f}" for name, p in snap["phases"].items())
+                + "; programs " + ", ".join(f"{k[:-2]} {v:.2f}" for k, v in sums.items()))
+
+
+# One account a process: its origin is the process's start, and every
+# ``ff.startup.*`` span and every program's first call lands here.
+GLOBAL_STARTUP = StartupAccount()

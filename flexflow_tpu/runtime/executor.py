@@ -1003,6 +1003,11 @@ class CompiledExecutor:
             self._measure_window_step(
                 program, traces_before, sync.t1 - dispatch.t0, num_steps
             )
+        if program in GLOBAL_PROGRAMS.unstamped:
+            # this call compiled: its wall (host seconds of the dispatch,
+            # and the drain where this call was a measured one) is the
+            # program's lump, beside the parts JAX's events gave it
+            GLOBAL_PROGRAMS.set_compile_time(program, (sync.t1 if measure else dispatch.t1) - dispatch.t0)
         return mets
 
     def train_batch(self, inputs: Sequence[jax.Array], label: jax.Array, rng: jax.Array) -> Dict[str, Any]:

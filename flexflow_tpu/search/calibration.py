@@ -30,6 +30,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..core.tensor import TensorSpec
 from ..core.types import DataType, OpType
+from ..obs.steptrace import GLOBAL_STARTUP
 from ..ops.base import get_op_def
 from ..parallel.machine import MachineSpec, TPUChipSpec
 
@@ -544,13 +545,20 @@ def load_or_calibrate(
         device_kind = (
             "analytic" if jax.default_backend() == "cpu" else detected_device_kind()
         )
+    # which branch resolved it rides on the start-up span that is open
+    # around this call (``ff.startup.search.calibrate``, ``.executor``)
     if device_kind == "analytic":
+        GLOBAL_STARTUP.annotate(calibration="analytic")
         return Calibration()
     hit = load_calibration(device_kind)
     if hit is not None:
+        cached = cache_dir() is not None and Path(hit.source).parent == cache_dir()
+        GLOBAL_STARTUP.annotate(calibration="cache_table" if cached else "committed_table")
         return hit
     if allow_measure:
+        GLOBAL_STARTUP.annotate(calibration="live_measurement")
         return calibrate(machine, device_kind=device_kind)
+    GLOBAL_STARTUP.annotate(calibration="analytic")
     return Calibration(device_kind=device_kind)
 
 

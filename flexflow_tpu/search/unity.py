@@ -24,6 +24,7 @@ from typing import Dict, List, Optional, Tuple
 from ..config import FFConfig
 from ..core.graph import PCGraph
 from ..core.types import OpType, PARALLEL_OP_TYPES, ParameterSyncOption
+from ..obs.steptrace import GLOBAL_STARTUP
 from ..ops.base import get_op_def
 from ..parallel.machine import MachineSpec, MachineView
 from ..parallel.mesh import DATA_AXIS, EXPERT_AXIS, MODEL_AXIS
@@ -920,7 +921,8 @@ def unity_optimize(
     measure = config.measure_op_costs
     if measure is None:
         measure = False  # auto: class-level calibration only (SURVEY §7.1)
-    calibration = load_or_calibrate(machine, allow_measure=True)
+    with GLOBAL_STARTUP.span("search.calibrate"):
+        calibration = load_or_calibrate(machine, allow_measure=True)
     cost_model = CostModel(machine, measure=measure, calibration=calibration)
     machine_model = build_machine_model(
         machine,
@@ -960,15 +962,18 @@ def unity_optimize(
         return helper.optimal_cost(g).cost
 
     budget = config.search_budget if config.search_budget > 0 else 10
-    best_graph, stats = base_optimize(
-        graph,
-        xfers,
-        runtime_cost,
-        budget=budget,
-        alpha=config.search_alpha,
-        max_num_ops=max(64, config.base_optimize_threshold * max(1, len(graph))),
-    )
-    result_dp = helper.optimal_cost(best_graph)
+    # the substitution search and the cost evaluations it asks for
+    with GLOBAL_STARTUP.span("search.unity", devices=num_devices, budget=budget):
+        best_graph, stats = base_optimize(
+            graph,
+            xfers,
+            runtime_cost,
+            budget=budget,
+            alpha=config.search_alpha,
+            max_num_ops=max(64, config.base_optimize_threshold * max(1, len(graph))),
+        )
+        result_dp = helper.optimal_cost(best_graph)
+        GLOBAL_STARTUP.annotate(graphs_costed=1 + stats.candidates_explored, xfers=len(xfers))
     lam = 1.0
 
     # memory-aware λ search (reference: graph.cc:2075-2131): if the
@@ -978,22 +983,23 @@ def unity_optimize(
         capacity = machine.chip.hbm_capacity
         if result_dp.memory_per_device > capacity:
             lo, hi = 0.0, 1.0
-            for _ in range(8):
-                lam = (lo + hi) / 2
+            with GLOBAL_STARTUP.span("search.memory_fit"):
+                for _ in range(8):
+                    lam = (lo + hi) / 2
 
-                def blended(g: PCGraph) -> float:
-                    r = helper.optimal_cost(g)
-                    return lam * r.cost + (1 - lam) * (r.memory_per_device / capacity) * r.cost
+                    def blended(g: PCGraph) -> float:
+                        r = helper.optimal_cost(g)
+                        return lam * r.cost + (1 - lam) * (r.memory_per_device / capacity) * r.cost
 
-                cand_graph, cand_stats = base_optimize(
-                    graph, xfers, blended, budget=budget, alpha=config.search_alpha
-                )
-                cand_dp = helper.optimal_cost(cand_graph)
-                if cand_dp.memory_per_device <= capacity:
-                    best_graph, result_dp = cand_graph, cand_dp
-                    lo = lam  # try weighting runtime more
-                else:
-                    hi = lam
+                    cand_graph, cand_stats = base_optimize(
+                        graph, xfers, blended, budget=budget, alpha=config.search_alpha
+                    )
+                    cand_dp = helper.optimal_cost(cand_graph)
+                    if cand_dp.memory_per_device <= capacity:
+                        best_graph, result_dp = cand_graph, cand_dp
+                        lo = lam  # try weighting runtime more
+                    else:
+                        hi = lam
 
     # pipeline-parallel candidates (VERDICT r2 missing #3): costed against
     # the substitution-search winner; the ORIGINAL graph is used because
@@ -1033,15 +1039,16 @@ def unity_optimize(
     if num_devices > 1 and not config.only_data_parallel:
         batch = config.batch_size
         capacity = machine.chip.hbm_capacity
-        pipe = _propose_pipeline(
-            graph, num_devices, cost_model, batch, capacity=capacity,
-        )
-        # sequence/context parallelism (optionally composed with Megatron
-        # tp, cp x tp): the long-context regime where the batch can't
-        # fill the machine
-        cpc = _propose_context_parallel(
-            graph, num_devices, cost_model, batch, capacity=capacity
-        )
+        with GLOBAL_STARTUP.span("search.candidates"):
+            pipe = _propose_pipeline(
+                graph, num_devices, cost_model, batch, capacity=capacity,
+            )
+            # sequence/context parallelism (optionally composed with Megatron
+            # tp, cp x tp): the long-context regime where the batch can't
+            # fill the machine
+            cpc = _propose_context_parallel(
+                graph, num_devices, cost_model, batch, capacity=capacity
+            )
         # unified winner selection: prefer candidates whose footprint
         # FITS per-device HBM, then cheapest by modeled cost — a feasible
         # composed candidate must never lose to an infeasible cheaper one

@@ -44,9 +44,30 @@ class FakeClock:
         self.t += dt
 
 
+def _startup_section():
+    """What PR 50's account (``/v2/stats`` ``startup``) would have held at
+    the window's opening: the hand-made runs were processes of their own."""
+    phases = {
+        "import": 6.0, "backend": 4.0, "engine_build": 3.0, "search": 1.5, "search.calibrate": 0.1,
+        "search.unity": 1.3, "mesh": 0.1, "executor": 0.2, "param_init": 0.7,
+    }
+    program = {"calls": 1, "trace_s": 1.0, "lower_s": 0.5, "compile_s": 0.0, "cache_load_s": 2.0,
+               "cache_hit": True, "run_s": 0.5, "at_s": 20.0}
+    return {
+        "origin": "process_start", "now_s": 100.0,
+        "phases": {k: {"count": 1, "total_s": v, "self_s": v} for k, v in phases.items()},
+        "programs": {"step": program}, "programs_dropped": 0,
+        "cache": {"requests": 1, "hits": 1, "misses": 0, "missed": []}, "spanned_s": 30.0, "spans": [],
+    }
+
+
 def _with_pipeline_section(make):
     ctx = make()
-    if "stats_open" in ctx:
+    # the trainer's ctx carries no stats (its readers read the account in
+    # place, which in a test process is whatever ran before): for this
+    # hand-made one the section stands where a serving driver keeps it
+    ctx.setdefault("stats_open", {})["startup"] = _startup_section()
+    if "stats_close" in ctx:
         section = lambda k: {  # noqa: E731
             "decode_steps_total": 100 * k, "pipelined_steps_total": 60 * k, "reclaims_total": 0,
             "drains_total": {"nonsteady": 5 * k, "finish": 5 * k, "pressure": 0, "idle": 0},
@@ -122,6 +143,8 @@ _as_pr_37_left_it = _per_layer_through("trace_record_share.served")
 # lists' lengths (PR 45 appended after PR 41, PR 48 after both)
 _as_pr_41_left_it = _as_it_was_after("longcat-flash-chat.agent-turns", "longcat-flash-chat", "latent_prefill_ms_per_ktoken.served")
 _as_pr_45_left_it = _as_it_was_after("sdar-30b-a3b-chat.block-gen", "sdar-30b-a3b-chat", "block_prefill_ms_per_ktoken.served")
+# PR 48 pins the SET of metrics that list its cell (PR 50 appended ten, seven of which list every cell)
+_as_pr_49_left_it = _per_layer_through("ssm_prefill_ms_per_ktoken.served")
 
 
 def _seeing(item, view):
@@ -155,17 +178,18 @@ _PINNED_TAILS = {
     ("test_host_release_share.py", "test_benchmark_json_asks_for_it_in_the_five_serving_cells"): _as_pr_41_left_it,
     ("test_longcat_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_five_metrics_that_list_it"): _as_pr_41_left_it,
     ("test_sdar_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_six_metrics_that_list_it"): _as_pr_45_left_it,
+    ("test_nemotron_cell.py", "test_benchmark_json_holds_the_configuration_the_cell_and_four_metrics_that_list_it"): _as_pr_49_left_it,
 }
 
 
 def pytest_collection_modifyitems(items):
     """The hand-made serving run of ``benchmark_yardstick/test_yardstick.py``
     dates from before the scheduler counted its pipeline's decisions
-    (PR 30), and one test there wants EVERY per-layer metric of the cell
-    from it. That directory is the benchmark's: a PR that adds a metric may
+    (PR 30) and the process accounted for its start-up (PR 50), and one
+    test there wants EVERY per-layer metric of the cell from it. That directory is the benchmark's: a PR that adds a metric may
     add a file there and edit none, its ``conftest.py`` (which completes
     the same run with PR 23's counters, and says why) included. So the
-    ``pipeline`` section is added from here, composed with that hook
+    ``pipeline`` and ``startup`` sections are added from here, composed with that hook
     whichever runs first. For the next ``benchmark`` issue: fold both into
     ``_serve_ctx``."""
     for item in items:

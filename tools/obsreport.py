@@ -61,7 +61,11 @@ Against a live server (serving/server.py):
       thread's seconds: wall = working + empty + idle wait + the loop's
       own, working = host-lane phases + unspanned, the decode dispatch's
       wall against its CPU seconds — the "is this server host-bound, and
-      where?" answer, exact under the overlap pipeline. --capture K arms
+      where?" answer, exact under the overlap pipeline; then the
+      process's start-up account (/v2/stats "startup": the ff.startup.*
+      spans, each program's first call split into trace, lowering,
+      compile or cache load, and what the compile cache answered).
+      --capture K arms
       a K-step two-lane capture (scrape again once the engine has
       stepped); --anatomy-out dumps the captured chrome://tracing
       timeline.
@@ -412,6 +416,24 @@ def _print_thread_account(snap: dict) -> None:
           + ("" if own is None else f", the loop's own {own:.3%} of wall") + ")")
 
 
+def _print_startup(acct: dict) -> None:
+    """The ``startup`` section of ``/v2/stats``: where the process's
+    seconds went before it served (spans, then the programs' first calls
+    by JAX's compile events, then what the compile cache answered)."""
+    print(f"    start-up, {acct['now_s']:.1f} s since the {acct['origin'].replace('_', ' ')} "
+          f"({acct['spanned_s']:.1f} s of them named):")
+    for name, p in sorted(acct["phases"].items(), key=lambda kv: -kv[1]["total_s"]):
+        print(f"        {name:<22} {p['total_s']:10.3f} s  self {p['self_s']:8.3f} s  x{p['count']}")
+    print("        program                   trace    lower  compile cache-load      run")
+    for name, p in sorted(acct["programs"].items(), key=lambda kv: -sum(kv[1][k] for k in ("trace_s", "lower_s", "compile_s", "cache_load_s")))[:12]:
+        run = "       -" if p["run_s"] is None else f"{p['run_s']:8.3f}"
+        print(f"        {name[:24]:<24} {p['trace_s']:7.3f} {p['lower_s']:8.3f} {p['compile_s']:8.3f} {p['cache_load_s']:10.3f} {run}"
+              + ("" if p["cache_hit"] is not False else "  MISSED the cache"))
+    c = acct["cache"]
+    print(f"        compile cache: {c['requests']} request(s), {c['hits']} hit(s), {c['misses']} written"
+          + (f"; missed: {', '.join(c['missed'][:6])}" if c["missed"] else ""))
+
+
 def show_anatomy(base: str, capture=None, out: str = "") -> int:
     """Phase breakdown + the conserved thread account per generation unit."""
     url = f"{base}/v2/debug/anatomy"
@@ -437,6 +459,8 @@ def show_anatomy(base: str, capture=None, out: str = "") -> int:
         snap = stats.get(name) or {}
         if snap.get("loop") and snap.get("step_phases"):
             _print_thread_account(snap)
+        if snap.get("startup"):
+            _print_startup(snap["startup"])
         cap = rep.get("capture", {})
         print(f"    capture: {cap.get('captured', 0)} step(s) retained, "
               f"{cap.get('remaining', 0)} armed")
@@ -952,6 +976,8 @@ def selfcheck() -> int:
         check("decode retraced" in blame
               and f"int32[{eng.max_batch_slots}] -> int32[{b}]" in blame,
               f"retrace blame string wrong: {blame!r}")
+        check(bool(retraces) and all(retraces[-1].get(k) is not None for k in ("trace_s", "lower_s", "compile_s_backend")),
+              f"the forced retrace's record has no split beside its blame: {retraces[-1] if retraces else None}")
 
         # -------------------- step anatomy: report + forced capture
         # (ISSUE 12) the profiler must have folded the healthy steps
@@ -977,6 +1003,13 @@ def selfcheck() -> int:
               f"loop wall not conserved: the loop's own {acct['loop_own_s']} s of {loop.get('wall_total_s')}")
         check(loop["decode_dispatch_wall_total_s"] > 0 and acct["parts"].get("dispatch.call", 0) > 0,
               f"the decode dispatch was not split: {loop} {acct['parts']}")
+        # (ISSUE 50) ... and the seconds BEFORE the first step: the engine's
+        # construction is a span, its programs' first calls are split
+        start = snap.get("startup") or {}
+        check(start.get("phases", {}).get("engine_build", {}).get("count", 0) >= 1
+              and start.get("programs", {}).get("decode", {}).get("trace_s", 0) > 0
+              and 0 < start.get("spanned_s", 0) <= start.get("now_s", 0),
+              f"/v2/stats startup does not account for the engine's start: {start.get('phases')}")
         decode_phases = rep.get("phases", {}).get("decode", {})
         for phase in ("dispatch", "execute", "readback", "bookkeep"):
             check(decode_phases.get(phase, {}).get("count", 0) >= 1,
