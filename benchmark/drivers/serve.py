@@ -144,8 +144,21 @@ def finish_loadgen(child, w: Dict) -> Dict:
     gen = json.loads(out)
     gen["records"].sort(key=lambda r: r["id"])
     if gen["exhausted"]:
-        raise RuntimeError("the closed loop ran out of requests: raise max_rate_per_s in the traffic file")
+        raise RuntimeError(
+            f"the closed loop ran out of requests: its list of {gen['listed']} lasted {gen['exhausted_after_s']:.1f} s of the "
+            f"traffic's seconds. The list is sized by max_rate_per_s in the traffic file for a server about twice as fast as the "
+            f"one it was sized on; a program that outruns it needs a `benchmark` issue that re-sizes the list (PERF.md, section 4)"
+        )
     return gen
+
+
+def sent_of_listed(gen: Dict, sched: Dict) -> str:
+    """How much of its list a run used, for the window's log line: a
+    closed loop draws from a list sized beforehand (``closed_clients``),
+    and a run that comes near its end says so before one crosses it."""
+    if sched["mode"] != "closed":
+        return f"{gen['sent']} sent in all"
+    return f"{gen['sent']} sent of {gen['listed']} listed ({100.0 * gen['sent'] / gen['listed']:.0f} %)"
 
 
 def run(cell: spec.Cell, rt, peaks) -> Dict:
@@ -238,7 +251,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     n_gaps = len(stats.window_gaps_ms(ctx))
     rt.log(f"window {t_close_real - t_open_real:.3f}s: {len(due)} requests due ({len(ok_due)} ok), "
            f"{len(done)} completed inside ({len(done) / seconds:.2f}/s), {gen['undrained']} undrained; "
-           f"{len(records)} sent in all")
+           f"{sent_of_listed(gen, sched)}")
     rt.log(f"first tokens: {len(ok_due)} ({stats.samples_beyond(len(ok_due), 90)} beyond p90); "
            f"gaps: {n_gaps} ({stats.samples_beyond(n_gaps, 95)} beyond p95, "
            f"{stats.samples_beyond(n_gaps, 99)} beyond p99)")
@@ -289,6 +302,8 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
                f"{time.monotonic() - t0:.1f}s")
     if not picked or read["near_tie_gap"] > limit:
         why.append(f"near_tie_gap {read['near_tie_gap'] if picked else None} over the limit {limit}")
+    # (what run.py prints last, in the result line and on standard error: each number compared beside its limit)
+    ctx["compared"] = {"near_tie_gap": [read["near_tie_gap"] if picked else None, limit]}
 
     ctx.update(correct=not why, why_incorrect=why, attempted=attempted, failed=attempted - len(ok_due))
     return ctx

@@ -29,7 +29,7 @@ import numpy as np
 
 from benchmark import layer_metrics, spec, stats, trace_reduce, traffic
 from benchmark.drivers.serve import (
-    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sleep_until, start_loadgen, warm,
+    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sent_of_listed, sleep_until, start_loadgen, warm,
 )
 from benchmark.drivers.serve_lfm2 import age_prefix_cache, host_tier
 from benchmark.drivers.serve_mellum2 import pad_to
@@ -166,13 +166,17 @@ def verdict(judged: Dict, stated: Dict, valid, w: Dict):
                        "off_own": count(judged["gap"] > 0).astype(int).tolist(),
                        "off_stated": count(stated["gap"] > 0).astype(int).tolist()},
     }
-    failures = [
-        f"{name} {read[name]} over the limit {float(w[key])}"
-        for name, key in (("gap_ratio", "gap_ratio_limit"), ("worst_request_excess", "request_excess_limit"),
-                          ("capped_gap_ratio", "capped_gap_ratio_limit"))
-        if not read[name] <= float(w[key])
-    ]
+    failures = [f"{name} {read[name]} over the limit {float(w[key])}" for name, key in HELD if not read[name] <= float(w[key])]
     return read, failures
+
+
+HELD = (("gap_ratio", "gap_ratio_limit"), ("worst_request_excess", "request_excess_limit"), ("capped_gap_ratio", "capped_gap_ratio_limit"))
+
+
+def compared(read: Dict, w: Dict) -> Dict:
+    """Every number :func:`verdict` held, beside its limit (``run.py``
+    prints them last, in the result line and on standard error)."""
+    return {name: [read[name], float(w[key])] for name, key in HELD}
 
 
 def describe(read: Dict, w: Dict) -> str:
@@ -288,7 +292,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     attempted = len(due)
     rt.log(f"window {t_close_real - t_open_real:.3f}s: {len(due)} requests due ({len(ok_due)} ok), "
            f"{len(done)} completed inside ({len(done) / seconds:.2f}/s), {gen['undrained']} undrained; "
-           f"{len(records)} sent in all; gaps: {len(stats.window_gaps_ms(ctx))}; memory peak {memory_peak}")
+           f"{sent_of_listed(gen, sched)}; gaps: {len(stats.window_gaps_ms(ctx))}; memory peak {memory_peak}")
     # the same window by the token and not by the request (no metric: what served_tokens_per_s's spread is held against)
     emitted = sum(t_open <= t < t_close for r in records for t in r.get("token_times") or [])
     prefilled = [r["prompt_len"] for r in records if r.get("token_times") and t_open <= r["token_times"][0] < t_close]
@@ -372,6 +376,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
         rt.log(f"reference, request by request: served {read['by_request']['own']}, stated {read['by_request']['stated']}; "
                f"off the argmax {read['by_request']['off_own']}, stated {read['by_request']['off_stated']}")
         why += failures
+        ctx["compared"] = compared(read, w)
         if len(picked) < int(w["reference_requests_least"]) or read["tokens"] < int(w["reference_tokens_least"]):
             why.append(f"the reference judged {read['tokens']} tokens of {len(picked)} requests: fewer than the cell asks")
         ctx["reference"] = dict(sample, read=read, params=params, picked=[r["id"] for r in picked])

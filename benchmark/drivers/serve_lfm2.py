@@ -22,7 +22,7 @@ import numpy as np
 
 from benchmark import layer_metrics, spec, stats, traffic
 from benchmark.drivers.serve import (
-    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sleep_until, start_loadgen, warm,
+    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sent_of_listed, sleep_until, start_loadgen, warm,
 )
 from benchmark.reference import lfm2 as reference
 
@@ -198,7 +198,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     n_gaps = len(stats.window_gaps_ms(ctx))
     rt.log(f"window {t_close_real - t_open_real:.3f}s: {len(due)} requests due ({len(ok_due)} ok), "
            f"{len(done)} completed inside ({len(done) / seconds:.2f}/s), {gen['undrained']} undrained; "
-           f"{len(records)} sent in all; gaps: {n_gaps}")
+           f"{sent_of_listed(gen, sched)}; gaps: {n_gaps}")
     rt.log("at the client: " + ", ".join(
         f"{name} {value:.2f}" for name in LOGGED if (value := layer_metrics.read(name, ctx)) is not None
     ))
@@ -270,5 +270,6 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     if worst is None or not worst <= request_limit:
         why.append(f"the worst request's gap_ratio {worst} over the limit {request_limit}")
 
+    ctx["compared"] = {"gap_ratio": [ratio, limit], "worst_request_gap_ratio": [worst, request_limit]}
     ctx.update(correct=not why, why_incorrect=why, attempted=attempted, failed=attempted - len(ok_due))
     return ctx

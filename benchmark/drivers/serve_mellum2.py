@@ -26,7 +26,7 @@ import numpy as np
 
 from benchmark import layer_metrics, spec, stats, trace_reduce, traffic
 from benchmark.drivers.serve import (
-    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sleep_until, start_loadgen, warm,
+    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sent_of_listed, sleep_until, start_loadgen, warm,
 )
 from benchmark.drivers.serve_lfm2 import age_prefix_cache, host_tier
 from benchmark.reference import mellum2 as reference
@@ -179,7 +179,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     attempted = len(due)
     rt.log(f"window {t_close_real - t_open_real:.3f}s: {len(due)} requests due ({len(ok_due)} ok), "
            f"{len(done)} completed inside ({len(done) / seconds:.2f}/s), {gen['undrained']} undrained; "
-           f"{len(records)} sent in all; gaps: {len(stats.window_gaps_ms(ctx))}")
+           f"{sent_of_listed(gen, sched)}; gaps: {len(stats.window_gaps_ms(ctx))}")
     # the same window by the token and not by the request (no metric: what served_tokens_per_s's spread is held against)
     emitted = sum(t_open <= t < t_close for r in records for t in r.get("token_times") or [])
     prefilled = [r["prompt_len"] for r in records if r.get("token_times") and t_open <= r["token_times"][0] < t_close]
@@ -264,5 +264,6 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     if worst is None or not worst <= request_limit:
         why.append(f"the worst request's excess {worst} over the limit {request_limit}")
 
+    ctx["compared"] = {"gap_ratio": [ratio, limit], "worst_request_excess": [worst, request_limit]}
     ctx.update(correct=not why, why_incorrect=why, attempted=attempted, failed=attempted - len(ok_due))
     return ctx

@@ -33,13 +33,13 @@ import numpy as np
 from benchmark import layer_metrics, spec, stats, trace_reduce, traffic
 from benchmark.stalls import Watch
 from benchmark.drivers.serve import (
-    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sleep_until, start_loadgen,
+    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sent_of_listed, sleep_until, start_loadgen,
 )
 from benchmark.drivers.serve_mellum2 import pad_to
 from benchmark.reference import nemotron_h as reference
 
 SSM_KERNELS = ("ssm_state_update",)
-SHARES = (0.5, 0.9, 0.99, 1.0)  # of the first layer's (request, head) pairs: logged beside the one that is held (reference.STATE_SHARE)
+SHARES = (0.5, 0.9, 0.99, 1.0)  # of the first layer's (request, head) pairs: those the cell's file gives a limit are held (state_error_limits), all are logged
 
 # Arms beside the program's and the stated arithmetic's, for ``tools/nemotron_check.py control`` alone (a run has
 # none): a control of ``reference.CONTROLS`` each
@@ -178,7 +178,11 @@ def probe_sample(params, cell: spec.Cell, probed: Dict, controls=()) -> Dict:
     distance from the yardstick's (the arithmetic the configuration
     states) by (request, head), the one that 0.9 of the pairs lie under
     (``reference.state_error`` says why not pooled; ``state_error_pooled``
-    and the first layer's ``state_error_at`` other shares are logged); ``pick_error``, the picks not shared with the yardstick's;
+    is logged), ``state_error_at``, the first layer's at each of ``SHARES``
+    (:func:`verdict` holds those the cell's file gives a limit), and
+    ``state_error_pairs``, its every pair, request by request (what
+    ``nemotron_check.py control`` keeps: a limit's room is taken from what
+    the pairs CAN do); ``pick_error``, the picks not shared with the yardstick's;
     ``router_shift``, the picks of the prompts' positions that move when
     the router matrices are rounded to bfloat16 beforehand, beside the
     yardstick's own (``router_shift_stated``)."""
@@ -192,17 +196,18 @@ def probe_sample(params, cell: spec.Cell, probed: Dict, controls=()) -> Dict:
 
     yardstick = reference.probe(params, cell.config, probed["tokens"], probed["lengths"], stated)
     shift_stated = shift(stated)
-    out = {"program": {"state_error": reference.state_error(probed["state"], yardstick["state"]),
-                       "state_error_pooled": reference.state_error_pooled(probed["state"], yardstick["state"]),
-                       "state_error_at": [reference.state_error(probed["state"][:1], yardstick["state"][:1], q)[0] for q in SHARES],
-                       "pick_error": reference.pick_error(probed["picks"], yardstick["picks"]),
+
+    def of_the_state(state) -> Dict:
+        pairs = reference.state_distances(state[:1], yardstick["state"][:1])[0]  # [requests, heads] of the first layer
+        return {"state_error": reference.state_error(state, yardstick["state"]),
+                "state_error_pooled": reference.state_error_pooled(state, yardstick["state"]),
+                "state_error_at": np.quantile(pairs, SHARES), "state_error_pairs": pairs.reshape(-1)}
+
+    out = {"program": {**of_the_state(probed["state"]), "pick_error": reference.pick_error(probed["picks"], yardstick["picks"]),
                        "router_shift": reference.pick_error(probed["prefill_picks"]["rounded"], probed["prefill_picks"]["given"])}}
     for name in controls:
         arm = reference.probe(params, cell.config, probed["tokens"], probed["lengths"], name, first)
-        out[name] = {"state_error": reference.state_error(arm["state"], yardstick["state"]),
-                     "state_error_pooled": reference.state_error_pooled(arm["state"], yardstick["state"]),
-                     "state_error_at": [reference.state_error(arm["state"][:1], yardstick["state"][:1], q)[0] for q in SHARES],
-                     "pick_error": reference.pick_error(arm["picks"], yardstick["picks"]), "router_shift": shift(name)}
+        out[name] = {**of_the_state(arm["state"]), "pick_error": reference.pick_error(arm["picks"], yardstick["picks"]), "router_shift": shift(name)}
     return {arm: {k: [float(x) for x in v] for k, v in dict(read, router_shift_stated=shift_stated).items()} for arm, read in out.items()}
 
 
@@ -213,9 +218,14 @@ def verdict(judged: Dict, stated: Dict, valid, w: Dict, probed: Dict):
     layer of each kind: the stored state's distance from the stated
     arithmetic's (what goes into that layer is the same numbers on both
     sides but for a rounding that fell the other way; deeper, the
-    bfloat16 activations' own noise is all one reads), held under a
-    limit; and the picks that rounding the router's weights moves, held
-    OVER a share of what it moves in the stated arithmetic. The readings,
+    bfloat16 activations' own noise is all one reads) at each share of
+    the (request, head) pairs that the cell's file names
+    (``state_error_limits``: the median, which a state stored coarser
+    moves and a skipped slot does not, and 0.9, the other way round; one
+    request's heads moved a little by the program's own rounding pass
+    both), each under its limit; and the picks that rounding the router's
+    weights moves, held OVER a share of what it moves in the stated
+    arithmetic. The readings,
     and for each of the cell's limits that the arm does not keep, a line. The run holds
     the program to it, and ``nemotron_check.py control`` every control:
     one function, so that a control that comes out correct here would
@@ -230,19 +240,31 @@ def verdict(judged: Dict, stated: Dict, valid, w: Dict, probed: Dict):
         "median_margin": float(np.median(judged["margin"])), "near_ties": own["near_ties"],
         "worst_request_ratio": reference.worst_request_ratio(judged, stated, valid),
         "state_error": probed["state_error"][0], "router_shift": probed["router_shift"][0],
-        "router_shift_stated": probed["router_shift_stated"][0], "state_error_at": dict(zip(map(str, SHARES), probed.get("state_error_at", ()))),
-        **{f"{k}_by_layer": v for k, v in probed.items() if k != "state_error_at"},
+        "router_shift_stated": probed["router_shift_stated"][0], "state_error_at": dict(zip(map(str, SHARES), probed["state_error_at"])),
+        "state_error_pairs": probed.get("state_error_pairs"),
+        **{f"{k}_by_layer": v for k, v in probed.items() if k not in ("state_error_at", "state_error_pairs")},
     }
     failures = [
-        f"{name} {read[name]} over the limit {float(w[key])}"
-        for name, key in (("gap_ratio", "gap_ratio_limit"), ("worst_request_excess", "request_excess_limit"),
-                          ("state_error", "state_error_limit"))
-        if not read[name] <= float(w[key])
+        f"{name} {value} over the limit {limit}"
+        for name, (value, limit) in compared(read, w).items() if name != "router_shift_at_least" and not value <= limit
     ]
     if not read["router_shift"] >= float(w["router_shift_least"]) * read["router_shift_stated"]:
         failures.append(f"router_shift {read['router_shift']} under {float(w['router_shift_least'])} of the stated arithmetic's "
                         f"{read['router_shift_stated']}")
     return read, failures
+
+
+def compared(read: Dict, w: Dict) -> Dict:
+    """Every number :func:`verdict` holds, beside its limit (the stored
+    state's distance at each share of the pairs that the cell's file gives
+    one); also the result line's last key and the run's last lines on
+    standard error."""
+    return {
+        "gap_ratio": [read["gap_ratio"], float(w["gap_ratio_limit"])],
+        "worst_request_excess": [read["worst_request_excess"], float(w["request_excess_limit"])],
+        **{f"state_error_at_{share}": [read["state_error_at"][share], float(limit)] for share, limit in w["state_error_limits"].items()},
+        "router_shift_at_least": [read["router_shift"], float(w["router_shift_least"]) * read["router_shift_stated"]],
+    }
 
 
 def describe(read: Dict, w: Dict) -> str:
@@ -255,9 +277,9 @@ def describe(read: Dict, w: Dict) -> str:
             f"its argmax), the stated arithmetic's own choices {read['mean_gap_stated']:.5f} ({read['off_argmax_stated']}); median "
             f"margin {read['median_margin']:.4f}; {read['near_ties']} positions at near-ties; probed: the first state-space layer's "
             f"stored state {read['state_error']:.3e} of a head's norm from the stated arithmetic's at {reference.STATE_SHARE} of the (request, head) "
-            f"pairs (limit {w['state_error_limit']}; by layer {[float(f'{x:.3g}') for x in read['state_error_by_layer']]}; all pairs pooled "
-            f"{[float(f'{x:.3g}') for x in read.get('state_error_pooled_by_layer', [])]}; the first layer's at shares "
-            f"{ {k: float(f'{x:.3g}') for k, x in read['state_error_at'].items()} }); router matrices rounded to bfloat16 move {read['router_shift']:.3e} "
+            f"pairs (the first layer's at shares { {k: float(f'{x:.3g}') for k, x in read['state_error_at'].items()} }, limits "
+            f"{w['state_error_limits']}; at {reference.STATE_SHARE} by layer {[float(f'{x:.3g}') for x in read['state_error_by_layer']]}; all pairs pooled "
+            f"{[float(f'{x:.3g}') for x in read.get('state_error_pooled_by_layer', [])]}); router matrices rounded to bfloat16 move {read['router_shift']:.3e} "
             f"of the first expert layer's picks over the prompts, in the stated arithmetic {read['router_shift_stated']:.3e} (at least "
             f"{w['router_shift_least']} of that; by layer {[float(f'{x:.3g}') for x in read['router_shift_by_layer']]} against "
             f"{[float(f'{x:.3g}') for x in read['router_shift_stated_by_layer']]}); picks not the stated arithmetic's, by layer "
@@ -366,7 +388,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     attempted = len(due)
     rt.log(f"window {t_close_real - t_open_real:.3f}s: {len(due)} requests due ({len(ok_due)} ok), "
            f"{len(done)} completed inside ({len(done) / seconds:.2f}/s), {gen['undrained']} undrained; "
-           f"{len(records)} sent in all; gaps: {len(stats.window_gaps_ms(ctx))}; memory peak {memory_peak}")
+           f"{sent_of_listed(gen, sched)}; gaps: {len(stats.window_gaps_ms(ctx))}; memory peak {memory_peak}")
     # the same window by the token and not by the request (no metric: what served_tokens_per_s's spread is held against)
     emitted = sum(t_open <= t < t_close for r in records for t in r.get("token_times") or [])
     prefilled = [r["prompt_len"] for r in records if r.get("token_times") and t_open <= r["token_times"][0] < t_close]
@@ -443,6 +465,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
         read, failures = verdict(sample["judged"]["program"], sample["judged"]["stated"], sample["valid"], w, sample["probed"]["program"])
         rt.log(f"reference: {describe(read, w)}; {time.monotonic() - t0:.1f}s")
         why += failures
+        ctx["compared"] = compared(read, w)
         if len(picked) < int(w["reference_sample"]) or read["tokens"] < int(w["reference_tokens_least"]):
             why.append(f"the reference judged {read['tokens']} tokens of {len(picked)} requests: fewer than the cell asks")
         ctx["reference"] = dict(sample, read=read, picked=[r["id"] for r in picked])

@@ -29,7 +29,7 @@ import numpy as np
 
 from benchmark import layer_metrics, spec, stats, traffic
 from benchmark.drivers.serve import (
-    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sleep_until, start_loadgen, warm,
+    LOGGED, ZERO_COUNTERS, engine_snapshot, finish_loadgen, sent_of_listed, sleep_until, start_loadgen, warm,
 )
 from benchmark.drivers.serve_lfm2 import age_prefix_cache, host_tier
 from benchmark.reference import sdar as reference
@@ -236,7 +236,7 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     attempted = len(due)
     rt.log(f"window {t_close_real - t_open_real:.3f}s: {len(due)} requests due ({len(ok_due)} ok), "
            f"{len(done)} completed inside ({len(done) / seconds:.2f}/s), {gen['undrained']} undrained; "
-           f"{len(records)} sent in all; gaps: {len(stats.window_gaps_ms(ctx))}")
+           f"{sent_of_listed(gen, sched)}; gaps: {len(stats.window_gaps_ms(ctx))}")
     emitted = sum(t_open <= t < t_close for r in records for t in r.get("token_times") or [])
     prefilled = [r["prompt_len"] for r in records if r.get("token_times") and t_open <= r["token_times"][0] < t_close]
     rt.log(f"by the token: {emitted} reply tokens emitted inside the window ({emitted / seconds:.2f}/s), {len(prefilled)} prompts "
@@ -305,5 +305,6 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     if read is None or not read["gap_ratio"] <= limit:
         why.append(f"gap_ratio {read and read['gap_ratio']} over the limit {limit}")
 
+    ctx["compared"] = {"gap_ratio": [read and read["gap_ratio"], limit]}
     ctx.update(correct=not why, why_incorrect=why, attempted=attempted, failed=attempted - len(ok_due))
     return ctx

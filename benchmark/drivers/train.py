@@ -234,5 +234,6 @@ def run(cell: spec.Cell, rt, peaks) -> Dict:
     if not rel <= tol:
         why.append(f"step-0 loss {losses[0]} departs from the reference {ref_loss} by {rel}")
 
+    ctx["compared"] = {"step0_loss_rel": [rel, tol]}
     ctx.update(correct=not why, why_incorrect=why, attempted=n_window, failed=0)
     return ctx

@@ -18,7 +18,9 @@ Job: ``{"url", "model", "mode": "open"|"closed", "t0", "requests":
   generator's own lateness.
 * closed: ``clients`` threads each take the next unsent request when
   their last completes (due = the moment they take it), and take none
-  after ``t0 + stop_s``.
+  after ``t0 + stop_s``. A client that finds the list empty before then
+  ends the run's claim to a closed loop: ``exhausted_after_s`` says how
+  many seconds after ``t0`` that was (``None`` if the list lasted).
 
 After the last request is handed out, in-flight requests get
 ``drain_s`` seconds; one still unfinished then is recorded as failed.
@@ -123,7 +125,7 @@ def run_closed(job: Dict, records: List[Dict], lock: threading.Lock, state: Dict
                     return
                 req = next(pending, None)
                 if req is None:
-                    state["exhausted"] = True
+                    state.setdefault("exhausted_after_s", time.monotonic() - job["t0"])
                     return
             rec = send(job["url"], job["model"], req, time.monotonic(), job["timeout_s"])
             with lock:
@@ -142,7 +144,7 @@ def main() -> int:
     job = json.load(sys.stdin)
     records: List[Dict] = []
     lock = threading.Lock()
-    state = {"exhausted": False}  # a closed loop that ran out of requests
+    state: Dict = {}  # "exhausted_after_s": a closed loop that ran out of requests, and when
     threads = (run_open if job["mode"] == "open" else run_closed)(job, records, lock, state)
     # closed clients stop taking requests at stop_s; open workers end
     # when the queue is empty. Either way, in-flight requests now drain.
@@ -156,8 +158,10 @@ def main() -> int:
     json.dump({
         "records": out,
         "undrained": undrained,
-        "exhausted": state["exhausted"],
+        "exhausted": "exhausted_after_s" in state,
+        "exhausted_after_s": state.get("exhausted_after_s"),
         "sent": len(out) + undrained,
+        "listed": len(job["requests"]),
     }, sys.stdout)
     sys.stdout.flush()
     return 0

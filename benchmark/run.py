@@ -10,7 +10,10 @@ come from ``--seed``. Everything the window will run is warmed first and
 counts as set-up; then the cell's traffic runs for a short unmeasured
 lead-in and ``--seconds`` measured seconds. The LAST line of stdout is
 one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
-``device`` and, with ``--trace 1``, ``breakdown``. With ``--trace 0``
+``device`` and, with ``--trace 1``, ``breakdown``; last in it, where the
+cell's driver hands them over (``ctx["compared"]``), ``compared``: every
+number that decided ``correct`` beside its limit, which are also the last
+lines on standard error. With ``--trace 0``
 the metrics are the cell's end-to-end metrics; with ``--trace 1`` a
 profiler trace of part of the window is taken and the metrics are the
 cell's per-layer metrics. Earlier lines are a log (medians, counts).
@@ -153,6 +156,22 @@ def result_object(cell: spec.Cell, ctx: Dict, device: Dict, reduced: Optional[Di
     return out
 
 
+def compared_numbers(ctx: Dict) -> Dict:
+    """``ctx["compared"]`` as plain numbers: ``{name: [value, limit]}``,
+    every number the driver's comparison held beside its limit
+    (``at_least`` in the name where the limit is a floor; a value that
+    could not be read is ``None``)."""
+    plain = lambda x: None if x is None else float(x)  # noqa: E731
+    return {name: [plain(value), plain(limit)] for name, (value, limit) in (ctx.get("compared") or {}).items()}
+
+
+def compared_lines(ctx: Dict) -> List[str]:
+    """What decided ``correct``, a line each: the numbers compared
+    beside their limits and, where the run is not correct, why."""
+    lines = [f"compared: {name} {value} (limit {limit})" for name, (value, limit) in compared_numbers(ctx).items()]
+    return lines + [f"not correct: {why}" for why in ctx.get("why_incorrect") or []]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -206,6 +225,7 @@ def main(argv=None) -> int:
         rt.log(f"readers that found something: {sorted(found)}")
         rt.log(f"rehearsal done: correct={ctx['correct']} attempted={ctx['attempted']} "
                f"failed={ctx['failed']} why={ctx.get('why_incorrect')}; no result line, by design")
+        sys.stderr.writelines(line + "\n" for line in compared_lines(ctx))
         return 0 if ctx["correct"] else 1
 
     if args.trace and (reduced is None or reduced["busy_s"] <= 0.0):
@@ -217,7 +237,10 @@ def main(argv=None) -> int:
         rt.log(f"longest single gaps: {reduced['longest_gaps']}")
     if not result["correct"]:
         rt.log(f"NOT CORRECT: {ctx.get('why_incorrect')}")
+    if ctx.get("compared"):
+        result["compared"] = compared_numbers(ctx)  # the last key: each number compared, beside its limit
     print(json.dumps(result), flush=True)
+    sys.stderr.writelines(line + "\n" for line in compared_lines(ctx))
     return 0
 
 

@@ -104,6 +104,8 @@ def compile_(slots_list) -> None:
 
 
 def control(seed: int, seconds: float, rehearsal: bool) -> int:
+    import numpy as np
+
     from benchmark import run as harness
     from benchmark.drivers import serve_nemotron as driver
     from benchmark.reference import nemotron_h
@@ -122,7 +124,8 @@ def control(seed: int, seconds: float, rehearsal: bool) -> int:
     sample, w = kept["reference"], kept["cell"].workload
     row = {"seed": seed, "seconds": round(time.monotonic() - t0, 1), "run_rc": rc, "run_correct": bool(kept["correct"]),
            "why_incorrect": kept["why_incorrect"],
-           "limits": {k: float(w[k]) for k in ("gap_ratio_limit", "request_excess_limit", "state_error_limit", "router_shift_least")},
+           "limits": {**{k: float(w[k]) for k in ("gap_ratio_limit", "request_excess_limit", "router_shift_least")},
+                      "state_error_limits": w["state_error_limits"]},
            "program": sample["read"], "comes_out_correct": {"program": bool(kept["correct"])}}
     for name in driver.CONTROL_ARMS:
         row[name], failures = driver.verdict(sample["judged"][name], sample["judged"]["stated"], sample["valid"], w, sample["probed"][name])
@@ -130,12 +133,19 @@ def control(seed: int, seconds: float, rehearsal: bool) -> int:
         row["comes_out_correct"][name] = not failures
     out_dir = ROOT / "chiprun_out" / "control"
     out_dir.mkdir(parents=True, exist_ok=True)
-    (out_dir / f"nemotron.{seed}.json").write_text(json.dumps(row))
-    print("control row: " + json.dumps(row), flush=True)
-    for arm in ("program",) + tuple(driver.CONTROL_ARMS):
+    (out_dir / f"nemotron.{seed}.json").write_text(json.dumps(row))  # (with every arm's 8 x 128 pairs, request by request)
+    arms = ("program",) + tuple(driver.CONTROL_ARMS)
+    print("control row: " + json.dumps({k: {x: y for x, y in v.items() if x != "state_error_pairs"} if k in arms else v for k, v in row.items()}), flush=True)
+    for arm in arms:
         r = row[arm]
-        print(f"{arm:16s} gap_ratio {r['gap_ratio']:.4g}  worst_request_excess {r['worst_request_excess']:.4g}  state_error "
-              f"{r['state_error']:.3e}  router_shift {r['router_shift']:.3e} (stated {r['router_shift_stated']:.3e})  ({r['tokens']} tokens of {r['requests']} requests)  -> {'correct' if row['comes_out_correct'][arm] else 'NOT correct'}",
+        # what the pairs CAN do, which a limit's room is taken from: the least pair, the least pair of the request
+        # moved most (a skipped or a flipped request moves all of its own), the worst pair
+        pairs = np.asarray(r["state_error_pairs"]).reshape(int(w["probe_sample"]), -1)
+        print(f"{arm:16s} gap_ratio {r['gap_ratio']:.4g}  worst_request_excess {r['worst_request_excess']:.4g}  state_error at shares "
+              f"{ {k: float(f'{x:.3g}') for k, x in r['state_error_at'].items()} } (pairs: least {pairs.min():.3g}, the most moved request's least "
+              f"{pairs.min(axis=1).max():.3g}, worst {pairs.max():.3g}, requests with every pair moved {int((pairs.min(axis=1) > 0).sum())})  "
+              f"router_shift {r['router_shift']:.3e} (stated {r['router_shift_stated']:.3e})  ({r['tokens']} tokens of {r['requests']} requests)  "
+              f"-> {'correct' if row['comes_out_correct'][arm] else 'NOT correct: ' + '; '.join(r.get('fails') or row['why_incorrect'])}",
               flush=True)
     sound = row["comes_out_correct"].pop("program")
     return 0 if sound and not any(row["comes_out_correct"].values()) else 1

@@ -78,8 +78,9 @@ def test_the_cell_is_the_one_the_issue_named_key_for_key():
     assert p["output"] == {"dist": "uniform", "min": 512, "max": 1024, "stratified_block": 32}
     assert p["prompt"]["max"] + p["output"]["max"] <= d["max_seq_len"]
     assert (WORKLOAD["lead_in_s"], WORKLOAD["drain_s"], WORKLOAD["reference_sample"]) == (40.0, 90.0, 16)
-    for limit in ("gap_ratio_limit", "request_excess_limit", "state_error_limit", "router_shift_least"):
+    for limit in ("gap_ratio_limit", "request_excess_limit", "router_shift_least"):
         assert WORKLOAD[limit] > 0 and limit in WORKLOAD["tolerances"], limit  # every tolerance with its reason
+    assert set(WORKLOAD["state_error_limits"]) == {"0.5", "0.9"} and "state_error_limits" in WORKLOAD["tolerances"] and "state_error_limit" not in WORKLOAD
     assert WORKLOAD["probe_sample"] <= WORKLOAD["reference_sample"] and WORKLOAD["probe_sample"] <= d["slots"] and WORKLOAD["probe_steps"] >= 128
     controls = {k: c for k, c in WORKLOAD["controls"].items() if k != "what"}
     assert set(controls) == {"bfloat16_state", "bfloat16_router", "state_skipped"}
@@ -90,8 +91,19 @@ def test_the_cell_is_the_one_the_issue_named_key_for_key():
     skipped, program = controls["state_skipped"], WORKLOAD["tolerances"]["program_readings"]
     assert min(skipped["gap_ratio"]) > WORKLOAD["gap_ratio_limit"] and min(skipped["worst_request_excess"]) > WORKLOAD["request_excess_limit"]
     assert max(program["gap_ratio"]) < WORKLOAD["gap_ratio_limit"] and max(program["worst_request_excess"]) < WORKLOAD["request_excess_limit"]
-    assert 3 * max(program["state_error"]) < WORKLOAD["state_error_limit"] < min(controls["bfloat16_state"]["state_error"]) / 3
-    assert min(skipped["state_error"]) > WORKLOAD["state_error_limit"] and len(program["state_error"]) >= 5
+    # the stored state, at two shares of the (request, head) pairs (PR 55): each limit a factor of 3 and more from what
+    # the statistic CAN do on its side, which is not the spread of the share's own readings. The median: a sound program
+    # leaves half of the pairs bit-equal, a rounded state moves every pair, its LEAST pair the nearest. The 0.9 share: a
+    # sound program's flip moves one request's pairs by its WORST pair at the most, a skipped request's LEAST pair the nearest
+    runs, limits = program["state_error_at_shares"]["runs"], WORKLOAD["state_error_limits"]
+    rounded, skipped_at = controls["bfloat16_state"]["state_error_at_shares"], skipped["state_error_at_shares"]
+    assert len(runs) >= 20 and all(r[share] <= limits[share] for r in runs for share in limits)
+    assert sum(r["0.9"] > 3e-4 for r in runs) >= 4  # (what the cell held until then: sound runs it called not correct)
+    assert max(r["0.5"] for r in runs) == 0.0 and 0 < limits["0.5"] < min(rounded["least_pair"]) / 3 < min(rounded["0.5"]) / 3
+    assert 3 * max(r["1.0"] for r in runs) < limits["0.9"] < min(skipped_at["least_moved_pair_of_the_skipped_request"]) / 3 < min(skipped_at["0.9"]) / 3
+    # each control by its own share and not by the other's: the median cannot see a skipped request, nor the 0.9 share a rounded state
+    assert max(skipped_at["0.5"]) <= limits["0.5"] and max(rounded["0.9"]) <= limits["0.9"]
+    assert max(controls["bfloat16_router"]["state_error_at_shares"]["1.0"]) == 0.0
     # (by (request, head) and not pooled: pooled, the program's largest reading and the control's least lie a factor of 5 apart)
     assert 10 * max(program["state_error_pooled"]) > min(controls["bfloat16_state"]["state_error_pooled"])
     assert max(controls["bfloat16_router"]["router_shift_over_stated"]) < WORKLOAD["router_shift_least"] < min(program["router_shift_over_stated"]) / 1.3
@@ -110,8 +122,20 @@ def _verdict(probed_program, gap=0.01):
     from benchmark.drivers import serve_nemotron
 
     judged = lambda g: {"gap": np.full((10,), g), "margin": np.full((10,), 0.1)}  # noqa: E731
-    probed = {"state_error": [5e-5, 4e-3], "pick_error": [5e-3, 6e-3], "router_shift": [3.1e-3, 9e-3], "router_shift_stated": [3.3e-3, 9e-3]}
+    probed = {"state_error": [5e-5, 4e-3], "state_error_at": [0.0, 5e-5, 1e-4, 1e-3], "pick_error": [5e-3, 6e-3], "router_shift": [3.1e-3, 9e-3],
+              "router_shift_stated": [3.3e-3, 9e-3]}
     return serve_nemotron.verdict(judged(gap), judged(0.01), np.ones((2, 5), bool), WORKLOAD, dict(probed, **probed_program))
+
+
+def _state_at(pairs):
+    """The probe's readings of a first layer whose 8 x 128 (request, head)
+    distances are ``pairs``, as ``serve_nemotron.probe_sample`` takes them."""
+    import numpy as np
+
+    from benchmark.drivers import serve_nemotron
+
+    at = np.quantile(np.asarray(pairs), serve_nemotron.SHARES)
+    return {"state_error": [float(at[1]), 4e-3], "state_error_at": [float(x) for x in at]}
 
 
 def test_the_comparison_holds_the_tokens_the_stored_state_and_the_router_s_sight_of_its_weights():
@@ -120,9 +144,25 @@ def test_the_comparison_holds_the_tokens_the_stored_state_and_the_router_s_sight
     # each of the four limits alone: tokens further off, a state that holds bfloat16 (its first layer lies 2^-9 of a
     # value and more from the float32 one), a router whose product never sees the weights below bfloat16
     assert [f.split()[0] for f in _verdict({}, gap=0.05)[1]] == ["gap_ratio", "worst_request_excess"]
-    assert [f.split()[0] for f in _verdict({"state_error": [3e-3, 4e-3]})[1]] == ["state_error"]
     assert [f.split()[0] for f in _verdict({"router_shift": [0.0, 0.0]})[1]] == ["router_shift"]
     assert _verdict({"state_error": [5e-5, 1.0], "router_shift": [3.1e-3, 0.0]})[1] == []  # (the first layer of each kind is what is held)
+
+
+@pytest.mark.parametrize("requests_moved, by, fails_at", [
+    pytest.param(1, 3e-3, [], id="one request of eight moved by 3e-3, the program's own flip"),
+    pytest.param(2, 3e-3, [], id="two requests of eight moved by 3e-3"),
+    pytest.param(8, 2e-3, ["0.5"], id="every pair moved by 2e-3, a state stored coarser"),
+    pytest.param(1, 0.1, ["0.9"], id="one request moved by 0.1, its updates skipped"),
+    pytest.param(8, 0.1, ["0.5", "0.9"], id="every pair moved by 0.1"),
+])
+def test_the_stored_state_is_held_at_two_shares_each_for_its_own_control(requests_moved, by, fails_at):
+    import numpy as np
+
+    off = np.zeros((8, 128))
+    off[:requests_moved] = by
+    read, failures = _verdict(_state_at(off))
+    assert [f.split()[0] for f in failures] == [f"state_error_at_{share}" for share in fails_at]
+    assert read["state_error_at"]["0.5"] == (by if requests_moved > 4 else 0.0)
 
 
 def test_benchmark_json_holds_the_configuration_the_cell_and_four_metrics_that_list_it():

@@ -78,3 +78,6 @@ def test_a_rehearsal_of_prompt_batch_finds_it():
     found = re.search(r"readers that found something: (\[.*\])", out.stdout)
     assert found, out.stdout[-3000:]
     assert "pipelined_step_share.served" in re.findall(r"'([^']+)'", found.group(1))
+    # the window's line says how much of its list the closed loop used (the rehearsal's list: 8 + 400 x 4 = 1,608)
+    used = re.search(r"(\d+) sent of 1608 listed \((\d+) %\)", out.stdout)
+    assert used and int(used.group(2)) == round(100 * int(used.group(1)) / 1608), out.stdout[-3000:]

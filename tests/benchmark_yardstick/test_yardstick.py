@@ -734,6 +734,90 @@ def test_loadgen_closed_loop(stub_url):
     assert sorted(r["id"] for r in recs) == list(range(len(recs)))  # taken in order, none twice
 
 
+def test_a_closed_loop_that_runs_out_of_its_list_fails_the_run_and_says_how_long_the_list_lasted(stub_url):
+    """Through the drivers' own pair (``serve.start_loadgen`` /
+    ``finish_loadgen``): three requests for two clients that would go on
+    for 5 s. The child says when the list ended; the driver raises, with
+    the list's size and the seconds it lasted in the message."""
+    from benchmark.drivers import serve
+
+    sched = {"mode": "closed", "clients": 2, "requests": [{"id": i, "prompt": [1], "max_new_tokens": 2} for i in range(3)]}
+    w = {"drain_s": 30.0, "request_timeout_s": 30.0}
+    child, t0 = serve.start_loadgen(stub_url, sched, w, 5.0)
+    with pytest.raises(RuntimeError, match=r"ran out of requests: its list of 3 lasted \d+\.\d s .*`benchmark` issue"):
+        serve.finish_loadgen(child, w)
+    assert child.poll() == 0
+    # ... and one that lasts is handed back with what it used: the window line's "N sent of M listed"
+    child, t0 = serve.start_loadgen(stub_url, dict(sched, requests=sched["requests"] * 40), w, 0.3)
+    gen = serve.finish_loadgen(child, w)
+    assert not gen["exhausted"] and gen["exhausted_after_s"] is None and gen["listed"] == 120 and 2 <= gen["sent"] < 60
+    assert serve.sent_of_listed(gen, sched) == f"{gen['sent']} sent of 120 listed ({100 * gen['sent'] / 120:.0f} %)"
+
+
+@pytest.mark.parametrize("mode, sent, listed, want", [
+    ("closed", 1241, 1360, "1241 sent of 1360 listed (91 %)"),  # prompt-batch on PR 50's program, the list of PR 22
+    ("closed", 1241, 2704, "1241 sent of 2704 listed (46 %)"),  # ... and the list of PR 55
+    ("closed", 417, 996, "417 sent of 996 listed (42 %)"),  # code-gen
+    ("open", 475, 475, "475 sent in all"),  # an open loop sends its whole schedule: no share to watch
+])
+def test_the_window_line_says_how_much_of_its_list_a_closed_loop_used(mode, sent, listed, want):
+    from benchmark.drivers import serve
+
+    assert serve.sent_of_listed({"sent": sent, "listed": listed}, {"mode": mode}) == want
+
+
+CLOSED_MIXES = sorted(p.stem for p in (ROOT / "benchmark" / "traffic").glob("*.json")
+                      if json.loads(p.read_text())["generator"] == "closed_clients")
+
+
+@pytest.mark.parametrize("mix", CLOSED_MIXES)
+def test_every_closed_mix_says_what_its_list_was_sized_on(mix):
+    """A list that ends fails the run (above), so each mix's file says
+    what sizes it; the cell's file may set the number, and then says on
+    which rate."""
+    assert len(CLOSED_MIXES) >= 8
+    t = json.loads((ROOT / "benchmark" / "traffic" / f"{mix}.json").read_text())
+    assert "max_rate_per_s" in t["params"] and "sizes the list" in t["max_rate_why"]
+
+
+def test_prompt_batch_s_list_holds_twice_the_rate_its_file_states():
+    """``max_rate_per_s`` >= 2 x the completions a second of the accepted
+    tree (``deployment.completions_per_s``, with the PR it was read on):
+    the cell predates the rule the later cells' tests hold, and a list
+    sized on PR 22's program ended under a change 9.6 % faster than PR
+    50's (PR 55). Twice and not 2.5 x: the list's way through the pipe to
+    the load generator's child is inside ``setup_s``."""
+    cell = spec.load_cell("gpt2-medium.prompt-batch")
+    d, p, w = cell.workload["deployment"], cell.traffic["params"], cell.workload
+    assert p["max_rate_per_s"] >= 2.0 * d["completions_per_s"] > 40 and "PR 5" in d["completions_per_s_why"]
+    sched = traffic.schedule("closed_clients", 7, w["lead_in_s"] + BENCH["run_seconds"], p, {"vocab_size": 50257})
+    assert len(sched["requests"]) == 16 + 48 * 56 == 2704
+    # what start_loadgen lets the child take to parse the job, inside setup_s (~38 s): 2 us a prompt token, 2.0 s at 24 a second, 5.0 at 60
+    assert 2e-6 * sum(len(r["prompt"]) for r in sched["requests"]) < 4.2
+
+
+def test_a_rehearsal_of_prompt_batch_on_a_list_too_short_fails_with_the_list_s_size_and_seconds():
+    """The whole command at rehearsal size with ``max_rate_per_s`` set
+    under what the rehearsal completes (some 100 a second on this CPU): no
+    result, a non-zero exit, and the message a builder reads in the log.
+    (With the file's own value it runs to its end:
+    ``test_pipelined_step_share.py`` and ``test_inside_metrics.py``.)"""
+    code = (
+        "import sys; sys.path.insert(0, '.')\n"
+        "from benchmark import run, spec\n"
+        "load = spec.load_cell\n"
+        "def short(name, rehearsal=False):\n"
+        "    cell = load(name, rehearsal)\n"
+        "    cell.traffic['params']['max_rate_per_s'] = 2\n"
+        "    return cell\n"
+        "spec.load_cell = short\n"
+        "sys.exit(run.main(['--workload', 'gpt2-medium.prompt-batch', '--seed', '5', '--seconds', '3', '--trace', '0', '--rehearse']))\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, capture_output=True, text=True, timeout=300)
+    assert out.returncode != 0 and "rehearsal done" not in out.stdout, out.stdout[-2000:]
+    assert re.search(r"RuntimeError: the closed loop ran out of requests: its list of 16 lasted \d+\.\d s", out.stderr), out.stderr[-3000:]
+
+
 # ------------------------------------------------- readers, on a hand-made run
 
 
@@ -863,6 +947,21 @@ def test_result_object_without_a_trace_holds_the_cell_s_end_to_end_metrics(ctx):
     assert out["device"]["busy_s"] == trace["busy_s"] and out["device"]["window_s"] == 2.0
     assert len(out["breakdown"]["device_ops"]) <= 10 and set(out["breakdown"]) == {"device_ops", "idle_gaps"}
     json.dumps(out)
+
+
+def test_what_was_compared_is_printed_as_plain_numbers_beside_its_limit_and_why_a_run_is_not_correct():
+    """``run.py`` ends a run's standard error, and its result line, with
+    every number the driver's comparison held beside its limit."""
+    import numpy as np
+
+    ctx = {"compared": {"gap_ratio": [np.float32(1.5), 1.45], "state_error_at_0.5": [0.0, 4e-05], "near_tie_gap": [None, 8e-05]},
+           "why_incorrect": ["gap_ratio 1.5 over the limit 1.45"]}
+    assert json.loads(json.dumps(bench_run.compared_numbers(ctx))) == {"gap_ratio": [1.5, 1.45], "state_error_at_0.5": [0.0, 4e-05], "near_tie_gap": [None, 8e-05]}
+    assert bench_run.compared_lines(ctx) == [
+        "compared: gap_ratio 1.5 (limit 1.45)", "compared: state_error_at_0.5 0.0 (limit 4e-05)", "compared: near_tie_gap None (limit 8e-05)",
+        "not correct: gap_ratio 1.5 over the limit 1.45",
+    ]
+    assert bench_run.compared_lines({"correct": True}) == []  # a driver that hands nothing over prints nothing
 
 
 def test_a_run_that_lacks_an_end_to_end_metric_prints_no_result():
