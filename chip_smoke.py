@@ -8,6 +8,7 @@
     python chip_smoke.py --expert-product    # the routed experts' sum alone: dense against grouped
     python chip_smoke.py --release-probe     # what the drop of a consumed step's device arrays waits for
     python chip_smoke.py --dispatch-probe    # ms a decode dispatch (args / upload / call), steady and after a change
+    python chip_smoke.py --evict-probe       # ms an eviction and ms a pressure tick of the prefix index, beside the plain scan
     python chip_smoke.py --group16           # the group-16 paged calls and the streamed prefill kernel alone
     python chip_smoke.py --grouped-kernels   # every grouped paged call of the cells alone (``--tree .parent``: a parent commit's)
     python chip_smoke.py --walk-sweep        # ... at 1 to 32 table columns a grid step
@@ -1310,6 +1311,55 @@ def release_probe(width=None, rows=None, step_ms=None, reps=None, streams=None, 
     return out
 
 
+def evict_probe(sizes=(1000, 7400, 15000), evictions: int = 100, ticks: int = 200) -> dict:
+    """What the prefix index costs the scheduler's thread on this host
+    (no device work): ms an eviction (``reclaim(1)``, the victim
+    dropped) and ms a pressure tick (``CacheTelemetry.tick``, once a
+    scheduler iteration) at ``sizes`` entries, every one resident and
+    unreferenced — ``heap``, the index as it is, beside ``scan``, the
+    same index finding its victim and its count by a walk over every
+    entry, written out here."""
+    from flexflow_tpu.generation.cache import BlockAllocator, CacheConfig
+    from flexflow_tpu.generation.prefix import PrefixCache
+    from flexflow_tpu.obs.capacity import CacheTelemetry
+
+    class Scan(PrefixCache):
+        @property
+        def evictable_blocks(self):
+            with self._lock:
+                return sum(1 for e in self._by_id.values() if e.resident and e.refs == 0)
+
+        def _pop_victim(self):
+            cands = [e for e in self._by_id.values() if e.resident and e.refs == 0]
+            return min(cands, key=lambda e: (e.last_touch, -e.depth)) if cands else None
+
+    out = {}
+    for n in sizes:
+        out[str(n)] = {}
+        for name, index in (("scan", Scan), ("heap", PrefixCache)):
+            config = CacheConfig(num_layers=1, num_heads=1, head_dim=64, num_blocks=n + 1, block_size=16)
+            alloc = BlockAllocator(config)
+            pc = index(alloc, config)
+            held = []
+            for i in range(n // 4):  # chains of four blocks, as a 64-token prompt leaves
+                prompt = [i] * 16 + list(range(49))
+                pc.register_chain(prompt, alloc.allocate(4), set(), held, len(prompt))
+            pc.release(held)
+            telemetry = CacheTelemetry(alloc, reclaimable=lambda pc=pc: pc.evictable_blocks)
+            t0 = time.perf_counter()
+            for _ in range(ticks):
+                telemetry.tick()
+            tick_ms = (time.perf_counter() - t0) * 1e3 / ticks
+            t0 = time.perf_counter()
+            for _ in range(evictions):
+                check(pc.reclaim(1) == 1, f"evict probe: {name} at {n} entries evicts")
+            evict_ms = (time.perf_counter() - t0) * 1e3 / evictions
+            check(pc.evictable_blocks == pc.resident_blocks == 4 * (n // 4) - evictions, f"evict probe: {name} at {n} entries counts")
+            out[str(n)][name] = {"tick_ms": round(tick_ms, 5), "evict_ms": round(evict_ms, 5)}
+        log(f"evict probe: {n} entries: {json.dumps(out[str(n)])}")
+    return out
+
+
 def dispatch_probe(slots_list=(8, 64), num_layers: int = 24, short: int = 40, long: int = 100) -> dict:
     """What a decode dispatch costs the scheduler's thread, by child
     span (``args`` / ``upload`` / ``call``, ms, medians), at each of
@@ -2089,6 +2139,8 @@ def main(argv=None) -> int:
                     help="the drop of a finished step's device arrays, timed beside a program in flight and beside woken stream threads")
     ap.add_argument("--dispatch-probe", action="store_true",
                     help="ms a decode dispatch by child span, a steady step beside one after a composition change, at 8 and 64 slots")
+    ap.add_argument("--evict-probe", action="store_true",
+                    help="ms an eviction and ms a pressure tick of the prefix index at 1,000 / 7,400 / 15,000 entries, beside the plain scan")
     ap.add_argument("--tree", default=None, metavar="DIR",
                     help="import flexflow_tpu from DIR (a parent commit unpacked under the checkout) and not from here")
     args = ap.parse_args(argv)
@@ -2165,6 +2217,8 @@ def main(argv=None) -> int:
         name = "dispatch_probe_" + (pathlib.Path(args.tree).name.strip(".") if args.tree else "change") + ".json"
         (REPO / "chiprun_out" / "pr40" / name).write_text(json.dumps(summary["dispatch_probe"]) + "\n")
         print(json.dumps(summary["dispatch_probe"]), flush=True)
+    elif args.evict_probe:
+        summary["evict_probe"] = evict_probe()
     else:
         summary["calibration"] = calibration_hit(dev.device_kind)
         log(f"calibration lookup for {dev.device_kind!r}: {summary['calibration']}")
@@ -2195,6 +2249,7 @@ def main(argv=None) -> int:
             else "chip_smoke_loss_chain.json" if args.loss_chain
             else "chip_smoke_train_ops" + ("_" + pathlib.Path(args.tree).name.strip(".") if args.tree else "") + ".json" if args.train_ops
             else "chip_smoke_release.json" if args.release_probe else "chip_smoke_dispatch.json" if args.dispatch_probe
+            else "chip_smoke_evict.json" if args.evict_probe
             else "chip_smoke.json")
     (out_dir / name).write_text(json.dumps(summary, indent=1, default=str) + "\n")
     print(json.dumps({"ok": True, "device": device}), flush=True)

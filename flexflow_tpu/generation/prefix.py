@@ -28,7 +28,9 @@ the existing block-structured cache:
   copy, admission-time only) and the copy becomes sequence-private.
 
 * **Host-RAM offload tier.** Cold blocks (refcount 0, LRU by last
-  touch) swap out to host buffers instead of being dropped — including
+  touch: the index keeps them in that order as it goes, so an eviction
+  pops its victim and never walks the entries) swap out to host buffers
+  instead of being dropped — including
   preempt-evicted blocks, so a preempted request's re-admission can
   swap its KV back in instead of recomputing it. Swap-in vs recompute
   is decided by the PR 7 cost-model roofline (transfer bytes over the
@@ -53,6 +55,7 @@ never takes it (prefix work is admission-time only).
 """
 from __future__ import annotations
 
+import heapq
 import threading
 import time
 import zlib
@@ -98,11 +101,15 @@ class PrefixEntry:
     window pool takes back (:meth:`PrefixCache.reclaim_window`). On the
     host tier the window half rides in ``host_s``. A prefix can be
     resumed at a boundary only if the entries of the window behind it
-    still have their halves (:meth:`PrefixCache.has_window`)."""
+    still have their halves (:meth:`PrefixCache.has_window`).
+
+    ``queued`` says the entry has its key in the index's victim order
+    (:meth:`PrefixCache._evictable_now`): at most one key an entry."""
 
     __slots__ = (
         "eid", "parent_eid", "tokens", "depth", "block", "wblock", "wrefs",
         "host_k", "host_v", "host_s", "crc", "refs", "children", "last_touch",
+        "queued",
     )
 
     def __init__(self, eid: int, parent_eid: int, tokens: Tuple[int, ...],
@@ -121,6 +128,7 @@ class PrefixEntry:
         self.refs = 0
         self.children = 0
         self.last_touch = 0.0
+        self.queued = False
 
     @property
     def resident(self) -> bool:
@@ -201,6 +209,23 @@ class PrefixCache:
     *outstanding* from the allocator's point of view until eviction
     frees it), and the engine performs all device reads/writes through
     the jitted block-copy programs it passes in.
+
+    The resident, unreferenced entries (the evictable ones) are kept in
+    eviction order: a binary heap of ``(last_touch, -depth, eid)`` keys,
+    one key an entry at most, pushed when the entry becomes evictable. A
+    touch or a reference does not move the key: ``last_touch`` only
+    grows, so a stored key is a lower bound of the entry's true one, and
+    :meth:`reclaim` pops the least key, pushes it back under its true
+    value if the entry was touched since, and discards it if the entry
+    is referenced or gone. The victims are those a scan for the least
+    ``(last_touch, -depth)`` over the entries in the order of their
+    ``eid`` would pick, one after the other; an orphan (its parent
+    dropped from the trie) stays in the order, so its block comes back
+    under pressure like any other. The heap never holds more keys than
+    there are resident entries, and ``evictable_blocks``,
+    ``resident_blocks`` and ``offloaded_blocks`` are counts kept where
+    the entries change, so nothing a scheduler iteration calls walks
+    the entries.
     """
 
     ROOT = 0  # parent_eid of depth-0 entries
@@ -241,6 +266,13 @@ class PrefixCache:
         # (parent_eid, token tuple) -> entry; entries by id — guarded-by: _lock
         self._edges: Dict[Tuple[int, Tuple[int, ...]], PrefixEntry] = {}
         self._by_id: Dict[int, PrefixEntry] = {}
+        # the victim order: (last_touch, -depth, eid, entry), the key of
+        # every evictable entry and of some that no longer are — guarded-by: _lock
+        self._victims: List[Tuple[float, int, int, PrefixEntry]] = []
+        # entries on the device, and those of them that are evictable:
+        # written under _lock, read without it like the telemetry below
+        self._resident = 0
+        self._evictable = 0
         # telemetry (admission-path writes; gauges read without the
         # lock — plain ints under the GIL, same idiom as CacheTelemetry)
         self.lookups = 0
@@ -256,6 +288,11 @@ class PrefixCache:
         self.evicted_total = 0
         self.dropped_total = 0
         self.host_bytes = 0
+        # keys popped off the victim order, and those of them that named
+        # no victim (pushed back under a later touch, or discarded):
+        # evicted_total over victim_pops_total is the order's hit share
+        self.victim_pops_total = 0
+        self.victim_stale_total = 0
         # where the seconds of the host tier's two spans go (the engine
         # opens ff.cache.offload around reclaim() and ff.cache.restore
         # around a swap-in): the scheduler points this at its model's
@@ -265,22 +302,25 @@ class PrefixCache:
     # ------------------------------------------------------------- queries
     @property
     def resident_blocks(self) -> int:
-        with self._lock:
-            return sum(1 for e in self._by_id.values() if e.resident)
+        return self._resident
 
     @property
     def offloaded_blocks(self) -> int:
         with self._lock:
-            return sum(1 for e in self._by_id.values() if not e.resident)
+            return len(self._by_id) - self._resident
 
     @property
     def evictable_blocks(self) -> int:
         """Device blocks reclaimable on demand (resident, unreferenced)
-        — counted as available by the pressure telemetry."""
+        — counted as available by the pressure telemetry, once a
+        scheduler iteration."""
+        return self._evictable
+
+    @property
+    def victim_keys(self) -> int:
+        """Keys the victim order holds now, stale ones included."""
         with self._lock:
-            return sum(
-                1 for e in self._by_id.values() if e.resident and e.refs == 0
-            )
+            return len(self._victims)
 
     def hit_ratio(self) -> float:
         return self.hits / self.lookups if self.lookups else 0.0
@@ -291,7 +331,8 @@ class PrefixCache:
         residency — counters ride as gauges like the cache_* family."""
         stats.add_gauge("prefix_cache_hit_ratio", self.hit_ratio)
         for name in ("blocks_reused_total", "tokens_reused_total", "cow_copies_total", "swaps_in_total",
-                     "swaps_out_total", "host_bytes", "resident_blocks", "offloaded_blocks"):
+                     "swaps_out_total", "host_bytes", "resident_blocks", "offloaded_blocks",
+                     "victim_pops_total", "victim_stale_total", "victim_keys"):
             stats.add_gauge("prefix_cache_" + name, lambda name=name: getattr(self, name))
 
     def match(self, prompt: Sequence[int]) -> List[PrefixEntry]:
@@ -352,6 +393,8 @@ class PrefixCache:
         now = self.clock()
         with self._lock:
             for e in entries:
+                if e.refs == 0 and e.resident and self._by_id.get(e.eid) is e:
+                    self._evictable -= 1  # its key goes stale where it lies
                 e.refs += 1
                 e.last_touch = now
 
@@ -363,6 +406,8 @@ class PrefixCache:
             for e in entries:
                 if self._by_id.get(e.eid) is e and e.refs > 0:
                     e.refs -= 1
+                    if e.refs == 0 and e.resident:
+                        self._evictable_now(e)
 
     # ----------------------------------------------------------- registration
     def register_chain(
@@ -410,6 +455,7 @@ class PrefixCache:
                         self._by_id[parent].children += 1
                     entry.refs += 1
                     entry.last_touch = now
+                    self._resident += 1
                     shared_idx.add(j)
                     entries.append(entry)
                     self.registered_total += 1
@@ -421,6 +467,7 @@ class PrefixCache:
                     entry.block = blocks[j]
                     entry.refs += 1
                     entry.last_touch = now
+                    self._resident += 1
                     shared_idx.add(j)
                     entries.append(entry)
                     n_new += 1
@@ -430,17 +477,52 @@ class PrefixCache:
 
     # ------------------------------------------------------------- eviction
     def _drop_host(self, entry: PrefixEntry) -> None:
-        if entry.host_k is not None:
+        # what a reset() left behind is in host_bytes no longer
+        if entry.host_k is not None and self._by_id.get(entry.eid) is entry:  # flexlint: disable=lock-discipline — every caller holds _lock
             self.host_bytes -= self.bytes_per_block
         entry.host_k = None
         entry.host_v = None
         entry.host_s = None
         entry.crc = None
 
+    def _evictable_now(self, entry: PrefixEntry) -> None:
+        """``entry`` (in the trie, resident) has just lost its last
+        reference, or come back to the device with none: count it and
+        see that the victim order has its key. A key already there is
+        left where it is, a lower bound of the true one. Caller holds
+        _lock."""
+        self._evictable += 1
+        if not entry.queued:
+            entry.queued = True
+            heapq.heappush(self._victims, (entry.last_touch, -entry.depth, entry.eid, entry))  # flexlint: disable=lock-discipline — caller holds _lock (see docstring)
+
+    def _pop_victim(self) -> Optional[PrefixEntry]:
+        """Take the next victim off the victim order: the evictable
+        entry of least ``(last_touch, -depth, eid)``, None when nothing
+        is evictable. Caller holds _lock."""
+        victims = self._victims  # flexlint: disable=lock-discipline — caller holds _lock (see docstring)
+        while victims:
+            touch, _, eid, entry = victims[0]
+            self.victim_pops_total += 1
+            if entry.refs == 0 and entry.resident and self._by_id.get(eid) is entry:
+                if entry.last_touch == touch:
+                    heapq.heappop(victims)
+                    entry.queued = False
+                    return entry
+                # touched since its key was stored: back under the true one
+                heapq.heapreplace(victims, (entry.last_touch, -entry.depth, eid, entry))
+            else:
+                # referenced (release() queues it again), or gone
+                heapq.heappop(victims)
+                entry.queued = False
+            self.victim_stale_total += 1
+        return None
+
     def _remove(self, entry: PrefixEntry) -> None:
-        """Drop ``entry`` from the trie entirely. Caller holds _lock
-        (reclaim and _enforce_host_budget both invoke this inside their
-        ``with self._lock:`` blocks)."""
+        """Drop ``entry``, which no longer holds a device block, from
+        the trie entirely. Caller holds _lock (reclaim and
+        _enforce_host_budget both invoke this inside their ``with
+        self._lock:`` blocks)."""
         self._drop_host(entry)
         del self._edges[(entry.parent_eid, entry.tokens)]  # flexlint: disable=lock-discipline — caller holds _lock (see docstring)
         del self._by_id[entry.eid]
@@ -467,12 +549,9 @@ class PrefixCache:
         freed = 0
         while freed < n_blocks:
             with self._lock:
-                cands = [
-                    e for e in self._by_id.values() if e.resident and e.refs == 0
-                ]
-                if not cands:
+                victim = self._pop_victim()
+                if victim is None:
                     break
-                victim = min(cands, key=lambda e: (e.last_touch, -e.depth))
                 # an orphan (its parent already dropped from the trie)
                 # can never be reached by match() again: drop it free
                 # instead of paying a device read + host budget for
@@ -505,13 +584,15 @@ class PrefixCache:
             with self._lock:
                 block, victim.block = victim.block, None
                 wblock, victim.wblock = victim.wblock, 0
+                self._resident -= 1
+                self._evictable -= 1
                 if not offloaded:
                     # dropped: no tier holds the content, so the node
                     # leaves the trie. Descendants are orphaned (the
                     # match walk can no longer reach them) but stay
-                    # evictable — reclaim scans all entries, so their
-                    # blocks still come back under pressure and their
-                    # own removal tolerates the missing parent.
+                    # evictable — their keys stay in the victim order,
+                    # so their blocks still come back under pressure and
+                    # their own removal tolerates the missing parent.
                     self._remove(victim)
                 self.evicted_total += 1
             self.allocator.free([block])
@@ -595,6 +676,10 @@ class PrefixCache:
             entry.wblock = wblock
             entry.last_touch = self.clock()
             self.swaps_in_total += 1
+            if self._by_id.get(entry.eid) is entry:
+                self._resident += 1
+                if entry.refs == 0:
+                    self._evictable_now(entry)
 
     # ------------------------------------------------------ decision model
     def swap_in_cost_s(self, n_blocks: int) -> float:
@@ -612,12 +697,14 @@ class PrefixCache:
         with self._lock:
             self._edges.clear()
             self._by_id.clear()
+            self._victims.clear()
+            self._resident = self._evictable = 0
             self.host_bytes = 0
 
     # -------------------------------------------------------------- report
     def snapshot(self) -> Dict:
         with self._lock:
-            resident = sum(1 for e in self._by_id.values() if e.resident)
+            resident = self._resident
             offloaded = len(self._by_id) - resident
             shared = sum(1 for e in self._by_id.values() if e.refs > 0)
         return {
@@ -639,6 +726,9 @@ class PrefixCache:
             "recompute_fallbacks": self.recompute_fallbacks,
             "registered_total": self.registered_total,
             "evicted_total": self.evicted_total,
+            "victim_pops_total": self.victim_pops_total,
+            "victim_stale_total": self.victim_stale_total,
+            "victim_keys": self.victim_keys,
             **({"window_dropped_total": self.window_dropped_total} if self.window_allocator is not None else {}),
         }
 

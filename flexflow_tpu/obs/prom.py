@@ -104,6 +104,9 @@ _HELP = {
     "prefix_cache_host_bytes": "Bytes currently resident in the host-RAM KV tier.",
     "prefix_cache_resident_blocks": "Device blocks currently owned by the prefix index.",
     "prefix_cache_offloaded_blocks": "Prefix blocks currently on the host-RAM tier.",
+    "prefix_cache_victim_pops_total": "Keys popped off the prefix index's eviction order (cumulative): each an eviction or a stale key.",
+    "prefix_cache_victim_stale_total": "Popped keys that named no victim: pushed back under a later touch, or discarded (cumulative).",
+    "prefix_cache_victim_keys": "Keys the prefix index's eviction order holds now, stale ones included; never more than the resident blocks.",
     "flexflow_sim_prediction_error_ratio": "Signed relative error of simulator/cost-model predictions vs measured time, per key quantile.",
     "flexflow_sim_prediction_pairs_total": "Measured samples joined with a registered prediction, per key.",
     "flexflow_sim_prediction_unpredicted_total": "Measured samples that had no registered prediction (counted, not dropped).",

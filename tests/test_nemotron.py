@@ -347,4 +347,4 @@ def test_the_stored_state_s_distance_is_read_by_request_and_head_and_not_pooled(
     one_row = theirs.copy()
     one_row[0, 5] *= 0.5
     assert nemotron_h.state_error(one_row, theirs) == pytest.approx([0.5, 0.0])
-    assert nemotron_h.state_error(one_row, theirs, 0.5).tolist() == [0.0, 0.0]  # (why 0.9 and not the median)
+    assert nemotron_h.state_error(one_row, theirs, 0.5).tolist() == [0.0, 0.0]  # (why the 0.9 share is held beside the median)

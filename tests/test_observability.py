@@ -249,6 +249,9 @@ def _golden_stats():
     s.add_gauge("prefix_cache_host_bytes", lambda: 4096)
     s.add_gauge("prefix_cache_resident_blocks", lambda: 5)
     s.add_gauge("prefix_cache_offloaded_blocks", lambda: 2)
+    s.add_gauge("prefix_cache_victim_pops_total", lambda: 9)
+    s.add_gauge("prefix_cache_victim_stale_total", lambda: 4)
+    s.add_gauge("prefix_cache_victim_keys", lambda: 3)
     # ISSUE 14 overload-control families (binary-exact values); the
     # per-reason/per-priority rejection split joins requests_total as
     # dynamic counters like drafter_errors above
