@@ -33,7 +33,8 @@ setting of every one of them):
   frequencies and the factor on cos and sin) — ``latent``, attention
   whose K and V come from ONE low-rank row a token shared by all heads
   (below) — or ``conv``, a gated short convolution:
-  (or ``ssm``, a Mamba-2 state-space mixer: :func:`_ssm` has its
+  (or ``mamba``, ``gmu``, ``cross``: a decoder-hybrid-decoder's kinds,
+  below; or ``ssm``, a Mamba-2 state-space mixer: :func:`_ssm` has its
   equations; its per-sequence state is the convolution's last ``K - 1``
   input rows and a float32 recurrent state ``[H, P, N]``, kept per batch
   slot and nowhere else: ops/ssm.py, generation/cache.py) — the gated
@@ -122,6 +123,52 @@ equal in exact arithmetic:
   (sum_j p_i(t, j) c(j)) W_UV_i``. ``W_UK`` / ``W_UV`` are the two halves
   of the stored ``W_UKV``, sliced where they are used.
 
+**A decoder-hybrid-decoder** (SambaY: ``mamba``, ``window``, ONE
+``attention`` layer, then ``gmu`` and ``cross`` layers; every layer
+``sequential`` with its own feed-forward). With ``h`` the layer's normed
+input:
+
+* ``mamba``, a Mamba-1 mixer (``D = mamba_expand x E``, ``N =
+  ssm_state_size``, ``R = dt_rank``): ``[x, z] = W_in h``; ``x <-
+  silu(conv1d_causal(x) + b)`` (depthwise, kernel K, zeros before the
+  sequence); ``[delta, B, C] = W_x x`` (D -> R + 2N); ``dt = softplus(W_dt
+  delta + b_dt)`` [D]; ``A = -exp(A_log)`` [D, N]; in float32
+  ``S_t[d, n] = exp(dt_t[d] A[d, n]) S_{t-1}[d, n] + dt_t[d] B_t[n] x_t[d]``,
+  ``y_t[d] = sum_n C_t[n] S_t[d, n] + D[d] x_t[d]``; ``out = W_out (y_t *
+  silu(z_t))``. A decay for every (channel, state) pair and no heads: the
+  three forms are ops/ssm.py ``selective_*``. Its state per sequence is the
+  convolution's last ``K - 1`` rows of ``x`` and ``S`` stored ``[N, D]``
+  float32, per batch slot and nowhere else. The layer ``memory_source``
+  also hands on ``m_t = y_t`` (before the gate): the MEMORY, carried to the
+  ``gmu`` layers inside the one forward and never cached.
+* ``gmu``, a Gated Memory Unit: ``out = W_2 (m_t * silu(W_1 h))``, ``m_t``
+  the memory at the same row. It keeps nothing.
+* ``cross``: attention that has ``W_q`` and ``W_o`` alone and reads the K/V
+  that layer ``kv_source`` stores (a ``cross`` layer ATTENDS and has no K/V
+  of its own: ``attention_layers`` against ``kv_layers``; ``kv_index`` names
+  the producer's place in the arrays). It writes nothing.
+* ``differential`` (every attending layer): query pair ``i`` is ``q1_i =
+  q[2i]``, ``q2_i = q[2i + 1]``; K/V pair ``j`` is ``k1_j = k[2j]``, ``k2_j =
+  k[2j + 1]``, ``V_j = [v[2j] ‖ v[2j + 1]]``; query pair ``i`` reads K/V pair
+  ``i // (H / Hkv)``. ``a1_i = softmax(q1_i k1_j^T / sqrt(D) + mask) V_j``,
+  ``a2_i`` of ``q2_i``, ``k2_j``; ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2)
+  + lambda_init``, ``lambda_init = 0.8 - 0.6 exp(-0.3 l)``; ``o_i = (1 -
+  lambda_init) RMSNorm_2D(a1_i - lambda a2_i; g)``, the ``H / 2`` x ``2D``
+  read as ``H`` x ``D`` into ``W_o`` (biases on the four projections with
+  ``attention_bias``). It runs through the attention calls AS THEY STAND, in
+  a padded-query form: a layer's K/V is stored as ``Hkv / 2`` heads of ``2D``
+  (``[k1_j ‖ k2_j]``, ``[v1_j ‖ v2_j]``: the projection's own row-major order)
+  and pair ``j`` gets the query rows ``[q1 ‖ 0]`` and ``[0 ‖ q2]`` of each of
+  its query pairs, times ``sqrt(2)`` so that a call's own ``1 / sqrt(2D)``
+  comes to ``1 / sqrt(D)``; prefill, the window call and the full call then
+  compute ``a1`` and ``a2`` with K/V read once, and lambda and the pair norm
+  are an elementwise epilogue (:func:`_diff_qkv`, :func:`_diff_out`). The
+  stored pairs are padded to a count of rows a block of the cache can be
+  copied by (``cache_kv_heads``: 10 pairs are stored as 16 rows, 6 zero).
+* a prefill (``last_only``) runs the layers from ``cross_from`` on, and the
+  head, on each sequence's LAST row alone: they store nothing a later
+  position reads.
+
 **Block diffusion** (``block_mask = B`` > 0). The mask is not causal:
 position ``i`` attends position ``j`` iff ``j // B <= i // B`` — every
 earlier block, and the whole of its own, later rows included — and the
@@ -170,7 +217,7 @@ import jax.numpy as jnp
 from ..core.types import DataType
 from ..models.transformer import TransformerConfig
 from ..ops.attention import (
-    append_attention_core, decode_attention_core, latent_attention_core, prefill_attention,
+    append_attention_core, decode_attention_core, latent_attention_core, masked_attention, prefill_attention,
 )
 from ..ops import ssm as ssm_ops
 from ..ops.expert_product import expert_lowering, grouped_expert_sum
@@ -267,13 +314,28 @@ class DecoderConfig(TransformerConfig):
     # hidden size; the router and the shared expert read h itself
     moe_latent_size: int = 0
     shared_ff_size: int = 0  # the shared expert's width; 0: num_shared_experts x moe_ff_size
+    # a "mamba" layer (Mamba-1, module docstring): the inner width is `mamba_expand` x hidden_size, the step's
+    # low rank `mamba_dt_rank` (0: ceil(hidden_size / 16)); the state's width, the convolution's kernel, the
+    # prefill's chunk and the steps' range are the ssm fields above (`ssm_state_size`, `ssm_conv_kernel`, ...)
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    # differential attention in every layer that attends (module docstring): two softmaxes over paired heads,
+    # subtracted, a norm over the pair; K/V is stored as `kv_heads / 2` heads of `2 x head_dim`
+    differential: bool = False
+    attention_bias: bool = False  # biases on W_q, W_k, W_v and W_o
+    # a "cross" layer attends the K/V that layer `kv_source` (an "attention" layer before it) stores, and has
+    # none of its own; a "gmu" layer gates the scan output of layer `memory_source` (a "mamba" layer before it)
+    kv_source: int = -1
+    memory_source: int = -1
 
     def __post_init__(self):
         if self.layer_types and len(self.layer_types) != self.num_layers:
             raise ValueError(f"{len(self.layer_types)} layer_types for {self.num_layers} layers")
         for kind in self.layer_types:
-            if kind not in ("attention", "window", "latent", "conv", "ssm", "ffn"):
-                raise ValueError(f"layer type {kind!r}: 'attention', 'window', 'latent', 'conv', 'ssm' or 'ffn'")
+            if kind not in ("attention", "window", "latent", "conv", "ssm", "ffn", "mamba", "gmu", "cross"):
+                raise ValueError(
+                    f"layer type {kind!r}: 'attention', 'window', 'latent', 'conv', 'ssm', 'ffn', 'mamba', 'gmu' or 'cross'"
+                )
         if "window" in self.layer_types and self.window < 1:
             raise ValueError("a 'window' layer needs window >= 1")
         if self.router not in ("sigmoid", "softmax"):
@@ -288,8 +350,35 @@ class DecoderConfig(TransformerConfig):
             h, p, g, n = self.ssm_heads, self.ssm_head_dim, self.ssm_groups, self.ssm_state_size
             if min(h, p, g, n) < 1 or h % g or self.ssm_conv_kernel < 2 or self.ssm_chunk < 1:
                 raise ValueError(f"an 'ssm' layer needs its heads, head width, groups (dividing the heads) and state width, got {(h, p, g, n)}")
-            if self.conv_layers or self.window_layers or self.latent_layers:
-                raise ValueError("ssm layers beside convolution, window or latent layers: one kind of state beside paged K/V")
+            if self.conv_layers or self.window_layers or self.latent_layers or self.mamba_layers:
+                raise ValueError(
+                    "Mamba-2 ('ssm') layers beside convolution, window, latent or Mamba-1 layers: per-slot state beside a "
+                    "window pool is written down, and tested, for Mamba-1 ('mamba') layers"
+                )
+        if self.mamba_layers:
+            if self.ssm_state_size < 1 or self.mamba_expand < 1 or self.ssm_conv_kernel < 2 or self.ssm_chunk < 1:
+                raise ValueError(
+                    f"a 'mamba' layer needs its state width, expansion, kernel and chunk, got "
+                    f"{(self.ssm_state_size, self.mamba_expand, self.ssm_conv_kernel, self.ssm_chunk)}"
+                )
+            if self.conv_layers or self.latent_layers or self.block != "sequential":
+                raise ValueError("mamba layers are written down for a sequential block, beside attention, window, gmu and cross layers")
+        for kind, source, producer in (("cross", self.kv_source, "attention"), ("gmu", self.memory_source, "mamba")):
+            readers = tuple(l for l in range(self.num_layers) if self.operator(l) == kind)
+            if readers and not (0 <= source < readers[0] and self.operator(source) == producer):
+                raise ValueError(
+                    f"a {kind!r} layer reads what layer {source} keeps: that has to be a {producer!r} layer before the first of them "
+                    f"(layer {readers[0]})"
+                )
+        if self.differential:
+            rotated = self.positions == "rotary" and any(
+                self.rope_parameters.get(kind, {}).get("positions") != "none" for kind in ("attention", "window")
+            )
+            if self.num_heads % 2 or self.kv_heads % 2 or self.num_heads % self.kv_heads or self.qk_norm or rotated or self.latent_layers or self.block_mask or self.block != "sequential":
+                raise ValueError(
+                    "differential attention pairs neighbouring heads (an even count of query and of K/V heads) and is written "
+                    "down without rotation, q/k norm, latent layers or a block mask, in a sequential block"
+                )
         if self.expert_activation not in ("swiglu", "relu2"):
             raise ValueError(f"expert_activation {self.expert_activation!r}: 'swiglu' or 'relu2'")
         if self.block == "parallel" and (self.stateful or self.latent_layers):
@@ -340,8 +429,60 @@ class DecoderConfig(TransformerConfig):
 
     @property
     def attention_layers(self) -> Tuple[int, ...]:
-        """Layers with K/V, of either kind, in layer order."""
-        return tuple(l for l in range(self.num_layers) if self.operator(l) not in ("conv", "ssm", "ffn"))
+        """Layers that ATTEND, in layer order: those with K/V of their
+        own, of either kind, and the ``cross`` layers, which read another
+        layer's (:attr:`kv_layers` are the ones that store)."""
+        return tuple(l for l in range(self.num_layers) if self.operator(l) in ("attention", "window", "latent", "cross"))
+
+    @property
+    def kv_layers(self) -> Tuple[int, ...]:
+        """Layers that STORE K/V (a ``cross`` layer attends and stores none)."""
+        return tuple(l for l in self.attention_layers if self.operator(l) != "cross")
+
+    @property
+    def cross_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "cross")
+
+    @property
+    def gmu_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "gmu")
+
+    @property
+    def cross_from(self) -> int:
+        """The first layer of the cross-decoder: from here on no layer
+        stores anything a later position reads (``gmu`` and ``cross``
+        layers and their feed-forwards), so a prefill runs these on each
+        sequence's last row alone. ``num_layers`` where there is none."""
+        readers = self.gmu_layers + self.cross_layers
+        first = min(readers) if readers else self.num_layers
+        return first if all(self.operator(l) in ("gmu", "cross") for l in range(first, self.num_layers)) else self.num_layers
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """K/V heads as the cache and the attention calls see them: with
+        differential attention a PAIR of neighbouring heads is one, and
+        the pairs are stored at the next count of rows a block of the
+        cache can be copied by: 1, 2, 4 or a multiple of 8 (the device
+        lays ``[..., R, 128]`` out in tiles of 8 rows and the paged kernel
+        copies whole tiles, so 10 pairs are stored as 16 rows, the 6
+        behind them zero). One call over 16 rows reads at the memory's
+        rate; five calls over 2 rows each, which would store nothing
+        idle, take 2.2 times as long (PERF.md section 6, PR 57)."""
+        if not self.differential:
+            return self.kv_heads
+        pairs = self.kv_heads // 2
+        return next(r for r in (1, 2, 4) if r >= pairs) if pairs <= 4 else -(-pairs // 8) * 8
+
+    @property
+    def attend_heads(self) -> int:
+        """Query heads as the attention calls see them: the real ones
+        and, where the stored pairs are padded, the group of each padded
+        pair (zero queries, whose results are dropped)."""
+        return self.cache_kv_heads * (self.num_heads // (self.kv_heads // 2)) if self.differential else self.num_heads
+
+    @property
+    def cache_head_dim(self) -> int:
+        return 2 * self.dim_per_head if self.differential else self.dim_per_head
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -371,12 +512,22 @@ class DecoderConfig(TransformerConfig):
         """For the ``ai``-th attention layer: its kind and its index in
         that kind's K/V arrays."""
         seen = {"attention": 0, "window": 0, "latent": 0}
-        out = []
+        out, at = [], {}
         for l in self.attention_layers:
             kind = self.operator(l)
+            if kind == "cross":  # reads the arrays of the layer that produced its K/V, and writes nothing
+                out.append((kind, at[self.kv_source]))
+                continue
             out.append((kind, seen[kind]))
+            at[l] = seen[kind]
             seen[kind] += 1
         return tuple(out)
+
+    @property
+    def stored_index(self) -> Tuple[Tuple[str, int], ...]:
+        """:attr:`kv_index` of the layers that store K/V: what a
+        prefill's returned K/V is indexed by."""
+        return tuple(entry for entry in self.kv_index if entry[0] != "cross")
 
     @property
     def conv_layers(self) -> Tuple[int, ...]:
@@ -384,17 +535,30 @@ class DecoderConfig(TransformerConfig):
 
     @property
     def ssm_layers(self) -> Tuple[int, ...]:
-        return tuple(l for l in range(self.num_layers) if self.operator(l) == "ssm")
+        """The state-space layers, Mamba-2 (``ssm``) or Mamba-1
+        (``mamba``): a configuration has one of the two."""
+        return tuple(l for l in range(self.num_layers) if self.operator(l) in ("ssm", "mamba"))
+
+    @property
+    def mamba_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.operator(l) == "mamba")
 
     @property
     def ssm_inner(self) -> int:
-        """An ssm layer's inner width, ``H x P``."""
-        return self.ssm_heads * self.ssm_head_dim
+        """A state-space layer's inner width: ``H x P``, or a Mamba-1
+        layer's ``mamba_expand x hidden_size``."""
+        return self.mamba_expand * self.hidden_size if self.mamba_layers else self.ssm_heads * self.ssm_head_dim
 
     @property
     def ssm_conv_width(self) -> int:
-        """What an ssm layer's convolution runs over: ``[x, B, C]``."""
-        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
+        """What a state-space layer's convolution runs over: ``[x, B,
+        C]``, or a Mamba-1 layer's ``x`` alone."""
+        return self.ssm_inner + (0 if self.mamba_layers else 2 * self.ssm_groups * self.ssm_state_size)
+
+    @property
+    def dt_rank(self) -> int:
+        """The low rank a Mamba-1 layer's step comes through."""
+        return self.mamba_dt_rank or -(-self.hidden_size // 16)
 
     def shortcut(self, layer: int) -> bool:
         """``layer`` carries a shortcut expert branch (beside its dense
@@ -465,6 +629,8 @@ def init_decoder_params(
     p = max_positions or cfg.seq_length
     # (a configuration without latent layers or shared experts draws the keys it always drew)
     per_layer = 14 if cfg.latent_layers or cfg.num_shared_experts or cfg.shortcut_experts or cfg.block == "single" else 10
+    if cfg.differential or cfg.mamba_layers:
+        per_layer = 20
     keys = iter(jax.random.split(rng, 4 + per_layer * cfg.num_layers))
     ones, zeros = jnp.ones((e,), dt), jnp.zeros((e,), dt)
     params: DecoderParams = {"tok_embed": _glorot(next(keys), (v, e), dt)}
@@ -491,15 +657,28 @@ def init_decoder_params(
             )
         elif cfg.operator(li) == "ssm":
             layer.update(_init_ssm(cfg, keys, dt))
+        elif cfg.operator(li) == "mamba":
+            layer.update(_init_mamba(cfg, keys, dt))
+        elif cfg.operator(li) == "gmu":
+            layer.update(gmu_in=_glorot(next(keys), (e, cfg.ssm_inner), dt), gmu_out=_glorot(next(keys), (cfg.ssm_inner, e), dt))
         elif cfg.operator(li) == "ffn":
             pass  # the layer is its feed-forward alone
         elif cfg.operator(li) != "conv":
-            layer.update(
-                wq=_glorot(next(keys), (e, h, d), dt), wk=_glorot(next(keys), (e, hk, d), dt),
-                wv=_glorot(next(keys), (e, hk, d), dt), wo=_glorot(next(keys), (h, d, e), dt),
-            )
+            layer.update(wq=_glorot(next(keys), (e, h, d), dt))
+            if cfg.operator(li) != "cross":  # a cross layer reads another layer's K/V: W_q and W_o alone
+                layer.update(wk=_glorot(next(keys), (e, hk, d), dt), wv=_glorot(next(keys), (e, hk, d), dt))
+            layer.update(wo=_glorot(next(keys), (h, d, e), dt))
             if cfg.qk_norm:
                 layer.update(q_norm_g=jnp.ones((d,), dt), k_norm_g=jnp.ones((d,), dt))
+            if cfg.attention_bias:
+                small = lambda shape: (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(dt)  # noqa: E731
+                layer.update(bq=small((h, d)), bo=small((e,)))
+                if "wk" in layer:
+                    layer.update(bk=small((hk, d)), bv=small((hk, d)))
+            if cfg.differential:
+                # the four vectors of lambda (float32, as the router's weights are) and the pair norm's weight
+                layer.update({f"lambda_{n}": 0.1 * jax.random.normal(next(keys), (d,), jnp.float32) for n in ("q1", "k1", "q2", "k2")})
+                layer.update(subln_g=jnp.ones((2 * d,), dt))
         else:
             layer.update(
                 conv_in=_glorot(next(keys), (e, 3 * e), dt),
@@ -562,6 +741,25 @@ def _init_ssm(cfg: DecoderConfig, keys, dt) -> Dict[str, Any]:
         ssm_conv_b=jnp.zeros((cw,), dt), ssm_dt_bias=step + jnp.log(-jnp.expm1(-step)),
         ssm_a_log=jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)), ssm_d=jnp.ones((h,), jnp.float32),
         ssm_norm_g=jnp.ones((di,), dt), ssm_out=_glorot(next(keys), (di, e), dt),
+    )
+
+
+def _init_mamba(cfg: DecoderConfig, keys, dt) -> Dict[str, Any]:
+    """A Mamba-1 layer's weights: the projections Glorot, the rates and
+    steps as the published initialisation draws them (``A_log = log(1..N)``
+    a channel, ``dt`` log-uniform in ``cfg.ssm_dt_range`` through the
+    inverse softplus, ``D = 1``). ``A_log``, ``D`` and the step's bias stay
+    float32, as the router does."""
+    e, di, n, r = cfg.hidden_size, cfg.ssm_inner, cfg.ssm_state_size, cfg.dt_rank
+    lo, hi, floor = cfg.ssm_dt_range
+    step = jnp.exp(jax.random.uniform(next(keys), (di,), jnp.float32) * (math.log(hi) - math.log(lo)) + math.log(lo))
+    step = jnp.maximum(step, floor)
+    return dict(
+        ssm_in=_glorot(next(keys), (e, 2 * di), dt), ssm_conv_w=_glorot(next(keys), (di, cfg.ssm_conv_kernel), dt),
+        ssm_conv_b=jnp.zeros((di,), dt), ssm_x=_glorot(next(keys), (di, r + 2 * n), dt),
+        ssm_dt_w=_glorot(next(keys), (r, di), dt), ssm_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+        ssm_a_log=jnp.log(jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (di, n))),
+        ssm_d=jnp.ones((di,), jnp.float32), ssm_out=_glorot(next(keys), (di, e), dt),
     )
 
 
@@ -643,19 +841,18 @@ def _head(cfg: DecoderConfig, params, x):
 
 
 def _qkv(cfg: DecoderConfig, layer, h, positions, kind: str = "attention"):
+    # (a cross layer has W_q alone: the K/V it attends is another ATTENTION layer's, already normed and rotated)
+    cross = "wk" not in layer
     q = _mm("...e,ehd->...hd", h, layer["wq"])
-    k = _mm("...e,ehd->...hd", h, layer["wk"])
-    v = _mm("...e,ehd->...hd", h, layer["wv"])
+    k, v = (None, None) if cross else (_mm("...e,ehd->...hd", h, layer["wk"]), _mm("...e,ehd->...hd", h, layer["wv"]))
     if cfg.qk_norm:
         q = _rms(q.astype(jnp.float32), layer["q_norm_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
-        k = _rms(k.astype(jnp.float32), layer["k_norm_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
-    rope = cfg.rope_parameters.get(kind, {})
+        k = k if cross else _rms(k.astype(jnp.float32), layer["k_norm_g"].astype(jnp.float32), cfg.norm_eps).astype(h.dtype)
+    rope = cfg.rope_parameters.get("attention" if cross else kind, {})
     if cfg.positions == "rotary" and rope.get("positions") != "none":
         theta, yarn = float(rope.get("theta", cfg.rope_theta)), rope if "factor" in rope else None
-        if cfg.rope_interleave:
-            q, k = _rope_pairs(q, positions, theta), _rope_pairs(k, positions, theta)
-        else:
-            q, k = _rope(q, positions, theta, yarn), _rope(k, positions, theta, yarn)
+        rotate = (lambda x: _rope_pairs(x, positions, theta)) if cfg.rope_interleave else (lambda x: _rope(x, positions, theta, yarn))
+        q, k = rotate(q), k if cross else rotate(k)
     return q, k, v
 
 
@@ -953,6 +1150,13 @@ def _ffn(cfg: DecoderConfig, li: int, layer, x, live, counts: Optional[List], no
         return x + out + layer["ff2_b"] if normed is None else out + layer["ff2_b"]
 
 
+def _ssm_conv(layer, xpad, t: int):
+    """``silu(conv1d_causal(.) + b)`` of a state-space layer for the
+    window's ``t`` tokens out of its padded rows (depthwise, float32)."""
+    w, xf = layer["ssm_conv_w"].astype(jnp.float32), xpad.astype(jnp.float32)
+    return jax.nn.silu(sum(w[:, j] * xf[:, j : j + t] for j in range(w.shape[1])) + layer["ssm_conv_b"].astype(jnp.float32))
+
+
 def _ssm(cfg: DecoderConfig, layer, h, live, si: int, window: Callable, scan: Callable):
     """A Mamba-2 layer's mixer of its normed input ``h`` ([B, S, E], or
     [B, E] for a decode step's one position): ``[z, xBC, dt] = W_in h``;
@@ -974,10 +1178,7 @@ def _ssm(cfg: DecoderConfig, layer, h, live, si: int, window: Callable, scan: Ca
     z, xbc, dt = proj[..., :di], proj[..., di : di + cfg.ssm_conv_width], proj[..., di + cfg.ssm_conv_width :]
     xpad = window(si, xbc)  # [B, S + K - 1, width]
     with jax.named_scope("ssm.conv"):
-        w, t = layer["ssm_conv_w"].astype(jnp.float32), xbc.shape[1]
-        xf = xpad.astype(jnp.float32)
-        mixed = sum(w[:, j] * xf[:, j : j + t] for j in range(w.shape[1])) + layer["ssm_conv_b"].astype(jnp.float32)
-        xbc = jax.nn.silu(mixed).astype(h.dtype)
+        xbc = _ssm_conv(layer, xpad, xbc.shape[1]).astype(h.dtype)
     lead = xbc.shape[:2]
     x = jnp.where(live[..., None], xbc[..., :di], 0).reshape(*lead, hh, p)
     b = xbc[..., di : di + gn].reshape(*lead, cfg.ssm_groups, cfg.ssm_state_size)
@@ -991,6 +1192,102 @@ def _ssm(cfg: DecoderConfig, layer, h, live, si: int, window: Callable, scan: Ca
     return out[:, 0] if one else out
 
 
+def lambda_init(layer: int) -> float:
+    """Differential attention's constant of layer index ``layer``."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def _diff_qkv(cfg: DecoderConfig, layer, h):
+    """A differential layer's projections in the PADDED-QUERY form (module
+    docstring): ``q`` [..., H, 2D], query head ``2i`` as ``[q1_i ‖ 0]`` and
+    ``2i + 1`` as ``[0 ‖ q2_i]`` (behind them zero heads where the stored
+    pairs are padded: ``cfg.attend_heads``), times ``sqrt(2)`` in float32 before the one
+    cast (a call's own ``1 / sqrt(2D)`` then comes to ``1 / sqrt(D)``); ``k``
+    / ``v`` [..., Hkv / 2, 2D], neighbouring heads side by side, which is
+    the projection's own row-major order: nothing moves. A ``cross`` layer
+    has no K/V of its own: ``k`` and ``v`` are None."""
+    f32 = jnp.float32
+    q = jnp.einsum("...e,ehd->...hd", h, layer["wq"], preferred_element_type=f32)
+    if cfg.attention_bias:
+        q = q + layer["bq"].astype(f32)
+    q = (q * math.sqrt(2.0)).astype(h.dtype)
+    pair = q.reshape(*q.shape[:-2], q.shape[-2] // 2, 2, q.shape[-1])
+    zero = jnp.zeros_like(pair[..., 0, :])
+    q = jnp.stack(
+        [jnp.concatenate([pair[..., 0, :], zero], axis=-1), jnp.concatenate([zero, pair[..., 1, :]], axis=-1)], axis=-2
+    ).reshape(*q.shape[:-1], 2 * q.shape[-1])
+
+    def filled(x, heads):  # zero heads behind the real ones, where the stored pairs are padded (`cache_kv_heads`)
+        return x if x.shape[-2] == heads else jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, heads - x.shape[-2]), (0, 0)])
+
+    q = filled(q, cfg.attend_heads)
+    if "wk" not in layer:
+        return q, None, None
+
+    def stored(w, b):
+        out = jnp.einsum("...e,ehd->...hd", h, layer[w], preferred_element_type=f32)
+        if cfg.attention_bias:
+            out = out + layer[b].astype(f32)
+        return filled(out.astype(h.dtype).reshape(*out.shape[:-2], cfg.kv_heads // 2, cfg.cache_head_dim), cfg.cache_kv_heads)
+
+    return q, stored("wk", "bk"), stored("wv", "bv")
+
+
+def _diff_out(cfg: DecoderConfig, li: int, layer, ctx):
+    """The epilogue of a differential layer behind its attention call:
+    ``ctx`` [..., H, 2D] holds ``a1_i`` at head ``2i`` and ``a2_i`` at ``2i +
+    1``; ``o_i = (1 - lambda_init) RMSNorm_2D(a1_i - lambda a2_i; g)``, the
+    ``H / 2`` x ``2D`` read as ``H`` x ``D`` into ``W_o``. Lambda and the norm
+    in float32."""
+    f32 = jnp.float32
+    init = lambda_init(li)
+    lam = (jnp.exp(jnp.sum(layer["lambda_q1"] * layer["lambda_k1"])) - jnp.exp(jnp.sum(layer["lambda_q2"] * layer["lambda_k2"])) + init)
+    ctx = ctx[..., : cfg.num_heads, :]  # (the padded pairs' groups, where there are any, are dropped)
+    pair = ctx.astype(f32).reshape(*ctx.shape[:-2], ctx.shape[-2] // 2, 2, ctx.shape[-1])
+    o = (1.0 - init) * _rms(pair[..., 0, :] - lam * pair[..., 1, :], layer["subln_g"].astype(f32), cfg.norm_eps)
+    o = o.astype(ctx.dtype).reshape(*ctx.shape[:-2], ctx.shape[-2], ctx.shape[-1] // 2)
+    out = _mm("...hd,hde->...e", o, layer["wo"])
+    return out + layer["bo"] if cfg.attention_bias else out
+
+
+def _mamba(cfg: DecoderConfig, layer, h, live, si: int, window: Callable, scan: Callable):
+    """A Mamba-1 layer's mixer of its normed input ``h`` ([B, S, E], or [B,
+    E] for a decode step's one position): ``[x, z] = W_in h``; ``x <-
+    silu(conv1d_causal(x) + b)`` (depthwise, kernel K, float32); ``[delta,
+    B, C] = W_x x``; ``dt = softplus(W_dt delta + b_dt)``, ``A =
+    -exp(A_log)`` [D, N]; the recurrence (ops/ssm.py ``selective_*``)
+    through ``scan``; ``y <- y + D x``; ``out = W_out (y * silu(z))``.
+    Returns ``out`` and ``y`` (before the gate: the MEMORY a ``gmu`` layer
+    reads, float32). Rows that are not ``live`` get ``dt = 0`` and ``x =
+    0``: the state passes them unchanged. ``window`` and ``scan`` as
+    :func:`_ssm` takes them; ``dt``, the decay, the recurrence, the skip and
+    the gate are float32."""
+    one = h.ndim == 2
+    if one:
+        h, live = h[:, None], live[:, None]
+    di, n, r = cfg.ssm_inner, cfg.ssm_state_size, cfg.dt_rank
+    proj = _mm("...e,ef->...f", h, layer["ssm_in"])
+    x, z = proj[..., :di], proj[..., di:]
+    xpad = window(si, x)  # [B, S + K - 1, D]
+    with jax.named_scope("ssm.conv"):
+        x = jnp.where(live[..., None], _ssm_conv(layer, xpad, x.shape[1]).astype(h.dtype), 0)
+    dbc = _mm("...f,fr->...r", x, layer["ssm_x"])
+    delta, b, c = dbc[..., :r], dbc[..., r : r + n], dbc[..., r + n :]
+    dt = jnp.einsum("...r,rf->...f", delta, layer["ssm_dt_w"], preferred_element_type=jnp.float32)
+    dt = jnp.where(live[..., None], jax.nn.softplus(dt + layer["ssm_dt_bias"]), 0.0)
+    y = scan(si, x, dt, -jnp.exp(layer["ssm_a_log"]), b, c)  # [B, S, D] float32
+    y = y + layer["ssm_d"] * x.astype(jnp.float32)
+    out = _mm("...f,fe->...e", (y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype), layer["ssm_out"])
+    return (out[:, 0], y[:, 0]) if one else (out, y)
+
+
+def _gmu(layer, h, memory):
+    """A Gated Memory Unit: ``W_2 (m * silu(W_1 h))``, ``m`` the memory
+    layer's scan output at the same row (float32), the gate in float32."""
+    gate = jnp.einsum("...e,ef->...f", h, layer["gmu_in"], preferred_element_type=jnp.float32)
+    return _mm("...f,fe->...e", (memory * jax.nn.silu(gate)).astype(h.dtype), layer["gmu_out"])
+
+
 def _layers(
     cfg: DecoderConfig,
     params: DecoderParams,
@@ -1001,6 +1298,8 @@ def _layers(
     convolve: Callable,
     counts: Optional[List] = None,
     recur: Optional[Tuple[Callable, Callable]] = None,
+    span: Optional[Tuple[int, int]] = None,
+    memory=None,
 ):
     """THE block definition: every layer of ``params`` applied to ``x``
     ([..., E]; ``positions`` and ``live`` over its leading axes). What
@@ -1026,9 +1325,19 @@ def _layers(
     with window layers names the two kinds apart, ``attention.window``
     and ``attention.full`` (:func:`attention_scope`); a latent layer is
     ``attention.latent``, its attention proper ``attention.latent.expand``
-    or ``attention.latent.absorb`` by the form the forward runs."""
-    ai = ci = si = 0
-    for li, layer in enumerate(params["layers"]):
+    or ``attention.latent.absorb`` by the form the forward runs; a ``mamba``
+    layer is ``ssm`` (``ssm.conv`` inside), a ``gmu`` layer ``gmu``, a
+    ``cross`` layer ``attention.cross``.
+
+    ``span = (lo, hi)``: the layers ``lo <= l < hi`` alone (a prefill runs
+    the cross-decoder, ``cfg.cross_from`` on, over other rows than the
+    rest). Returns ``x`` and the MEMORY: the scan output of layer
+    ``cfg.memory_source`` where the span held it, else ``memory`` as given
+    (what the ``gmu`` layers of the span read)."""
+    lo, hi = span or (0, cfg.num_layers)
+    ai, ci, si = (sum(l < lo for l in ls) for ls in (cfg.attention_layers, cfg.conv_layers, cfg.ssm_layers))
+    for li in range(lo, hi):
+        layer = params["layers"][li]
         with jax.named_scope(f"layer{li}"):
             kind = cfg.operator(li)
             if cfg.block == "single":
@@ -1075,6 +1384,24 @@ def _layers(
                 with jax.named_scope("block.parallel"):
                     x = x + attended + fed
                 continue
+            elif kind == "mamba":
+                with jax.named_scope("ssm"):
+                    out, scanned = _mamba(cfg, layer, _norm(cfg, x, layer, "ln1"), live, si, *recur)
+                    x = x + out
+                if li == cfg.memory_source:
+                    memory = scanned  # handed to the gmu layers inside this forward; nothing of it is cached
+                si += 1
+            elif kind == "gmu":
+                with jax.named_scope("gmu"):
+                    x = x + _gmu(layer, _norm(cfg, x, layer, "ln1"), memory)
+            elif cfg.differential:
+                scope = attention_scope(cfg, kind)
+                with jax.named_scope(scope):
+                    q, k, v = _diff_qkv(cfg, layer, _norm(cfg, x, layer, "ln1"))
+                ctx = attend(ai, q, k, v)
+                with jax.named_scope(scope):
+                    x = x + _diff_out(cfg, li, layer, ctx)
+                ai += 1
             elif kind != "conv":
                 scope = attention_scope(cfg, kind)
                 with jax.named_scope(scope):
@@ -1108,11 +1435,13 @@ def _layers(
             if cfg.shortcut(li + 1):  # (the period's last sub-layer)
                 with jax.named_scope("experts.shortcut"):
                     x = x + branch
-    return x
+    return x, memory
 
 
 def attention_scope(cfg: DecoderConfig, kind: str) -> str:
     """The scope an attention layer's operations are named under."""
+    if kind == "cross":
+        return "attention.cross"
     if not cfg.window_layers:
         return "attention"
     return "attention.window" if kind == "window" else "attention.full"
@@ -1142,6 +1471,7 @@ def prefill(
     counts: Optional[List] = None,
     backend: str = "cpu",
     head: bool = True,
+    last_only: bool = False,
 ):
     """Prefill forward: logits [B, S, V] plus every attention layer's
     K/V ([n_attn, B, S, Hkv, D] each, both kinds in layer order:
@@ -1155,7 +1485,17 @@ def prefill(
     ``head`` False (a prefill that samples nothing: block diffusion's):
     the first result is the last layer's output [B, S, E] and the head,
     whose product over all S rows is the peak temporary of every other
-    prefill, is not run."""
+    prefill, is not run. A Mamba-1 configuration's fourth result is
+    ``{"xbc": [n, B, S + K - 1, D], "state": [n, B, D, N]}``. K/V comes
+    back for the layers that STORE it (``cfg.stored_index``).
+
+    ``last_only`` (a configuration with a cross-decoder, ``cfg.cross_from``
+    < ``num_layers``): the layers from ``cfg.cross_from`` on, and the head,
+    run on each sequence's LAST live row alone and the first result is [B,
+    1, V]. They store nothing a later position reads (their K/V is the
+    ``kv_source`` layer's, which the layers before have produced for every
+    row; the memory is needed at that row alone), so that row's logits are
+    the same numbers as the full forward's."""
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
     b, s = tokens.shape
     lens = lengths if lengths is not None else jnp.full((b,), s, jnp.int32)
@@ -1172,12 +1512,23 @@ def prefill(
 
     def scan(si, x_, dt, a, b_, c_):
         with jax.named_scope("ssm.scan"):
-            y, final = ssm_ops.chunk_scan(x_, dt, a, b_, c_, cfg.ssm_chunk)
+            form = ssm_ops.selective_scan if cfg.mamba_layers else ssm_ops.chunk_scan
+            y, final = form(x_, dt, a, b_, c_, cfg.ssm_chunk)
         finals.append(final)
         return y
 
+    stored = {}  # the storing layers' place in ks / vs, by (kind, index in that kind's arrays)
+    tail = {"rows": False}  # the cross-decoder is on each sequence's last row alone
+
     def attend(ai, q, k, v):
-        kind = cfg.kv_index[ai][0]
+        kind, at = cfg.kv_index[ai]
+        if kind == "cross":
+            src = stored[("attention", at)]
+            with jax.named_scope("attention.cross"), jax.named_scope("prefill_attention"):
+                if tail["rows"]:  # one query a sequence over the producer's rows: every live position is seen
+                    return masked_attention(q, ks[src], vs[src], lens, causal=False)
+                return prefill_attention(q, ks[src], vs[src], lens, backend=backend)
+        stored[(kind, at)] = len(ks)
         if kind == "latent":
             ks.append(k)  # the rows, as stored; V has no width
             vs.append(k[..., :0])
@@ -1196,7 +1547,18 @@ def prefill(
         return zs[-1]
 
     live = positions < lens[:, None]
-    x = _layers(cfg, params, x, positions, live, attend, convolve, counts, recur=(window, scan))
+    if last_only and cfg.cross_from < cfg.num_layers:
+        x, memory = _layers(cfg, params, x, positions, live, attend, convolve, counts, recur=(window, scan), span=(0, cfg.cross_from))
+        with jax.named_scope("prefill.last_row"):
+            last = jnp.maximum(lens - 1, 0)[:, None]  # [B, 1]
+            x = jnp.take_along_axis(x, last[:, :, None], axis=1)
+            memory = memory if memory is None else jnp.take_along_axis(memory, last[:, :, None], axis=1)
+        tail["rows"] = True
+        x, _ = _layers(
+            cfg, params, x, last, jnp.ones_like(last, bool), attend, convolve, counts, span=(cfg.cross_from, cfg.num_layers), memory=memory,
+        )
+    else:
+        x, _ = _layers(cfg, params, x, positions, live, attend, convolve, counts, recur=(window, scan))
     with jax.named_scope("head"):
         empty = jnp.zeros((0,), x.dtype)  # a configuration without attention layers
         out = (_head(cfg, params, x) if head else x, jnp.stack(ks) if ks else empty, jnp.stack(vs) if vs else empty)
@@ -1286,6 +1648,9 @@ def decode_step(
         # write this token's K/V, then attend over the updated cache
         # so the token sees itself (context_lens includes it)
         kind, at = cfg.kv_index[ai]
+        if kind == "cross":  # the producer layer has written this token's K/V already; nothing is written here
+            with jax.named_scope("attention.cross"):
+                return decode_attention_core(q, state["k"], state["v"], at, block_tables, context_lens, backend=backend, mesh=mesh)
         if kind == "latent":
             with jax.named_scope("cache_write"):
                 state["k"] = write_rows(state["k"], at, block, offset, k)
@@ -1322,10 +1687,11 @@ def decode_step(
 
     def ssm_scan(si, x_, dt, a, b_, c_):
         with jax.named_scope("ssm.update"):
-            y, state["ssm"] = ssm_ops.update(state["ssm"], si, x_[:, 0], dt[:, 0], a, b_[:, 0], c_[:, 0], backend=backend)
+            step = ssm_ops.selective_update if cfg.mamba_layers else ssm_ops.update
+            y, state["ssm"] = step(state["ssm"], si, x_[:, 0], dt[:, 0], a, b_[:, 0], c_[:, 0], backend=backend)
         return y[:, None]
 
-    x = _layers(
+    x, _ = _layers(
         cfg, params, x, positions, live, attend, convolve if conv is not None else _no_conv, counts,
         recur=(ssm_window, ssm_scan) if ssm is not None else None,
     )
@@ -1389,6 +1755,11 @@ def verify_step(
     result is the last layer's output [B, W, E], no logits.
     """
     cfg = decoder_config(cfg) if cfg is not None else _config_of(params)
+    if cfg.cross_layers:
+        raise NotImplementedError(
+            "an append window over cross layers (speculative verification, a prefix hit's suffix prefill) is not written "
+            "down: they are served beside state-space layers, whose every row's state would have to be kept"
+        )
     if cfg.ssm_layers:
         raise NotImplementedError(
             "an append window over ssm layers (speculative verification, a prefix hit's suffix prefill) is not "
@@ -1438,7 +1809,7 @@ def verify_step(
             zs.append(conv_window(conv_in[ci], z))
         return zs[-1]
 
-    x = _layers(
+    x, _ = _layers(
         cfg, params, x, safe_pos, positions >= 0, attend,
         convolve if conv_in is not None else _no_conv, counts,
     )

@@ -342,6 +342,30 @@ def unsupported_paths(kind: str, dcfg) -> Dict[str, str]:
                 "could be resumed; every prompt is prefilled whole"
             ),
         },
+        "samba": {
+            "speculation": (
+                "speculative verification (engine.verify) is refused for a decoder-hybrid-decoder configuration "
+                "(Mamba-1, gmu and cross layers): every window row's recurrent state and its memory row would have "
+                "to be kept, megabytes a row, to choose one at the accepted length, and a rejected draft would have "
+                "to give back its window blocks"
+            ),
+            "kv_handoff": (
+                "the disaggregation wire (pack_kv_blocks / import_kv_block(s)) is refused for a decoder-hybrid-decoder "
+                "configuration: a payload carries one table's K/V blocks, and the decode side would need the Mamba-1 "
+                "layers' per-slot state and the window layers' last blocks beside the one shared K/V"
+            ),
+            "tensor_parallel": (
+                "tp_degree > 1 is refused for a decoder-hybrid-decoder configuration: the serving layout shards "
+                "attention heads and the FFN, and has no placement for the Mamba-1 mixer, its per-slot state, the "
+                "gated memory units or differential attention's paired heads; the head-sharded paged kernel takes "
+                "no window"
+            ),
+            "prefix_reuse": (
+                "prefix reuse is off: a Mamba-1 layer's state exists per slot and nowhere else, and the gmu layers' "
+                "memory is never cached at all, so no cached prefix could be resumed; every prompt is prefilled "
+                "whole (its cross-decoder on the last row alone)"
+            ),
+        },
         "conv": {
             "speculation": (
                 "speculative verification (engine.verify) is refused for a configuration "
@@ -554,7 +578,7 @@ class GenerationEngine:
             or (mesh is not None and int(dict(mesh.shape).get("model", 1)) > 1)
             or (tp_degree is None and mesh is None and (mesh_devices or 1) > 1)
         )
-        if self.dcfg.window_layers and self.dcfg.stateful:
+        if self.dcfg.window_layers and self.dcfg.conv_layers:
             raise NotImplementedError(
                 "a configuration with both convolution and sliding-window layers is refused: a "
                 "cached block would carry a convolution snapshot and a window half at once"
@@ -566,6 +590,8 @@ class GenerationEngine:
             self.unsupported.update(unsupported_paths("parallel", self.dcfg))
         if self.dcfg.shortcut_experts:
             self.unsupported.update(unsupported_paths("shortcut", self.dcfg))
+        if self.dcfg.mamba_layers or self.dcfg.gmu_layers or self.dcfg.cross_layers or self.dcfg.differential:
+            self.unsupported.update(unsupported_paths("samba", self.dcfg))
         # the generation rule of a block-diffusion model (its steps are
         # block steps, and no decode step ever runs); None: one token a
         # sequence a step under the causal mask
@@ -602,7 +628,7 @@ class GenerationEngine:
                 from ..parallel.mesh import MODEL_AXIS
 
                 tp = int(mesh.shape.get(MODEL_AXIS, 1))
-                self.layout = ServingLayout.build(self.dcfg.kv_heads, tp, mesh=mesh)
+                self.layout = ServingLayout.build(self.dcfg.cache_kv_heads, tp, mesh=mesh)
             else:
                 n_dev = mesh_devices or tp_degree
             self.serving_strategy = choose_serving_strategy(
@@ -619,7 +645,7 @@ class GenerationEngine:
             )
             if self.layout is None:
                 self.layout = ServingLayout.build(
-                    self.dcfg.kv_heads, self.serving_strategy.tp_degree
+                    self.dcfg.cache_kv_heads, self.serving_strategy.tp_degree
                 )
         self.tp_degree = self.layout.tp_degree if self.layout else 1
         self.mesh_devices = self.layout.mesh.size if self.layout else 1
@@ -634,8 +660,8 @@ class GenerationEngine:
         self.window_columns = 0
         if self.dcfg.window_layers:
             wkv = dict(
-                num_layers=len(self.dcfg.window_layers), num_heads=self.dcfg.kv_heads,
-                head_dim=self.dcfg.dim_per_head, block_size=cache_config.block_size if cache_config else block_size,
+                num_layers=len(self.dcfg.window_layers), num_heads=self.dcfg.cache_kv_heads,
+                head_dim=self.dcfg.cache_head_dim, block_size=cache_config.block_size if cache_config else block_size,
                 dtype=cfg.dtype, window=self.dcfg.window,
             )
             if cache_config is None and cache_budget_bytes is not None:
@@ -666,7 +692,9 @@ class GenerationEngine:
                 num_layers=len(d.ssm_layers), slots=max_batch_slots,
                 parts=(
                     ("ssm_conv", (d.ssm_conv_kernel - 1, d.ssm_conv_width), cfg.dtype),
-                    ("ssm", ssm_ops.state_shape(d.ssm_heads, d.ssm_head_dim, d.ssm_groups, d.ssm_state_size), DataType.FLOAT),
+                    # (a Mamba-1 layer's: [N, D], a decay for every pair and no heads to pack)
+                    ("ssm", ssm_ops.selective_state_shape(d.ssm_inner, d.ssm_state_size) if d.mamba_layers
+                     else ssm_ops.state_shape(d.ssm_heads, d.ssm_head_dim, d.ssm_groups, d.ssm_state_size), DataType.FLOAT),
                 ),
             )
         if cache_config is None:
@@ -676,8 +704,8 @@ class GenerationEngine:
             # too: those have the pool above)
             kv = dict(
                 num_layers=len(self.dcfg.full_layers),
-                num_heads=self.dcfg.kv_heads,
-                head_dim=self.dcfg.dim_per_head,
+                num_heads=self.dcfg.cache_kv_heads,
+                head_dim=self.dcfg.cache_head_dim,
                 block_size=block_size,
                 dtype=cfg.dtype,
             )
@@ -732,6 +760,15 @@ class GenerationEngine:
             slot_state=self.slot_state,
         )
         self._ssm_slots_live = 0  # the slots the last decode step ran live (`cache.ssm`)
+        # a cross-decoder (decoder.py `cross_from`): its layers run on a prompt's last row alone; the rows of
+        # the prefill programs (a bucket's) that they ran on and that they were spared (`prefill` of /v2/stats)
+        self._cross_decoder = self.dcfg.cross_from < self.dcfg.num_layers
+        # a configuration with a window pool AND per-slot state: its prefill returns the prompt's K/V rows and no
+        # pool, and the hand-over (the one admission program that donates) writes them with the slot's parts, so
+        # that no second copy of the pools stands beside the first while a prompt is prefilled
+        self._rows_install = self.slot_state is not None and self.window_config is not None
+        self.cross_rows_run = 0
+        self.cross_rows_skipped = 0
         # the live sequences' tables of the window pool, by batch slot,
         # and what the release has given back (the `cache` section of
         # /v2/stats: cache_stats)
@@ -1021,7 +1058,7 @@ class GenerationEngine:
         # (nothing inserted, evicted or read out to the host tier) and
         # the refusal is named; every other configuration's hits are
         # served as they always were
-        suffix_scores = 4 * self.dcfg.num_heads * self.buckets[0] * self.max_seq_len
+        suffix_scores = 4 * self.dcfg.attend_heads * self.buckets[0] * self.max_seq_len
         if self.slot_state is not None:
             prefix_cache = False  # (named in `unsupported` above: unsupported_paths("ssm"))
         elif prefix_cache and suffix_scores > STREAM_SCORE_BYTES:
@@ -1057,6 +1094,7 @@ class GenerationEngine:
         # program that donates, and only the slots' state: it runs after the prefill has succeeded, so a
         # failed prefill still leaves every array as it was
         self._install_state_jit = jax.jit(self._install_state_impl, donate_argnums=(0,) if self.donate else ())
+        self._install_rows_jit = jax.jit(self._install_rows_impl, donate_argnums=(0, 1, 2) if self.donate else ())
         self._copy_block_jit = jax.jit(self._copy_block_impl, **blk_sh)
         self._read_block_jit = jax.jit(self._read_block_impl, **rd_sh)
         self._write_block_jit = jax.jit(self._write_block_impl, **blk_sh)
@@ -1133,6 +1171,8 @@ class GenerationEngine:
             stats.add_section("cache", self.cache_stats)
         if self.diffusion is not None:
             stats.add_section("diffusion", self.diffusion_stats)
+        if self._cross_decoder:
+            stats.add_section("prefill", self.prefill_stats)
 
     def _register_strategy_predictions(self) -> None:
         """Put the chosen serving layout's predicted step times into the
@@ -1250,15 +1290,68 @@ class GenerationEngine:
         k = self.dcfg.ssm_conv_kernel
         with jax.named_scope("ssm.handover"):
             rows = jax.vmap(lambda z: state_at(z, n_tokens[None], k)[0])(left["xbc"])  # [n_ssm, K-1, width]
+            if self.dcfg.mamba_layers:  # [n, D, N] -> the stored [n, N, D]
+                return {"ssm_conv": rows, "ssm": jnp.swapaxes(left["state"][:, 0], -1, -2)}
             return {"ssm_conv": rows, "ssm": ssm_ops.pack_state(left["state"][:, 0], self.dcfg.ssm_groups)}
 
     def _install_state_impl(self, state, slot, parts):
         """A slot's parts into the slots' arrays (donated: in place)."""
         self.trace_counts["state_install"] = self.trace_counts.get("state_install", 0) + 1
+        return self._parts_installed(state, slot, parts)
+
+    @staticmethod
+    def _parts_installed(state, slot, parts):
         return {
             name: jax.lax.dynamic_update_slice_in_dim(state[name], parts[name][:, None].astype(state[name].dtype), slot, axis=1)
             for name in state
         }
+
+    def _write_prefill_rows(self, cache_k, cache_v, state, ks, vs, length, block_table, wtable):
+        """A prompt's K/V rows (``ks`` / ``vs`` [n storing layers, S, R,
+        LW], decoder.py ``prefill``) into the blocks of its tables: the
+        full layers' into ``cache_k`` / ``cache_v``, the window layers'
+        into the window pool's arrays (``state["wk" / "wv"]``), of which
+        nothing behind what the table still holds is kept. Returns the
+        two arrays and ``{"wk", "wv"}`` (empty without window layers)."""
+        s = ks.shape[1]
+        positions = jnp.arange(s, dtype=jnp.int32)
+        block, offset = slot_mapping(block_table, positions, cache_k.shape[2])
+        block = jnp.where(positions < length, block, 0)  # padding -> scratch
+        offset = jnp.where(positions < length, offset, 0)
+        if self.window_config is not None:
+            # the window layers' rows go to the window pool's blocks: of
+            # the positions behind what the table still holds nothing is
+            # kept (scratch), the next query cannot reach them
+            held = jnp.logical_and(positions < length, positions >= wtable["first"])
+            wblock, woffset = slot_mapping(wtable["tables"], positions - wtable["first"], cache_k.shape[2])
+            wblock, woffset = jnp.where(held, wblock, 0), jnp.where(held, woffset, 0)
+            wk, wv = state["wk"], state["wv"]
+        with jax.named_scope("cache_write"):
+            # layer by layer, as a decode step writes: one scatter over
+            # all layers would have the compiler transpose the whole
+            # cache to bring the scattered axes to the front, and back
+            for li in range(ks.shape[0]):
+                kind, at = self.dcfg.stored_index[li]
+                if kind == "window":
+                    wk = write_rows(wk, at, wblock, woffset, ks[li])
+                    wv = write_rows(wv, at, wblock, woffset, vs[li])
+                    continue
+                cache_k = write_rows(cache_k, at, block, offset, ks[li])
+                cache_v = write_rows(cache_v, at, block, offset, vs[li])
+        return cache_k, cache_v, ({"wk": wk, "wv": wv} if self.window_config is not None else {})
+
+    def _install_rows_impl(self, cache_k, cache_v, state, slot, handed, length, block_table, wtable):
+        """The hand-over of a configuration whose prefill returns its rows
+        and no pool (``_rows_install``): the prompt's K/V rows into both
+        pools and the slot's parts into the slots' arrays, everything
+        donated: in place."""
+        name = f"state_install[{handed['rows_k'].shape[1]}]"  # (a program a bucket, as the prefills are)
+        self.trace_counts[name] = self.trace_counts.get(name, 0) + 1
+        cache_k, cache_v, pools = self._write_prefill_rows(
+            cache_k, cache_v, state, handed["rows_k"], handed["rows_v"], length, block_table, wtable
+        )
+        parts = {name: state[name] for name in self.slot_state.names}
+        return cache_k, cache_v, dict(pools, **self._parts_installed(parts, slot, handed))
 
     def _install_state(self, slot: int, parts: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
         """The hand-over behind a successful prefill: ``slot`` takes the
@@ -1290,45 +1383,28 @@ class GenerationEngine:
             "temp": temp, "top_k": top_k, "key": key, "mask": mask,
         })
         state, counts, rows = state or {}, counts or {}, []
+        last_only = self.dcfg.cross_from < self.dcfg.num_layers  # the cross-decoder runs on the last row alone
         logits, ks, vs, *zs = prefill(
             params, tokens, jnp.full((1,), length, jnp.int32), cfg=self.dcfg, counts=rows, backend=self.backend,
-            head=self.diffusion is None,
+            head=self.diffusion is None, last_only=last_only,
         )
         if self.state_config is not None:
             state = self._write_state(state, zs[0], slot, length, block_table, 0)
         if self.slot_state is not None:
             state = self._slot_parts(zs[0], length)
         counts = self._count(counts, rows, 1)
-        positions = jnp.arange(s, dtype=jnp.int32)
-        block, offset = slot_mapping(block_table, positions, cache_k.shape[2])
-        block = jnp.where(positions < length, block, 0)  # padding -> scratch
-        offset = jnp.where(positions < length, offset, 0)
-        if self.window_config is not None:
-            # the window layers' rows go to the window pool's blocks: of
-            # the positions behind what the table still holds nothing is
-            # kept (scratch), the next query cannot reach them
-            held = jnp.logical_and(positions < length, positions >= wtable["first"])
-            wblock, woffset = slot_mapping(wtable["tables"], positions - wtable["first"], cache_k.shape[2])
-            wblock, woffset = jnp.where(held, wblock, 0), jnp.where(held, woffset, 0)
-            wk, wv = state["wk"], state["wv"]
-        with jax.named_scope("cache_write"):
-            # layer by layer, as a decode step writes: one scatter over
-            # all layers would have the compiler transpose the whole
-            # cache to bring the scattered axes to the front, and back
-            for li in range(ks.shape[0]):
-                kind, at = self.dcfg.kv_index[li]
-                if kind == "window":
-                    wk = write_rows(wk, at, wblock, woffset, ks[li, 0])
-                    wv = write_rows(wv, at, wblock, woffset, vs[li, 0])
-                    continue
-                cache_k = write_rows(cache_k, at, block, offset, ks[li, 0])
-                cache_v = write_rows(cache_v, at, block, offset, vs[li, 0])
-        if self.window_config is not None:
-            state = dict(state, wk=wk, wv=wv)
+        if self._rows_install:
+            # the rows go out as they are and the hand-over writes them (`_install_rows`): this program returns
+            # no pool, so none is copied beside the one it was given
+            state = dict(state, rows_k=ks[:, 0], rows_v=vs[:, 0])
+            cache_k = cache_v = None
+        else:
+            cache_k, cache_v, pools = self._write_prefill_rows(cache_k, cache_v, state, ks[:, 0], vs[:, 0], length, block_table, wtable)
+            state = dict(state, **pools)
         if self.diffusion is not None:
             return self._no_token(logits[0, length - 1]) + (cache_k, cache_v, state, counts)
         with jax.named_scope("sample"):
-            last = logits[0, length - 1]
+            last = logits[0, 0 if last_only else length - 1]
             ok = jnp.all(jnp.isfinite(last))  # blame: poisoned prompt
             # grammar mask: additive [V] bias, 0 / NEG (finite — the ok
             # gate above still sees model NaN, never the mask)
@@ -1385,7 +1461,8 @@ class GenerationEngine:
             # snapshots are the prefill programs' to write
             state = {"conv": conv[0]}
         if self.window_config is not None:
-            state = {"wk": conv[-1]["k"], "wv": conv[-1]["v"]}
+            # (beside the slots' named parts, where the configuration has both)
+            state = dict(state if self.slot_state is not None else {}, wk=conv[-1]["k"], wv=conv[-1]["v"])
         expert_counts = self._count(expert_counts, rows, 0)
         # bias is the fault plan's per-slot NaN poison (zeros outside
         # chaos runs); applying it before the finiteness reduce makes the
@@ -1797,12 +1874,20 @@ class GenerationEngine:
         with phase("engine.prefill.block") as block:
             jax.block_until_ready((token, ok, ck, cv, state))  # device execution done
         with phase("engine.prefill.readback") as read:
-            if self.slot_state is not None:
+            if self._rows_install:
+                with phase("cache.state_install", slot=slot):
+                    ck, cv, state = self._install_rows_jit(
+                        self.cache.k, self.cache.v, self._step_state(), jnp.int32(slot), state, args[1], args[4], args[-1]
+                    )
+            elif self.slot_state is not None:
                 state = self._install_state(slot, state)
             self.cache.update(ck, cv, **state)
             self.expert_counts = counts
             self.last_finite = np.asarray(ok).reshape(1)
             out = int(token)  # result sync lands inside the readback span
+        if self._cross_decoder:
+            self.cross_rows_run += 1
+            self.cross_rows_skipped += bucket - 1
         elapsed, execute_s = self._record_step_phases("prefill", disp, block, read)
         # FLOPs accrue only on SUCCESS, next to the time they pair with:
         # a step that raises (and is retried by the supervisor) must not
@@ -1923,7 +2008,8 @@ class GenerationEngine:
         and, where the configuration has window layers, the slot's table
         of the window pool at ``window_columns`` columns."""
         args = (
-            # (a state-space configuration's prefill RETURNS the slot's parts and takes none: _install_state)
+            # (a state-space configuration's prefill RETURNS the slot's parts, and beside a window pool the prompt's
+            # K/V rows, and takes none: _install_state, _install_rows_impl)
             self.cache.state if self.slot_state is None else {},
             jnp.int32(slot) if self.state_config is not None else None,
             self.expert_counts,
@@ -2053,18 +2139,32 @@ class GenerationEngine:
         cc, wc = self.cache_config, self.window_config
         if cc.latent:
             return {"latent": self.latent_stats()}
+        ssm = {}
         if self.slot_state is not None:
             ss, live = self.slot_state, self._ssm_slots_live
-            return {
+            ssm = {
                 "ssm": {"layers": ss.num_layers, "slots": ss.slots, "bytes_per_slot": ss.bytes_per_sequence,
                         "state_bytes_per_slot": ss.part_bytes("ssm"), "conv_bytes_per_slot": ss.part_bytes("ssm_conv"),
                         "slots_live": live, "bytes_held": ss.total_bytes, "live_bytes": live * ss.bytes_per_sequence},
+            }
+        if self.dcfg.cross_layers:
+            # ONE layer's K/V that the cross layers read again: what a token costs, and what it would cost
+            # if every reader stored K/V of its own
+            readers = len(self.dcfg.cross_layers)
+            ssm["shared_kv"] = {
+                "producer_layers": [self.dcfg.kv_source], "reader_layers": list(self.dcfg.cross_layers),
+                "bytes_per_token": cc.bytes_per_token, "bytes_saved_per_token": readers * cc.bytes_per_token // max(1, cc.num_layers),
+            }
+        if wc is None:
+            return {
+                **ssm,
                 "full": {"layers": cc.num_layers, "blocks_total": self.allocator.num_total,
                          "blocks_used": self.allocator.num_total - self.allocator.num_free,
                          "bytes_per_block": cc.bytes_per_block},
             }
         full, window = self._live_blocks
         return {
+            **ssm,
             "full": {"layers": cc.num_layers, "blocks_total": self.allocator.num_total,
                      "blocks_used": self.allocator.num_total - self.allocator.num_free,
                      "bytes_per_block": cc.bytes_per_block},
@@ -2079,6 +2179,16 @@ class GenerationEngine:
             "window_release_total_s": self.window_release_total_s,
             "live_bytes": full * cc.bytes_per_block + window * wc.bytes_per_block,
             "one_table_bytes": full * (cc.bytes_per_block + wc.bytes_per_block),
+        }
+
+    def prefill_stats(self) -> Dict:
+        """The ``prefill`` section of ``/v2/stats`` (a configuration with a
+        cross-decoder): of the rows the prefill programs ran, a bucket's
+        each, those its layers ran on (one a prompt) and those they were
+        spared."""
+        return {
+            "cross_layers": self.dcfg.num_layers - self.dcfg.cross_from,
+            "cross_rows_run_total": self.cross_rows_run, "cross_rows_skipped_total": self.cross_rows_skipped,
         }
 
     def latent_stats(self) -> Dict:
@@ -2835,8 +2945,8 @@ class GenerationEngine:
                 prev_k, prev_v, prev_conv = (None, None, None) if self.donate else (
                     self.cache.k, self.cache.v, self.cache.state.get("conv")
                 )
-                # (the window pool's arrays or the slots' named parts: a configuration has one of them at most)
-                named = ("wk", "wv") if self.window_config is not None else self.slot_state.names if self.slot_state is not None else ()
+                # (the window pool's arrays and the slots' named parts)
+                named = (("wk", "wv") if self.window_config is not None else ()) + (self.slot_state.names if self.slot_state is not None else ())
                 prev_window = {k: self.cache.state[k] for k in named} if named and not self.donate else None
                 with self._part("decode", "call"):
                     out, ok, ck, cv, state, counts, *carried = self._decode_jit(self.params, *args)
@@ -3374,7 +3484,7 @@ class GenerationEngine:
             kinds = {"block_step": (self.cache.k, {**shape, "window": self.diffusion.block_length})}
         return {
             kind: paged_call_lowering(
-                self.dcfg.num_heads, self.dcfg.dim_per_head, arrays,
+                self.dcfg.attend_heads, self.dcfg.cache_head_dim, arrays,
                 backend=self.backend, mesh=self._kernel_mesh, **of,
             )
             for kind, (arrays, of) in kinds.items() if arrays.shape[0]
@@ -3401,11 +3511,11 @@ class GenerationEngine:
             # (a latent layer's expanded form: every head has K of its own, at the score's width, and V at its own)
             heads, width, value = (
                 (d.num_heads, d.qk_nope_head_dim + d.qk_rope_head_dim, d.v_head_dim) if self._n_latent
-                else (d.kv_heads, d.dim_per_head, d.dim_per_head)
+                else (d.cache_kv_heads, d.cache_head_dim, d.cache_head_dim)
             )
             self._prefill_lowerings[bucket] = prefill_call_lowering(
-                (1, bucket, d.num_heads, width), (1, bucket, heads, width), d.dtype.size_bytes, backend=self.backend,
-                v_shape=(1, bucket, heads, value), block=d.block_mask,
+                (1, bucket, d.num_heads if self._n_latent else d.attend_heads, width), (1, bucket, heads, width), d.dtype.size_bytes,
+                backend=self.backend, v_shape=(1, bucket, heads, value), block=d.block_mask,
             )
         return self._prefill_lowerings[bucket]
 

@@ -347,6 +347,13 @@ class ServingFlops:
         self.window_kv_bytes_per_pos = 0
         # state-space layers (from_config): a live sequence's recurrent state over all of them, in bytes
         self.state_bytes_per_seq = 0
+        # what a decode step READS of a cached position: its K/V once a layer that attends it (cross layers
+        # read the one layer's that stores it again: from_config); None: what it stores, `kv_bytes_per_pos`
+        self.kv_read_bytes_per_pos: Optional[int] = None
+        # a cross-decoder (from_config): of `per_token_flops` / `per_ctx_flops`, what a prefill runs on a
+        # prompt's last row alone (its layers and the head)
+        self.last_row_token_flops = 0
+        self.last_row_ctx_flops = 0
 
     @classmethod
     def from_config(cls, cfg, dtype: DataType = DataType.FLOAT, chip=None) -> "ServingFlops":
@@ -370,7 +377,12 @@ class ServingFlops:
         reads and writes for every live sequence (``state_bytes_per_seq``);
         ungated experts or experts in a latent: two matrices an expert at
         the latent's width, the picks that land on a held expert, the two
-        latent projections. A shortcut expert branch (``shortcut_experts``): beside the layer's
+        latent projections. A Mamba-1 layer (``mamba``): its four projections and convolution, 6 flops a
+        state value a token and the float32 state; a ``gmu`` layer its two matrices; a ``cross`` layer
+        ``W_q`` and ``W_o`` and the attended positions, its K/V READ again and stored nowhere
+        (``kv_read_bytes_per_pos``); differential attention scores two halves and weighs twice the
+        width (6 flops a query head's width a position, not 4); the layers from ``cross_from`` on and the
+        head are a prefill's on ONE row a prompt (``last_row_*``). A shortcut expert branch (``shortcut_experts``): beside the layer's
         dense feed-forward, the router, the picks that land on a held
         expert and the identity experts' picks at ``2 E`` flops each."""
         model = cls(
@@ -387,16 +399,34 @@ class ServingFlops:
         q, kv = cfg.num_heads * cfg.dim_per_head, cfg.kv_heads * cfg.dim_per_head
         flops, params, n_attn, n_window, n_ssm = 2 * e * v, v * e * (1 if cfg.tied_head else 2), 0, 0, 0
         n_latent = len(cfg.latent_layers)
+        n_cross = n_mamba = 0
+        bias = (q + 2 * kv + e) if getattr(cfg, "attention_bias", False) else 0
+        cross_from = getattr(cfg, "cross_from", cfg.num_layers)
+        last_row = 2 * e * v if cross_from < cfg.num_layers else 0  # (the head, with the cross-decoder)
         for l in range(cfg.num_layers):
-            if cfg.operator(l) == "latent":
+            before = flops
+            if cfg.operator(l) == "mamba":
+                di, n, r = cfg.ssm_inner, cfg.ssm_state_size, cfg.dt_rank
+                op = e * 2 * di + cfg.ssm_conv_kernel * di + di * (r + 2 * n) + r * di + di * e
+                params += 2 * di + di * n + di  # the convolution's bias, the step's, A_log and D
+                n_mamba += 1
+            elif cfg.operator(l) == "gmu":
+                op = 2 * e * cfg.ssm_inner
+            elif cfg.operator(l) == "cross":
+                op = 2 * e * q
+                params += bias - 2 * kv
+                n_cross += 1
+            elif cfg.operator(l) == "latent":
                 h, qk = cfg.num_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
                 op = (e * cfg.q_lora_rank + cfg.q_lora_rank * h * qk + e * cfg.latent_width
                       + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim) + h * cfg.v_head_dim * e)
             elif cfg.operator(l) == "attention":
                 op = 2 * e * q + 2 * e * kv
+                params += bias
                 n_attn += 1
             elif cfg.operator(l) == "window":
                 op = 2 * e * q + 2 * e * kv
+                params += bias
                 n_window += 1
             elif cfg.operator(l) == "ssm":
                 # the two projections and the convolution; the recurrence is counted apart (ssm_token_flops)
@@ -435,11 +465,21 @@ class ServingFlops:
                 ffn_params += cfg.held_experts * per_expert + e * outputs
             flops += 2 * (op + ffn)
             params += op + ffn_params
+            if l >= cross_from:
+                last_row += flops - before
+        per_head = 6 if getattr(cfg, "differential", False) else 4  # (two half-width scores, a double-width value)
         model.per_token_flops = flops
-        model.per_ctx_flops = n_attn * 4 * q
+        model.per_ctx_flops = (n_attn + n_cross) * per_head * q
+        model.last_row_token_flops, model.last_row_ctx_flops = last_row, n_cross * per_head * q
         model.param_count = params
         model.param_bytes = params * model.dtype_bytes
         model.kv_bytes_per_pos = 2 * n_attn * kv * model.dtype_bytes
+        if n_cross:
+            model.kv_read_bytes_per_pos = 2 * (n_attn + n_cross) * kv * model.dtype_bytes
+        if n_mamba:
+            values = cfg.ssm_inner * cfg.ssm_state_size
+            model.per_token_flops += n_mamba * 6 * values
+            model.state_bytes_per_seq = n_mamba * values * 4
         if n_latent:
             from ..ops.kernels.decode_attention import latent_row_width
 
@@ -452,7 +492,7 @@ class ServingFlops:
             model.per_token_flops += n_ssm * 5 * values
             model.state_bytes_per_seq = n_ssm * values * 4
         model.window = getattr(cfg, "window", 0) if n_window else 0
-        model.window_ctx_flops = n_window * 4 * q
+        model.window_ctx_flops = n_window * per_head * q
         model.window_kv_bytes_per_pos = 2 * n_window * kv * model.dtype_bytes
         return model
 
@@ -468,7 +508,9 @@ class ServingFlops:
         n = max(0, prompt_len)
         w = min(n, self.window)
         in_window = w * (w + 1) // 2 + (n - w) * w  # sum over positions of min(position + 1, window)
-        return n * self.per_token_flops + self.per_ctx_flops * (n * (n + 1) // 2) + self.window_ctx_flops * in_window
+        whole = n * self.per_token_flops + self.per_ctx_flops * (n * (n + 1) // 2) + self.window_ctx_flops * in_window
+        # (a cross-decoder's layers and the head run on the last row alone: the other n - 1 rows' share comes off)
+        return whole - max(0, n - 1) * self.last_row_token_flops - self.last_row_ctx_flops * (n * (n - 1) // 2)
 
     def decode_flops(self, n_active: int, context_sum: int) -> float:
         """One decode step: ``n_active`` live tokens attending to
@@ -506,7 +548,8 @@ class ServingFlops:
     def decode_bytes(self, n_active: int, context_sum: int) -> float:
         """HBM bytes for one decode step: weights once, KV read per live
         context position, KV write per active token."""
-        return (self.param_bytes + self.kv_bytes_per_pos * (context_sum + n_active)
+        read = self.kv_bytes_per_pos if self.kv_read_bytes_per_pos is None else self.kv_read_bytes_per_pos
+        return (self.param_bytes + read * context_sum + self.kv_bytes_per_pos * n_active
                 + self.window_kv_bytes_per_pos * (self.windowed(n_active, context_sum) + n_active)
                 + 2 * self.state_bytes_per_seq * n_active)
 

@@ -65,3 +65,57 @@ def ssm_state_update(state, layer: int, decay, xd, b, c, interpret: bool = False
         interpret=interpret,
         name="ssm_state_update",
     )(state, decay, xd, b[:, :, None, :], c[:, :, None, :])
+
+
+# ------------------------------------------------------------- Mamba-1
+# A Mamba-1 layer's decay is one a (channel, state) pair, ``exp(dt[d] A[d, n])``:
+# it cannot arrive as a lane row, so the call below forms it inside, from
+# ``dt`` [1, D] and ``A`` [N, D], and the state-sized decay is never written.
+SELECTIVE_SLOTS_PER_STEP = (4, 2, 1)  # slots a grid step takes: the largest that divides the slots
+
+
+def _selective_kernel(state_ref, dt_ref, x_ref, a_ref, b_ref, c_ref, y_ref, out_ref):
+    a = a_ref[...]  # [N, D]: the rates, the same every grid step
+    for s in range(state_ref.shape[1]):
+        dt = dt_ref[s]  # [1, D]
+        new = jnp.exp(dt * a) * state_ref[0, s] + b_ref[s] * (dt * x_ref[s])  # b: [N, 1], over the lanes
+        out_ref[0, s] = new
+        y_ref[s] = jnp.sum(new * c_ref[s], axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("layer", "interpret"))
+def selective_state_update(state, layer: int, dt, x, a, b, c, interpret: bool = False):
+    """A Mamba-1 layer's decode step as ONE pass over the slots' state:
+    ``state`` [L, slots, N, D] float32 (``N`` on the sublanes, the ``D``
+    channels on the lanes), ``dt`` / ``x`` [slots, D] float32 (both 0 in a
+    slot that is not live: ``exp(0) S + 0`` is ``S``, bit for bit), ``a``
+    [N, D] float32 (negative), ``b`` / ``c`` [slots, N] float32 ->
+    (``y`` [slots, D] float32, the state with static ``layer`` updated in
+    place: ``input_output_aliases``, the other layers' blocks untouched)::
+
+        S[n, d] <- exp(dt[d] a[n, d]) S[n, d] + b[n] dt[d] x[d]      y[d] = sum_n c[n] S[n, d]
+
+    A grid step takes ``SELECTIVE_SLOTS_PER_STEP`` slots' blocks; the
+    decay is formed in registers. Float32 on the VPU and the EUP: the call
+    moves the state once each way and is bound by that."""
+    _, slots, n, d = state.shape
+    per = next(k for k in SELECTIVE_SLOTS_PER_STEP if slots % k == 0)
+    row = pl.BlockSpec((per, 1, d), lambda i: (i, 0, 0))
+    col = pl.BlockSpec((per, n, 1), lambda i: (i, 0, 0))
+    block = pl.BlockSpec((1, per, n, d), lambda i: (layer, i, 0, 0))
+    y, state = pl.pallas_call(
+        _selective_kernel,
+        grid=(slots // per,),
+        in_specs=[block, row, row, pl.BlockSpec((n, d), lambda i: (0, 0)), col, col],
+        out_specs=[row, block],
+        out_shape=[jax.ShapeDtypeStruct((slots, 1, d), jnp.float32), jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={0: 1},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * slots * n * d, transcendentals=slots * n * d,
+            bytes_accessed=2 * 4 * slots * n * d + 3 * 4 * slots * d + 4 * n * d + 2 * 4 * slots * n,
+        ),
+        interpret=interpret,
+        name="selective_state_update",
+    )(state, dt[:, None, :], x[:, None, :], a, b[:, :, None], c[:, :, None])
+    return y[:, 0], state

@@ -1,4 +1,6 @@
-"""A Mamba-2 state-space layer's recurrence, in its three forms.
+"""A state-space layer's recurrence, in its three forms: Mamba-2's first,
+and below it Mamba-1's (``selective_*``), which has no heads and a decay
+for every (channel, state) pair.
 
 For a head ``h`` of group ``g`` (``H`` heads of width ``P``; ``G`` groups
 whose ``B_t``, ``C_t`` [N] the group's ``H / G`` heads share), in float32::
@@ -41,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from ..device import on_tpu
-from .kernels.ssm_update import ssm_state_update
+from .kernels.ssm_update import selective_state_update, ssm_state_update
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 LANES = 128
@@ -175,3 +177,91 @@ def update(state, layer: int, x, dt, a, b, c, backend: Optional[str] = None, int
         y, state = ssm_state_update(state, layer, decay, xd, b.astype(jnp.float32), c.astype(jnp.float32), interpret=interpret)
         return y.reshape(x.shape), state
     return update_reference(state, layer, x, dt, a, b, c)
+
+
+# ------------------------------------------------------------- Mamba-1
+# A Mamba-1 (selective) recurrence has a decay for every (channel, state)
+# pair and no heads: for channel ``d`` of ``D`` and state index ``n`` of
+# ``N``, in float32::
+#
+#     S_t[d, n] = exp(dt_t[d] A[d, n]) S_{t-1}[d, n] + dt_t[d] B_t[n] x_t[d]
+#     y_t[d]    = sum_n C_t[n] S_t[d, n]
+#
+# so there is no ``[Q, Q]`` matrix of decays a chunk that an MXU could take
+# (:func:`chunk_scan` rests on ONE decay a head): the three forms below are
+# the oracle, a scan in chunks and a decode step's update. A row with ``dt =
+# 0`` and ``x = 0`` passes the state on bit for bit, as above. The stored
+# state is ``[slots, N, D]`` float32, ``N`` on the sublanes and the channels
+# on the lanes (:func:`selective_state_shape`).
+
+
+def selective_state_shape(inner: int, state: int) -> Tuple[int, int]:
+    """One sequence's stored state of one Mamba-1 layer: ``[N, D]``."""
+    return state, inner
+
+
+def selective_recurrence(x, dt, a, b, c, state=None):
+    """The equations, position by position: ``x`` [B, S, D], ``dt`` [B, S,
+    D] float32, ``a`` [D, N] float32 (negative), ``b`` / ``c`` [B, S, N];
+    ``state`` [B, D, N] float32 (None: zeros). Returns ``y`` [B, S, D]
+    float32 and the state after the last position."""
+    s0 = jnp.zeros((x.shape[0], x.shape[2], b.shape[2]), jnp.float32) if state is None else state
+
+    def step(s, row):
+        xt, dtt, bt, ct = row  # [B, D], [B, D], [B, N] x 2
+        s = jnp.exp(dtt[..., None] * a) * s + (dtt * xt)[..., None] * bt[:, None, :]
+        return s, jnp.sum(s * ct[:, None, :], axis=-1)
+
+    rows = tuple(jnp.moveaxis(v.astype(jnp.float32), 1, 0) for v in (x, dt, b, c))
+    final, ys = jax.lax.scan(step, s0, rows)
+    return jnp.moveaxis(ys, 0, 1), final
+
+
+def selective_scan(x, dt, a, b, c, chunk: int):
+    """A prefill's form, from a zero state: the operands and results of
+    :func:`selective_recurrence`, the state kept as it is stored (``[B, N,
+    D]``: the channels on the lanes) and carried by a scan over chunks of
+    ``chunk`` positions whose inside is unrolled (the sequence padded
+    behind with rows of ``dt = 0``, which pass the state on): the same
+    float32 arithmetic position by position as the oracle's, a loop
+    iteration a chunk and not a position."""
+    bsz, s, d = x.shape
+    pad = -s % chunk
+    rows = [v.astype(jnp.float32) for v in (x, dt, b, c)]
+    if pad:
+        rows = [jnp.pad(v, ((0, 0), (0, pad), (0, 0))) for v in rows]
+    at = a.T  # [N, D]
+
+    def step(state, row):
+        xt, dtt, bt, ct = row
+        state = jnp.exp(dtt[:, None, :] * at) * state + bt[:, :, None] * (dtt * xt)[:, None, :]
+        return state, jnp.sum(state * ct[:, :, None], axis=1)
+
+    final, ys = jax.lax.scan(
+        step, jnp.zeros((bsz, b.shape[2], d), jnp.float32), tuple(jnp.moveaxis(v, 1, 0) for v in rows), unroll=chunk
+    )
+    return jnp.moveaxis(ys, 0, 1)[:, :s], jnp.swapaxes(final, 1, 2)
+
+
+def selective_update_reference(state, layer: int, x, dt, a, b, c):
+    """:func:`selective_update` as an XLA composition on the stored layout."""
+    dt = dt.astype(jnp.float32)
+    new = jnp.exp(dt[:, None, :] * a.T) * state[layer] + b.astype(jnp.float32)[:, :, None] * (dt * x.astype(jnp.float32))[:, None, :]
+    return jnp.sum(new * c.astype(jnp.float32)[:, :, None], axis=1), state.at[layer].set(new)
+
+
+def selective_update(state, layer: int, x, dt, a, b, c, backend: Optional[str] = None, interpret: bool = False):
+    """One decode step of static ``layer`` of the WHOLE stored state ``[n_layers,
+    slots, N, D]`` float32 (never a sliced-out layer: the caller donates
+    the array and the update runs in place). ``x`` / ``dt`` [slots, D] (``dt``
+    float32; both 0 in a slot that is not live), ``a`` [D, N], ``b`` / ``c``
+    [slots, N]. Returns ``y`` [slots, D] float32 and the state: on a TPU
+    the Pallas call ``selective_state_update`` (the decay formed inside),
+    elsewhere the XLA composition of the same arithmetic."""
+    kernel = interpret or (on_tpu() if backend is None else backend == "tpu")
+    if kernel and state.shape[-1] % LANES == 0 and state.shape[-2] % 8 == 0:
+        f32 = jnp.float32
+        return selective_state_update(
+            state, layer, dt.astype(f32), x.astype(f32), a.T, b.astype(f32), c.astype(f32), interpret=interpret
+        )
+    return selective_update_reference(state, layer, x, dt, a, b, c)

@@ -145,6 +145,9 @@ _as_pr_41_left_it = _as_it_was_after("longcat-flash-chat.agent-turns", "longcat-
 _as_pr_45_left_it = _as_it_was_after("sdar-30b-a3b-chat.block-gen", "sdar-30b-a3b-chat", "block_prefill_ms_per_ktoken.served")
 # PR 48 pins the SET of metrics that list its cell (PR 50 appended ten, seven of which list every cell)
 _as_pr_49_left_it = _per_layer_through("ssm_prefill_ms_per_ktoken.served")
+# PR 57 appended a configuration, a cell and six metrics, and the cell's name to the lists of the accepted
+# metrics it reports (three of them PR 48's own): the file as PR 56 left it
+_as_pr_56_left_it = _as_it_was_after("nemotron-3-super-120b-a12b.reason-gen", "nemotron-3-super-120b-a12b", "setup_spanned_share")
 
 
 def _seeing(item, view):
@@ -178,7 +181,7 @@ _PINNED_TAILS = {
     ("test_host_release_share.py", "test_benchmark_json_asks_for_it_in_the_five_serving_cells"): _as_pr_41_left_it,
     ("test_longcat_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_five_metrics_that_list_it"): _as_pr_41_left_it,
     ("test_sdar_cell.py", "test_benchmark_json_gained_one_configuration_one_cell_and_six_metrics_that_list_it"): _as_pr_45_left_it,
-    ("test_nemotron_cell.py", "test_benchmark_json_holds_the_configuration_the_cell_and_four_metrics_that_list_it"): _as_pr_49_left_it,
+    ("test_nemotron_cell.py", "test_benchmark_json_holds_the_configuration_the_cell_and_four_metrics_that_list_it"): lambda b: _as_pr_49_left_it(_as_pr_56_left_it(b)),
 }
 
 
